@@ -32,7 +32,7 @@ logger = logging.getLogger("glint_word2vec_tpu")
 _ABI_VERSION = 2
 _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                     "native", "ingest.cpp")
-_LIB = os.path.join(os.path.dirname(_SRC), "libingest.so")
+_LIB_STEM = os.path.join(os.path.dirname(_SRC), "libingest")
 
 _lock = make_lock("data.ingest_native.load")
 _lib: Optional[ctypes.CDLL] = None
@@ -50,7 +50,7 @@ def _load() -> Optional[ctypes.CDLL]:
             _load_failed = True
             return None
         from glint_word2vec_tpu.data.native import build_or_reload
-        lib = build_or_reload(_SRC, _LIB, "glint_ingest_abi_version",
+        lib = build_or_reload(_SRC, _LIB_STEM, "glint_ingest_abi_version",
                               _ABI_VERSION, "c++20", "ingest")
         if lib is None:
             _load_failed = True

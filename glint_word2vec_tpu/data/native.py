@@ -1,7 +1,8 @@
 """ctypes binding for the native pair generator (``native/pairgen.cpp``).
 
 The shared library is built on first use with ``g++`` (no Python headers, no
-pybind11 — plain C ABI) and cached next to the source. Everything degrades
+pybind11 — plain C ABI) and cached next to the source under a name keyed by the
+source's hash, so a copied tree can never run a stale build. Everything degrades
 gracefully: if the toolchain or build is unavailable, :func:`native_available`
 returns False and the pipeline stays on the bit-identical numpy path.
 
@@ -14,6 +15,7 @@ from __future__ import annotations
 
 import ctypes
 import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -29,21 +31,26 @@ logger = logging.getLogger("glint_word2vec_tpu")
 _ABI_VERSION = 1
 _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                     "native", "pairgen.cpp")
-_LIB = os.path.join(os.path.dirname(_SRC), "libpairgen.so")
+_LIB_STEM = os.path.join(os.path.dirname(_SRC), "libpairgen")
 
 _lock = make_lock("data.native.load")
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
 
-def build_or_reload(src: str, lib_path: str, abi_symbol: str, abi_version: int,
+def build_or_reload(src: str, lib_stem: str, abi_symbol: str, abi_version: int,
                     std: str, what: str) -> Optional[ctypes.CDLL]:
     """The shared build-on-first-use contract for every native component:
-    compile with g++ when the cached .so is missing or older than the source,
-    load, verify the ABI symbol, and rebuild once on a stale/broken cache.
-    Returns the CDLL or None (with a logged warning — callers fall back to
-    their pure-Python path). Argtype configuration and caching stay with the
-    calling module."""
+    the cached library is ``<lib_stem>.<sha256(source)[:16]>.so`` — compile
+    with g++ when that file is missing (mtimes do not survive a copy, a
+    content hash does), load, verify the ABI symbol, and rebuild once on a
+    broken cache. Returns the CDLL or None (with a logged warning — callers
+    fall back to their pure-Python path). Argtype configuration and caching
+    stay with the calling module."""
+    from glint_word2vec_tpu.train.faults import retry_io
+    with retry_io(lambda: open(src, "rb"), what=f"native source {src!r}") as f:
+        lib_path = f"{lib_stem}.{hashlib.sha256(f.read()).hexdigest()[:16]}.so"
+
     def build() -> bool:
         # per-process temp name: co-hosted builders (multi-process JAX workers,
         # parallel pytest) must not interleave g++ output into one file before
@@ -55,7 +62,7 @@ def build_or_reload(src: str, lib_path: str, abi_symbol: str, abi_version: int,
         # glob.escape: a cache path containing [, ?, * must match literally —
         # unescaped it would silently sweep nothing (orphans accumulate) or
         # match unrelated files for deletion
-        for stale in glob.glob(glob.escape(lib_path) + ".tmp*"):  # incl. legacy fixed ".tmp"
+        for stale in glob.glob(glob.escape(lib_stem) + ".*.tmp*"):
             try:
                 if time.time() - os.path.getmtime(stale) > 300:
                     os.unlink(stale)
@@ -80,9 +87,7 @@ def build_or_reload(src: str, lib_path: str, abi_symbol: str, abi_version: int,
         os.replace(tmp, lib_path)
         return True
 
-    needs_build = (not os.path.exists(lib_path)
-                   or os.path.getmtime(lib_path) < os.path.getmtime(src))
-    if needs_build and not build():
+    if not os.path.exists(lib_path) and not build():
         return None
     try:
         lib = ctypes.CDLL(lib_path)
@@ -106,7 +111,7 @@ def _load() -> Optional[ctypes.CDLL]:
         if os.environ.get("GLINT_DISABLE_NATIVE"):
             _load_failed = True
             return None
-        lib = build_or_reload(_SRC, _LIB, "glint_pairgen_abi_version",
+        lib = build_or_reload(_SRC, _LIB_STEM, "glint_pairgen_abi_version",
                               _ABI_VERSION, "c++17", "pairgen")
         if lib is None:
             _load_failed = True

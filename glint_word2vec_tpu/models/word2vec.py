@@ -268,9 +268,9 @@ class Word2VecModel:
         nprobe: Optional[int] = None,
     ) -> List[List[Tuple[str, float]]]:
         """Batched :meth:`find_synonyms`: one device dispatch per ``chunk``
-        queries instead of one per query. Through a thin host→device link the
-        per-query round trip dominates (PERF.md §6: ~300 ms/query at V=1M rows);
-        batching amortizes it — the [chunk, V] cosine matrix rides one matmul.
+        queries instead of one per query. The per-query launch and fetch round
+        trip dominates a scan this short; batching amortizes it — the
+        [chunk, V] cosine matrix rides one matmul.
         Word queries exclude themselves (mllib:621-629); vector queries do not.
         ``chunk`` bounds device memory at chunk·V·4 bytes of scores.
 

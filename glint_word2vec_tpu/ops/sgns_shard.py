@@ -56,7 +56,6 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from glint_word2vec_tpu.ops.sgns import (
@@ -275,7 +274,7 @@ def make_shard_map_sgns_step(
             loss = mean_f_pos = jnp.float32(0.0)
         return new_syn0, new_syn1, loss, mean_f_pos, pairs
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(MODEL_AXIS, None), P(MODEL_AXIS, None),
                   P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS), P(), P()),
@@ -284,7 +283,7 @@ def make_shard_map_sgns_step(
         # applies the identical all-gathered payload to the identical block;
         # scalars ride a psum) — but the tracer cannot prove it through the
         # scatters, so replication checking is off
-        check_rep=False)
+        check_vma=False)
 
     def step(params, batch, negatives, alpha):
         syn0, syn1 = params
@@ -424,7 +423,7 @@ def make_shard_map_sgns_step(
             loss = mean_f_pos = jnp.zeros((k,), jnp.float32)
         return merged0, merged1, loss, mean_f_pos, pairs
 
-    mapped_window = shard_map(
+    mapped_window = jax.shard_map(
         local_window, mesh=mesh,
         in_specs=(P(MODEL_AXIS, None), P(MODEL_AXIS, None),
                   P(None, DATA_AXIS), P(None, DATA_AXIS), P(None, DATA_AXIS),
@@ -433,7 +432,7 @@ def make_shard_map_sgns_step(
         # replication holds BY the merge (bitwise-identical psum result +
         # replicated start on every data replica), but the tracer cannot
         # prove it through the scatters — same waiver as the k=1 step
-        check_rep=False)
+        check_vma=False)
 
     def window(params, batch, negatives, alphas):
         syn0, syn1 = params

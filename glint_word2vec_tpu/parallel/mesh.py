@@ -83,9 +83,9 @@ class MeshPlan:
     @property
     def pairs_stacked(self) -> NamedSharding:
         """[K, 2, B] packed (centers, contexts) chunk: scan and stream axes replicated,
-        batch axis split over data. One contiguous transfer per dispatch — through a
-        narrow host→device link (tunnel, DCN feed), per-transfer overhead dominates
-        small puts, so the whole chunk ships as a single array."""
+        batch axis split over data. One contiguous transfer per dispatch — over PCIe
+        or a DCN feed the per-transfer overhead dominates small puts, so the whole
+        chunk ships as a single array."""
         return NamedSharding(self.mesh, P(None, None, DATA_AXIS))
 
     @property

@@ -318,7 +318,10 @@ class SubprocessReplica:
             cmd += ["--telemetry", self.telemetry_path,
                     "--process-name", self.name]
         env = dict(self._env if self._env is not None else os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # the subprocess fleet is a HOST tier: N replica processes cannot share
+        # one chip (a chip belongs to one process), so children are pinned to
+        # the CPU backend whatever the parent runs on
+        env["JAX_PLATFORMS"] = "cpu"
         stderr = (open(self._stderr_path, "ab")
                   if self._stderr_path else subprocess.DEVNULL)
         try:

@@ -7,11 +7,12 @@ get wrong: dynamic per-position windows, sentence boundaries inside a block,
 subsampled (kept) streams, padded tails, and — the banded-only hazard — examples
 whose windows cross a chunk cut (the ±window halo must make them exact).
 
-Float64 runs (via jax.experimental.enable_x64) hold the two formulations to
+Float64 runs (via jax.enable_x64) hold the two formulations to
 ~1e-12: at that tolerance any dropped/double-counted context link or off-by-one
 interval endpoint is a hard failure, not noise.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -77,20 +78,27 @@ def _host_windows(ktoks, starts, window):
 
 def _scatter_reference(params, ktoks, left, right, sel, negatives, alpha,
                        num_negatives, window, dtype):
-    """One cbow_step_shared_core step over the stream positions in ``sel``."""
+    """One cbow_step_shared_core step over the stream positions in ``sel``.
+    The batch is padded with masked rows to a multiple of 16: the step runs
+    eagerly, so every distinct batch length would recompile each of its ops
+    (the multi-block cases feed a dozen lengths); a masked row adds exact
+    zeros to row 0."""
     C = 2 * window
     nb = len(sel)
-    ctx = np.zeros((nb, C), np.int32)
-    ctxm = np.zeros((nb, C), np.float32)
+    padded = -(-nb // 16) * 16
+    centers = np.zeros(padded, np.int32)
+    centers[:nb] = ktoks[sel]
+    ctx = np.zeros((padded, C), np.int32)
+    ctxm = np.zeros((padded, C), np.float32)
     for i, b in enumerate(sel):
         idx = (list(range(b - left[b], b))
                + list(range(b + 1, b + right[b] + 1)))
         ctx[i, :len(idx)] = ktoks[idx]
         ctxm[i, :len(idx)] = 1.0
+    mask = (np.arange(padded) < nb).astype(np.float32)
     return cbow_step_shared_core(
-        params, jnp.asarray(ktoks[sel].astype(np.int32)), jnp.asarray(ctx),
-        jnp.asarray(ctxm), jnp.ones(nb, jnp.float32), negatives, alpha,
-        num_negatives, "exact", dtype)
+        params, jnp.asarray(centers), jnp.asarray(ctx), jnp.asarray(ctxm),
+        jnp.asarray(mask), negatives, alpha, num_negatives, "exact", dtype)
 
 
 def _banded_blocks(ktoks, starts, T, window):
@@ -240,8 +248,7 @@ def test_banded_equals_scatter_float32_subsampled():
 def test_banded_equals_scatter_float64_tight():
     """float64 on CPU: any structural mismatch (lost/duplicated context link,
     off-by-one interval) is far above 1e-12 — this is the exactness pin."""
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         _equivalence_case(jnp.float64, 1e-12, 1e-14, subsample=0.0)
 
 

@@ -98,8 +98,7 @@ def _inputs(seed=0, V=60, D=12, B=24, P=8):
 
 
 def _run_shared(params_np, centers, contexts, mask, negs, alpha, **kw):
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         got = sgns_step_shared_core(
             EmbeddingPair(jnp.asarray(params_np[0]), jnp.asarray(params_np[1])),
             jnp.asarray(centers), jnp.asarray(contexts),
@@ -141,14 +140,12 @@ def test_fused_and_chain_match_classic_f64(kw):
 
 
 def test_perpair_fused_and_chain_match_classic_f64():
-    from jax.experimental import enable_x64
-
     syn0, syn1, centers, contexts, mask, _ = _inputs()
     rng = np.random.default_rng(7)
     pn = rng.integers(0, syn0.shape[0], (centers.shape[0], NEG)).astype(
         np.int32)
     pn[0, 0] = contexts[0]               # negative colliding with positive
-    with enable_x64():
+    with jax.enable_x64():
         params = EmbeddingPair(jnp.asarray(syn0), jnp.asarray(syn1))
         args = (jnp.asarray(centers), jnp.asarray(contexts),
                 jnp.asarray(mask, jnp.float32), jnp.asarray(pn),
@@ -188,8 +185,7 @@ def test_fused_chain_bf16_tracks_f32():
 
 
 def _hot_slabs(k, d, dtype=jnp.float64):
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         return (jnp.zeros((k, d), dtype), jnp.zeros((k, d), dtype))
 
 
@@ -201,8 +197,7 @@ def test_hot_single_step_matches_classic_f64():
     got, mh, (s0, s1) = _run_shared(
         (syn0, syn1), centers, contexts, mask, negs, 0.05,
         hot_slabs=_hot_slabs(16, syn0.shape[1]))
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         got = EmbeddingPair(hot_flush(got.syn0, s0), hot_flush(got.syn1, s1))
     np.testing.assert_allclose(np.asarray(got.syn0), np.asarray(base.syn0),
                                atol=1e-12)
@@ -215,11 +210,10 @@ def test_hot_single_step_matches_classic_f64():
 def test_hot_multi_step_accumulation_matches_stepwise_f64():
     """K steps with the slab carried and ONE flush at the end reproduce K
     classic steps applied sequentially — the cross-step contract."""
-    from jax.experimental import enable_x64
 
     syn0, syn1, centers, contexts, mask, negs = _inputs()
     D = syn0.shape[1]
-    with enable_x64():
+    with jax.enable_x64():
         ref = EmbeddingPair(jnp.asarray(syn0), jnp.asarray(syn1))
         hot = ref
         slabs = _hot_slabs(16, D)
@@ -245,10 +239,9 @@ def test_hot_multi_step_accumulation_matches_stepwise_f64():
 def test_hot_fully_masked_batch_is_noop():
     """A padding batch (mask all zero, placeholder index 0 = a HOT row) must
     leave params and slabs exactly unchanged through step + flush."""
-    from jax.experimental import enable_x64
 
     syn0, syn1, centers, contexts, _, negs = _inputs()
-    with enable_x64():
+    with jax.enable_x64():
         params = EmbeddingPair(jnp.asarray(syn0), jnp.asarray(syn1))
         zeros = jnp.zeros(centers.shape[0], jnp.float32)
         got, _, (s0, s1) = sgns_step_shared_core(
@@ -265,13 +258,11 @@ def test_hot_fully_masked_batch_is_noop():
 
 
 def test_perpair_hot_matches_classic_f64():
-    from jax.experimental import enable_x64
-
     syn0, syn1, centers, contexts, mask, _ = _inputs()
     rng = np.random.default_rng(9)
     pn = rng.integers(0, 60, (centers.shape[0], NEG)).astype(np.int32)
     pn[:, 0] = 1                          # hot negatives with duplicates
-    with enable_x64():
+    with jax.enable_x64():
         params = EmbeddingPair(jnp.asarray(syn0), jnp.asarray(syn1))
         args = (jnp.asarray(centers), jnp.asarray(contexts),
                 jnp.asarray(mask, jnp.float32), jnp.asarray(pn),
@@ -383,9 +374,8 @@ def test_trainer_fused_and_chain_fit_smoke():
 def test_shard_map_fused_matches_gspmd_fused_f64():
     """shard_map runs the SAME fused chain through the shared helper —
     cross-lowering equivalence at f64 on a 2x4 mesh."""
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with jax.enable_x64():
         rng = np.random.default_rng(0)
         v, d, b, pool = 64, 16, 32, 8
         params = EmbeddingPair(
@@ -409,7 +399,8 @@ def test_shard_map_fused_matches_gspmd_fused_f64():
         step = make_shard_map_sgns_step(
             plan.mesh, NEG, "exact", jnp.float64, jnp.float64, True,
             fused=True, bf16_chain=True)
-        got, mgot = step(sharded, batch, negs, alpha)
+        # jitted like the trainer's dispatch: eager shard_map is op-by-op
+        got, mgot = jax.jit(step)(sharded, batch, negs, alpha)
         np.testing.assert_allclose(np.asarray(got.syn0),
                                    np.asarray(ref.syn0), atol=1e-11)
         np.testing.assert_allclose(np.asarray(got.syn1),

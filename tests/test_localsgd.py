@@ -123,11 +123,10 @@ def test_window_matches_numpy_oracle_f64(shape, stab):
     every mesh shape, stabilizers off and on (the owner-local clamp pass runs
     on the LOCAL touched set, which is exactly what the per-shard oracle
     replays; the merge preserves the clamp ball by convexity)."""
-    from jax.experimental import enable_x64
 
     nd, nm = shape
     k = 2
-    with enable_x64():
+    with jax.enable_x64():
         params, batch, negs, alphas = _inputs(
             jnp.float64, k=k, nd=nd, seed=5)
         plan = make_mesh(*shape)

@@ -24,6 +24,7 @@ import pytest
 
 WORKER = r"""
 import os, sys
+# CPU by design, like the rest of the suite: each worker is one "host" of 4 virtual devices
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax

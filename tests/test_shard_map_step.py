@@ -61,9 +61,8 @@ def _f64_inputs(v=64, d=16, b=32, pool=8, seed=0):
 @pytest.mark.parametrize("shape", MESHES)
 def test_equivalence_f64_all_mesh_shapes(shape):
     """shard_map ≡ GSPMD ≡ single-device at f64 ~1e-12, per mesh shape."""
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with jax.enable_x64():
         params, batch, negs, alpha = _f64_inputs()
         ref, mref = sgns_step_shared_core(
             params, batch["centers"], batch["contexts"], batch["mask"],
@@ -107,9 +106,8 @@ def test_cross_layout_loss_rows_vs_cols():
     """The CIKM'16 column layout (GSPMD, embedding_partition='cols') and the
     explicit rows schedule compute the same loss — the dryrun's cross-layout
     check extended to the shard_map step (f64)."""
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with jax.enable_x64():
         params, batch, negs, alpha = _f64_inputs(v=64, d=32, b=32, pool=8)
         plan = make_mesh(2, 4)
         rows_p = EmbeddingPair(

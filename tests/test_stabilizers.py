@@ -124,14 +124,12 @@ def _oracle_inputs(seed=0, V=60, D=12, B=24, P=8):
     Stabilizers(max_row_norm=1e6),                        # present, no row hit
 ])
 def test_shared_pool_oracle_f64(stab):
-    from jax.experimental import enable_x64
-
     syn0, syn1, centers, contexts, mask, negs = _oracle_inputs()
     n = 3
     alpha = 0.05
     ref0, ref1 = _np_shared_step(
         syn0, syn1, centers, contexts, mask, negs, alpha, n, stab)
-    with enable_x64():
+    with jax.enable_x64():
         got, _ = sgns_step_shared_core(
             EmbeddingPair(jnp.asarray(syn0), jnp.asarray(syn1)),
             jnp.asarray(centers), jnp.asarray(contexts),
@@ -197,14 +195,12 @@ MESHES = [(1, 8), (2, 4), (8, 1)]
 
 @pytest.mark.parametrize("shape", MESHES)
 def test_shard_map_stabilized_equivalence_f64(shape):
-    from jax.experimental import enable_x64
-
     from glint_word2vec_tpu.ops.sgns_shard import make_shard_map_sgns_step
     from glint_word2vec_tpu.parallel.mesh import make_mesh
 
     if len(jax.devices()) < 8:
         pytest.skip("needs the 8-device CPU mesh (conftest)")
-    with enable_x64():
+    with jax.enable_x64():
         syn0, syn1, centers, contexts, mask, negs = _oracle_inputs(
             seed=2, V=64, D=16, B=16, P=8)
         stab = Stabilizers(max_row_norm=5.0, update_clip=0.1, row_l2=1e-3)
@@ -219,7 +215,9 @@ def test_shard_map_stabilized_equivalence_f64(shape):
         step = make_shard_map_sgns_step(
             plan.mesh, 3, compute_dtype=jnp.float64,
             logits_dtype=jnp.float64, stabilizers=stab)
-        ps, _ = step(sharded, batch, jnp.asarray(negs), alpha)
+        # jitted like the trainer's dispatch (and tests/test_shard_map_step.py):
+        # an eager shard_map runs op by op across the 8 devices, ~40 s here
+        ps, _ = jax.jit(step)(sharded, batch, jnp.asarray(negs), alpha)
         pr, _ = sgns_step_shared_core(
             params, batch["centers"], batch["contexts"], batch["mask"],
             jnp.asarray(negs), alpha, 3, "exact", jnp.float64, False,
@@ -235,13 +233,12 @@ def test_banded_scatter_stabilized_equivalence_f64():
     here: the two formulations' touched SETS differ on context-less tokens —
     documented in cbow_step_banded_core — so decay is pinned by the oracle
     and SGNS lowering tests instead)."""
-    from jax.experimental import enable_x64
 
     from test_cbow_banded import _banded_blocks, _host_windows, _kept_stream
 
     from glint_word2vec_tpu.ops.cbow_banded import cbow_step_banded_core
 
-    with enable_x64():
+    with jax.enable_x64():
         rng = np.random.default_rng(3)
         V, D, P, W, NEG = 120, 16, 16, 3, 4
         ktoks, starts = _kept_stream(rng, 40, 15, V)

@@ -169,8 +169,13 @@ def test_peer_death_restarts_whole_gang(tmp_path):
         calls.append(attempt)
         return {"MY_RC": str(PEER_ABORT_EXIT) if attempt == 0 else "0"}
 
+    # poll_s well above the few ms between the two children's exits: a poll
+    # that lands between two CLEAN exits trips the gang rule (the rc-0 race
+    # _classify documents) and costs a third attempt — at the default 0.02 s
+    # that happened in 2 of 5 serial tier-1 runs
     sup = _supervisor(tmp_path, [_child(script), _child(script)],
-                      max_restarts=3, stall_s=30.0, env_for_attempt=env_for)
+                      max_restarts=3, stall_s=30.0, env_for_attempt=env_for,
+                      poll_s=0.5)
     v = sup.run()
     assert v.status == "ok" and v.attempts == 2
     assert v.history[0]["cls"] == "peer-death"

@@ -92,6 +92,8 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
 
+# CPU by design: the schedule kills and restarts toy fits in subprocesses; a
+# chip would be held by the first of them
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np  # noqa: E402

@@ -40,7 +40,8 @@ import os
 import re
 import sys
 
-# self-provision the virtual multi-device CPU mesh BEFORE jax initializes
+# self-provision the virtual multi-device CPU mesh BEFORE jax initializes — CPU by
+# design: collective BYTES are counted from compiled HLO, nothing is timed
 if __name__ == "__main__":
     os.environ["JAX_PLATFORMS"] = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")

@@ -35,8 +35,6 @@ import time
 _here = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(_here))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)

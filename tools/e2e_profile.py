@@ -146,4 +146,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from glint_word2vec_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -244,7 +244,8 @@ def main() -> int:
     zero_cost = _zero_cost_probe()
 
     # 2) the instrumented in-process stack
-    os.environ["JAX_PLATFORMS"] = os.environ.get("JAX_PLATFORMS", "cpu")
+    # CPU by design: the checker perturbs host lock schedules; the device is idle
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     rep = _smoke_stack(workdir, args.seed, args.perturb, duration)
 
     # 3) full mode: chaos phases with instrumentation exported to children

@@ -16,6 +16,7 @@ import sys
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 V, K = 200_000, 16
 
@@ -66,4 +67,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from glint_word2vec_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -66,8 +66,6 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import numpy as np  # noqa: E402
 from glint_word2vec_tpu.lockcheck import make_lock
 

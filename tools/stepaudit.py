@@ -45,7 +45,8 @@ import os
 import re
 import sys
 
-# self-provision the virtual multi-device CPU mesh BEFORE jax initializes
+# self-provision the virtual multi-device CPU mesh BEFORE jax initializes — CPU by
+# design: the contracts are read off lowered/compiled modules, not timed
 if __name__ == "__main__":
     os.environ["JAX_PLATFORMS"] = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")

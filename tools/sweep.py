@@ -58,4 +58,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from glint_word2vec_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

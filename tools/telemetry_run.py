@@ -34,8 +34,6 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import numpy as np  # noqa: E402
 
 # span names the scripted fit must produce (the acceptance list from ISSUE 6;
@@ -353,4 +351,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from glint_word2vec_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -38,8 +38,6 @@ import tempfile
 _here = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(_here))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
@@ -255,7 +253,10 @@ def run_stall_drill(workdir: str, n_sentences: int = 200) -> dict:
                     "GLINT_FAULT_STALL_S": "120"}
         return {"GLINT_FAULT_STALL_AT_STEP": ""}
 
-    stall_s = 2.0
+    # above a worker's boot (import jax alone is ~2 s on this installation —
+    # at 2.0 every attempt was killed as stalled at step 0, before its first
+    # telemetry record), far below the injected 120 s wedge
+    stall_s = 8.0
     sink = TelemetrySink(os.path.join(workdir, "supervisor.jsonl"))
     try:
         sup = _drill_supervisor(workdir, n_sentences, sink,
