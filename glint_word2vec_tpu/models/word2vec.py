@@ -27,6 +27,7 @@ import numpy as np
 
 from glint_word2vec_tpu.config import Word2VecConfig
 from glint_word2vec_tpu.data.vocab import Vocabulary
+from glint_word2vec_tpu.obs.spans import default_tracer
 from glint_word2vec_tpu.parallel.mesh import MeshPlan, pad_vocab_for_sharding
 from glint_word2vec_tpu.train import checkpoint as ckpt
 
@@ -289,33 +290,55 @@ class Word2VecModel:
                     "model.attach_ann(index)")
             return self._find_synonyms_batch_ann(queries, num, nprobe)
         self.norms  # materialize the cached full-row norms
+        tracer = default_tracer()
         out: List[List[Tuple[str, float]]] = []
         k = min(num + 1, self.num_words)
         for lo in range(0, len(queries), chunk):
             part = queries[lo:lo + chunk]
-            words: List[Optional[str]] = []
-            rows = []
-            for q in part:
-                if isinstance(q, str):
-                    idx = self.vocab.get(q)
-                    if idx < 0:
-                        raise KeyError(f"{q} not in vocabulary")
-                    words.append(q)
-                    rows.append(self._full0[idx])
-                else:
-                    words.append(None)
-                    rows.append(jnp.asarray(q, jnp.float32))
-            scores, idxs = _topk_dispatch(
-                self._full0, self._norms, jnp.stack(rows), k, self.num_words)
-            for word, srow, irow in zip(words, np.asarray(scores),
-                                        np.asarray(idxs)):
-                res: List[Tuple[str, float]] = []
-                for i, s in zip(irow, srow):
-                    w = self.vocab.words[int(i)]
-                    if w == word:
-                        continue
-                    res.append((w, float(s)))
-                out.append(res[:num])
+            # spans of the serve table (obs/spans.py, docs/observability.md
+            # §4): the host side of one scan, region by region
+            with tracer.span("serve.row_fetch") as sp:
+                words: List[Optional[str]] = []
+                rows = []
+                for q in part:
+                    if isinstance(q, str):
+                        idx = self.vocab.get(q)
+                        if idx < 0:
+                            raise KeyError(f"{q} not in vocabulary")
+                        words.append(q)
+                        rows.append(self._full0[idx])
+                    else:
+                        words.append(None)
+                        rows.append(jnp.asarray(q, jnp.float32))
+                block = jnp.stack(rows)
+                # device operations issued to build the block: a row read or
+                # a put per query, and the stack
+                sp.set(ops=len(rows) + 1)
+            with tracer.span("serve.scan_enqueue", queries=len(part)):
+                scores, idxs = _topk_dispatch(
+                    self._full0, self._norms, block, k, self.num_words)
+            with tracer.span("serve.result_fetch"):
+                scores, idxs = np.asarray(scores), np.asarray(idxs)
+            with tracer.span("serve.reply_build"):
+                out.extend(self._replies(words, scores, idxs, num))
+        return out
+
+    def _replies(self, words: List[Optional[str]], scores, idxs,
+                 num: int) -> List[List[Tuple[str, float]]]:
+        """Rows of (score, row id) as ``(word, score)`` lists, the query
+        word itself left out; a negative id ends a row (the ANN arm found
+        fewer candidates than k in the probed cells)."""
+        out: List[List[Tuple[str, float]]] = []
+        for word, srow, irow in zip(words, scores, idxs):
+            res: List[Tuple[str, float]] = []
+            for i, s in zip(irow, srow):
+                if i < 0:
+                    break
+                w = self.vocab.words[int(i)]
+                if w == word:
+                    continue
+                res.append((w, float(s)))
+            out.append(res[:num])
         return out
 
     def _find_synonyms_batch_ann(
@@ -326,32 +349,26 @@ class Word2VecModel:
         own normalized copy (no device gather); vector queries are
         normalized by the index (cosine is scale-invariant)."""
         index = self._ann
-        words: List[Optional[str]] = []
-        rows: List[np.ndarray] = []
-        for q in queries:
-            if isinstance(q, str):
-                idx = self.vocab.get(q)
-                if idx < 0:
-                    raise KeyError(f"{q} not in vocabulary")
-                words.append(q)
-                rows.append(index.vector(idx))
-            else:
-                words.append(None)
-                rows.append(np.asarray(q, np.float32))
+        tracer = default_tracer()
+        with tracer.span("serve.row_fetch", ops=0):
+            words: List[Optional[str]] = []
+            rows: List[np.ndarray] = []
+            for q in queries:
+                if isinstance(q, str):
+                    idx = self.vocab.get(q)
+                    if idx < 0:
+                        raise KeyError(f"{q} not in vocabulary")
+                    words.append(q)
+                    rows.append(index.vector(idx))
+                else:
+                    words.append(None)
+                    rows.append(np.asarray(q, np.float32))
+            block = np.stack(rows)
         k = min(num + 1, self.num_words)
-        scores, idxs = index.search(np.stack(rows), k, nprobe)
-        out: List[List[Tuple[str, float]]] = []
-        for word, srow, irow in zip(words, scores, idxs):
-            res: List[Tuple[str, float]] = []
-            for i, s in zip(irow, srow):
-                if i < 0:
-                    break  # fewer candidates than k in the probed cells
-                w = self.vocab.words[int(i)]
-                if w == word:
-                    continue
-                res.append((w, float(s)))
-            out.append(res[:num])
-        return out
+        with tracer.span("serve.ann_search", queries=len(rows)):
+            scores, idxs = index.search(block, k, nprobe)
+        with tracer.span("serve.reply_build"):
+            return self._replies(words, scores, idxs, num)
 
     def analogy(self, a: str, b: str, c: str, num: int = 10) -> List[Tuple[str, float]]:
         """b − a + c vector arithmetic, excluding the three query words — the analogy
@@ -546,13 +563,14 @@ def _cosine_batch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
                   valid_rows: int) -> jax.Array:
     """The [Q, V] masked cosine matrix of :func:`_cosine_topk_batch` without
     the top-k — the shared front half of the device and CPU top-k routes."""
-    qn = jnp.linalg.norm(queries, axis=1, keepdims=True)
-    q = queries / jnp.maximum(qn, 1e-12)
-    dots = q @ syn0.T                                          # [Q, V]
-    cos = jnp.where(norms[None, :] > 0,
-                    dots / jnp.maximum(norms[None, :], 1e-12), 0.0)
-    return jnp.where(jnp.arange(cos.shape[1])[None, :] < valid_rows,
-                     cos, -jnp.inf)
+    with jax.named_scope("scan.cosine"):
+        qn = jnp.linalg.norm(queries, axis=1, keepdims=True)
+        q = queries / jnp.maximum(qn, 1e-12)
+        dots = q @ syn0.T                                      # [Q, V]
+        cos = jnp.where(norms[None, :] > 0,
+                        dots / jnp.maximum(norms[None, :], 1e-12), 0.0)
+        return jnp.where(jnp.arange(cos.shape[1])[None, :] < valid_rows,
+                         cos, -jnp.inf)
 
 
 @partial(jax.jit, static_argnames=("k", "valid_rows"))
@@ -564,8 +582,9 @@ def _cosine_topk_batch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
     norms with zero-norm → 0 (mllib:601-609), batched device top-k instead of
     the client-side BoundedPriorityQueue scan (mllib:611-619). Rows past
     valid_rows are sharding padding, excluded outright."""
-    return jax.lax.top_k(
-        _cosine_batch(syn0, norms, queries, valid_rows), k)
+    cos = _cosine_batch(syn0, norms, queries, valid_rows)
+    with jax.named_scope("scan.topk"):
+        return jax.lax.top_k(cos, k)
 
 
 # CPU route tiling: queries are sub-chunked so the fetched [q, V] score
@@ -595,7 +614,7 @@ def _cpu_topk_row(row: np.ndarray, k: int) -> Tuple[np.ndarray, np.ndarray]:
 
 
 def _topk_dispatch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
-                   k: int, valid_rows: int) -> Tuple[np.ndarray, np.ndarray]:
+                   k: int, valid_rows: int):
     """Route the cosine top-k (PERF.md §10). Default everywhere:
     ``lax.top_k`` in the same dispatch as the matmul. The host route —
     fetch scores in ~512 MB sub-chunks, rank with chunked ``np.argpartition``
@@ -610,8 +629,9 @@ def _topk_dispatch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
     import os
     if (jax.default_backend() != "cpu"
             or os.environ.get("GLINT_CPU_TOPK") != "argpartition"):
-        s, i = _cosine_topk_batch(syn0, norms, queries, k, valid_rows)
-        return np.asarray(s), np.asarray(i)
+        # device arrays: this returns once the program is enqueued, and the
+        # caller's fetch is where the host waits for it
+        return _cosine_topk_batch(syn0, norms, queries, k, valid_rows)
     Q, V = queries.shape[0], syn0.shape[0]
     qsub = max(1, min(Q, _CPU_TOPK_SCORE_BYTES // max(V * 4, 1)))
     scores = np.empty((Q, k), np.float32)

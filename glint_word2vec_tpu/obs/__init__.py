@@ -9,8 +9,9 @@ Seven layers, each usable alone, all off by default and zero-cost when off:
   fires on the probe channels where the non-finite guardrail stays silent.
 - :mod:`.sink` + :mod:`.schema` — the schema-versioned JSONL run log
   (rotating file, never stdout — graftlint R7).
-- :mod:`.spans` — thread-safe host trace spans exported as Chrome-trace JSON
-  (Perfetto-loadable).
+- :mod:`.spans` — the one host span recorder (ids, parents, counts as
+  args): on under telemetry or while a ``jax.profiler`` trace is live, when
+  the spans are in the profiler's trace too; Chrome-trace JSON export.
 - :mod:`.phases` — host-side per-phase log2 duration histograms (producer
   wait / stage / dispatch / device block), the "where did the time go"
   attribution without a trace viewer.

@@ -1,36 +1,67 @@
-"""Host trace spans: a small thread-safe span API + Chrome-trace export.
+"""Host trace spans: the program's one span recorder.
 
-The trainer already records two aggregate timers (``host_wait_time`` /
-``dispatch_time``), but PR 3/PR 4 added a multi-threaded producer, a
-one-round-ahead stager, and parallel checkpoint I/O — and no artifact shows
-where wall-clock actually goes across those threads, which is exactly what
-the first pod session (ROADMAP items 3–4) needs to attribute step time. A
-span is one timed region on one thread; the export is the Chrome trace event
-format (``chrome://tracing`` / Perfetto / ``about:tracing`` all load it), so
-nesting and cross-thread overlap render without any custom viewer.
+A span is one timed region on one thread: name, start, duration, a
+process-unique ``id``, the ``parent`` that caused it, and counts riding as
+args (``size=``, ``ops=``), so that ratios are measured where the work
+happens. The fit loop, the feed threads, the checkpoint I/O, the serve
+batcher, ``EmbeddingService._dispatch`` and ``find_synonyms_batch`` all
+record through the one process-wide :func:`default_tracer`.
+
+When it records. ``Tracer.span()`` is active when ``enabled`` is set (run
+telemetry: ``config.telemetry_path`` / ``status_port``) **or while a
+``jax.profiler`` trace is live**. While a trace is live each span is entered
+as a ``jax.profiler.TraceAnnotation`` too, so it lands in the ``.xplane.pb``'s
+host plane on the clock of the device's operations, beside its record in the
+ring: a profile of the program names the program's own regions, and a reader
+in the same process (``benchmark/readers/program_spans.py``) reduces
+``events()`` once the trace has stopped. "Live" is
+``TraceAnnotation.is_enabled()``: true from ``start_trace`` until
+``stop_trace`` *begins* (the session object outlives the export, which
+takes seconds; spans taken then would have no counterpart in the trace). A
+span that is open when the trace stops is not kept. tests/test_obs.py holds
+that rule against a JAX upgrade.
+
+The two aggregate timers of the trainer (``host_wait_time``,
+``dispatch_time``) predate this and feed the sink's ``heartbeat`` /
+``run_end`` records; spans are what says where the time of a dispatch, a
+batch or a feed thread goes.
 
 Design constraints:
 
-- zero-cost when disabled: ``span()`` returns a shared no-op context manager
-  (no allocation, no clock read) — every fit path can instrument
-  unconditionally;
+- near-zero cost when inactive: ``span()`` returns a shared no-op context
+  manager after one attribute read and one ``is_enabled()`` call (no
+  allocation, no clock read): every path can instrument unconditionally;
+- one clock: ``time.monotonic`` (:data:`now`), the clock of the serve
+  tier's tickets and of the fleet's ``trace_span`` records, so a region is
+  timed once and every consumer is fed the span's own ``t0`` / ``dur``;
 - thread-safe and bounded: events land in a ring (oldest dropped past
-  ``max_events``) under one lock held only for the append — producer/stager
-  threads never serialize against each other's timed regions;
+  ``max_events``) under one lock held only for the append; the parent of a
+  span is the enclosing span on the same thread (a ``threading.local``
+  stack, never shared), or an explicit ``parent=`` for work caused on
+  another thread;
 - no ad-hoc threads (graftlint R1): this module only OBSERVES threads.
 
-One process-wide default tracer exists so layers with no Trainer handle
-(checkpoint save/load) can record spans; the Trainer enables/clears it per
-run when telemetry is on.
+The Chrome-trace export (``chrome://tracing`` / Perfetto) renders nesting
+and cross-thread overlap without a custom viewer.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
 from glint_word2vec_tpu.lockcheck import make_rlock
+
+# the recorder's clock; ``Tracer.record`` takes its ``t0`` on it
+now = time.monotonic
+
+# process-unique span ids; ``next`` on a count is atomic under the GIL
+_IDS = itertools.count(1)
 
 
 class _NoopSpan:
@@ -40,6 +71,9 @@ class _NoopSpan:
         return self
 
     def __exit__(self, *exc):
+        return None
+
+    def set(self, **args):
         return None
 
 
@@ -59,20 +93,51 @@ _PHASE_OF = {
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    """One region. ``t0`` / ``dur`` (seconds on :data:`now`) are readable after
+    exit, ``id`` from entry; ``recorded`` says whether it reached the ring."""
 
-    def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
+    __slots__ = ("_tracer", "name", "args", "parent", "id", "t0", "dur",
+                 "recorded", "_keep", "_ann")
+
+    def __init__(self, tracer: "Tracer", name: str, args: Optional[dict],
+                 parent: Optional[int], keep: bool, live: bool):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self.parent = parent
+        self.id = None
+        self.recorded = False
+        self._keep = keep
+        self._ann = TraceAnnotation(name) if live else None
+
+    def set(self, **args) -> None:
+        """Counts known only once the work is done (``ops=``)."""
+        self.args = {**self.args, **args} if self.args else args
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        if self._keep:
+            stack = self._tracer._stack()
+            if self.parent is None and stack:
+                self.parent = stack[-1]
+            self.id = next(_IDS)
+            stack.append(self.id)
+            if self._ann is not None:
+                self._ann.__enter__()
+        self.t0 = now()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        self._tracer._record(self.name, self._t0, t1 - self._t0, self.args)
+        self.dur = now() - self.t0
+        if self._keep:
+            tracer = self._tracer
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+            tracer._stack().pop()
+            # a span the trace's stop cut short has no counterpart there
+            if tracer.enabled or TraceAnnotation.is_enabled():
+                tracer._record(self.name, self.t0, self.dur, self.args,
+                               self.id, self.parent)
+                self.recorded = True
         return None
 
 
@@ -91,10 +156,13 @@ class Tracer:
         # the tail of a long run is what a hang/slowdown investigation needs
         self._events: "deque" = deque(maxlen=self.max_events)
         self._dropped = 0
-        self._epoch = time.perf_counter()
+        self._epoch = now()
         self._phases = None  # PhaseAccumulator of the running trainer, or None
+        self._local = threading.local()  # per-thread stack of open span ids
 
     def configure(self, enabled: bool) -> None:
+        """Telemetry on or off. A live ``jax.profiler`` trace arms the
+        recorder whatever this says (module docstring)."""
         self.enabled = enabled
 
     def attach_phases(self, acc) -> None:
@@ -106,21 +174,46 @@ class Tracer:
         with self._lock:
             self._events.clear()
             self._dropped = 0
-            self._epoch = time.perf_counter()
+            self._epoch = now()
 
-    def span(self, name: str, **args):
-        """Context manager timing one region on the calling thread."""
-        if not self.enabled:
-            return _NOOP
-        return _Span(self, name, args or None)
+    def _stack(self) -> list:
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
+
+    def span(self, name: str, *, parent: Optional[int] = None,
+             timed: bool = False, **args):
+        """Context manager timing one region on the calling thread. Its
+        parent is the enclosing span of this thread, or ``parent`` (a span's
+        ``id``) for work another thread caused. ``timed=True`` is for a
+        caller that needs the region's ``t0`` / ``dur`` itself (the batcher's
+        service-time estimate, the fleet's ``trace_span`` records): the clock
+        is then read even when nothing is recorded, once, by the span."""
+        live = TraceAnnotation.is_enabled()
+        if self.enabled or live:
+            return _Span(self, name, args or None, parent, True, live)
+        if timed:
+            return _Span(self, name, None, None, False, False)
+        return _NOOP
+
+    def record(self, name: str, t0: float, dur: float, *,
+               parent: Optional[int] = None, **args) -> None:
+        """A retroactive span: start (on :data:`now`) and duration known only
+        afterwards, as a ticket's queue wait is. To the ring only; the caller
+        asks first whether anything records (its enclosing span's
+        ``recorded``)."""
+        self._record(name, t0, dur, args or None, next(_IDS), parent)
 
     def wrap_iter(self, name: str, it):
         """Wrap an iterator so each ``next()`` is a span ON THE CONSUMING
         THREAD — handed to a producer-thread iterator (``_threaded_iter``),
         this times production where it happens. Always wraps: ``span()``
-        re-checks ``enabled`` per item (feed iterators are built before the
-        run bookkeeping arms the tracer), and the per-chunk no-op cost is
-        nothing next to chunk assembly."""
+        re-checks per item whether anything records (feed iterators are
+        built before the run bookkeeping arms the tracer, and a profiler
+        trace starts mid-run), and the per-chunk no-op cost is nothing next
+        to chunk assembly."""
 
         def gen():
             src = iter(it)
@@ -134,14 +227,14 @@ class Tracer:
 
         return gen()
 
-    def _record(self, name: str, t0: float, dur: float,
-                args: Optional[dict]) -> None:
+    def _record(self, name: str, t0: float, dur: float, args: Optional[dict],
+                span_id: int, parent: Optional[int]) -> None:
         if self._phases is not None:
             phase = _PHASE_OF.get(name)
             if phase is not None:
                 self._phases.add(phase, dur)
         ev = (name, threading.get_ident(), threading.current_thread().name,
-              t0 - self._epoch, dur, args)
+              t0 - self._epoch, dur, args, span_id, parent)
         with self._lock:
             if len(self._events) == self.max_events:
                 self._dropped += 1
@@ -152,9 +245,10 @@ class Tracer:
     def events(self) -> List[dict]:
         with self._lock:
             evs = list(self._events)
-        return [{"name": n, "tid": tid, "thread": tname,
-                 "ts_s": ts, "dur_s": dur, **({"args": a} if a else {})}
-                for n, tid, tname, ts, dur, a in evs]
+        return [{"name": n, "tid": tid, "thread": tname, "ts_s": ts,
+                 "dur_s": dur, "id": sid, "parent": parent,
+                 **({"args": a} if a else {})}
+                for n, tid, tname, ts, dur, a, sid, parent in evs]
 
     def span_summary(self) -> Dict[str, dict]:
         """Per-span-name {count, total_s, max_s} — the run_end digest."""
@@ -177,14 +271,12 @@ class Tracer:
         tid_map: Dict[int, int] = {}
         names: Dict[int, str] = {}
         trace = []
-        for n, tid, tname, ts, dur, a in evs:
+        for n, tid, tname, ts, dur, a, sid, parent in evs:
             small = tid_map.setdefault(tid, len(tid_map))
             names.setdefault(small, tname)
-            ev = {"ph": "X", "name": n, "pid": 0, "tid": small,
-                  "ts": round(ts * 1e6, 1), "dur": round(dur * 1e6, 1)}
-            if a:
-                ev["args"] = a
-            trace.append(ev)
+            trace.append({"ph": "X", "name": n, "pid": 0, "tid": small,
+                          "ts": round(ts * 1e6, 1), "dur": round(dur * 1e6, 1),
+                          "args": {"id": sid, "parent": parent, **(a or {})}})
         meta = [{"ph": "M", "name": "thread_name", "pid": 0, "tid": small,
                  "args": {"name": tname}} for small, tname in names.items()]
         doc = {"traceEvents": meta + trace, "displayTimeUnit": "ms",
@@ -198,5 +290,6 @@ _default = Tracer()
 
 
 def default_tracer() -> Tracer:
-    """The process-wide tracer (disabled until a telemetry-on run enables it)."""
+    """The process-wide tracer: records under run telemetry or a live
+    ``jax.profiler`` trace, and is a no-op otherwise."""
     return _default

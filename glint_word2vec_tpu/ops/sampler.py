@@ -203,13 +203,14 @@ def sample_negatives_hash(
     from glint_word2vec_tpu.ops.prng import randint_mod, uniform01
 
     V = prob.shape[0]
-    prob2 = prob.reshape(V, 1)    # free view; (V, 1) row gathers take the fast path
-    alias2 = alias.reshape(V, 1)
-    buckets = randint_mod(seed, 0, counter, shape, V)
-    u = uniform01(seed, 1, counter, shape)
-    flat = buckets.reshape(-1)
-    keep = u < prob2[flat][:, 0].reshape(shape)
-    return jnp.where(keep, buckets, alias2[flat][:, 0].reshape(shape))
+    with jax.named_scope("sgns.sample"):
+        prob2 = prob.reshape(V, 1)    # free view; (V, 1) row gathers take the fast path
+        alias2 = alias.reshape(V, 1)
+        buckets = randint_mod(seed, 0, counter, shape, V)
+        u = uniform01(seed, 1, counter, shape)
+        flat = buckets.reshape(-1)
+        keep = u < prob2[flat][:, 0].reshape(shape)
+        return jnp.where(keep, buckets, alias2[flat][:, 0].reshape(shape))
 
 
 def sampled_probabilities(counts: np.ndarray, power: float = 0.75) -> np.ndarray:

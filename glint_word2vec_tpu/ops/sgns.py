@@ -361,15 +361,16 @@ def sgns_step_core(
         neg_valid = (negatives != contexts[:, None]).astype(jnp.float32) \
             * mask[:, None]
 
-    if hot_slabs is not None:
-        slab0, slab1 = hot_slabs
-        e_in = hot_gather(syn0, slab0, centers, compute_dtype)    # [B, D]
-        e_pos = hot_gather(syn1, slab1, contexts, compute_dtype)  # [B, D]
-        e_neg = hot_gather(syn1, slab1, negatives, compute_dtype)  # [B, n, D]
-    else:
-        e_in = syn0[centers].astype(compute_dtype)          # [B, D]
-        e_pos = syn1[contexts].astype(compute_dtype)        # [B, D]
-        e_neg = syn1[negatives].astype(compute_dtype)       # [B, n, D]
+    with jax.named_scope("sgns.gather"):
+        if hot_slabs is not None:
+            slab0, slab1 = hot_slabs
+            e_in = hot_gather(syn0, slab0, centers, compute_dtype)    # [B, D]
+            e_pos = hot_gather(syn1, slab1, contexts, compute_dtype)  # [B, D]
+            e_neg = hot_gather(syn1, slab1, negatives, compute_dtype)  # [B, n, D]
+        else:
+            e_in = syn0[centers].astype(compute_dtype)          # [B, D]
+            e_pos = syn1[contexts].astype(compute_dtype)        # [B, D]
+            e_neg = syn1[negatives].astype(compute_dtype)       # [B, n, D]
 
     if bf16_chain:
         pf = jnp.promote_types(compute_dtype, jnp.float32)
@@ -416,15 +417,19 @@ def sgns_step_core(
     dtype = syn0.dtype
     D = syn1.shape[1]
     if hot_slabs is not None:
-        new_syn0, slab0 = hot_scatter_add(syn0, slab0, centers, d_in)
-        new_syn1, slab1 = hot_scatter_add(syn1, slab1, contexts, d_pos)
-        new_syn1, slab1 = hot_scatter_add(
-            new_syn1, slab1, negatives.reshape(-1), d_neg.reshape(-1, D))
+        with jax.named_scope("sgns.scatter_syn0"):
+            new_syn0, slab0 = hot_scatter_add(syn0, slab0, centers, d_in)
+        with jax.named_scope("sgns.scatter_syn1"):
+            new_syn1, slab1 = hot_scatter_add(syn1, slab1, contexts, d_pos)
+            new_syn1, slab1 = hot_scatter_add(
+                new_syn1, slab1, negatives.reshape(-1), d_neg.reshape(-1, D))
     else:
-        new_syn0 = syn0.at[centers].add(d_in.astype(dtype))
-        new_syn1 = syn1.at[contexts].add(d_pos.astype(dtype))
-        new_syn1 = new_syn1.at[negatives.reshape(-1)].add(
-            d_neg.reshape(-1, D).astype(dtype))
+        with jax.named_scope("sgns.scatter_syn0"):
+            new_syn0 = syn0.at[centers].add(d_in.astype(dtype))
+        with jax.named_scope("sgns.scatter_syn1"):
+            new_syn1 = syn1.at[contexts].add(d_pos.astype(dtype))
+            new_syn1 = new_syn1.at[negatives.reshape(-1)].add(
+                d_neg.reshape(-1, D).astype(dtype))
     if stabilizers is not None and stabilizers.post_pass:
         enable = (mask.sum() > 0).astype(jnp.float32)
         new_syn0 = stabilize_rows(
@@ -653,20 +658,24 @@ def sgns_step_shared_core(
     if hot_slabs is not None and stabilizers is not None:
         raise ValueError("stabilizers have no hot-row form (refused at "
                          "config construction)")
-    if hot_slabs is not None:
-        slab0, slab1 = hot_slabs
-        e_in = hot_gather(syn0, slab0, centers, compute_dtype)    # [B, D]
-        e_pos = hot_gather(syn1, slab1, contexts, compute_dtype)  # [B, D]
-        Z = hot_gather(syn1, slab1, negatives, compute_dtype)     # [P, D]
-    else:
-        e_in = syn0[centers].astype(compute_dtype)          # [B, D]
-        e_pos = syn1[contexts].astype(compute_dtype)        # [B, D]
-        Z = syn1[negatives].astype(compute_dtype)           # [P, D]
+    # named scopes are metadata for a profile's reader (docs/observability.md
+    # §4); the compiled step is the same program without them (tested)
+    with jax.named_scope("sgns.gather"):
+        if hot_slabs is not None:
+            slab0, slab1 = hot_slabs
+            e_in = hot_gather(syn0, slab0, centers, compute_dtype)    # [B, D]
+            e_pos = hot_gather(syn1, slab1, contexts, compute_dtype)  # [B, D]
+            Z = hot_gather(syn1, slab1, negatives, compute_dtype)     # [P, D]
+        else:
+            e_in = syn0[centers].astype(compute_dtype)          # [B, D]
+            e_pos = syn1[contexts].astype(compute_dtype)        # [B, D]
+            Z = syn1[negatives].astype(compute_dtype)           # [P, D]
 
-    f_pos, f_neg, neg_valid, g_pos, g_neg = shared_pool_coeffs(
-        e_in, e_pos, Z, contexts, negatives, mask, alpha,
-        num_negatives, sigmoid_mode, logits_dtype,
-        fused=fused, bf16_chain=bf16_chain)
+    with jax.named_scope("sgns.pool_matmul"):
+        f_pos, f_neg, neg_valid, g_pos, g_neg = shared_pool_coeffs(
+            e_in, e_pos, Z, contexts, negatives, mask, alpha,
+            num_negatives, sigmoid_mode, logits_dtype,
+            fused=fused, bf16_chain=bf16_chain)
 
     if duplicate_scaling:
         cnt0 = jnp.zeros(V, jnp.float32).at[centers].add(mask)
@@ -686,12 +695,13 @@ def sgns_step_shared_core(
         g_pos_in, g_neg_in, g_pos_out = g_pos, g_neg, g_pos
         z_scale = None
 
-    gp_in = g_pos_in[:, None].astype(compute_dtype)
-    gn_in = g_neg_in.astype(compute_dtype)
-    gn = g_neg.astype(compute_dtype)
-    d_in = gp_in * e_pos + gn_in @ Z                     # [B, D] — MXU
-    d_pos = g_pos_out[:, None].astype(compute_dtype) * e_in
-    d_Z = gn.T @ e_in                                    # [P, D] — MXU
+    with jax.named_scope("sgns.pool_matmul"):
+        gp_in = g_pos_in[:, None].astype(compute_dtype)
+        gn_in = g_neg_in.astype(compute_dtype)
+        gn = g_neg.astype(compute_dtype)
+        d_in = gp_in * e_pos + gn_in @ Z                     # [B, D] — MXU
+        d_pos = g_pos_out[:, None].astype(compute_dtype) * e_in
+        d_Z = gn.T @ e_in                                    # [P, D] — MXU
     if z_scale is not None:
         d_Z = d_Z * z_scale[:, None].astype(compute_dtype)
     if stabilizers is not None and stabilizers.update_clip:
@@ -700,13 +710,17 @@ def sgns_step_shared_core(
 
     dtype = syn0.dtype
     if hot_slabs is not None:
-        new_syn0, slab0 = hot_scatter_add(syn0, slab0, centers, d_in)
-        new_syn1, slab1 = hot_scatter_add(syn1, slab1, contexts, d_pos)
-        new_syn1, slab1 = hot_scatter_add(new_syn1, slab1, negatives, d_Z)
+        with jax.named_scope("sgns.scatter_syn0"):
+            new_syn0, slab0 = hot_scatter_add(syn0, slab0, centers, d_in)
+        with jax.named_scope("sgns.scatter_syn1"):
+            new_syn1, slab1 = hot_scatter_add(syn1, slab1, contexts, d_pos)
+            new_syn1, slab1 = hot_scatter_add(new_syn1, slab1, negatives, d_Z)
     else:
-        new_syn0 = syn0.at[centers].add(d_in.astype(dtype))
-        new_syn1 = syn1.at[contexts].add(d_pos.astype(dtype))
-        new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
+        with jax.named_scope("sgns.scatter_syn0"):
+            new_syn0 = syn0.at[centers].add(d_in.astype(dtype))
+        with jax.named_scope("sgns.scatter_syn1"):
+            new_syn1 = syn1.at[contexts].add(d_pos.astype(dtype))
+            new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
     if stabilizers is not None and stabilizers.post_pass:
         enable = (mask.sum() > 0).astype(jnp.float32)
         new_syn0 = stabilize_rows(

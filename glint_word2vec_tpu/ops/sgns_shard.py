@@ -175,11 +175,12 @@ def make_shard_map_sgns_step(
         row_offset = (jax.lax.axis_index(MODEL_AXIS) * vs).astype(jnp.int32)
 
         # (1) forward assembly: owner-local gathers, ONE psum over `model`
-        cat = jnp.concatenate([
-            _owned_rows(syn0, centers, row_offset),
-            _owned_rows(syn1, contexts, row_offset),
-            _owned_rows(syn1, negatives, row_offset),
-        ], axis=0)                                   # [2·Bl + P, D] param dtype
+        with jax.named_scope("sgns.gather"):
+            cat = jnp.concatenate([
+                _owned_rows(syn0, centers, row_offset),
+                _owned_rows(syn1, contexts, row_offset),
+                _owned_rows(syn1, negatives, row_offset),
+            ], axis=0)                               # [2·Bl + P, D] param dtype
         if nm > 1:
             cat = jax.lax.psum(cat, MODEL_AXIS)
         e_in = cat[:bl].astype(compute_dtype)
@@ -188,14 +189,15 @@ def make_shard_map_sgns_step(
 
         # (2) the shared coefficient/update math — literally the same helpers
         # the GSPMD step runs (ops/sgns.py), per data shard
-        f_pos, f_neg, neg_valid, g_pos, g_neg = shared_pool_coeffs(
-            e_in, e_pos, Z, contexts, negatives, mask, alpha,
-            num_negatives, sigmoid_mode, logits_dtype,
-            fused=fused, bf16_chain=bf16_chain)
-        gn = g_neg.astype(compute_dtype)
-        d_in = g_pos[:, None].astype(compute_dtype) * e_pos + gn @ Z
-        d_pos = g_pos[:, None].astype(compute_dtype) * e_in
-        d_Z = gn.T @ e_in                            # [P, D] partial over Bl pairs
+        with jax.named_scope("sgns.pool_matmul"):
+            f_pos, f_neg, neg_valid, g_pos, g_neg = shared_pool_coeffs(
+                e_in, e_pos, Z, contexts, negatives, mask, alpha,
+                num_negatives, sigmoid_mode, logits_dtype,
+                fused=fused, bf16_chain=bf16_chain)
+            gn = g_neg.astype(compute_dtype)
+            d_in = g_pos[:, None].astype(compute_dtype) * e_pos + gn @ Z
+            d_pos = g_pos[:, None].astype(compute_dtype) * e_in
+            d_Z = gn.T @ e_in                        # [P, D] partial over Bl pairs
         if stabilizers is not None and stabilizers.update_clip:
             # the per-pair rows only, never the (shard-partial) d_Z — the
             # exact scoping the single-program lowering applies (ops/sgns.py
@@ -224,8 +226,10 @@ def make_shard_map_sgns_step(
         idx1 = seg_idx[:, bl:].reshape(-1)
 
         # (4) owner-local scatters — ZERO update bytes cross the model axis
-        new_syn0 = _owner_local_scatter_add(syn0, idx0, upd0, row_offset)
-        new_syn1 = _owner_local_scatter_add(syn1, idx1, upd1, row_offset)
+        with jax.named_scope("sgns.scatter_syn0"):
+            new_syn0 = _owner_local_scatter_add(syn0, idx0, upd0, row_offset)
+        with jax.named_scope("sgns.scatter_syn1"):
+            new_syn1 = _owner_local_scatter_add(syn1, idx1, upd1, row_offset)
 
         # (4b) owner-local touched-row stabilizer pass (config.max_row_norm /
         # row_l2): the rows layout owns FULL rows per shard, so the clamp's
@@ -327,25 +331,27 @@ def make_shard_map_sgns_step(
         bl = centers.shape[0]
         pool = negatives.shape[0]
 
-        cat = jnp.concatenate([
-            _owned_rows(syn0, centers, row_offset),
-            _owned_rows(syn1, contexts, row_offset),
-            _owned_rows(syn1, negatives, row_offset),
-        ], axis=0)                                   # [2·Bl + P, D] param dtype
+        with jax.named_scope("sgns.gather"):
+            cat = jnp.concatenate([
+                _owned_rows(syn0, centers, row_offset),
+                _owned_rows(syn1, contexts, row_offset),
+                _owned_rows(syn1, negatives, row_offset),
+            ], axis=0)                               # [2·Bl + P, D] param dtype
         if nm > 1:
             cat = jax.lax.psum(cat, MODEL_AXIS)
         e_in = cat[:bl].astype(compute_dtype)
         e_pos = cat[bl:2 * bl].astype(compute_dtype)
         Z = cat[2 * bl:].astype(compute_dtype)
 
-        f_pos, f_neg, neg_valid, g_pos, g_neg = shared_pool_coeffs(
-            e_in, e_pos, Z, contexts, negatives, mask, alpha,
-            num_negatives, sigmoid_mode, logits_dtype,
-            fused=fused, bf16_chain=bf16_chain)
-        gn = g_neg.astype(compute_dtype)
-        d_in = g_pos[:, None].astype(compute_dtype) * e_pos + gn @ Z
-        d_pos = g_pos[:, None].astype(compute_dtype) * e_in
-        d_Z = gn.T @ e_in
+        with jax.named_scope("sgns.pool_matmul"):
+            f_pos, f_neg, neg_valid, g_pos, g_neg = shared_pool_coeffs(
+                e_in, e_pos, Z, contexts, negatives, mask, alpha,
+                num_negatives, sigmoid_mode, logits_dtype,
+                fused=fused, bf16_chain=bf16_chain)
+            gn = g_neg.astype(compute_dtype)
+            d_in = g_pos[:, None].astype(compute_dtype) * e_pos + gn @ Z
+            d_pos = g_pos[:, None].astype(compute_dtype) * e_in
+            d_Z = gn.T @ e_in
         if stabilizers is not None and stabilizers.update_clip:
             d_in = clip_update_rows(d_in, stabilizers.update_clip)
             d_pos = clip_update_rows(d_pos, stabilizers.update_clip)
@@ -355,8 +361,10 @@ def make_shard_map_sgns_step(
         upd0 = d_in.astype(dtype)
         idx1 = jnp.concatenate([contexts, negatives])
         upd1 = jnp.concatenate([d_pos, d_Z], axis=0).astype(dtype)
-        new_syn0 = _owner_local_scatter_add(syn0, idx0, upd0, row_offset)
-        new_syn1 = _owner_local_scatter_add(syn1, idx1, upd1, row_offset)
+        with jax.named_scope("sgns.scatter_syn0"):
+            new_syn0 = _owner_local_scatter_add(syn0, idx0, upd0, row_offset)
+        with jax.named_scope("sgns.scatter_syn1"):
+            new_syn1 = _owner_local_scatter_add(syn1, idx1, upd1, row_offset)
 
         if stabilizers is not None and stabilizers.post_pass:
             # owner-local in-window form: the LOCAL touched mask gates the

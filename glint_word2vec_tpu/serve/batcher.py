@@ -36,6 +36,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 from glint_word2vec_tpu.lockcheck import make_condition
+from glint_word2vec_tpu.obs.spans import default_tracer
 
 logger = logging.getLogger("glint_word2vec_tpu")
 
@@ -72,12 +73,18 @@ class _Ticket:
     """One in-flight request: payload in, result/error out, an event the
     submitting thread parks on. ``trace`` is the cross-process trace
     context (``{"tid": ..., "ps": ...}``, obs/trace.py) when the request is
-    being traced, else None — the default path allocates nothing extra."""
+    being traced, else None — the default path allocates nothing extra.
+    ``enqueued`` is on the span recorder's clock (obs/spans.py ``now``);
+    ``seq`` is the admission sequence number its ``serve.queue_wait`` span
+    carries as ``request``."""
 
-    __slots__ = ("payload", "enqueued", "done", "result", "error", "trace")
+    __slots__ = ("payload", "enqueued", "done", "result", "error", "trace",
+                 "seq")
 
-    def __init__(self, payload: Any, trace: Optional[dict] = None):
+    def __init__(self, payload: Any, trace: Optional[dict] = None,
+                 seq: int = 0):
         self.payload = payload
+        self.seq = seq
         self.enqueued = time.monotonic()
         self.done = threading.Event()
         self.result: Any = None
@@ -113,7 +120,8 @@ class BatchingScheduler:
         a ``queue_wait`` span (submit → batch pop: the admission latency the
         micro-batching deadline trades) and a ``batch_service`` span (the
         handler's wall time), both parented to the context the request
-        carried across the wire. Untraced tickets (trace=None — every
+        carried across the wire, both from the ``serve.batch`` span's own
+        clock reads (obs/spans.py). Untraced tickets (trace=None — every
         ticket when tracing is off) never reach the hook: the zero-cost
         contract is "no trace, no call", not a no-op callee.
 
@@ -137,6 +145,7 @@ class BatchingScheduler:
         self._straggle_s = float(straggle_ms) / 1000.0
         self._span_emit = span_emit
         self._batch_observer = batch_observer
+        self._tracer = default_tracer()
         self._name = name
         self._q: collections.deque = collections.deque()
         self._cv = make_condition("serve.batcher.cv")
@@ -206,9 +215,9 @@ class BatchingScheduler:
                 raise ServerOverloaded(
                     f"admission queue full ({self.max_queue} waiting)",
                     retry_after_s=self._retry_after_locked())
-            t = _Ticket(payload, trace)
-            self._q.append(t)
             self._submitted += 1
+            t = _Ticket(payload, trace, self._submitted)
+            self._q.append(t)
             self._cv.notify_all()
         return t
 
@@ -244,24 +253,27 @@ class BatchingScheduler:
     # -- worker side -------------------------------------------------------------------
 
     def _collect(self) -> Optional[List[_Ticket]]:
-        """Pop one batch: block for the first request, then coalesce until
-        ``max_batch`` or ``max_delay_ms`` past the first arrival. None =
-        stopped and drained."""
+        """Pop one batch: block for the first request (span ``serve.idle``),
+        then coalesce until ``max_batch`` or ``max_delay_ms`` past the first
+        arrival (span ``serve.coalesce``). None = stopped and drained."""
         with self._cv:
-            while not self._q and not self._stopping:
-                self._cv.wait()
+            if not self._q and not self._stopping:
+                with self._tracer.span("serve.idle"):
+                    while not self._q and not self._stopping:
+                        self._cv.wait()
             if not self._q:
                 return None  # stopping, queue drained
-            batch = [self._q.popleft()]
-            deadline = batch[0].enqueued + self.max_delay_s
-            while len(batch) < self.max_batch:
-                if self._q:
-                    batch.append(self._q.popleft())
-                    continue
-                remaining = deadline - time.monotonic()
-                if remaining <= 0 or self._stopping:
-                    break
-                self._cv.wait(remaining)
+            with self._tracer.span("serve.coalesce"):
+                batch = [self._q.popleft()]
+                deadline = batch[0].enqueued + self.max_delay_s
+                while len(batch) < self.max_batch:
+                    if self._q:
+                        batch.append(self._q.popleft())
+                        continue
+                    remaining = deadline - time.monotonic()
+                    if remaining <= 0 or self._stopping:
+                        break
+                    self._cv.wait(remaining)
             return batch
 
     def _run(self) -> None:
@@ -269,64 +281,76 @@ class BatchingScheduler:
             batch = self._collect()
             if batch is None:
                 return
-            pop = time.monotonic()
-            if self._straggle_every:
-                with self._cv:
-                    nth = self._batches + 1
-                if nth % self._straggle_every == 0:
-                    time.sleep(self._straggle_s)  # injected straggler
-            t0 = time.monotonic()
-            try:
-                results = self._handler([t.payload for t in batch])
-                if len(results) != len(batch):
-                    raise RuntimeError(
-                        f"handler returned {len(results)} results for a "
-                        f"batch of {len(batch)}")
-            except Exception as e:  # noqa: BLE001 — delivered to each caller
-                with self._cv:
-                    self._note_batch_seconds(time.monotonic() - t0)
-                    self._batches += 1
-                    self._batched_items += len(batch)
-                    self._errors += len(batch)
-                for t in batch:
-                    t.error = e
-                    t.done.set()
-                self._after_batch(batch, pop, time.monotonic())
-                continue
-            n_err = 0
+            # serve.batch: batch closed → last caller released. The one pair
+            # of clock reads per batch: the service-time estimate, the batch
+            # observer and the fleet's trace_span records are fed its times.
+            with self._tracer.span("serve.batch", timed=True,
+                                   size=len(batch)) as sp:
+                self._serve(batch, sp.t0)
+            self._after_batch(batch, sp)
+
+    def _serve(self, batch: List[_Ticket], t0: float) -> None:
+        """Run the handler over one batch and release its callers. ``t0`` is
+        the batch's start, from which the handler's wall time is taken."""
+        if self._straggle_every:
+            with self._cv:
+                nth = self._batches + 1
+            if nth % self._straggle_every == 0:
+                time.sleep(self._straggle_s)  # injected straggler
+                t0 = time.monotonic()
+        n_err = 0
+        try:
+            results = self._handler([t.payload for t in batch])
+            if len(results) != len(batch):
+                raise RuntimeError(
+                    f"handler returned {len(results)} results for a "
+                    f"batch of {len(batch)}")
+        except Exception as e:  # noqa: BLE001 — delivered to each caller
+            n_err = len(batch)
+            for t in batch:
+                t.error = e
+        else:
             for t, r in zip(batch, results):
                 if isinstance(r, BaseException):
                     t.error = r
                     n_err += 1
                 else:
                     t.result = r
-            with self._cv:
-                self._note_batch_seconds(time.monotonic() - t0)
-                self._batches += 1
-                self._batched_items += len(batch)
-                self._errors += n_err
-                self._completed += len(batch) - n_err
-            for t in batch:
-                t.done.set()
-            self._after_batch(batch, pop, time.monotonic())
+        with self._cv:
+            self._note_batch_seconds(time.monotonic() - t0)
+            self._batches += 1
+            self._batched_items += len(batch)
+            self._errors += n_err
+            self._completed += len(batch) - n_err
+        for t in batch:
+            t.done.set()
 
-    def _after_batch(self, batch: List[_Ticket], pop_s: float,
-                     done_s: float) -> None:
+    def _after_batch(self, batch: List[_Ticket], sp) -> None:
         """Post-batch observability (worker thread, AFTER the callers were
-        released — a slow sink must not sit inside any caller's latency):
-        the per-batch dispatch observer, then queue_wait/batch_service
-        spans for each TRACED ticket. Best-effort like every obs surface —
+        released — a slow sink must not sit inside any caller's latency),
+        all from the ``serve.batch`` span ``sp``'s own times: a
+        ``serve.queue_wait`` span per ticket (enqueued → its batch closed,
+        child of ``sp``) where the recorder kept ``sp``, the per-batch
+        dispatch observer, then queue_wait/batch_service ``trace_span``
+        records for each TRACED ticket. Best-effort like every obs surface —
         a hook failure must never kill the worker."""
-        if self._batch_observer is None and self._span_emit is None:
+        if not (sp.recorded or self._batch_observer is not None
+                or self._span_emit is not None):
             return
         try:
+            if sp.recorded:
+                for t in batch:
+                    self._tracer.record(
+                        "serve.queue_wait", t.enqueued,
+                        max(0.0, sp.t0 - t.enqueued), parent=sp.id,
+                        request=t.seq,
+                        **({"tid": t.trace["tid"]} if t.trace else {}))
             if self._batch_observer is not None:
                 self._batch_observer(
-                    len(batch), done_s - pop_s,
-                    max(0.0, pop_s - batch[0].enqueued))
+                    len(batch), sp.dur, max(0.0, sp.t0 - batch[0].enqueued))
             if self._span_emit is not None:
-                pop_ns = int(pop_s * 1e9)
-                dur_ns = int((done_s - pop_s) * 1e9)
+                pop_ns = int(sp.t0 * 1e9)
+                dur_ns = int(sp.dur * 1e9)
                 for t in batch:
                     if t.trace is None:
                         continue

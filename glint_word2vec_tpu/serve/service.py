@@ -32,6 +32,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from glint_word2vec_tpu.obs.spans import default_tracer
 from glint_word2vec_tpu.serve.ann import build_ivf
 from glint_word2vec_tpu.serve.batcher import BatchingScheduler
 from glint_word2vec_tpu.serve.reload import (
@@ -148,6 +149,7 @@ class EmbeddingService:
         self._leaked_threads = 0
         self._blackbox = None
         self._span_emitter = None
+        self._tracer = default_tracer()
         self._dispatch_count = 0
         t0 = time.perf_counter()
         # signature BEFORE the load: a publish landing during the slow
@@ -422,12 +424,36 @@ class EmbeddingService:
         is answered by the same model generation, and a swap landing
         mid-batch waits for the lease to drain before the old buffers go.
 
+        The whole of it is the span ``serve.dispatch`` (obs/spans.py; child
+        of the batcher's ``serve.batch``, parent of ``find_synonyms_batch``'s
+        row_fetch / scan_enqueue / result_fetch / reply_build): its self time
+        is the lease, the payload sort and the result slicing.
+
         A ``syn`` payload may carry a 4th element — the cross-process trace
-        context (obs/trace.py) — in which case the scan's wall time is
+        context (obs/trace.py) — in which case the dispatch's wall time is
         emitted as an ``ann_probe``/``exact_scan`` child span for each
         traced request (siblings of the batcher's batch_service span under
-        the same wire parent; the duration is the BATCH's scan — per-query
-        attribution below one device dispatch does not exist by design)."""
+        the same wire parent; the duration is the BATCH's — per-query
+        attribution below one device dispatch does not exist by design),
+        from the ``serve.dispatch`` span's own clock reads."""
+        traced = (self._span_emitter is not None
+                  and any(len(p) > 3 for p in payloads))
+        with self._tracer.span("serve.dispatch", timed=traced,
+                               size=len(payloads)) as sp:
+            results, scanned, use_ann = self._dispatch_leased(payloads)
+        if traced:
+            name = "ann_probe" if use_ann else "exact_scan"
+            t0_ns, dur_ns = int(sp.t0 * 1e9), int(sp.dur * 1e9)
+            for tr in scanned:
+                if tr is not None:
+                    self._span_emitter.emit(tr["tid"], name, t0_ns, dur_ns,
+                                            parent=tr.get("ps"))
+        return results
+
+    def _dispatch_leased(self, payloads: List[Tuple]
+                         ) -> Tuple[List[Any], List[Optional[dict]], bool]:
+        """The batch's results, the wire trace context (or None) of each
+        query its scan answered, and whether the scan took the ANN arm."""
         with self._handle.lease() as (model, index):
             results: List[Any] = [None] * len(payloads)
             syn_pos: List[int] = []
@@ -454,12 +480,9 @@ class EmbeddingService:
                         results[i] = e
                 else:
                     results[i] = ValueError(f"unknown op {op!r}")
+            use_ann = self._ann_enabled and index is not None
             if syn_pos:
                 kmax = max(syn_num)
-                use_ann = self._ann_enabled and index is not None
-                traced = (self._span_emitter is not None
-                          and any(t is not None for t in syn_trace))
-                t0_ns = time.monotonic_ns() if traced else 0
                 try:
                     rows = model.find_synonyms_batch(
                         syn_q, kmax, ann=use_ann, nprobe=self._nprobe)
@@ -469,15 +492,7 @@ class EmbeddingService:
                 else:
                     for i, res, num in zip(syn_pos, rows, syn_num):
                         results[i] = res[:num]
-                if traced:
-                    dur_ns = time.monotonic_ns() - t0_ns
-                    name = "ann_probe" if use_ann else "exact_scan"
-                    for tr in syn_trace:
-                        if tr is not None:
-                            self._span_emitter.emit(
-                                tr["tid"], name, t0_ns, dur_ns,
-                                parent=tr.get("ps"))
-            return results
+            return results, syn_trace, use_ann
 
     # -- client surface ----------------------------------------------------------------
 

@@ -558,15 +558,17 @@ class Trainer:
         self._statusd = None                 # obs/statusd.py, fit-scoped
         self._prev_sigterm = None            # saved handler while fit runs
         self._sigterm_installed = False      # see _install_run_signals
-        # arm (or DISARM) the process-wide tracer for this trainer — at
-        # construction, not only at fit start: the fit paths build their feed
-        # iterators before _start_run_bookkeeping runs, and the producer
-        # spans must observe the right state from the start. Disarming
-        # matters as much as arming: a telemetry-off trainer after a
-        # telemetry-on one in the same process (the overhead A/B's off arm)
-        # must not keep recording spans into the shared ring. The phase
-        # accumulator attaches under the same rule (spans tee durations into
-        # it — obs/spans.py _PHASE_OF).
+        # arm (or DISARM) the process-wide tracer's TELEMETRY recording for
+        # this trainer — at construction, not only at fit start: the fit
+        # paths build their feed iterators before _start_run_bookkeeping
+        # runs, and the producer spans must observe the right state from the
+        # start. Disarming matters as much as arming: a telemetry-off trainer
+        # after a telemetry-on one in the same process (the overhead A/B's
+        # off arm) must not keep recording spans into the shared ring. The
+        # phase accumulator attaches under the same rule (spans tee durations
+        # into it — obs/spans.py _PHASE_OF). A live jax.profiler trace arms
+        # the spans whatever this says (obs/spans.py): nothing here can
+        # switch that off.
         self._tracer.configure(enabled=observing)
         self._tracer.attach_phases(self._phases if observing else None)
         self.norm_watchdog = NormWatchdog(
@@ -1118,9 +1120,10 @@ class Trainer:
 
                 def build_batch(xs, nv):
                     ob = jax.lax.bitcast_convert_type(xs["obase"], jnp.uint32)
-                    dp = gen(xs["tokens"].astype(jnp.int32), xs["starts"],
-                             nv.astype(jnp.int32), ob[:, 0], ob[:, 1],
-                             keep_prob, sub_bases, win_bases)
+                    with jax.named_scope("pairgen"):
+                        dp = gen(xs["tokens"].astype(jnp.int32), xs["starts"],
+                                 nv.astype(jnp.int32), ob[:, 0], ob[:, 1],
+                                 keep_prob, sub_bases, win_bases)
                     return {"centers": dp.centers.reshape(-1),
                             "contexts": dp.contexts.reshape(-1),
                             "mask": dp.mask.reshape(-1)}, dp.dropped_pairs.sum()
@@ -1312,8 +1315,9 @@ class Trainer:
                 xs, alpha, nv, negs = inp
                 ob = jax.lax.bitcast_convert_type(xs["obase"], jnp.uint32)
                 tok = xs["tokens"].astype(jnp.int32)
-                band = win(tok, xs["starts"], nv.astype(jnp.int32),
-                           ob[:, 0], ob[:, 1], win_bases)
+                with jax.named_scope("pairgen"):
+                    band = win(tok, xs["starts"], nv.astype(jnp.int32),
+                               ob[:, 0], ob[:, 1], win_bases)
                 new_p, metrics = cbow_step_banded_core(
                     p, tok.reshape(-1),
                     band.left.reshape(-1), band.right.reshape(-1),
@@ -1384,17 +1388,25 @@ class Trainer:
         covers the host feed, both device feeds, and the sharded paths
         without touching any producer. Identical on every process — the
         scale only changes on probe rounds, which are allgather-consistent."""
-        meta = np.asarray(meta, np.float32)
-        if self._lr_scale != 1.0:
-            meta = meta.copy()  # never mutate the producer's array in place
-            meta[0] *= np.float32(self._lr_scale)
-        host = {"meta": meta,
-                "base": np.int32(base_step)}
-        for i, b in enumerate(bases):
-            host[f"b{i}"] = b
-        placed = put_global(self.plan.replicated, host)
-        return (placed["meta"], placed["base"],
-                *[placed[f"b{i}"] for i in range(len(bases))])
+        with self._tracer.span("dispatch.meta"):
+            meta = np.asarray(meta, np.float32)
+            if self._lr_scale != 1.0:
+                meta = meta.copy()  # never mutate the producer's array in place
+                meta[0] *= np.float32(self._lr_scale)
+            host = {"meta": meta,
+                    "base": np.int32(base_step)}
+            for i, b in enumerate(bases):
+                host[f"b{i}"] = b
+            placed = put_global(self.plan.replicated, host)
+            return (placed["meta"], placed["base"],
+                    *[placed[f"b{i}"] for i in range(len(bases))])
+
+    def _put_chunk(self, arrays):
+        """Place one unstaged chunk's arrays for the step (span
+        ``dispatch.put``); a staged feed placed them a round ahead under
+        ``stage_put``."""
+        with self._tracer.span("dispatch.put"):
+            return put_global(self._chunk_shardings, arrays)
 
     def _after_dispatch(self) -> None:
         """Collective-program serialization gate (see __init__): on the
@@ -1602,16 +1614,16 @@ class Trainer:
                     # the replicated feed is the path where divergence CAN
                     # happen: every process regenerated the stream itself
                     self._assert_feed_consistent(chunk["arrays"], chunk["meta"])
-                with self._tracer.span("dispatch"):
+                real = chunk["real"]
+                with self._tracer.span("dispatch", steps=real):
                     stacked = (chunk["arrays"] if staged else
-                               put_global(self._chunk_shardings,
-                                          chunk["arrays"]))
-                    real = chunk["real"]
+                               self._put_chunk(chunk["arrays"]))
                     meta_dev, base_dev = self._stage_dispatch_meta(
                         chunk["meta"], self.global_step + 1)
-                    self.params, metrics = self._dispatch_step_fn(real)(
-                        self.params, stacked, meta_dev, base_dev,
-                        self._table_prob, self._table_alias)
+                    with self._tracer.span("dispatch.enqueue"):
+                        self.params, metrics = self._dispatch_step_fn(real)(
+                            self.params, stacked, meta_dev, base_dev,
+                            self._table_prob, self._table_alias)
                 self.dispatch_time += time.perf_counter() - t0
                 self._after_dispatch()
                 self._finish_round(
@@ -2025,20 +2037,20 @@ class Trainer:
                 if chunk is None:
                     break
                 t0 = time.perf_counter()
-                with self._tracer.span("dispatch"):
+                real = chunk["real"]
+                with self._tracer.span("dispatch", steps=real):
                     stacked = (chunk["arrays"] if staged else
-                               put_global(self._chunk_shardings,
-                                          chunk["arrays"]))
-                    real = chunk["real"]
+                               self._put_chunk(chunk["arrays"]))
                     meta_dev, base_dev, sub_dev, win_dev = \
                         self._stage_dispatch_meta(
                             chunk["meta"], self.global_step + 1,
                             chunk["sub_bases"], chunk["win_bases"])
-                    self.params, (metrics, dropped) = \
-                        self._dispatch_step_fn(real)(
-                            self.params, stacked, meta_dev, base_dev,
-                            self._table_prob, self._table_alias,
-                            self._keep_prob_dev, sub_dev, win_dev)
+                    with self._tracer.span("dispatch.enqueue"):
+                        self.params, (metrics, dropped) = \
+                            self._dispatch_step_fn(real)(
+                                self.params, stacked, meta_dev, base_dev,
+                                self._table_prob, self._table_alias,
+                                self._keep_prob_dev, sub_dev, win_dev)
                 self.dispatch_time += time.perf_counter() - t0
                 self._after_dispatch()
                 pairs_arrays.append(metrics.pairs)
@@ -2474,16 +2486,17 @@ class Trainer:
                 if rnd is None:
                     break
                 t0 = time.perf_counter()
-                with self._tracer.span("dispatch"):
+                with self._tracer.span("dispatch", steps=rnd["real"]):
                     meta_dev, base_dev, sub_dev, win_dev = \
                         self._stage_dispatch_meta(
                             rnd["meta"], self.global_step + 1,
                             rnd["sub_bases"], rnd["win_bases"])
-                    self.params, (metrics, dropped) = \
-                        self._dispatch_step_fn(rnd["real"])(
-                            self.params, rnd["stacked"], meta_dev, base_dev,
-                            self._table_prob, self._table_alias,
-                            self._keep_prob_dev, sub_dev, win_dev)
+                    with self._tracer.span("dispatch.enqueue"):
+                        self.params, (metrics, dropped) = \
+                            self._dispatch_step_fn(rnd["real"])(
+                                self.params, rnd["stacked"], meta_dev,
+                                base_dev, self._table_prob, self._table_alias,
+                                self._keep_prob_dev, sub_dev, win_dev)
                 self.dispatch_time += time.perf_counter() - t0
                 self._after_dispatch()
                 pairs_arrays.append(metrics.pairs)
@@ -3300,7 +3313,10 @@ class Trainer:
                     **({"norms": channels} if channels is not None else {}),
                     **({"phases": phases_window} if phases_window else {}))
             if on_heartbeat is not None:
-                on_heartbeat(rec)
+                # the caller's time, not the program's: kept apart from the
+                # fit's own spans in a profile
+                with self._tracer.span("heartbeat.callback"):
+                    on_heartbeat(rec)
             self._last_log_time, self._last_log_step = now, self.global_step
 
         if ckpt_due:
@@ -3623,13 +3639,14 @@ class Trainer:
 
                 if cfg.feed_consistency_check:
                     self._assert_feed_consistent(feed, meta)
-                with self._tracer.span("dispatch"):
-                    stacked = put_global(self._chunk_shardings, feed)
+                with self._tracer.span("dispatch", steps=real):
+                    stacked = self._put_chunk(feed)
                     meta_dev, base_dev = self._stage_dispatch_meta(
                         meta, self.global_step + 1)
-                    self.params, metrics = self._dispatch_step_fn(real)(
-                        self.params, stacked, meta_dev, base_dev,
-                        self._table_prob, self._table_alias)
+                    with self._tracer.span("dispatch.enqueue"):
+                        self.params, metrics = self._dispatch_step_fn(real)(
+                            self.params, stacked, meta_dev, base_dev,
+                            self._table_prob, self._table_alias)
                 self.dispatch_time += time.perf_counter() - t0
                 self._after_dispatch()
                 self._finish_round(

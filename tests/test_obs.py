@@ -11,6 +11,7 @@ import os
 import subprocess
 import sys
 import threading
+import time
 
 import jax
 import numpy as np
@@ -445,3 +446,241 @@ def test_telemetry_run_smoke(tmp_path):
     assert res["missing_spans"] == []
     assert os.path.exists(res["run_log"])
     assert os.path.exists(res["trace"])
+
+
+# -- the recorder follows the profiler (ISSUE 25) --------------------------------------
+
+
+@pytest.fixture
+def live_trace(tmp_path):
+    """A real jax.profiler trace on the CPU backend, telemetry off. Yields
+    ``stop()``, which ends the trace once and returns its host spans as
+    benchmark/harness/trace.py reads them."""
+    import sys
+    import jax.profiler as jp
+    from glint_word2vec_tpu.obs.spans import default_tracer
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import trace as htr
+    tracer = default_tracer()
+    tracer.configure(enabled=False)
+    tracer.clear()
+    state = {"live": True}
+    jp.start_trace(str(tmp_path))
+
+    def stop():
+        if state["live"]:
+            state["live"] = False
+            jp.stop_trace()
+        return htr.load(htr.newest_xplane(str(tmp_path)), "cpu")["host"]
+
+    try:
+        yield stop
+    finally:
+        if state["live"]:
+            jp.stop_trace()
+        tracer.clear()
+
+
+def test_trace_annotation_is_enabled_tracks_the_trace(tmp_path):
+    """The rule the recorder rests on: a JAX upgrade that moves it must fail
+    here and not blind the benchmark in silence."""
+    import jax.profiler as jp
+    assert not jp.TraceAnnotation.is_enabled()
+    jp.start_trace(str(tmp_path))
+    try:
+        assert jp.TraceAnnotation.is_enabled()
+    finally:
+        jp.stop_trace()
+    assert not jp.TraceAnnotation.is_enabled()
+
+
+def test_live_trace_arms_spans_in_ring_and_xplane(live_trace):
+    from glint_word2vec_tpu.obs.spans import default_tracer
+    tracer = default_tracer()
+    assert not tracer.enabled
+    with tracer.span("t25.outer", size=3):
+        with tracer.span("t25.inner") as inner:
+            inner.set(ops=7)
+    host = live_trace()
+    evs = {e["name"]: e for e in tracer.events()}
+    assert set(evs) == {"t25.outer", "t25.inner"}
+    assert evs["t25.outer"]["args"] == {"size": 3}
+    assert evs["t25.inner"]["args"] == {"ops": 7}
+    assert evs["t25.inner"]["parent"] == evs["t25.outer"]["id"]
+    assert evs["t25.outer"]["parent"] is None
+    # and in the profiler's own host plane, under their names
+    assert {n for n, _, _ in host} >= {"t25.outer", "t25.inner"}
+    # stop_trace disarmed it: nothing more is recorded
+    with tracer.span("t25.late"):
+        pass
+    assert len(tracer.events()) == 2
+
+
+def test_span_open_when_the_trace_stops_is_not_kept(live_trace):
+    from glint_word2vec_tpu.obs.spans import default_tracer
+    tracer = default_tracer()
+    with tracer.span("t25.cut") as sp:
+        with tracer.span("t25.whole"):
+            pass
+        live_trace()
+    assert [e["name"] for e in tracer.events()] == ["t25.whole"]
+    assert not sp.recorded and sp.dur > 0
+
+
+def test_fit_start_with_telemetry_off_keeps_following_the_profiler(live_trace):
+    """``Trainer._start_run_bookkeeping`` configures telemetry off; under a
+    live trace the fit's spans are recorded all the same, with the dispatch
+    span's children by parent id and the caller's callback apart."""
+    from glint_word2vec_tpu.obs.spans import default_tracer
+    trainer, sents = _toy_trainer()
+    trainer.fit(sents, on_heartbeat=lambda rec: None)
+    live_trace()
+    tracer = default_tracer()
+    assert not tracer.enabled
+    evs = tracer.events()
+    by_id = {e["id"]: e for e in evs}
+    names = {e["name"] for e in evs}
+    assert names >= {"producer", "dispatch", "dispatch.put", "dispatch.meta",
+                     "dispatch.enqueue", "device_block", "heartbeat.callback"}
+    for e in evs:
+        if e["name"].startswith("dispatch."):
+            assert by_id[e["parent"]]["name"] == "dispatch"
+    assert all(e["args"]["steps"] >= 1 for e in evs if e["name"] == "dispatch")
+
+
+def test_span_ids_parents_and_retroactive_record_across_threads():
+    tr = Tracer(enabled=True)
+    with tr.span("batch") as batch:
+        def other():
+            with tr.span("caused", parent=batch.id):
+                with tr.span("nested"):
+                    pass
+            with tr.span("orphan"):
+                pass
+        t = threading.Thread(target=other)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+    tr.record("waited", batch.t0 - 0.5, 0.5, parent=batch.id, request=11)
+    evs = {e["name"]: e for e in tr.events()}
+    ids = [e["id"] for e in evs.values()]
+    assert len(set(ids)) == len(ids) == 5
+    assert evs["caused"]["parent"] == evs["batch"]["id"]
+    assert evs["nested"]["parent"] == evs["caused"]["id"]
+    # the other thread's stack is its own: no parent leaks across threads
+    assert evs["orphan"]["parent"] is None
+    assert evs["waited"]["parent"] == evs["batch"]["id"]
+    assert evs["waited"]["args"] == {"request": 11}
+    assert abs(evs["waited"]["dur_s"] - 0.5) < 1e-9
+    assert (evs["waited"]["ts_s"] + 0.5
+            == pytest.approx(evs["batch"]["ts_s"], abs=1e-6))
+
+
+def test_inactive_span_builds_nothing(monkeypatch):
+    """Telemetry off and no live trace: the shared no-op, no annotation, no
+    record; ``timed=True`` still hands its caller the region's times."""
+    from glint_word2vec_tpu.obs import spans
+
+    class Counting:
+        built = 0
+        is_enabled = staticmethod(lambda: False)
+
+        def __init__(self, name):
+            Counting.built += 1
+
+    monkeypatch.setattr(spans, "TraceAnnotation", Counting)
+    tr = Tracer(enabled=False)
+    assert tr.span("x") is tr.span("y", size=1)
+    with tr.span("x") as sp:
+        sp.set(ops=1)
+    with tr.span("t", timed=True) as timed:
+        time.sleep(0.002)
+    assert timed.dur >= 0.002 and timed.t0 > 0 and not timed.recorded
+    assert tr.events() == [] and Counting.built == 0
+
+
+def test_wrap_iter_and_export_share_the_id_parent_path(tmp_path):
+    tr = Tracer(enabled=True)
+    with tr.span("consumer") as outer:
+        assert list(tr.wrap_iter("item", iter([1, 2]))) == [1, 2]
+    items = [e for e in tr.events() if e["name"] == "item"]
+    assert len(items) == 3          # two items and the StopIteration probe
+    assert all(e["parent"] == outer.id for e in items)
+    p = str(tmp_path / "trace.json")
+    tr.export_chrome_trace(p)
+    with open(p) as f:
+        xs = [e for e in json.load(f)["traceEvents"] if e["ph"] == "X"]
+    assert {e["args"]["parent"] for e in xs} == {None, outer.id}
+    assert len({e["args"]["id"] for e in xs}) == 4
+
+
+# -- device scopes are metadata only ---------------------------------------------------
+
+
+def _hlo_without_metadata(fn, args, monkeypatch, scoped: bool) -> str:
+    import contextlib
+    import re
+    import jax
+    if not scoped:
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+    jax.clear_caches()      # the inner jits' traces carry the scopes
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    monkeypatch.undo()
+    assert ("sgns.scatter_syn0" in text or "scan.topk" in text) == scoped
+    # metadata is each instruction's ``metadata={...}`` and the module's
+    # tables of the files, functions and stack frames those point into
+    text = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n"
+                  r"(?:.+\n)+", "\n", text)
+    return re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+
+
+def _sgns_shared_step_case():
+    import jax.numpy as jnp
+    from glint_word2vec_tpu.ops.sampler import sample_negatives_hash
+    from glint_word2vec_tpu.ops.sgns import (
+        EmbeddingPair, sgns_step_shared_core)
+    V, D, B, P = 96, 16, 32, 8
+    rng = np.random.default_rng(0)
+
+    def step(syn0, syn1, centers, contexts, mask, prob, alias, alpha):
+        negs = sample_negatives_hash(prob, alias, np.uint32(7), jnp.int32(3),
+                                     (P,))
+        return sgns_step_shared_core(
+            EmbeddingPair(syn0, syn1), centers, contexts, mask, negs, alpha,
+            num_negatives=5, compute_dtype=jnp.bfloat16,
+            logits_dtype=jnp.bfloat16)
+
+    args = (jnp.asarray(rng.standard_normal((V, D)), jnp.float32),
+            jnp.asarray(rng.standard_normal((V, D)), jnp.float32),
+            jnp.asarray(rng.integers(0, V, B), jnp.int32),
+            jnp.asarray(rng.integers(0, V, B), jnp.int32),
+            jnp.ones(B, jnp.float32), jnp.full(V, 0.5, jnp.float32),
+            jnp.asarray(rng.integers(0, V, V), jnp.int32), jnp.float32(0.025))
+    return step, args
+
+
+def _cosine_topk_case():
+    import jax.numpy as jnp
+    from glint_word2vec_tpu.models.word2vec import _cosine_topk_batch
+    rng = np.random.default_rng(1)
+    syn0 = jnp.asarray(rng.standard_normal((96, 16)), jnp.float32)
+
+    def scan(syn0, norms, queries):
+        return _cosine_topk_batch(syn0, norms, queries, 5, 90)
+
+    return scan, (syn0, jnp.linalg.norm(syn0, axis=1), syn0[:4])
+
+
+@pytest.mark.parametrize("case", [_sgns_shared_step_case, _cosine_topk_case])
+def test_named_scopes_change_metadata_only(case, monkeypatch):
+    """The compiled step and scan with the scopes are the programs without
+    them, up to metadata: what lets a PR that adds scopes say the device
+    program did not change."""
+    fn, args = case()
+    with_scopes = _hlo_without_metadata(fn, args, monkeypatch, scoped=True)
+    without = _hlo_without_metadata(fn, args, monkeypatch, scoped=False)
+    assert with_scopes == without

@@ -682,3 +682,146 @@ def test_serve_prometheus_rendering():
                    "glint_serve_reloads_total 2"):
         assert needle in text, f"{needle!r} missing from:\n{text}"
     assert "glint_serve_up 0" in serve_prometheus_text({"status": "closed"})
+
+
+# -- the serve spans (obs/spans.py; docs/observability.md §4) --------------------------
+
+_SERVE_TABLE = {"serve.coalesce", "serve.batch", "serve.dispatch",
+                "serve.row_fetch", "serve.scan_enqueue", "serve.result_fetch",
+                "serve.reply_build", "serve.queue_wait"}
+
+
+@pytest.fixture
+def serve_tracer():
+    """The process-wide tracer, telemetry off and empty, and left so."""
+    from glint_word2vec_tpu.obs.spans import default_tracer
+    tracer = default_tracer()
+    tracer.configure(enabled=False)
+    tracer.clear()
+    yield tracer
+    tracer.configure(enabled=False)
+    tracer.clear()
+
+
+def _ask(svc, n=6):
+    """``n`` concurrent callers of one query each, all answered."""
+    out = {}
+
+    def call(i):
+        out[i] = svc.synonyms(f"w{i}", 5)
+
+    threads = [threading.Thread(target=call, args=(i,)) for i in range(n)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads) and len(out) == n
+    return out
+
+
+def test_service_records_every_serve_span_under_a_live_trace(
+        tmp_path, serve_tracer):
+    import jax.profiler as jp
+    model = make_model(v=400, d=16)
+    svc = EmbeddingService(model=model, ann=False, max_delay_ms=20.0)
+    try:
+        _ask(svc)                       # warm: compiles outside the trace
+        assert serve_tracer.events() == []
+        jp.start_trace(str(tmp_path))
+        try:
+            _ask(svc)
+            time.sleep(0.05)            # the worker's post-batch records
+        finally:
+            jp.stop_trace()
+    finally:
+        svc.close()
+        model.stop()
+    evs = serve_tracer.events()
+    by_id = {e["id"]: e for e in evs}
+    assert {e["name"] for e in evs} >= _SERVE_TABLE
+    # parents: by the worker's stack, and across threads for the tickets
+    parent_name = {e["name"]: by_id[e["parent"]]["name"]
+                   for e in evs if e["parent"] in by_id}
+    assert parent_name["serve.dispatch"] == "serve.batch"
+    for leaf in ("serve.row_fetch", "serve.scan_enqueue",
+                 "serve.result_fetch", "serve.reply_build"):
+        assert parent_name[leaf] == "serve.dispatch"
+    waits = [e for e in evs if e["name"] == "serve.queue_wait"]
+    batches = {e["id"]: e for e in evs if e["name"] == "serve.batch"}
+    assert len(waits) == 6 == sum(b["args"]["size"] for b in batches.values())
+    assert len({w["args"]["request"] for w in waits}) == 6
+    for w in waits:
+        # a ticket waits until ITS batch closes: the wait ends where the
+        # batch that names it as parent starts, on the one clock
+        batch = batches[w["parent"]]
+        assert w["ts_s"] + w["dur_s"] == pytest.approx(batch["ts_s"], abs=1e-6)
+    # counts ride as args, where the work happens
+    for e in evs:
+        if e["name"] == "serve.row_fetch":
+            assert e["args"]["ops"] == by_id[e["parent"]]["args"]["size"] + 1
+        if e["name"] == "serve.scan_enqueue":
+            assert e["args"]["queries"] >= 1
+
+
+def test_service_records_no_span_with_tracing_off(serve_tracer, monkeypatch):
+    from glint_word2vec_tpu.obs import spans
+    built = []
+    real = spans.TraceAnnotation
+
+    class Counting(real):
+        def __init__(self, name):
+            built.append(name)
+            super().__init__(name)
+
+    monkeypatch.setattr(spans, "TraceAnnotation", Counting)
+    model = make_model(v=400, d=16)
+    svc = EmbeddingService(model=model, ann=False)
+    try:
+        _ask(svc)
+        assert svc.stats()["completed"] == 6
+    finally:
+        svc.close()
+        model.stop()
+    assert serve_tracer.events() == [] and built == []
+
+
+def test_ann_arm_spans(serve_tracer):
+    model = make_model(v=600, d=16)
+    model.attach_ann(build_ivf(np.asarray(model.syn0), 16))
+    serve_tracer.configure(enabled=True)
+    got = model.find_synonyms_batch(["w1", "w2"], 5, ann=True)
+    assert len(got) == 2 and all(len(r) == 5 for r in got)
+    evs = serve_tracer.events()
+    assert [e["name"] for e in evs] == [
+        "serve.row_fetch", "serve.ann_search", "serve.reply_build"]
+    assert evs[0]["args"] == {"ops": 0} and evs[1]["args"] == {"queries": 2}
+    model.stop()
+
+
+def test_batcher_hooks_are_fed_the_batch_spans_times(serve_tracer):
+    """One pair of clock reads per batch: the observer's service time and
+    the trace hook's records come from the ``serve.batch`` span, recorded or
+    not, and agree with each other to the nanosecond."""
+    seen, spans_out = [], []
+
+    def handler(batch):
+        time.sleep(0.01)
+        return batch
+
+    b = BatchingScheduler(
+        handler, max_batch=4, max_delay_ms=1.0,
+        span_emit=lambda tr, name, t0, dur: spans_out.append((name, t0, dur)),
+        batch_observer=lambda n, service, wait: seen.append(
+            (n, service, wait))).start()
+    try:
+        t = b.submit_async(1, trace={"tid": "t1", "ps": "s1"})
+        assert b.wait(t, 30) == 1
+    finally:
+        b.stop()
+    (n, service_s, wait_s), = seen
+    assert n == 1 and service_s >= 0.01 and wait_s >= 0.0
+    by = {name: (t0, dur) for name, t0, dur in spans_out}
+    assert set(by) == {"queue_wait", "batch_service"}
+    assert by["batch_service"][1] == int(service_s * 1e9)
+    assert by["queue_wait"][0] + by["queue_wait"][1] == by["batch_service"][0]
+    assert serve_tracer.events() == []      # telemetry off, no live trace
