@@ -268,11 +268,20 @@ class Word2VecModel:
         ann: bool = False,
         nprobe: Optional[int] = None,
     ) -> List[List[Tuple[str, float]]]:
-        """Batched :meth:`find_synonyms`: one device dispatch per ``chunk``
-        queries instead of one per query. The per-query launch and fetch round
-        trip dominates a scan this short; batching amortizes it — the
-        [chunk, V] cosine matrix rides one matmul.
+        """Batched :meth:`find_synonyms`: ONE device program per ``chunk``
+        queries, word ids in and the top-k out. The host resolves the words
+        to row ids (dictionary lookups) and hands over one ``int32[Q]``
+        array; the program gathers the rows from the table it already
+        holds, normalises them, and runs the [chunk, V] cosine matmul and
+        the top-k (:func:`_gather_topk_batch`). No per-query device
+        operation: a launch costs more than this scan's share of a query.
+        A chunk that holds a vector query (``np.ndarray``; analogies)
+        additionally sends one float32 ``[Q, D]`` block, and the program
+        takes row ``i`` from the gather where ``ids[i] >= 0`` and from the
+        block otherwise — chosen from what the chunk holds, so the second
+        program per batch size is compiled only where vectors are sent.
         Word queries exclude themselves (mllib:621-629); vector queries do not.
+        An unknown word raises ``KeyError`` before anything is dispatched.
         ``chunk`` bounds device memory at chunk·V·4 bytes of scores.
 
         ``ann=True`` routes the batch through the attached IVF index
@@ -289,38 +298,51 @@ class Word2VecModel:
                     "serve.ann.build_ivf(np.asarray(model.syn0)) and "
                     "model.attach_ann(index)")
             return self._find_synonyms_batch_ann(queries, num, nprobe)
-        self.norms  # materialize the cached full-row norms
+        if self._norms is None:
+            self.norms  # materialize the cached full-row norms
         tracer = default_tracer()
         out: List[List[Tuple[str, float]]] = []
         k = min(num + 1, self.num_words)
-        for lo in range(0, len(queries), chunk):
-            part = queries[lo:lo + chunk]
-            # spans of the serve table (obs/spans.py, docs/observability.md
-            # §4): the host side of one scan, region by region
-            with tracer.span("serve.row_fetch") as sp:
-                words: List[Optional[str]] = []
-                rows = []
-                for q in part:
-                    if isinstance(q, str):
-                        idx = self.vocab.get(q)
-                        if idx < 0:
-                            raise KeyError(f"{q} not in vocabulary")
-                        words.append(q)
-                        rows.append(self._full0[idx])
-                    else:
-                        words.append(None)
-                        rows.append(jnp.asarray(q, jnp.float32))
-                block = jnp.stack(rows)
-                # device operations issued to build the block: a row read or
-                # a put per query, and the stack
-                sp.set(ops=len(rows) + 1)
-            with tracer.span("serve.scan_enqueue", queries=len(part)):
+        # spans of the serve table (obs/spans.py, docs/observability.md §4):
+        # the host side of the scan, region by region
+        with tracer.span("serve.row_fetch") as sp:
+            words: List[Optional[str]] = []
+            ids = np.full(len(queries), -1, np.int32)
+            block: Optional[np.ndarray] = None
+            for i, q in enumerate(queries):
+                if isinstance(q, str):
+                    idx = self.vocab.get(q)
+                    if idx < 0:
+                        raise KeyError(f"{q} not in vocabulary")
+                    words.append(q)
+                    ids[i] = idx
+                else:
+                    if block is None:
+                        block = np.zeros(
+                            (len(queries), self.vector_size), np.float32)
+                    words.append(None)
+                    block[i] = q
+            # what each chunk's program is handed: its ids, and the vector
+            # block only where the chunk holds a vector query
+            parts = []
+            for lo in range(0, len(queries), chunk):
+                part_ids = ids[lo:lo + chunk]
+                vectors = block is not None and part_ids.min() < 0
+                parts.append(
+                    (lo, part_ids, block[lo:lo + chunk] if vectors else None))
+            # device operations issued to build the query blocks: one put
+            # per host array above, whatever the number of queries
+            sp.set(ops=sum(1 + (b is not None) for _, _, b in parts))
+        for lo, part_ids, part_block in parts:
+            with tracer.span("serve.scan_enqueue", queries=len(part_ids)):
                 scores, idxs = _topk_dispatch(
-                    self._full0, self._norms, block, k, self.num_words)
+                    self._full0, self._norms, part_ids, part_block,
+                    k, self.num_words)
             with tracer.span("serve.result_fetch"):
                 scores, idxs = np.asarray(scores), np.asarray(idxs)
             with tracer.span("serve.reply_build"):
-                out.extend(self._replies(words, scores, idxs, num))
+                out.extend(self._replies(
+                    words[lo:lo + chunk], scores, idxs, num))
         return out
 
     def _replies(self, words: List[Optional[str]], scores, idxs,
@@ -558,6 +580,35 @@ class Word2VecModel:
 from functools import partial
 
 
+def _query_block(syn0: jax.Array, ids: jax.Array,
+                 block: Optional[jax.Array], partitioned: bool) -> jax.Array:
+    """The [Q, D] query rows, built inside the scan's own program: row
+    ``ids[i]`` of the table, or row ``i`` of ``block`` where ``ids[i] < 0``
+    (a vector query). ``block`` is None for an all-word batch — another
+    trace, with no second operand. The dtype is what stacking the rows gave:
+    the table's for words alone, promoted with the block's float32 otherwise.
+
+    The rows are read as Q slices, not as one gather op: the TPU keeps a
+    [V, D] table whose D is no multiple of 128 column-major, and its gather
+    first copies the whole table row-major (4.6 GB and a second pass over it
+    at 3M × 300, by the v5e compiler), where a slice reads a row in place.
+    A table ``partitioned`` by rows over a mesh takes the gather: GSPMD
+    gives each shard its own rows and one [Q, D] all-reduce, where a slice
+    along a sharded dimension all-gathers the table."""
+    with jax.named_scope("scan.gather"):
+        ids0 = jnp.maximum(ids, 0)
+        if partitioned:
+            rows = syn0[ids0]
+        else:
+            rows = jnp.concatenate([
+                jax.lax.dynamic_slice_in_dim(
+                    syn0, ids0[i], 1, allow_negative_indices=False)
+                for i in range(ids.shape[0])])
+        if block is None:
+            return rows
+        return jnp.where((ids >= 0)[:, None], rows, block)
+
+
 @partial(jax.jit, static_argnames=("valid_rows",))
 def _cosine_batch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
                   valid_rows: int) -> jax.Array:
@@ -587,6 +638,26 @@ def _cosine_topk_batch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
         return jax.lax.top_k(cos, k)
 
 
+@partial(jax.jit, static_argnames=("valid_rows", "partitioned"))
+def _gather_cosine_batch(syn0: jax.Array, norms: jax.Array, ids: jax.Array,
+                         block: Optional[jax.Array], valid_rows: int,
+                         partitioned: bool) -> jax.Array:
+    """:func:`_cosine_batch` over the rows :func:`_query_block` builds."""
+    return _cosine_batch(
+        syn0, norms, _query_block(syn0, ids, block, partitioned), valid_rows)
+
+
+@partial(jax.jit, static_argnames=("k", "valid_rows", "partitioned"))
+def _gather_topk_batch(syn0: jax.Array, norms: jax.Array, ids: jax.Array,
+                       block: Optional[jax.Array], k: int, valid_rows: int,
+                       partitioned: bool) -> Tuple[jax.Array, jax.Array]:
+    """Word ids in, top-k out, ONE program: :func:`_cosine_topk_batch` over
+    the rows :func:`_query_block` reads from the table it already holds."""
+    return _cosine_topk_batch(
+        syn0, norms, _query_block(syn0, ids, block, partitioned), k,
+        valid_rows)
+
+
 # CPU route tiling: queries are sub-chunked so the fetched [q, V] score
 # block stays under ~512 MB of host RAM
 _CPU_TOPK_SCORE_BYTES = 512 << 20
@@ -613,32 +684,37 @@ def _cpu_topk_row(row: np.ndarray, k: int) -> Tuple[np.ndarray, np.ndarray]:
     return sc[order], cand[order]
 
 
-def _topk_dispatch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
-                   k: int, valid_rows: int):
-    """Route the cosine top-k (PERF.md §10). Default everywhere:
-    ``lax.top_k`` in the same dispatch as the matmul. The host route —
-    fetch scores in ~512 MB sub-chunks, rank with chunked ``np.argpartition``
-    (:func:`_cpu_topk_row`), bit-identical results tie-order included
-    (tested) — exists for CPU backends whose XLA top-k lowers to a per-row
-    SORT (round 5 measured >30 min for 64 queries at V=10M, PERF.md §6, which
-    bricked CPU serving at scale). That pathology did NOT reproduce under the
-    current jaxlib — re-measured at 6.4 s for the same shape, beating the
-    host route 2-3x at every shape tried (§10) — so the host route is opt-in:
-    set ``GLINT_CPU_TOPK=argpartition`` on toolchains that still exhibit the
+def _topk_dispatch(syn0: jax.Array, norms: jax.Array, ids: np.ndarray,
+                   block: Optional[np.ndarray], k: int, valid_rows: int):
+    """Route the cosine top-k of one chunk: ``ids`` (and ``block``, where
+    the chunk holds vector queries) are host arrays, transferred by the one
+    call that runs the program. Default everywhere: ``lax.top_k`` in the
+    same program as the gather and the matmul. The host route — the same
+    gather and cosine on the device, scores fetched in ~512 MB sub-chunks and
+    ranked with chunked ``np.argpartition`` (:func:`_cpu_topk_row`),
+    bit-identical results tie-order included (tested) — exists for CPU
+    backends whose XLA top-k lowers to a per-row SORT (an earlier jaxlib
+    took >30 min for 64 queries at V=10M; under the current one the device
+    route wins 2-3x at every shape tried), so it is opt-in: set
+    ``GLINT_CPU_TOPK=argpartition`` on toolchains that still exhibit the
     sort lowering."""
     import os
+    partitioned = not syn0.sharding.is_fully_replicated
     if (jax.default_backend() != "cpu"
             or os.environ.get("GLINT_CPU_TOPK") != "argpartition"):
         # device arrays: this returns once the program is enqueued, and the
         # caller's fetch is where the host waits for it
-        return _cosine_topk_batch(syn0, norms, queries, k, valid_rows)
-    Q, V = queries.shape[0], syn0.shape[0]
+        return _gather_topk_batch(
+            syn0, norms, ids, block, k, valid_rows, partitioned)
+    Q, V = ids.shape[0], syn0.shape[0]
     qsub = max(1, min(Q, _CPU_TOPK_SCORE_BYTES // max(V * 4, 1)))
     scores = np.empty((Q, k), np.float32)
     idxs = np.empty((Q, k), np.int64)
     for lo in range(0, Q, qsub):
-        cos = np.asarray(_cosine_batch(
-            syn0, norms, queries[lo:lo + qsub], valid_rows))
+        cos = np.asarray(_gather_cosine_batch(
+            syn0, norms, ids[lo:lo + qsub],
+            None if block is None else block[lo:lo + qsub], valid_rows,
+            partitioned))
         for r in range(cos.shape[0]):
             scores[lo + r], idxs[lo + r] = _cpu_topk_row(cos[r], k)
     return scores, idxs

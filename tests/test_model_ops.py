@@ -192,3 +192,135 @@ def test_stop_releases():
     m.stop()  # idempotent
     with pytest.raises(RuntimeError, match="stopped"):
         m.transform("a")
+
+
+# -- one device program per query batch: word ids in, the top-k out ----------------
+
+
+def _scan_model(table: str):
+    """203 words × 16 dims with a zero-norm row: float32, bfloat16, or
+    row-sharded over the eight fake devices (208 padded rows)."""
+    import jax.numpy as jnp
+    from glint_word2vec_tpu.parallel.mesh import make_mesh
+    rng = np.random.default_rng(3)
+    syn0 = rng.standard_normal((203, 16)).astype(np.float32)
+    syn0[11] = 0.0
+    vocab = Vocabulary.from_words_and_counts(
+        [f"w{i}" for i in range(203)], np.ones(203, np.int64))
+    if table == "sharded":
+        return Word2VecModel(vocab, syn0, plan=make_mesh(1, 8)), syn0
+    return Word2VecModel(vocab, jnp.asarray(syn0, jnp.dtype(table))), syn0
+
+
+def _scan_batches(syn0):
+    rng = np.random.default_rng(4)
+    vec = [rng.standard_normal(16).astype(np.float32) for _ in range(3)]
+    return {
+        # (queries, num, chunk)
+        "words_repeated": (["w5", "w9", "w5", "w202", "w11"], 4, 128),
+        "vectors": ([syn0[4] * 3.0, vec[0], np.zeros(16, np.float32)], 4, 128),
+        "mixed": (["w5", vec[1], "w5", syn0[9], "w0"], 4, 128),
+        "one_word": (["w7"], 3, 128),
+        "one_vector": ([vec[2]], 3, 128),
+        "longer_than_chunk": (["w1", vec[0], "w2", "w3", "w1", vec[1], "w200"],
+                              5, 3),
+        "num_over_vocabulary": (["w5", vec[2], "w202"], 300, 128),
+    }
+
+
+def _row_by_row(model, queries, num, chunk):
+    """Today's replies from yesterday's host side: the block read one row
+    at a time, stacked, and scanned by the matrix-query form."""
+    import jax.numpy as jnp
+    from glint_word2vec_tpu.models.word2vec import _cosine_topk_batch
+    model.norms
+    k, out = min(num + 1, model.num_words), []
+    for lo in range(0, len(queries), chunk):
+        part = queries[lo:lo + chunk]
+        block = jnp.stack([
+            model._full0[model.vocab.get(q)] if isinstance(q, str)
+            else jnp.asarray(q, jnp.float32) for q in part])
+        scores, idxs = _cosine_topk_batch(
+            model._full0, model._norms, block, k, model.num_words)
+        out.extend(model._replies(
+            [q if isinstance(q, str) else None for q in part],
+            np.asarray(scores), np.asarray(idxs), num))
+    return out
+
+
+@pytest.mark.parametrize("route", ["device_topk", "argpartition"])
+@pytest.mark.parametrize("table", ["float32", "bfloat16", "sharded"])
+@pytest.mark.parametrize("batch", [
+    "words_repeated", "vectors", "mixed", "one_word", "one_vector",
+    "longer_than_chunk", "num_over_vocabulary"])
+def test_gathered_batch_equals_row_by_row(batch, table, route, monkeypatch):
+    if route == "argpartition":
+        monkeypatch.setenv("GLINT_CPU_TOPK", "argpartition")
+    model, syn0 = _scan_model(table)
+    queries, num, chunk = _scan_batches(syn0)[batch]
+    got = model.find_synonyms_batch(queries, num, chunk=chunk)
+    monkeypatch.delenv("GLINT_CPU_TOPK", raising=False)
+    want = _row_by_row(model, queries, num, chunk)
+    assert len(got) == len(queries)
+    for q, g, w in zip(queries, got, want):
+        assert [x for x, _ in g] == [x for x, _ in w]
+        np.testing.assert_allclose([s for _, s in g], [s for _, s in w],
+                                   rtol=0, atol=1e-6)
+        assert len(g) == min(num, 203 - isinstance(q, str))
+        if isinstance(q, str):
+            assert q not in [x for x, _ in g]      # self-exclusion intact
+    model.stop()
+
+
+def test_unknown_word_dispatches_nothing(monkeypatch):
+    from glint_word2vec_tpu.models import word2vec as w2v
+    model, _ = _scan_model("float32")
+    calls = []
+    monkeypatch.setattr(w2v, "_topk_dispatch", lambda *a: calls.append(a))
+    # the unknown word sits in the second chunk: the first is not sent either
+    with pytest.raises(KeyError, match="zzz not in vocabulary"):
+        model.find_synonyms_batch(["w1", "w2", "w3", "zzz"], 2, chunk=2)
+    assert calls == []
+    model.stop()
+
+
+def test_block_dtype_is_what_stacking_gave():
+    """An all-word batch scans in the table's dtype (the benchmark's
+    bfloat16 control rides on it); a vector query promotes the block."""
+    import jax
+    import jax.numpy as jnp
+    from glint_word2vec_tpu.models.word2vec import _query_block
+    table = jnp.ones((8, 4), jnp.bfloat16)
+    ids = jnp.asarray([2, -1], jnp.int32)
+    for partitioned in (False, True):
+        words = jax.eval_shape(
+            lambda t, i: _query_block(t, i, None, partitioned), table, ids)
+        mixed = jax.eval_shape(
+            lambda t, i, b: _query_block(t, i, b, partitioned), table, ids,
+            jnp.zeros((2, 4), jnp.float32))
+        assert words.dtype == jnp.bfloat16 and mixed.dtype == jnp.float32
+        assert words.shape == mixed.shape == (2, 4)
+
+
+@pytest.mark.parametrize("table,partitioned", [("float32", False),
+                                               ("sharded", True)])
+def test_row_sharded_table_takes_the_gather(table, partitioned, monkeypatch):
+    """What the program reads rows with follows the table it is handed: Q
+    slices of a table on one device, one gather of a row-partitioned one —
+    which no program all-gathers."""
+    from glint_word2vec_tpu.models import word2vec as w2v
+    model, _ = _scan_model(table)
+    seen = []
+    real = w2v._gather_topk_batch
+    monkeypatch.setattr(
+        w2v, "_gather_topk_batch",
+        lambda *a: seen.append(a) or real(*a))
+    model.find_synonyms_batch(["w1", "w2"], 3)
+    (syn0, norms, ids, block, k, valid_rows, flag), = seen
+    assert flag is partitioned and block is None and ids.dtype == np.int32
+    hlo = real.lower(syn0, norms, ids, block, k, valid_rows, flag
+                     ).compile().as_text()
+    gathered = [line for line in hlo.splitlines()
+                if " all-gather(" in line and f"[{syn0.shape[0]},16]" in line]
+    assert gathered == []
+    model.stop()

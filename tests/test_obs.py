@@ -675,7 +675,26 @@ def _cosine_topk_case():
     return scan, (syn0, jnp.linalg.norm(syn0, axis=1), syn0[:4])
 
 
-@pytest.mark.parametrize("case", [_sgns_shared_step_case, _cosine_topk_case])
+def _gather_topk_case(mixed=False):
+    """The served program: word ids in, top-k out (``scan.gather`` too); the
+    mixed form takes the gather a row-partitioned table takes."""
+    import jax.numpy as jnp
+    from glint_word2vec_tpu.models.word2vec import _gather_topk_batch
+    scan, (syn0, norms, queries) = _cosine_topk_case()
+    ids = jnp.asarray([3, -1, 95, 3] if mixed else [3, 0, 95, 3], jnp.int32)
+
+    def served(syn0, norms, ids, block):
+        return _gather_topk_batch(syn0, norms, ids, block, 5, 90, mixed)
+
+    return served, (syn0, norms, ids, queries if mixed else None)
+
+
+def _gather_topk_mixed_case():
+    return _gather_topk_case(mixed=True)
+
+
+@pytest.mark.parametrize("case", [_sgns_shared_step_case, _cosine_topk_case,
+                                  _gather_topk_case, _gather_topk_mixed_case])
 def test_named_scopes_change_metadata_only(case, monkeypatch):
     """The compiled step and scan with the scopes are the programs without
     them, up to metadata: what lets a PR that adds scopes say the device

@@ -335,11 +335,10 @@ def test_cpu_topk_matches_lax_topk(monkeypatch):
     words, counts, syn0, _ = _ckpt_fixtures(rows=800, dim=16)
     vocab = Vocabulary.from_words_and_counts(words, counts)
     model = w2v.Word2VecModel(vocab, jnp.asarray(syn0))
-    queries = jnp.asarray(syn0[:5])
     s_ref, i_ref = w2v._cosine_topk_batch(
-        model._full0, model.norms, queries, 12, 800)
+        model._full0, model.norms, jnp.asarray(syn0[:5]), 12, 800)
     s_cpu, i_cpu = w2v._topk_dispatch(
-        model._full0, model.norms, queries, 12, 800)
+        model._full0, model.norms, np.arange(5, dtype=np.int32), None, 12, 800)
     assert np.array_equal(np.asarray(i_ref), i_cpu)
     assert np.allclose(np.asarray(s_ref), s_cpu, atol=1e-6)
     # and through the public API

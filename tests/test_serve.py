@@ -254,9 +254,18 @@ def test_overload_hint_is_none_before_first_batch():
     try:
         t = threading.Thread(target=lambda: b.submit(1))
         t.start()
+        deadline = time.monotonic() + 5
+
+        def taken():        # the worker holds the first ticket at the gate
+            s = b.stats()
+            return s["submitted"] == 1 and s["queue_depth"] == 0
+
+        # only then the second: sent at once it can find the first still
+        # queued and be refused itself, and nothing would fill the queue
+        while not taken() and time.monotonic() < deadline:
+            time.sleep(0.005)
         t2 = threading.Thread(target=lambda: b.submit(2))
         t2.start()
-        deadline = time.monotonic() + 5
         while b.stats()["queue_depth"] < 1 and time.monotonic() < deadline:
             time.sleep(0.005)
         with pytest.raises(ServerOverloaded) as ei:
@@ -758,9 +767,64 @@ def test_service_records_every_serve_span_under_a_live_trace(
     # counts ride as args, where the work happens
     for e in evs:
         if e["name"] == "serve.row_fetch":
-            assert e["args"]["ops"] == by_id[e["parent"]]["args"]["size"] + 1
+            # word queries: the id array alone, whatever the batch's size
+            assert e["args"]["ops"] == 1
         if e["name"] == "serve.scan_enqueue":
             assert e["args"]["queries"] >= 1
+
+
+def _row_fetch_ops(tracer, model, queries):
+    tracer.clear()
+    model.find_synonyms_batch(queries, 5)
+    return [e["args"]["ops"] for e in tracer.events()
+            if e["name"] == "serve.row_fetch"]
+
+
+@pytest.mark.parametrize("kind,ops", [("words", 1), ("mixed", 2)])
+def test_row_fetch_ops_do_not_grow_with_the_batch(serve_tracer, kind, ops):
+    """No device operation per query: the id array is one put, a vector
+    block a second, for a batch of 2 as for a batch of 40."""
+    model = make_model(v=400, d=16)
+    vec = np.asarray(model.syn0[7])
+    serve_tracer.configure(enabled=True)
+
+    def batch(n):
+        return ([f"w{i}" for i in range(n - 1)]
+                + [vec if kind == "mixed" else "w0"])
+
+    assert (_row_fetch_ops(serve_tracer, model, batch(2))
+            == _row_fetch_ops(serve_tracer, model, batch(40)) == [ops])
+    model.stop()
+
+
+_COMPILED = []      # every backend compile of this process, by function
+
+
+def _on_compile(name, seconds, **kw):
+    if name == "/jax/core/compile/backend_compile_duration":
+        _COMPILED.append(kw.get("fun_name"))
+
+
+@pytest.mark.parametrize("size", [1, 2, 3, 4])
+def test_one_program_per_all_word_batch_size(size):
+    """A size's first all-word batch compiles the scan-with-gather and
+    nothing else (no stack, no row read); its second compiles nothing."""
+    import jax.monitoring
+    from glint_word2vec_tpu.models.word2vec import _gather_topk_batch
+    if not _COMPILED:       # jax.monitoring has no public unregister
+        _COMPILED.append("listening")
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+    # a vocabulary of its own, so no other test has compiled these shapes
+    model = make_model(v=417 + size, d=16)
+    model.norms
+    cached, mark = _gather_topk_batch._cache_size(), len(_COMPILED)
+    words = [f"w{i}" for i in range(size)]
+    first = model.find_synonyms_batch(words, 5)
+    assert _COMPILED[mark:] == ["jit(_gather_topk_batch)"]
+    assert model.find_synonyms_batch(words, 5) == first
+    assert len(_COMPILED) == mark + 1
+    assert _gather_topk_batch._cache_size() == cached + 1
+    model.stop()
 
 
 def test_service_records_no_span_with_tracing_off(serve_tracer, monkeypatch):
