@@ -297,8 +297,10 @@ class Word2VecConfig:
                                     # "banded": sentence-contiguous token-block
                                     # feed + prefix-sum interval accumulation
                                     # (ops/cbow_banded.py) — ~B context rows
-                                    # instead of B·C, projected ≥2× examples/s
-                                    # at the headline geometry (PERF.md §9).
+                                    # instead of B·C: 2.5× the scatter form's
+                                    # examples/s on a TPU v5 lite at 3M × 300,
+                                    # B=64k (2.17 M against 0.85 M; PERF.md §6,
+                                    # PR 27).
                                     # Identical update math (float64-equivalence
                                     # tested); needs the shared-pool estimator
                                     # (negative_pool > 0), window ≥ 2, and no

@@ -532,16 +532,29 @@ def _block_cbow(
     if prologue is None:
         return empty
     toks, left, total, Nk = prologue
-    j = np.arange(C, dtype=np.int64)[None, :]
-    ctx_pos = np.where(j < left[:, None],
-                       np.arange(Nk, dtype=np.int64)[:, None] - left[:, None] + j,
-                       np.arange(Nk, dtype=np.int64)[:, None] + j - left[:, None] + 1)
-    valid = j < total[:, None]
-    contexts = np.where(valid, toks[np.clip(ctx_pos, 0, Nk - 1)], 0).astype(np.int32)
-    has_ctx = total > 0
-    return (toks[has_ctx].astype(np.int32), contexts[has_ctx],
-            total[has_ctx].astype(np.int32),
-            np.flatnonzero(has_ctx) + 1, int(Nk))
+    # the CBOW-specific host work of the scatter form's feed (the subsample
+    # and window draws above are the skip-gram feed's too)
+    with _cbow_pack_span() as sp:
+        j = np.arange(C, dtype=np.int64)[None, :]
+        ctx_pos = np.where(j < left[:, None],
+                           np.arange(Nk, dtype=np.int64)[:, None] - left[:, None] + j,
+                           np.arange(Nk, dtype=np.int64)[:, None] + j - left[:, None] + 1)
+        valid = j < total[:, None]
+        contexts = np.where(valid, toks[np.clip(ctx_pos, 0, Nk - 1)], 0).astype(np.int32)
+        has_ctx = total > 0
+        out = (toks[has_ctx].astype(np.int32), contexts[has_ctx],
+               total[has_ctx].astype(np.int32),
+               np.flatnonzero(has_ctx) + 1, int(Nk))
+        sp.set(examples=int(out[0].shape[0]), context_rows=int(out[2].sum()))
+    return out
+
+
+def _cbow_pack_span():
+    """Span ``producer.cbow_pack`` (docs/observability.md §4): the host work a
+    CBOW feed adds to a chunk, on whichever thread does it. Imported here so
+    that this module stays free of JAX until a CBOW feed runs."""
+    from glint_word2vec_tpu.obs.spans import default_tracer
+    return default_tracer().span("producer.cbow_pack")
 
 
 def pack_halo_token_blocks(
@@ -593,23 +606,33 @@ def pack_halo_token_blocks(
                            bitorder="little")
         return (tokens, bits, n, bpos & 0xFFFFFFFFFFFFFFFF, n_core)
 
+    def advance():
+        nonlocal buf_tok, buf_start, bpos
+        buf_tok = buf_tok[Tc:]
+        buf_start = buf_start[Tc:].copy()
+        bpos += Tc
+
     for ktoks, kstart in slabs:
         if ktoks.shape[0] == 0:
             continue
-        buf_tok = np.concatenate([buf_tok, ktoks.astype(tok_dtype)])
-        buf_start = np.concatenate([buf_start, kstart])
+        # the banded form's CBOW-specific host work: a span over taking a slab
+        # into the buffer, then one a block (closed before the yield: open
+        # across it, it would time the consumer)
+        with _cbow_pack_span() as sp:
+            buf_tok = np.concatenate([buf_tok, ktoks.astype(tok_dtype)])
+            buf_start = np.concatenate([buf_start, kstart])
+            sp.set(examples=0, context_rows=0)
         while buf_tok.shape[0] >= T:
-            yield emit(Tc)
-            buf_tok = buf_tok[Tc:]
-            buf_start = buf_start[Tc:].copy()
-            bpos += Tc
+            with _cbow_pack_span() as sp:
+                block = emit(Tc)
+                advance()
+                sp.set(examples=Tc, context_rows=T)
+            yield block
     # flush: emit while un-centered core positions remain (len > halo ⟺ some
     # stream token at position ≥ bpos + halo has not been a core slot yet)
     while buf_tok.shape[0] > halo:
         yield emit(min(buf_tok.shape[0] - halo, Tc))
-        buf_tok = buf_tok[Tc:]
-        buf_start = buf_start[Tc:].copy()
-        bpos += Tc
+        advance()
 
 
 @dataclass

@@ -65,7 +65,7 @@ from glint_word2vec_tpu.ops.sgns import (
 _SHIFT_UNROLL_MAX_WINDOW = 16
 
 
-def cumsum_rows(x: jax.Array) -> jax.Array:
+def cumsum_rows(x: jax.Array, one_pass: bool = False) -> jax.Array:
     """Inclusive prefix sum along axis 0 of a [T, D] array.
 
     The float twin of ops/pairgen._cumsum_i32: XLA's 1-D cumulative ops cost
@@ -74,13 +74,25 @@ def cumsum_rows(x: jax.Array) -> jax.Array:
     totals take the (tiny) native cumsum. Unlike the int variant there is no
     exactness window — callers pick an accumulation dtype wide enough for
     their cancellation needs (the banded step uses ≥ float32).
+
+    The matmul asks for ``Precision.HIGHEST``: at the default the TPU's MXU
+    takes float32 operands ROUNDED to bfloat16, and a prefix sum of rounded
+    difference-array entries never cancels — the errors walk over the block
+    (on the v5e syn0's update came out ~30% off, its norm 5-6% over the
+    float32 reference's, where the CPU, which computes a float32 matmul
+    exactly, had shown nothing; PERF.md §6, PR 27). HIGHEST is six MXU passes,
+    ~0.24 ms of a 23 ms step at T = 64k, D = 384. ``one_pass=True`` is the
+    caller's word that every entry of ``x`` is a bfloat16 value already: the
+    default precision's single pass is then exact.
     """
     T, D = x.shape
     chunk = 128
     rows = -(-T // chunk)
     xp = jnp.pad(x, ((0, rows * chunk - T), (0, 0))).reshape(rows, chunk, D)
     tri = jnp.tril(jnp.ones((chunk, chunk), x.dtype))  # [i, j] = 1 iff j <= i
-    within = jnp.einsum("ij,rjd->rid", tri, xp)        # inclusive within-chunk
+    within = jnp.einsum(                               # inclusive within-chunk
+        "ij,rjd->rid", tri, xp,
+        precision=None if one_pass else jax.lax.Precision.HIGHEST)
     totals = within[:, -1, :]                          # [rows, D]
     # graftlint: disable=R4 -- accumulation dtype is the CALLER's contract (docstring above); both call sites pass >=f32 and are R4-checked there
     offs = jnp.cumsum(totals, axis=0) - totals         # exclusive chunk offsets
@@ -167,20 +179,33 @@ def cbow_step_banded_core(
     has_ctx = (ctx_n_i > 0).astype(jnp.float32)
     live = center_mask * has_ctx                                    # [T]
 
+    # named scopes: the scatter form's five (ops/sgns.cbow_step_shared_core),
+    # metadata only
     # -- forward: windowed context mean via one prefix-sum difference ---------
-    e = syn0[tokens]                                                # [T, D]
-    S = cumsum_rows(e.astype(pf))                                   # [T, D]
-    Spad = jnp.concatenate([jnp.zeros((1, S.shape[1]), pf), S])     # S[<i] sums
-    ctx_sum = Spad[t + right + 1] - Spad[t - left] - e.astype(pf)
-    ctx_n = jnp.maximum(ctx_n_i, 1).astype(pf)
-    hidden = (ctx_sum / ctx_n[:, None]).astype(compute_dtype)       # [T, D]
+    rows_in_bf16 = jnp.dtype(compute_dtype) == jnp.bfloat16
+    with jax.named_scope("cbow.gather"):
+        e = syn0[tokens].astype(pf)                                 # [T, D]
+        if rows_in_bf16:
+            # the rows enter the sum at compute_dtype, as the scatter form's
+            # do, rounded to nearest HERE: the forward prefix is then one MXU
+            # pass and exact on them (reduce_precision, because XLA may drop
+            # an astype round trip as excess precision)
+            e = jax.lax.reduce_precision(e, exponent_bits=8, mantissa_bits=7)
+    with jax.named_scope("cbow.context_sum"):
+        S = cumsum_rows(e.astype(pf), one_pass=rows_in_bf16)        # [T, D]
+        Spad = jnp.concatenate([jnp.zeros((1, S.shape[1]), pf), S])  # S[<i] sums
+        ctx_sum = Spad[t + right + 1] - Spad[t - left] - e
+        ctx_n = jnp.maximum(ctx_n_i, 1).astype(pf)
+        hidden = (ctx_sum / ctx_n[:, None]).astype(compute_dtype)   # [T, D]
 
     # -- shared-pool positive/negative chain, unchanged from the scatter step
     tok_i = tokens.astype(jnp.int32)
-    e_out = syn1[tokens].astype(compute_dtype)                      # [T, D]
-    Z = syn1[negatives].astype(compute_dtype)                       # [P, D]
-    f_pos = jnp.sum(hidden * e_out, axis=-1).astype(jnp.float32)
-    f_neg = (hidden @ Z.T).astype(logits_dtype)                     # [T, P]
+    with jax.named_scope("cbow.gather"):
+        e_out = syn1[tokens].astype(compute_dtype)                  # [T, D]
+        Z = syn1[negatives].astype(compute_dtype)                   # [P, D]
+    with jax.named_scope("cbow.pool_matmul"):
+        f_pos = jnp.sum(hidden * e_out, axis=-1).astype(jnp.float32)
+        f_neg = (hidden @ Z.T).astype(logits_dtype)                 # [T, P]
     neg_valid = (negatives[None, :] != tok_i[:, None]).astype(logits_dtype) \
         * center_mask[:, None].astype(logits_dtype)
 
@@ -190,11 +215,12 @@ def cbow_step_banded_core(
              * has_ctx[:, None].astype(logits_dtype)
              * jnp.asarray(num_negatives / P, logits_dtype))
 
-    gp = g_pos[:, None].astype(compute_dtype)
-    gn = g_neg.astype(compute_dtype)
-    d_hidden = gp * e_out + gn @ Z                                  # [T, D]
-    d_out = gp * hidden
-    d_Z = gn.T @ hidden                                             # [P, D]
+    with jax.named_scope("cbow.pool_matmul"):
+        gp = g_pos[:, None].astype(compute_dtype)
+        gn = g_neg.astype(compute_dtype)
+        d_hidden = gp * e_out + gn @ Z                              # [T, D]
+        d_out = gp * hidden
+        d_Z = gn.T @ hidden                                         # [P, D]
     if stabilizers is not None and stabilizers.update_clip:
         # clip BEFORE the mean-convention split/spread — the same quantity
         # the scatter formulation clips (ops/sgns.py), so the two CBOW
@@ -204,14 +230,17 @@ def cbow_step_banded_core(
         d_out = clip_update_rows(d_out, stabilizers.update_clip)
 
     # -- backward: banded spread of d_hidden/n via difference array + prefix --
-    g_row = d_hidden.astype(pf) / ctx_n[:, None]                    # [T, D]
-    delta = _band_endpoint_delta(g_row, left, right, window)
-    d_ctx = (cumsum_rows(delta) - g_row) * token_mask[:, None].astype(pf)
+    with jax.named_scope("cbow.context_sum"):
+        g_row = d_hidden.astype(pf) / ctx_n[:, None]                # [T, D]
+        delta = _band_endpoint_delta(g_row, left, right, window)
+        d_ctx = (cumsum_rows(delta) - g_row) * token_mask[:, None].astype(pf)
 
     dtype = syn0.dtype
-    new_syn0 = syn0.at[tokens].add(d_ctx.astype(dtype))
-    new_syn1 = syn1.at[tokens].add(d_out.astype(dtype))
-    new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
+    with jax.named_scope("cbow.scatter_syn0"):
+        new_syn0 = syn0.at[tokens].add(d_ctx.astype(dtype))
+    with jax.named_scope("cbow.scatter_syn1"):
+        new_syn1 = syn1.at[tokens].add(d_out.astype(dtype))
+        new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
     if stabilizers is not None and stabilizers.post_pass:
         # touched sets of THIS formulation: syn0 at every valid token slot
         # (each is a potential context row of the band — a context-less token

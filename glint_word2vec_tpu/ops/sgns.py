@@ -897,15 +897,22 @@ def cbow_step_shared_core(
     neg_valid = (negatives[None, :] != centers[:, None]).astype(logits_dtype) \
         * mask[:, None].astype(logits_dtype)
 
-    e_ctx = syn0[contexts].astype(compute_dtype)                      # [B, C, D]
-    ctx_m = ctx_mask.astype(compute_dtype)[..., None]
-    ctx_n = jnp.maximum(ctx_mask.sum(axis=-1), 1.0).astype(compute_dtype)  # [B]
-    hidden = (e_ctx * ctx_m).sum(axis=1) / ctx_n[:, None]             # [B, D]
+    # named scopes are metadata for a profile's reader (docs/observability.md
+    # §4), the same five on both CBOW step forms; the compiled step is the
+    # same program without them (tested)
+    with jax.named_scope("cbow.gather"):
+        e_ctx = syn0[contexts].astype(compute_dtype)                  # [B, C, D]
+    with jax.named_scope("cbow.context_sum"):
+        ctx_m = ctx_mask.astype(compute_dtype)[..., None]
+        ctx_n = jnp.maximum(ctx_mask.sum(axis=-1), 1.0).astype(compute_dtype)  # [B]
+        hidden = (e_ctx * ctx_m).sum(axis=1) / ctx_n[:, None]         # [B, D]
 
-    e_out = syn1[centers].astype(compute_dtype)                       # [B, D]
-    Z = syn1[negatives].astype(compute_dtype)                         # [P, D]
-    f_pos = jnp.sum(hidden * e_out, axis=-1).astype(jnp.float32)
-    f_neg = (hidden @ Z.T).astype(logits_dtype)                       # [B, P] — MXU
+    with jax.named_scope("cbow.gather"):
+        e_out = syn1[centers].astype(compute_dtype)                   # [B, D]
+        Z = syn1[negatives].astype(compute_dtype)                     # [P, D]
+    with jax.named_scope("cbow.pool_matmul"):
+        f_pos = jnp.sum(hidden * e_out, axis=-1).astype(jnp.float32)
+        f_neg = (hidden @ Z.T).astype(logits_dtype)                   # [B, P] — MXU
 
     has_ctx = (ctx_mask.sum(axis=-1) > 0).astype(jnp.float32)
     g_pos = (1.0 - _sigmoid(f_pos, sigmoid_mode)) * alpha * mask * has_ctx
@@ -914,22 +921,27 @@ def cbow_step_shared_core(
              * has_ctx[:, None].astype(logits_dtype)
              * jnp.asarray(num_negatives / P, logits_dtype))
 
-    gp = g_pos[:, None].astype(compute_dtype)
-    gn = g_neg.astype(compute_dtype)
-    d_hidden = gp * e_out + gn @ Z                                    # [B, D] — MXU
-    d_out = gp * hidden
-    d_Z = gn.T @ hidden                                               # [P, D] — MXU
+    with jax.named_scope("cbow.pool_matmul"):
+        gp = g_pos[:, None].astype(compute_dtype)
+        gn = g_neg.astype(compute_dtype)
+        d_hidden = gp * e_out + gn @ Z                                # [B, D] — MXU
+        d_out = gp * hidden
+        d_Z = gn.T @ hidden                                           # [P, D] — MXU
     if stabilizers is not None and stabilizers.update_clip:
         d_hidden = clip_update_rows(d_hidden, stabilizers.update_clip)
         d_out = clip_update_rows(d_out, stabilizers.update_clip)
-    # mean convention: each context word gets d_hidden / |context|
-    d_ctx = (d_hidden / ctx_n[:, None])[:, None, :] * ctx_m
+    with jax.named_scope("cbow.context_sum"):
+        # mean convention: each context word gets d_hidden / |context|
+        d_ctx = (d_hidden / ctx_n[:, None])[:, None, :] * ctx_m
 
     dtype = syn0.dtype
     D = syn0.shape[1]
-    new_syn0 = syn0.at[contexts.reshape(-1)].add(d_ctx.reshape(-1, D).astype(dtype))
-    new_syn1 = syn1.at[centers].add(d_out.astype(dtype))
-    new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
+    with jax.named_scope("cbow.scatter_syn0"):
+        new_syn0 = syn0.at[contexts.reshape(-1)].add(
+            d_ctx.reshape(-1, D).astype(dtype))
+    with jax.named_scope("cbow.scatter_syn1"):
+        new_syn1 = syn1.at[centers].add(d_out.astype(dtype))
+        new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
     if stabilizers is not None and stabilizers.post_pass:
         V = syn0.shape[0]
         enable = (mask.sum() > 0).astype(jnp.float32)

@@ -130,6 +130,40 @@ def test_cumsum_rows_matches_numpy():
             rtol=1e-5, atol=1e-5)
 
 
+def test_prefix_matmuls_ask_the_mxu_for_what_they_need():
+    """The CPU computes a float32 matmul exactly and cannot show what the TPU
+    does at the default precision (bfloat16 operands: the banded backward's
+    rounded difference array then never cancels; seen on the v5e, PR 27). What
+    can be held here is what the lowered matmuls ask for: HIGHEST, but for the
+    forward prefix over rows already rounded to bfloat16, which is one pass."""
+    x = jnp.zeros((300, 8), jnp.float32)
+
+    def precisions(fn, *args):
+        text = jax.jit(fn).lower(*args).as_text()
+        return ["HIGHEST" in l for l in text.splitlines() if "dot_general" in l]
+
+    assert precisions(cumsum_rows, x) == [True]
+    assert precisions(lambda a: cumsum_rows(a, one_pass=True), x) == [False]
+    tokens = jnp.arange(40, dtype=jnp.int32)
+    ones, one = jnp.ones(40, jnp.float32), jnp.ones(40, jnp.int32)
+    table = jnp.zeros((64, 8), jnp.float32)
+
+    def step(compute_dtype):
+        return lambda t: cbow_step_banded_core(
+            EmbeddingPair(t, t), tokens, one, 0 * one, ones, ones, tokens[:4],
+            jnp.float32(0.1), 5, 3, compute_dtype=compute_dtype,
+            logits_dtype=compute_dtype)
+
+    # the two prefix sums are the step's only [128, 128] matmuls
+    for compute_dtype, want in ((jnp.bfloat16, [False, True]),
+                                (jnp.float32, [True, True])):
+        text = jax.jit(step(compute_dtype)).lower(table).as_text()
+        got = ["HIGHEST" in l for l in text.splitlines()
+               if "dot_general" in l and "128x128" in l]
+        assert got == want, (compute_dtype, got)
+        assert ("reduce_precision" in text) == (compute_dtype == jnp.bfloat16)
+
+
 def test_halo_blocks_cover_every_token_once():
     rng = np.random.default_rng(1)
     ktoks, starts = _kept_stream(rng, 50, 30, 12)

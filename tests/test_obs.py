@@ -630,7 +630,8 @@ def _hlo_without_metadata(fn, args, monkeypatch, scoped: bool) -> str:
     jax.clear_caches()      # the inner jits' traces carry the scopes
     text = jax.jit(fn).lower(*args).compile().as_text()
     monkeypatch.undo()
-    assert ("sgns.scatter_syn0" in text or "scan.topk" in text) == scoped
+    assert any(name in text for name in (
+        "sgns.scatter_syn0", "cbow.scatter_syn0", "scan.topk")) == scoped
     # metadata is each instruction's ``metadata={...}`` and the module's
     # tables of the files, functions and stack frames those point into
     text = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n"
@@ -693,8 +694,98 @@ def _gather_topk_mixed_case():
     return _gather_topk_case(mixed=True)
 
 
+CBOW_SCOPES = ("cbow.gather", "cbow.context_sum", "cbow.pool_matmul",
+               "cbow.scatter_syn0", "cbow.scatter_syn1")
+
+
+def _cbow_step_case(form):
+    """One CBOW step of either form over the same block of tokens."""
+    import jax.numpy as jnp
+    from glint_word2vec_tpu.ops.cbow_banded import cbow_step_banded_core
+    from glint_word2vec_tpu.ops.sgns import cbow_step_shared_core
+    V, D, T, P, W = 96, 16, 32, 8, 3
+    rng = np.random.default_rng(2)
+    kw = dict(compute_dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16)
+
+    def scatter(syn0, syn1, tokens, contexts, ctx_mask, negs, alpha):
+        return cbow_step_shared_core(
+            EmbeddingPair(syn0, syn1), tokens, contexts, ctx_mask,
+            jnp.ones(T, jnp.float32), negs, alpha, 5, **kw)
+
+    def banded(syn0, syn1, tokens, left, right, negs, alpha):
+        ones = jnp.ones(T, jnp.float32)
+        return cbow_step_banded_core(
+            EmbeddingPair(syn0, syn1), tokens, left, right, ones, ones, negs,
+            alpha, 5, W, **kw)
+
+    tables = (jnp.asarray(rng.standard_normal((V, D)), jnp.float32),
+              jnp.asarray(rng.standard_normal((V, D)), jnp.float32),
+              jnp.asarray(rng.integers(0, V, T), jnp.int32))
+    tail = (jnp.asarray(rng.integers(0, V, P), jnp.int32), jnp.float32(0.025))
+    if form == "scatter":
+        return scatter, tables + (
+            jnp.asarray(rng.integers(0, V, (T, 2 * W)), jnp.int32),
+            jnp.asarray(rng.integers(0, 2, (T, 2 * W)), jnp.float32)) + tail
+    pos = np.arange(T)
+    return banded, tables + (
+        jnp.asarray(np.minimum(pos, 2), jnp.int32),
+        jnp.asarray(np.minimum(T - 1 - pos, 1), jnp.int32)) + tail
+
+
+def _cbow_scatter_step_case():
+    return _cbow_step_case("scatter")
+
+
+def _cbow_banded_step_case():
+    return _cbow_step_case("banded")
+
+
+@pytest.mark.parametrize("form", ["scatter", "banded"])
+def test_both_cbow_step_forms_carry_the_five_device_scopes(form):
+    """The scopes a profile's reader finds on a CBOW step, whichever form ran
+    (docs/observability.md §4): in the lowered text's locations."""
+    fn, args = _cbow_step_case(form)
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    assert [s for s in CBOW_SCOPES if s not in text] == []
+
+
+def _toy_cbow_fit(update, **kw):
+    """A toy CBOW fit whose producer runs on the calling thread (prefetch off)."""
+    trainer, sents = _toy_trainer(n=60, cbow=True, cbow_update=update,
+                                  negative_pool=8, **kw)
+    beats = []
+    trainer.fit(sents, on_heartbeat=beats.append)
+    assert beats
+
+
+@pytest.mark.parametrize("update", ["scatter", "banded"])
+def test_cbow_pack_span_is_recorded_with_its_args_only_when_on(update, tmp_path):
+    from glint_word2vec_tpu.obs.spans import default_tracer
+    tracer = default_tracer()
+    tracer.clear()
+    _toy_cbow_fit(update)
+    assert not tracer.enabled and tracer.events() == []
+    _toy_cbow_fit(update, telemetry_path=str(tmp_path / "t.jsonl"))
+    try:
+        events = tracer.events()
+        packs = [e for e in events if e["name"] == "producer.cbow_pack"]
+        assert packs and all(set(e["args"]) == {"examples", "context_rows"}
+                             for e in packs)
+        assert sum(e["args"]["examples"] for e in packs) > 0
+        assert (sum(e["args"]["context_rows"] for e in packs)
+                >= sum(e["args"]["examples"] for e in packs))
+        # inside the feed's producer span, on the thread that ran it
+        producers = {e["id"] for e in events if e["name"] == "producer"}
+        assert all(e["parent"] in producers for e in packs)
+    finally:
+        tracer.configure(enabled=False)
+        tracer.attach_phases(None)
+        tracer.clear()
+
+
 @pytest.mark.parametrize("case", [_sgns_shared_step_case, _cosine_topk_case,
-                                  _gather_topk_case, _gather_topk_mixed_case])
+                                  _gather_topk_case, _gather_topk_mixed_case,
+                                  _cbow_scatter_step_case, _cbow_banded_step_case])
 def test_named_scopes_change_metadata_only(case, monkeypatch):
     """The compiled step and scan with the scopes are the programs without
     them, up to metadata: what lets a PR that adds scopes say the device
