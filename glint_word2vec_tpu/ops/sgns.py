@@ -214,6 +214,84 @@ def hot_flush(mat: jax.Array, slab: jax.Array) -> jax.Array:
     return mat.at[:k].add(slab.astype(mat.dtype))
 
 
+def run_positions(idx: jax.Array, max_run: int) -> jax.Array:
+    """Position of every entry inside its run of equal neighbouring ``idx``
+    (int32 [N]; 0 = the run's head). A run longer than ``max_run`` is cut into
+    pieces of ``max_run``, each with a head of its own, so :func:`run_sums`
+    needs ``max_run − 1`` shifts whatever the input holds (equal neighbouring
+    words, a masked tail of zeros). Cheap 1-D work on ``idx`` alone."""
+    at = jnp.arange(idx.shape[0], dtype=jnp.int32)
+    first = jnp.concatenate([jnp.ones((1,), jnp.bool_), idx[1:] != idx[:-1]])
+    # distance to the last change of idx, cut every max_run
+    return (at - jax.lax.cummax(jnp.where(first, at, 0))) % max_run
+
+
+def run_sums(rows: jax.Array, pos: jax.Array, max_run: int,
+             dtype: jnp.dtype) -> jax.Array:
+    """``[N, D]`` in ``dtype`` holding AT each run's head (``pos == 0``) the
+    sum of the run's ``rows`` (the other rows hold partial sums nobody reads):
+    ``max_run − 1`` shifted, masked adds. No sort, no scatter, no matmul (a
+    prefix sum through the MXU rounds float32 to bfloat16 at the TPU's default
+    precision): each sum is the plain left-to-right sum of the run's rows,
+    each cast to ``dtype`` first.
+
+    The shifts are static slices of ONE zero-padded copy: the TPU compiler
+    fuses them, the casts and the adds into a single pass that writes the
+    result once (``jnp.roll`` instead materialises every shifted block)."""
+    n = rows.shape[0]
+    # the padding's position 0 belongs to no run headed before it
+    pos_pad = jnp.pad(pos, (0, max_run - 1))
+    rows_pad = jnp.pad(rows, ((0, max_run - 1), (0, 0)))
+    sums = rows.astype(dtype)
+    for j in range(1, max_run):
+        # row i + j belongs to the run headed at i exactly when its position is j
+        member = pos_pad[j:j + n] == j
+        sums = sums + jnp.where(member[:, None],
+                                rows_pad[j:j + n].astype(dtype),
+                                jnp.zeros((), dtype))
+    return sums
+
+
+def scatter_add_by_runs(mat: jax.Array, idx: jax.Array, rows: jax.Array,
+                        max_run: int, cap: int) -> Tuple[jax.Array, jax.Array]:
+    """``mat.at[idx].add(rows)`` that hands the scatter ONE row per run of
+    equal neighbouring ``idx``: ``(new_mat, rows_handed_over)``.
+
+    XLA's TPU scatter is priced per update row, ~100 ns each into
+    f32[3000000,384], whether rows repeat or are dropped out of bounds (PERF.md
+    §6, PR 28: 65,536 rows 6.65 ms, 24,576 rows 2.52 ms, 65,536 rows with 74%
+    sent out of bounds 6.66 ms). So the runs are summed first
+    (:func:`run_sums`), the heads are compacted to a static ``cap`` rows (a
+    sort of their positions; ``jnp.nonzero`` is itself a 65,536-row scatter)
+    and only those reach the scatter, the padding dropped out of bounds.
+
+    The native pair feed emits a center's pairs consecutively
+    (native/pairgen.cpp), so by ``centers`` a batch has ~1/4 as many heads as
+    pairs at window 5; pairs sorted by context would give the same for syn1.
+    The step decides from its own batch: one with more heads than ``cap``
+    (rows that all differ, a feed that does not emit runs) takes the plain
+    scatter, the same op on the same ``rows`` as without this function. A row
+    of ``mat`` receives the same sum of the same ``rows`` either way;
+    coalesced, the float additions run over the run first, then into the row."""
+    n, v = idx.shape[0], mat.shape[0]
+    pos = run_positions(idx, max_run)
+    head = pos == 0
+    heads = head.sum(dtype=jnp.int32)
+
+    def coalesced(mat):
+        sums = run_sums(rows, pos, max_run, mat.dtype)
+        at = jnp.sort(jnp.where(head, jnp.arange(n, dtype=jnp.int32), n))[:cap]
+        src = jnp.minimum(at, n - 1)
+        return mat.at[jnp.where(at < n, idx[src], v)].add(sums[src], mode="drop")
+
+    def plain(mat):
+        return mat.at[idx].add(rows.astype(mat.dtype))
+
+    fits = heads <= cap
+    return (jax.lax.cond(fits, coalesced, plain, mat),
+            jnp.where(fits, heads, n).astype(jnp.float32))
+
+
 class EmbeddingPair(NamedTuple):
     """The two trainable matrices: input (syn0) and output (syn1neg) embeddings —
     the reference's ``BigWord2VecMatrix`` pair (G2, README.md:69)."""
@@ -229,6 +307,9 @@ class StepMetrics(NamedTuple):
     loss: jax.Array       # masked mean SGNS loss
     mean_f_pos: jax.Array  # mean positive dot product (gradient-health signal)
     pairs: jax.Array      # number of real (unmasked) pairs in the batch
+    # update rows that reached syn0's scatter with a live index (the shared-pool
+    # SGNS step: B plain, one per center run coalesced); None = not counted
+    syn0_rows: Optional[jax.Array] = None
 
 
 def init_embeddings(
@@ -597,9 +678,18 @@ def sgns_step_shared_core(
     fused: bool = False,
     bf16_chain: bool = False,
     hot_slabs: Optional[Tuple[jax.Array, jax.Array]] = None,
+    center_runs: Optional[Tuple[int, int]] = None,
 ):
     """:func:`sgns_step_shared` with the pool supplied by the caller (see
     :func:`sgns_step_core` for why sampling lives outside the jitted scan).
+
+    ``center_runs`` ``(max_run, cap)``: syn0's update goes through
+    :func:`scatter_add_by_runs` — the pair feed emits a center's pairs
+    consecutively, so a batch hands the scatter one summed row per center run
+    (about a quarter of B at window 5) where it holds at most ``cap`` runs,
+    and takes the plain scatter where it does not. The trainer derives both
+    numbers from ``config.window``; nothing else in the step changes, and it
+    is not applied beside ``hot_slabs``.
 
     ``fused``/``bf16_chain`` (config.fused_logits / config.bf16_chain —
     ISSUE 14): the fused coefficient chain and the f32-accumulating dot
@@ -715,9 +805,15 @@ def sgns_step_shared_core(
         with jax.named_scope("sgns.scatter_syn1"):
             new_syn1, slab1 = hot_scatter_add(syn1, slab1, contexts, d_pos)
             new_syn1, slab1 = hot_scatter_add(new_syn1, slab1, negatives, d_Z)
+        syn0_rows = None  # the slab takes part of the rows: not counted
     else:
         with jax.named_scope("sgns.scatter_syn0"):
-            new_syn0 = syn0.at[centers].add(d_in.astype(dtype))
+            if center_runs is None:
+                new_syn0 = syn0.at[centers].add(d_in.astype(dtype))
+                syn0_rows = jnp.float32(centers.shape[0])
+            else:
+                new_syn0, syn0_rows = scatter_add_by_runs(
+                    syn0, centers, d_in, *center_runs)
         with jax.named_scope("sgns.scatter_syn1"):
             new_syn1 = syn1.at[contexts].add(d_pos.astype(dtype))
             new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
@@ -742,6 +838,7 @@ def sgns_step_shared_core(
         loss=loss,
         mean_f_pos=mean_f_pos,
         pairs=mask.sum(),
+        syn0_rows=syn0_rows,
     )
     if hot_slabs is not None:
         return EmbeddingPair(new_syn0, new_syn1), metrics, (slab0, slab1)

@@ -76,6 +76,23 @@ def _cbow_examples_per_kept_token(window: int) -> float:
     return max((window - 1) / window, 1e-3)
 
 
+def _center_run_cap(window: int, batch: int) -> int:
+    """Static row cap of the step's coalesced syn0 scatter
+    (ops/sgns.scatter_add_by_runs), 0 = do not build it. The pair feed emits a
+    kept token's pairs consecutively, so a batch holds one center run per
+    token that emits any pair: (window−1)/window of the kept tokens over
+    :func:`_pairs_per_kept_token` pairs each, 0.25 runs a pair at window 5
+    (0.262 measured: sentence ends clip windows). The cap is that share with
+    40% of room, in eighths of the batch (24,576 of 65,536 at window 5); where
+    a run holds two pairs or fewer on average (window ≤ 2) it is not built."""
+    runs_per_pair = (_cbow_examples_per_kept_token(window)
+                     / _pairs_per_kept_token(window))
+    if runs_per_pair > 0.5 or batch < 8:
+        return 0
+    eighth = batch // 8
+    return -(-int(1.4 * runs_per_pair * batch) // eighth) * eighth
+
+
 @dataclass
 class HeartbeatRecord:
     words: int
@@ -1011,13 +1028,23 @@ class Trainer:
                     logits_dtype, with_metrics, stabilizers=stab,
                     fused=fused, bf16_chain=chain, sync_every=cfg.sync_every)
             else:
+                # syn0's update, one scatter row per center run (the step
+                # chooses per batch; ops/sgns.scatter_add_by_runs), where one
+                # program sees the batch whole: a batch split over a data axis
+                # or fed in per-process segments cuts runs at every seam
+                runs = None
+                if plan.num_data == 1 and self._feed_segments == 1:
+                    cap = _center_run_cap(cfg.window, cfg.pairs_per_batch)
+                    runs = (2 * cfg.window, cap) if cap else None
+
                 def inner(params, batch, negatives, alpha):
                     return sgns_step_shared_core(
                         params, batch["centers"], batch["contexts"],
                         batch["mask"], negatives, alpha, cfg.negatives,
                         cfg.sigmoid_mode, compute_dtype,
                         cfg.duplicate_scaling, logits_dtype, with_metrics,
-                        stabilizers=stab, fused=fused, bf16_chain=chain)
+                        stabilizers=stab, fused=fused, bf16_chain=chain,
+                        center_runs=runs)
 
                 if hot_k:
                     def inner_hot(params, slabs, batch, negatives, alpha):
@@ -3259,9 +3286,15 @@ class Trainer:
             # disallows, reachable here only on heartbeat rounds (which the
             # audit's scripted fits are too short to hit; tests/test_obs.py
             # runs a probing fit under the guard to keep this path honest)
-            with self._tracer.span("device_block"):
-                loss_k, fpos_k = jax.device_get(
-                    (metrics.loss, metrics.mean_f_pos))
+            with self._tracer.span("device_block") as blocked:
+                loss_k, fpos_k, pairs_k, rows_k = jax.device_get(
+                    (metrics.loss, metrics.mean_f_pos, metrics.pairs,
+                     metrics.syn0_rows))
+                if rows_k is not None and pairs_k[real - 1] > 0:
+                    # how far the step coalesced syn0's update: 1.0 plain,
+                    # heads over pairs where center runs were summed first
+                    blocked.set(syn0_rows_per_pair=float(
+                        rows_k[real - 1] / pairs_k[real - 1]))
             # per-phase attribution over THIS heartbeat window (obs/
             # phases.py): delta of the accumulator the spans + wait sites
             # have been feeding since the previous heartbeat

@@ -1,0 +1,325 @@
+"""syn0's update coalesced by center run (ops/sgns.scatter_add_by_runs).
+
+The pair feed emits a center's pairs consecutively, so the shared-pool SGNS
+step sums each run of equal neighbouring centers first and hands the scatter
+one row a run, where a batch holds at most ``cap`` runs; a batch with more
+takes the plain scatter, bit for bit. Every case runs in float32 and in the
+benchmark cell's bfloat16 compute dtype (the tables stay float32).
+"""
+
+import os
+import shutil
+import sys
+import tempfile
+from dataclasses import replace as dc_replace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from glint_word2vec_tpu.config import Word2VecConfig
+from glint_word2vec_tpu.data.pipeline import epoch_batches
+from glint_word2vec_tpu.data.vocab import Vocabulary
+from glint_word2vec_tpu.ops.sgns import (
+    EmbeddingPair,
+    Stabilizers,
+    run_positions,
+    scatter_add_by_runs,
+    sgns_step_shared_core,
+)
+from glint_word2vec_tpu.train import trainer as trainer_mod
+from glint_word2vec_tpu.train.trainer import Trainer, _center_run_cap
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from reference import sgns_ref  # noqa: E402
+
+V, D, B, P, WINDOW = 2000, 24, 1024, 32, 5
+MAX_RUN = 2 * WINDOW
+CAP = _center_run_cap(WINDOW, B)
+ALPHA, NEG = 0.05, 5
+
+
+def _vocab_and_sentences(seed=0, n_tokens=40_000):
+    counts = np.maximum(1e6 / (np.arange(V) + 10.0) ** 1.07, 5.0)
+    vocab = Vocabulary.from_words_and_counts(
+        [f"w{i}" for i in range(V)], counts.astype(np.int64))
+    rng = np.random.default_rng(seed)
+    toks = rng.choice(V, n_tokens, p=counts / counts.sum()).astype(np.int32)
+    return vocab, [toks[i:i + 40] for i in range(0, n_tokens, 40)]
+
+
+def feed_batches(steps=3):
+    """Batches as the trainer gets them: the pair feed (native where it
+    builds) over a Zipf corpus at window 5, never shuffled below sentences."""
+    vocab, sents = _vocab_and_sentences()
+    out = []
+    for b in epoch_batches(sents, vocab, pairs_per_batch=B, window=WINDOW,
+                           subsample_ratio=0.0, seed=1, iteration=1):
+        out.append((b.centers.astype(np.int32), b.contexts.astype(np.int32)))
+        if len(out) == steps:
+            return out
+    raise AssertionError("corpus too small")
+
+
+def _tables(seed=5):
+    rng = np.random.default_rng(seed)
+    return EmbeddingPair(jnp.asarray(rng.uniform(-0.3, 0.3, (V, D)), jnp.float32),
+                         jnp.asarray(rng.uniform(-0.3, 0.3, (V, D)), jnp.float32))
+
+
+def _rows(dtype, seed=9):
+    return jnp.asarray(np.random.default_rng(seed).normal(0, 0.01, (B, D)), dtype)
+
+
+def _add_at(idx, rows):
+    want = np.zeros((V, D), np.float64)
+    np.add.at(want, np.asarray(idx), np.asarray(rows, np.float64))
+    return want
+
+
+def _helper_against_add_at(idx, dtype, expect_rows=None, rows=None):
+    """scatter_add_by_runs on a zero table against np.add.at in float64."""
+    idx = jnp.asarray(idx, jnp.int32)
+    rows = _rows(dtype) if rows is None else rows
+    got, handed = jax.jit(scatter_add_by_runs, static_argnums=(3, 4))(
+        jnp.zeros((V, D), jnp.float32), idx, rows, MAX_RUN, CAP)
+    want = _add_at(idx, rows)
+    # float32 sums of up to B rows: to rounding of the largest entry
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-6,
+                               atol=2e-6 * np.abs(want).max())
+    if expect_rows is not None:
+        assert int(handed) == expect_rows
+    return int(handed)
+
+
+def _step(params, c, x, mask, dtype, runs, **kw):
+    negs = jnp.asarray(np.random.default_rng(11).integers(0, V, P), jnp.int32)
+    fn = jax.jit(lambda p: sgns_step_shared_core(
+        p, jnp.asarray(c), jnp.asarray(x), jnp.asarray(mask, jnp.float32), negs,
+        jnp.float32(ALPHA), NEG, "exact", dtype, logits_dtype=dtype,
+        center_runs=runs, **kw))
+    # strict bfloat16: by default XLA may keep a fused bfloat16 value in float32
+    # ("excess precision"), and the two programs fuse differently, so their
+    # update rows would differ by a rounding that neither step asks for
+    strict = fn.lower(params).compile(
+        compiler_options={"xla_allow_excess_precision": False})
+    return strict(params)
+
+
+def _both(c, x, mask, dtype, **kw):
+    params = _tables()
+    plain, m0 = _step(params, c, x, mask, dtype, None, **kw)
+    runs, m1 = _step(params, c, x, mask, dtype, (MAX_RUN, CAP), **kw)
+    return params, plain, m0, runs, m1
+
+
+def _close_to_plain(params, plain, runs):
+    """The coalesced update against the plain one: the same per-pair terms,
+    float32 additions in another order. syn1 is not touched by the change."""
+    np.testing.assert_array_equal(np.asarray(runs.syn1), np.asarray(plain.syn1))
+    want = np.asarray(plain.syn0) - np.asarray(params.syn0)
+    got = np.asarray(runs.syn0) - np.asarray(params.syn0)
+    assert np.linalg.norm(got - want) <= 1e-5 * np.linalg.norm(want)
+
+
+# ---- the cases ------------------------------------------------------------------------
+
+def case_native_feed_against_add_at(dtype):
+    for c, _ in feed_batches():
+        heads = 1 + int((c[1:] != c[:-1]).sum())
+        assert heads < 0.4 * B          # the feed does emit runs
+        handed = _helper_against_add_at(c, dtype)
+        assert heads <= handed <= CAP   # forced heads may add a few
+
+
+def case_native_feed_against_reference(dtype):
+    """Three steps on feed batches against benchmark/reference/sgns_ref on the
+    touched rows, as ``correct`` compares them: float32 to rounding, bfloat16
+    compute by the change norms."""
+    batches = feed_batches()
+    rng = np.random.default_rng(11)
+    negs = rng.integers(0, V, (len(batches), P)).astype(np.int32)
+    params = _tables()
+    init = params
+    step = jax.jit(lambda p, c, x, n: sgns_step_shared_core(
+        p, c, x, jnp.ones(B, jnp.float32), n, jnp.float32(ALPHA), NEG, "exact",
+        dtype, logits_dtype=dtype, center_runs=(MAX_RUN, CAP)))
+    with jax.default_matmul_precision("highest"):
+        for (c, x), n in zip(batches, negs):
+            params, metrics = step(params, jnp.asarray(c), jnp.asarray(x), jnp.asarray(n))
+            assert float(metrics.syn0_rows) < 0.4 * B
+    ref = sgns_ref.follow_steps(
+        init.syn0, init.syn1, [jnp.asarray(c) for c, _ in batches],
+        [jnp.asarray(x) for _, x in batches], jnp.asarray(negs),
+        [ALPHA] * len(batches), NEG)
+    got = (sgns_ref.leaf_norm(params.syn0 - init.syn0),
+           sgns_ref.leaf_norm(params.syn1 - init.syn1))
+    limit = 1e-5 if dtype == jnp.float32 else 5e-3
+    for g, w in zip(got, ref["change_norm"]):
+        assert abs(g - w) / w < limit
+
+
+def case_native_feed_step_against_plain(dtype):
+    for c, x in feed_batches():
+        params, plain, m0, runs, m1 = _both(c, x, np.ones(B), dtype)
+        _close_to_plain(params, plain, runs)
+        assert float(m0.syn0_rows) == B and float(m1.syn0_rows) < 0.4 * B
+        assert float(m0.loss) == float(m1.loss) and float(m1.pairs) == B
+
+
+def case_runs_longer_than_max_run(dtype):
+    # 25 pairs a center: every run is cut at 10 and 20, three heads a run
+    idx = np.repeat(np.arange(100, 100 + B // 25 + 1), 25)[:B]
+    pos = np.asarray(run_positions(jnp.asarray(idx, jnp.int32), MAX_RUN))
+    assert pos.max() == MAX_RUN - 1
+    _helper_against_add_at(idx, dtype, expect_rows=int((pos == 0).sum()))
+    assert int((pos == 0).sum()) == 3 * (B // 25) + 3
+
+
+def case_equal_neighbouring_words(dtype):
+    # "a a b": the two a's pairs merge into one run of 8, b's stay apart
+    idx = np.tile(np.repeat([7, 7, 9], 4), B // 12 + 1)[:B]
+    _helper_against_add_at(idx, dtype)
+
+
+def case_run_cut_by_batch_start_and_end(dtype):
+    # the batch opens inside a run and closes inside another: both are summed
+    # from what the batch holds, and nothing wraps from the end to the front
+    idx = np.concatenate([np.full(3, 41), np.repeat(np.arange(200, 200 + 254), 4),
+                          np.full(5, 41)])
+    assert idx.shape[0] == B
+    _helper_against_add_at(idx, dtype, expect_rows=256)
+
+
+def case_masked_tail(dtype):
+    c, x = feed_batches(1)[0]
+    real = 700
+    c, x = c.copy(), x.copy()
+    c[real:], x[real:] = 0, 0             # the batcher pads with zeros
+    mask = (np.arange(B) < real).astype(np.float32)
+    params, plain, m0, runs, m1 = _both(c, x, mask, dtype)
+    _close_to_plain(params, plain, runs)
+    assert float(m1.pairs) == real
+    # the tail is one run of row 0, cut every MAX_RUN, with zero updates
+    assert float(m1.syn0_rows) < 0.4 * real + (B - real) / MAX_RUN + 2
+
+
+def case_every_pair_masked(dtype):
+    z = np.zeros(B, np.int32)
+    params, plain, _, runs, m1 = _both(z, z, np.zeros(B), dtype)
+    np.testing.assert_array_equal(np.asarray(runs.syn0), np.asarray(params.syn0))
+    np.testing.assert_array_equal(np.asarray(runs.syn1), np.asarray(params.syn1))
+    assert float(m1.pairs) == 0 and float(m1.syn0_rows) == -(-B // MAX_RUN)
+
+
+def case_all_rows_different_bit_equal(dtype):
+    # the benchmark's check batches: a head on every row, the plain scatter taken
+    rng = np.random.default_rng(3)
+    c, x = rng.permutation(V)[:B], rng.permutation(V)[:B]
+    _, plain, _, runs, m1 = _both(c.astype(np.int32), x.astype(np.int32),
+                                     np.ones(B), dtype)
+    np.testing.assert_array_equal(np.asarray(runs.syn0), np.asarray(plain.syn0))
+    np.testing.assert_array_equal(np.asarray(runs.syn1), np.asarray(plain.syn1))
+    assert float(m1.syn0_rows) == B
+
+
+def case_one_word_in_every_slot(dtype):
+    idx = np.full(B, 17)
+    _helper_against_add_at(idx, dtype, expect_rows=-(-B // MAX_RUN))
+
+
+def case_heads_over_cap_bit_equal(dtype):
+    # runs of two: B/2 heads > cap, so today's scatter runs, bit for bit
+    idx = jnp.asarray(np.repeat(np.random.default_rng(4).permutation(V)[:B // 2], 2),
+                      jnp.int32)
+    assert B // 2 > CAP
+    rows = _rows(dtype)
+    table = _tables().syn0
+    got, handed = jax.jit(scatter_add_by_runs, static_argnums=(3, 4))(
+        table, idx, rows, MAX_RUN, CAP)
+    want = jax.jit(lambda t: t.at[idx].add(rows.astype(t.dtype)))(table)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(handed) == B
+
+
+def case_update_clip_and_duplicate_scaling(dtype):
+    # both act per pair, ahead of the run sum
+    c, x = feed_batches(1)[0]
+    for kw in (dict(stabilizers=Stabilizers(update_clip=0.002)),
+               dict(duplicate_scaling=True),
+               dict(stabilizers=Stabilizers(update_clip=0.002, max_row_norm=2.0),
+                    duplicate_scaling=True)):
+        params, plain, _, runs, m1 = _both(c, x, np.ones(B), dtype, **kw)
+        got = np.asarray(runs.syn0) - np.asarray(params.syn0)
+        want = np.asarray(plain.syn0) - np.asarray(params.syn0)
+        assert np.linalg.norm(got - want) <= 1e-5 * np.linalg.norm(want)
+        np.testing.assert_array_equal(np.asarray(runs.syn1), np.asarray(plain.syn1))
+        assert float(m1.syn0_rows) < 0.4 * B
+
+
+def case_both_step_twins_one_program_each(dtype):
+    """Through Trainer.fit: both twins coalesce, compile once, report the share
+    on the heartbeat's device_block span, and train what the plain step trains."""
+    vocab, sents = _vocab_and_sentences(n_tokens=30_000)
+    cfg = Word2VecConfig(
+        vector_size=D, window=WINDOW, negatives=NEG, min_count=1,
+        compute_dtype=jnp.dtype(dtype).name, logits_dtype=jnp.dtype(dtype).name,
+        pairs_per_batch=B, steps_per_dispatch=2, heartbeat_every_steps=4,
+        negative_pool=P, subsample_ratio=0.0, num_iterations=1, seed=1)
+
+    def fit(coalesce):
+        # spans are recorded under run telemetry; each fit clears the ring
+        run_dir = tempfile.mkdtemp(prefix="coalesce_")
+        cap = trainer_mod._center_run_cap
+        if not coalesce:
+            trainer_mod._center_run_cap = lambda window, batch: 0
+        try:
+            t = Trainer(dc_replace(cfg, telemetry_path=os.path.join(run_dir, "run.jsonl")),
+                        vocab)
+        finally:
+            trainer_mod._center_run_cap = cap
+        try:
+            t.fit(sents)
+            shares = [e["args"]["syn0_rows_per_pair"] for e in t._tracer.events()
+                      if e["name"] == "device_block" and e.get("args")]
+        finally:
+            shutil.rmtree(run_dir, ignore_errors=True)
+        return t, shares
+
+    start = jax.device_get(Trainer(cfg, vocab).params)
+    (on, on_shares), (off, off_shares) = fit(True), fit(False)
+    assert on._step_fn_fast is not on._step_fn
+    assert on._step_fn._cache_size() == 1 and on._step_fn_fast._cache_size() == 1
+    assert on.global_step == off.global_step and on.global_step >= 8
+    assert on_shares and max(on_shares) < 0.4
+    assert off_shares and min(off_shares) >= 1.0
+    # bfloat16 on the CPU: XLA keeps a fused bfloat16 update row in float32 in
+    # one program and rounds it in the other (see _step), a rounding apart
+    limit = 1e-4 if dtype == jnp.float32 else 5e-3
+    for a, b, s in zip(on.params, off.params, start):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.linalg.norm(a - b) <= limit * np.linalg.norm(b - s)
+
+
+CASES = {name[len("case_"):]: fn for name, fn in sorted(globals().items())
+         if name.startswith("case_")}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_coalesced_update(case, dtype):
+    CASES[case](dtype)
+
+
+def test_cap_is_derived_from_the_window():
+    # window 5: 0.25 runs a pair, 40% of room, in eighths of the batch
+    assert _center_run_cap(5, 65536) == 24576
+    assert _center_run_cap(5, 2048) == 768
+    # a run of two pairs or fewer: not built
+    assert _center_run_cap(1, 65536) == 0 and _center_run_cap(2, 65536) == 0
+    assert 0 < _center_run_cap(10, 65536) < _center_run_cap(3, 65536) < 65536
