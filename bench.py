@@ -172,14 +172,14 @@ def eval_stable(rows: list, batch: int, pool: int, param_dtype: str,
 def bench_step(counts, b: int, pool: int, dtype: str = "float32",
                param_dtype: str = "float32", logits_dtype: str = "float32",
                v: int = V, label_extra: str = "", fused: bool = False,
-               chain: bool = False, hot_rows: int = 0) -> tuple:
+               chain: bool = False) -> tuple:
     import jax
     import jax.numpy as jnp
     from microbench import time_chunked
 
     from glint_word2vec_tpu.ops.sampler import build_alias_table, sample_negatives_hash
     from glint_word2vec_tpu.ops.sgns import (
-        EmbeddingPair, hot_flush, init_embeddings, sgns_step_shared_core)
+        EmbeddingPair, init_embeddings, sgns_step_shared_core)
 
     table = build_alias_table(counts)
     prob, alias = table.prob, table.alias
@@ -192,28 +192,6 @@ def bench_step(counts, b: int, pool: int, dtype: str = "float32",
 
     def chunk(params, batches, base_step, prob, alias):
         negs = sample_negatives_hash(prob, alias, 1234, base_step, (K, pool))
-
-        if hot_rows:
-            # the trainer's hot-row chunk shape (trainer._run_hot_scan at the
-            # AUTO cadence): slabs carried through the scan, ONE dense prefix
-            # flush at chunk end
-            slabs = (jnp.zeros((hot_rows, PAD_D), jnp.float32),
-                     jnp.zeros((hot_rows, PAD_D), jnp.float32))
-
-            def body_hot(carry, inp):
-                p, s = carry
-                batch, ng = inp
-                new_p, m, s = sgns_step_shared_core(
-                    p, batch["centers"], batch["contexts"], batch["mask"],
-                    ng, jnp.float32(0.025), NEG, "exact", cdt, False, ldt,
-                    with_metrics=False, fused=fused, bf16_chain=chain,
-                    hot_slabs=s)
-                return (new_p, s), m.loss
-
-            (p, (s0, s1)), losses = jax.lax.scan(
-                body_hot, (params, slabs), (batches, negs))
-            p = EmbeddingPair(hot_flush(p.syn0, s0), hot_flush(p.syn1, s1))
-            return p, losses
 
         def body(p, inp):
             batch, ng = inp
@@ -611,11 +589,8 @@ def main() -> None:
                                     logits_dtype="bfloat16")
     # ISSUE-14 step-restructuring rows at the headline geometry, LAYERED so
     # the trajectory shows which layer pays (PERF.md §11): the fused
-    # coefficient chain alone, + the end-to-end bf16 chain, + cross-step
-    # hot-row accumulation (K=4096 ≈ where the Zipf mass knee sits at
-    # V=200k; flush once per chunk, the trainer's AUTO cadence). Never the
-    # headline until their geometry carries its own EVAL evidence — the
-    # hot-row arm is gated by eval_quality --hotrow-ab.
+    # coefficient chain alone, + the end-to-end bf16 chain. Never the
+    # headline until their geometry carries its own EVAL evidence.
     bf16kw = dict(dtype="bfloat16", param_dtype="bfloat16",
                   logits_dtype="bfloat16")
     rows["bf16_fused"] = bench_step(
@@ -624,9 +599,6 @@ def main() -> None:
     rows["bf16_chain"] = bench_step(
         counts, B_MAIN, E2E_POOL, fused=True, chain=True,
         label_extra=" +fused+chain", **bf16kw)
-    rows["bf16_hot"] = bench_step(
-        counts, B_MAIN, E2E_POOL, fused=True, chain=True, hot_rows=4096,
-        label_extra=" +fused+chain+hot", **bf16kw)
     # CBOW rows at the same pool list as the SGNS step rows (comparable
     # geometry round to round): scatter (shipped default) and banded
     # (cbow_update="banded" — the ISSUE-2 prefix-sum path; step_ab.py --cbow
@@ -701,7 +673,6 @@ def main() -> None:
         # from the first rung that carries them)
         "step_fused_pairs_per_sec": round(rows["bf16_fused"][0]),
         "step_bf16_chain_pairs_per_sec": round(rows["bf16_chain"][0]),
-        "step_hotrow_pairs_per_sec": round(rows["bf16_hot"][0]),
         "v1m_step_pairs_per_sec": round(scale["step_bf16_pairs_per_sec"]),
         "cbow_examples_per_sec": round(cbow_rows[E2E_POOL][0]),
         "cbow_step_ms": round(cbow_rows[E2E_POOL][1], 3),

@@ -12,14 +12,13 @@ random weights from a seed and a corpus drawn from a seed. Depth is cut: a few
 dispatches, not an epoch of enwiki.
 
 Legs: (a) host-feed fit; (b) device_pairgen fit; (c) find_synonyms and
-find_synonyms_batch against a NumPy cosine top-k of get_vectors(); (d) the
-Pallas kernel, compiled, against the XLA shared-pool step; (e) leg (a) again
-on 1x4 and 2x2 meshes and under step_lowering="shard_map" when the machine
-has four devices. Any leg that raises fails the run.
+find_synonyms_batch against a NumPy cosine top-k of get_vectors(); (d) leg
+(a) again on 1x4 and 2x2 meshes and under step_lowering="shard_map" when the
+machine has four devices. Any leg that raises fails the run.
 
 No arguments = the real thing: it requires a TPU and exits non-zero without
-one. ``--tiny`` runs the same legs at toy width on whatever platform there is
-(Pallas interpreted on CPU), to debug the command before chip time is spent.
+one. ``--tiny`` runs the same legs at toy width on whatever platform there is,
+to debug the command before chip time is spent.
 
 Every time printed here is a SMOKE time — it says the run got through, it is
 not a metric. The last stdout line is one JSON object.
@@ -41,33 +40,18 @@ NUM_SYNONYMS = 10
 
 # window/negatives/dtypes are BASELINE config 3's and the same at both widths;
 # only the table, the batch and the corpus shrink under --tiny
-FULL = dict(v=1_000_000, d=300, b=65_536, k=16, tokens=2_400_000,
-            pallas_v=200_000, pallas_b=8192, pallas_d=384, pallas_pool=512,
-            pallas_k=2)
-TINY = dict(v=20_000, d=40, b=4096, k=4, tokens=200_000,
-            pallas_v=2048, pallas_b=256, pallas_d=128, pallas_pool=64,
-            pallas_k=2)
+FULL = dict(v=1_000_000, d=300, b=65_536, k=16, tokens=2_400_000)
+TINY = dict(v=20_000, d=40, b=4096, k=4, tokens=200_000)
 
 # cosine tolerance of leg (c), absolute: the tables are bf16 and the package
 # sets no matmul precision, so the device cosine carries bf16 rounding of the
 # normalized query, of the [Q, V] product and of the row norms (~3 x 2^-8
 # relative on values <= 1); the NumPy reference is exact float32.
 COSINE_TOL = 2e-2
-# first-heartbeat loss, sharded vs one chip (leg e): same batches and the same
+# first-heartbeat loss, sharded vs one chip (leg d): same batches and the same
 # math, but bf16 scatter-adds associate differently per layout, and 32 steps of
 # that drift a 65k-pair mean loss in the 3rd digit. 2% is ~10x the drift seen.
 SHARDED_LOSS_RTOL = 2e-2
-# leg (d), kernel vs XLA step: tests/test_pallas_kernel.py's tolerance
-# (rtol 1e-5, atol 1e-6), which the interpreted kernel meets. No allowance for
-# in-tile last-wins duplicates is needed: each step's batch is built
-# duplicate-free, the regime where the two semantics coincide (kernel
-# docstring). Compiled, the kernel's in-VMEM dots run at the MXU's default
-# precision (one bf16 pass, ~2^-8 relative on an update of ~1e-4) while the XLA
-# reference traces under matmul precision "highest": measured max |d| 1.8e-6
-# on the v5e, so the compiled allowance is atol 1e-5 (5x that), and 1e-3
-# relative on the batch-mean loss.
-PALLAS_RTOL, PALLAS_ATOL = 1e-5, 1e-6
-PALLAS_MXU_ATOL, PALLAS_MXU_LOSS_RTOL = 1e-5, 1e-3
 
 
 def log(msg: str = "") -> None:
@@ -292,70 +276,6 @@ def synonyms_leg(model, rng) -> None:
         f"{COSINE_TOL} (compile included: {wall:.1f}s)")
 
 
-def pallas_leg(spec: dict) -> None:
-    """One dispatch of the use_pallas=True trainer step against the XLA
-    shared-pool trainer step: same initial params, same duplicate-free batch,
-    same hash-PRNG negatives."""
-    import jax
-    import numpy as np
-
-    from bench import zipf_counts
-    from glint_word2vec_tpu.config import Word2VecConfig
-    from glint_word2vec_tpu.data.vocab import Vocabulary
-    from glint_word2vec_tpu.ops.sgns import EmbeddingPair
-    from glint_word2vec_tpu.train.trainer import Trainer
-
-    v, b, k = spec["pallas_v"], spec["pallas_b"], spec["pallas_k"]
-    log(f"--- leg d: Pallas kernel, one dispatch at V={v} B={b} "
-        f"d={spec['pallas_d']} pool={spec['pallas_pool']} (default tile)")
-    vocab = Vocabulary.from_words_and_counts(
-        [f"w{i}" for i in range(v)], zipf_counts(v).astype(np.int64))
-    common = dict(vector_size=spec["pallas_d"], min_count=1, negatives=5,
-                  pairs_per_batch=b, negative_pool=spec["pallas_pool"],
-                  steps_per_dispatch=k, seed=SEED)
-    kernel = Trainer(Word2VecConfig(use_pallas=True, **common), vocab)
-    xla = Trainer(Word2VecConfig(**common), vocab)
-    rng = np.random.default_rng(SEED)
-    pairs = np.stack([np.stack([rng.permutation(v)[:b], rng.permutation(v)[:b]])
-                      for _ in range(k)]).astype(kernel._pair_dtype)
-    meta = np.stack([np.full(k, 0.025, np.float32), np.full(k, b, np.float32)])
-    # nonzero syn1 (the init is zeros) so the negative branch does real math
-    syn0 = np.asarray(kernel.params.syn0)
-    syn1 = rng.normal(0.0, 0.05, syn0.shape).astype(np.float32)
-
-    def dispatch(trainer):
-        args = (
-            EmbeddingPair(jax.device_put(syn0, trainer.plan.embedding),
-                          jax.device_put(syn1, trainer.plan.embedding)),
-            {"pairs": jax.device_put(pairs, trainer.plan.pairs_stacked)},
-            meta, np.int32(1), trainer._table_prob, trainer._table_alias)
-        lowered = trainer._step_fn.lower(*args).as_text()
-        new_params, metrics = trainer._step_fn(*args)
-        return jax.tree.map(np.asarray, (new_params, metrics)), lowered
-
-    t0 = time.perf_counter()
-    (got_p, got_m), lowered = dispatch(kernel)
-    compiled = "tpu_custom_call" in lowered
-    if jax.default_backend() == "tpu":
-        assert compiled, "use_pallas=True lowered without a Mosaic custom call"
-    with jax.default_matmul_precision("highest"):
-        (want_p, want_m), _ = dispatch(xla)
-    atol = PALLAS_MXU_ATOL if compiled else PALLAS_ATOL
-    np.testing.assert_allclose(got_p.syn0, want_p.syn0,
-                               rtol=PALLAS_RTOL, atol=atol)
-    np.testing.assert_allclose(got_p.syn1, want_p.syn1,
-                               rtol=PALLAS_RTOL, atol=atol)
-    np.testing.assert_allclose(
-        got_m.loss, want_m.loss,
-        rtol=PALLAS_MXU_LOSS_RTOL if compiled else PALLAS_RTOL)
-    assert not np.allclose(got_p.syn0, syn0), "the kernel moved nothing"
-    log(f"smoke: kernel {'compiled (Mosaic)' if compiled else 'interpreted'}, "
-        f"max |d syn0| vs XLA "
-        f"{float(np.max(np.abs(got_p.syn0 - want_p.syn0))):.2e}, max |d syn1| "
-        f"{float(np.max(np.abs(got_p.syn1 - want_p.syn1))):.2e} "
-        f"({time.perf_counter() - t0:.1f}s, compile included)")
-
-
 def four_chip_leg(spec: dict, problem: dict, clog: CompileLog,
                   one_chip_loss: float) -> list:
     """Leg (a) again on sharded tables: 1x4 and 2x2 under the default lowering,
@@ -368,7 +288,7 @@ def four_chip_leg(spec: dict, problem: dict, clog: CompileLog,
     shapes = []
     for (nd, nm), lowering in (((1, 4), "gspmd"), ((2, 2), "gspmd"),
                                ((2, 2), "shard_map")):
-        out = fit_leg(f"e: host feed on {nd}x{nm}, {lowering}", spec, problem,
+        out = fit_leg(f"d: host feed on {nd}x{nm}, {lowering}", spec, problem,
                       clog, plan=make_mesh(nd, nm, devices=devices),
                       step_lowering=lowering)
         trainer = out["trainer"]
@@ -463,7 +383,6 @@ def main(argv=None) -> int:
     synonyms_leg(leg["model"], np.random.default_rng(SEED))
     leg["model"].stop()
     del leg  # the trainer's placed tables go with it
-    pallas_leg(spec)
     if len(jax.devices()) >= 4:
         shards = four_chip_leg(spec, problem, clog, one_chip_loss)
     else:

@@ -1,6 +1,6 @@
 """glint_word2vec_tpu — a TPU-native framework for very-large-vocabulary word2vec.
 
-A ground-up JAX/XLA/Pallas/pjit redesign of the capabilities of glint-word2vec
+A ground-up JAX/XLA/pjit redesign of the capabilities of glint-word2vec
 (Spark + Glint parameter servers, see /root/reference): skip-gram negative
 sampling (SGNS) and CBOW trained fully in-core on a TPU mesh.
 
@@ -24,8 +24,8 @@ Architecture (vs. the reference, cited as file:line into the reference repo):
 - Persistence keeps the reference's on-disk contract: matrix shards + a
   ``words`` one-word-per-line sidecar + params metadata (mllib:493-498,714-715).
 
-Module map: ``data/`` (vocab + host pipeline), ``ops/`` (SGNS/CBOW steps, sampler,
-pallas kernels), ``parallel/`` (mesh + sharding), ``models/`` (model & estimator API),
+Module map: ``data/`` (vocab + host pipeline), ``ops/`` (SGNS/CBOW steps,
+sampler), ``parallel/`` (mesh + sharding), ``models/`` (model & estimator API),
 ``train/`` (trainer, checkpoint).
 """
 

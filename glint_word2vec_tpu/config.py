@@ -2,7 +2,7 @@
 
 Mirrors the reference's 16-knob surface (mllib/feature/ServerSideGlintWord2Vec.scala:67-244,
 ml/feature/ServerSideGlintWord2Vec.scala:40-222) with the same semantics and defaults, plus
-TPU-native knobs the reference had no analog for (mesh shape, pair-batch size, dtype, pallas).
+TPU-native knobs the reference had no analog for (mesh shape, pair-batch size, dtype).
 
 Reference defaults (mllib:67-81,251): vectorSize 100, learningRate 0.01875, numPartitions 1,
 numIterations 1, minCount 5, maxSentenceLength 1000, window 5, batchSize 50, n 5,
@@ -19,7 +19,14 @@ effect here: there is no RPC.
 from __future__ import annotations
 
 import dataclasses
+import logging
 from typing import Optional, Tuple
+
+logger = logging.getLogger("glint_word2vec_tpu")
+
+# Fields Word2VecConfig no longer has, with the defaults they had: the stored
+# configs of older checkpoints still carry them (Word2VecConfig.from_dict).
+_RETIRED_KEYS = {"use_pallas": False, "hot_rows": 0, "hot_flush_every": 0}
 
 
 @dataclasses.dataclass
@@ -117,7 +124,7 @@ class Word2VecConfig:
                                     # deterministic, but the two lowerings are NOT
                                     # bit-identical to each other (different FP
                                     # reduction orders). Shared-pool skip-gram rows
-                                    # layout only (pool > 0, no cbow/pallas/
+                                    # layout only (pool > 0, no cbow/
                                     # duplicate_scaling/cols — refused at
                                     # construction). GSPMD stays the default until a
                                     # hardware A/B lands (the audited collective
@@ -232,7 +239,7 @@ class Word2VecConfig:
                                     # bit-identical (multiply association
                                     # changes). SGNS paths only (per-pair,
                                     # shared-pool GSPMD + shard_map); refused
-                                    # beside cbow/use_pallas/duplicate_scaling
+                                    # beside cbow/duplicate_scaling
     bf16_chain: bool = False        # end-to-end reduced-precision update chain:
                                     # the logit dots accumulate in
                                     # promote(compute, f32) via
@@ -246,43 +253,7 @@ class Word2VecConfig:
                                     # compute there is no chain to narrow) and,
                                     # on the shared-pool paths, logits_dtype=
                                     # 'bfloat16'. SGNS paths only; refused
-                                    # beside cbow/use_pallas
-    hot_rows: int = 0               # > 0: cross-step hot-row accumulation
-                                    # (ops/sgns.py hot_* helpers) — updates to
-                                    # the K most frequent rows (the vocabulary
-                                    # index prefix, by the sorted-by-frequency
-                                    # contract) accumulate in a float32 [K, D]
-                                    # slab across the steps of a dispatch chunk
-                                    # and flush as ONE dense block add per
-                                    # hot_flush_every steps, cutting the
-                                    # [V, D] scatter-emitter rows per step by
-                                    # the Zipf mass of the hot set. Reads stay
-                                    # exact (gathers add the pending deltas
-                                    # back), so this changes FP rounding order
-                                    # only — but that IS a semantic change at
-                                    # reduced precision, so it ships default-
-                                    # off behind the --hotrow-ab EVAL parity
-                                    # gate (tools/eval_quality.py). The
-                                    # trainer clamps K to the real vocabulary.
-                                    # Single-device SGNS XLA paths only:
-                                    # refused beside cbow/use_pallas/
-                                    # duplicate_scaling/shard_map/cols/multi-
-                                    # shard meshes/stabilizers (the post-
-                                    # scatter clamp would measure rows missing
-                                    # their pending deltas) and norm_watch=
-                                    # 'recover' (auto-engages the clamp)
-    hot_flush_every: int = 0        # hot_rows flush cadence in steps. 0
-                                    # (default) = AUTO: once per dispatch
-                                    # chunk (steps_per_dispatch). An explicit
-                                    # value must divide steps_per_dispatch —
-                                    # the slab lives in the chunk's scan
-                                    # carry, and every chunk flushes
-                                    # unconditionally at its end so the
-                                    # params carry leaving a dispatch is
-                                    # always complete (checkpoints/probes
-                                    # never see a pending slab). Inert when
-                                    # hot_rows=0
-    use_pallas: bool = False        # fused Pallas SGNS kernel for the hot step
+                                    # beside cbow
     sharded_checkpoint: bool = False  # row-shards save (each process writes its own
                                       # rows, no host gather — G9 analog); forced on
                                       # for multi-process runs
@@ -529,9 +500,8 @@ class Word2VecConfig:
     # response — the knobs the watchdog diagnostic used to recommend by hand).
     # ALL off by default: the 0.0 defaults elide every stabilizer op from the
     # compiled step, so the default step is bit-identical to pre-stabilizer
-    # releases (tested). Implemented on every XLA step path (per-pair, shared
-    # pool, both CBOW formulations, both sharded lowerings); refused beside
-    # use_pallas (the fused kernel owns its own update math).
+    # releases (tested). Implemented on every step path (per-pair, shared
+    # pool, both CBOW formulations, both sharded lowerings).
     max_row_norm: float = 0.0       # > 0: per-TOUCHED-row L2 clamp applied on
                                     # the update path after each step's
                                     # scatter (touched rows only — NEVER a
@@ -879,7 +849,6 @@ class Word2VecConfig:
         #   banded  × negative_pool=0   → refuse (banded is built on the
         #       shared-pool estimator; per-example pools would re-create the
         #       [B, n, D] row traffic the path exists to remove)
-        #   banded  × use_pallas        → refuse (pallas step is SGNS-only)
         #   banded  × tokens_per_step   → refuse (banded derives its block size
         #       from pairs_per_batch + window; the knob is device_pairgen's)
         #   banded  × window=1          → refuse (legacy window b=nextInt(1)=0
@@ -903,10 +872,6 @@ class Word2VecConfig:
                     "implemented on the scatter path (its per-context-set "
                     "occurrence counts have no banded form) — use "
                     "cbow_update='scatter'")
-            if self.use_pallas:
-                raise ValueError(
-                    "cbow_update='banded' is an XLA path; use_pallas=True "
-                    "(the fused SGNS kernel) does not apply to CBOW")
             if self.negative_pool == 0:
                 raise ValueError(
                     "cbow_update='banded' requires the shared-pool estimator "
@@ -922,37 +887,6 @@ class Word2VecConfig:
                     "cbow_update='banded' with window=1 emits no contexts at "
                     "all under the reference's legacy asymmetric window "
                     "(b = nextInt(1) = 0 always) — use window >= 2")
-        # --- pallas selection matrix (graftlint R8 refusal-matrix parity:
-        # trainer._build_step carries the dispatch-side twin of these two
-        # refusals; every combination refused there must be refused at
-        # construction too, so no checkpoint can ever store knobs the
-        # dispatch will later reject). Multi-device×pallas stays dispatch-
-        # only — it depends on the mesh plan, which config cannot see.
-        if self.use_pallas:
-            if self.cbow:
-                raise ValueError(
-                    "use_pallas=True is not implemented for CBOW — the fused "
-                    "kernel is SGNS-only; use the XLA CBOW paths "
-                    "(cbow_update='scatter'/'banded')")
-            if self.duplicate_scaling:
-                raise ValueError(
-                    "duplicate_scaling is not implemented for use_pallas=True "
-                    "— the fused kernel applies sum semantics only; use the "
-                    "XLA path or bound the row loads via "
-                    "negative_pool/subsample_ratio instead")
-            if self.max_row_norm or self.update_clip or self.row_l2:
-                raise ValueError(
-                    "the in-step stabilizers (max_row_norm/update_clip/"
-                    "row_l2) are not implemented for use_pallas=True — the "
-                    "fused kernel owns its own update math; use the XLA "
-                    "paths, which compile the stabilizers into every "
-                    "lowering (ops/sgns.py)")
-            if self.norm_watch == "recover":
-                raise ValueError(
-                    "norm_watch='recover' auto-engages max_row_norm, which "
-                    "the fused pallas kernel does not implement — use "
-                    "norm_watch='warn'/'halt' with use_pallas=True, or the "
-                    "XLA paths for auto-recovery")
         if (self.cbow and self.duplicate_scaling and self.negative_pool > 0):
             raise ValueError(
                 "CBOW with duplicate_scaling=True implements mean semantics "
@@ -966,15 +900,14 @@ class Word2VecConfig:
             if self.cbow and self.duplicate_scaling:
                 # mean semantics exist only on the per-example scatter path
                 self.negative_pool = 0
-            elif (self.pairs_per_batch < 4096 and not self.use_pallas
+            elif (self.pairs_per_batch < 4096
                     and self.cbow_update != "banded"
                     and self.step_lowering != "shard_map"):
                 # Small batches take the per-pair exact path (the reference's G3
                 # semantics): the shared pool's matmul amortization buys nothing at
                 # this scale, and shared negatives measurably cost quality on small
                 # corpora (the bf16 toy-corpus gate fails at B=256/P=128 but passes
-                # per-pair — tests/test_integration_toy.py). The pallas step needs a
-                # pool, so use_pallas keeps the load-rule resolution below. NB the
+                # per-pair — tests/test_integration_toy.py). NB the
                 # per-pair path always runs its logit chain in f32 (trainer.py);
                 # logits_dtype applies to the shared-pool paths.
                 self.negative_pool = 0
@@ -992,33 +925,17 @@ class Word2VecConfig:
         # trainer._build_step carries the dispatch-side twins — graftlint R8
         # refusal parity, graftcheck executes the empirical sweep). Every
         # unsupported combination is an ERROR here, never a silent fallback:
-        #   fused_logits × use_pallas          → refuse (pallas owns the step)
         #   fused_logits × cbow                → refuse (SGNS chains only; the
         #       CBOW chain keeps the classic form until its own EVAL evidence)
         #   fused_logits × duplicate_scaling   → refuse (mean semantics read
         #       the per-pair coefficient arrays the fusion eliminates)
-        #   bf16_chain   × use_pallas/cbow     → refuse (as above)
+        #   bf16_chain   × cbow                → refuse (as above)
         #   bf16_chain   × compute f32         → refuse (no chain to narrow)
         #   bf16_chain   × pool>0 + logits f32 → refuse (the [B, pool] chain
         #       would silently stay f32 — exactly the half-applied state the
         #       _build_step logits warning exists to avoid; per-pair pool=0
         #       has no logits_dtype surface and is exempt)
-        #   hot_rows     × use_pallas/cbow/duplicate_scaling → refuse
-        #   hot_rows     × shard_map/cols      → refuse (the hot slab is the
-        #       GLOBAL index prefix [0, K); under the rows layout it lives
-        #       entirely on model shard 0 — owner-local accumulation would
-        #       serialize every hot update onto one shard. Documented initial
-        #       refusal, docs/sharding.md)
-        #   hot_rows     × multi-shard mesh    → refuse (single-chip path
-        #       initially; the trainer also refuses a multi-device plan)
-        #   hot_rows     × stabilizers/recover → refuse (the post-scatter
-        #       clamp would measure rows missing their pending slab deltas)
-        #   hot_flush_every (explicit)         → must divide steps_per_dispatch
         if self.fused_logits:
-            if self.use_pallas:
-                raise ValueError(
-                    "fused_logits=True is an XLA-chain restructuring; "
-                    "use_pallas=True owns the whole step — drop one")
             if self.cbow:
                 raise ValueError(
                     "fused_logits=True is implemented for the SGNS logit "
@@ -1031,10 +948,6 @@ class Word2VecConfig:
                     "coefficient arrays the fused chain eliminates — use "
                     "the classic chain")
         if self.bf16_chain:
-            if self.use_pallas:
-                raise ValueError(
-                    "bf16_chain=True is an XLA-chain restructuring; "
-                    "use_pallas=True owns the whole step — drop one")
             if self.cbow:
                 raise ValueError(
                     "bf16_chain=True is implemented for the SGNS paths "
@@ -1051,83 +964,11 @@ class Word2VecConfig:
                     "logits_dtype='bfloat16': a float32 [B, pool] logit "
                     "chain would silently keep the dense traffic the knob "
                     "exists to remove")
-        if self.hot_rows < 0:
-            raise ValueError(
-                f"hot_rows must be nonnegative (0 = off) "
-                f"but got {self.hot_rows}")
-        if self.hot_flush_every < 0:
-            raise ValueError(
-                f"hot_flush_every must be nonnegative (0 = auto: once per "
-                f"dispatch chunk) but got {self.hot_flush_every}")
-        if self.hot_rows:
-            if self.use_pallas:
-                raise ValueError(
-                    "hot_rows is not implemented for use_pallas=True — the "
-                    "fused kernel owns its own update math; use the XLA "
-                    "SGNS paths")
-            if self.cbow:
-                raise ValueError(
-                    "hot_rows is implemented for the SGNS paths only; CBOW "
-                    "keeps the classic per-step scatters — set hot_rows=0")
-            if self.duplicate_scaling:
-                raise ValueError(
-                    "hot_rows does not support duplicate_scaling=True: "
-                    "mean-update scaling and cross-step slab accumulation "
-                    "compose into semantics nothing has EVAL evidence for — "
-                    "use one or the other")
-            if self.step_lowering == "shard_map":
-                raise ValueError(
-                    "hot_rows has no shard_map form: the hot slab is the "
-                    "global index prefix [0, K), which under the rows "
-                    "layout lives entirely on model shard 0 — owner-local "
-                    "accumulation would serialize every hot update onto one "
-                    "shard (documented refusal, docs/sharding.md); use "
-                    "step_lowering='gspmd' on a single device")
-            if self.embedding_partition == "cols":
-                raise ValueError(
-                    "hot_rows requires the rows layout (the slab is a "
-                    "whole-row prefix block); embedding_partition='cols' "
-                    "owns columns — use 'rows'")
-            if self.num_model_shards > 1 or self.num_data_shards > 1:
-                raise ValueError(
-                    "hot_rows is the single-chip step restructuring "
-                    "(PERF.md §11); multi-shard meshes keep the classic "
-                    "scatters — set hot_rows=0 or use a 1x1 mesh")
-            if self.mesh_shape is not None and tuple(self.mesh_shape) != (1, 1):
-                raise ValueError(
-                    "hot_rows is the single-chip step restructuring "
-                    f"(PERF.md §11); mesh_shape={self.mesh_shape} keeps the "
-                    "classic scatters — set hot_rows=0 or use (1, 1)")
-            if self.max_row_norm or self.update_clip or self.row_l2:
-                raise ValueError(
-                    "hot_rows is incompatible with the in-step stabilizers "
-                    "(max_row_norm/update_clip/row_l2): the post-scatter "
-                    "touched-row pass would measure hot rows missing their "
-                    "pending slab deltas — clamping a partial row is the "
-                    "silent-distortion class the stabilizers exist to "
-                    "prevent; use one or the other")
-            if self.norm_watch == "recover":
-                raise ValueError(
-                    "hot_rows is incompatible with norm_watch='recover' "
-                    "(the recovery ladder auto-engages max_row_norm, which "
-                    "has no hot-row form); use norm_watch='warn'/'halt' or "
-                    "hot_rows=0")
-            if self.hot_flush_every and (
-                    self.hot_flush_every > self.steps_per_dispatch
-                    or self.steps_per_dispatch % self.hot_flush_every):
-                raise ValueError(
-                    f"hot_flush_every={self.hot_flush_every} must divide "
-                    f"steps_per_dispatch={self.steps_per_dispatch}: the hot "
-                    f"slab lives in the dispatch chunk's scan carry and "
-                    f"every chunk flushes at its end, so the cadence cannot "
-                    f"exceed or straddle the chunk (0 = auto: once per "
-                    f"chunk)")
         # --- step_lowering selection matrix (trainer._build_step dispatches on
         # it; every unsupported combination is an ERROR here, never a silent
         # fallback — same discipline as the CBOW matrix above):
         #   shard_map × cbow              → refuse (the explicit schedule is the
         #       shared-pool SGNS step only; CBOW keeps GSPMD)
-        #   shard_map × use_pallas        → refuse (pallas owns the whole step)
         #   shard_map × duplicate_scaling → refuse (mean semantics need global
         #       in-batch occurrence counts — a [V]-sized cross-shard psum the
         #       schedule exists to avoid)
@@ -1145,11 +986,6 @@ class Word2VecConfig:
                     "step_lowering='shard_map' is implemented for the "
                     "shared-pool skip-gram step only; CBOW runs under GSPMD "
                     "(step_lowering='gspmd')")
-            if self.use_pallas:
-                raise ValueError(
-                    "step_lowering='shard_map' and use_pallas=True both claim "
-                    "the step lowering; the pallas kernel is single-device "
-                    "only — drop one")
             if self.duplicate_scaling:
                 raise ValueError(
                     "step_lowering='shard_map' does not support "
@@ -1208,13 +1044,12 @@ class Word2VecConfig:
                     f"rollback/preemption saves land on merge boundaries "
                     f"only)")
         # --- device_pairgen selection matrix (graftcheck first-run findings,
-        # tools/graftcheck/ — these four refusals lived only in
+        # tools/graftcheck/ — these three refusals lived only in
         # Trainer.__init__, so a config could be constructed/serialized that
         # every Trainer would later reject; same parity discipline as the
-        # CBOW/pallas/step_lowering matrices above):
+        # CBOW/step_lowering matrices above):
         #   device_pairgen × cbow          → refuse (CBOW batches are grouped
         #       windows the device generator does not produce)
-        #   device_pairgen × use_pallas    → refuse (pallas owns the step)
         #   device_pairgen × window=1      → refuse (legacy asymmetric window
         #       b = nextInt(1) = 0 emits no pairs at all)
         #   device_pairgen × explicit tokens_per_step × window past the
@@ -1226,11 +1061,6 @@ class Word2VecConfig:
                 raise ValueError(
                     "device_pairgen is skip-gram only (CBOW batches are "
                     "grouped windows the device generator does not produce)")
-            if self.use_pallas:
-                raise ValueError(
-                    "device_pairgen is not supported with use_pallas — the "
-                    "fused kernel owns the whole step and consumes host "
-                    "pairs; drop one")
             if self.window == 1:
                 raise ValueError(
                     "device_pairgen with window=1 emits no pairs at all "
@@ -1526,6 +1356,12 @@ class Word2VecConfig:
     def from_dict(cls, d: dict) -> "Word2VecConfig":
         fields = {f.name for f in dataclasses.fields(cls)}
         clean = {k: v for k, v in d.items() if k in fields}
+        for key, default in _RETIRED_KEYS.items():
+            if d.get(key, default) != default:
+                logger.warning(
+                    "stored config sets %s=%r, a step variant this version "
+                    "no longer has; the run continues on the XLA shared-pool "
+                    "step", key, d[key])
         if "mesh_shape" in clean and clean["mesh_shape"] is not None:
             clean["mesh_shape"] = tuple(clean["mesh_shape"])
         if (clean.get("cbow") and clean.get("duplicate_scaling")

@@ -145,75 +145,6 @@ def _mask_sentinel(idx: jax.Array, gate: jax.Array, vs: int) -> jax.Array:
     return jnp.where(gate > 0, idx, jnp.int32(vs))
 
 
-# ---------------------------------------------------------------------------
-# Cross-step hot-row accumulation (config.hot_rows — ISSUE 14, PERF.md §11).
-#
-# The vocabulary is sorted by descending frequency (data/vocab.py contract),
-# so rows 0..K−1 are exactly the words Zipf mass concentrates the per-step
-# update traffic on. The hot-row scheme diverts their updates into a small
-# [K, D] float32 slab carried across the steps of a dispatch chunk:
-#
-#   - READS stay exact: every gather adds the slab's pending delta back
-#     (hot_gather), so no step ever trains on a stale hot row — the scheme
-#     changes floating-point ORDER (per-step param-dtype rounding becomes
-#     one f32-accumulated add per flush window), never the update math.
-#   - WRITES split (hot_scatter_add): indices < K accumulate into the slab
-#     (a scatter whose target is K rows, small enough to live in VMEM/cache),
-#     indices >= K take the normal [V, D] scatter with the hot candidates
-#     remapped to the OOB drop sentinel — the §3-measured cheap regime.
-#   - FLUSH (hot_flush): because the hot set is the CONTIGUOUS index prefix,
-#     the flush is one dense [K, D] block add (static slice + add + update —
-#     no scatter emitter at all), once per `hot_flush_every` steps.
-#
-# The slab accumulates in float32 regardless of param dtype (R4: cross-step
-# bf16 accumulation would round away exactly the small frequent-row updates
-# the scheme batches). The trainer flushes unconditionally at the end of
-# every dispatch chunk, so the params carry leaving a chunk is always
-# complete — checkpoints, probes, and donation never see a pending slab.
-# ---------------------------------------------------------------------------
-
-
-def hot_gather(mat: jax.Array, slab: jax.Array, idx: jax.Array,
-               compute_dtype: jnp.dtype) -> jax.Array:
-    """``mat[idx]`` with the hot slab's pending deltas added back for
-    ``idx < K`` — the read-freshness half of the hot-row contract. ``idx``
-    may be any shape; returns ``[..., D]`` in ``compute_dtype``."""
-    k = slab.shape[0]
-    rows = mat[idx].astype(compute_dtype)
-    hot = idx < k
-    pend = jnp.where(hot[..., None],
-                     slab[jnp.where(hot, idx, 0)].astype(compute_dtype),
-                     jnp.zeros((), compute_dtype))
-    return rows + pend
-
-
-def hot_scatter_add(
-    mat: jax.Array,    # [V, D] param matrix
-    slab: jax.Array,   # [K, D] float32 pending-delta slab
-    idx: jax.Array,    # int32 [N] (flattened by the caller if needed)
-    upd: jax.Array,    # [N, D] update rows (compute dtype)
-) -> Tuple[jax.Array, jax.Array]:
-    """Split scatter-add: rows ``idx < K`` accumulate into the f32 slab,
-    the rest into the matrix; each side drops the other's candidates via the
-    OOB sentinel (mode="drop"), so every update lands exactly once."""
-    k = slab.shape[0]
-    v = mat.shape[0]
-    cold = jnp.where(idx < k, jnp.int32(v), idx)
-    mat = mat.at[cold].add(upd.astype(mat.dtype), mode="drop")
-    hot = jnp.where(idx < k, idx, jnp.int32(k))
-    slab = slab.at[hot].add(upd.astype(slab.dtype), mode="drop")
-    return mat, slab
-
-
-def hot_flush(mat: jax.Array, slab: jax.Array) -> jax.Array:
-    """Apply the accumulated hot-row deltas: ONE dense [K, D] block add over
-    the contiguous index prefix (static slice — lowers to slice/add/update,
-    zero scatter-emitter rows; the "one sorted scatter" of the design, made
-    degenerate by the frequency-sorted vocabulary contract)."""
-    k = slab.shape[0]
-    return mat.at[:k].add(slab.astype(mat.dtype))
-
-
 def run_positions(idx: jax.Array, max_run: int) -> jax.Array:
     """Position of every entry inside its run of equal neighbouring ``idx``
     (int32 [N]; 0 = the run's head). A run longer than ``max_run`` is cut into
@@ -410,8 +341,7 @@ def sgns_step_core(
     stabilizers: Optional[Stabilizers] = None,
     fused: bool = False,
     bf16_chain: bool = False,
-    hot_slabs: Optional[Tuple[jax.Array, jax.Array]] = None,
-):
+) -> Tuple[EmbeddingPair, StepMetrics]:
     """:func:`sgns_step` with the negatives supplied by the caller — the form the
     trainer jits (sampling happens once per dispatch chunk, outside the scan, because
     in-program threefry is catastrophically slow on TPU; see ops/prng.py).
@@ -422,36 +352,27 @@ def sgns_step_core(
     touched rows: syn0 at the unmasked centers, syn1 at the unmasked contexts
     plus the negatives of unmasked pairs (see :class:`Stabilizers`).
 
-    ``fused``/``bf16_chain``/``hot_slabs``: the per-pair forms of the ISSUE-14
+    ``fused``/``bf16_chain``: the per-pair forms of the ISSUE-14
     step restructurings (see :func:`sgns_step_shared_core` for semantics):
     fused folds validity+mask+α into one [B, n] select with a precomputed
     scalar; bf16_chain accumulates both logit dots in promote(compute, f32)
     via ``preferred_element_type`` (the per-pair chain previously ran the
     einsum in compute dtype and upcast AFTER — chain mode is the stricter R4
-    form); hot_slabs routes updates through the cross-step hot-row slabs.
-    All default off; off elides the new ops entirely (bit-identical step)."""
+    form). Both default off; off elides the new ops entirely (bit-identical
+    step)."""
     syn0, syn1 = params
     V = syn0.shape[0]
-    if duplicate_scaling and (fused or hot_slabs is not None):
-        raise ValueError("duplicate_scaling has no fused/hot-row form "
+    if duplicate_scaling and fused:
+        raise ValueError("duplicate_scaling has no fused form "
                          "(refused at config construction)")
-    if hot_slabs is not None and stabilizers is not None:
-        raise ValueError("stabilizers have no hot-row form (refused at "
-                         "config construction)")
     if not fused:
         neg_valid = (negatives != contexts[:, None]).astype(jnp.float32) \
             * mask[:, None]
 
     with jax.named_scope("sgns.gather"):
-        if hot_slabs is not None:
-            slab0, slab1 = hot_slabs
-            e_in = hot_gather(syn0, slab0, centers, compute_dtype)    # [B, D]
-            e_pos = hot_gather(syn1, slab1, contexts, compute_dtype)  # [B, D]
-            e_neg = hot_gather(syn1, slab1, negatives, compute_dtype)  # [B, n, D]
-        else:
-            e_in = syn0[centers].astype(compute_dtype)          # [B, D]
-            e_pos = syn1[contexts].astype(compute_dtype)        # [B, D]
-            e_neg = syn1[negatives].astype(compute_dtype)       # [B, n, D]
+        e_in = syn0[centers].astype(compute_dtype)          # [B, D]
+        e_pos = syn1[contexts].astype(compute_dtype)        # [B, D]
+        e_neg = syn1[negatives].astype(compute_dtype)       # [B, n, D]
 
     if bf16_chain:
         pf = jnp.promote_types(compute_dtype, jnp.float32)
@@ -497,20 +418,12 @@ def sgns_step_core(
 
     dtype = syn0.dtype
     D = syn1.shape[1]
-    if hot_slabs is not None:
-        with jax.named_scope("sgns.scatter_syn0"):
-            new_syn0, slab0 = hot_scatter_add(syn0, slab0, centers, d_in)
-        with jax.named_scope("sgns.scatter_syn1"):
-            new_syn1, slab1 = hot_scatter_add(syn1, slab1, contexts, d_pos)
-            new_syn1, slab1 = hot_scatter_add(
-                new_syn1, slab1, negatives.reshape(-1), d_neg.reshape(-1, D))
-    else:
-        with jax.named_scope("sgns.scatter_syn0"):
-            new_syn0 = syn0.at[centers].add(d_in.astype(dtype))
-        with jax.named_scope("sgns.scatter_syn1"):
-            new_syn1 = syn1.at[contexts].add(d_pos.astype(dtype))
-            new_syn1 = new_syn1.at[negatives.reshape(-1)].add(
-                d_neg.reshape(-1, D).astype(dtype))
+    with jax.named_scope("sgns.scatter_syn0"):
+        new_syn0 = syn0.at[centers].add(d_in.astype(dtype))
+    with jax.named_scope("sgns.scatter_syn1"):
+        new_syn1 = syn1.at[contexts].add(d_pos.astype(dtype))
+        new_syn1 = new_syn1.at[negatives.reshape(-1)].add(
+            d_neg.reshape(-1, D).astype(dtype))
     if stabilizers is not None and stabilizers.post_pass:
         enable = (mask.sum() > 0).astype(jnp.float32)
         new_syn0 = stabilize_rows(
@@ -536,8 +449,6 @@ def sgns_step_core(
         mean_f_pos=(f_pos * mask).sum() / denom,
         pairs=mask.sum(),
     )
-    if hot_slabs is not None:
-        return EmbeddingPair(new_syn0, new_syn1), metrics, (slab0, slab1)
     return EmbeddingPair(new_syn0, new_syn1), metrics
 
 
@@ -677,9 +588,8 @@ def sgns_step_shared_core(
     stabilizers: Optional[Stabilizers] = None,
     fused: bool = False,
     bf16_chain: bool = False,
-    hot_slabs: Optional[Tuple[jax.Array, jax.Array]] = None,
     center_runs: Optional[Tuple[int, int]] = None,
-):
+) -> Tuple[EmbeddingPair, StepMetrics]:
     """:func:`sgns_step_shared` with the pool supplied by the caller (see
     :func:`sgns_step_core` for why sampling lives outside the jitted scan).
 
@@ -688,8 +598,7 @@ def sgns_step_shared_core(
     consecutively, so a batch hands the scatter one summed row per center run
     (about a quarter of B at window 5) where it holds at most ``cap`` runs,
     and takes the plain scatter where it does not. The trainer derives both
-    numbers from ``config.window``; nothing else in the step changes, and it
-    is not applied beside ``hot_slabs``.
+    numbers from ``config.window``; nothing else in the step changes.
 
     ``fused``/``bf16_chain`` (config.fused_logits / config.bf16_chain —
     ISSUE 14): the fused coefficient chain and the f32-accumulating dot
@@ -698,15 +607,6 @@ def sgns_step_shared_core(
     pre-restructure release — tested). Neither supports
     ``duplicate_scaling`` (the mean-update scaling reads the per-pair
     coefficient arrays the fusion eliminates; refused at config).
-
-    ``hot_slabs`` (config.hot_rows): the cross-step hot-row accumulation
-    slabs ``(slab0, slab1)`` — f32 [K, D] pending deltas for syn0/syn1's
-    first K rows, carried across the dispatch chunk's scan by the trainer.
-    When given, gathers read through :func:`hot_gather` (pending deltas
-    added back — no staleness), scatters split through
-    :func:`hot_scatter_add`, and the return grows a third element with the
-    updated slabs. Incompatible with stabilizers (the post-scatter clamp
-    would measure rows missing their pending deltas; refused at config).
 
     ``stabilizers`` (None/all-zero = off, bit-identical step): ``update_clip``
     caps the per-pair d_in/d_pos rows (NOT the pool deltas d_Z — see
@@ -742,24 +642,15 @@ def sgns_step_shared_core(
     no heartbeat will sample."""
     syn0, syn1 = params
     V = syn0.shape[0]
-    if duplicate_scaling and (fused or hot_slabs is not None):
-        raise ValueError("duplicate_scaling has no fused/hot-row form "
+    if duplicate_scaling and fused:
+        raise ValueError("duplicate_scaling has no fused form "
                          "(refused at config construction)")
-    if hot_slabs is not None and stabilizers is not None:
-        raise ValueError("stabilizers have no hot-row form (refused at "
-                         "config construction)")
     # named scopes are metadata for a profile's reader (docs/observability.md
     # §4); the compiled step is the same program without them (tested)
     with jax.named_scope("sgns.gather"):
-        if hot_slabs is not None:
-            slab0, slab1 = hot_slabs
-            e_in = hot_gather(syn0, slab0, centers, compute_dtype)    # [B, D]
-            e_pos = hot_gather(syn1, slab1, contexts, compute_dtype)  # [B, D]
-            Z = hot_gather(syn1, slab1, negatives, compute_dtype)     # [P, D]
-        else:
-            e_in = syn0[centers].astype(compute_dtype)          # [B, D]
-            e_pos = syn1[contexts].astype(compute_dtype)        # [B, D]
-            Z = syn1[negatives].astype(compute_dtype)           # [P, D]
+        e_in = syn0[centers].astype(compute_dtype)          # [B, D]
+        e_pos = syn1[contexts].astype(compute_dtype)        # [B, D]
+        Z = syn1[negatives].astype(compute_dtype)           # [P, D]
 
     with jax.named_scope("sgns.pool_matmul"):
         f_pos, f_neg, neg_valid, g_pos, g_neg = shared_pool_coeffs(
@@ -799,24 +690,16 @@ def sgns_step_shared_core(
         d_pos = clip_update_rows(d_pos, stabilizers.update_clip)
 
     dtype = syn0.dtype
-    if hot_slabs is not None:
-        with jax.named_scope("sgns.scatter_syn0"):
-            new_syn0, slab0 = hot_scatter_add(syn0, slab0, centers, d_in)
-        with jax.named_scope("sgns.scatter_syn1"):
-            new_syn1, slab1 = hot_scatter_add(syn1, slab1, contexts, d_pos)
-            new_syn1, slab1 = hot_scatter_add(new_syn1, slab1, negatives, d_Z)
-        syn0_rows = None  # the slab takes part of the rows: not counted
-    else:
-        with jax.named_scope("sgns.scatter_syn0"):
-            if center_runs is None:
-                new_syn0 = syn0.at[centers].add(d_in.astype(dtype))
-                syn0_rows = jnp.float32(centers.shape[0])
-            else:
-                new_syn0, syn0_rows = scatter_add_by_runs(
-                    syn0, centers, d_in, *center_runs)
-        with jax.named_scope("sgns.scatter_syn1"):
-            new_syn1 = syn1.at[contexts].add(d_pos.astype(dtype))
-            new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
+    with jax.named_scope("sgns.scatter_syn0"):
+        if center_runs is None:
+            new_syn0 = syn0.at[centers].add(d_in.astype(dtype))
+            syn0_rows = jnp.float32(centers.shape[0])
+        else:
+            new_syn0, syn0_rows = scatter_add_by_runs(
+                syn0, centers, d_in, *center_runs)
+    with jax.named_scope("sgns.scatter_syn1"):
+        new_syn1 = syn1.at[contexts].add(d_pos.astype(dtype))
+        new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
     if stabilizers is not None and stabilizers.post_pass:
         enable = (mask.sum() > 0).astype(jnp.float32)
         new_syn0 = stabilize_rows(
@@ -840,8 +723,6 @@ def sgns_step_shared_core(
         pairs=mask.sum(),
         syn0_rows=syn0_rows,
     )
-    if hot_slabs is not None:
-        return EmbeddingPair(new_syn0, new_syn1), metrics, (slab0, slab1)
     return EmbeddingPair(new_syn0, new_syn1), metrics
 
 

@@ -121,12 +121,7 @@ def make_shard_map_sgns_step(
     construction — the two lowerings cannot drift. The per-data-shard
     [Bl, P] chain shrinks exactly like the single-program [B, P] one; the
     collective schedule is untouched (the fusion is local elementwise
-    restructuring, no new cross-shard values). ``hot_rows`` has NO shard_map
-    form and is refused at config construction: the hot slab covers the
-    global index prefix [0, K), which under the rows layout lives entirely
-    on model shard 0 — accumulating it owner-locally would serialize every
-    hot update onto one shard, the exact imbalance the owner-local schedule
-    exists to avoid (docs/sharding.md records the refusal contract).
+    restructuring, no new cross-shard values).
 
     ``sync_every`` (config.sync_every — local-SGD, docs/sharding.md
     §Local-SGD): 1 (default) returns the synchronous step above, byte-for-byte

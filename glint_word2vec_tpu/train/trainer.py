@@ -22,7 +22,7 @@ from __future__ import annotations
 import logging
 import time
 from dataclasses import dataclass, replace as dc_replace
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,7 +39,6 @@ from glint_word2vec_tpu.ops.sgns import (
     alpha_schedule,
     cbow_step_core,
     cbow_step_shared_core,
-    hot_flush,
     init_embeddings,
     sgns_step_core,
     sgns_step_shared_core,
@@ -91,6 +90,139 @@ def _center_run_cap(window: int, batch: int) -> int:
         return 0
     eighth = batch // 8
     return -(-int(1.4 * runs_per_pair * batch) // eighth) * eighth
+
+
+class StepChoice(NamedTuple):
+    """One row of the step selection matrix (:func:`select_step`)."""
+
+    core: Callable   # the ops-level step the row runs — what names the row
+    # (params, batch, negatives, alpha) -> (params, StepMetrics); ``batch`` is
+    # the dict the row's chunk body builds
+    step: Callable
+    neg_shape: Callable[[int, int], Tuple[int, ...]]  # (K, B) -> one chunk's negatives
+    # (max_run, cap) where syn0's update goes to the scatter by center runs
+    center_runs: Optional[Tuple[int, int]]
+
+
+def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
+                stabilizers: Optional[Stabilizers],
+                with_metrics: bool) -> StepChoice:
+    """The step selection matrix: which update one configuration trains with.
+    Every legal combination is one row, read top to bottom; what is on no row
+    config.__post_init__ refuses at construction, never silently downgrades
+    (P = negative_pool, n = negatives, nd = the mesh's data degree):
+
+      cbow   cbow_update  P    duplicate_scaling  step_lowering  → core                      negatives
+      -----  -----------  ---  -----------------  -------------  --------------------------  ----------
+      True   "banded"     > 0  False              gspmd          cbow_step_banded_core       [K, P]
+      True   "scatter"    > 0  False              gspmd          cbow_step_shared_core       [K, P]
+      True   "scatter"    = 0  any                gspmd          cbow_step_core              [K, B, n]
+      False  —            = 0  any                gspmd          sgns_step_core              [K, B, n]
+      False  —            > 0  False              "shard_map"    make_shard_map_sgns_step    [K, P]
+                                                  sync_every>1   (the same, windowed)        [K, nd·P]
+      False  —            > 0  any                gspmd          sgns_step_shared_core       [K, P]
+                                                                 (+ center_runs, below)
+
+    ``stabilizers`` is the trainer's state (None = all off), ``with_metrics``
+    the twin; the rows without a ``with_metrics`` form have one twin."""
+    compute_dtype = jnp.dtype(cfg.compute_dtype)
+    logits_dtype = jnp.dtype(cfg.logits_dtype)
+    n, pool = cfg.negatives, cfg.negative_pool
+
+    def shared_pool(K, B):
+        return (K, pool)
+
+    def per_example(K, B):
+        return (K, B, n)
+
+    if cfg.cbow and cfg.cbow_update == "banded":
+        from glint_word2vec_tpu.ops.cbow_banded import cbow_step_banded_core
+
+        def step(params, batch, negatives, alpha):
+            band = batch["band"]
+            return cbow_step_banded_core(
+                params, batch["tokens"],
+                band.left.reshape(-1), band.right.reshape(-1),
+                band.center.reshape(-1), band.token.reshape(-1),
+                negatives, alpha, n, cfg.window, cfg.sigmoid_mode,
+                compute_dtype, logits_dtype, with_metrics,
+                stabilizers=stabilizers)
+
+        return StepChoice(cbow_step_banded_core, step, shared_pool, None)
+
+    if cfg.cbow and pool > 0 and not cfg.duplicate_scaling:
+        def step(params, batch, negatives, alpha):
+            return cbow_step_shared_core(
+                params, batch["centers"], batch["contexts"], batch["ctx_mask"],
+                batch["mask"], negatives, alpha, n,
+                cfg.sigmoid_mode, compute_dtype, logits_dtype, with_metrics,
+                stabilizers=stabilizers)
+
+        return StepChoice(cbow_step_shared_core, step, shared_pool, None)
+
+    if cfg.cbow:
+        # per-example CBOW (pool resolved to 0: small batches, or
+        # duplicate_scaling — config refuses an explicit pool beside it)
+        def step(params, batch, negatives, alpha):
+            return cbow_step_core(
+                params, batch["centers"], batch["contexts"], batch["ctx_mask"],
+                batch["mask"], negatives, alpha,
+                cfg.sigmoid_mode, compute_dtype, cfg.duplicate_scaling,
+                stabilizers=stabilizers)
+
+        return StepChoice(cbow_step_core, step, per_example, None)
+
+    fused, chain = cfg.fused_logits, cfg.bf16_chain
+    if pool == 0:
+        def step(params, batch, negatives, alpha):
+            return sgns_step_core(
+                params, batch["centers"], batch["contexts"], batch["mask"],
+                negatives, alpha, cfg.sigmoid_mode, compute_dtype,
+                cfg.duplicate_scaling, stabilizers=stabilizers,
+                fused=fused, bf16_chain=chain)
+
+        return StepChoice(sgns_step_core, step, per_example, None)
+
+    if cfg.step_lowering == "shard_map":
+        # the explicit schedule (ops/sgns_shard.py, docs/sharding.md):
+        # owner-local gathers + ONE model-axis psum forward, owner-local
+        # scatters + ONE data-axis payload all_gather backward — zero update
+        # bytes over the model axis (HLO-audited, tools/collectives.py)
+        from glint_word2vec_tpu.ops.sgns_shard import make_shard_map_sgns_step
+        step = make_shard_map_sgns_step(
+            plan.mesh, n, cfg.sigmoid_mode, compute_dtype, logits_dtype,
+            with_metrics, stabilizers=stabilizers, fused=fused,
+            bf16_chain=chain, sync_every=cfg.sync_every)
+
+        # local-SGD window (docs/sharding.md §Local-SGD): the step consumes
+        # [k, B]-stacked batches and [k, nd·P] negatives — each data shard a
+        # DISJOINT [k, P] lattice slice, so the merged run is deterministic
+        # per (seed, mesh, k)
+        def window_pools(K, B):
+            return (K, plan.num_data * pool)
+
+        return StepChoice(make_shard_map_sgns_step, step,
+                          shared_pool if cfg.sync_every == 1 else window_pools,
+                          None)
+
+    # syn0's update, one scatter row per center run (the step chooses per
+    # batch; ops/sgns.scatter_add_by_runs), where one program sees the batch
+    # whole: a batch split over a data axis or fed in per-process segments
+    # cuts runs at every seam
+    runs = None
+    if plan.num_data == 1 and feed_segments == 1:
+        cap = _center_run_cap(cfg.window, cfg.pairs_per_batch)
+        runs = (2 * cfg.window, cap) if cap else None
+
+    def step(params, batch, negatives, alpha):
+        return sgns_step_shared_core(
+            params, batch["centers"], batch["contexts"], batch["mask"],
+            negatives, alpha, n, cfg.sigmoid_mode, compute_dtype,
+            cfg.duplicate_scaling, logits_dtype, with_metrics,
+            stabilizers=stabilizers, fused=fused, bf16_chain=chain,
+            center_runs=runs)
+
+    return StepChoice(sgns_step_shared_core, step, shared_pool, runs)
 
 
 @dataclass
@@ -453,8 +585,6 @@ class Trainer:
                 raise ValueError("device_pairgen is skip-gram only (CBOW batches "
                                  "are grouped windows the device generator does "
                                  "not produce)")
-            if config.use_pallas:
-                raise ValueError("device_pairgen is not supported with use_pallas")
             if config.window == 1:
                 raise ValueError(
                     "device_pairgen with window=1 emits no pairs at all under the "
@@ -477,7 +607,7 @@ class Trainer:
         # lattice — but with a ±window halo overlap at block cuts
         # (pipeline.pack_halo_token_blocks) so chunk-edge windows are exact.
         # The config-level selection matrix already refused unsupported
-        # combinations (duplicate_scaling/pool=0/pallas/window=1).
+        # combinations (duplicate_scaling/pool=0/window=1).
         self._banded_cbow = bool(config.cbow and config.cbow_update == "banded")
         self._block_halo = 0
         if self._banded_cbow:
@@ -519,27 +649,6 @@ class Trainer:
             max_row_norm=config.max_row_norm,
             update_clip=config.update_clip,
             row_l2=config.row_l2)
-        # cross-step hot-row accumulation (config.hot_rows, ISSUE 14 /
-        # PERF.md §11): K clamped to the REAL vocabulary — config cannot see
-        # it, and the padding rows past vocab.size are never touched by
-        # construction so a slab covering them would waste VMEM. The flush
-        # cadence resolves AUTO (0) to once per dispatch chunk; config
-        # already refused explicit values that do not divide the chunk.
-        self._hot_rows = 0
-        self._hot_flush = 0
-        if config.hot_rows:
-            if len(plan.mesh.devices.flat) > 1:
-                # runtime twin of the config-side multi-shard refusal (the
-                # plan's device count is state config cannot see — same
-                # split as the pallas multi-device guard)
-                raise ValueError(
-                    "hot_rows is the single-chip step restructuring "
-                    "(PERF.md §11) and the mesh plan has "
-                    f"{len(plan.mesh.devices.flat)} devices; use a "
-                    "single-device plan or hot_rows=0")
-            self._hot_rows = int(min(config.hot_rows, vocab.size))
-            self._hot_flush = (config.hot_flush_every
-                               or config.steps_per_dispatch)
         self._lr_scale = 1.0
         self.recoveries_performed = 0
         self._health_fn: Optional[Callable] = None  # fused probe (obs/probe.py)
@@ -609,16 +718,7 @@ class Trainer:
         # gate, pipelining untouched.
         self._sync_collectives = (
             jax.default_backend() == "cpu" and plan.mesh.devices.size > 1)
-        self._step_fn = self._build_step()
-        # fast twin (metrics elided) for the shared-pool paths (skip-gram and
-        # CBOW): the paths whose loss side-channel is an extra full [B, pool]
-        # pass (PERF.md §4); the CBOW+duplicate_scaling and per-pair paths
-        # keep full metrics (their loss chains are not the measured slice)
-        self._step_fn_fast = (
-            self._build_step(with_metrics=False)
-            if (self.config.negative_pool > 0 and not self.config.use_pallas
-                and not (self.config.cbow and self.config.duplicate_scaling))
-            else self._step_fn)
+        self._build_step_twins()
 
     # -- setup -------------------------------------------------------------------------
 
@@ -709,9 +809,8 @@ class Trainer:
         cfg = self.config
         if cfg.duplicate_scaling:
             return  # mean-update semantics bound both channels by construction
-        pool = cfg.negative_pool if cfg.negative_pool > 0 else 64  # pallas substitute
-        pool_load = (cfg.pairs_per_batch * cfg.negatives / pool if check_pool
-                     else 0.0)
+        pool_load = (cfg.pairs_per_batch * cfg.negatives / cfg.negative_pool
+                     if check_pool else 0.0)
         if pool_load > 300 and self.vocab.size > 500_000:
             # large-vocab advisory (EVAL.md round-5 ladder) — takes precedence
             # over the generic >2000 warning, whose "keep the load ~1300"
@@ -890,228 +989,72 @@ class Trainer:
         new_cfg._auto_pool = True  # still AUTO — geometry changes re-derive
         self.config = new_cfg
 
-    def _build_step(self, with_metrics: bool = True) -> Callable:
-        """Build the jitted chunk function. ``with_metrics=False`` builds the
-        fast twin of the shared-pool paths (skip-gram and CBOW):
-        loss/mean_f_pos elided (one fewer full [B, P] pass, ~0.3 ms at the
-        headline shape — PERF.md §4), pairs kept exact. The trainer dispatches
-        the fast twin for chunks no heartbeat will sample (see
-        _dispatch_step_fn); both twins share the same update math, so the
-        trained parameters are bit-identical."""
+    @property
+    def _shared_pool(self) -> bool:
+        """Whether the step runs on a batch-shared negative pool: the paths
+        with a [B, pool] logit chain (``logits_dtype`` applies) and a
+        metrics-elided fast twin."""
         cfg = self.config
-        quiet = not with_metrics  # the full build already warned at __init__
-        compute_dtype = jnp.dtype(cfg.compute_dtype)
-        logits_dtype = jnp.dtype(cfg.logits_dtype)
-        # in-step stabilizers: trainer state, not raw config — a
-        # norm_watch="recover" firing may have engaged max_row_norm since
-        # construction (the rebuild path through _perform_recovery). None
-        # when all off, so the default step compiles bit-identical to the
-        # pre-stabilizer step.
-        stab = self._stabilizers if self._stabilizers.enabled else None
-        # ISSUE-14 step restructurings: dispatch-side twins of the config
-        # selection matrix (construction already refused these — graftlint R8
-        # refusal parity; kept here so a hand-mutated config can never reach
-        # an unsupported lowering), plus the resolved hot-row geometry.
-        if cfg.hot_rows and (cfg.use_pallas or cfg.cbow
-                             or cfg.step_lowering == "shard_map"
-                             or cfg.duplicate_scaling):
+        return cfg.negative_pool > 0 and not (
+            cfg.cbow and cfg.duplicate_scaling)
+
+    def _build_step_twins(self) -> None:
+        """(Re)build both step twins from the trainer's current state (at
+        construction, and again when a recovery engages ``max_row_norm``)."""
+        self._step_fn = self._build_step()
+        # fast twin (metrics elided) for the shared-pool paths (skip-gram and
+        # CBOW): the paths whose loss side-channel is an extra full [B, pool]
+        # pass (PERF.md §4); the CBOW+duplicate_scaling and per-pair paths
+        # keep full metrics (their loss chains are not the measured slice)
+        self._step_fn_fast = (self._build_step(with_metrics=False)
+                              if self._shared_pool else self._step_fn)
+
+    def _build_step(self, with_metrics: bool = True) -> Callable:
+        """Build the jitted chunk function around the step :func:`select_step`
+        chooses. ``with_metrics=False`` builds the fast twin of the
+        shared-pool paths (skip-gram and CBOW): loss/mean_f_pos elided (one
+        fewer full [B, P] pass, ~0.3 ms at the headline shape — PERF.md §4),
+        pairs kept exact. The trainer dispatches the fast twin for chunks no
+        heartbeat will sample (see _dispatch_step_fn); both twins share the
+        same update math, so the trained parameters are bit-identical."""
+        cfg = self.config
+        # dispatch-side twins of the config selection matrix (construction
+        # already refused these — graftlint R8 refusal parity; kept here so a
+        # hand-mutated config can never reach an unsupported lowering)
+        if (cfg.fused_logits or cfg.bf16_chain) and cfg.cbow:
             raise ValueError(
-                "hot_rows supports the single-device SGNS XLA paths only "
-                "(not use_pallas/cbow/shard_map/duplicate_scaling) — config "
-                "construction refuses these combinations (docs/sharding.md)")
-        if (cfg.fused_logits or cfg.bf16_chain) and (
-                cfg.use_pallas or cfg.cbow):
-            raise ValueError(
-                "fused_logits/bf16_chain support the SGNS XLA chains only "
-                "(not use_pallas/cbow) — config construction refuses these "
-                "combinations")
+                "fused_logits/bf16_chain support the SGNS chains only (not "
+                "cbow) — config construction refuses these combinations")
         if cfg.sync_every > 1 and cfg.step_lowering != "shard_map":
             raise ValueError(
                 "sync_every > 1 (local-SGD) requires the shard_map lowering "
                 "— the owner-local k-step window has no GSPMD form; config "
                 "construction refuses this combination (docs/sharding.md "
                 "§Local-SGD)")
-        fused = cfg.fused_logits
-        chain = cfg.bf16_chain
-        hot_k = self._hot_rows
-        inner_hot = None
-        if not quiet and logits_dtype != jnp.float32 and not (
-                cfg.negative_pool > 0 and not cfg.use_pallas
-                and not (cfg.cbow and cfg.duplicate_scaling)):
-            logger.warning(
-                "logits_dtype=%s only applies to the shared-pool XLA paths "
-                "(negative_pool > 0, no pallas, no CBOW+duplicate_scaling); this "
-                "configuration keeps the float32 logit chain", cfg.logits_dtype)
-        plan = self.plan
+        if with_metrics:  # the fast twin's build would only repeat them
+            if cfg.logits_dtype != "float32" and not self._shared_pool:
+                logger.warning(
+                    "logits_dtype=%s only applies to the shared-pool paths "
+                    "(negative_pool > 0, no CBOW+duplicate_scaling); this "
+                    "configuration keeps the float32 logit chain",
+                    cfg.logits_dtype)
+            # the per-pair paths have no pool to overload, but the duplicate
+            # channel still applies to them (EVAL.md)
+            self._stability_warnings(check_pool=cfg.negative_pool > 0)
+        # in-step stabilizers: trainer state, not raw config — a
+        # norm_watch="recover" firing may have engaged max_row_norm since
+        # construction (the rebuild path through _perform_recovery). None
+        # when all off, so the default step compiles bit-identical to the
+        # pre-stabilizer step.
+        stab = self._stabilizers if self._stabilizers.enabled else None
+        choice = select_step(cfg, self.plan, self._feed_segments, stab,
+                             with_metrics)
+        inner, neg_shape = choice.step, choice.neg_shape
         # np.uint32 (not a Python int): any negative or 64-bit seed masked to 32 bits
         # lands in [2^31, 2^32), which jnp.asarray rejects under int32 canonicalization
         seed = np.uint32(cfg.seed & 0xFFFFFFFF)
-
-        def shared_pool_shape(K, B):  # negatives per chunk on the shared-pool paths
-            return (K, cfg.negative_pool)
-
-        # CBOW update-path selection matrix (config.__post_init__ holds the
-        # validation-side twin — every unsupported combination is refused at
-        # construction, never silently downgraded):
-        #
-        #   cbow_update  duplicate_scaling  pool   → step
-        #   ------------ -----------------  -----  ---------------------------
-        #   "banded"     False              > 0    cbow_step_banded_core
-        #                                          (token-block feed + halo)
-        #   "banded"     True               any    REFUSED (config)
-        #   "banded"     False              = 0    REFUSED (config; banded is
-        #                                          built on the shared pool)
-        #   "scatter"    False              > 0    cbow_step_shared_core
-        #   "scatter"    True               = 0    cbow_step_core (per-example
-        #                                          negatives; explicit pool>0
-        #                                          REFUSED, auto resolves to 0)
-        #   "scatter"    False              = 0    cbow_step_core
-        #   any          any + use_pallas   any    REFUSED (SGNS-only kernel)
         if self._banded_cbow:
-            if not quiet:
-                self._stability_warnings()
-            return self._build_banded_cbow_chunk(
-                with_metrics, compute_dtype, logits_dtype, seed)
-
-        if cfg.use_pallas:
-            from glint_word2vec_tpu.ops.pallas import sgns_kernel  # deferred import
-            if cfg.duplicate_scaling:
-                raise ValueError(
-                    "duplicate_scaling is not implemented for use_pallas=True — the "
-                    "fused kernel applies sum semantics only; use the XLA path or "
-                    "bound the row loads via negative_pool/subsample_ratio instead")
-            if cfg.max_row_norm or cfg.update_clip or cfg.row_l2:
-                raise ValueError(
-                    "the in-step stabilizers (max_row_norm/update_clip/row_l2) "
-                    "are not implemented for use_pallas=True — the fused "
-                    "kernel owns its own update math; use the XLA paths")
-            if cfg.norm_watch == "recover":
-                raise ValueError(
-                    "norm_watch='recover' auto-engages max_row_norm, which "
-                    "the fused pallas kernel does not implement — use "
-                    "norm_watch='warn'/'halt' or the XLA paths")
-            self._stability_warnings()
-            if len(plan.mesh.devices.flat) > 1:
-                raise ValueError(
-                    "use_pallas=True currently supports single-device plans only: the "
-                    "fused kernel owns the whole [V, D] matrices in one HBM space and "
-                    "cannot be GSPMD-partitioned; use the XLA negative_pool path on "
-                    "multi-device meshes")
-            if cfg.cbow:
-                raise ValueError("use_pallas=True is not implemented for CBOW")
-            inner = sgns_kernel.make_pallas_sgns_step(
-                cfg.negatives, cfg.negative_pool, cfg.sigmoid_mode, compute_dtype,
-                interpret=jax.default_backend() == "cpu")
-            if cfg.negative_pool <= 0:
-                logger.warning(
-                    "use_pallas=True requires a shared negative pool; negative_pool=0 "
-                    "(per-pair negatives) is substituted with a 64-negative shared pool "
-                    "— a different objective estimator. Set negative_pool explicitly "
-                    "to silence this.")
-            pool = cfg.negative_pool if cfg.negative_pool > 0 else 64
-            neg_shape = lambda K, B: (K, pool)  # noqa: E731
-        elif cfg.negative_pool > 0 and not cfg.cbow:
-            if not quiet:
-                self._stability_warnings()
-
-            if cfg.step_lowering == "shard_map":
-                # the explicit schedule (ops/sgns_shard.py, docs/sharding.md):
-                # owner-local gathers + ONE model-axis psum forward, owner-local
-                # scatters + ONE data-axis payload all_gather backward — zero
-                # update bytes over the model axis (HLO-audited,
-                # tools/collectives.py). The config selection matrix already
-                # refused cbow/pallas/duplicate_scaling/cols beside it.
-                from glint_word2vec_tpu.ops.sgns_shard import (
-                    make_shard_map_sgns_step)
-                inner = make_shard_map_sgns_step(
-                    plan.mesh, cfg.negatives, cfg.sigmoid_mode, compute_dtype,
-                    logits_dtype, with_metrics, stabilizers=stab,
-                    fused=fused, bf16_chain=chain, sync_every=cfg.sync_every)
-            else:
-                # syn0's update, one scatter row per center run (the step
-                # chooses per batch; ops/sgns.scatter_add_by_runs), where one
-                # program sees the batch whole: a batch split over a data axis
-                # or fed in per-process segments cuts runs at every seam
-                runs = None
-                if plan.num_data == 1 and self._feed_segments == 1:
-                    cap = _center_run_cap(cfg.window, cfg.pairs_per_batch)
-                    runs = (2 * cfg.window, cap) if cap else None
-
-                def inner(params, batch, negatives, alpha):
-                    return sgns_step_shared_core(
-                        params, batch["centers"], batch["contexts"],
-                        batch["mask"], negatives, alpha, cfg.negatives,
-                        cfg.sigmoid_mode, compute_dtype,
-                        cfg.duplicate_scaling, logits_dtype, with_metrics,
-                        stabilizers=stab, fused=fused, bf16_chain=chain,
-                        center_runs=runs)
-
-                if hot_k:
-                    def inner_hot(params, slabs, batch, negatives, alpha):
-                        return sgns_step_shared_core(
-                            params, batch["centers"], batch["contexts"],
-                            batch["mask"], negatives, alpha, cfg.negatives,
-                            cfg.sigmoid_mode, compute_dtype,
-                            cfg.duplicate_scaling, logits_dtype,
-                            with_metrics, stabilizers=stab, fused=fused,
-                            bf16_chain=chain, hot_slabs=slabs)
-
-            neg_shape = shared_pool_shape
-            if cfg.step_lowering == "shard_map" and cfg.sync_every > 1:
-                # local-SGD window (docs/sharding.md §Local-SGD): `inner`
-                # consumes [k, B]-stacked batches and [k, nd·P] negatives —
-                # each data shard a DISJOINT [k, P] lattice slice, so the
-                # merged run is deterministic per (seed, mesh, k)
-                neg_shape = lambda K, B: (  # noqa: E731
-                    K, plan.num_data * cfg.negative_pool)
-        elif cfg.cbow and cfg.negative_pool > 0 and not cfg.duplicate_scaling:
-            if not quiet:
-                self._stability_warnings()
-
-            def inner(params, batch, negatives, alpha):
-                return cbow_step_shared_core(
-                    params, batch["centers"], batch["contexts"], batch["ctx_mask"],
-                    batch["mask"], negatives, alpha, cfg.negatives,
-                    cfg.sigmoid_mode, compute_dtype, logits_dtype, with_metrics,
-                    stabilizers=stab)
-
-            neg_shape = shared_pool_shape
-        elif cfg.cbow:
-            # per-example CBOW (pool resolved to 0: small batches, or
-            # duplicate_scaling — config refuses an explicit pool beside it)
-            self._stability_warnings(check_pool=False)
-
-            def inner(params, batch, negatives, alpha):
-                return cbow_step_core(
-                    params, batch["centers"], batch["contexts"], batch["ctx_mask"],
-                    batch["mask"], negatives, alpha,
-                    cfg.sigmoid_mode, compute_dtype, cfg.duplicate_scaling,
-                    stabilizers=stab)
-
-            neg_shape = lambda K, B: (K, B, cfg.negatives)  # noqa: E731
-        else:
-            # per-pair path (negative_pool=0): no shared pool, but the duplicate
-            # overload channel still applies (summed scatter-adds of a frequent
-            # word's updates — the EVAL.md regime)
-            self._stability_warnings(check_pool=False)
-
-            def inner(params, batch, negatives, alpha):
-                return sgns_step_core(
-                    params, batch["centers"], batch["contexts"], batch["mask"],
-                    negatives, alpha, cfg.sigmoid_mode, compute_dtype,
-                    cfg.duplicate_scaling, stabilizers=stab,
-                    fused=fused, bf16_chain=chain)
-
-            if hot_k:
-                def inner_hot(params, slabs, batch, negatives, alpha):
-                    return sgns_step_core(
-                        params, batch["centers"], batch["contexts"],
-                        batch["mask"], negatives, alpha, cfg.sigmoid_mode,
-                        compute_dtype, cfg.duplicate_scaling,
-                        stabilizers=stab, fused=fused, bf16_chain=chain,
-                        hot_slabs=slabs)
-
-            neg_shape = lambda K, B: (K, B, cfg.negatives)  # noqa: E731
+            return self._build_banded_cbow_chunk(inner, neg_shape, seed)
 
         is_cbow = cfg.cbow
         S = self._feed_segments
@@ -1163,21 +1106,8 @@ class Trainer:
                         new_p, EmbeddingPair(emb_sharding, emb_sharding))
                     return new_p, (metrics, dropped)
 
-                xs_all = (arrays, alphas, nvalid, negatives)
-                if not hot_k:
-                    return jax.lax.scan(body, params, xs_all)
-
-                def body_hot(carry, inp):
-                    p, slabs = carry
-                    xs, alpha, nv, negs = inp
-                    batch, dropped = build_batch(xs, nv)
-                    new_p, metrics, slabs = inner_hot(p, slabs, batch, negs,
-                                                      alpha)
-                    new_p = jax.lax.with_sharding_constraint(
-                        new_p, EmbeddingPair(emb_sharding, emb_sharding))
-                    return (new_p, slabs), (metrics, dropped)
-
-                return self._run_hot_scan(body_hot, params, xs_all, K)
+                return jax.lax.scan(
+                    body, params, (arrays, alphas, nvalid, negatives))
 
             return jax.jit(device_chunk, donate_argnums=(0,))
 
@@ -1279,47 +1209,28 @@ class Trainer:
                 m = jax.tree.map(
                     lambda x: x.reshape((K,) + x.shape[2:]), m)
                 return final_p, m
-            if not hot_k:
-                return jax.lax.scan(body, params, xs_all)
-
-            def body_hot(carry, inp):
-                p, slabs = carry
-                xs, alpha, real, negs = inp
-                new_p, metrics, slabs = inner_hot(
-                    p, slabs, build_batch(xs, real), negs, alpha)
-                new_p = jax.lax.with_sharding_constraint(
-                    new_p, EmbeddingPair(emb_sharding, emb_sharding))
-                return (new_p, slabs), metrics
-
-            return self._run_hot_scan(body_hot, params, xs_all, K)
+            return jax.lax.scan(body, params, xs_all)
 
         return jax.jit(chunk, donate_argnums=(0,))
 
-    def _build_banded_cbow_chunk(
-        self,
-        with_metrics: bool,
-        compute_dtype: jnp.dtype,
-        logits_dtype: jnp.dtype,
-        seed: np.uint32,
-    ) -> Callable:
+    def _build_banded_cbow_chunk(self, inner: Callable, neg_shape: Callable,
+                                 seed: np.uint32) -> Callable:
         """Jitted chunk for cbow_update='banded': same feed/chunk signature as
         the device_pairgen chunk (token blocks + hash-lattice draws on device;
         keep_prob/sub_bases ride along unused — the packer presubsampled), but
         each scan step derives per-slot CBOW window intervals
         (ops/pairgen.device_cbow_windows) and applies the banded update
-        (ops/cbow_banded.cbow_step_banded_core). Segments are flattened
-        [Sd, T] → [Sd·T] for ONE prefix-sum pass: window intervals are
-        in-block by construction, so prefix differences never leak across
-        segments. The second return slot keeps the device-feed (metrics,
-        dropped) shape; banded blocks have fixed example slots, so dropped
-        is identically 0."""
+        (``inner`` — :func:`select_step`'s cbow_step_banded_core row). Segments
+        are flattened [Sd, T] → [Sd·T] for ONE prefix-sum pass: window
+        intervals are in-block by construction, so prefix differences never
+        leak across segments. The second return slot keeps the device-feed
+        (metrics, dropped) shape; banded blocks have fixed example slots, so
+        dropped is identically 0."""
         cfg = self.config
-        from glint_word2vec_tpu.ops.cbow_banded import cbow_step_banded_core
         from glint_word2vec_tpu.ops.pairgen import device_cbow_windows
         W = cfg.window
         H = self._block_halo
         emb_sharding = self._emb_sharding
-        stab = self._stabilizers if self._stabilizers.enabled else None
 
         win = jax.vmap(
             lambda tk, st, nv, lo, hi, wb: device_cbow_windows(
@@ -1332,7 +1243,8 @@ class Trainer:
             alphas, nvalid = meta[0], meta[1:].T          # [K], [K, Sd]
             K = alphas.shape[0]
             negatives = sample_negatives_hash(
-                prob, alias, seed, base_step, (K, cfg.negative_pool))
+                prob, alias, seed, base_step,
+                neg_shape(K, cfg.pairs_per_batch))
             # tie feed + negatives to the params carry (see _build_step's
             # chunk for the live-deadlock rationale)
             params, arrays, negatives = jax.lax.optimization_barrier(
@@ -1345,13 +1257,8 @@ class Trainer:
                 with jax.named_scope("pairgen"):
                     band = win(tok, xs["starts"], nv.astype(jnp.int32),
                                ob[:, 0], ob[:, 1], win_bases)
-                new_p, metrics = cbow_step_banded_core(
-                    p, tok.reshape(-1),
-                    band.left.reshape(-1), band.right.reshape(-1),
-                    band.center.reshape(-1), band.token.reshape(-1),
-                    negs, alpha, cfg.negatives, W, cfg.sigmoid_mode,
-                    compute_dtype, logits_dtype, with_metrics,
-                    stabilizers=stab)
+                new_p, metrics = inner(
+                    p, {"tokens": tok.reshape(-1), "band": band}, negs, alpha)
                 new_p = jax.lax.with_sharding_constraint(
                     new_p, EmbeddingPair(emb_sharding, emb_sharding))
                 return new_p, (metrics, jnp.int32(0))
@@ -1359,44 +1266,6 @@ class Trainer:
             return jax.lax.scan(body, params, (arrays, alphas, nvalid, negatives))
 
         return jax.jit(banded_chunk, donate_argnums=(0,))
-
-    def _run_hot_scan(self, body_hot, params, xs, K: int):
-        """Cross-step hot-row scan (config.hot_rows — ISSUE 14 / PERF.md §11):
-        the chunk's scan carries the two f32 [K_hot, D] pending-delta slabs
-        beside the params, and the chunk splits into ``steps_per_dispatch /
-        hot_flush_every`` statically-unrolled scan segments with ONE dense
-        prefix-block flush (ops/sgns.hot_flush — no scatter emitter) between
-        segments and after the last. The final flush makes the returned
-        params complete, so the chunk's external contract — (params, stacked
-        per-step outputs) — is unchanged: checkpoints, probes, donation, and
-        the heartbeat metrics path never see a pending slab. ``body_hot``
-        has signature ``((params, slabs), inp) -> ((params, slabs), ys)``;
-        config guarantees ``hot_flush_every`` divides ``K``."""
-        hk, dp = self._hot_rows, self.padded_dim
-        F = min(self._hot_flush, K)
-        # slab accumulation dtype: promote(param, f32) — the R4 discipline
-        # (cross-step bf16 accumulation would round away exactly the small
-        # frequent-row updates the slab batches), never below the params'
-        # own precision (the f64 oracle suite holds the helpers exact)
-        sdt = jnp.promote_types(jnp.dtype(self.config.param_dtype),
-                                jnp.float32)
-
-        def zero_slabs():
-            return (jnp.zeros((hk, dp), sdt), jnp.zeros((hk, dp), sdt))
-
-        carry = (params, zero_slabs())
-        outs = []
-        for si in range(max(1, K // F)):
-            seg = jax.tree.map(lambda a, si=si: a[si * F:(si + 1) * F], xs)
-            carry, ys = jax.lax.scan(body_hot, carry, seg)
-            p, (s0, s1) = carry
-            p = EmbeddingPair(hot_flush(p.syn0, s0), hot_flush(p.syn1, s1))
-            carry = (p, zero_slabs())
-            outs.append(ys)
-        if len(outs) == 1:
-            return carry[0], outs[0]
-        return carry[0], jax.tree.map(
-            lambda *a: jnp.concatenate(a, axis=0), *outs)
 
     def _stage_dispatch_meta(self, meta: np.ndarray, base_step, *bases):
         """Explicitly stage the small per-dispatch host arrays (the meta rows,
@@ -2962,12 +2831,7 @@ class Trainer:
             # sit at norm 1-15, the threshold at 100)
             self._stabilizers = self._stabilizers._replace(
                 max_row_norm=float(cfg.norm_watch_threshold))
-            self._step_fn = self._build_step()
-            self._step_fn_fast = (
-                self._build_step(with_metrics=False)
-                if (cfg.negative_pool > 0 and not cfg.use_pallas
-                    and not (cfg.cbow and cfg.duplicate_scaling))
-                else self._step_fn)
+            self._build_step_twins()
         logger.warning(
             "norm watchdog recovery %d/%d at step %d: rolled back to the "
             "snapshot from step %d, re-seeded the sample lattice (counter -> "

@@ -394,7 +394,6 @@ def test_config_selection_matrix_errors():
          "duplicate_scaling"),
         (dict(cbow=True, cbow_update="banded", negative_pool=0),
          "shared-pool"),
-        (dict(cbow=True, cbow_update="banded", use_pallas=True), "pallas"),
         (dict(cbow=True, cbow_update="banded", tokens_per_step=64),
          "tokens_per_step"),
         (dict(cbow=True, cbow_update="banded", window=1), "window"),

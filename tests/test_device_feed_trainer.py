@@ -157,9 +157,6 @@ def test_device_feed_config_validation():
     with pytest.raises(ValueError, match="skip-gram only"):
         Trainer(Word2VecConfig(min_count=1, device_pairgen=True, cbow=True,
                                negative_pool=8), vocab)
-    with pytest.raises(ValueError, match="use_pallas"):
-        Trainer(Word2VecConfig(min_count=1, device_pairgen=True, use_pallas=True,
-                               negative_pool=8), vocab)
 
 
 def test_device_feed_resume_is_deterministic(tmp_path):

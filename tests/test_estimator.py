@@ -419,8 +419,6 @@ def test_auto_negative_pool_scales_with_batch():
     # shared negatives measurably cost quality on small corpora (toy bf16 gate)
     assert Word2VecConfig(pairs_per_batch=256).negative_pool == 0
     assert Word2VecConfig(pairs_per_batch=4096).negative_pool == 128
-    # the pallas step requires a shared pool — auto never strands it at 0
-    assert Word2VecConfig(pairs_per_batch=256, use_pallas=True).negative_pool == 128
     # explicit choices pass through untouched; 0 keeps the per-pair path
     assert Word2VecConfig(negative_pool=256).negative_pool == 256
     assert Word2VecConfig(negative_pool=0).negative_pool == 0

@@ -1,25 +1,18 @@
-"""ISSUE-14 step restructurings: fused logit chain (config.fused_logits),
-end-to-end bf16 update chain (config.bf16_chain), and cross-step hot-row
-accumulation (config.hot_rows / hot_flush_every).
+"""ISSUE-14 step restructurings: fused logit chain (config.fused_logits) and
+end-to-end bf16 update chain (config.bf16_chain).
 
-Four layers, mirroring the PR-7 stabilizer discipline:
+Three layers, mirroring the PR-7 stabilizer discipline:
 
 1. ORACLE — the fused coefficient chain against a plain-NumPy float64 oracle
    (masked slots, duplicate indices, pool-collision entries, pool edge sizes
    P=1 / odd / P=B), plus fused ≡ classic and bf16_chain ≡ classic at f64.
-2. HOT-ROW SEMANTICS — read-corrected gathers + split scatters + prefix
-   flush reproduce the classic step at f64 (shared-pool and per-pair,
-   duplicates spanning the hot/cold boundary, fully-masked padding batches a
-   no-op), and multi-step slab accumulation with one flush matches stepwise
-   application.
-3. OFF-IS-BIT-IDENTICAL — the PR-7 contract: all three knobs off elide the
-   new ops entirely (identical lowered module, bit-identical trained
-   params vs a default-constructed trainer).
-4. DISPATCH — trainer fits with each knob on every supported feed (host,
-   device_pairgen), shard_map gets the fused chain (cross-lowering f64
-   equivalence), and the config selection matrix refuses every documented
-   illegal combination (graftlint R8 parses the parity; graftcheck executes
-   it).
+2. OFF-IS-BIT-IDENTICAL — the PR-7 contract: both knobs off elide the new
+   ops entirely (identical lowered module, bit-identical trained params vs
+   a default-constructed trainer).
+3. DISPATCH — trainer fits with each knob on, shard_map gets the fused chain
+   (cross-lowering f64 equivalence), and the config selection matrix refuses
+   every documented illegal combination (graftlint R8 parses the parity;
+   graftcheck executes it).
 """
 
 import jax
@@ -32,7 +25,6 @@ from glint_word2vec_tpu.data.pipeline import encode_sentences
 from glint_word2vec_tpu.data.vocab import Vocabulary
 from glint_word2vec_tpu.ops.sgns import (
     EmbeddingPair,
-    hot_flush,
     sgns_step_core,
     sgns_step_shared_core,
 )
@@ -180,105 +172,7 @@ def test_fused_chain_bf16_tracks_f32():
 
 
 # ---------------------------------------------------------------------------
-# 2. Hot-row accumulation semantics
-# ---------------------------------------------------------------------------
-
-
-def _hot_slabs(k, d, dtype=jnp.float64):
-    with jax.enable_x64():
-        return (jnp.zeros((k, d), dtype), jnp.zeros((k, d), dtype))
-
-
-def test_hot_single_step_matches_classic_f64():
-    """One step + flush == the classic step (reads are delta-corrected, the
-    split scatter covers the hot/cold boundary, the flush is exact)."""
-    syn0, syn1, centers, contexts, mask, negs = _inputs()
-    base, mb = _run_shared((syn0, syn1), centers, contexts, mask, negs, 0.05)
-    got, mh, (s0, s1) = _run_shared(
-        (syn0, syn1), centers, contexts, mask, negs, 0.05,
-        hot_slabs=_hot_slabs(16, syn0.shape[1]))
-    with jax.enable_x64():
-        got = EmbeddingPair(hot_flush(got.syn0, s0), hot_flush(got.syn1, s1))
-    np.testing.assert_allclose(np.asarray(got.syn0), np.asarray(base.syn0),
-                               atol=1e-12)
-    np.testing.assert_allclose(np.asarray(got.syn1), np.asarray(base.syn1),
-                               atol=1e-12)
-    # the metrics (loss/f_pos) come from the delta-corrected gathers: exact
-    assert abs(float(mh.loss) - float(mb.loss)) < 1e-12
-
-
-def test_hot_multi_step_accumulation_matches_stepwise_f64():
-    """K steps with the slab carried and ONE flush at the end reproduce K
-    classic steps applied sequentially — the cross-step contract."""
-
-    syn0, syn1, centers, contexts, mask, negs = _inputs()
-    D = syn0.shape[1]
-    with jax.enable_x64():
-        ref = EmbeddingPair(jnp.asarray(syn0), jnp.asarray(syn1))
-        hot = ref
-        slabs = _hot_slabs(16, D)
-        for step in range(4):
-            rng = np.random.default_rng(100 + step)
-            c = jnp.asarray(rng.integers(0, 60, 24), jnp.int32)
-            x = jnp.asarray(rng.integers(0, 60, 24), jnp.int32)
-            ng = jnp.asarray(rng.integers(0, 60, 8), jnp.int32)
-            m = jnp.asarray(np.ones(24), jnp.float32)
-            args = (c, x, m, ng, jnp.float64(0.05), NEG, "exact",
-                    jnp.float64, False, jnp.float64, False)
-            ref, _ = sgns_step_shared_core(ref, *args)
-            hot, _, slabs = sgns_step_shared_core(hot, *args,
-                                                  hot_slabs=slabs)
-        hot = EmbeddingPair(hot_flush(hot.syn0, slabs[0]),
-                            hot_flush(hot.syn1, slabs[1]))
-    np.testing.assert_allclose(np.asarray(hot.syn0), np.asarray(ref.syn0),
-                               atol=1e-11)
-    np.testing.assert_allclose(np.asarray(hot.syn1), np.asarray(ref.syn1),
-                               atol=1e-11)
-
-
-def test_hot_fully_masked_batch_is_noop():
-    """A padding batch (mask all zero, placeholder index 0 = a HOT row) must
-    leave params and slabs exactly unchanged through step + flush."""
-
-    syn0, syn1, centers, contexts, _, negs = _inputs()
-    with jax.enable_x64():
-        params = EmbeddingPair(jnp.asarray(syn0), jnp.asarray(syn1))
-        zeros = jnp.zeros(centers.shape[0], jnp.float32)
-        got, _, (s0, s1) = sgns_step_shared_core(
-            params, jnp.asarray(centers), jnp.asarray(contexts), zeros,
-            jnp.asarray(negs), jnp.float64(0.05), NEG, "exact", jnp.float64,
-            False, jnp.float64, True, hot_slabs=_hot_slabs(16, syn0.shape[1]))
-        got = EmbeddingPair(hot_flush(got.syn0, s0), hot_flush(got.syn1, s1))
-    # the pool rows still receive their (zero-coefficient) scatter adds, so
-    # compare numerically-exact: nothing may move
-    assert np.array_equal(np.asarray(got.syn0), syn0)
-    # syn1 pool rows: zero-valued adds may flip -0.0 signs at most; require
-    # exact values
-    np.testing.assert_array_equal(np.asarray(got.syn1), syn1)
-
-
-def test_perpair_hot_matches_classic_f64():
-    syn0, syn1, centers, contexts, mask, _ = _inputs()
-    rng = np.random.default_rng(9)
-    pn = rng.integers(0, 60, (centers.shape[0], NEG)).astype(np.int32)
-    pn[:, 0] = 1                          # hot negatives with duplicates
-    with jax.enable_x64():
-        params = EmbeddingPair(jnp.asarray(syn0), jnp.asarray(syn1))
-        args = (jnp.asarray(centers), jnp.asarray(contexts),
-                jnp.asarray(mask, jnp.float32), jnp.asarray(pn),
-                jnp.float64(0.05), "exact", jnp.float64, False)
-        base, _ = sgns_step_core(params, *args)
-        hot, _, (s0, s1) = sgns_step_core(
-            params, *args, hot_slabs=_hot_slabs(16, syn0.shape[1]))
-        hot = EmbeddingPair(hot_flush(hot.syn0, s0), hot_flush(hot.syn1, s1))
-    np.testing.assert_allclose(np.asarray(hot.syn0), np.asarray(base.syn0),
-                               atol=1e-12)
-    np.testing.assert_allclose(np.asarray(hot.syn1), np.asarray(base.syn1),
-                               atol=1e-12)
-
-
-# ---------------------------------------------------------------------------
-# 3. Off-is-bit-identical (the PR-7 elision contract)
+# 2. Off-is-bit-identical (the PR-7 elision contract)
 # ---------------------------------------------------------------------------
 
 
@@ -319,45 +213,18 @@ def test_knobs_off_elide_ops_bit_identical():
                                          NEG, **kw)
         return jax.jit(step).lower(params, *args[:4]).as_text()
 
-    assert lower() == lower(fused=False, bf16_chain=False, hot_slabs=None)
+    assert lower() == lower(fused=False, bf16_chain=False)
 
     vocab, enc = _toy()
     a = _fit(vocab, enc, negative_pool=16)
     b = _fit(vocab, enc, negative_pool=16, fused_logits=False,
-             bf16_chain=False, hot_rows=0, hot_flush_every=0)
+             bf16_chain=False)
     assert np.array_equal(a[0], b[0]) and np.array_equal(a[1], b[1])
 
 
 # ---------------------------------------------------------------------------
-# 4. Trainer dispatch, shard_map fused, and the refusal matrix
+# 3. Trainer dispatch, shard_map fused, and the refusal matrix
 # ---------------------------------------------------------------------------
-
-
-def test_trainer_hot_rows_close_to_classic_all_feeds():
-    vocab, enc = _toy()
-    base = _fit(vocab, enc, negative_pool=16)
-    hot = _fit(vocab, enc, negative_pool=16, hot_rows=8)
-    assert np.allclose(base[0], hot[0], atol=2e-6)
-    hot2 = _fit(vocab, enc, negative_pool=16, hot_rows=8, hot_flush_every=2)
-    assert np.allclose(base[0], hot2[0], atol=2e-6)
-    dev = _fit(vocab, enc, negative_pool=16, device_pairgen=True)
-    devh = _fit(vocab, enc, negative_pool=16, device_pairgen=True, hot_rows=8)
-    assert np.allclose(dev[0], devh[0], atol=2e-6)
-    # per-pair path
-    pp = _fit(vocab, enc, negative_pool=0)
-    pph = _fit(vocab, enc, negative_pool=0, hot_rows=8)
-    assert np.allclose(pp[0], pph[0], atol=2e-6)
-
-
-def test_trainer_hot_rows_clamped_to_vocab():
-    vocab, enc = _toy()
-    cfg = Word2VecConfig(vector_size=16, min_count=1, pairs_per_batch=32,
-                         negative_pool=16, steps_per_dispatch=4,
-                         prefetch_chunks=0, hot_rows=10_000)
-    t = Trainer(cfg, vocab, plan=make_mesh(1, 1))
-    assert t._hot_rows == vocab.size
-    t.fit(enc)
-    assert np.isfinite(np.asarray(t.params.syn0, np.float32)).all()
 
 
 def test_trainer_fused_and_chain_fit_smoke():
@@ -367,7 +234,7 @@ def test_trainer_fused_and_chain_fit_smoke():
     assert np.allclose(base[0], fus[0], atol=2e-6)
     bf = _fit(vocab, enc, negative_pool=16, param_dtype="bfloat16",
               compute_dtype="bfloat16", logits_dtype="bfloat16",
-              fused_logits=True, bf16_chain=True, hot_rows=8)
+              fused_logits=True, bf16_chain=True)
     assert np.isfinite(bf[0]).all() and np.abs(bf[0]).sum() > 0
 
 
@@ -409,28 +276,10 @@ def test_shard_map_fused_matches_gspmd_fused_f64():
 
 
 @pytest.mark.parametrize("kw", [
-    dict(hot_rows=4, cbow=True),
-    dict(hot_rows=4, use_pallas=True),
-    dict(hot_rows=4, step_lowering="shard_map"),
-    dict(hot_rows=4, embedding_partition="cols"),
-    dict(hot_rows=4, duplicate_scaling=True),
-    dict(hot_rows=4, max_row_norm=10.0),
-    dict(hot_rows=4, update_clip=0.5),
-    dict(hot_rows=4, row_l2=1e-4),
-    dict(hot_rows=4, norm_watch="recover"),
-    dict(hot_rows=4, num_model_shards=2),
-    dict(hot_rows=4, num_data_shards=2),
-    dict(hot_rows=4, mesh_shape=(2, 4)),
-    dict(hot_rows=4, hot_flush_every=3, steps_per_dispatch=16),
-    dict(hot_rows=4, hot_flush_every=32, steps_per_dispatch=16),
-    dict(hot_rows=-1),
-    dict(hot_flush_every=-1),
     dict(fused_logits=True, cbow=True),
-    dict(fused_logits=True, use_pallas=True),
     dict(fused_logits=True, duplicate_scaling=True),
     dict(bf16_chain=True),                       # compute f32: no chain
     dict(bf16_chain=True, cbow=True, compute_dtype="bfloat16"),
-    dict(bf16_chain=True, use_pallas=True, compute_dtype="bfloat16"),
     dict(bf16_chain=True, compute_dtype="bfloat16", negative_pool=512),
 ])
 def test_config_refusal_matrix(kw):
@@ -439,8 +288,6 @@ def test_config_refusal_matrix(kw):
 
 
 def test_config_legal_combinations_construct():
-    Word2VecConfig(hot_rows=4096)
-    Word2VecConfig(hot_rows=4096, hot_flush_every=16)
     Word2VecConfig(fused_logits=True)
     Word2VecConfig(fused_logits=True, step_lowering="shard_map",
                    pairs_per_batch=8192)
@@ -449,15 +296,7 @@ def test_config_legal_combinations_construct():
     Word2VecConfig(bf16_chain=True, compute_dtype="bfloat16",
                    negative_pool=0)
     # round-trip + replace preserve the knobs
-    c = Word2VecConfig(hot_rows=256, hot_flush_every=8, fused_logits=True)
-    d = Word2VecConfig.from_dict(c.to_dict())
-    assert (d.hot_rows, d.hot_flush_every, d.fused_logits) == (256, 8, True)
-    assert c.replace(seed=5).hot_rows == 256
+    c = Word2VecConfig(fused_logits=True)
+    assert Word2VecConfig.from_dict(c.to_dict()).fused_logits
+    assert c.replace(seed=5).fused_logits
 
-
-def test_trainer_refuses_hot_rows_on_multi_device_plan():
-    vocab, _ = _toy()
-    cfg = Word2VecConfig(vector_size=16, min_count=1, pairs_per_batch=32,
-                         negative_pool=16, hot_rows=8)
-    with pytest.raises(ValueError, match="single-chip"):
-        Trainer(cfg, vocab, plan=make_mesh(2, 4))

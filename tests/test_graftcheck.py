@@ -29,7 +29,6 @@ from tools.graftcheck.shrink import shrink  # noqa: E402
 
 FIRST_RUN_COUNTEREXAMPLES = [
     (dict(device_pairgen=True, cbow=True), "skip-gram only"),
-    (dict(device_pairgen=True, use_pallas=True), "use_pallas"),
     (dict(device_pairgen=True, window=1), "window"),
     (dict(device_pairgen=True, tokens_per_step=200_000, window=100),
      "prefix-sum bound"),
@@ -106,31 +105,32 @@ def test_shrinker_reduces_seeded_violation_to_three_knobs():
     (every registry knob set) and the shrinker must come back with exactly
     the ≤3-knob core."""
     wide = dict(next(iter(lattice.pairwise_tier()))[1])
-    wide.update(cbow=True, use_pallas=True, window=7)
+    wide.update(cbow=True, step_lowering="shard_map", window=7)
     nd = lattice.nondefault(wide)
     assert len(nd) > 10  # genuinely wide before shrinking
 
     def seeded_predicate(kwargs):
-        if (kwargs.get("cbow") and kwargs.get("use_pallas")
+        if (kwargs.get("cbow")
+                and kwargs.get("step_lowering") == "shard_map"
                 and kwargs.get("window") == 7):
             return "seeded-violation"
         return None
 
     assert seeded_predicate(nd) == "seeded-violation"
     small = shrink(nd, seeded_predicate, "seeded-violation")
-    assert set(small) == {"cbow", "use_pallas", "window"}
+    assert set(small) == {"cbow", "step_lowering", "window"}
     assert len(small) <= 3
 
 
 def test_shrinker_finds_real_minimal_combo():
     """Same machinery against the REAL constructor: a kitchen-sink refused
     config shrinks to the documented 2-knob combo."""
-    kwargs = dict(cbow=True, use_pallas=True, vector_size=8, seed=9,
+    kwargs = dict(cbow=True, step_lowering="shard_map", vector_size=8, seed=9,
                   negatives=25, shuffle=False, norm_watch="warn")
     key = properties.construction_key(kwargs)
     assert key and key.startswith("refused")
     small = shrink(kwargs, properties.construction_key, key)
-    assert set(small) == {"cbow", "use_pallas"}
+    assert set(small) == {"cbow", "step_lowering"}
 
 
 # ---------------------------------------------------------------------------
@@ -244,7 +244,7 @@ def test_smoke_sweep_runs_clean_cli():
     assert len(lines) == 1, proc.stdout
     report = json.loads(lines[0])
     assert report["ok"] and report["tool"] == "graftcheck"
-    assert report["knobs"] == 96
+    assert report["knobs"] == 93
     assert report["unexplained_violations"] == 0
     assert report["configs_executed"] >= 200   # the thinned lattice
     assert report["refusal_signatures"], "refusal inventory must be nonempty"

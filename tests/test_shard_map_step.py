@@ -196,8 +196,8 @@ def test_shard_map_device_pairgen_smoke():
 
 
 def test_config_refusals():
-    for kw in (dict(cbow=True), dict(use_pallas=True),
-               dict(duplicate_scaling=True), dict(negative_pool=0),
+    for kw in (dict(cbow=True), dict(duplicate_scaling=True),
+               dict(negative_pool=0),
                dict(embedding_partition="cols")):
         with pytest.raises(ValueError, match="shard_map|lowering"):
             Word2VecConfig(step_lowering="shard_map", **kw)

@@ -1,0 +1,143 @@
+"""The step selection matrix (train/trainer.select_step): which ops-level core
+one configuration trains with, the shape of the negatives its chunk draws and
+whether syn0's update goes to the scatter by center runs — every row the
+function can return, both twins, without building a Trainer."""
+
+import inspect
+
+import pytest
+
+from glint_word2vec_tpu.config import Word2VecConfig
+from glint_word2vec_tpu.ops import cbow_banded, sgns_shard
+from glint_word2vec_tpu.ops.sgns import Stabilizers
+from glint_word2vec_tpu.parallel.mesh import make_mesh
+from glint_word2vec_tpu.train import trainer as trainer_mod
+from glint_word2vec_tpu.train.trainer import _center_run_cap, select_step
+
+B, P, N, K = 64, 16, 5, 4
+BASE = dict(vector_size=16, min_count=1, pairs_per_batch=B, negatives=N,
+            steps_per_dispatch=K)
+POOL, PER_EXAMPLE, WINDOW_POOLS = (K, P), (K, B, N), (K, 2 * P)
+RUNS_W5 = (10, _center_run_cap(5, B))
+
+# where each core is looked up when select_step runs
+CORE_HOME = {
+    "sgns_step_shared_core": trainer_mod,
+    "sgns_step_core": trainer_mod,
+    "cbow_step_shared_core": trainer_mod,
+    "cbow_step_core": trainer_mod,
+    "cbow_step_banded_core": cbow_banded,
+    "make_shard_map_sgns_step": sgns_shard,
+}
+
+# id: (config beside BASE, mesh, feed_segments) -> (core, negatives, center_runs)
+ROWS = {
+    "sgns-shared-gspmd-runs": (
+        dict(negative_pool=P, window=5), (1, 1), 1,
+        ("sgns_step_shared_core", POOL, RUNS_W5)),
+    "sgns-shared-gspmd-window2": (   # two pairs a run or fewer: not built
+        dict(negative_pool=P, window=2), (1, 1), 1,
+        ("sgns_step_shared_core", POOL, None)),
+    "sgns-shared-gspmd-data-axis": (
+        dict(negative_pool=P, window=5), (2, 4), 1,
+        ("sgns_step_shared_core", POOL, None)),
+    "sgns-shared-gspmd-two-feed-segments": (
+        dict(negative_pool=P, window=5), (1, 1), 2,
+        ("sgns_step_shared_core", POOL, None)),
+    "sgns-shared-gspmd-duplicate-scaling": (
+        dict(negative_pool=P, window=5, duplicate_scaling=True), (1, 1), 1,
+        ("sgns_step_shared_core", POOL, RUNS_W5)),
+    "sgns-device-pairgen-runs": (
+        dict(negative_pool=P, window=5, device_pairgen=True), (1, 1), 1,
+        ("sgns_step_shared_core", POOL, RUNS_W5)),
+    "sgns-device-pairgen-data-axis": (
+        dict(negative_pool=P, window=5, device_pairgen=True), (2, 4), 1,
+        ("sgns_step_shared_core", POOL, None)),
+    "sgns-shard-map": (
+        dict(negative_pool=P, window=5, step_lowering="shard_map"), (2, 4), 1,
+        ("make_shard_map_sgns_step", POOL, None)),
+    "sgns-shard-map-sync-every": (
+        dict(negative_pool=P, window=5, step_lowering="shard_map",
+             sync_every=2), (2, 4), 1,
+        ("make_shard_map_sgns_step", WINDOW_POOLS, None)),
+    "sgns-per-pair": (
+        dict(negative_pool=0, window=5), (1, 1), 1,
+        ("sgns_step_core", PER_EXAMPLE, None)),
+    "cbow-banded": (
+        dict(cbow=True, cbow_update="banded", negative_pool=P, window=5),
+        (1, 1), 1, ("cbow_step_banded_core", POOL, None)),
+    "cbow-scatter-shared": (
+        dict(cbow=True, negative_pool=P, window=5), (1, 1), 1,
+        ("cbow_step_shared_core", POOL, None)),
+    "cbow-per-example": (
+        dict(cbow=True, negative_pool=0, window=5), (1, 1), 1,
+        ("cbow_step_core", PER_EXAMPLE, None)),
+    "cbow-per-example-duplicate-scaling": (   # the AUTO pool resolves to 0
+        dict(cbow=True, duplicate_scaling=True, window=5), (1, 1), 1,
+        ("cbow_step_core", PER_EXAMPLE, None)),
+}
+
+
+class _Operand:
+    """Stands for any array a step is handed: nothing here computes."""
+
+    def __getattr__(self, name):
+        return self
+
+    def reshape(self, *shape):
+        return self
+
+
+class _Batch(dict):
+    def __missing__(self, key):
+        return _Operand()
+
+
+@pytest.fixture
+def cores(monkeypatch):
+    """Every core replaced, where select_step finds it, by a recorder of its
+    call bound to the real core's signature."""
+    calls, stubs = [], {}
+    for name, home in CORE_HOME.items():
+        real = inspect.signature(getattr(home, name))
+
+        def stub(*args, _name=name, _real=real, **kwargs):
+            calls.append((_name, _real.bind(*args, **kwargs).arguments))
+            if _name == "make_shard_map_sgns_step":   # the factory's product
+                return lambda *a: calls.append(("shard_map_step", a)) or "out"
+            return "out"
+
+        monkeypatch.setattr(home, name, stub)
+        stubs[name] = stub
+    return calls, stubs
+
+
+@pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
+@pytest.mark.parametrize("row", list(ROWS))
+def test_step_selection(row, with_metrics, cores):
+    calls, stubs = cores
+    kw, mesh, segments, (core, negatives, runs) = ROWS[row]
+    cfg = Word2VecConfig(**BASE, **kw)
+    stab = Stabilizers(update_clip=0.5)
+
+    choice = select_step(cfg, make_mesh(*mesh), segments, stab, with_metrics)
+
+    assert choice.core is stubs[core]
+    assert choice.neg_shape(K, B) == negatives
+    assert choice.center_runs == runs
+    assert choice.step("params", _Batch(), "negatives", "alpha") == "out"
+    # the chosen core ran, once, and no other
+    name, bound = calls[0]
+    assert name == core and len(calls) == (2 if "shard_map" in core else 1)
+    assert bound["stabilizers"] is stab
+    # the twin reaches the cores that have one; the others have one twin
+    has_twin = core not in ("sgns_step_core", "cbow_step_core")
+    assert bound.get("with_metrics", "absent") == (
+        with_metrics if has_twin else "absent")
+    if core == "sgns_step_shared_core":
+        assert bound["center_runs"] == runs
+        assert bound["duplicate_scaling"] == cfg.duplicate_scaling
+    if core == "make_shard_map_sgns_step":
+        assert bound["sync_every"] == cfg.sync_every
+        assert calls[1] == ("shard_map_step",
+                            ("params", {}, "negatives", "alpha"))

@@ -50,8 +50,6 @@ def test_stepaudit_smoke_all_variants():
     chain = result["variants"]["rows_gspmd_bf16_chain"]
     assert chain["dtype"]["dense_f32_bd_free"] is True
     assert chain["dtype"]["dense_f32_vd_free"] is True
-    # the hot-row slab scan holds donation/one-compile on its 1x1 mesh
-    assert result["variants"]["rows_gspmd_hot"]["mesh"] == [1, 1]
     # the recover-rebuild contract (ISSUE 8): one recovery, twins rebuilt
     # once, exactly one extra compile — 2 total for the whole
     # blowup-and-recover fit

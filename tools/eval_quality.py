@@ -572,25 +572,6 @@ def main():
                     help="continual_lr_rewarm for the increment")
     ap.add_argument("--continual-iterations", type=int, default=1,
                     help="continual_iterations for the increment")
-    # --- hot-row parity gate (ISSUE 14 / PERF.md §11): the cross-step
-    # hot-row accumulation changes FP rounding order (f32 slab accumulation
-    # + one flush per chunk instead of per-step param-dtype rounding), so it
-    # ships default-off behind THIS measured A/B: two arms on the identical
-    # corpus/seed, the original per-step scatters vs hot_rows, scored on the
-    # same ladder metrics. Documented tolerance: the hot arm fails parity
-    # when its purity@10 drops more than 0.02 absolute below the classic
-    # arm (analogy reported beside it; both rows land in EVAL_RUNS) ---
-    ap.add_argument("--hotrow-ab", action="store_true",
-                    help="train TWO arms on the identical corpus/seed — "
-                         "classic per-step scatters and hot_rows=--hot-rows "
-                         "— and emit one EVAL_RUNS row per arm "
-                         "(hotrow_ab_arm=classic/hot) plus a parity verdict "
-                         "(purity drop > 0.02 absolute fails)")
-    ap.add_argument("--hot-rows", type=int, default=4096,
-                    help="hot_rows for the hot arm of --hotrow-ab")
-    ap.add_argument("--hot-flush-every", type=int, default=0,
-                    help="hot_flush_every for the hot arm (0 = auto: once "
-                         "per dispatch chunk)")
     # --- local-SGD staleness gate (ISSUE 17 / docs/sharding.md §Local-SGD):
     # sync_every=k trades k× fewer data-axis collective bytes (priced by
     # tools/collectives.py --sync-every) for k−1 steps of gradient staleness
@@ -694,7 +675,7 @@ def main():
         """Train one configuration and score it; appends the EVAL_RUNS row
         (ground-truth corpora only) carrying the requested stabilizer knobs
         AND the engaged end state, and returns the result dict. ``arm_field``
-        names the A/B-arm key the row carries (stab_ab_arm / hotrow_ab_arm /
+        names the A/B-arm key the row carries (stab_ab_arm /
         localsgd_ab_arm), so every A/B harness funnels through this one
         trainer. ``plan`` pins the mesh (the local-SGD A/B needs a real data
         axis; every other caller takes the default)."""
@@ -888,36 +869,6 @@ def main():
             "vocab_base": v_base, "vocab_grown": inc["vocab_size"],
             "new_words": inc["new_words"],
             "arms": [row_pre, row_post]}))
-        return
-
-    if args.hotrow_ab:
-        # the ISSUE-14 parity gate: classic per-step scatters vs hot-row
-        # accumulation, identical corpus/seed, scored on the same ladder.
-        # Documented tolerance: hot-arm purity@10 more than 0.02 absolute
-        # below the classic arm fails parity (the knob then stays off).
-        r_classic = run_arm(dict(hot_rows=0), save_arrays=False,
-                            arm="classic", arm_field="hotrow_ab_arm")
-        r_hot = run_arm(
-            dict(hot_rows=args.hot_rows,
-                 hot_flush_every=args.hot_flush_every),
-            save_arrays=True, arm="hot", arm_field="hotrow_ab_arm")
-        delta = analogy_delta = None
-        if "purity_at_10" in r_classic and "purity_at_10" in r_hot:
-            delta = round(r_hot["purity_at_10"] - r_classic["purity_at_10"],
-                          4)
-        if ("analogy_accuracy_at_1" in r_classic
-                and "analogy_accuracy_at_1" in r_hot):
-            analogy_delta = round(r_hot["analogy_accuracy_at_1"]
-                                  - r_classic["analogy_accuracy_at_1"], 4)
-        print(json.dumps({
-            "metric": "hotrow_ab",
-            "hot_rows": args.hot_rows,
-            "hot_flush_every": args.hot_flush_every,
-            "purity_delta": delta,
-            "analogy_delta": analogy_delta,
-            "parity_ok": (delta is not None and delta >= -0.02),
-            "parity_rule": "hot purity_at_10 >= classic - 0.02 absolute",
-            "arms": [r_classic, r_hot]}))
         return
 
     if args.localsgd_ab:

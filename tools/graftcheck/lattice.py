@@ -37,7 +37,6 @@ REFUSAL_GROUPS: Dict[str, Dict[str, tuple]] = {
         "cbow_update": ("scatter", "banded"),
         "duplicate_scaling": (False, True),
         "negative_pool": (-1, 0, 64),
-        "use_pallas": (False, True),
         "tokens_per_step": (0, 64),
         "window": (1, 2),
     },
@@ -45,22 +44,13 @@ REFUSAL_GROUPS: Dict[str, Dict[str, tuple]] = {
         "step_lowering": ("gspmd", "shard_map"),
         "embedding_partition": ("rows", "cols"),
         "cbow": (False, True),
-        "use_pallas": (False, True),
         "duplicate_scaling": (False, True),
         "negative_pool": (-1, 0, 64),
         "sharded_checkpoint": (False, True),
     },
-    "pallas-stabilizers": {
-        "use_pallas": (False, True),
-        "max_row_norm": (0.0, 50.0),
-        "update_clip": (0.0, 0.5),
-        "row_l2": (0.0, 1e-4),
-        "norm_watch": ("off", "warn", "recover", "halt"),
-    },
     "device-feed": {
         "device_pairgen": (False, True),
         "cbow": (False, True),
-        "use_pallas": (False, True),
         "window": (1, 2, 127),
         "tokens_per_step": (0, 64, 200_000),
         "shard_input": (True, False),

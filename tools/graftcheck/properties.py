@@ -110,7 +110,6 @@ def check_serialization(cfg) -> Finding:
 # knob (seed — the flip that historically FROZE the resolved pool)
 REPLACE_FLIPS = (
     ("cbow", True),
-    ("use_pallas", True),
     ("step_lowering", "shard_map"),
     ("cbow_update", "banded"),
     ("duplicate_scaling", True),

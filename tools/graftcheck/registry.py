@@ -77,14 +77,11 @@ KNOBS = {k.name: k for k in [
     _K("param_dtype", ("float32", "bfloat16"), invalid="float8"),
     _K("compute_dtype", ("float32", "bfloat16"), invalid="float8"),
     _K("logits_dtype", ("float32", "bfloat16"), invalid="float64"),
-    # --- ISSUE-14 step restructurings (PERF.md §11): all three gate
-    # dispatch-path selection and carry multi-knob refusals, so none is
+    # --- ISSUE-14 step restructurings (PERF.md §11): both gate
+    # dispatch-path selection and carry multi-knob refusals, so neither is
     # dispatch-inert ---
     _K("fused_logits", (False, True)),
     _K("bf16_chain", (False, True)),
-    _K("hot_rows", (0, 8), invalid=-1),
-    _K("hot_flush_every", (0, 2), invalid=-1),
-    _K("use_pallas", (False, True)),
     _K("sharded_checkpoint", (False, True)),
     _K("cbow", (False, True)),
     _K("cbow_update", ("scatter", "banded"), invalid="fused"),

@@ -68,12 +68,9 @@ GATED: Dict[str, float] = {
     "cbow_examples_per_sec": 0.20,
     # --- ISSUE-14 restructured step rows (gated only once a rung carries
     # them — r01-r05 predate the knobs). Same harness/trial structure as
-    # the step rows above, so the same 0.12 band; the hot-row arm adds the
-    # slab-scan/flush structure whose relative cost is geometry-sensitive,
-    # hence the step-row-widest 0.15 ---
+    # the step rows above, so the same 0.12 band ---
     "step_fused_pairs_per_sec": 0.12,
     "step_bf16_chain_pairs_per_sec": 0.12,
-    "step_hotrow_pairs_per_sec": 0.15,
     # --- flat per-row scalars (ISSUE 17 satellite): bench.py now emits one
     # `step_<row>_pairs_per_sec` per step row as a top-level scalar, the
     # PREFERRED gate names going forward — every step row gets gated by a
@@ -87,7 +84,6 @@ GATED: Dict[str, float] = {
     "step_bf16_p512_pairs_per_sec": 0.12,
     "step_bf16_p1024_pairs_per_sec": 0.12,
     "step_bf16_fused_pairs_per_sec": 0.12,
-    "step_bf16_hot_pairs_per_sec": 0.15,
 }
 
 # legacy top-level name -> flat per-row name (back-fill for rungs that
@@ -96,7 +92,6 @@ GATED: Dict[str, float] = {
 _FLAT_ALIASES = {
     "step_f32_pairs_per_sec": "step_f32_p512_pairs_per_sec",
     "step_fused_pairs_per_sec": "step_bf16_fused_pairs_per_sec",
-    "step_hotrow_pairs_per_sec": "step_bf16_hot_pairs_per_sec",
 }
 
 # the SERVING trajectory's bands (--kind serve, SERVEBENCH_r*.json from

@@ -65,13 +65,11 @@ VARIANTS = ("rows_gspmd", "shard_map", "cols", "cbow_banded",
             # break aliasing), transfers, dtype (stabilizer norm math is
             # promote(dtype, f32) — no f64 creep), one-compile
             "rows_gspmd_stab", "shard_map_stab",
-            # ISSUE-14 step restructurings: the fused coefficient chain, the
-            # cross-step hot-row slab scan (segmented scans + prefix flush
-            # must keep donation/transfers/one-compile), and the end-to-end
-            # bf16 chain twin, which additionally carries the NEW dtype
-            # contract — no dense f32 [B, D] intermediate in the lowered
-            # bf16 module (dense_f32_bd_free)
-            "rows_gspmd_fused", "rows_gspmd_hot", "rows_gspmd_bf16_chain",
+            # ISSUE-14 step restructurings: the fused coefficient chain and
+            # the end-to-end bf16 chain twin, which additionally carries the
+            # NEW dtype contract — no dense f32 [B, D] intermediate in the
+            # lowered bf16 module (dense_f32_bd_free)
+            "rows_gspmd_fused", "rows_gspmd_bf16_chain",
             # ISSUE-17 local-SGD: the sync_every=k owner-local window — the
             # k-step unrolled shard_map body plus the delta-merge psum must
             # keep donation (window params carry aliased), transfers, dtype,
@@ -120,8 +118,6 @@ def _variant_config_kwargs(variant: str) -> dict:
                     max_row_norm=50.0, update_clip=0.5, row_l2=1e-4)
     if variant == "rows_gspmd_fused":
         return dict(negative_pool=16, fused_logits=True)
-    if variant == "rows_gspmd_hot":
-        return dict(negative_pool=16, hot_rows=8, hot_flush_every=2)
     if variant == "rows_gspmd_bf16_chain":
         return dict(negative_pool=16, param_dtype="bfloat16",
                     compute_dtype="bfloat16", logits_dtype="bfloat16",
@@ -188,11 +184,6 @@ def audit_variant(variant: str, mesh_shape, geom: dict) -> dict:
     from glint_word2vec_tpu.train.trainer import Trainer
 
     vocab, enc = _toy_problem(geom)
-    if variant == "rows_gspmd_hot":
-        # the hot-row restructuring is the single-chip path by contract
-        # (config refuses multi-shard meshes, the trainer refuses
-        # multi-device plans — PERF.md §11); audit it where it runs
-        mesh_shape = (1, 1)
     plan = make_mesh(*mesh_shape)
     cfg = Word2VecConfig(
         vector_size=geom["d"], min_count=1, pairs_per_batch=geom["b"],
