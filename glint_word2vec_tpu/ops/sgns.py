@@ -184,7 +184,8 @@ def run_sums(rows: jax.Array, pos: jax.Array, max_run: int,
 
 
 def scatter_add_by_runs(mat: jax.Array, idx: jax.Array, rows: jax.Array,
-                        max_run: int, cap: int) -> Tuple[jax.Array, jax.Array]:
+                        max_run: int, cap: int,
+                        sort: bool = False) -> Tuple[jax.Array, jax.Array]:
     """``mat.at[idx].add(rows)`` that hands the scatter ONE row per run of
     equal neighbouring ``idx``: ``(new_mat, rows_handed_over)``.
 
@@ -198,22 +199,30 @@ def scatter_add_by_runs(mat: jax.Array, idx: jax.Array, rows: jax.Array,
 
     The native pair feed emits a center's pairs consecutively
     (native/pairgen.cpp), so by ``centers`` a batch has ~1/4 as many heads as
-    pairs at window 5; pairs sorted by context would give the same for syn1.
+    pairs at window 5. ``contexts`` come in no order: ``sort`` makes the runs
+    here, by a stable 1-D sort of ``idx`` that carries the positions, and the
+    coalesced branch reads ``rows`` in that order (one row gather).
     The step decides from its own batch: one with more heads than ``cap``
     (rows that all differ, a feed that does not emit runs) takes the plain
-    scatter, the same op on the same ``rows`` as without this function. A row
-    of ``mat`` receives the same sum of the same ``rows`` either way;
+    scatter, the same op on the same unsorted ``rows`` as without this
+    function. A row of ``mat`` receives the same sum of the same ``rows``
+    either way, in the order the batch holds them (the sort is stable);
     coalesced, the float additions run over the run first, then into the row."""
     n, v = idx.shape[0], mat.shape[0]
-    pos = run_positions(idx, max_run)
+    at = jnp.arange(n, dtype=jnp.int32)
+    keys, order = (jax.lax.sort((idx, at), num_keys=1, is_stable=True)
+                   if sort else (idx, None))
+    pos = run_positions(keys, max_run)
     head = pos == 0
     heads = head.sum(dtype=jnp.int32)
 
     def coalesced(mat):
-        sums = run_sums(rows, pos, max_run, mat.dtype)
-        at = jnp.sort(jnp.where(head, jnp.arange(n, dtype=jnp.int32), n))[:cap]
-        src = jnp.minimum(at, n - 1)
-        return mat.at[jnp.where(at < n, idx[src], v)].add(sums[src], mode="drop")
+        by_run = rows if order is None else rows[order]
+        sums = run_sums(by_run, pos, max_run, mat.dtype)
+        live = jnp.sort(jnp.where(head, at, n))[:cap]
+        src = jnp.minimum(live, n - 1)
+        return mat.at[jnp.where(live < n, keys[src], v)].add(
+            sums[src], mode="drop")
 
     def plain(mat):
         return mat.at[idx].add(rows.astype(mat.dtype))
@@ -241,6 +250,9 @@ class StepMetrics(NamedTuple):
     # update rows that reached syn0's scatter with a live index (the shared-pool
     # SGNS step: B plain, one per center run coalesced); None = not counted
     syn0_rows: Optional[jax.Array] = None
+    # the same for syn1's context scatter (one per context run of the batch
+    # sorted by context); the pool rows' scatter is not counted
+    syn1_rows: Optional[jax.Array] = None
 
 
 def init_embeddings(
@@ -589,6 +601,7 @@ def sgns_step_shared_core(
     fused: bool = False,
     bf16_chain: bool = False,
     center_runs: Optional[Tuple[int, int]] = None,
+    context_runs: Optional[Tuple[int, int]] = None,
 ) -> Tuple[EmbeddingPair, StepMetrics]:
     """:func:`sgns_step_shared` with the pool supplied by the caller (see
     :func:`sgns_step_core` for why sampling lives outside the jitted scan).
@@ -599,6 +612,13 @@ def sgns_step_shared_core(
     (about a quarter of B at window 5) where it holds at most ``cap`` runs,
     and takes the plain scatter where it does not. The trainer derives both
     numbers from ``config.window``; nothing else in the step changes.
+
+    ``context_runs`` ``(max_run, cap)``: the same for syn1's context update,
+    whose runs the helper makes by sorting the batch's pairs by context inside
+    the step (a batch holds ~0.18 distinct contexts a pair at V = 3M, PERF.md
+    §6, PR 30); the trainer derives the cap from the vocabulary's counts. The
+    pool rows' scatter, the stabilizers' post-pass and ``duplicate_scaling``
+    read ``contexts``, not the order, and are as without it.
 
     ``fused``/``bf16_chain`` (config.fused_logits / config.bf16_chain —
     ISSUE 14): the fused coefficient chain and the f32-accumulating dot
@@ -698,7 +718,18 @@ def sgns_step_shared_core(
             new_syn0, syn0_rows = scatter_add_by_runs(
                 syn0, centers, d_in, *center_runs)
     with jax.named_scope("sgns.scatter_syn1"):
-        new_syn1 = syn1.at[contexts].add(d_pos.astype(dtype))
+        if context_runs is None:
+            new_syn1 = syn1.at[contexts].add(d_pos.astype(dtype))
+            syn1_rows = jnp.float32(contexts.shape[0])
+        else:
+            # a conditional updates its table in place only where every read
+            # of that table is ordered before it. d_pos does not depend on the
+            # pool rows' gather (Z), so tie the two: left unordered, XLA
+            # copies syn1 into the branch and back, 14 ms a step at V = 3M
+            # (PERF.md §6, PR 30)
+            d_pos, _ = jax.lax.optimization_barrier((d_pos, Z))
+            new_syn1, syn1_rows = scatter_add_by_runs(
+                syn1, contexts, d_pos, *context_runs, sort=True)
         new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
     if stabilizers is not None and stabilizers.post_pass:
         enable = (mask.sum() > 0).astype(jnp.float32)
@@ -722,6 +753,7 @@ def sgns_step_shared_core(
         mean_f_pos=mean_f_pos,
         pairs=mask.sum(),
         syn0_rows=syn0_rows,
+        syn1_rows=syn1_rows,
     )
     return EmbeddingPair(new_syn0, new_syn1), metrics
 

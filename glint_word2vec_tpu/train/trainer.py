@@ -92,6 +92,42 @@ def _center_run_cap(window: int, batch: int) -> int:
     return -(-int(1.4 * runs_per_pair * batch) // eighth) * eighth
 
 
+# pairs in a piece of a context run (ops/sgns.run_sums makes one shifted add
+# for each beyond the first): named, with the cap's room, by the chip (PERF.md
+# §6, PR 30)
+_CONTEXT_MAX_RUN = 6
+
+
+def _context_run_cap(counts: np.ndarray, train_words_count: int,
+                     subsample_ratio: float, window: int, batch: int) -> int:
+    """Static row cap of the step's coalesced syn1 scatter
+    (ops/sgns.scatter_add_by_runs on the batch sorted by context), 0 = do not
+    build it. Unlike center runs, context runs are a property of the corpus:
+    sorted by context a batch holds one run per distinct context word, cut
+    every :data:`_CONTEXT_MAX_RUN` pairs. Its contexts are its kept tokens,
+    ``batch`` / :func:`_pairs_per_kept_token` of them, each drawn from the
+    kept-token distribution p: Σ 1 − (1 − p_w)^tokens distinct words expected,
+    and a word expected in more than a run's pairs (``batch`` · p_w) adds a
+    piece per run's length of them. At V = 3M / 10M that reads 16,218 /
+    16,472 where feed batches hold 16,440-17,050 (sentence ends clip windows,
+    so a batch holds ~5% more tokens): 20% of room, in sixteenths of the batch
+    (20,480 of 65,536). An estimate over half the batch builds nothing."""
+    from glint_word2vec_tpu.data.pipeline import keep_probabilities
+    kept = np.asarray(counts, np.float64) * keep_probabilities(
+        counts, train_words_count, subsample_ratio)
+    total = kept.sum()
+    if batch < 16 or total <= 0:
+        return 0
+    p = kept / total
+    tokens = batch / _pairs_per_kept_token(window)
+    distinct = -np.expm1(tokens * np.log1p(-np.minimum(p, 1 - 1e-12))).sum()
+    pairs = batch * p
+    pieces = pairs[pairs > _CONTEXT_MAX_RUN].sum() / _CONTEXT_MAX_RUN
+    sixteenth = batch // 16
+    cap = -(-int(1.2 * (distinct + pieces)) // sixteenth) * sixteenth
+    return cap if cap <= batch // 2 else 0
+
+
 class StepChoice(NamedTuple):
     """One row of the step selection matrix (:func:`select_step`)."""
 
@@ -102,10 +138,12 @@ class StepChoice(NamedTuple):
     neg_shape: Callable[[int, int], Tuple[int, ...]]  # (K, B) -> one chunk's negatives
     # (max_run, cap) where syn0's update goes to the scatter by center runs
     center_runs: Optional[Tuple[int, int]]
+    # the same for syn1's context update, by runs of the batch sorted by context
+    context_runs: Optional[Tuple[int, int]] = None
 
 
 def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
-                stabilizers: Optional[Stabilizers],
+                context_cap: int, stabilizers: Optional[Stabilizers],
                 with_metrics: bool) -> StepChoice:
     """The step selection matrix: which update one configuration trains with.
     Every legal combination is one row, read top to bottom; what is on no row
@@ -121,8 +159,10 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
       False  —            > 0  False              "shard_map"    make_shard_map_sgns_step    [K, P]
                                                   sync_every>1   (the same, windowed)        [K, nd·P]
       False  —            > 0  any                gspmd          sgns_step_shared_core       [K, P]
-                                                                 (+ center_runs, below)
+                                                                 (+ center_runs and
+                                                                 context_runs, below)
 
+    ``context_cap`` is :func:`_context_run_cap` of the trainer's vocabulary,
     ``stabilizers`` is the trainer's state (None = all off), ``with_metrics``
     the twin; the rows without a ``with_metrics`` form have one twin."""
     compute_dtype = jnp.dtype(cfg.compute_dtype)
@@ -213,6 +253,11 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
     if plan.num_data == 1 and feed_segments == 1:
         cap = _center_run_cap(cfg.window, cfg.pairs_per_batch)
         runs = (2 * cfg.window, cap) if cap else None
+    # syn1's, one row per run of the pairs sorted by context: the step sorts,
+    # so the feed's order and its segments do not matter, a data axis does
+    context_runs = None
+    if plan.num_data == 1 and context_cap:
+        context_runs = (_CONTEXT_MAX_RUN, context_cap)
 
     def step(params, batch, negatives, alpha):
         return sgns_step_shared_core(
@@ -220,9 +265,10 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
             negatives, alpha, n, cfg.sigmoid_mode, compute_dtype,
             cfg.duplicate_scaling, logits_dtype, with_metrics,
             stabilizers=stabilizers, fused=fused, bf16_chain=chain,
-            center_runs=runs)
+            center_runs=runs, context_runs=context_runs)
 
-    return StepChoice(sgns_step_shared_core, step, shared_pool, runs)
+    return StepChoice(sgns_step_shared_core, step, shared_pool, runs,
+                      context_runs)
 
 
 @dataclass
@@ -1001,6 +1047,11 @@ class Trainer:
     def _build_step_twins(self) -> None:
         """(Re)build both step twins from the trainer's current state (at
         construction, and again when a recovery engages ``max_row_norm``)."""
+        cfg = self.config
+        # select_step's SGNS shared-pool row reads it; CBOW has no such row
+        self._context_cap = 0 if cfg.cbow else _context_run_cap(
+            self.vocab.counts, self.vocab.train_words_count,
+            cfg.subsample_ratio, cfg.window, cfg.pairs_per_batch)
         self._step_fn = self._build_step()
         # fast twin (metrics elided) for the shared-pool paths (skip-gram and
         # CBOW): the paths whose loss side-channel is an extra full [B, pool]
@@ -1047,8 +1098,8 @@ class Trainer:
         # when all off, so the default step compiles bit-identical to the
         # pre-stabilizer step.
         stab = self._stabilizers if self._stabilizers.enabled else None
-        choice = select_step(cfg, self.plan, self._feed_segments, stab,
-                             with_metrics)
+        choice = select_step(cfg, self.plan, self._feed_segments,
+                             self._context_cap, stab, with_metrics)
         inner, neg_shape = choice.step, choice.neg_shape
         # np.uint32 (not a Python int): any negative or 64-bit seed masked to 32 bits
         # lands in [2^31, 2^32), which jnp.asarray rejects under int32 canonicalization
@@ -3151,14 +3202,18 @@ class Trainer:
             # audit's scripted fits are too short to hit; tests/test_obs.py
             # runs a probing fit under the guard to keep this path honest)
             with self._tracer.span("device_block") as blocked:
-                loss_k, fpos_k, pairs_k, rows_k = jax.device_get(
+                loss_k, fpos_k, pairs_k, rows0_k, rows1_k = jax.device_get(
                     (metrics.loss, metrics.mean_f_pos, metrics.pairs,
-                     metrics.syn0_rows))
-                if rows_k is not None and pairs_k[real - 1] > 0:
-                    # how far the step coalesced syn0's update: 1.0 plain,
-                    # heads over pairs where center runs were summed first
-                    blocked.set(syn0_rows_per_pair=float(
-                        rows_k[real - 1] / pairs_k[real - 1]))
+                     metrics.syn0_rows, metrics.syn1_rows))
+                if rows0_k is not None and pairs_k[real - 1] > 0:
+                    # how far the step coalesced each table's update: 1.0
+                    # plain, heads over pairs where runs were summed first
+                    # (syn0's by center, syn1's by context)
+                    blocked.set(
+                        syn0_rows_per_pair=float(
+                            rows0_k[real - 1] / pairs_k[real - 1]),
+                        syn1_rows_per_pair=float(
+                            rows1_k[real - 1] / pairs_k[real - 1]))
             # per-phase attribution over THIS heartbeat window (obs/
             # phases.py): delta of the accumulator the spans + wait sites
             # have been feeding since the previous heartbeat

@@ -1,10 +1,13 @@
-"""syn0's update coalesced by center run (ops/sgns.scatter_add_by_runs).
+"""syn0's update coalesced by center run, syn1's by context run
+(ops/sgns.scatter_add_by_runs).
 
 The pair feed emits a center's pairs consecutively, so the shared-pool SGNS
 step sums each run of equal neighbouring centers first and hands the scatter
 one row a run, where a batch holds at most ``cap`` runs; a batch with more
-takes the plain scatter, bit for bit. Every case runs in float32 and in the
-benchmark cell's bfloat16 compute dtype (the tables stay float32).
+takes the plain scatter, bit for bit. Contexts come in no order: the helper
+sorts them inside the step (``sort=True``) and does the same with their runs
+(the ``context_*`` cases). Every case runs in float32 and in the benchmark
+cell's bfloat16 compute dtype (the tables stay float32).
 """
 
 import os
@@ -29,7 +32,12 @@ from glint_word2vec_tpu.ops.sgns import (
     sgns_step_shared_core,
 )
 from glint_word2vec_tpu.train import trainer as trainer_mod
-from glint_word2vec_tpu.train.trainer import Trainer, _center_run_cap
+from glint_word2vec_tpu.train.trainer import (
+    _CONTEXT_MAX_RUN,
+    Trainer,
+    _center_run_cap,
+    _context_run_cap,
+)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
@@ -42,12 +50,15 @@ V, D, B, P, WINDOW = 2000, 24, 1024, 32, 5
 MAX_RUN = 2 * WINDOW
 CAP = _center_run_cap(WINDOW, B)
 ALPHA, NEG = 0.05, 5
+COUNTS = np.maximum(1e6 / (np.arange(V) + 10.0) ** 1.07, 5.0).astype(np.int64)
+# the context side's pair, as a trainer over this vocabulary derives it
+CTX_RUN = _CONTEXT_MAX_RUN
+CTX_CAP = _context_run_cap(COUNTS, int(COUNTS.sum()), 0.0, WINDOW, B)
 
 
 def _vocab_and_sentences(seed=0, n_tokens=40_000):
-    counts = np.maximum(1e6 / (np.arange(V) + 10.0) ** 1.07, 5.0)
-    vocab = Vocabulary.from_words_and_counts(
-        [f"w{i}" for i in range(V)], counts.astype(np.int64))
+    counts = COUNTS.astype(np.float64)
+    vocab = Vocabulary.from_words_and_counts([f"w{i}" for i in range(V)], COUNTS)
     rng = np.random.default_rng(seed)
     toks = rng.choice(V, n_tokens, p=counts / counts.sum()).astype(np.int32)
     return vocab, [toks[i:i + 40] for i in range(0, n_tokens, 40)]
@@ -82,12 +93,14 @@ def _add_at(idx, rows):
     return want
 
 
-def _helper_against_add_at(idx, dtype, expect_rows=None, rows=None):
-    """scatter_add_by_runs on a zero table against np.add.at in float64."""
+def _helper_against_add_at(idx, dtype, expect_rows=None, by_context=False):
+    """scatter_add_by_runs on a zero table against np.add.at in float64: by
+    the runs ``idx`` comes in, or (``by_context``) by those its own sort makes."""
     idx = jnp.asarray(idx, jnp.int32)
-    rows = _rows(dtype) if rows is None else rows
-    got, handed = jax.jit(scatter_add_by_runs, static_argnums=(3, 4))(
-        jnp.zeros((V, D), jnp.float32), idx, rows, MAX_RUN, CAP)
+    rows = _rows(dtype)
+    runs = (CTX_RUN, CTX_CAP, True) if by_context else (MAX_RUN, CAP, False)
+    got, handed = jax.jit(scatter_add_by_runs, static_argnums=(3, 4, 5))(
+        jnp.zeros((V, D), jnp.float32), idx, rows, *runs)
     want = _add_at(idx, rows)
     # float32 sums of up to B rows: to rounding of the largest entry
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-6,
@@ -111,9 +124,13 @@ def _step(params, c, x, mask, dtype, runs, **kw):
     return strict(params)
 
 
-def _both(c, x, mask, dtype, **kw):
+def _both(c, x, mask, dtype, by_context=False, **kw):
+    """The plain step against the step with syn0's update coalesced, or
+    (``by_context``) with both tables' updates coalesced."""
     params = _tables()
     plain, m0 = _step(params, c, x, mask, dtype, None, **kw)
+    if by_context:
+        kw = dict(kw, context_runs=(CTX_RUN, CTX_CAP))
     runs, m1 = _step(params, c, x, mask, dtype, (MAX_RUN, CAP), **kw)
     return params, plain, m0, runs, m1
 
@@ -262,9 +279,138 @@ def case_update_clip_and_duplicate_scaling(dtype):
         assert float(m1.syn0_rows) < 0.4 * B
 
 
-def case_both_step_twins_one_program_each(dtype):
+# ---- syn1 by context run: the helper sorts inside the step ------------------------------
+
+def _ctx_heads(x):
+    """Heads the step counts in a batch's contexts: one per CTX_RUN of a word."""
+    xs = jnp.sort(jnp.asarray(x, jnp.int32))
+    return int((np.asarray(run_positions(xs, CTX_RUN)) == 0).sum())
+
+
+def _both_tables_close(params, plain, runs, limit=1e-5):
+    for got, want, start in zip(runs, plain, params):
+        want = np.asarray(want) - np.asarray(start)
+        got = np.asarray(got) - np.asarray(start)
+        assert np.linalg.norm(got - want) <= limit * np.linalg.norm(want)
+
+
+def case_context_feed_against_add_at(dtype):
+    """The helper with its own sort on the feed's contexts, as they come."""
+    for _, x in feed_batches():
+        heads = _ctx_heads(x)
+        assert len(np.unique(x)) <= heads <= CTX_CAP < 0.4 * B
+        _helper_against_add_at(x, dtype, expect_rows=heads, by_context=True)
+
+
+def case_context_feed_against_reference(dtype):
+    """Three steps on feed batches with BOTH updates coalesced against
+    benchmark/reference/sgns_ref: syn0's and syn1's change norms."""
+    batches = feed_batches()
+    negs = np.random.default_rng(11).integers(0, V, (len(batches), P)).astype(np.int32)
+    params = init = _tables()
+    step = jax.jit(lambda p, c, x, n: sgns_step_shared_core(
+        p, c, x, jnp.ones(B, jnp.float32), n, jnp.float32(ALPHA), NEG, "exact",
+        dtype, logits_dtype=dtype, center_runs=(MAX_RUN, CAP),
+        context_runs=(CTX_RUN, CTX_CAP)))
+    with jax.default_matmul_precision("highest"):
+        for (c, x), n in zip(batches, negs):
+            params, metrics = step(params, jnp.asarray(c), jnp.asarray(x), jnp.asarray(n))
+            assert float(metrics.syn0_rows) < 0.4 * B
+            assert float(metrics.syn1_rows) == _ctx_heads(x)
+    ref = sgns_ref.follow_steps(
+        init.syn0, init.syn1, [jnp.asarray(c) for c, _ in batches],
+        [jnp.asarray(x) for _, x in batches], jnp.asarray(negs),
+        [ALPHA] * len(batches), NEG)
+    got = (sgns_ref.leaf_norm(params.syn0 - init.syn0),
+           sgns_ref.leaf_norm(params.syn1 - init.syn1))
+    limit = 1e-5 if dtype == jnp.float32 else 5e-3
+    for g, w in zip(got, ref["change_norm"]):
+        assert abs(g - w) / w < limit
+
+
+def case_context_feed_step_against_plain(dtype):
+    for c, x in feed_batches():
+        params, plain, m0, runs, m1 = _both(c, x, np.ones(B), dtype, True)
+        _both_tables_close(params, plain, runs)
+        assert float(m0.syn1_rows) == B and float(m1.syn1_rows) == _ctx_heads(x)
+        assert float(m0.loss) == float(m1.loss) and float(m1.pairs) == B
+
+
+def case_context_runs_longer_than_max_run(dtype):
+    # 25 pairs a context, scattered over the batch: sorted, every run is cut
+    # into five pieces (6 + 6 + 6 + 6 + 1)
+    words = np.arange(100, 100 + B // 25 + 1)
+    x = np.random.default_rng(6).permutation(np.repeat(words, 25)[:B])
+    pieces = sum(-(-int(n) // CTX_RUN) for n in np.bincount(x)[100:])
+    assert pieces <= CTX_CAP
+    _helper_against_add_at(x, dtype, expect_rows=pieces, by_context=True)
+
+
+def case_context_masked_tail(dtype):
+    c, x = feed_batches(1)[0]
+    real = 700
+    c, x = c.copy(), x.copy()
+    c[real:], x[real:] = 0, 0
+    mask = (np.arange(B) < real).astype(np.float32)
+    params, plain, _, runs, m1 = _both(c, x, mask, dtype, True)
+    _both_tables_close(params, plain, runs)
+    assert float(m1.pairs) == real
+    # the masked slots sort to the front as one run of row 0 with zero updates
+    assert float(m1.syn1_rows) == _ctx_heads(x)
+    assert _ctx_heads(x) >= -(-(B - real) // CTX_RUN)
+
+
+def case_context_every_pair_masked(dtype):
+    z = np.zeros(B, np.int32)
+    params, _, _, runs, m1 = _both(z, z, np.zeros(B), dtype, True)
+    np.testing.assert_array_equal(np.asarray(runs.syn0), np.asarray(params.syn0))
+    # the pool rows' scatter adds zeros too: no pair is valid
+    np.testing.assert_array_equal(np.asarray(runs.syn1), np.asarray(params.syn1))
+    assert float(m1.pairs) == 0 and float(m1.syn1_rows) == -(-B // CTX_RUN)
+
+
+def case_context_all_rows_different_bit_equal(dtype):
+    # the benchmark's check batches: both updates take their plain scatter
+    rng = np.random.default_rng(3)
+    c, x = rng.permutation(V)[:B], rng.permutation(V)[:B]
+    _, plain, _, runs, m1 = _both(c.astype(np.int32), x.astype(np.int32),
+                                     np.ones(B), dtype, True)
+    np.testing.assert_array_equal(np.asarray(runs.syn0), np.asarray(plain.syn0))
+    np.testing.assert_array_equal(np.asarray(runs.syn1), np.asarray(plain.syn1))
+    assert float(m1.syn0_rows) == B and float(m1.syn1_rows) == B
+
+
+def case_context_heads_over_cap_bit_equal(dtype):
+    # every context twice, far apart: B/2 heads > cap, the parent's scatter on
+    # the parent's unsorted rows
+    half = np.random.default_rng(4).permutation(V)[:B // 2]
+    x = np.concatenate([half, half[::-1]]).astype(np.int32)
+    c = feed_batches(1)[0][0]
+    assert B // 2 > CTX_CAP
+    _, plain, _, runs, m1 = _both(c, x, np.ones(B), dtype, True)
+    np.testing.assert_array_equal(np.asarray(runs.syn1), np.asarray(plain.syn1))
+    assert float(m1.syn1_rows) == B and float(m1.syn0_rows) < 0.4 * B
+
+
+def case_context_update_clip_and_duplicate_scaling(dtype):
+    # both act per pair, ahead of the sort and the run sums; the post-pass
+    # and duplicate_scaling's counts read contexts, not the order
+    c, x = feed_batches(1)[0]
+    for kw in (dict(stabilizers=Stabilizers(update_clip=0.002)),
+               dict(duplicate_scaling=True),
+               dict(stabilizers=Stabilizers(update_clip=0.002, max_row_norm=2.0),
+                    duplicate_scaling=True)):
+        params, plain, _, runs, m1 = _both(c, x, np.ones(B), dtype, True, **kw)
+        # float32 sums in another order, at the table's magnitude (rows of
+        # norm ~1.5 against a change of 0.06): a few ulps of the row
+        _both_tables_close(params, plain, runs, limit=3e-5)
+        assert float(m1.syn1_rows) == _ctx_heads(x)
+
+
+def _fit_with_and_without(dtype, cap_fn, arg):
     """Through Trainer.fit: both twins coalesce, compile once, report the share
-    on the heartbeat's device_block span, and train what the plain step trains."""
+    on the heartbeat's device_block span as ``arg``, and train what the step
+    trains with ``cap_fn`` (the trainer's derivation of that cap) giving 0."""
     vocab, sents = _vocab_and_sentences(n_tokens=30_000)
     cfg = Word2VecConfig(
         vector_size=D, window=WINDOW, negatives=NEG, min_count=1,
@@ -275,17 +421,17 @@ def case_both_step_twins_one_program_each(dtype):
     def fit(coalesce):
         # spans are recorded under run telemetry; each fit clears the ring
         run_dir = tempfile.mkdtemp(prefix="coalesce_")
-        cap = trainer_mod._center_run_cap
+        cap = getattr(trainer_mod, cap_fn)
         if not coalesce:
-            trainer_mod._center_run_cap = lambda window, batch: 0
+            setattr(trainer_mod, cap_fn, lambda *a: 0)
         try:
             t = Trainer(dc_replace(cfg, telemetry_path=os.path.join(run_dir, "run.jsonl")),
                         vocab)
         finally:
-            trainer_mod._center_run_cap = cap
+            setattr(trainer_mod, cap_fn, cap)
         try:
             t.fit(sents)
-            shares = [e["args"]["syn0_rows_per_pair"] for e in t._tracer.events()
+            shares = [e["args"][arg] for e in t._tracer.events()
                       if e["name"] == "device_block" and e.get("args")]
         finally:
             shutil.rmtree(run_dir, ignore_errors=True)
@@ -306,6 +452,14 @@ def case_both_step_twins_one_program_each(dtype):
         assert np.linalg.norm(a - b) <= limit * np.linalg.norm(b - s)
 
 
+def case_both_step_twins_one_program_each(dtype):
+    _fit_with_and_without(dtype, "_center_run_cap", "syn0_rows_per_pair")
+
+
+def case_context_both_step_twins_one_program_each(dtype):
+    _fit_with_and_without(dtype, "_context_run_cap", "syn1_rows_per_pair")
+
+
 CASES = {name[len("case_"):]: fn for name, fn in sorted(globals().items())
          if name.startswith("case_")}
 
@@ -323,3 +477,26 @@ def test_cap_is_derived_from_the_window():
     # a run of two pairs or fewer: not built
     assert _center_run_cap(1, 65536) == 0 and _center_run_cap(2, 65536) == 0
     assert 0 < _center_run_cap(10, 65536) < _center_run_cap(3, 65536) < 65536
+
+
+def test_context_cap_is_derived_from_the_counts():
+    total = int(COUNTS.sum())
+    caps = [_context_run_cap(COUNTS, total, 0.0, WINDOW, b)
+            for b in (256, 1024, 4096, 16384, 65536)]
+    # more pairs a batch, more distinct contexts and more cut pieces; in
+    # sixteenths of the batch, and a smaller share of a larger batch
+    assert caps == sorted(caps) and all(caps)
+    assert all(c % (b // 16) == 0 for c, b in zip(caps, (256, 1024, 4096, 16384, 65536)))
+    assert caps[0] / 256 > caps[-1] / 65536
+    assert CTX_CAP == caps[1] == 320
+    # stronger subsampling flattens the kept tokens: more distinct words
+    assert _context_run_cap(COUNTS, total, 1e-4, WINDOW, B) >= CTX_CAP
+    # a flat vocabulary: every kept token another word, B / 3.2 of them at
+    # window 5, with the room
+    flat = np.full(1_000_000, 5, np.int64)
+    assert _context_run_cap(flat, int(flat.sum()), 0.0, WINDOW, B) == 384
+    # where the estimate passes half the batch nothing is built: windows with
+    # a pair a token or fewer
+    assert _context_run_cap(flat, int(flat.sum()), 0.0, 2, B) == 0
+    assert _context_run_cap(COUNTS, total, 0.0, 1, B) == 0
+    assert _context_run_cap(COUNTS, total, 0.0, WINDOW, 8) == 0

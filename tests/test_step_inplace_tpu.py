@@ -1,0 +1,62 @@
+"""The shared-pool SGNS step updates its tables in place on the TPU.
+
+Compiled at ``sgns-3m-300``'s real size for a v5e chip that is described, not
+attached (the TPU's compiler is installed beside the CPU backend; nothing
+runs): no instruction of the compiled chunk copies a ``f32[3000000,384]``
+table. A ``lax.cond`` writes a table in place only where every read of that
+table is ordered before it; syn1's context update does not depend on the pool
+rows' gather, and left unordered the compiler copied syn1 into the branch and
+back, 14 ms of a 26.5 ms step on the chip (PERF.md §6, PR 30). A count of
+instructions, not a time.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from glint_word2vec_tpu.ops.sgns import EmbeddingPair, sgns_step_shared_core
+
+V, D, B, P, K = 3_000_000, 384, 65536, 2048, 2
+# what the trainer derives at this size (tests/test_coalesce_runs.py,
+# tests/test_step_selection.py hold the derivations)
+RUNS = dict(center_runs=(10, 24576), context_runs=(6, 20480))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
+def test_no_table_is_copied(one_chip, with_metrics):
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def chunk(params, centers, contexts, negatives, alphas):
+        def body(p, xs):
+            c, x, n, a = xs
+            return sgns_step_shared_core(
+                p, c, x, jnp.ones(B, jnp.float32), n, a, 5, "exact",
+                jnp.bfloat16, logits_dtype=jnp.bfloat16,
+                with_metrics=with_metrics, **RUNS)
+        return jax.lax.scan(body, params, (centers, contexts, negatives, alphas))
+
+    table = spec((V, D), jnp.float32)
+    compiled = jax.jit(chunk, donate_argnums=(0,)).lower(
+        EmbeddingPair(table, table), spec((K, B), jnp.int32),
+        spec((K, B), jnp.int32), spec((K, P), jnp.int32),
+        spec((K,), jnp.float32)).compile().as_text()
+    assert " sort(" in compiled and " conditional(" in compiled
+    copies = [line.strip()[:120] for line in compiled.splitlines()
+              if re.search(rf"= f32\[{V},{D}\]\S* copy\(", line)]
+    assert not copies, copies

@@ -1,10 +1,16 @@
 """The step selection matrix (train/trainer.select_step): which ops-level core
 one configuration trains with, the shape of the negatives its chunk draws and
-whether syn0's update goes to the scatter by center runs — every row the
-function can return, both twins, without building a Trainer."""
+whether syn0's update goes to the scatter by center runs and syn1's by context
+runs — every row the function can return, both twins, without building a
+Trainer."""
 
+import collections
 import inspect
+import os
+import re
+import sys
 
+import numpy as np
 import pytest
 
 from glint_word2vec_tpu.config import Word2VecConfig
@@ -12,13 +18,25 @@ from glint_word2vec_tpu.ops import cbow_banded, sgns_shard
 from glint_word2vec_tpu.ops.sgns import Stabilizers
 from glint_word2vec_tpu.parallel.mesh import make_mesh
 from glint_word2vec_tpu.train import trainer as trainer_mod
-from glint_word2vec_tpu.train.trainer import _center_run_cap, select_step
+from glint_word2vec_tpu.train.trainer import (
+    _CONTEXT_MAX_RUN,
+    _center_run_cap,
+    select_step,
+)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
 
 B, P, N, K = 64, 16, 5, 4
 BASE = dict(vector_size=16, min_count=1, pairs_per_batch=B, negatives=N,
             steps_per_dispatch=K)
 POOL, PER_EXAMPLE, WINDOW_POOLS = (K, P), (K, B, N), (K, 2 * P)
 RUNS_W5 = (10, _center_run_cap(5, B))
+# what a trainer derived from its vocabulary (_context_run_cap); 0 = nothing
+CONTEXT_CAP = 24
+BY_CONTEXT = (_CONTEXT_MAX_RUN, CONTEXT_CAP)
 
 # where each core is looked up when select_step runs
 CORE_HOME = {
@@ -30,26 +48,30 @@ CORE_HOME = {
     "make_shard_map_sgns_step": sgns_shard,
 }
 
-# id: (config beside BASE, mesh, feed_segments) -> (core, negatives, center_runs)
+# id: (config beside BASE, mesh, feed_segments)
+#     -> (core, negatives, center_runs[, context_runs: None where left out])
 ROWS = {
     "sgns-shared-gspmd-runs": (
         dict(negative_pool=P, window=5), (1, 1), 1,
-        ("sgns_step_shared_core", POOL, RUNS_W5)),
+        ("sgns_step_shared_core", POOL, RUNS_W5, BY_CONTEXT)),
+    "sgns-shared-gspmd-model-axis": (   # rows over 1x4: the batch is still whole
+        dict(negative_pool=P, window=5), (1, 4), 1,
+        ("sgns_step_shared_core", POOL, RUNS_W5, BY_CONTEXT)),
     "sgns-shared-gspmd-window2": (   # two pairs a run or fewer: not built
-        dict(negative_pool=P, window=2), (1, 1), 1,
-        ("sgns_step_shared_core", POOL, None)),
+        dict(negative_pool=P, window=2), (1, 1), 1,   # (contexts: the corpus's)
+        ("sgns_step_shared_core", POOL, None, BY_CONTEXT)),
     "sgns-shared-gspmd-data-axis": (
         dict(negative_pool=P, window=5), (2, 4), 1,
         ("sgns_step_shared_core", POOL, None)),
-    "sgns-shared-gspmd-two-feed-segments": (
+    "sgns-shared-gspmd-two-feed-segments": (   # the step sorts by context itself
         dict(negative_pool=P, window=5), (1, 1), 2,
-        ("sgns_step_shared_core", POOL, None)),
+        ("sgns_step_shared_core", POOL, None, BY_CONTEXT)),
     "sgns-shared-gspmd-duplicate-scaling": (
         dict(negative_pool=P, window=5, duplicate_scaling=True), (1, 1), 1,
-        ("sgns_step_shared_core", POOL, RUNS_W5)),
+        ("sgns_step_shared_core", POOL, RUNS_W5, BY_CONTEXT)),
     "sgns-device-pairgen-runs": (
         dict(negative_pool=P, window=5, device_pairgen=True), (1, 1), 1,
-        ("sgns_step_shared_core", POOL, RUNS_W5)),
+        ("sgns_step_shared_core", POOL, RUNS_W5, BY_CONTEXT)),
     "sgns-device-pairgen-data-axis": (
         dict(negative_pool=P, window=5, device_pairgen=True), (2, 4), 1,
         ("sgns_step_shared_core", POOL, None)),
@@ -116,15 +138,18 @@ def cores(monkeypatch):
 @pytest.mark.parametrize("row", list(ROWS))
 def test_step_selection(row, with_metrics, cores):
     calls, stubs = cores
-    kw, mesh, segments, (core, negatives, runs) = ROWS[row]
+    kw, mesh, segments, (core, negatives, runs, *by_context) = ROWS[row]
+    by_context = by_context[0] if by_context else None
     cfg = Word2VecConfig(**BASE, **kw)
     stab = Stabilizers(update_clip=0.5)
 
-    choice = select_step(cfg, make_mesh(*mesh), segments, stab, with_metrics)
+    choice = select_step(cfg, make_mesh(*mesh), segments, CONTEXT_CAP, stab,
+                         with_metrics)
 
     assert choice.core is stubs[core]
     assert choice.neg_shape(K, B) == negatives
     assert choice.center_runs == runs
+    assert choice.context_runs == by_context
     assert choice.step("params", _Batch(), "negatives", "alpha") == "out"
     # the chosen core ran, once, and no other
     name, bound = calls[0]
@@ -136,8 +161,53 @@ def test_step_selection(row, with_metrics, cores):
         with_metrics if has_twin else "absent")
     if core == "sgns_step_shared_core":
         assert bound["center_runs"] == runs
+        assert bound["context_runs"] == by_context
         assert bound["duplicate_scaling"] == cfg.duplicate_scaling
     if core == "make_shard_map_sgns_step":
         assert bound["sync_every"] == cfg.sync_every
         assert calls[1] == ("shard_map_step",
                             ("params", {}, "negatives", "alpha"))
+
+
+def test_context_runs_need_a_cap():
+    """A vocabulary whose estimate passes half the batch (_context_run_cap
+    gives 0) builds no context coalescing; syn0's stays as it is."""
+    cfg = Word2VecConfig(**BASE, negative_pool=P, window=5)
+    choice = select_step(cfg, make_mesh(1, 1), 1, 0, None, True)
+    assert choice.context_runs is None and choice.center_runs == RUNS_W5
+
+
+COLLECTIVE = re.compile(r"\b(all-reduce|all-gather|reduce-scatter|all-to-all|"
+                        r"collective-permute)(?:-start)?\(")
+
+
+@pytest.mark.parametrize("twin", ["_step_fn", "_step_fn_fast"])
+def test_x4_step_holds_the_parents_collectives(twin):
+    """``sgns-10m-300-x4`` at ``tiny`` on its 1x4 mesh (over the 8 virtual CPU
+    devices): the step with both updates coalesced compiles to the collective
+    ops the step before PR 30 compiled to, ONE all-reduce (the forward
+    assembly of the gathered rows over the model axis) — the sort, the row
+    gather and the conditional bring none. A count of the compiled module's
+    ops, not a time."""
+    from harness import loader
+    from kinds import train as train_kind
+
+    from glint_word2vec_tpu.parallel.distributed import put_global
+
+    cell = loader.resolve(loader.load_manifest(ROOT), "sgns-10m-300-x4.train", ROOT)
+    trainer, _, _ = train_kind.build_trainer(cell, 0, tiny=True)
+    cfg = trainer.config
+    assert (trainer.plan.num_data, trainer.plan.num_model) == (1, 4)
+    choice = select_step(cfg, trainer.plan, 1, trainer._context_cap, None, True)
+    assert choice.context_runs and choice.center_runs   # both engage here
+
+    k, b = cfg.steps_per_dispatch, cfg.pairs_per_batch
+    staged = put_global(trainer._chunk_shardings,
+                        {"pairs": np.zeros((k, 2, b), trainer._pair_dtype)})
+    meta_dev, base_dev = trainer._stage_dispatch_meta(np.zeros((2, k), np.float32), 0)
+    compiled = getattr(trainer, twin).lower(
+        trainer.params, staged, meta_dev, base_dev,
+        trainer._table_prob, trainer._table_alias).compile().as_text()
+    assert " sort(" in compiled
+    found = collections.Counter(m.group(1) for m in COLLECTIVE.finditer(compiled))
+    assert found == {"all-reduce": 1}
