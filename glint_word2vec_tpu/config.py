@@ -279,6 +279,22 @@ class Word2VecConfig:
                                     # are refused at construction, never
                                     # silently downgraded. Stays opt-in until
                                     # EVAL evidence lands (acceptance rule)
+    subword: bool = False           # subword skip-gram (fastText; Bojanowski et
+                                    # al. 2017, arXiv:1607.04606): a word's
+                                    # input vector is the mean of its own row
+                                    # and the bucket rows of its hashed
+                                    # character n-grams (data/subword.py,
+                                    # ops/subword.py); syn0 then has vocabulary
+                                    # + subword_buckets rows. The shared-pool
+                                    # skip-gram step on one device only:
+                                    # refused beside cbow, negative_pool=0,
+                                    # step_lowering='shard_map', a mesh larger
+                                    # than 1x1, device_pairgen,
+                                    # duplicate_scaling, sharded_checkpoint and
+                                    # the touched-row stabilizers
+    subword_min_n: int = 3          # shortest and longest character n-gram of
+    subword_max_n: int = 6          # "<word>" (fastText's minn / maxn)
+    subword_buckets: int = 2_000_000  # hash buckets the n-grams share (its bucket)
     shuffle: bool = True            # shuffle sentence order each iteration (reference order is
                                     # whatever repartition() produced, i.e. arbitrary; mllib:345)
 
@@ -1075,6 +1091,76 @@ class Word2VecConfig:
                     f"exact-f32 prefix-sum bound (T * (2*window - 1) must "
                     f"stay below 2^24); lower tokens_per_step or split the "
                     f"batch")
+        # --- subword selection matrix (trainer.select_step's docstring has the
+        # row; Trainer.__init__ keeps the runtime twin for a mesh handed in as
+        # a plan). The row source is the shared-pool skip-gram step's center
+        # side on one device; every other combination is an ERROR here:
+        #   subword × cbow               → refuse (a multi-row CONTEXT is
+        #       another step: ops/cbow_banded.py has no list per token)
+        #   subword × negative_pool=0    → refuse (the per-pair step gathers
+        #       one row a center; the row source lives in the shared-pool step)
+        #   subword × shard_map          → refuse (a list's rows live on other
+        #       chips; ops/sgns_shard.py gathers owner-locally one row a center)
+        #   subword × mesh > 1x1         → refuse (the same, under GSPMD)
+        #   subword × device_pairgen     → refuse (center runs are the host
+        #       pair feed's order; the token-block chunk has no table argument)
+        #   subword × duplicate_scaling  → refuse (occurrence counts are per
+        #       word row; a list's rows have none)
+        #   subword × sharded_checkpoint → refuse (bucket rows ride a file of
+        #       their own beside the dense layout)
+        #   subword × max_row_norm / row_l2 / norm_watch="recover" → refuse
+        #       (the touched-row pass walks centers, not their lists' rows;
+        #       recover would engage max_row_norm)
+        if self.subword:
+            if not (0 < self.subword_min_n <= self.subword_max_n):
+                raise ValueError(
+                    f"subword needs 0 < subword_min_n <= subword_max_n but got "
+                    f"{self.subword_min_n}..{self.subword_max_n}")
+            if self.subword_buckets <= 0:
+                raise ValueError(
+                    f"subword_buckets must be positive but got "
+                    f"{self.subword_buckets}")
+            if self.cbow:
+                raise ValueError(
+                    "subword=True is the skip-gram step's center side; CBOW's "
+                    "context lists have no subword form — set cbow=False")
+            if self.negative_pool == 0:
+                raise ValueError(
+                    "subword=True requires the shared-pool estimator "
+                    "(negative_pool > 0, or -1 for auto at pairs_per_batch >= "
+                    "4096): the per-pair step has no row source")
+            if self.step_lowering == "shard_map":
+                raise ValueError(
+                    "subword=True does not support step_lowering='shard_map': "
+                    "a word's listed rows live on other chips, and the "
+                    "explicit schedule gathers one owner-local row a center")
+            mesh = self.mesh_shape or (self.num_data_shards,
+                                       self.num_model_shards)
+            if tuple(mesh) != (1, 1):
+                raise ValueError(
+                    f"subword=True trains on one device: a {mesh[0]}x{mesh[1]} "
+                    f"mesh would spread a word's listed rows over chips (no "
+                    f"sharded row source yet)")
+            if self.device_pairgen:
+                raise ValueError(
+                    "subword=True does not support device_pairgen: the row "
+                    "source works per center run of the host pair feed")
+            if self.duplicate_scaling:
+                raise ValueError(
+                    "subword=True does not support duplicate_scaling=True: "
+                    "mean-update counts are per word row, and a center moves "
+                    "every row of its list")
+            if self.sharded_checkpoint:
+                raise ValueError(
+                    "subword=True does not support sharded_checkpoint=True: "
+                    "the bucket rows are saved beside the dense layout only")
+            if self.max_row_norm or self.row_l2 or self.norm_watch == "recover":
+                raise ValueError(
+                    "subword=True does not support max_row_norm, row_l2 or "
+                    "norm_watch='recover' (which engages max_row_norm): the "
+                    "touched-row pass walks center words, not the rows of "
+                    "their lists; update_clip and norm_watch='warn'/'halt' "
+                    "are available")
         # cols × sharded_checkpoint: row-shards checkpoints need each process
         # to own whole ROWS — the cols layout owns columns (design rationale:
         # PERF.md §7). Trainer.__init__ keeps the runtime twin (cols ×

@@ -81,7 +81,8 @@ class Word2Vec:
         self.last_run_stats = trainer.last_run_stats
         return Word2VecModel(
             vocab=vocab, syn0=params.syn0, syn1=params.syn1,
-            config=cfg, plan=trainer.plan, train_state=trainer.state)
+            config=cfg, plan=trainer.plan, train_state=trainer.state,
+            subword_buckets=trainer.subword_buckets())
 
     @staticmethod
     def resume(
@@ -195,8 +196,13 @@ class Word2Vec:
             if data["syn1"] is None:
                 raise ValueError("checkpoint has no syn1; cannot resume training")
             import jax.numpy as jnp
-            params = EmbeddingPair(
-                jnp.asarray(data["syn0"]), jnp.asarray(data["syn1"]))
+            syn0 = jnp.asarray(data["syn0"])
+            if data.get("subword_buckets") is not None:
+                # a subword model's input table: its bucket rows follow the
+                # vocabulary's (save_model keeps them in a file of their own)
+                syn0 = jnp.concatenate(
+                    [syn0, jnp.asarray(data["subword_buckets"])])
+            params = EmbeddingPair(syn0, jnp.asarray(data["syn1"]))
         trainer = Trainer(cfg, vocab, plan=plan, params=params, train_state=state)
         if not state.finished:
             # pass checkpoint_every_steps explicitly to keep periodic checkpointing
@@ -207,4 +213,5 @@ class Word2Vec:
         out = trainer.unpadded_params()
         return Word2VecModel(
             vocab=vocab, syn0=out.syn0, syn1=out.syn1, config=cfg,
-            plan=trainer.plan, train_state=trainer.state)
+            plan=trainer.plan, train_state=trainer.state,
+            subword_buckets=trainer.subword_buckets())

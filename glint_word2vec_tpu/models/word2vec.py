@@ -45,7 +45,16 @@ class Word2VecModel:
         config: Optional[Word2VecConfig] = None,
         plan: Optional[MeshPlan] = None,
         train_state: Optional["ckpt.TrainState"] = None,
+        subword_buckets: Optional[jax.Array] = None,
     ):
+        # a subword model (config.subword) answers with COMPOSED vectors: what
+        # arrives is syn0's words' own rows and its bucket rows; every query
+        # below scans h_w, the mean of a word's listed rows, made once here
+        self._raw0 = self._buckets = None
+        if subword_buckets is not None:
+            self._raw0 = jnp.asarray(syn0)[: vocab.size]
+            self._buckets = jnp.asarray(subword_buckets)
+            syn0 = self._compose(vocab, config, self._raw0, self._buckets)
         Vp = (pad_vocab_for_sharding(vocab.size, plan.num_model)
               if plan is not None else vocab.size)
         if syn0.shape[0] not in (vocab.size, Vp):
@@ -88,9 +97,43 @@ class Word2VecModel:
         self._ann = None
         self._stopped = False
 
+    @staticmethod
+    def _compose(vocab: Vocabulary, config: Word2VecConfig, raw0: jax.Array,
+                 buckets: jax.Array) -> jax.Array:
+        """[V, D] h_w of every word (fastText's ``get_word_vector``), in row
+        blocks on the device (ops/subword.compose_vectors)."""
+        from glint_word2vec_tpu.data.subword import build_subword_table
+        from glint_word2vec_tpu.ops.subword import SubwordTable, compose_vectors
+        if config is None or not config.subword:
+            raise ValueError("subword_buckets need a config with subword=True")
+        if buckets.shape[0] != config.subword_buckets:
+            raise ValueError(
+                f"{buckets.shape[0]} bucket rows but config.subword_buckets is "
+                f"{config.subword_buckets}")
+        rows = build_subword_table(vocab.words, config.subword_min_n,
+                                   config.subword_max_n, config.subword_buckets)
+        table = SubwordTable(jnp.asarray(rows.offsets), jnp.asarray(rows.rows),
+                             jnp.asarray(rows.counts))
+        return compose_vectors(jnp.concatenate([raw0, buckets]), table,
+                               rows.max_groups, vocab.size)
+
+    def _unseen_vector(self, word: str) -> np.ndarray:
+        """A string the vocabulary has never seen, on a subword model: the
+        mean of its n-grams' bucket rows (zeros where it has none)."""
+        from glint_word2vec_tpu.data.subword import ngram_buckets
+        cfg = self.config
+        ids = ngram_buckets(word, cfg.subword_min_n, cfg.subword_max_n,
+                            cfg.subword_buckets)
+        if not ids:
+            return np.zeros(self.vector_size, np.float32)
+        return np.asarray(
+            self._buckets[jnp.asarray(ids, jnp.int32)].astype(jnp.float32)
+            .mean(axis=0))
+
     @property
     def syn0(self) -> jax.Array:
-        """Input embeddings, unpadded view [vocab_size, D]."""
+        """Input embeddings, unpadded view [vocab_size, D] (a subword model's
+        composed vectors)."""
         self._check_alive()
         return self._full0[: self.vocab.size]
 
@@ -118,9 +161,13 @@ class Word2VecModel:
     # -- transform (C8 mllib:511-546; C12 ml:432-460) ----------------------------------
 
     def transform(self, word: str) -> np.ndarray:
-        """Vector of a single word. Raises on OOV like the reference (mllib:516-518)."""
+        """Vector of a single word. Raises on OOV like the reference
+        (mllib:516-518), except on a subword model, which composes one from
+        the string's n-grams."""
         self._check_alive()
         idx = self.vocab.get(word)
+        if idx < 0 and self._buckets is not None:
+            return self._unseen_vector(word)
         if idx < 0:
             raise KeyError(f"{word} not in vocabulary")
         return np.asarray(self.syn0[idx])
@@ -491,11 +538,16 @@ class Word2VecModel:
 
     def save(self, path: str) -> None:
         self._check_alive()
+        # a subword model saves what it trained (own rows and bucket rows),
+        # not the composed table its queries scan
+        raw0 = self.syn0 if self._raw0 is None else self._raw0
         ckpt.save_model(
             path, self.vocab.words, self.vocab.counts,
-            np.asarray(self.syn0),
+            np.asarray(raw0),
             np.asarray(self.syn1) if self.syn1 is not None else None,
-            self.config, self.train_state)
+            self.config, self.train_state,
+            subword_buckets=(None if self._buckets is None
+                             else np.asarray(self._buckets)))
 
     @classmethod
     def load(cls, path: str, plan: Optional[MeshPlan] = None,
@@ -542,6 +594,7 @@ class Word2VecModel:
             config=data["config"],
             plan=plan,
             train_state=data["train_state"],
+            subword_buckets=data.get("subword_buckets"),
         )
 
     @classmethod
@@ -564,7 +617,8 @@ class Word2VecModel:
         (client.terminateOnSpark + matrix.destroy, mllib:655-667). Idempotent."""
         if self._stopped:
             return
-        for arr in (self._full0, self._full1, self._norms):
+        for arr in (self._full0, self._full1, self._norms, self._raw0,
+                    self._buckets):
             if arr is not None:
                 try:
                     arr.delete()
@@ -573,6 +627,7 @@ class Word2VecModel:
         self._full0 = None  # type: ignore[assignment]
         self._full1 = None
         self._norms = None
+        self._raw0 = self._buckets = None
         self._ann = None
         self._stopped = True
 

@@ -253,6 +253,9 @@ class StepMetrics(NamedTuple):
     # the same for syn1's context scatter (one per context run of the batch
     # sorted by context); the pool rows' scatter is not counted
     syn1_rows: Optional[jax.Array] = None
+    # rows of the centers' subword lists that reached syn0's scatter with a
+    # live index (config.subword; ops/subword.py); None = not a subword step
+    subword_rows: Optional[jax.Array] = None
 
 
 def init_embeddings(
@@ -602,9 +605,18 @@ def sgns_step_shared_core(
     bf16_chain: bool = False,
     center_runs: Optional[Tuple[int, int]] = None,
     context_runs: Optional[Tuple[int, int]] = None,
+    subword: Optional[tuple] = None,
 ) -> Tuple[EmbeddingPair, StepMetrics]:
     """:func:`sgns_step_shared` with the pool supplied by the caller (see
     :func:`sgns_step_core` for why sampling lives outside the jitted scan).
+
+    ``subword`` ``(SubwordTable, SubwordShape)`` (config.subword;
+    :mod:`.subword`): the center's row source. syn0 then has the vocabulary's
+    rows and the bucket rows; a center's ``e_in`` is the mean of the rows its
+    word's list names, made once per center run, and ``d_in``, summed per run
+    and divided by the list's length, is spread back over them in one scatter.
+    Everything after ``e_in`` and before syn0's scatter is the same code;
+    ``center_runs`` is not read (the shape carries the run length and cap).
 
     ``center_runs`` ``(max_run, cap)``: syn0's update goes through
     :func:`scatter_add_by_runs` — the pair feed emits a center's pairs
@@ -667,8 +679,15 @@ def sgns_step_shared_core(
                          "(refused at config construction)")
     # named scopes are metadata for a profile's reader (docs/observability.md
     # §4); the compiled step is the same program without them (tested)
+    if subword is not None:
+        from glint_word2vec_tpu.ops import subword as sw
+        sw_table, sw_shape = subword
+        sw_plan = sw.plan_centers(centers, sw_table, sw_shape)
+        e_in = sw.center_vectors(syn0, centers, sw_table, sw_shape, sw_plan,
+                                 compute_dtype)              # [B, D]
     with jax.named_scope("sgns.gather"):
-        e_in = syn0[centers].astype(compute_dtype)          # [B, D]
+        if subword is None:
+            e_in = syn0[centers].astype(compute_dtype)      # [B, D]
         e_pos = syn1[contexts].astype(compute_dtype)        # [B, D]
         Z = syn1[negatives].astype(compute_dtype)           # [P, D]
 
@@ -710,8 +729,15 @@ def sgns_step_shared_core(
         d_pos = clip_update_rows(d_pos, stabilizers.update_clip)
 
     dtype = syn0.dtype
+    subword_rows = None
     with jax.named_scope("sgns.scatter_syn0"):
-        if center_runs is None:
+        if subword is not None:
+            new_syn0 = sw.scatter_center_updates(
+                syn0, centers, d_in, sw_table, sw_shape, sw_plan)
+            syn0_rows = jnp.where(sw_plan.fits, sw_plan.heads,
+                                  centers.shape[0]).astype(jnp.float32)
+            subword_rows = sw_plan.live_rows
+        elif center_runs is None:
             new_syn0 = syn0.at[centers].add(d_in.astype(dtype))
             syn0_rows = jnp.float32(centers.shape[0])
         else:
@@ -754,6 +780,7 @@ def sgns_step_shared_core(
         pairs=mask.sum(),
         syn0_rows=syn0_rows,
         syn1_rows=syn1_rows,
+        subword_rows=subword_rows,
     )
     return EmbeddingPair(new_syn0, new_syn1), metrics
 

@@ -241,6 +241,7 @@ def save_model(
     config: Word2VecConfig,
     train_state: Optional[TrainState] = None,
     extra_metadata: Optional[Dict[str, Any]] = None,
+    subword_buckets: Optional[np.ndarray] = None,
 ) -> None:
     """Atomic save: everything is written to a sibling temp directory first and swapped
     into place, so a crash mid-save never corrupts an existing checkpoint (the whole point
@@ -257,7 +258,12 @@ def save_model(
     ``extra_metadata``: additive keys merged into ``metadata.json`` (readers
     ignore unknown keys — no format bump). The continual subsystem rides
     this for the ``vocab_lineage`` chain (continual/extend.py); reserved
-    keys (anything :func:`load_model_header` already reads) are refused."""
+    keys (anything :func:`load_model_header` already reads) are refused.
+
+    ``subword_buckets``: a subword model's bucket rows (config.subword: the
+    rows of syn0 after the vocabulary's), a file ``syn0_buckets.npy`` with a
+    digest of its own; ``syn0.npy`` keeps the words' own rows, so every reader
+    of the dense layout still finds one row a word."""
     bad = [w for w in words if (not w) or ("\n" in w)]
     if bad:
         raise ValueError(
@@ -285,6 +291,11 @@ def save_model(
             tasks.append(lambda: _save_npy_hashed(
                 stage("syn1.npy"), np.asarray(syn1, dtype=np.float32)))
             names.append("syn1.npy")
+        if subword_buckets is not None:
+            tasks.append(lambda: _save_npy_hashed(
+                stage("syn0_buckets.npy"),
+                np.asarray(subword_buckets, dtype=np.float32)))
+            names.append("syn0_buckets.npy")
         digests: Dict[str, str] = dict(
             zip(names, _run_io(tasks, getattr(config, "io_workers", 1))))
         faults.crash_point("save:arrays-written")
@@ -852,11 +863,15 @@ def load_model(path: str, header: Optional[Dict[str, Any]] = None,
     if syn0.shape[0] != len(words):
         raise ValueError(
             f"words sidecar has {len(words)} entries but syn0 has {syn0.shape[0]} rows")
+    buckets_path = os.path.join(path, "syn0_buckets.npy")
     return {
         "words": words,
         "counts": header["counts"],
         "syn0": syn0,
         "syn1": syn1,
+        # a subword model's bucket rows (save_model), None for any other
+        "subword_buckets": (np.load(buckets_path)
+                            if os.path.exists(buckets_path) else None),
         "config": header["config"],
         "train_state": header["train_state"],
     }

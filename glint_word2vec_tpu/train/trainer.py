@@ -92,6 +92,10 @@ def _center_run_cap(window: int, batch: int) -> int:
     return -(-int(1.4 * runs_per_pair * batch) // eighth) * eighth
 
 
+# the subword row table's groups are padded to a multiple of this on the device
+# (Trainer._place_subword_table has the reason)
+_SUBWORD_GROUPS_UNIT = 1 << 20
+
 # pairs in a piece of a context run (ops/sgns.run_sums makes one shifted add
 # for each beyond the first): named, with the cap's room, by the chip (PERF.md
 # §6, PR 30)
@@ -144,7 +148,7 @@ class StepChoice(NamedTuple):
 
 def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
                 context_cap: int, stabilizers: Optional[Stabilizers],
-                with_metrics: bool) -> StepChoice:
+                with_metrics: bool, subword_shape=None) -> StepChoice:
     """The step selection matrix: which update one configuration trains with.
     Every legal combination is one row, read top to bottom; what is on no row
     config.__post_init__ refuses at construction, never silently downgrades
@@ -162,9 +166,18 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
                                                                  (+ center_runs and
                                                                  context_runs, below)
 
+    ``subword=True`` is no row of its own: it is the last row with the center's
+    row source on (``subword=(table, shape)``, ops/subword.py; the table rides
+    the batch as ``batch["subword_table"]``, a jit argument of the chunk), on
+    one device and the host pair feed. Beside it config refuses cbow, P = 0,
+    "shard_map", a mesh larger than 1x1, device_pairgen, duplicate_scaling,
+    sharded_checkpoint and the touched-row stabilizers.
+
     ``context_cap`` is :func:`_context_run_cap` of the trainer's vocabulary,
     ``stabilizers`` is the trainer's state (None = all off), ``with_metrics``
-    the twin; the rows without a ``with_metrics`` form have one twin."""
+    the twin; the rows without a ``with_metrics`` form have one twin.
+    ``subword_shape`` is the trainer's :class:`..ops.subword.SubwordShape`
+    (None where the model is not subword)."""
     compute_dtype = jnp.dtype(cfg.compute_dtype)
     logits_dtype = jnp.dtype(cfg.logits_dtype)
     n, pool = cfg.negatives, cfg.negative_pool
@@ -258,6 +271,20 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
     context_runs = None
     if plan.num_data == 1 and context_cap:
         context_runs = (_CONTEXT_MAX_RUN, context_cap)
+
+    if subword_shape is not None:
+        def step(params, batch, negatives, alpha):
+            return sgns_step_shared_core(
+                params, batch["centers"], batch["contexts"], batch["mask"],
+                negatives, alpha, n, cfg.sigmoid_mode, compute_dtype,
+                False, logits_dtype, with_metrics,
+                stabilizers=stabilizers, fused=fused, bf16_chain=chain,
+                context_runs=context_runs,
+                subword=(batch["subword_table"], subword_shape))
+
+        return StepChoice(
+            sgns_step_shared_core, step, shared_pool,
+            (subword_shape.max_run, subword_shape.head_cap), context_runs)
 
     def step(params, batch, negatives, alpha):
         return sgns_step_shared_core(
@@ -550,6 +577,18 @@ class Trainer:
                 f"{config.pairs_per_batch} must be divisible by num_data="
                 f"{plan.num_data}")
         self.padded_vocab = pad_vocab_for_sharding(vocab.size, plan.num_model)
+        # rows of syn0: the vocabulary's, and where the model is subword the
+        # bucket rows after them (ops/subword.py); syn1 has the vocabulary's
+        self._syn0_rows = self.padded_vocab
+        if config.subword:
+            # runtime twin of config's mesh refusal: a plan handed in
+            if plan.mesh.devices.size > 1:
+                raise ValueError(
+                    f"subword=True trains on one device, and the plan holds "
+                    f"{plan.mesh.devices.size}: a word's listed rows would "
+                    "live on other chips (no sharded row source yet)")
+            self._syn0_rows = pad_vocab_for_sharding(
+                vocab.size + config.subword_buckets, plan.num_model)
         # Pad the minor dim to the TPU lane width: D=300 rows are misaligned and row
         # gathers/scatters measurably slower than at 384. Padded columns are zero-init and
         # receive zero gradient (all products with the zero columns vanish), so they stay
@@ -576,11 +615,14 @@ class Trainer:
         self._root_key = jax.random.key(config.seed)
         if params is None:
             params = init_embeddings(
-                self.padded_vocab, config.vector_size,
+                self._syn0_rows, config.vector_size,
                 jax.random.fold_in(self._root_key, 0),
                 dtype=jnp.dtype(config.param_dtype))
+            if config.subword:      # syn1 has the vocabulary's rows alone
+                params = EmbeddingPair(params.syn0,
+                                       params.syn1[:self.padded_vocab])
         if (isinstance(params.syn0, jax.Array)
-                and params.syn0.shape == (self.padded_vocab, self.padded_dim)
+                and params.syn0.shape == (self._syn0_rows, self.padded_dim)
                 and params.syn0.dtype == jnp.dtype(config.param_dtype)
                 and params.syn0.sharding.is_equivalent_to(self._emb_sharding, 2)):
             # already padded and placed (e.g. streamed in by load_params_into_plan)
@@ -623,6 +665,19 @@ class Trainer:
                     f"shard_input=True needs pairs_per_batch divisible by the "
                     f"process count ({config.pairs_per_batch} % {n} != 0)")
             self._feed_segments = n
+        # the subword row source (config.subword): every word's list of input
+        # rows, built once and placed beside the tables at the end of
+        # construction (_place_subword_table); the chunk takes it as
+        # arguments (_step_extra)
+        self._subword_shape = None
+        self._step_extra: tuple = ()
+        self.subword_table_time = 0.0
+        if config.subword:
+            if self._feed_segments > 1:
+                raise ValueError(
+                    "subword=True needs the batch whole in one program: a "
+                    "feed in per-process segments (shard_input on several "
+                    "processes) cuts center runs at every seam")
         # On-device pair generation (ops/pairgen.py): host ships raw token blocks,
         # the jitted step subsamples + windows them itself — same hash lattice, so
         # the pair stream is bit-identical to the host pipeline's.
@@ -764,6 +819,8 @@ class Trainer:
         # gate, pipelining untouched.
         self._sync_collectives = (
             jax.default_backend() == "cpu" and plan.mesh.devices.size > 1)
+        if config.subword:
+            self._place_subword_table()
         self._build_step_twins()
 
     # -- setup -------------------------------------------------------------------------
@@ -822,16 +879,58 @@ class Trainer:
         T = int(np.ceil(0.93 * cfg.pairs_per_batch / self.plan.num_data / rate))
         return max(T, 64)
 
+    def _place_subword_table(self) -> None:
+        """Build the vocabulary's row table (data/subword.py) and put it on
+        the device: span ``vocab.subword_table``, its seconds kept in
+        ``subword_table_time``; the step's shape (ops/subword.py) takes the
+        center-run capacity the plain step has."""
+        from glint_word2vec_tpu.data.subword import NO_ROW, build_subword_table
+        from glint_word2vec_tpu.ops import subword as sw
+        cfg = self.config
+        t0 = time.perf_counter()
+        with self._tracer.span("vocab.subword_table",
+                               words=self.vocab.size) as span:
+            rows = build_subword_table(
+                self.vocab.words, cfg.subword_min_n, cfg.subword_max_n,
+                cfg.subword_buckets)
+            # the groups' count is an argument's shape of the step: rounded
+            # up (2^20 groups, 3% of the published vocabulary's 10.7 M), a
+            # vocabulary that differs by a few words, as the benchmark's
+            # does from seed to seed, compiles the same program and finds it
+            # in the compile cache
+            groups = np.full((-(-rows.rows.shape[0] // _SUBWORD_GROUPS_UNIT)
+                              * _SUBWORD_GROUPS_UNIT, rows.rows.shape[1]),
+                             NO_ROW, np.int32)
+            groups[:rows.rows.shape[0]] = rows.rows
+            placed = put_global(self.plan.replicated, {
+                "offsets": rows.offsets, "rows": groups,
+                "counts": rows.counts})
+            jax.block_until_ready(placed)
+            span.set(slots=rows.slots)
+        self.subword_table_time = time.perf_counter() - t0
+        self._step_extra = (placed["offsets"], placed["rows"], placed["counts"])
+        # center runs as the plain step's (one head per run of a center's
+        # pairs); where none are built every pair is its own head
+        cap = (_center_run_cap(cfg.window, cfg.pairs_per_batch)
+               if self.plan.num_data == 1 else 0)
+        self._subword_shape = sw.SubwordShape(
+            rows.max_groups, *((2 * cfg.window, cap) if cap
+                               else (1, cfg.pairs_per_batch)))
+        logger.info("subword table: %d words, %d slots, %s in %.2fs",
+                    self.vocab.size, rows.slots, self._subword_shape,
+                    self.subword_table_time)
+
     def _pad_params(self, params: EmbeddingPair) -> EmbeddingPair:
-        def pad(a):
+        def pad(a, rows):
             a = jnp.asarray(a)
-            row_pad = self.padded_vocab - a.shape[0]
+            row_pad = rows - a.shape[0]
             col_pad = self.padded_dim - a.shape[1]
             if row_pad or col_pad:
                 a = jnp.pad(a, ((0, row_pad), (0, col_pad)))
             return a
 
-        return EmbeddingPair(syn0=pad(params.syn0), syn1=pad(params.syn1))
+        return EmbeddingPair(syn0=pad(params.syn0, self._syn0_rows),
+                             syn1=pad(params.syn1, self.padded_vocab))
 
     def _stability_warnings(self, check_pool: bool = True) -> None:
         """Large synchronous batches can diverge through two per-step row-overload
@@ -1099,7 +1198,8 @@ class Trainer:
         # pre-stabilizer step.
         stab = self._stabilizers if self._stabilizers.enabled else None
         choice = select_step(cfg, self.plan, self._feed_segments,
-                             self._context_cap, stab, with_metrics)
+                             self._context_cap, stab, with_metrics,
+                             subword_shape=self._subword_shape)
         inner, neg_shape = choice.step, choice.neg_shape
         # np.uint32 (not a Python int): any negative or 64-bit seed masked to 32 bits
         # lands in [2^31, 2^32), which jnp.asarray rejects under int32 canonicalization
@@ -1162,7 +1262,9 @@ class Trainer:
 
             return jax.jit(device_chunk, donate_argnums=(0,))
 
-        def chunk(params, arrays, meta, base_step, prob, alias):
+        def chunk(params, arrays, meta, base_step, prob, alias, *subword_table):
+            # ``subword_table``: nothing, or the row table's three arrays
+            # (config.subword; _step_extra) — arguments, like the alias tables
             # scan over steps_per_dispatch stacked batches in one device dispatch:
             # per-step dispatch/transfer latency would otherwise dominate the ~ms
             # step. Two hard-won TPU constraints
@@ -1215,7 +1317,11 @@ class Trainer:
                     return {"centers": xs["centers"].astype(jnp.int32),
                             "contexts": ctx, "ctx_mask": ctx_mask, "mask": mask}
                 prs = xs["pairs"].astype(jnp.int32)
-                return {"centers": prs[0], "contexts": prs[1], "mask": mask}
+                batch = {"centers": prs[0], "contexts": prs[1], "mask": mask}
+                if subword_table:
+                    from glint_word2vec_tpu.ops.subword import SubwordTable
+                    batch["subword_table"] = SubwordTable(*subword_table)
+                return batch
 
             def body(p, inp):
                 xs, alpha, real, negs = inp
@@ -1570,7 +1676,8 @@ class Trainer:
                     with self._tracer.span("dispatch.enqueue"):
                         self.params, metrics = self._dispatch_step_fn(real)(
                             self.params, stacked, meta_dev, base_dev,
-                            self._table_prob, self._table_alias)
+                            self._table_prob, self._table_alias,
+                            *self._step_extra)
                 self.dispatch_time += time.perf_counter() - t0
                 self._after_dispatch()
                 self._finish_round(
@@ -3202,9 +3309,11 @@ class Trainer:
             # audit's scripted fits are too short to hit; tests/test_obs.py
             # runs a probing fit under the guard to keep this path honest)
             with self._tracer.span("device_block") as blocked:
-                loss_k, fpos_k, pairs_k, rows0_k, rows1_k = jax.device_get(
-                    (metrics.loss, metrics.mean_f_pos, metrics.pairs,
-                     metrics.syn0_rows, metrics.syn1_rows))
+                loss_k, fpos_k, pairs_k, rows0_k, rows1_k, rows_sw_k = (
+                    jax.device_get(
+                        (metrics.loss, metrics.mean_f_pos, metrics.pairs,
+                         metrics.syn0_rows, metrics.syn1_rows,
+                         metrics.subword_rows)))
                 if rows0_k is not None and pairs_k[real - 1] > 0:
                     # how far the step coalesced each table's update: 1.0
                     # plain, heads over pairs where runs were summed first
@@ -3214,6 +3323,11 @@ class Trainer:
                             rows0_k[real - 1] / pairs_k[real - 1]),
                         syn1_rows_per_pair=float(
                             rows1_k[real - 1] / pairs_k[real - 1]))
+                    if rows_sw_k is not None:
+                        # rows of the centers' subword lists that reached
+                        # syn0's scatter live (config.subword)
+                        blocked.set(subword_rows_per_pair=float(
+                            rows_sw_k[real - 1] / pairs_k[real - 1]))
             # per-phase attribution over THIS heartbeat window (obs/
             # phases.py): delta of the accumulator the spans + wait sites
             # have been feeding since the previous heartbeat
@@ -3659,6 +3773,15 @@ class Trainer:
         return EmbeddingPair(syn0=self.params.syn0[:V, :D],
                              syn1=self.params.syn1[:V, :D])
 
+    def subword_buckets(self) -> Optional[jax.Array]:
+        """syn0's bucket rows [subword_buckets, D] (they follow the
+        vocabulary's rows), None where the model is not subword."""
+        if not self.config.subword:
+            return None
+        V = self.vocab.size
+        return self.params.syn0[V:V + self.config.subword_buckets,
+                                :self.config.vector_size]
+
     def save_checkpoint(self, path: str,
                         _channels: Optional[dict] = None) -> None:
         if self.config.nonfinite_policy != "none":
@@ -3684,10 +3807,13 @@ class Trainer:
                 extra_metadata=extra)
         else:
             p = self.unpadded_params()
+            buckets = self.subword_buckets()
             save_model(
                 path, self.vocab.words, self.vocab.counts,
                 np.asarray(p.syn0), np.asarray(p.syn1),
-                self.config, self.state, extra_metadata=extra)
+                self.config, self.state, extra_metadata=extra,
+                subword_buckets=(None if buckets is None
+                                 else np.asarray(buckets)))
         logger.info("checkpoint saved to %s at step %d", path, self.global_step)
         # the preempt record's progress-lost denominator (docs/robustness.md)
         self._last_save_step = int(self.global_step)

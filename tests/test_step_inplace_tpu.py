@@ -8,6 +8,10 @@ table is ordered before it; syn1's context update does not depend on the pool
 rows' gather, and left unordered the compiler copied syn1 into the branch and
 back, 14 ms of a 26.5 ms step on the chip (PERF.md §6, PR 30). A count of
 instructions, not a time.
+
+The third program is the subword step at ``subword-sgns-2.5m-300``'s size (PR
+31): syn0 is read by one conditional (the centers' listed rows, per run or plain)
+and written by another, and neither may copy f32[4519376,384].
 """
 
 import os
@@ -59,4 +63,37 @@ def test_no_table_is_copied(one_chip, with_metrics):
     assert " sort(" in compiled and " conditional(" in compiled
     copies = [line.strip()[:120] for line in compiled.splitlines()
               if re.search(rf"= f32\[{V},{D}\]\S* copy\(", line)]
+    assert not copies, copies
+
+
+@pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
+def test_no_table_is_copied_with_the_subword_row_source(one_chip, with_metrics):
+    from glint_word2vec_tpu.ops.subword import SubwordShape, SubwordTable
+
+    words, rows0, groups = 2_519_376, 4_519_376, 10_730_000
+    # what the trainer derives at this size (PERF.md §6, PR 31)
+    shape = SubwordShape(max_groups=5, max_run=10, head_cap=24576)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def chunk(params, table, centers, contexts, negatives, alphas):
+        def body(p, xs):
+            c, x, n, a = xs
+            return sgns_step_shared_core(
+                p, c, x, jnp.ones(B, jnp.float32), n, a, 5, "exact",
+                jnp.bfloat16, logits_dtype=jnp.bfloat16,
+                with_metrics=with_metrics, context_runs=RUNS["context_runs"],
+                subword=(table, shape))
+        return jax.lax.scan(body, params, (centers, contexts, negatives, alphas))
+
+    compiled = jax.jit(chunk, donate_argnums=(0,)).lower(
+        EmbeddingPair(spec((rows0, D), jnp.float32), spec((words, D), jnp.float32)),
+        SubwordTable(spec((words + 2,), jnp.int32), spec((groups, 8), jnp.int32),
+                     spec((words + 1,), jnp.int32)),
+        spec((K, B), jnp.int32), spec((K, B), jnp.int32), spec((K, P), jnp.int32),
+        spec((K,), jnp.float32)).compile().as_text()
+    assert compiled.count(" conditional(") >= 3      # gather, scatter, syn1's
+    copies = [line.strip()[:120] for line in compiled.splitlines()
+              if re.search(rf"= f32\[({rows0}|{words}),{D}\]\S* copy\(", line)]
     assert not copies, copies

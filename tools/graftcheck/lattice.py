@@ -55,6 +55,17 @@ REFUSAL_GROUPS: Dict[str, Dict[str, tuple]] = {
         "tokens_per_step": (0, 64, 200_000),
         "shard_input": (True, False),
     },
+    "subword-matrix": {
+        "subword": (False, True),
+        "subword_buckets": (64,),
+        "cbow": (False, True),
+        "negative_pool": (-1, 0, 64),
+        "pairs_per_batch": (64, 4096),
+        "step_lowering": ("gspmd", "shard_map"),
+        "device_pairgen": (False, True),
+        "duplicate_scaling": (False, True),
+        "max_row_norm": (0.0, 50.0),
+    },
     "auto-markers": {
         "subsample_ratio": (-1.0, 0.0, 1e-3),
         "negative_pool": (-1, 0, 64),

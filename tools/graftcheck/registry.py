@@ -85,6 +85,13 @@ KNOBS = {k.name: k for k in [
     _K("sharded_checkpoint", (False, True)),
     _K("cbow", (False, True)),
     _K("cbow_update", ("scatter", "banded"), invalid="fused"),
+    # --- subword skip-gram (ISSUE 31): selects the center's row source of
+    # the shared-pool step and carries the subword selection matrix of
+    # config.__post_init__; the three sizes only matter beside subword=True
+    _K("subword", (False, True)),
+    _K("subword_min_n", (3, 2)),
+    _K("subword_max_n", (6, 4)),
+    _K("subword_buckets", (2_000_000, 64)),
     _K("shuffle", (True, False), dispatch_inert=True),
     _K("min_alpha_factor", (1e-4, 1.0), dispatch_inert=True),
     _K("decay_interval_words", (1, 10_000), dispatch_inert=True),
