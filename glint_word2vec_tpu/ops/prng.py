@@ -1,13 +1,16 @@
 """Counter-based stateless PRNG for the training hot path.
 
-Why not ``jax.random``: on TPU, threefry (and rbg) ops inside the training program
-measurably destroy step time — the scan-chunked SGNS step runs at ~2.2 ms/step with a
-single in-program ``jax.random.randint`` and at ~0.04 ms/step without it (55x, measured
-on v5e; see bench.py). The negative sampler only needs statistically-good, reproducible
-draws, not crypto-strength ones, so the hot path uses a murmur3-finalizer hash over a
-(seed, stream, counter, lane) lattice — pure vectorizable integer ops, identical results
-on every backend and every device (the reference's shared-seed trick, G3 mllib:419-421,
-survives as: all shards derive the same negatives from the same step counter for free).
+Why not ``jax.random``: the negative sampler only needs statistically-good,
+reproducible draws, not crypto-strength ones, and the training programs need them to
+be a pure function of (seed, step) that is identical on every backend and every
+device. So the hot path uses a murmur3-finalizer hash over a (seed, stream, counter,
+lane) lattice — pure vectorizable integer ops (the reference's shared-seed trick, G3
+mllib:419-421, survives as: all shards derive the same negatives from the same step
+counter for free). What it costs on this installation's chip (TPU v5 lite; PERF.md §6,
+PR 32): the whole (16, 2048) draw of a dispatch, hash lattice and both alias look-ups,
+1.10 ms alone and under 0.5 ms inside the trainer's chunk, 0.03 ms a step. The
+"~2.2 ms a step with one ``jax.random.randint``" of this header's earlier versions was
+another installation's reading and has not been taken again.
 
 ``jax.random`` remains in use for one-time work outside the step (embedding init).
 """

@@ -173,9 +173,9 @@ def sample_negatives(
 
     Two uniforms per draw: bucket u1·V, then keep-vs-alias on u2 < prob[bucket].
 
-    NOTE: uses ``jax.random`` (threefry). Fine for one-off draws, but inside a
-    training program threefry ops cost ~2 ms per call on TPU — the hot path must use
-    :func:`sample_negatives_hash` instead (see ops/prng.py for the measurements).
+    NOTE: uses ``jax.random`` (threefry): for one-off draws and tests. The training
+    programs draw through :func:`sample_negatives_hash`, whose stream is a pure
+    function of (seed, step) on every backend (ops/prng.py).
     """
     k1, k2 = jax.random.split(key)
     V = table.vocab_size
@@ -193,18 +193,25 @@ def sample_negatives_hash(
     shape: Tuple[int, ...],
 ) -> jax.Array:
     """Hot-path sampler: same alias-method draw as :func:`sample_negatives`, but from
-    the counter-based hash PRNG (ops/prng.py) — deterministic in (seed, counter) and
-    ~55x faster inside a jitted training step than the threefry path.
+    the counter-based hash PRNG (ops/prng.py) — deterministic in (seed, counter).
 
-    The tables must be passed into the enclosing jit as arguments: closure-captured
-    constants degrade the whole program on TPU (measured 3.4M → 204M pairs/s by this
-    change plus the PRNG swap; see bench.py).
+    The two look-ups compile, on a TPU v5 lite, to element gathers
+    (``gather(f32[V], s32[N]), slice_sizes={1}``, each behind a prefetch of the
+    whole table into fast memory; the ``reshape(V, 1)`` below is folded away).
+    Measured there (PERF.md §6, PR 32): the shared pool's (16, 2048) draw alone
+    1.10 ms a call at V = 3M and at 10M, and inside the trainer's own chunk
+    0.234 ms a look-up a dispatch of 16 steps, 0.03 ms a step for both. Reading
+    the tables as 128-lane rows halves that (0.55 ms a call) for 0.02 ms a step
+    and a second arm for draws too large for a ``[N, 128]`` block: not built.
+
+    The tables must be passed into the enclosing jit as arguments: a closure-
+    captured table is baked into the program as a constant.
     """
     from glint_word2vec_tpu.ops.prng import randint_mod, uniform01
 
     V = prob.shape[0]
     with jax.named_scope("sgns.sample"):
-        prob2 = prob.reshape(V, 1)    # free view; (V, 1) row gathers take the fast path
+        prob2 = prob.reshape(V, 1)
         alias2 = alias.reshape(V, 1)
         buckets = randint_mod(seed, 0, counter, shape, V)
         u = uniform01(seed, 1, counter, shape)

@@ -268,6 +268,75 @@ def test_probe_matches_numpy_oracle():
         assert got["p99_norm"] <= true_p99 * 2 ** 0.25 * (1 + 1e-6)
 
 
+def _probe_rows(case: str, V: int, D: int = 8) -> np.ndarray:
+    """Row norms laid out to stress the p99 bucket: where it falls, and the edges."""
+    rng = np.random.default_rng(len(case) + V)
+    unit = rng.normal(size=(V, D)).astype(np.float32)
+    unit /= np.linalg.norm(unit, axis=1, keepdims=True)
+    if case == "spread":            # many live buckets, 2^-14 .. 2^22: both clamps
+        scale = np.exp2(rng.uniform(-14, 22, V))
+    elif case == "one_bucket":      # every row in one bucket
+        scale = np.full(V, 3.0)
+    elif case == "zeros":           # all-zero rows clamp to bucket 0
+        scale = np.zeros(V)
+    elif case == "tail_of_one":     # 99% small, the rest huge: the crossing is at the edge
+        scale = np.where(np.arange(V) < -(-V * 99 // 100), 0.5, 1e6)
+    elif case == "tail_starts_early":   # one row short of 99% small
+        scale = np.where(np.arange(V) < -(-V * 99 // 100) - 1, 0.5, 1e6)
+    return (unit * scale[:, None]).astype(np.float32)
+
+
+def _histogram_p99(rows: np.ndarray) -> float:
+    """The p99 bucket's upper edge as the 128-bucket histogram's CDF gives it,
+    built by NumPy from the probe's own float32 norms of ``rows``."""
+    from glint_word2vec_tpu.obs import probe as P
+
+    x = jax.numpy.asarray(rows)
+    norms = np.asarray(jax.numpy.sqrt(jax.numpy.sum(x * x, axis=1)))
+    logn = np.log2(np.maximum(norms, np.float32(2.0 ** P._HIST_LO)))
+    idx = np.clip(np.floor((logn - np.float32(P._HIST_LO)) * P._HIST_PER_OCTAVE),
+                  0, P._HIST_BUCKETS - 1).astype(np.int64)
+    cdf = np.cumsum(np.bincount(idx, minlength=P._HIST_BUCKETS))
+    k = int(np.argmax(cdf >= -(-rows.shape[0] * 99 // 100)))
+    return float(np.exp2(np.float32((k + 1.0) / P._HIST_PER_OCTAVE + P._HIST_LO)))
+
+
+@pytest.mark.parametrize("case", ["spread", "one_bucket", "zeros", "tail_of_one",
+                                  "tail_starts_early"])
+@pytest.mark.parametrize("V,pad", [(1, 0), (100, 28), (1000, 0), (4097, 31)])
+def test_probe_p99_is_the_histograms_bucket(case, V, pad):
+    """The p99 bucket found by bisection (7 counts) is the one the histogram's
+    CDF gives: exactly, not to a bucket. syn1 is syn0 upside down, so its
+    first V rows are the padding and the table's tail: another distribution."""
+    rows = _probe_rows(case, V)
+    m = np.concatenate([rows, np.zeros((pad, rows.shape[1]), np.float32)])
+    flipped = m[::-1].copy()
+    ch = stats_to_channels(jax.device_get(make_health_probe(V, 10.0)(
+        EmbeddingPair(jax.numpy.asarray(m), jax.numpy.asarray(flipped)))))
+    assert ch["syn0"]["p99_norm"] == pytest.approx(_histogram_p99(rows), rel=1e-6)
+    assert ch["syn1"]["p99_norm"] == pytest.approx(_histogram_p99(flipped[:V]), rel=1e-6)
+
+
+def test_probe_is_the_same_on_a_mesh():
+    """Row-sharded tables (the 8 virtual devices of conftest.py as a 2x4 mesh):
+    the counts become all-reduces and every channel is the one-device value."""
+    from glint_word2vec_tpu.parallel.mesh import make_mesh
+
+    rows = _probe_rows("spread", 4096)
+    plan = make_mesh(2, 4)
+    probe = make_health_probe(4000, 10.0)
+    one = stats_to_channels(jax.device_get(probe(
+        EmbeddingPair(jax.numpy.asarray(rows), jax.numpy.asarray(rows * 2)))))
+    put = lambda x: jax.device_put(jax.numpy.asarray(x), plan.embedding)  # noqa: E731
+    many = stats_to_channels(jax.device_get(probe(
+        EmbeddingPair(put(rows), put(rows * 2)))))
+    for name in ("syn0", "syn1"):
+        assert many[name]["p99_norm"] == one[name]["p99_norm"]
+        assert many[name]["frac_over"] == pytest.approx(one[name]["frac_over"], abs=1e-7)
+        assert many[name]["max_norm"] == one[name]["max_norm"]
+    assert many["finite"] is True
+
+
 def test_probe_finite_bit_matches_old_semantics():
     rng = np.random.default_rng(1)
     a = rng.normal(size=(64, 8)).astype(np.float32)

@@ -6,6 +6,7 @@ production training negative, so its distribution and determinism are load-beari
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from glint_word2vec_tpu.ops.prng import hash_bits, randint_mod, uniform01
 from glint_word2vec_tpu.ops.sampler import (
@@ -75,3 +76,34 @@ def test_sample_negatives_hash_same_under_jit_and_eager():
         lambda p, a, c: sample_negatives_hash(p, a, 9, c, (128,))
     )(table.prob, table.alias, jnp.int32(4))
     np.testing.assert_array_equal(np.asarray(eager), np.asarray(jitted))
+
+
+# The draw, held to NumPy bit for bit over the same lattice: whatever form the two
+# look-ups take on a device (an element gather today; PERF.md §6, PR 32, has the
+# 128-lane row form's probe), the negatives are `where(u < prob[b], b, alias[b])`.
+# V under a lane row, a whole row, ragged, a multiple of 128, large and ragged;
+# the shared pool's [K, P], a flat draw, and a per-example [K, B, n].
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("shape", [(16, 2048), (200_000,), (4, 4096, 5)],
+                         ids=["pool", "flat", "per_example"])
+@pytest.mark.parametrize("V", [100, 128, 1_001, 3 * 2 ** 14, 300_007])
+def test_sample_negatives_hash_is_numpys_draw_bit_for_bit(V, shape, jitted):
+    rng = np.random.default_rng(V)
+    prob = rng.random(V, dtype=np.float32)
+    prob[rng.random(V) < 0.2] = 1.0          # leftovers keep their own index
+    alias = rng.integers(0, V, V, dtype=np.int32)
+    seed, counter = np.uint32(0x9E3779B9), jnp.int32(48)
+    buckets = np.asarray(randint_mod(seed, 0, counter, shape, V))
+    u = np.asarray(uniform01(seed, 1, counter, shape))
+    want = np.where(u < prob[buckets], buckets, alias[buckets])
+
+    def draw(p, a, c):
+        return sample_negatives_hash(p, a, seed, c, shape)
+    got = (jax.jit(draw) if jitted else draw)(jnp.asarray(prob), jnp.asarray(alias), counter)
+    assert got.dtype == jnp.int32 and got.shape == shape
+    np.testing.assert_array_equal(np.asarray(got), want)
+    # the [V, 1] form of the tables draws the same
+    if not jitted:
+        np.testing.assert_array_equal(
+            np.asarray(draw(jnp.asarray(prob)[:, None], jnp.asarray(alias)[:, None], counter)),
+            want)

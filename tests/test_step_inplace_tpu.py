@@ -12,6 +12,13 @@ instructions, not a time.
 The third program is the subword step at ``subword-sgns-2.5m-300``'s size (PR
 31): syn0 is read by one conditional (the centers' listed rows, per run or plain)
 and written by another, and neither may copy f32[4519376,384].
+
+The fourth is no step at all: the health probe every heartbeat runs between two
+dispatches (obs/probe.py). Its p99 bucket used to come from a histogram built by
+a scatter-add of V indices into s32[128], 26.2 ms a table on the chip and 1.64
+ms of every training step (PERF.md §6, PR 32: the ``fusion_s32_128`` pair of the
+ledger's breakdowns). The compiled probe holds no scatter, and on the 1x4 mesh
+of ``sgns-10m-300-x4`` its reductions stay all-reduces of scalars.
 """
 
 import os
@@ -31,13 +38,17 @@ RUNS = dict(center_runs=(10, 24576), context_runs=(6, 20480))
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -97,3 +108,28 @@ def test_no_table_is_copied_with_the_subword_row_source(one_chip, with_metrics):
     copies = [line.strip()[:120] for line in compiled.splitlines()
               if re.search(rf"= f32\[({rows0}|{words}),{D}\]\S* copy\(", line)]
     assert not copies, copies
+
+
+@pytest.mark.parametrize("mesh_shape", [None, (1, 4)], ids=["one_chip", "mesh_1x4"])
+def test_the_health_probe_holds_no_scatter(topo, one_chip, mesh_shape):
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from glint_word2vec_tpu.obs.probe import make_health_probe
+
+    rows, sharding = V, one_chip
+    if mesh_shape:
+        rows = 10_000_000
+        mesh = Mesh(np.array(topo.devices).reshape(mesh_shape), ("data", "model"))
+        sharding = NamedSharding(mesh, PartitionSpec("model", None))
+    table = jax.ShapeDtypeStruct((rows, D), jnp.float32, sharding=sharding)
+    compiled = make_health_probe(rows, 100.0).lower(
+        EmbeddingPair(table, table)).compile().as_text()
+    assert " reduce(" in compiled
+    for op in ("scatter", "sort", "all-gather", "all-to-all", "collective-permute"):
+        assert f" {op}(" not in compiled, op
+    # what the histogram's scatter produced: one s32[128] a table
+    assert not re.search(r"= \(?s32\[128\]", compiled)
+    if mesh_shape:
+        reduced = re.findall(r"= (\S+) all-reduce\(", compiled)
+        assert reduced and all(re.fullmatch(r"\(?\w+\[\]\S*", t) for t in reduced), reduced
