@@ -279,6 +279,16 @@ class Word2VecConfig:
                                     # are refused at construction, never
                                     # silently downgraded. Stays opt-in until
                                     # EVAL evidence lands (acceptance rule)
+    cbow_position_weights: bool = False  # CBOW with position weights (Mikolov
+                                    # et al. 2018, arXiv:1712.09405 §2.2;
+                                    # fastText's cc.*.300 recipe): the window
+                                    # is summed under a learned vector per
+                                    # relative position, a third trainable
+                                    # leaf [2·window, D] initialised to ones
+                                    # and moved by the mean of its examples'
+                                    # updates (ops/cbow_banded.py). The banded
+                                    # form only, with or without subword;
+                                    # refused beside sharded_checkpoint
     subword: bool = False           # subword skip-gram (fastText; Bojanowski et
                                     # al. 2017, arXiv:1607.04606): a word's
                                     # input vector is the mean of its own row
@@ -286,8 +296,11 @@ class Word2VecConfig:
                                     # character n-grams (data/subword.py,
                                     # ops/subword.py); syn0 then has vocabulary
                                     # + subword_buckets rows. The shared-pool
-                                    # skip-gram step on one device only:
-                                    # refused beside cbow, negative_pool=0,
+                                    # skip-gram step, or beside cbow the banded
+                                    # step (a context TOKEN's vector is that
+                                    # mean), on one device only: refused
+                                    # beside cbow_update='scatter',
+                                    # negative_pool=0,
                                     # step_lowering='shard_map', a mesh larger
                                     # than 1x1, device_pairgen,
                                     # duplicate_scaling, sharded_checkpoint and
@@ -903,6 +916,20 @@ class Word2VecConfig:
                     "cbow_update='banded' with window=1 emits no contexts at "
                     "all under the reference's legacy asymmetric window "
                     "(b = nextInt(1) = 0 always) — use window >= 2")
+        # position weights: a row of the banded step alone (select_step's
+        # docstring); the scatter form's [B, 2·window] context sets carry no
+        # position, and a row-shards checkpoint has no file for the leaf
+        if self.cbow_position_weights:
+            if not (self.cbow and self.cbow_update == "banded"):
+                raise ValueError(
+                    "cbow_position_weights=True requires cbow=True with "
+                    "cbow_update='banded': the scatter form's context sets "
+                    "are left-packed and carry no relative position")
+            if self.sharded_checkpoint:
+                raise ValueError(
+                    "cbow_position_weights=True does not support "
+                    "sharded_checkpoint=True: the position weights are saved "
+                    "beside the dense layout only")
         if (self.cbow and self.duplicate_scaling and self.negative_pool > 0):
             raise ValueError(
                 "CBOW with duplicate_scaling=True implements mean semantics "
@@ -1094,16 +1121,19 @@ class Word2VecConfig:
         # --- subword selection matrix (trainer.select_step's docstring has the
         # row; Trainer.__init__ keeps the runtime twin for a mesh handed in as
         # a plan). The row source is the shared-pool skip-gram step's center
-        # side on one device; every other combination is an ERROR here:
-        #   subword × cbow               → refuse (a multi-row CONTEXT is
-        #       another step: ops/cbow_banded.py has no list per token)
+        # side, or the banded CBOW step's token side, on one device; every
+        # other combination is an ERROR here:
+        #   subword × cbow "scatter"     → refuse (the row source of a token
+        #       block lives in ops/cbow_banded.py; the [B, 2·window] context
+        #       sets of the scatter forms have no list per entry)
         #   subword × negative_pool=0    → refuse (the per-pair step gathers
         #       one row a center; the row source lives in the shared-pool step)
         #   subword × shard_map          → refuse (a list's rows live on other
         #       chips; ops/sgns_shard.py gathers owner-locally one row a center)
         #   subword × mesh > 1x1         → refuse (the same, under GSPMD)
         #   subword × device_pairgen     → refuse (center runs are the host
-        #       pair feed's order; the token-block chunk has no table argument)
+        #       pair feed's order; the skip-gram token-block chunk has no
+        #       table argument)
         #   subword × duplicate_scaling  → refuse (occurrence counts are per
         #       word row; a list's rows have none)
         #   subword × sharded_checkpoint → refuse (bucket rows ride a file of
@@ -1120,10 +1150,11 @@ class Word2VecConfig:
                 raise ValueError(
                     f"subword_buckets must be positive but got "
                     f"{self.subword_buckets}")
-            if self.cbow:
+            if self.cbow and self.cbow_update != "banded":
                 raise ValueError(
-                    "subword=True is the skip-gram step's center side; CBOW's "
-                    "context lists have no subword form — set cbow=False")
+                    "subword=True beside cbow=True needs cbow_update='banded': "
+                    "a context token's row list is the banded step's row "
+                    "source, and the scatter form's context sets have none")
             if self.negative_pool == 0:
                 raise ValueError(
                     "subword=True requires the shared-pool estimator "

@@ -82,7 +82,8 @@ class Word2Vec:
         return Word2VecModel(
             vocab=vocab, syn0=params.syn0, syn1=params.syn1,
             config=cfg, plan=trainer.plan, train_state=trainer.state,
-            subword_buckets=trainer.subword_buckets())
+            subword_buckets=trainer.subword_buckets(),
+            position_weights=trainer.position_weights())
 
     @staticmethod
     def resume(
@@ -202,7 +203,9 @@ class Word2Vec:
                 # vocabulary's (save_model keeps them in a file of their own)
                 syn0 = jnp.concatenate(
                     [syn0, jnp.asarray(data["subword_buckets"])])
-            params = EmbeddingPair(syn0, jnp.asarray(data["syn1"]))
+            pos = data.get("position_weights")
+            params = EmbeddingPair(syn0, jnp.asarray(data["syn1"]),
+                                   None if pos is None else jnp.asarray(pos))
         trainer = Trainer(cfg, vocab, plan=plan, params=params, train_state=state)
         if not state.finished:
             # pass checkpoint_every_steps explicitly to keep periodic checkpointing
@@ -214,4 +217,5 @@ class Word2Vec:
         return Word2VecModel(
             vocab=vocab, syn0=out.syn0, syn1=out.syn1, config=cfg,
             plan=trainer.plan, train_state=trainer.state,
-            subword_buckets=trainer.subword_buckets())
+            subword_buckets=trainer.subword_buckets(),
+            position_weights=trainer.position_weights())

@@ -46,7 +46,13 @@ class Word2VecModel:
         plan: Optional[MeshPlan] = None,
         train_state: Optional["ckpt.TrainState"] = None,
         subword_buckets: Optional[jax.Array] = None,
+        position_weights: Optional[np.ndarray] = None,
     ):
+        # a position-weighted CBOW model's third leaf
+        # (config.cbow_position_weights): trained state kept so that a saved
+        # model can be resumed; no query reads it and no export writes it
+        self.position_weights = (None if position_weights is None
+                                 else np.asarray(position_weights, np.float32))
         # a subword model (config.subword) answers with COMPOSED vectors: what
         # arrives is syn0's words' own rows and its bucket rows; every query
         # below scans h_w, the mean of a word's listed rows, made once here
@@ -547,7 +553,8 @@ class Word2VecModel:
             np.asarray(self.syn1) if self.syn1 is not None else None,
             self.config, self.train_state,
             subword_buckets=(None if self._buckets is None
-                             else np.asarray(self._buckets)))
+                             else np.asarray(self._buckets)),
+            position_weights=self.position_weights)
 
     @classmethod
     def load(cls, path: str, plan: Optional[MeshPlan] = None,
@@ -595,6 +602,7 @@ class Word2VecModel:
             plan=plan,
             train_state=data["train_state"],
             subword_buckets=data.get("subword_buckets"),
+            position_weights=data.get("position_weights"),
         )
 
     @classmethod

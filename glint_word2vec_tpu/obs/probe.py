@@ -103,6 +103,10 @@ def make_health_probe(vocab_size: int, threshold: float) -> Callable:
     def probe(params) -> HealthStats:
         finite = (jnp.isfinite(params.syn0).all()
                   & jnp.isfinite(params.syn1).all())
+        if params.pos is not None:
+            # the position weights (config.cbow_position_weights) are part of
+            # the carry: a diverged leaf is a non-finite carry
+            finite = finite & jnp.isfinite(params.pos).all()
         return HealthStats(
             finite=finite,
             syn0=_matrix_stats(params.syn0, vocab_size, threshold),

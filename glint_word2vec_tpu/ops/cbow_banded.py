@@ -138,6 +138,62 @@ def _band_endpoint_delta(
     return delta
 
 
+def _tap_masks(left: jax.Array, right: jax.Array, window: int):
+    """For each relative position p of −window..window without 0, in the order
+    of the position weights' rows: (p, int32 [T + 2·window] extent to compare
+    with |p|, padded so that a shifted slice reads "no window" past the
+    block's ends). Slot t has position p where ``|p| <= extent[t]``."""
+    lp = jnp.pad(left, (window, window), constant_values=-1)
+    rp = jnp.pad(right, (window, window), constant_values=-1)
+    return [(p, lp if p < 0 else rp)
+            for p in list(range(-window, 0)) + list(range(1, window + 1))]
+
+
+def position_taps(x: jax.Array, weights: jax.Array, left: jax.Array,
+                  right: jax.Array, window: int,
+                  transpose: bool = False) -> jax.Array:
+    """The window sum under per-position weights, a depthwise correlation
+    along the token axis with 2·window taps masked per (slot, position) by the
+    drawn extents (no interval sum: the weights differ by position):
+
+        forward    out[t] = Σ_p [p ∈ P_t] · weights[p] ⊙ x[t + p]
+        transpose  out[j] = Σ_p [p ∈ P_{j−p}] · weights[p] ⊙ x[j − p]
+
+    ``x`` [T, D] and ``weights`` [2·window, D] in the accumulation dtype;
+    2·window shifted masked multiply-adds over static slices of one
+    zero-padded copy, which XLA fuses into one pass (as
+    :func:`_band_endpoint_delta`'s)."""
+    T = x.shape[0]
+    xp = jnp.pad(x, ((window, window), (0, 0)))
+    out = jnp.zeros_like(x)
+    for row, (p, extent) in enumerate(_tap_masks(left, right, window)):
+        if transpose:       # slot j − p gives, under ITS window
+            at = window - p
+            has = extent[at:at + T] >= abs(p)
+        else:               # slot t takes from t + p, under its own window
+            at = window + p
+            has = extent[window:window + T] >= abs(p)
+        out = out + jnp.where(has[:, None], xp[at:at + T] * weights[row], 0)
+    return out
+
+
+def position_weight_sums(g: jax.Array, x: jax.Array, live: jax.Array,
+                         left: jax.Array, right: jax.Array, window: int):
+    """``(Σ_t [p ∈ P_t] g[t] ⊙ x[t + p], #{live t : p ∈ P_t})`` for every
+    position p: the position weights' summed gradient [2·window, D] and the
+    examples that have each position [2·window]: 2·window product-reductions
+    over the block."""
+    T = x.shape[0]
+    xp = jnp.pad(x, ((window, window), (0, 0)))
+    sums, counts = [], []
+    for p, extent in _tap_masks(left, right, window):
+        has = extent[window:window + T] >= abs(p)
+        sums.append(jnp.sum(jnp.where(
+            has[:, None], g * xp[window + p:window + p + T], 0), axis=0))
+        counts.append(jnp.sum(jnp.where(has, live, 0)))
+    return jnp.stack(sums), jnp.stack(counts)
+
+
 def cbow_step_banded_core(
     params: EmbeddingPair,
     tokens: jax.Array,       # int32 [T] — kept tokens, sentence-contiguous
@@ -154,6 +210,7 @@ def cbow_step_banded_core(
     logits_dtype: jnp.dtype = jnp.float32,
     with_metrics: bool = True,
     stabilizers: Optional[Stabilizers] = None,
+    subword: Optional[tuple] = None,
 ) -> Tuple[EmbeddingPair, StepMetrics]:
     """Banded CBOW update — mathematically the shared-pool scatter step
     (:func:`~glint_word2vec_tpu.ops.sgns.cbow_step_shared_core`) on the example
@@ -168,8 +225,27 @@ def cbow_step_banded_core(
     this block's core centers (their remaining gradient arrives in the block
     where they are core — each (center, context) link is applied exactly once
     across the overlapping feed).
+
+    ``params.pos`` (config.cbow_position_weights; Mikolov et al. 2018,
+    arXiv:1712.09405 §2.2): the window is summed under a learned vector per
+    relative position, ``hidden_b = (1/n_b) Σ_p pos[p] ⊙ e_{b+p}``. No
+    interval sum holds for unequal weights, so both prefix sums give way to
+    :func:`position_taps`, and ``pos`` moves by the MEAN of its per-example
+    updates over the live examples that have each position (it is the one
+    parameter every example touches: their sum at the per-example rate would
+    be a step tens of thousands of times one example's).
+
+    ``subword`` ``(SubwordTable, SubwordShape)`` (config.subword;
+    :mod:`.subword`): a TOKEN's vector is the mean of the rows its word's
+    list names, ``e = u[tokens]`` composed from the lists, and ``d_ctx`` is
+    divided and spread back over them in one scatter; syn0 then holds the
+    vocabulary's rows and the bucket rows. Every token slot reads its own
+    word's list (``shape`` is ``(max_groups, 1, T)``): reading a list once per
+    distinct word of the block, the tokens sorted by word inside the step,
+    came in at 0.86 of this form's step on the chip where the bar was 0.7
+    (PERF.md §6, PR 33). With neither, the program is the one it was.
     """
-    syn0, syn1 = params
+    syn0, syn1, pos_w = params
     T = tokens.shape[0]
     P = negatives.shape[0]
     t = jnp.arange(T, dtype=jnp.int32)
@@ -183,18 +259,32 @@ def cbow_step_banded_core(
     # metadata only
     # -- forward: windowed context mean via one prefix-sum difference ---------
     rows_in_bf16 = jnp.dtype(compute_dtype) == jnp.bfloat16
+    if subword is not None:
+        from glint_word2vec_tpu.ops import subword as sw
+        sw_table, sw_shape = subword
+        # a masked slot is no word: it lists nothing and receives nothing
+        words = jnp.where(token_mask > 0, tokens, sw_table.counts.shape[0] - 1)
+        sw_plan = sw.plan_centers(words, sw_table, sw_shape, dtype=pf)
     with jax.named_scope("cbow.gather"):
-        e = syn0[tokens].astype(pf)                                 # [T, D]
+        if subword is None:
+            e = syn0[tokens].astype(pf)                             # [T, D]
+        else:
+            e = sw.center_vectors(syn0, words, sw_table, sw_shape, sw_plan, pf)
         if rows_in_bf16:
             # the rows enter the sum at compute_dtype, as the scatter form's
             # do, rounded to nearest HERE: the forward prefix is then one MXU
             # pass and exact on them (reduce_precision, because XLA may drop
             # an astype round trip as excess precision)
             e = jax.lax.reduce_precision(e, exponent_bits=8, mantissa_bits=7)
-    with jax.named_scope("cbow.context_sum"):
-        S = cumsum_rows(e.astype(pf), one_pass=rows_in_bf16)        # [T, D]
-        Spad = jnp.concatenate([jnp.zeros((1, S.shape[1]), pf), S])  # S[<i] sums
-        ctx_sum = Spad[t + right + 1] - Spad[t - left] - e
+    with jax.named_scope("cbow.context_sum" if pos_w is None
+                         else "cbow.position_taps"):
+        if pos_w is None:
+            S = cumsum_rows(e.astype(pf), one_pass=rows_in_bf16)    # [T, D]
+            Spad = jnp.concatenate([jnp.zeros((1, S.shape[1]), pf), S])  # S[<i]
+            ctx_sum = Spad[t + right + 1] - Spad[t - left] - e
+        else:
+            weights = pos_w.astype(pf)
+            ctx_sum = position_taps(e, weights, left, right, window)
         ctx_n = jnp.maximum(ctx_n_i, 1).astype(pf)
         hidden = (ctx_sum / ctx_n[:, None]).astype(compute_dtype)   # [T, D]
 
@@ -230,14 +320,29 @@ def cbow_step_banded_core(
         d_out = clip_update_rows(d_out, stabilizers.update_clip)
 
     # -- backward: banded spread of d_hidden/n via difference array + prefix --
-    with jax.named_scope("cbow.context_sum"):
-        g_row = d_hidden.astype(pf) / ctx_n[:, None]                # [T, D]
-        delta = _band_endpoint_delta(g_row, left, right, window)
-        d_ctx = (cumsum_rows(delta) - g_row) * token_mask[:, None].astype(pf)
+    g_row = d_hidden.astype(pf) / ctx_n[:, None]                    # [T, D]
+    new_pos = None
+    if pos_w is None:
+        with jax.named_scope("cbow.context_sum"):
+            delta = _band_endpoint_delta(g_row, left, right, window)
+            d_ctx = cumsum_rows(delta) - g_row
+    else:
+        with jax.named_scope("cbow.position_taps"):
+            d_ctx = position_taps(g_row, weights, left, right, window,
+                                  transpose=True)
+            d_pos, having = position_weight_sums(
+                g_row, e, live.astype(pf), left, right, window)
+            new_pos = pos_w + (d_pos / jnp.maximum(having, 1)[:, None]
+                               ).astype(pos_w.dtype)
+    d_ctx = d_ctx * token_mask[:, None].astype(pf)
 
     dtype = syn0.dtype
     with jax.named_scope("cbow.scatter_syn0"):
-        new_syn0 = syn0.at[tokens].add(d_ctx.astype(dtype))
+        if subword is None:
+            new_syn0 = syn0.at[tokens].add(d_ctx.astype(dtype))
+        else:
+            new_syn0 = sw.scatter_center_updates(
+                syn0, words, d_ctx, sw_table, sw_shape, sw_plan)
     with jax.named_scope("cbow.scatter_syn1"):
         new_syn1 = syn1.at[tokens].add(d_out.astype(dtype))
         new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
@@ -271,5 +376,6 @@ def cbow_step_banded_core(
         loss=loss,
         mean_f_pos=mean_f_pos,
         pairs=live.sum(),
+        subword_rows=None if subword is None else sw_plan.live_rows,
     )
-    return EmbeddingPair(new_syn0, new_syn1), metrics
+    return EmbeddingPair(new_syn0, new_syn1, new_pos), metrics

@@ -238,6 +238,11 @@ class EmbeddingPair(NamedTuple):
 
     syn0: jax.Array  # [V, D] input embeddings — the word vectors the model exports
     syn1: jax.Array  # [V, D] output embeddings — negative-sampling softmax weights
+    # [2·window, D] position weights (config.cbow_position_weights;
+    # ops/cbow_banded.py): row p + window for p < 0, p + window − 1 for p > 0.
+    # None on every other model: the pytree then has the two leaves it always
+    # had, so their compiled steps and checkpoints are what they were
+    pos: Optional[jax.Array] = None
 
 
 class StepMetrics(NamedTuple):
@@ -375,7 +380,7 @@ def sgns_step_core(
     einsum in compute dtype and upcast AFTER — chain mode is the stricter R4
     form). Both default off; off elides the new ops entirely (bit-identical
     step)."""
-    syn0, syn1 = params
+    syn0, syn1 = params.syn0, params.syn1
     V = syn0.shape[0]
     if duplicate_scaling and fused:
         raise ValueError("duplicate_scaling has no fused form "
@@ -672,7 +677,7 @@ def sgns_step_shared_core(
     bf16, PERF.md §4); ``pairs`` stays exact (it is load-bearing for the
     trainer's pair accounting). The trainer dispatches this variant for chunks
     no heartbeat will sample."""
-    syn0, syn1 = params
+    syn0, syn1 = params.syn0, params.syn1
     V = syn0.shape[0]
     if duplicate_scaling and fused:
         raise ValueError("duplicate_scaling has no fused form "
@@ -831,7 +836,7 @@ def cbow_step_core(
     applies the identical clipped quantity), d_out, and per-example d_neg
     rows; the post pass clamps/decays syn0 at the live context slots and syn1
     at the live centers plus the negatives of unmasked examples."""
-    syn0, syn1 = params
+    syn0, syn1 = params.syn0, params.syn1
     B, C = contexts.shape
     neg_valid = (negatives != centers[:, None]).astype(jnp.float32) * mask[:, None]
 
@@ -929,7 +934,7 @@ def cbow_step_shared_core(
     the trainer's metrics-elided fast twin). ``stabilizers``: clips d_hidden
     (pre mean-split, so the banded formulation matches) and d_out, never d_Z;
     post pass over the live context slots, live centers, and the whole pool."""
-    syn0, syn1 = params
+    syn0, syn1 = params.syn0, params.syn1
     P = negatives.shape[0]
     neg_valid = (negatives[None, :] != centers[:, None]).astype(logits_dtype) \
         * mask[:, None].astype(logits_dtype)

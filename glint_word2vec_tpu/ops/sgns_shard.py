@@ -285,7 +285,7 @@ def make_shard_map_sgns_step(
         check_vma=False)
 
     def step(params, batch, negatives, alpha):
-        syn0, syn1 = params
+        syn0, syn1 = params.syn0, params.syn1
         v, b = syn0.shape[0], batch["centers"].shape[0]
         if v % nm:
             raise ValueError(
@@ -438,7 +438,7 @@ def make_shard_map_sgns_step(
         check_vma=False)
 
     def window(params, batch, negatives, alphas):
-        syn0, syn1 = params
+        syn0, syn1 = params.syn0, params.syn1
         v, b = syn0.shape[0], batch["centers"].shape[1]
         if v % nm:
             raise ValueError(

@@ -77,9 +77,16 @@ class CenterPlan(NamedTuple):
     live_rows: jax.Array  # float32: rows with a live index the scatter gets
 
 
-def _lists(words: jax.Array, table: SubwordTable, max_groups: int):
+def _acc(syn0: jax.Array):
+    """The dtype the lists' sums run in: float32, or the table's if wider."""
+    return jnp.promote_types(syn0.dtype, jnp.float32)
+
+
+def _lists(words: jax.Array, table: SubwordTable, max_groups: int,
+           dtype: jnp.dtype = jnp.float32):
     """Every word's list padded to the longest: [C, max_groups · GROUP] row
-    ids (NO_ROW past the list), and 1 / |G| (0 for index V, "no word")."""
+    ids (NO_ROW past the list), and 1 / |G| in ``dtype`` (0 for index V, "no
+    word")."""
     lo = table.offsets[words]
     g = jnp.arange(max_groups, dtype=jnp.int32)
     has = g[None, :] < (table.offsets[words + 1] - lo)[:, None]
@@ -87,15 +94,22 @@ def _lists(words: jax.Array, table: SubwordTable, max_groups: int):
         jnp.where(has, lo[:, None] + g[None, :], table.rows.shape[0])
     ].get(mode="fill", fill_value=NO_ROW)
     count = table.counts[words]
-    inv = jnp.where(count > 0, 1.0 / jnp.maximum(count, 1).astype(jnp.float32), 0.0)
+    inv = jnp.where(count > 0, 1.0 / jnp.maximum(count, 1).astype(dtype), 0.0)
     return rows.reshape(words.shape[0], max_groups * GROUP), inv
 
 
 def plan_centers(centers: jax.Array, table: SubwordTable,
-                 shape: SubwordShape) -> CenterPlan:
+                 shape: SubwordShape,
+                 dtype: jnp.dtype = jnp.float32) -> CenterPlan:
     """Heads of the batch's center runs, compacted (as
     :func:`..ops.sgns.scatter_add_by_runs` compacts them), and each head's
-    list: 1-D index work and one small gather of row ids."""
+    list: 1-D index work and one small gather of row ids. ``dtype``: the
+    lists' sums run in it (float32, or the tables' dtype if wider).
+
+    ``shape.max_run == 1`` with room for every entry is the row source of a
+    CBOW token block (ops/cbow_banded.py): a block has no runs (the same word
+    recurs scattered over it), so every token slot is the head of its own
+    list."""
     from glint_word2vec_tpu.ops.sgns import run_positions
 
     n, v = centers.shape[0], table.counts.shape[0] - 1
@@ -107,8 +121,11 @@ def plan_centers(centers: jax.Array, table: SubwordTable,
     live = jnp.sort(jnp.where(head, at, n))[:hcap]
     src = jnp.minimum(live, n - 1)
     word = jnp.where(live < n, centers[src], v)             # v: no word
-    rows, inv = _lists(word, table, shape.max_groups)
-    fits = heads <= hcap
+    rows, inv = _lists(word, table, shape.max_groups, dtype)
+    # every entry a head of its own and room for all of them: known while
+    # tracing, so no second branch is built
+    fits = (jnp.bool_(True) if shape.max_run == 1 and hcap >= n
+            else heads <= hcap)
     return CenterPlan(
         fits=fits, pos=pos,
         pair_head=jnp.minimum(jnp.cumsum(head.astype(jnp.int32)) - 1, hcap - 1),
@@ -117,18 +134,28 @@ def plan_centers(centers: jax.Array, table: SubwordTable,
                             table.counts[centers].sum()).astype(jnp.float32))
 
 
+def _either(plan: CenterPlan, shape: SubwordShape, n: int, per_run, plain,
+            syn0: jax.Array) -> jax.Array:
+    """``per_run`` where the plan fits its capacity, else ``plain``; one
+    branch alone where that is known while tracing."""
+    if shape.max_run == 1 and shape.head_cap >= n:
+        return per_run(syn0)
+    return jax.lax.cond(plan.fits, per_run, plain, syn0)
+
+
 def center_vectors(syn0: jax.Array, centers: jax.Array, table: SubwordTable,
                    shape: SubwordShape, plan: CenterPlan,
                    compute_dtype: jnp.dtype) -> jax.Array:
     """``e_in`` [B, D] in ``compute_dtype``: every pair's center vector, the
-    mean of its word's listed rows (sums and the count in float32)."""
-    d = syn0.shape[1]
+    mean of its word's listed rows (sums and the count in float32, or in
+    the table's dtype if wider)."""
+    d, acc = syn0.shape[1], _acc(syn0)
 
     def mean_of(syn0, rows, inv):
         with jax.named_scope("subword.gather"):
             got = syn0.at[rows].get(mode="fill", fill_value=0)
         with jax.named_scope("subword.mean"):
-            return (got.astype(jnp.float32).sum(axis=1) * inv[:, None]
+            return (got.astype(acc).sum(axis=1) * inv[:, None]
                     ).astype(compute_dtype)
 
     def per_run(syn0):
@@ -137,10 +164,11 @@ def center_vectors(syn0: jax.Array, centers: jax.Array, table: SubwordTable,
     def plain(syn0):
         c = math.gcd(centers.shape[0], _PLAIN_CHUNK)
         return jax.lax.map(
-            lambda words: mean_of(syn0, *_lists(words, table, shape.max_groups)),
+            lambda words: mean_of(
+                syn0, *_lists(words, table, shape.max_groups, acc)),
             centers.reshape(-1, c)).reshape(-1, d)
 
-    return jax.lax.cond(plan.fits, per_run, plain, syn0)
+    return _either(plan, shape, centers.shape[0], per_run, plain, syn0)
 
 
 def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
@@ -151,7 +179,7 @@ def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
     (:func:`run_sums`), then one scatter of the heads' blocks."""
     from glint_word2vec_tpu.ops.sgns import run_sums
 
-    d = syn0.shape[1]
+    d, acc = syn0.shape[1], _acc(syn0)
 
     def spread(syn0, rows, d_h):
         return syn0.at[rows].add(
@@ -160,7 +188,7 @@ def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
 
     def per_run(syn0):
         with jax.named_scope("subword.mean"):
-            sums = run_sums(d_in, plan.pos, shape.max_run, jnp.float32)
+            sums = run_sums(d_in, plan.pos, shape.max_run, acc)
             d_h = sums[plan.src] * plan.inv[:, None]
         with jax.named_scope("subword.scatter"):
             return spread(syn0, plan.rows, d_h)
@@ -170,12 +198,12 @@ def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
 
         def chunk(syn0, xs):
             words, rows_d = xs
-            rows, inv = _lists(words, table, shape.max_groups)
-            return spread(syn0, rows, rows_d.astype(jnp.float32) * inv[:, None]), None
+            rows, inv = _lists(words, table, shape.max_groups, acc)
+            return spread(syn0, rows, rows_d.astype(acc) * inv[:, None]), None
         return jax.lax.scan(
             chunk, syn0, (centers.reshape(-1, c), d_in.reshape(-1, c, d)))[0]
 
-    return jax.lax.cond(plan.fits, per_run, plain, syn0)
+    return _either(plan, shape, centers.shape[0], per_run, plain, syn0)
 
 
 def compose_vectors(syn0: jax.Array, table: SubwordTable, max_groups: int,

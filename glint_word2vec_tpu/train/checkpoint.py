@@ -242,6 +242,7 @@ def save_model(
     train_state: Optional[TrainState] = None,
     extra_metadata: Optional[Dict[str, Any]] = None,
     subword_buckets: Optional[np.ndarray] = None,
+    position_weights: Optional[np.ndarray] = None,
 ) -> None:
     """Atomic save: everything is written to a sibling temp directory first and swapped
     into place, so a crash mid-save never corrupts an existing checkpoint (the whole point
@@ -263,7 +264,12 @@ def save_model(
     ``subword_buckets``: a subword model's bucket rows (config.subword: the
     rows of syn0 after the vocabulary's), a file ``syn0_buckets.npy`` with a
     digest of its own; ``syn0.npy`` keeps the words' own rows, so every reader
-    of the dense layout still finds one row a word."""
+    of the dense layout still finds one row a word.
+
+    ``position_weights``: a position-weighted CBOW model's third leaf
+    (config.cbow_position_weights), [2·window, D], a file
+    ``position_weights.npy`` with a digest of its own. Trained state that a
+    resume needs; no part of a served vector."""
     bad = [w for w in words if (not w) or ("\n" in w)]
     if bad:
         raise ValueError(
@@ -296,6 +302,11 @@ def save_model(
                 stage("syn0_buckets.npy"),
                 np.asarray(subword_buckets, dtype=np.float32)))
             names.append("syn0_buckets.npy")
+        if position_weights is not None:
+            tasks.append(lambda: _save_npy_hashed(
+                stage("position_weights.npy"),
+                np.asarray(position_weights, dtype=np.float32)))
+            names.append("position_weights.npy")
         digests: Dict[str, str] = dict(
             zip(names, _run_io(tasks, getattr(config, "io_workers", 1))))
         faults.crash_point("save:arrays-written")
@@ -864,6 +875,17 @@ def load_model(path: str, header: Optional[Dict[str, Any]] = None,
         raise ValueError(
             f"words sidecar has {len(words)} entries but syn0 has {syn0.shape[0]} rows")
     buckets_path = os.path.join(path, "syn0_buckets.npy")
+    pos_path = os.path.join(path, "position_weights.npy")
+    position_weights = np.load(pos_path) if os.path.exists(pos_path) else None
+    cfg = header["config"]
+    got = None if position_weights is None else position_weights.shape
+    want = ((2 * cfg.window, syn0.shape[1]) if cfg.cbow_position_weights
+            else None)
+    if got != want:
+        raise ValueError(
+            f"checkpoint {path!r}: position weights of shape {got} where its "
+            f"config (cbow_position_weights={cfg.cbow_position_weights}, "
+            f"window={cfg.window}) needs {want}")
     return {
         "words": words,
         "counts": header["counts"],
@@ -872,6 +894,8 @@ def load_model(path: str, header: Optional[Dict[str, Any]] = None,
         # a subword model's bucket rows (save_model), None for any other
         "subword_buckets": (np.load(buckets_path)
                             if os.path.exists(buckets_path) else None),
+        # a position-weighted CBOW model's third leaf, None for any other
+        "position_weights": position_weights,
         "config": header["config"],
         "train_state": header["train_state"],
     }

@@ -157,6 +157,8 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
       cbow   cbow_update  P    duplicate_scaling  step_lowering  → core                      negatives
       -----  -----------  ---  -----------------  -------------  --------------------------  ----------
       True   "banded"     > 0  False              gspmd          cbow_step_banded_core       [K, P]
+                                                                 (+ subword, position
+                                                                 weights, below)
       True   "scatter"    > 0  False              gspmd          cbow_step_shared_core       [K, P]
       True   "scatter"    = 0  any                gspmd          cbow_step_core              [K, B, n]
       False  —            = 0  any                gspmd          sgns_step_core              [K, B, n]
@@ -169,9 +171,18 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
     ``subword=True`` is no row of its own: it is the last row with the center's
     row source on (``subword=(table, shape)``, ops/subword.py; the table rides
     the batch as ``batch["subword_table"]``, a jit argument of the chunk), on
-    one device and the host pair feed. Beside it config refuses cbow, P = 0,
+    one device and the host pair feed; or the FIRST row with the token block's
+    row source on (the same argument of ``cbow_step_banded_core``: a context
+    token's vector is the mean of its word's listed rows), on one device and
+    the token feed. Beside it config refuses cbow "scatter", P = 0,
     "shard_map", a mesh larger than 1x1, device_pairgen, duplicate_scaling,
     sharded_checkpoint and the touched-row stabilizers.
+
+    ``cbow_position_weights=True`` is the first row too, with a third leaf in
+    the params (``EmbeddingPair.pos``, [2·window, D]): the banded step sums
+    the window under it by taps in place of its two prefix sums, with or
+    without ``subword``. Beside it config refuses every other row (cbow
+    "scatter", skip-gram) and sharded_checkpoint.
 
     ``context_cap`` is :func:`_context_run_cap` of the trainer's vocabulary,
     ``stabilizers`` is the trainer's state (None = all off), ``with_metrics``
@@ -199,7 +210,9 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
                 band.center.reshape(-1), band.token.reshape(-1),
                 negatives, alpha, n, cfg.window, cfg.sigmoid_mode,
                 compute_dtype, logits_dtype, with_metrics,
-                stabilizers=stabilizers)
+                stabilizers=stabilizers,
+                subword=(None if subword_shape is None else
+                         (batch["subword_table"], subword_shape)))
 
         return StepChoice(cbow_step_banded_core, step, shared_pool, None)
 
@@ -621,6 +634,25 @@ class Trainer:
             if config.subword:      # syn1 has the vocabulary's rows alone
                 params = EmbeddingPair(params.syn0,
                                        params.syn1[:self.padded_vocab])
+        if config.cbow_position_weights and params.pos is None:
+            # the model starts as plain CBOW: every position weighs one
+            params = params._replace(pos=jnp.ones(
+                (2 * config.window, config.vector_size),
+                jnp.dtype(config.param_dtype)))
+        if (params.pos is not None) != config.cbow_position_weights or (
+                params.pos is not None
+                and params.pos.shape[0] != 2 * config.window):
+            raise ValueError(
+                f"cbow_position_weights={config.cbow_position_weights} with "
+                f"window={config.window} needs position weights of "
+                f"{2 * config.window} rows, and the params hold "
+                f"{None if params.pos is None else params.pos.shape}")
+        # the carry's shardings: the tables by rows, the position weights (a
+        # few rows every example reads) on every device
+        self._params_sharding = EmbeddingPair(
+            self._emb_sharding, self._emb_sharding,
+            None if params.pos is None else plan.replicated)
+        pos = params.pos
         if (isinstance(params.syn0, jax.Array)
                 and params.syn0.shape == (self._syn0_rows, self.padded_dim)
                 and params.syn0.dtype == jnp.dtype(config.param_dtype)
@@ -635,6 +667,17 @@ class Trainer:
                 # the callback assembly is consistent across hosts
                 {"syn0": np.asarray(params.syn0), "syn1": np.asarray(params.syn1)})
             self.params = EmbeddingPair(placed["syn0"], placed["syn1"])
+        if pos is not None and not (
+                isinstance(pos, jax.Array)
+                and pos.shape == (2 * config.window, self.padded_dim)
+                and pos.dtype == jnp.dtype(config.param_dtype)
+                and pos.sharding.is_equivalent_to(plan.replicated, 2)):
+            # lane-padded as the tables are, the padding exactly 0
+            padded = np.zeros((2 * config.window, self.padded_dim),
+                              jnp.dtype(config.param_dtype))
+            padded[:, :pos.shape[1]] = np.asarray(pos)
+            pos = put_global(plan.replicated, {"pos": padded})["pos"]
+        self.params = self.params._replace(pos=pos)
         self.state = train_state or TrainState()
         # additive checkpoint-metadata keys (train/checkpoint.py
         # extra_metadata) merged into EVERY save this trainer performs —
@@ -909,13 +952,19 @@ class Trainer:
             span.set(slots=rows.slots)
         self.subword_table_time = time.perf_counter() - t0
         self._step_extra = (placed["offsets"], placed["rows"], placed["counts"])
-        # center runs as the plain step's (one head per run of a center's
-        # pairs); where none are built every pair is its own head
-        cap = (_center_run_cap(cfg.window, cfg.pairs_per_batch)
-               if self.plan.num_data == 1 else 0)
-        self._subword_shape = sw.SubwordShape(
-            rows.max_groups, *((2 * cfg.window, cap) if cap
-                               else (1, cfg.pairs_per_batch)))
+        if self._banded_cbow:
+            # the row source of a token block (ops/cbow_banded.py): every
+            # token slot of the block reads its own word's list
+            self._subword_shape = sw.SubwordShape(
+                rows.max_groups, 1, self._tokens_per_step)
+        else:
+            # center runs as the plain step's (one head per run of a center's
+            # pairs); where none are built every pair is its own head
+            cap = (_center_run_cap(cfg.window, cfg.pairs_per_batch)
+                   if self.plan.num_data == 1 else 0)
+            self._subword_shape = sw.SubwordShape(
+                rows.max_groups, *((2 * cfg.window, cap) if cap
+                                   else (1, cfg.pairs_per_batch)))
         logger.info("subword table: %d words, %d slots, %s in %.2fs",
                     self.vocab.size, rows.slots, self._subword_shape,
                     self.subword_table_time)
@@ -1209,7 +1258,6 @@ class Trainer:
 
         is_cbow = cfg.cbow
         S = self._feed_segments
-        emb_sharding = self._emb_sharding
         # > 1 only on the shard_map SGNS path (config refuses every other
         # combination) — the chunk below scans windows instead of steps
         sync_k = cfg.sync_every
@@ -1254,7 +1302,7 @@ class Trainer:
                     batch, dropped = build_batch(xs, nv)
                     new_p, metrics = inner(p, batch, negs, alpha)
                     new_p = jax.lax.with_sharding_constraint(
-                        new_p, EmbeddingPair(emb_sharding, emb_sharding))
+                        new_p, self._params_sharding)
                     return new_p, (metrics, dropped)
 
                 return jax.lax.scan(
@@ -1327,7 +1375,7 @@ class Trainer:
                 xs, alpha, real, negs = inp
                 new_p, metrics = inner(p, build_batch(xs, real), negs, alpha)
                 new_p = jax.lax.with_sharding_constraint(
-                    new_p, EmbeddingPair(emb_sharding, emb_sharding))
+                    new_p, self._params_sharding)
                 return new_p, metrics
 
             xs_all = (arrays, alphas, reals, negatives)
@@ -1357,7 +1405,7 @@ class Trainer:
                     new_p, metrics = inner(
                         p, build_window(xs, real), negs, alpha)
                     new_p = jax.lax.with_sharding_constraint(
-                        new_p, EmbeddingPair(emb_sharding, emb_sharding))
+                        new_p, self._params_sharding)
                     return new_p, metrics
 
                 xs_win = jax.tree.map(
@@ -1387,7 +1435,6 @@ class Trainer:
         from glint_word2vec_tpu.ops.pairgen import device_cbow_windows
         W = cfg.window
         H = self._block_halo
-        emb_sharding = self._emb_sharding
 
         win = jax.vmap(
             lambda tk, st, nv, lo, hi, wb: device_cbow_windows(
@@ -1395,7 +1442,9 @@ class Trainer:
             in_axes=(0, 0, 0, 0, 0, 0))
 
         def banded_chunk(params, arrays, meta, base_step, prob, alias,
-                         keep_prob, sub_bases, win_bases):
+                         keep_prob, sub_bases, win_bases, *subword_table):
+            # ``subword_table``: nothing, or the row table's three arrays
+            # (config.subword; _step_extra), as the pair feed's chunk takes it
             del keep_prob, sub_bases  # host packer already subsampled
             alphas, nvalid = meta[0], meta[1:].T          # [K], [K, Sd]
             K = alphas.shape[0]
@@ -1414,15 +1463,25 @@ class Trainer:
                 with jax.named_scope("pairgen"):
                     band = win(tok, xs["starts"], nv.astype(jnp.int32),
                                ob[:, 0], ob[:, 1], win_bases)
-                new_p, metrics = inner(
-                    p, {"tokens": tok.reshape(-1), "band": band}, negs, alpha)
+                batch = {"tokens": tok.reshape(-1), "band": band}
+                if subword_table:
+                    from glint_word2vec_tpu.ops.subword import SubwordTable
+                    batch["subword_table"] = SubwordTable(*subword_table)
+                new_p, metrics = inner(p, batch, negs, alpha)
                 new_p = jax.lax.with_sharding_constraint(
-                    new_p, EmbeddingPair(emb_sharding, emb_sharding))
+                    new_p, self._params_sharding)
                 return new_p, (metrics, jnp.int32(0))
 
             return jax.lax.scan(body, params, (arrays, alphas, nvalid, negatives))
 
-        return jax.jit(banded_chunk, donate_argnums=(0,))
+        if self.params.pos is None:
+            return jax.jit(banded_chunk, donate_argnums=(0,))
+        # the position weights come back placed as they went in: left to
+        # itself jit hands a donated carry's leaf the placement of the first
+        # argument of its rank (syn0's, by rows), and the next dispatch, whose
+        # argument is then placed otherwise, compiles the chunk a second time
+        return jax.jit(banded_chunk, donate_argnums=(0,),
+                       out_shardings=(self._params_sharding, None))
 
     def _stage_dispatch_meta(self, meta: np.ndarray, base_step, *bases):
         """Explicitly stage the small per-dispatch host arrays (the meta rows,
@@ -2104,7 +2163,8 @@ class Trainer:
                             self._dispatch_step_fn(real)(
                                 self.params, stacked, meta_dev, base_dev,
                                 self._table_prob, self._table_alias,
-                                self._keep_prob_dev, sub_dev, win_dev)
+                                self._keep_prob_dev, sub_dev, win_dev,
+                                *self._step_extra)
                 self.dispatch_time += time.perf_counter() - t0
                 self._after_dispatch()
                 pairs_arrays.append(metrics.pairs)
@@ -3244,9 +3304,9 @@ class Trainer:
 
         if faults.take_nan_injection(self.global_step):
             if self._poison_fn is None:
-                self._poison_fn = jax.jit(lambda p: EmbeddingPair(
-                    p.syn0.at[0, 0].set(jnp.asarray(jnp.nan, p.syn0.dtype)),
-                    p.syn1))
+                self._poison_fn = jax.jit(lambda p: p._replace(
+                    syn0=p.syn0.at[0, 0].set(
+                        jnp.asarray(jnp.nan, p.syn0.dtype))))
             self.params = self._poison_fn(self.params)
         scale = faults.take_scale_injection(self.global_step)
         if scale:
@@ -3309,11 +3369,11 @@ class Trainer:
             # audit's scripted fits are too short to hit; tests/test_obs.py
             # runs a probing fit under the guard to keep this path honest)
             with self._tracer.span("device_block") as blocked:
-                loss_k, fpos_k, pairs_k, rows0_k, rows1_k, rows_sw_k = (
+                loss_k, fpos_k, pairs_k, rows0_k, rows1_k, rows_sw_k, pos = (
                     jax.device_get(
                         (metrics.loss, metrics.mean_f_pos, metrics.pairs,
                          metrics.syn0_rows, metrics.syn1_rows,
-                         metrics.subword_rows)))
+                         metrics.subword_rows, self.params.pos)))
                 if rows0_k is not None and pairs_k[real - 1] > 0:
                     # how far the step coalesced each table's update: 1.0
                     # plain, heads over pairs where runs were summed first
@@ -3323,11 +3383,20 @@ class Trainer:
                             rows0_k[real - 1] / pairs_k[real - 1]),
                         syn1_rows_per_pair=float(
                             rows1_k[real - 1] / pairs_k[real - 1]))
-                    if rows_sw_k is not None:
-                        # rows of the centers' subword lists that reached
-                        # syn0's scatter live (config.subword)
-                        blocked.set(subword_rows_per_pair=float(
-                            rows_sw_k[real - 1] / pairs_k[real - 1]))
+                if rows_sw_k is not None and pairs_k[real - 1] > 0:
+                    # rows of the subword lists (the centers', or a CBOW
+                    # block's tokens') that reached syn0's scatter live
+                    # (config.subword), over the step's pairs or examples
+                    blocked.set(subword_rows_per_pair=float(
+                        rows_sw_k[real - 1] / pairs_k[real - 1]))
+                if pos is not None:
+                    # how far the position weights have moved from the ones
+                    # they start at, |pos − 1| / |1|: 0 = the leaf is not
+                    # training, non-finite = it diverged
+                    away = np.asarray(
+                        pos, np.float64)[:, :self.config.vector_size] - 1.0
+                    blocked.set(position_drift=float(
+                        np.sqrt((away * away).sum() / away.size)))
             # per-phase attribution over THIS heartbeat window (obs/
             # phases.py): delta of the accumulator the spans + wait sites
             # have been feeding since the previous heartbeat
@@ -3782,6 +3851,14 @@ class Trainer:
         return self.params.syn0[V:V + self.config.subword_buckets,
                                 :self.config.vector_size]
 
+    def position_weights(self) -> Optional[jax.Array]:
+        """The position weights [2·window, D] (config.cbow_position_weights),
+        None on every other model. Part of the trained state, saved and
+        restored, and no part of a served vector."""
+        if self.params.pos is None:
+            return None
+        return self.params.pos[:, :self.config.vector_size]
+
     def save_checkpoint(self, path: str,
                         _channels: Optional[dict] = None) -> None:
         if self.config.nonfinite_policy != "none":
@@ -3807,13 +3884,14 @@ class Trainer:
                 extra_metadata=extra)
         else:
             p = self.unpadded_params()
-            buckets = self.subword_buckets()
+            buckets, pos = self.subword_buckets(), self.position_weights()
             save_model(
                 path, self.vocab.words, self.vocab.counts,
                 np.asarray(p.syn0), np.asarray(p.syn1),
                 self.config, self.state, extra_metadata=extra,
                 subword_buckets=(None if buckets is None
-                                 else np.asarray(buckets)))
+                                 else np.asarray(buckets)),
+                position_weights=None if pos is None else np.asarray(pos))
         logger.info("checkpoint saved to %s at step %d", path, self.global_step)
         # the preempt record's progress-lost denominator (docs/robustness.md)
         self._last_save_step = int(self.global_step)

@@ -375,7 +375,7 @@ def test_shared_pool_duplicate_scaling_mean_semantics():
         centers = jnp.full((B,), 2, jnp.int32)
         contexts = jnp.full((B,), 5, jnp.int32)
         mask = jnp.ones((B,), jnp.float32)
-        (s0, s1), _ = sgns_step_shared_core(
+        (s0, s1, _), _ = sgns_step_shared_core(
             EmbeddingPair(syn0, syn1), centers, contexts, mask, pool, alpha,
             num_negatives=2, duplicate_scaling=scaled)
         return np.asarray(s0), np.asarray(s1)

@@ -19,6 +19,14 @@ a scatter-add of V indices into s32[128], 26.2 ms a table on the chip and 1.64
 ms of every training step (PERF.md §6, PR 32: the ``fusion_s32_128`` pair of the
 ledger's breakdowns). The compiled probe holds no scatter, and on the 1x4 mesh
 of ``sgns-10m-300-x4`` its reductions stay all-reduces of scalars.
+
+The fifth (PR 33) is the banded CBOW step with the token row source and the
+position weights at ``cbow-subword-2m-300``'s size: syn0 (f32[4000000,384]) is
+read by the tokens' list gather and written by their list scatter, once a step;
+syn1 by the centers' scatter and the pool's; the third leaf rides the carry.
+That the plain banded step and the subword skip-gram step are the programs
+they were is held where it is cheap, on their lowered text
+(``tests/test_cbow_subword.py``).
 """
 
 import os
@@ -108,6 +116,48 @@ def test_no_table_is_copied_with_the_subword_row_source(one_chip, with_metrics):
     copies = [line.strip()[:120] for line in compiled.splitlines()
               if re.search(rf"= f32\[({rows0}|{words}),{D}\]\S* copy\(", line)]
     assert not copies, copies
+
+
+@pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
+def test_no_table_is_copied_with_token_lists_and_position_weights(one_chip, with_metrics):
+    from glint_word2vec_tpu.ops.cbow_banded import cbow_step_banded_core
+    from glint_word2vec_tpu.ops.subword import SubwordShape, SubwordTable
+
+    words, rows0, groups, tokens, window = 2_000_000, 4_000_000, 3 << 20, 65546, 5
+    # what the trainer derives at this size: every token slot its own list
+    shape = SubwordShape(max_groups=2, max_run=1, head_cap=tokens)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def chunk(params, table, toks, left, right, center, negatives, alphas):
+        def body(p, xs):
+            tk, l, r, c, n, a = xs
+            return cbow_step_banded_core(
+                p, tk, l, r, c, jnp.ones(tokens, jnp.float32), n, a, 10, window,
+                "exact", jnp.bfloat16, jnp.bfloat16, with_metrics,
+                subword=(table, shape))
+        return jax.lax.scan(body, params, (toks, left, right, center, negatives, alphas))
+
+    block = spec((K, tokens), jnp.int32)
+    compiled = jax.jit(chunk, donate_argnums=(0,)).lower(
+        EmbeddingPair(spec((rows0, D), jnp.float32), spec((words, D), jnp.float32),
+                      spec((2 * window, D), jnp.float32)),
+        SubwordTable(spec((words + 2,), jnp.int32), spec((groups, 8), jnp.int32),
+                     spec((words + 1,), jnp.int32)),
+        block, block, block, spec((K, tokens), jnp.float32), spec((K, 4096), jnp.int32),
+        spec((K,), jnp.float32)).compile().as_text()
+    assert " conditional(" not in compiled         # the capacity is known while tracing
+    copies = [line.strip()[:120] for line in compiled.splitlines()
+              if re.search(rf"= f32\[({rows0}|{words}),{D}\]\S* copy\(", line)]
+    assert not copies, copies
+
+    def scatters(rows):
+        return len(re.findall(rf"= f32\[{rows},{D}\]\S* scatter\(", compiled))
+
+    # the lists reach syn0's scatter once a step; syn1 takes the centers' rows
+    # and the pool's
+    assert (scatters(rows0), scatters(words)) == (1, 2)
 
 
 @pytest.mark.parametrize("mesh_shape", [None, (1, 4)], ids=["one_chip", "mesh_1x4"])

@@ -224,7 +224,7 @@ def test_masked_pairs_and_the_lane_padding_stay_zero():
             jnp.asarray(mask), negatives, jnp.float32(0.05), NEG, "exact",
             jnp.bfloat16, logits_dtype=jnp.bfloat16, subword=(dev, shape))
 
-    (got0, got1), metrics = run(c, x, mask)
+    (got0, got1, _), metrics = run(c, x, mask)
     assert float(metrics.pairs) == real
     assert not np.asarray(got0[:, D:]).any() and not np.asarray(got1[:, D:]).any()
     # rows that only the masked tail's word lists would touch did not move
@@ -377,7 +377,7 @@ def test_heartbeat_reports_the_rows_and_the_span_the_table(tmp_path):
 
 
 @pytest.mark.parametrize("beside, says", [
-    (dict(cbow=True), "CBOW"),
+    (dict(cbow=True), "needs cbow_update='banded'"),    # the scatter forms have no lists
     (dict(negative_pool=0), "shared-pool"),
     (dict(pairs_per_batch=128), "shared-pool"),             # AUTO resolves the pool to 0
     (dict(step_lowering="shard_map"), "shard_map"),

@@ -59,12 +59,23 @@ REFUSAL_GROUPS: Dict[str, Dict[str, tuple]] = {
         "subword": (False, True),
         "subword_buckets": (64,),
         "cbow": (False, True),
+        "cbow_update": ("scatter", "banded"),
         "negative_pool": (-1, 0, 64),
         "pairs_per_batch": (64, 4096),
         "step_lowering": ("gspmd", "shard_map"),
         "device_pairgen": (False, True),
         "duplicate_scaling": (False, True),
         "max_row_norm": (0.0, 50.0),
+    },
+    "position-weights": {
+        "cbow_position_weights": (False, True),
+        "cbow": (False, True),
+        "cbow_update": ("scatter", "banded"),
+        "subword": (False, True),
+        "subword_buckets": (64,),
+        "sharded_checkpoint": (False, True),
+        "negative_pool": (-1, 64),
+        "pairs_per_batch": (64, 4096),
     },
     "auto-markers": {
         "subsample_ratio": (-1.0, 0.0, 1e-3),

@@ -85,6 +85,9 @@ KNOBS = {k.name: k for k in [
     _K("sharded_checkpoint", (False, True)),
     _K("cbow", (False, True)),
     _K("cbow_update", ("scatter", "banded"), invalid="fused"),
+    # position weights (ISSUE 33): a third trainable leaf of the banded CBOW
+    # step; refused beside everything but cbow_update="banded"
+    _K("cbow_position_weights", (False, True)),
     # --- subword skip-gram (ISSUE 31): selects the center's row source of
     # the shared-pool step and carries the subword selection matrix of
     # config.__post_init__; the three sizes only matter beside subword=True

@@ -283,7 +283,7 @@ def main() -> None:
 
     def core_merged_syn1(params, centers, contexts, mask, negatives, alpha):
         """contexts+pool in one scatter; syn0/syn1 stay separate (2 scatters)."""
-        syn0, syn1 = params
+        syn0, syn1 = params.syn0, params.syn1
         cdt = jnp.float32
         e_in = syn0[centers].astype(cdt)
         e_pos = syn1[contexts].astype(cdt)
@@ -364,7 +364,7 @@ def main() -> None:
                                 for g in gbatches]))
 
     def core_grouped(params, centers, ctx, cmask, negatives, alpha):
-        syn0, syn1 = params
+        syn0, syn1 = params.syn0, params.syn1
         cdt = jnp.float32
         e_in = syn0[centers].astype(cdt)                 # [Bc, D]
         e_pos = syn1[ctx].astype(cdt)                    # [Bc, W, D]
@@ -421,7 +421,7 @@ def main() -> None:
         })
 
     def core_sorted(params, centers, contexts, mask, negatives, alpha):
-        syn0, syn1 = params
+        syn0, syn1 = params.syn0, params.syn1
         cdt = jnp.float32
         e_in = syn0[centers].astype(cdt)
         e_pos = syn1[contexts].astype(cdt)

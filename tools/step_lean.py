@@ -129,7 +129,7 @@ def main() -> None:
                         s, b["centers"], b["contexts"], b["mask"], ng,
                         jnp.float32(ALPHA), NEG, "exact", dt, False, dt)
                     return new_p, m.loss
-                syn0, syn1 = s
+                syn0, syn1 = s.syn0, s.syn1
                 d_in, d_pos, d_Z, f_pos, f_neg = updates(
                     syn0, syn1, b["centers"], b["contexts"], b["mask"], ng,
                     fused=(kind == "fused"))
