@@ -618,8 +618,10 @@ def sgns_step_shared_core(
     ``subword`` ``(SubwordTable, SubwordShape)`` (config.subword;
     :mod:`.subword`): the center's row source. syn0 then has the vocabulary's
     rows and the bucket rows; a center's ``e_in`` is the mean of the rows its
-    word's list names, made once per center run, and ``d_in``, summed per run
-    and divided by the list's length, is spread back over them in one scatter.
+    word's list names, made once per distinct center word of the batch (once
+    per center run where the batch has too many), and ``d_in``, summed per run
+    and per word and divided by the list's length, is spread back over them in
+    one scatter.
     Everything after ``e_in`` and before syn0's scatter is the same code;
     ``center_runs`` is not read (the shape carries the run length and cap).
 

@@ -8,34 +8,56 @@ and its update is spread back over them:
     h_w = (1/|G(w)|) Σ_{r ∈ G(w)} syn0[r]        syn0[r] += d_h / |G(w)|
 
 :func:`sgns_step_shared_core` stays one body; this module makes its ``e_in``
-and applies its ``d_in``. The work is done once per center RUN, not per pair
-(the pair feed emits a center's pairs consecutively: ~0.26 runs a pair at
-window 5, so ~5 listed rows a pair where a pair's own list holds ~19): the
-run heads are compacted to a static capacity as
-:func:`..ops.sgns.scatter_add_by_runs` compacts them, every head's list is
-read as one block padded to the longest list ([heads, max_groups · 8] row
-ids; lists are stored in groups of :data:`GROUP` rows, padding slots out of
-bounds), gathered, summed and divided; ``d_in`` is summed per run
-(:func:`run_sums`), divided, broadcast over the block and scattered once.
+and applies its ``d_in``. The work is done once per DISTINCT CENTER WORD of
+the batch, not per pair and not per center run. The pair feed emits a
+center's pairs consecutively (~0.26 runs a pair at window 5), and a batch's
+center tokens repeat their words (58% of them distinct at the published
+shape), so a pair's own ~19 listed rows are ~5 a pair per run and ~3.7 per
+word. Two levels of heads:
+
+- the run heads are compacted to a static capacity as
+  :func:`..ops.sgns.scatter_add_by_runs` compacts them (``head_cap``);
+- the compacted run heads are sorted by word inside the step (a stable 1-D
+  sort that carries their rank), a word's heads are cut every ``word_run``
+  into pieces, and the pieces' heads are compacted to ``word_cap``. A word
+  with more heads than ``word_run`` has several pieces that list the same
+  rows again; the scatter-add sums them.
+
+Every word head's list is read as one block padded to the longest list
+([word_cap, max_groups · 8] row ids; lists are stored in groups of
+:data:`GROUP` rows, padding slots out of bounds), gathered, summed and
+divided, and handed to every pair through one composed index (pair → run
+head → word head): the values are the per-run form's bit for bit. ``d_in`` is
+summed per run (:func:`run_sums`), the run sums are read in word order,
+summed again per piece and divided; the block's slots are sorted by row
+inside the step and scattered once, each with its head's update row (XLA
+sorts a scatter's indices itself only above an eighth of the table's rows:
+:func:`scatter_center_updates`).
 
 The block padded per head, and not a batch's lists laid end to end under a
-second capacity: the chip read 78.1 ms a step for this form and 66.5 for
-the flat one (58.3 with no room in its capacity), where ISSUE 31 asked the
-flat form to come in under half before its index work (a search over group
-ends, a second run sum, a second overflow case) was worth having (PERF.md
-§6, PR 31: XLA sorts this form's scatter indices, so its ~650,000 padding
-rows cost it little).
+second capacity: the chip read 78.1 ms a step for the padded form (per run)
+and 66.5 for the flat one (58.3 with no room in its capacity), where ISSUE
+31 asked the flat form to come in under half before its index work (a
+search over group ends, a second run sum, a second overflow case) was worth
+having (PERF.md §6, PR 31: XLA sorts this form's scatter indices, so its
+padding rows cost it little). Fewer word heads make the flat form's capacity
+smaller too: it is next (ROADMAP B10 (c)).
 
-A batch with more center runs than the capacity (centers that all differ)
-takes the plain form instead: every pair its own list, in chunks of pairs
-under ``lax.map`` / ``lax.scan``, so that no [B, G, D] block is ever made.
-Same sums, same rows.
+Three forms, chosen by the step from its own batch. More word pieces than
+``word_cap`` (a batch whose centers hardly repeat): the per-RUN form, a run
+head's list once a run, at that form's cost plus the sort of the heads. More
+center runs than ``head_cap`` (centers that all differ): the plain form,
+every pair its own list, in chunks of pairs under ``lax.map`` /
+``lax.scan``, so that no [B, G, D] block is ever made. Same sums, same rows.
+``word_cap`` 0 (the trainer's rule found no saving; a CBOW token block)
+builds the last two alone, as before there were three.
 """
 
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Tuple
+from functools import partial
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +84,22 @@ class SubwordShape(NamedTuple):
     max_groups: int      # groups of the longest list
     max_run: int         # center runs are cut every max_run pairs
     head_cap: int        # center runs of a batch the per-run form holds
+    word_run: int = 1    # a word's run heads are cut every word_run into pieces
+    word_cap: int = 0    # word pieces of a batch the per-word form holds; 0: not built
+
+
+# CenterPlan.form: which of the three forms the batch takes
+PLAIN, PER_RUN, PER_WORD = 0, 1, 2
+
+
+class WordPlan(NamedTuple):
+    """The second level of one batch's index work: the run heads by word."""
+
+    src: jax.Array       # int32 [H] batch position of each run head, in word order
+    pos: jax.Array       # int32 [H] position of each run head in its word's piece
+    head: jax.Array      # int32 [W] where (in word order) each piece starts
+    pair_head: jax.Array  # int32 [B] which word head (by rank) a pair belongs to
+    pieces: jax.Array    # int32: word pieces of the batch
 
 
 class CenterPlan(NamedTuple):
@@ -73,8 +111,13 @@ class CenterPlan(NamedTuple):
     src: jax.Array       # int32 [H] batch position of each head
     rows: jax.Array      # int32 [H, max_groups · GROUP] syn0 rows, NO_ROW where none
     inv: jax.Array       # float32 [H] 1 / |G(head word)|, 0 past the heads
-    heads: jax.Array     # int32: center runs of the batch
+    heads: jax.Array     # int32: center runs of the batch (its word pieces, PER_WORD)
     live_rows: jax.Array  # float32: rows with a live index the scatter gets
+    # where shape.word_cap is set: the form the batch takes (PLAIN, PER_RUN,
+    # PER_WORD), and the run heads by word; ``rows`` / ``inv`` then hold the
+    # lists of that form's heads (the first word_cap of them per word)
+    form: Optional[jax.Array] = None
+    words: Optional[WordPlan] = None
 
 
 def _acc(syn0: jax.Array):
@@ -98,13 +141,41 @@ def _lists(words: jax.Array, table: SubwordTable, max_groups: int,
     return rows.reshape(words.shape[0], max_groups * GROUP), inv
 
 
+def _plan_words(word: jax.Array, src: jax.Array, pair_head: jax.Array,
+                shape: SubwordShape, v: int):
+    """The run heads' words ([H], ``v`` past the heads) sorted, cut into
+    pieces of ``word_run`` heads and compacted to ``word_cap``: the
+    :class:`WordPlan` and each word head's word ([W], ``v`` past them). 1-D
+    work on 24,576 entries where the batch has 65,536 pairs."""
+    from glint_word2vec_tpu.ops.sgns import run_positions
+
+    h = word.shape[0]
+    at = jnp.arange(h, dtype=jnp.int32)
+    keys, order = jax.lax.sort((word, at), num_keys=1, is_stable=True)
+    pos = run_positions(keys, shape.word_run)
+    head = (pos == 0) & (keys < v)              # the padding sorts last: no piece
+    live = jnp.sort(jnp.where(head, at, h))[:shape.word_cap]
+    at_head = jnp.minimum(live, h - 1)
+    # a run head's word head, by the head's rank in the batch: through the
+    # sort's inverse (a second sort; a scatter of 24,576 indices costs more)
+    of_sorted = jnp.minimum(jnp.cumsum(head.astype(jnp.int32)) - 1,
+                            shape.word_cap - 1)
+    _, back = jax.lax.sort((order, at), num_keys=1)
+    plan = WordPlan(src=src[order], pos=pos, head=at_head,
+                    pair_head=of_sorted[back][pair_head],
+                    pieces=head.sum(dtype=jnp.int32))
+    return plan, jnp.where(live < h, keys[at_head], v)
+
+
 def plan_centers(centers: jax.Array, table: SubwordTable,
                  shape: SubwordShape,
                  dtype: jnp.dtype = jnp.float32) -> CenterPlan:
     """Heads of the batch's center runs, compacted (as
-    :func:`..ops.sgns.scatter_add_by_runs` compacts them), and each head's
-    list: 1-D index work and one small gather of row ids. ``dtype``: the
-    lists' sums run in it (float32, or the tables' dtype if wider).
+    :func:`..ops.sgns.scatter_add_by_runs` compacts them), by word where
+    ``shape.word_cap`` is set (:func:`_plan_words`), and the lists of the
+    heads of the form the batch takes: 1-D index work and one small gather of
+    row ids. ``dtype``: the lists' sums run in it (float32, or the tables'
+    dtype if wider).
 
     ``shape.max_run == 1`` with room for every entry is the row source of a
     CBOW token block (ops/cbow_banded.py): a block has no runs (the same word
@@ -121,6 +192,9 @@ def plan_centers(centers: jax.Array, table: SubwordTable,
     live = jnp.sort(jnp.where(head, at, n))[:hcap]
     src = jnp.minimum(live, n - 1)
     word = jnp.where(live < n, centers[src], v)             # v: no word
+    if shape.word_cap:
+        return _plan_by_word(centers, table, shape, dtype, pos, head, heads,
+                             src, word)
     rows, inv = _lists(word, table, shape.max_groups, dtype)
     # every entry a head of its own and room for all of them: known while
     # tracing, so no second branch is built
@@ -134,13 +208,46 @@ def plan_centers(centers: jax.Array, table: SubwordTable,
                             table.counts[centers].sum()).astype(jnp.float32))
 
 
-def _either(plan: CenterPlan, shape: SubwordShape, n: int, per_run, plain,
-            syn0: jax.Array) -> jax.Array:
-    """``per_run`` where the plan fits its capacity, else ``plain``; one
-    branch alone where that is known while tracing."""
+def _plan_by_word(centers, table, shape, dtype, pos, head, heads, src, word):
+    """:func:`plan_centers` where ``shape.word_cap`` is set: the run heads by
+    word, the form the batch takes, and the lists of that form's heads."""
+    v, hcap = table.counts.shape[0] - 1, shape.head_cap
+    pair_head = jnp.minimum(jnp.cumsum(head.astype(jnp.int32)) - 1, hcap - 1)
+    fits = heads <= hcap
+    words, head_word = _plan_words(word, src, pair_head, shape, v)
+    form = (fits.astype(jnp.int32)
+            + (fits & (words.pieces <= shape.word_cap)).astype(jnp.int32))
+
+    # the lists of the heads of the form that runs, and no others: reading
+    # [24576, 5] groups of row ids is 2.5 ms on the chip (PERF.md §5)
+    def lists_of(heads_words, pad):
+        rows, inv = _lists(heads_words, table, shape.max_groups, dtype)
+        return (jnp.pad(rows, ((0, pad), (0, 0)), constant_values=NO_ROW),
+                jnp.pad(inv, (0, pad)), (rows != NO_ROW).sum(dtype=jnp.int32))
+
+    def no_lists():
+        return (jnp.full((hcap, shape.max_groups * GROUP), NO_ROW, jnp.int32),
+                jnp.zeros((hcap,), dtype), table.counts[centers].sum(dtype=jnp.int32))
+
+    rows, inv, live = jax.lax.switch(
+        form, (no_lists, partial(lists_of, word, 0),
+               partial(lists_of, head_word, hcap - shape.word_cap)))
+    return CenterPlan(
+        fits=fits, pos=pos, pair_head=pair_head, src=src, rows=rows, inv=inv,
+        heads=jnp.where(form == PER_WORD, words.pieces, heads),
+        live_rows=live.astype(jnp.float32), form=form, words=words)
+
+
+def _either(plan: CenterPlan, shape: SubwordShape, n: int, per_word, per_run,
+            plain, syn0: jax.Array) -> jax.Array:
+    """``per_word`` where both levels of the plan fit their capacities,
+    ``per_run`` where the runs alone do, else ``plain``; only the branches
+    that can run, where that is known while tracing."""
     if shape.max_run == 1 and shape.head_cap >= n:
         return per_run(syn0)
-    return jax.lax.cond(plan.fits, per_run, plain, syn0)
+    if plan.words is None:
+        return jax.lax.cond(plan.fits, per_run, plain, syn0)
+    return jax.lax.switch(plan.form, (plain, per_run, per_word), syn0)
 
 
 def center_vectors(syn0: jax.Array, centers: jax.Array, table: SubwordTable,
@@ -158,6 +265,10 @@ def center_vectors(syn0: jax.Array, centers: jax.Array, table: SubwordTable,
             return (got.astype(acc).sum(axis=1) * inv[:, None]
                     ).astype(compute_dtype)
 
+    def per_word(syn0):
+        w = shape.word_cap
+        return mean_of(syn0, plan.rows[:w], plan.inv[:w])[plan.words.pair_head]
+
     def per_run(syn0):
         return mean_of(syn0, plan.rows, plan.inv)[plan.pair_head]
 
@@ -168,7 +279,7 @@ def center_vectors(syn0: jax.Array, centers: jax.Array, table: SubwordTable,
                 syn0, *_lists(words, table, shape.max_groups, acc)),
             centers.reshape(-1, c)).reshape(-1, d)
 
-    return _either(plan, shape, centers.shape[0], per_run, plain, syn0)
+    return _either(plan, shape, centers.shape[0], per_word, per_run, plain, syn0)
 
 
 def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
@@ -176,7 +287,8 @@ def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
                            plan: CenterPlan) -> jax.Array:
     """syn0 with every pair's ``d_in`` row, divided by |G(center)|, added to
     each row of the center's list, duplicate rows summed: per run first
-    (:func:`run_sums`), then one scatter of the heads' blocks."""
+    (:func:`run_sums`), per word piece where the plan has them, then one
+    scatter of the heads' blocks."""
     from glint_word2vec_tpu.ops.sgns import run_sums
 
     d, acc = syn0.shape[1], _acc(syn0)
@@ -185,6 +297,30 @@ def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
         return syn0.at[rows].add(
             jnp.broadcast_to(d_h.astype(syn0.dtype)[:, None, :], rows.shape + (d,)),
             mode="drop")
+
+    def spread_sorted(syn0, rows, d_h):
+        """:func:`spread` with the slots handed over sorted by row, each with
+        its head's update row read in that order: what XLA's TPU scatter makes
+        of a scatter of more update rows than an eighth of the table's rows,
+        and does not below that (PERF.md §6, PR 34: unsorted, 491,520 slots
+        cost 95 ns each, dropped or not, 46.8 ms; sorted, the padding sorts
+        last and costs little, 32.1 ms, and no broadcast block is made)."""
+        keys, slot = jax.lax.sort(
+            (rows.reshape(-1), jnp.arange(rows.size, dtype=jnp.int32)), num_keys=1)
+        return syn0.at[keys].add(
+            d_h.astype(syn0.dtype)[slot // rows.shape[1]], mode="drop",
+            indices_are_sorted=True)
+
+    def per_word(syn0):
+        w, by = shape.word_cap, plan.words
+        with jax.named_scope("subword.mean"):
+            sums = run_sums(d_in, plan.pos, shape.max_run, acc)
+            # the run sums in word order (one gather: the sort's order is
+            # composed into the heads' batch positions), summed per piece
+            sums = run_sums(sums[by.src], by.pos, shape.word_run, acc)
+            d_h = sums[by.head] * plan.inv[:w, None]
+        with jax.named_scope("subword.scatter"):
+            return spread_sorted(syn0, plan.rows[:w], d_h)
 
     def per_run(syn0):
         with jax.named_scope("subword.mean"):
@@ -203,7 +339,7 @@ def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
         return jax.lax.scan(
             chunk, syn0, (centers.reshape(-1, c), d_in.reshape(-1, c, d)))[0]
 
-    return _either(plan, shape, centers.shape[0], per_run, plain, syn0)
+    return _either(plan, shape, centers.shape[0], per_word, per_run, plain, syn0)
 
 
 def compose_vectors(syn0: jax.Array, table: SubwordTable, max_groups: int,
@@ -225,5 +361,5 @@ def compose_vectors(syn0: jax.Array, table: SubwordTable, max_groups: int,
 
 
 __all__: Tuple[str, ...] = (
-    "SubwordTable", "SubwordShape", "CenterPlan", "plan_centers",
+    "SubwordTable", "SubwordShape", "CenterPlan", "WordPlan", "plan_centers",
     "center_vectors", "scatter_center_updates", "compose_vectors")

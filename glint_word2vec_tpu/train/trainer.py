@@ -102,34 +102,86 @@ _SUBWORD_GROUPS_UNIT = 1 << 20
 _CONTEXT_MAX_RUN = 6
 
 
+def _expected_heads(counts: np.ndarray, train_words_count: int,
+                    subsample_ratio: float, draws: float, entries: float,
+                    max_run: int) -> Optional[float]:
+    """Heads expected where ``entries`` entries, each carrying the word of one
+    of ``draws`` kept tokens, are sorted by word and cut every ``max_run``:
+    the tokens are drawn from the kept-token distribution p, so
+    Σ 1 − (1 − p_w)^draws distinct words, and a word expected in more entries
+    than a run holds (``entries`` · p_w) adds a piece per run's length of
+    them. None where subsampling keeps no token."""
+    from glint_word2vec_tpu.data.pipeline import keep_probabilities
+    kept = np.asarray(counts, np.float64) * keep_probabilities(
+        counts, train_words_count, subsample_ratio)
+    total = kept.sum()
+    if total <= 0:
+        return None
+    p = kept / total
+    distinct = -np.expm1(draws * np.log1p(-np.minimum(p, 1 - 1e-12))).sum()
+    per_word = entries * p
+    return float(distinct + per_word[per_word > max_run].sum() / max_run)
+
+
 def _context_run_cap(counts: np.ndarray, train_words_count: int,
                      subsample_ratio: float, window: int, batch: int) -> int:
     """Static row cap of the step's coalesced syn1 scatter
     (ops/sgns.scatter_add_by_runs on the batch sorted by context), 0 = do not
     build it. Unlike center runs, context runs are a property of the corpus:
     sorted by context a batch holds one run per distinct context word, cut
-    every :data:`_CONTEXT_MAX_RUN` pairs. Its contexts are its kept tokens,
-    ``batch`` / :func:`_pairs_per_kept_token` of them, each drawn from the
-    kept-token distribution p: Σ 1 − (1 − p_w)^tokens distinct words expected,
-    and a word expected in more than a run's pairs (``batch`` · p_w) adds a
-    piece per run's length of them. At V = 3M / 10M that reads 16,218 /
+    every :data:`_CONTEXT_MAX_RUN` pairs (:func:`_expected_heads`: its
+    contexts are its kept tokens, ``batch`` / :func:`_pairs_per_kept_token`
+    of them, over ``batch`` pairs). At V = 3M / 10M that reads 16,218 /
     16,472 where feed batches hold 16,440-17,050 (sentence ends clip windows,
     so a batch holds ~5% more tokens): 20% of room, in sixteenths of the batch
     (20,480 of 65,536). An estimate over half the batch builds nothing."""
-    from glint_word2vec_tpu.data.pipeline import keep_probabilities
-    kept = np.asarray(counts, np.float64) * keep_probabilities(
-        counts, train_words_count, subsample_ratio)
-    total = kept.sum()
-    if batch < 16 or total <= 0:
+    heads = None if batch < 16 else _expected_heads(
+        counts, train_words_count, subsample_ratio,
+        batch / _pairs_per_kept_token(window), batch, _CONTEXT_MAX_RUN)
+    if heads is None:
         return 0
-    p = kept / total
-    tokens = batch / _pairs_per_kept_token(window)
-    distinct = -np.expm1(tokens * np.log1p(-np.minimum(p, 1 - 1e-12))).sum()
-    pairs = batch * p
-    pieces = pairs[pairs > _CONTEXT_MAX_RUN].sum() / _CONTEXT_MAX_RUN
     sixteenth = batch // 16
-    cap = -(-int(1.2 * (distinct + pieces)) // sixteenth) * sixteenth
+    cap = -(-int(1.2 * heads) // sixteenth) * sixteenth
     return cap if cap <= batch // 2 else 0
+
+
+# run heads in a piece of a word (the subword row source's second level,
+# ops/subword.py): named, with the cap's unit, by the chip (PERF.md §6, PR 34)
+_WORD_MAX_RUN = 8
+
+
+def _word_pieces(counts: np.ndarray, train_words_count: int,
+                 subsample_ratio: float, window: int, batch: int) -> float:
+    """Word pieces a batch's center runs are expected to make, sorted by word
+    and cut every :data:`_WORD_MAX_RUN` heads (:func:`_expected_heads`): a
+    batch holds one center run per kept token that emits a pair, ``batch`` ·
+    runs a pair of them (:func:`_center_run_cap` has the share), and a run
+    has one head."""
+    runs = batch * (_cbow_examples_per_kept_token(window)
+                    / _pairs_per_kept_token(window))
+    heads = _expected_heads(counts, train_words_count, subsample_ratio,
+                            runs, runs, _WORD_MAX_RUN)
+    return float(batch) if heads is None else heads
+
+
+def _word_cap(counts: np.ndarray, train_words_count: int,
+              subsample_ratio: float, window: int, batch: int,
+              run_cap: int) -> int:
+    """Static capacity of the subword row source's per-word form
+    (ops/subword.py: one list per distinct center word of the batch, not one
+    per center run), 0 = do not build it. :func:`_word_pieces` with 20% of
+    room, in 32nds of the batch: at wiki.en's shape (V = 2,519,370, Zipf
+    counts, the AUTO subsample) it reads 10,100 where feed batches hold
+    10,300-10,600 pieces (sentence ends clip windows, so a batch holds ~5%
+    more runs), 12,288 of 65,536. Over 0.8 of ``run_cap`` (a flat
+    distribution: every center another word) the second level saves nothing
+    and is not built."""
+    if not run_cap or batch < 32:
+        return 0
+    unit = batch // 32
+    cap = -(-int(1.2 * _word_pieces(counts, train_words_count, subsample_ratio,
+                                    window, batch)) // unit) * unit
+    return cap if cap <= 0.8 * run_cap else 0
 
 
 class StepChoice(NamedTuple):
@@ -926,7 +978,8 @@ class Trainer:
         """Build the vocabulary's row table (data/subword.py) and put it on
         the device: span ``vocab.subword_table``, its seconds kept in
         ``subword_table_time``; the step's shape (ops/subword.py) takes the
-        center-run capacity the plain step has."""
+        center-run capacity the plain step has and, under it, the word
+        capacity :func:`_word_cap` derives from the counts."""
         from glint_word2vec_tpu.data.subword import NO_ROW, build_subword_table
         from glint_word2vec_tpu.ops import subword as sw
         cfg = self.config
@@ -962,9 +1015,14 @@ class Trainer:
             # pairs); where none are built every pair is its own head
             cap = (_center_run_cap(cfg.window, cfg.pairs_per_batch)
                    if self.plan.num_data == 1 else 0)
+            # and under them one head per distinct word of the batch's
+            # centers, where the vocabulary's counts promise fewer
             self._subword_shape = sw.SubwordShape(
                 rows.max_groups, *((2 * cfg.window, cap) if cap
-                                   else (1, cfg.pairs_per_batch)))
+                                   else (1, cfg.pairs_per_batch)),
+                _WORD_MAX_RUN, _word_cap(
+                    self.vocab.counts, self.vocab.train_words_count,
+                    cfg.subsample_ratio, cfg.window, cfg.pairs_per_batch, cap))
         logger.info("subword table: %d words, %d slots, %s in %.2fs",
                     self.vocab.size, rows.slots, self._subword_shape,
                     self.subword_table_time)

@@ -317,11 +317,19 @@ def test_masked_slots_and_the_lane_padding_stay_zero():
 # tree: the token row source, the taps and the third leaf add no op and no
 # argument where the model uses none of them. A later PR that changes either
 # step on purpose takes new digests from its own tree (the failure prints them).
+# PR 34 did, for the subword skip-gram step: its row source plans one list per
+# distinct center word where the trainer's rule derives a word cap (it does at
+# the tiny sizes), so those two digests are PR 34's tree's. The token block's
+# row source shares `plan_centers` and took none of it: `cbow-subword-2m-300.train`'s
+# two are the parent commit of PR 34's (1b95d5f), the new `SubwordShape` fields
+# at their defaults.
 PARENT_STEP_TEXT = {
     ("cbow-3m-300.train", "train_cbow", "_step_fn"): "7f02f0da70d07c76",
     ("cbow-3m-300.train", "train_cbow", "_step_fn_fast"): "436c26f275ae9be0",
-    ("subword-sgns-2.5m-300.train", "train_subword", "_step_fn"): "62a0601c185fc86b",
-    ("subword-sgns-2.5m-300.train", "train_subword", "_step_fn_fast"): "229e6cb735096922",
+    ("subword-sgns-2.5m-300.train", "train_subword", "_step_fn"): "f53dceb464f6b6b9",
+    ("subword-sgns-2.5m-300.train", "train_subword", "_step_fn_fast"): "188e2230a80d8e87",
+    ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn"): "10a5adbf669d0d5d",
+    ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn_fast"): "978b7f0b686be25b",
 }
 
 
@@ -337,7 +345,14 @@ def test_steps_without_the_new_parts_lower_to_the_parents_text(cell_name, kind_n
     cell = loader.resolve(loader.load_manifest(ROOT), cell_name, ROOT)
     trainer, _, _ = importlib.import_module("kinds." + kind_name).build_trainer(
         cell, 0, tiny=True)
-    assert trainer.params.pos is None
+    shape = trainer._subword_shape
+    if kind_name == "train_cbow_subword":
+        # every token slot its own list, and no second level under them
+        assert (shape.max_run, shape.head_cap, shape.word_cap) == (
+            1, trainer._tokens_per_step, 0)
+    else:
+        assert trainer.params.pos is None
+        assert shape is None or shape.word_cap > 0
     cfg = trainer.config
     k, b = cfg.steps_per_dispatch, cfg.pairs_per_batch
     zeros = np.zeros((2, k), np.float32)
@@ -350,7 +365,7 @@ def test_steps_without_the_new_parts_lower_to_the_parents_text(cell_name, kind_n
         meta, base, sub, win = trainer._stage_dispatch_meta(
             zeros, 0, np.zeros(1, np.uint32), np.zeros(1, np.uint32))
         args = (staged, meta, base, trainer._table_prob, trainer._table_alias,
-                trainer._keep_prob_dev, sub, win)
+                trainer._keep_prob_dev, sub, win, *trainer._step_extra)
     else:
         staged = put_global(trainer._chunk_shardings,
                             {"pairs": np.zeros((k, 2, b), trainer._pair_dtype)})

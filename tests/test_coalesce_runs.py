@@ -35,8 +35,11 @@ from glint_word2vec_tpu.train import trainer as trainer_mod
 from glint_word2vec_tpu.train.trainer import (
     _CONTEXT_MAX_RUN,
     Trainer,
+    _WORD_MAX_RUN,
     _center_run_cap,
     _context_run_cap,
+    _word_cap,
+    _word_pieces,
 )
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -477,6 +480,51 @@ def test_cap_is_derived_from_the_window():
     # a run of two pairs or fewer: not built
     assert _center_run_cap(1, 65536) == 0 and _center_run_cap(2, 65536) == 0
     assert 0 < _center_run_cap(10, 65536) < _center_run_cap(3, 65536) < 65536
+
+
+def test_word_cap_is_derived_from_the_counts():
+    """The subword row source's second level (ops/subword.py): at
+    ``subword-sgns-2.5m-300``'s counts and resolved subsample the estimate is
+    within 10% of what the pair feed's batches hold."""
+    from harness import zipf
+
+    v, b, ratio = 2_519_370, 65536, 6.5e-4
+    counts = zipf.zipf_counts(v).astype(np.int64)
+    total, run_cap = int(counts.sum()), _center_run_cap(WINDOW, b)
+    pieces = _word_pieces(counts, total, ratio, WINDOW, b)
+    cap = _word_cap(counts, total, ratio, WINDOW, b, run_cap)
+    assert cap == 12288 and cap % (b // 32) == 0 and pieces < cap <= 0.8 * run_cap
+
+    vocab = Vocabulary.from_words_and_counts(zipf.words_of(v), counts)
+    tokens = zipf.draw(np.random.default_rng(11), v, 1_200_000)
+    held = []
+    for batch in epoch_batches([tokens[i:i + 40] for i in range(0, tokens.shape[0], 40)],
+                               vocab, pairs_per_batch=b, window=WINDOW,
+                               subsample_ratio=ratio, seed=1, iteration=1):
+        if batch.num_real_pairs < b:
+            continue
+        c = np.asarray(batch.centers)
+        _, heads = np.unique(c[np.diff(c, prepend=-1) != 0], return_counts=True)
+        held.append((heads.shape[0], (-(-heads // _WORD_MAX_RUN)).sum()))
+        if len(held) == 3:
+            break
+    assert len(held) == 3
+    for distinct, in_pieces in held:
+        assert distinct <= in_pieces <= cap
+        assert abs(pieces - in_pieces) < 0.1 * in_pieces, (pieces, held)
+
+    # more pairs a batch, more words; a smaller share of a larger batch
+    caps = [_word_cap(counts, total, ratio, WINDOW, n, _center_run_cap(WINDOW, n))
+            for n in (4096, 16384, 65536)]
+    assert caps == sorted(caps) and all(c % (n // 32) == 0
+                                        for c, n in zip(caps, (4096, 16384, 65536)))
+    # a flat vocabulary: every center another word, nothing to save, not built
+    flat = np.full(1_000_000, 5, np.int64)
+    assert _word_pieces(flat, int(flat.sum()), 0.0, WINDOW, b) > 0.6 * run_cap
+    assert _word_cap(flat, int(flat.sum()), 0.0, WINDOW, b, run_cap) == 0
+    # no center runs (window <= 2, a data axis), or a batch of a few pairs: not built
+    assert _word_cap(counts, total, ratio, WINDOW, b, 0) == 0
+    assert _word_cap(counts, total, ratio, WINDOW, 16, 6) == 0
 
 
 def test_context_cap_is_derived_from_the_counts():

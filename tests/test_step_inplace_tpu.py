@@ -10,8 +10,10 @@ back, 14 ms of a 26.5 ms step on the chip (PERF.md §6, PR 30). A count of
 instructions, not a time.
 
 The third program is the subword step at ``subword-sgns-2.5m-300``'s size (PR
-31): syn0 is read by one conditional (the centers' listed rows, per run or plain)
-and written by another, and neither may copy f32[4519376,384].
+31; PR 34: one list per distinct center word): syn0 is read by one conditional
+(the centers' listed rows: per word, per run or plain) and written by another,
+and neither may copy f32[4519376,384]; each branch scatters into syn0 once; the
+temporaries are no larger than with the per-run form alone.
 
 The fourth is no step at all: the health probe every heartbeat runs between two
 dispatches (obs/probe.py). Its p99 bucket used to come from a histogram built by
@@ -89,9 +91,15 @@ def test_no_table_is_copied(one_chip, with_metrics):
 def test_no_table_is_copied_with_the_subword_row_source(one_chip, with_metrics):
     from glint_word2vec_tpu.ops.subword import SubwordShape, SubwordTable
 
-    words, rows0, groups = 2_519_376, 4_519_376, 10_730_000
-    # what the trainer derives at this size (PERF.md §6, PR 31)
-    shape = SubwordShape(max_groups=5, max_run=10, head_cap=24576)
+    words, rows0, groups = 2_519_376, 4_519_376, 11 << 20
+    # what the trainer derives at this size (PERF.md §6, PR 31 and PR 34;
+    # tests/test_coalesce_runs.py holds the word cap's derivation)
+    shape = SubwordShape(max_groups=5, max_run=10, head_cap=24576,
+                         word_run=8, word_cap=12288)
+    # temp_size_in_bytes of the same compile with word_cap=0, the parent's
+    # form (my compile for the described v5e, PR 34): the per-run branch's
+    # [24576, 40, 384] float32 block is the largest of either program
+    parent_temporaries = {True: 1_574_144_512, False: 1_574_402_048}
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -106,16 +114,25 @@ def test_no_table_is_copied_with_the_subword_row_source(one_chip, with_metrics):
                 subword=(table, shape))
         return jax.lax.scan(body, params, (centers, contexts, negatives, alphas))
 
-    compiled = jax.jit(chunk, donate_argnums=(0,)).lower(
+    program = jax.jit(chunk, donate_argnums=(0,)).lower(
         EmbeddingPair(spec((rows0, D), jnp.float32), spec((words, D), jnp.float32)),
         SubwordTable(spec((words + 2,), jnp.int32), spec((groups, 8), jnp.int32),
                      spec((words + 1,), jnp.int32)),
         spec((K, B), jnp.int32), spec((K, B), jnp.int32), spec((K, P), jnp.int32),
-        spec((K,), jnp.float32)).compile().as_text()
-    assert compiled.count(" conditional(") >= 3      # gather, scatter, syn1's
+        spec((K,), jnp.float32)).compile()
+    compiled = program.as_text()
+    # the row ids, the gather, the scatter, syn1's
+    assert compiled.count(" conditional(") >= 4
     copies = [line.strip()[:120] for line in compiled.splitlines()
               if re.search(rf"= f32\[({rows0}|{words}),{D}\]\S* copy\(", line)]
     assert not copies, copies
+    # one scatter into syn0 in each of the three branches, and nowhere else
+    assert len(re.findall(rf"= f32\[{rows0},{D}\]\S* scatter\(", compiled)) == 3
+    # the per-word form reads its heads' row ids alone: [12288 · 5, 8], inside
+    # a branch, beside the per-run form's [24576 · 5, 8]
+    assert re.search(r"= s32\[61440,8\]\S* fusion\(", compiled)
+    assert (program.memory_analysis().temp_size_in_bytes
+            <= parent_temporaries[with_metrics])
 
 
 @pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])

@@ -2,8 +2,8 @@
 
 The n-gram function against hand-written cases and the vectorised row table
 against the plain reference's loop; the step against ``subword_ref``'s
-``jax.grad`` updates on the pair feed's own batches, through the per-run
-branch and through the plain fallback, both twins; the lowered step of a configuration
+``jax.grad`` updates on the pair feed's own batches, through the per-word
+branch, the per-run branch it overflows to and the plain fallback, both twins; the lowered step of a configuration
 that is not subword against the parent's text; the model's composed vectors and
 a string the vocabulary has never seen; save and load; every refusal.
 """
@@ -143,9 +143,30 @@ def _case():
     return CASE["v"]
 
 
-# head_cap: roomy (a head's list once a center run), or too few heads for the
-# batch (the plain form: every pair its own list)
-BRANCHES = {"per_run": 1024, "over_head_cap": 16}
+# (head_cap, word_run, word_cap). head_cap: roomy (a head's list once a center
+# run), or too few heads for the batch (the plain form: every pair its own
+# list). word_cap: not built, roomy (a word's list once a batch; with pieces of
+# two heads a frequent word has several, each listing the same rows), or a few
+# heads under a roomy head_cap (the per-run form)
+BRANCHES = {"per_run": (1024, 1, 0), "over_head_cap": (16, 1, 0),
+            "per_word": (1024, 8, 768), "per_word_pieces_of_2": (1024, 2, 768),
+            "over_word_cap": (1024, 8, 16), "over_both_caps": (16, 8, 8)}
+
+
+def _run_head_words(centers, max_run=10):
+    """The word of every center run's head, a run cut every ``max_run``
+    pairs, in NumPy."""
+    at = np.arange(centers.shape[0])
+    start = np.maximum.accumulate(
+        np.where(np.diff(centers, prepend=-1) != 0, at, 0))
+    return centers[(at - start) % max_run == 0]
+
+
+def _word_pieces_of(centers, word_run):
+    """The words of a batch's run heads and how many pieces of ``word_run``
+    heads each makes."""
+    words, count = np.unique(_run_head_words(centers), return_counts=True)
+    return words, -(-count // word_run)
 
 
 @pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
@@ -156,11 +177,14 @@ def test_step_follows_the_reference_on_feed_batches(branch, with_metrics):
     syn0 = jnp.asarray(rng.uniform(-0.3, 0.3, (V + BUCKETS, D)), jnp.float32)
     syn1 = jnp.asarray(rng.uniform(-0.3, 0.3, (V, D)), jnp.float32)
     negatives = rng.integers(0, V, (STEPS, P)).astype(np.int32)
-    shape = SubwordShape(table.max_groups, 10, BRANCHES[branch])
+    head_cap, word_run, word_cap = BRANCHES[branch]
+    shape = SubwordShape(table.max_groups, 10, head_cap, word_run, word_cap)
     dev = SubwordTable(jnp.asarray(table.offsets), jnp.asarray(table.rows),
                        jnp.asarray(table.counts))
-    heads = int((np.diff(centers[0], prepend=-1) != 0).sum())
-    assert heads < B // 2                         # the feed emits center runs
+    heads = _run_head_words(centers[0])
+    assert heads.shape[0] < B // 2                # the feed emits center runs
+    words, pieces = _word_pieces_of(centers[0], word_run)
+    assert words.shape[0] < 0.8 * heads.shape[0]  # and its centers repeat their words
 
     @jax.jit
     def step(params, dev, c, x, n):
@@ -191,15 +215,21 @@ def test_step_follows_the_reference_on_feed_batches(branch, with_metrics):
     assert not np.allclose(params.syn0[V:], syn0[V:])      # bucket rows moved
     if with_metrics:
         np.testing.assert_allclose(losses, ref["losses"], rtol=1e-6)
-    # the counter says which branch ran: a head's list once a run, or every pair's
-    if branch == "per_run":
-        assert handed[0] == (heads, table.counts[
-            centers[0][np.diff(centers[0], prepend=-1) != 0]].sum())
+    # the counter says which branch ran: a word's list once a piece, a head's
+    # once a run, or every pair's
+    if branch.startswith("per_word"):
+        assert 16 < pieces.sum() <= word_cap
+        # a word with more heads than a piece holds lists its rows once a piece
+        assert (pieces.max() > 1) == (word_run == 2)
+        assert handed[0] == (pieces.sum(), (table.counts[words] * pieces).sum())
+    elif branch in ("per_run", "over_word_cap"):
+        assert handed[0] == (heads.shape[0], table.counts[heads].sum())
     else:
         assert handed[0] == (B, table.counts[centers[0]].sum())
 
 
-def test_masked_pairs_and_the_lane_padding_stay_zero():
+@pytest.mark.parametrize("branch", ["per_run", "per_word"])
+def test_masked_pairs_and_the_lane_padding_stay_zero(branch):
     """A batch whose tail is masked (centers 0, mask 0) moves nothing for it,
     and zero columns stay exactly zero."""
     strings, table, centers, contexts = _case()
@@ -215,7 +245,8 @@ def test_masked_pairs_and_the_lane_padding_stay_zero():
     mask = (np.arange(B) < real).astype(np.float32)
     dev = SubwordTable(jnp.asarray(table.offsets), jnp.asarray(table.rows),
                        jnp.asarray(table.counts))
-    shape = SubwordShape(table.max_groups, 10, 1024)
+    head_cap, word_run, word_cap = BRANCHES[branch]
+    shape = SubwordShape(table.max_groups, 10, head_cap, word_run, word_cap)
     negatives = jnp.asarray(rng.integers(0, V, P), jnp.int32)
 
     def run(c, x, mask):
@@ -226,6 +257,10 @@ def test_masked_pairs_and_the_lane_padding_stay_zero():
 
     (got0, got1, _), metrics = run(c, x, mask)
     assert float(metrics.pairs) == real
+    # the masked tail is one long run of word 0, cut into heads of its own
+    assert float(metrics.syn0_rows) == (
+        _word_pieces_of(c, word_run)[1].sum() if word_cap
+        else _run_head_words(c).shape[0])
     assert not np.asarray(got0[:, D:]).any() and not np.asarray(got1[:, D:]).any()
     # rows that only the masked tail's word lists would touch did not move
     touched = np.unique(np.concatenate([table.rows_of(w) for w in np.unique(c[:real])]))
