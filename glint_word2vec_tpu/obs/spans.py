@@ -39,6 +39,11 @@ Design constraints:
   span is the enclosing span on the same thread (a ``threading.local``
   stack, never shared), or an explicit ``parent=`` for work caused on
   another thread;
+- a region is a ``with`` block; the one that begins in one method and ends
+  in another (the fit loop's heartbeat round, ``Trainer._finish_round`` to
+  ``Trainer._after_dispatch``) is :meth:`Tracer.open` and ``close()``, the
+  same span on the same stack and in the same trace, never a retroactive
+  :meth:`Tracer.record`, which reaches the ring only;
 - no ad-hoc threads (graftlint R1): this module only OBSERVES threads.
 
 The Chrome-trace export (``chrome://tracing`` / Perfetto) renders nesting
@@ -83,10 +88,10 @@ _NOOP = _NoopSpan()
 # whose name maps here also lands in the accumulator attached for the run,
 # so the phase histograms need no second clock read at the span sites
 _PHASE_OF = {
-    "producer_wait": "producer_wait",
     "stage_put": "stage",
     "allgather_fetch": "stage",
     "dispatch": "dispatch",
+    "heartbeat.drain": "device_block",
     "health_probe": "device_block",
     "device_block": "device_block",
 }
@@ -127,18 +132,25 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        self.dur = now() - self.t0
+        self.close()
+        return None
+
+    def close(self, end: Optional[float] = None, keep: bool = True) -> None:
+        """Leave the region; by hand for one that :meth:`Tracer.open`
+        entered. ``end`` (on :data:`now`) puts its end before the call and
+        ``keep=False`` leaves it out of the ring: a region known only
+        afterwards to have been shorter, or not to have been one."""
+        self.dur = (now() if end is None else end) - self.t0
         if self._keep:
             tracer = self._tracer
             if self._ann is not None:
                 self._ann.__exit__(None, None, None)
             tracer._stack().pop()
             # a span the trace's stop cut short has no counterpart there
-            if tracer.enabled or TraceAnnotation.is_enabled():
+            if keep and (tracer.enabled or TraceAnnotation.is_enabled()):
                 tracer._record(self.name, self.t0, self.dur, self.args,
                                self.id, self.parent)
                 self.recorded = True
-        return None
 
 
 class Tracer:
@@ -155,7 +167,6 @@ class Tracer:
         # deque(maxlen): appending past capacity drops the OLDEST in O(1) —
         # the tail of a long run is what a hang/slowdown investigation needs
         self._events: "deque" = deque(maxlen=self.max_events)
-        self._dropped = 0
         self._epoch = now()
         self._phases = None  # PhaseAccumulator of the running trainer, or None
         self._local = threading.local()  # per-thread stack of open span ids
@@ -173,7 +184,6 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._events.clear()
-            self._dropped = 0
             self._epoch = now()
 
     def _stack(self) -> list:
@@ -197,6 +207,16 @@ class Tracer:
         if timed:
             return _Span(self, name, None, None, False, False)
         return _NOOP
+
+    def open(self, name: str, **args) -> Optional[_Span]:
+        """A span already entered, for a region that begins in one method
+        and ends in another, where no ``with`` block can hold it: the owner
+        ends it with :meth:`_Span.close`, innermost first. ``None`` when
+        nothing records."""
+        span = self.span(name, **args)
+        if span is _NOOP:
+            return None
+        return span.__enter__()
 
     def record(self, name: str, t0: float, dur: float, *,
                parent: Optional[int] = None, **args) -> None:
@@ -236,8 +256,6 @@ class Tracer:
         ev = (name, threading.get_ident(), threading.current_thread().name,
               t0 - self._epoch, dur, args, span_id, parent)
         with self._lock:
-            if len(self._events) == self.max_events:
-                self._dropped += 1
             self._events.append(ev)
 
     # -- introspection / export -------------------------------------------------
@@ -267,7 +285,6 @@ class Tracer:
         order, with metadata events naming each thread."""
         with self._lock:
             evs = list(self._events)
-            dropped = self._dropped
         tid_map: Dict[int, int] = {}
         names: Dict[int, str] = {}
         trace = []
@@ -279,8 +296,7 @@ class Tracer:
                           "args": {"id": sid, "parent": parent, **(a or {})}})
         meta = [{"ph": "M", "name": "thread_name", "pid": 0, "tid": small,
                  "args": {"name": tname}} for small, tname in names.items()]
-        doc = {"traceEvents": meta + trace, "displayTimeUnit": "ms",
-               "otherData": {"dropped_events": dropped}}
+        doc = {"traceEvents": meta + trace, "displayTimeUnit": "ms"}
         with open(path, "w", encoding="utf-8") as f:
             json.dump(doc, f)
         return len(trace)

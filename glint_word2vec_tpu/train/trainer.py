@@ -856,6 +856,10 @@ class Trainer:
         from glint_word2vec_tpu.obs.spans import default_tracer
         from glint_word2vec_tpu.obs.watch import NormWatchdog
         self._tracer = default_tracer()
+        # the open spans of a heartbeat round, outermost first (heartbeat,
+        # then heartbeat.refill): begun in _finish_round, ended in
+        # _after_dispatch; empty whenever nothing records
+        self._round: List = []
         self._telemetry = None
         if config.telemetry_path:
             from glint_word2vec_tpu.obs.sink import TelemetrySink
@@ -1585,7 +1589,13 @@ class Trainer:
         host/device pipelining this trainer is built around is unchanged on
         real accelerators; on the CPU mesh the dispatch_time split becomes
         device-inclusive, which that backend never reported honestly
-        anyway."""
+        anyway.
+
+        First, where the round before this dispatch was a heartbeat round
+        that something recorded, its ``heartbeat.refill`` and ``heartbeat``
+        spans end here: the device has work again."""
+        if self._round:
+            self._close_round(dispatched=True)
         if self._sync_collectives:
             with self._tracer.span("device_block"):
                 jax.block_until_ready(self.params)
@@ -2849,7 +2859,30 @@ class Trainer:
                     "max_row_norm", "update_clip", "row_l2",
                     "recover_lr_backoff", "max_recoveries")})
 
+    def _open_round_span(self, name: str, **args) -> None:
+        span = self._tracer.open(name, **args)
+        if span is not None:
+            self._round.append(span)
+
+    def _close_round(self, dispatched: bool) -> None:
+        """End the heartbeat round's open spans, innermost first. Where no
+        dispatch followed (the fit's last heartbeat, or one that raised) the
+        refill was none and is not kept, and the ``heartbeat`` ends where
+        the refill would have begun."""
+        end = None
+        while self._round:
+            span = self._round.pop()
+            if not dispatched and span.name == "heartbeat.refill":
+                span.close(keep=False)
+                end = span.t0
+            else:
+                span.close(end=end)
+
     def _stop_profiler(self) -> None:
+        # every fit loop leaves through here (its ``finally``), before the
+        # fit's last save: a heartbeat round no dispatch followed ends too
+        if self._round:
+            self._close_round(dispatched=False)
         if getattr(self, "_profiling", False):
             import jax.profiler
             jax.profiler.stop_trace()
@@ -2886,7 +2919,8 @@ class Trainer:
             self._health_fn = make_health_probe(
                 self.vocab.size, self.config.norm_watch_threshold)
         from glint_word2vec_tpu.obs.probe import stats_to_channels
-        jax.block_until_ready(self.params)
+        with self._tracer.span("heartbeat.drain"):
+            jax.block_until_ready(self.params)
         with self._tracer.span("health_probe"):
             channels = stats_to_channels(
                 jax.device_get(self._health_fn(self.params)))
@@ -3388,6 +3422,15 @@ class Trainer:
                         and self.global_step % checkpoint_every_steps < real)
         hb_due = (self.global_step - self._last_log_step
                   >= cfg.heartbeat_every_steps)
+        if hb_due:
+            # the heartbeat round as one span tree on this thread
+            # (docs/observability.md §4): heartbeat.drain, health_probe,
+            # device_block, heartbeat.callback, then heartbeat.refill, which
+            # _after_dispatch ends with the round once the next chunk is
+            # enqueued
+            self._open_round_span(
+                "heartbeat", step=self.global_step,
+                steps=self.global_step - self._last_log_step)
         # ONE fused probe per probing round (obs/probe.py): finiteness for the
         # guardrail + the norm channels for the watchdog and the heartbeat
         channels: Optional[dict] = None
@@ -3526,6 +3569,11 @@ class Trainer:
         # normal atomic path. Never returns.
         if getattr(self, "_preempt_deadline", None) is not None:
             self._preempt_exit(checkpoint_path, channels)
+
+        if hb_due:
+            # the drain left the device with nothing queued: it waits from
+            # here until the next chunk reaches it, inside this span
+            self._open_round_span("heartbeat.refill")
 
     def _preempt_exit(self, checkpoint_path: Optional[str],
                       channels: Optional[dict]) -> None:
