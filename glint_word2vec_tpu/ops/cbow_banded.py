@@ -243,7 +243,10 @@ def cbow_step_banded_core(
     word's list (``shape`` is ``(max_groups, 1, T)``): reading a list once per
     distinct word of the block, the tokens sorted by word inside the step,
     came in at 0.86 of this form's step on the chip where the bar was 0.7
-    (PERF.md §6, PR 33). With neither, the program is the one it was.
+    (PERF.md §6, PR 33). Where ``shape.slot_cap`` is set the scatter takes the
+    lists' slots sorted by row and cut to that many (a block with more live
+    slots takes them all; :mod:`.subword`), and ``StepMetrics.subword_slots``
+    says how many it was handed. With neither, the program is the one it was.
     """
     syn0, syn1, pos_w = params
     T = tokens.shape[0]
@@ -377,5 +380,7 @@ def cbow_step_banded_core(
         mean_f_pos=mean_f_pos,
         pairs=live.sum(),
         subword_rows=None if subword is None else sw_plan.live_rows,
+        subword_slots=(None if subword is None
+                       else sw.scatter_slots(sw_plan, sw_shape)),
     )
     return EmbeddingPair(new_syn0, new_syn1, new_pos), metrics

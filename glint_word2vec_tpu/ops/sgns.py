@@ -261,6 +261,10 @@ class StepMetrics(NamedTuple):
     # rows of the centers' subword lists that reached syn0's scatter with a
     # live index (config.subword; ops/subword.py); None = not a subword step
     subword_rows: Optional[jax.Array] = None
+    # slots a CBOW token block's list scatter was handed (ops/subword.py
+    # scatter_slots: the slot capacity, or every slot of the block); None
+    # everywhere but the banded subword step
+    subword_slots: Optional[jax.Array] = None
 
 
 def init_embeddings(

@@ -41,7 +41,7 @@ and 66.5 for the flat one (58.3 with no room in its capacity), where ISSUE
 search over group ends, a second run sum, a second overflow case) was worth
 having (PERF.md §6, PR 31: XLA sorts this form's scatter indices, so its
 padding rows cost it little). Fewer word heads make the flat form's capacity
-smaller too: it is next (ROADMAP B10 (c)).
+smaller too: it is next (ROADMAP A11 (c)).
 
 Three forms, chosen by the step from its own batch. More word pieces than
 ``word_cap`` (a batch whose centers hardly repeat): the per-RUN form, a run
@@ -51,6 +51,21 @@ every pair its own list, in chunks of pairs under ``lax.map`` /
 ``lax.scan``, so that no [B, G, D] block is ever made. Same sums, same rows.
 ``word_cap`` 0 (the trainer's rule found no saving; a CBOW token block)
 builds the last two alone, as before there were three.
+
+A CBOW token block (ops/cbow_banded.py; ``max_run`` 1 with room for every
+token slot, so the per-run form alone is built) has no runs and no word
+level: every token slot reads its own word's list, [T, max_groups · 8] slots
+of which two thirds are padding at cc.en.300's shape (1,048,736 slots, ~330,000
+live). Its scatter takes ``slot_cap`` where the trainer derives one from the
+counts: the block's (row, slot) keys sorted inside the step (NO_ROW sorts
+last, so the live slots are a prefix), the first ``slot_cap`` of them
+scattered with ``indices_are_sorted``, each with its token's update row read
+in that order. A sorted scatter's padding slot costs ~19.6 ns on the chip and
+a live one ~82: 28.2 ms a step at 393,216 slots where all 1,048,736 cost 41.7
+and the broadcast block 3.0 more (PERF.md §6, PR 36). A block with more live
+slots than the capacity (``plan.live_rows`` counts them) takes the whole form
+under one ``lax.cond``: same rows, same sums. The gather keeps every slot:
+its sum needs token order (ROADMAP A11 (a)).
 """
 
 from __future__ import annotations
@@ -86,6 +101,9 @@ class SubwordShape(NamedTuple):
     head_cap: int        # center runs of a batch the per-run form holds
     word_run: int = 1    # a word's run heads are cut every word_run into pieces
     word_cap: int = 0    # word pieces of a batch the per-word form holds; 0: not built
+    # slots the per-run form's scatter takes, sorted by row and cut to their
+    # live prefix; 0: not built (the trainer sets it for a CBOW token block)
+    slot_cap: int = 0
 
 
 # CenterPlan.form: which of the three forms the batch takes
@@ -250,6 +268,23 @@ def _either(plan: CenterPlan, shape: SubwordShape, n: int, per_word, per_run,
     return jax.lax.switch(plan.form, (plain, per_run, per_word), syn0)
 
 
+def _cut_holds(plan: CenterPlan, shape: SubwordShape) -> jax.Array:
+    """bool: the live slots of the per-run form's block fit ``slot_cap``."""
+    return plan.live_rows <= shape.slot_cap
+
+
+def scatter_slots(plan: CenterPlan, shape: SubwordShape) -> jax.Array:
+    """float32: slots the list scatter of the per-run or the plain form is
+    handed, live or padding: ``slot_cap`` where the block's live slots fit
+    it, else every slot of the heads' block (of every pair's list, plain)."""
+    handed = jnp.float32(plan.rows.size)
+    if shape.slot_cap:
+        handed = jnp.where(_cut_holds(plan, shape),
+                           jnp.float32(shape.slot_cap), handed)
+    return jnp.where(plan.fits, handed, jnp.float32(
+        plan.pos.shape[0] * shape.max_groups * GROUP))
+
+
 def center_vectors(syn0: jax.Array, centers: jax.Array, table: SubwordTable,
                    shape: SubwordShape, plan: CenterPlan,
                    compute_dtype: jnp.dtype) -> jax.Array:
@@ -298,15 +333,19 @@ def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
             jnp.broadcast_to(d_h.astype(syn0.dtype)[:, None, :], rows.shape + (d,)),
             mode="drop")
 
-    def spread_sorted(syn0, rows, d_h):
+    def spread_sorted(syn0, rows, d_h, cut=None):
         """:func:`spread` with the slots handed over sorted by row, each with
         its head's update row read in that order: what XLA's TPU scatter makes
         of a scatter of more update rows than an eighth of the table's rows,
         and does not below that (PERF.md §6, PR 34: unsorted, 491,520 slots
         cost 95 ns each, dropped or not, 46.8 ms; sorted, the padding sorts
-        last and costs little, 32.1 ms, and no broadcast block is made)."""
+        last and costs little, 32.1 ms, and no broadcast block is made).
+        ``cut``: the first ``cut`` sorted slots alone, for a caller who knows
+        the live ones (NO_ROW sorts last) are no more."""
         keys, slot = jax.lax.sort(
             (rows.reshape(-1), jnp.arange(rows.size, dtype=jnp.int32)), num_keys=1)
+        if cut is not None:
+            keys, slot = keys[:cut], slot[:cut]
         return syn0.at[keys].add(
             d_h.astype(syn0.dtype)[slot // rows.shape[1]], mode="drop",
             indices_are_sorted=True)
@@ -327,7 +366,14 @@ def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
             sums = run_sums(d_in, plan.pos, shape.max_run, acc)
             d_h = sums[plan.src] * plan.inv[:, None]
         with jax.named_scope("subword.scatter"):
-            return spread(syn0, plan.rows, d_h)
+            if not shape.slot_cap:
+                return spread(syn0, plan.rows, d_h)
+            # a block with more live slots than the capacity takes the whole
+            # form: same rows, same sums
+            return jax.lax.cond(
+                _cut_holds(plan, shape),
+                partial(spread_sorted, cut=shape.slot_cap), spread,
+                syn0, plan.rows, d_h)
 
     def plain(syn0):
         c = math.gcd(centers.shape[0], _PLAIN_CHUNK)

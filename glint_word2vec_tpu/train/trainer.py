@@ -102,6 +102,17 @@ _SUBWORD_GROUPS_UNIT = 1 << 20
 _CONTEXT_MAX_RUN = 6
 
 
+def _kept_token_distribution(counts: np.ndarray, train_words_count: int,
+                             subsample_ratio: float) -> Optional[np.ndarray]:
+    """p_w of a token the subsampling kept being word w (float64 [V]); None
+    where it keeps no token."""
+    from glint_word2vec_tpu.data.pipeline import keep_probabilities
+    kept = np.asarray(counts, np.float64) * keep_probabilities(
+        counts, train_words_count, subsample_ratio)
+    total = kept.sum()
+    return kept / total if total > 0 else None
+
+
 def _expected_heads(counts: np.ndarray, train_words_count: int,
                     subsample_ratio: float, draws: float, entries: float,
                     max_run: int) -> Optional[float]:
@@ -111,13 +122,9 @@ def _expected_heads(counts: np.ndarray, train_words_count: int,
     Σ 1 − (1 − p_w)^draws distinct words, and a word expected in more entries
     than a run holds (``entries`` · p_w) adds a piece per run's length of
     them. None where subsampling keeps no token."""
-    from glint_word2vec_tpu.data.pipeline import keep_probabilities
-    kept = np.asarray(counts, np.float64) * keep_probabilities(
-        counts, train_words_count, subsample_ratio)
-    total = kept.sum()
-    if total <= 0:
+    p = _kept_token_distribution(counts, train_words_count, subsample_ratio)
+    if p is None:
         return None
-    p = kept / total
     distinct = -np.expm1(draws * np.log1p(-np.minimum(p, 1 - 1e-12))).sum()
     per_word = entries * p
     return float(distinct + per_word[per_word > max_run].sum() / max_run)
@@ -182,6 +189,31 @@ def _word_cap(counts: np.ndarray, train_words_count: int,
     cap = -(-int(1.2 * _word_pieces(counts, train_words_count, subsample_ratio,
                                     window, batch)) // unit) * unit
     return cap if cap <= 0.8 * run_cap else 0
+
+
+def _slot_cap(counts: np.ndarray, train_words_count: int,
+              subsample_ratio: float, list_rows: np.ndarray, tokens: int,
+              slots: int) -> int:
+    """Static capacity of a CBOW token block's list scatter (ops/subword.py:
+    the block's ``slots`` = tokens · max_groups · 8 slots sorted by row inside
+    the step and cut to the live ones), 0 = do not build it. A block's live
+    slots are its tokens' list lengths (``list_rows`` [V], the row table's
+    own counts): ``tokens`` · Σ p_w · list_rows[w] over the kept-token
+    distribution, with 20% of room, to the NEAREST unit (the power of two at
+    or under a 32nd of the slots, so 15-25% of room). At cc.en.300's shape
+    326,700-331,300 are expected over the benchmark's seeds where feed blocks
+    hold 329,900-332,600: 393,216 = 12 units of 1,048,736 slots for every
+    seed, where rounding UP gives 12 units to some seeds and 13 to others, two
+    programs 0.64 ms a step apart (PERF.md §6, PR 36). Where the cut saves
+    under a fifth of the slots (a vocabulary whose lists are full) it is not
+    built."""
+    p = _kept_token_distribution(counts, train_words_count, subsample_ratio)
+    if p is None or slots < 32:
+        return 0
+    live = tokens * float(p @ np.asarray(list_rows, np.float64)[:p.shape[0]])
+    unit = 1 << ((slots // 32).bit_length() - 1)
+    cap = int(1.2 * live / unit + 0.5) * unit
+    return cap if cap <= 0.8 * slots else 0
 
 
 class StepChoice(NamedTuple):
@@ -983,8 +1015,10 @@ class Trainer:
         the device: span ``vocab.subword_table``, its seconds kept in
         ``subword_table_time``; the step's shape (ops/subword.py) takes the
         center-run capacity the plain step has and, under it, the word
-        capacity :func:`_word_cap` derives from the counts."""
-        from glint_word2vec_tpu.data.subword import NO_ROW, build_subword_table
+        capacity :func:`_word_cap` derives from the counts; a CBOW token
+        block's takes the slot capacity :func:`_slot_cap` derives from them."""
+        from glint_word2vec_tpu.data.subword import (
+            GROUP, NO_ROW, build_subword_table)
         from glint_word2vec_tpu.ops import subword as sw
         cfg = self.config
         t0 = time.perf_counter()
@@ -1011,9 +1045,15 @@ class Trainer:
         self._step_extra = (placed["offsets"], placed["rows"], placed["counts"])
         if self._banded_cbow:
             # the row source of a token block (ops/cbow_banded.py): every
-            # token slot of the block reads its own word's list
+            # token slot of the block reads its own word's list, and the
+            # lists' scatter takes the live slots alone where the counts
+            # promise few enough of them
+            t = self._tokens_per_step
             self._subword_shape = sw.SubwordShape(
-                rows.max_groups, 1, self._tokens_per_step)
+                rows.max_groups, 1, t, slot_cap=_slot_cap(
+                    self.vocab.counts, self.vocab.train_words_count,
+                    cfg.subsample_ratio, rows.counts, t,
+                    t * rows.max_groups * GROUP))
         else:
             # center runs as the plain step's (one head per run of a center's
             # pairs); where none are built every pair is its own head
@@ -3470,11 +3510,12 @@ class Trainer:
             # audit's scripted fits are too short to hit; tests/test_obs.py
             # runs a probing fit under the guard to keep this path honest)
             with self._tracer.span("device_block") as blocked:
-                loss_k, fpos_k, pairs_k, rows0_k, rows1_k, rows_sw_k, pos = (
-                    jax.device_get(
-                        (metrics.loss, metrics.mean_f_pos, metrics.pairs,
-                         metrics.syn0_rows, metrics.syn1_rows,
-                         metrics.subword_rows, self.params.pos)))
+                (loss_k, fpos_k, pairs_k, rows0_k, rows1_k, rows_sw_k,
+                 slots_sw_k, pos) = jax.device_get(
+                    (metrics.loss, metrics.mean_f_pos, metrics.pairs,
+                     metrics.syn0_rows, metrics.syn1_rows,
+                     metrics.subword_rows, metrics.subword_slots,
+                     self.params.pos))
                 if rows0_k is not None and pairs_k[real - 1] > 0:
                     # how far the step coalesced each table's update: 1.0
                     # plain, heads over pairs where runs were summed first
@@ -3490,6 +3531,11 @@ class Trainer:
                     # (config.subword), over the step's pairs or examples
                     blocked.set(subword_rows_per_pair=float(
                         rows_sw_k[real - 1] / pairs_k[real - 1]))
+                if slots_sw_k is not None and pairs_k[real - 1] > 0:
+                    # slots a token block's list scatter was handed, live or
+                    # padding: the slot capacity's engagement counter
+                    blocked.set(subword_slots_per_pair=float(
+                        slots_sw_k[real - 1] / pairs_k[real - 1]))
                 if pos is not None:
                     # how far the position weights have moved from the ones
                     # they start at, |pos − 1| / |1|: 0 = the leaf is not

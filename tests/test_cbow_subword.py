@@ -5,7 +5,8 @@ size.
 The banded step with {row source on, off} x {position weights on, off} against
 ``cbow_subword_ref``'s ``jax.grad`` updates on token blocks with repeated words,
 real sentence ends and a halo, in float32 and (the taps' equivalence) in
-float64; the row source's forms giving the same sums; masked slots and the lane
+float64; the row source's forms giving the same sums, the lists' scatter under a
+slot capacity and a block over it among them; masked slots and the lane
 padding; the lowered steps of the configurations that use neither against the
 parent's text; the third leaf through a fit, a save, a load and a resume; the
 model's composed vectors and a string the vocabulary has never seen; the
@@ -237,10 +238,29 @@ def test_taps_transpose_is_the_adjoint():
 
 # -- the row source's forms ----------------------------------------------------------
 
-# (max_run, head_cap): every token slot the head of its own list, with room
-# for all of them (what the trainer builds: one branch, known while tracing),
-# and with too few heads (the chunked plain form, chosen by the step)
-FORMS = {"capacity_held": (1, T), "capacity_overflowed": (1, 16)}
+# (max_run, head_cap, slot_cap): every token slot the head of its own list,
+# with room for all of them (one branch, known while tracing), and with too few
+# heads (the chunked plain form, chosen by the step); under the first, the
+# lists' slots sorted by row and cut to a capacity over every block's live
+# slots (what the trainer builds where the counts promise one), to one that
+# every block overflows (the whole form, chosen by the step), and to one
+# between the blocks' 3,531 / 3,636 / 3,592 live slots (a form a step)
+FORMS = {"capacity_held": (1, T, 0), "capacity_overflowed": (1, 16, 0),
+         "slots_cut": (1, T, 4096), "slot_capacity_overflowed": (1, T, 2048),
+         "slot_capacity_straddled": (1, T, 3600)}
+
+
+def _form_shape(table, form):
+    max_run, head_cap, slot_cap = FORMS[form]
+    return SubwordShape(table.max_groups, max_run, head_cap, slot_cap=slot_cap)
+
+
+def _slots_handed(case, form, step=0):
+    """What ``subword_slots`` must read: the capacity where the block's live
+    slots fit it, else every slot of the block."""
+    table, cap = case["table"], FORMS[form][2]
+    live = table.counts[case["tokens"][step]].sum()
+    return cap if cap and live <= cap else T * table.max_groups * 8
 
 
 @pytest.mark.parametrize("form", list(FORMS))
@@ -249,39 +269,76 @@ def test_row_source_branches_give_the_same_sums(form):
     table = case["table"]
     want, _ = _run_reference(case, True, True)
     got, metrics = _run_program(case, True, True, jnp.float32,
-                                shape=SubwordShape(table.max_groups, *FORMS[form]))
+                                shape=_form_shape(table, form))
     np.testing.assert_allclose(got.syn0, want["syn0"], rtol=3e-5, atol=3e-7)
     np.testing.assert_allclose(got.syn1, want["syn1"], rtol=3e-5, atol=3e-7)
     np.testing.assert_allclose(got.pos, want["d"], rtol=3e-6, atol=1e-7)
     # either way every token's list reaches the scatter once
-    assert float(metrics[0].subword_rows) == table.counts[case["tokens"][0]].sum()
+    live = [table.counts[tokens].sum() for tokens in case["tokens"]]
+    assert [float(m.subword_rows) for m in metrics] == live
+    assert [float(m.subword_slots) for m in metrics] == [
+        _slots_handed(case, form, k) for k in range(STEPS)]
+    if form == "slots_cut":
+        assert max(live) <= FORMS[form][2] < 0.5 * T * table.max_groups * 8
+    if form == "slot_capacity_overflowed":
+        assert min(live) > FORMS[form][2]
+    if form == "slot_capacity_straddled":
+        assert min(live) <= FORMS[form][2] < max(live)
+
+
+@pytest.mark.parametrize("form", ["slots_cut", "slot_capacity_overflowed"])
+def test_slot_capacity_branches_give_the_same_sums_in_float64(form):
+    """The cut and its overflow against the reference with float64 tables and
+    sums: a live slot the cut dropped, or a padding slot it kept, is a row's
+    whole update, a million times what this can hold."""
+    case = _case()
+    with jax.enable_x64():
+        params, metrics = _run_program(case, True, True, jnp.float64,
+                                       shape=_form_shape(case["table"], form))
+        ref, _ = _run_reference(case, True, True, jnp.float64)
+        for got, want in ((params.syn0, ref["syn0"]), (params.syn1, ref["syn1"]),
+                          (params.pos, ref["d"])):
+            np.testing.assert_allclose(got, want, rtol=1e-7, atol=2e-8)
+        assert float(metrics[0].subword_slots) == _slots_handed(case, form)
 
 
 def test_the_trainers_row_source_builds_one_branch():
-    """With room for every token slot the step holds no conditional: the
-    capacity is known while tracing."""
+    """With room for every token slot and no slot capacity the step holds no
+    conditional (the head capacity is known while tracing); with the slot
+    capacity it holds exactly one, around the lists' scatter, and the gather
+    stays outside any."""
     case = _case()
     table = case["table"]
     band = case["bands"][0]
 
-    def lowered(cap):
+    def lowered(cap, slot_cap=0):
         return jax.jit(lambda p, dev, tk, n: cbow_step_banded_core(
             p, tk, band.left, band.right, band.center, band.token, n,
             jnp.float32(0.05), NEG, W, subword=(dev, SubwordShape(
-                table.max_groups, 1, cap)))).lower(
+                table.max_groups, 1, cap, slot_cap=slot_cap)))).lower(
             EmbeddingPair(jnp.asarray(case["syn0"], jnp.float32),
                           jnp.asarray(case["syn1"], jnp.float32),
                           jnp.asarray(case["pos"], jnp.float32)),
             _device_table(table), jnp.asarray(case["tokens"][0]),
             jnp.asarray(case["negatives"][0])).as_text()
 
-    assert "stablehlo.case" not in lowered(T) and "stablehlo.if" not in lowered(T)
-    assert "stablehlo.case" in lowered(16) or "stablehlo.if" in lowered(16)
+    def conditionals(text):
+        return text.count("stablehlo.case") + text.count("stablehlo.if")
+
+    assert conditionals(lowered(T)) == 0
+    assert conditionals(lowered(16)) >= 1
+    cut = lowered(T, FORMS["slots_cut"][2])
+    assert conditionals(cut) == 1
+    # one sort more than the program without the capacity: the cut branch's own
+    assert cut.count("stablehlo.sort") == lowered(T).count("stablehlo.sort") + 1
 
 
-def test_masked_slots_and_the_lane_padding_stay_zero():
+@pytest.mark.parametrize("slot_cap", [0, 2048], ids=["every_slot", "slots_cut"])
+def test_masked_slots_and_the_lane_padding_stay_zero(slot_cap):
     """A block whose tail is not valid (token_mask 0) lists nothing and moves
-    nothing for it, and zero columns stay exactly zero, the weights' too."""
+    nothing for it, and zero columns stay exactly zero, the weights' too: with
+    every slot of the block handed to the scatter, and with the slots sorted
+    by row and cut (a third of the block is live: its slots fit 2,048)."""
     case = _case()
     table, pad, real = case["table"], 8, T // 3
     tokens = np.where(np.arange(T) < real, case["tokens"][0], 0).astype(np.int32)
@@ -300,10 +357,12 @@ def test_masked_slots_and_the_lane_padding_stay_zero():
         params, jnp.asarray(tokens), band.left, band.right, band.center, band.token,
         jnp.asarray(case["negatives"][0]), jnp.float32(0.05), NEG, W, "exact",
         jnp.bfloat16, jnp.bfloat16,
-        subword=(_device_table(table), SubwordShape(table.max_groups, 1, T)))
+        subword=(_device_table(table), SubwordShape(table.max_groups, 1, T,
+                                                    slot_cap=slot_cap)))
     for leaf in got:
         assert not np.asarray(leaf[:, D:]).any()
     assert float(metrics.subword_rows) == table.counts[tokens[:real]].sum()
+    assert float(metrics.subword_slots) == (slot_cap or T * table.max_groups * 8)
     touched = np.unique(np.concatenate([table.rows_of(w) for w in np.unique(tokens[:real])]))
     still = np.setdiff1d(np.arange(V + BUCKETS), touched)
     np.testing.assert_array_equal(got.syn0[still], params.syn0[still])
@@ -321,15 +380,17 @@ def test_masked_slots_and_the_lane_padding_stay_zero():
 # distinct center word where the trainer's rule derives a word cap (it does at
 # the tiny sizes), so those two digests are PR 34's tree's. The token block's
 # row source shares `plan_centers` and took none of it: `cbow-subword-2m-300.train`'s
-# two are the parent commit of PR 34's (1b95d5f), the new `SubwordShape` fields
-# at their defaults.
+# two were the parent commit of PR 34's (1b95d5f) until PR 36, which changed that
+# step on purpose (its lists' scatter under a slot capacity, which the trainer's
+# rule derives at the tiny sizes too: 10,240 of 16,464 slots) and took these two
+# from its own tree; the other four are as they were.
 PARENT_STEP_TEXT = {
     ("cbow-3m-300.train", "train_cbow", "_step_fn"): "7f02f0da70d07c76",
     ("cbow-3m-300.train", "train_cbow", "_step_fn_fast"): "436c26f275ae9be0",
     ("subword-sgns-2.5m-300.train", "train_subword", "_step_fn"): "f53dceb464f6b6b9",
     ("subword-sgns-2.5m-300.train", "train_subword", "_step_fn_fast"): "188e2230a80d8e87",
-    ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn"): "10a5adbf669d0d5d",
-    ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn_fast"): "978b7f0b686be25b",
+    ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn"): "eb7f0f696d8380eb",
+    ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn_fast"): "93fb95a8e57c8cab",
 }
 
 
@@ -347,12 +408,14 @@ def test_steps_without_the_new_parts_lower_to_the_parents_text(cell_name, kind_n
         cell, 0, tiny=True)
     shape = trainer._subword_shape
     if kind_name == "train_cbow_subword":
-        # every token slot its own list, and no second level under them
+        # every token slot its own list, no second level under them, and the
+        # lists' scatter under a slot capacity
         assert (shape.max_run, shape.head_cap, shape.word_cap) == (
             1, trainer._tokens_per_step, 0)
+        assert 0 < shape.slot_cap < trainer._tokens_per_step * shape.max_groups * 8
     else:
         assert trainer.params.pos is None
-        assert shape is None or shape.word_cap > 0
+        assert shape is None or (shape.word_cap > 0 and shape.slot_cap == 0)
     cfg = trainer.config
     k, b = cfg.steps_per_dispatch, cfg.pairs_per_batch
     zeros = np.zeros((2, k), np.float32)
@@ -466,6 +529,10 @@ def test_heartbeat_reports_the_rows_and_the_drift(fitted):
     assert blocks, "device_block carries position_drift on this model"
     assert all(0 < e["args"]["position_drift"] < 0.5 for e in blocks)
     assert all(1.0 < e["args"]["subword_rows_per_pair"] < 40 for e in blocks)
+    # slots handed to the lists' scatter, live or padding: never fewer than the
+    # live rows, and at most every slot of a block over the live examples
+    assert all(e["args"]["subword_rows_per_pair"] <= e["args"]["subword_slots_per_pair"]
+               < 200 for e in blocks)
     assert any(json.loads(line).get("event", json.loads(line).get("kind")) for line in open(path))
 
 

@@ -38,6 +38,7 @@ from glint_word2vec_tpu.train.trainer import (
     _WORD_MAX_RUN,
     _center_run_cap,
     _context_run_cap,
+    _slot_cap,
     _word_cap,
     _word_pieces,
 )
@@ -525,6 +526,47 @@ def test_word_cap_is_derived_from_the_counts():
     # no center runs (window <= 2, a data axis), or a batch of a few pairs: not built
     assert _word_cap(counts, total, ratio, WINDOW, b, 0) == 0
     assert _word_cap(counts, total, ratio, WINDOW, 16, 6) == 0
+
+
+def test_slot_cap_is_derived_from_the_counts():
+    """The capacity of a CBOW token block's list scatter (ops/subword.py): at
+    ``cbow-subword-2m-300``'s counts, strings and resolved subsample it holds
+    1.1 to 1.35 times the live slots expected of a block and under half the
+    block's slots, and blocks of kept tokens hold what was expected."""
+    from harness import words, zipf
+
+    from glint_word2vec_tpu.data.pipeline import keep_probabilities
+    from glint_word2vec_tpu.data.subword import build_subword_table
+
+    v, t, ratio = 2_000_000, 65546, 6.45e-4
+    counts = zipf.zipf_counts(v).astype(np.int64)
+    total = int(counts.sum())
+    table = build_subword_table(words.make_words(7, v), 5, 5, 2_000_000)
+    slots = t * table.max_groups * 8
+    cap = _slot_cap(counts, total, ratio, table.counts, t, slots)
+    kept = counts * keep_probabilities(counts, total, ratio)
+    p = kept / kept.sum()
+    expected = t * float(p @ table.counts[:v])
+    assert slots == 1_048_736 and cap == 393_216 and cap % (1 << 15) == 0
+    assert 1.1 * expected < cap < 1.35 * expected and cap < 0.5 * slots
+    rng = np.random.default_rng(11)
+    for _ in range(3):
+        live = int(table.counts[rng.choice(v, t, p=p)].sum())
+        assert abs(live - expected) < 0.02 * expected and live <= cap
+
+    # a block of another size takes the same share of its slots
+    for n in (4096, 16384):
+        small = _slot_cap(counts, total, ratio, table.counts, n, n * 16)
+        assert 1.1 * expected * n / t < small < 1.35 * expected * n / t
+        assert small % (1 << ((n * 16 // 32).bit_length() - 1)) == 0
+    # lists that fill their groups: the cut saves nothing, not built
+    full = np.full(v + 1, 16, np.int32)
+    assert _slot_cap(counts, total, ratio, full, t, slots) == 0
+    assert _slot_cap(counts, total, ratio, full * 3 // 4, t, slots) == 0   # saves a tenth
+    assert _slot_cap(counts, total, ratio, full // 2, t, slots) == 19 << 15    # 0.59 of them
+    # a vocabulary subsampling keeps nothing of, or a block of a few slots
+    assert _slot_cap(np.zeros(8, np.int64), 0, 0.0, np.ones(9, np.int32), 64, 512) == 0
+    assert _slot_cap(counts, total, ratio, table.counts, 1, 16) == 0
 
 
 def test_context_cap_is_derived_from_the_counts():

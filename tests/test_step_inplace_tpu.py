@@ -26,6 +26,10 @@ The fifth (PR 33) is the banded CBOW step with the token row source and the
 position weights at ``cbow-subword-2m-300``'s size: syn0 (f32[4000000,384]) is
 read by the tokens' list gather and written by their list scatter, once a step;
 syn1 by the centers' scatter and the pool's; the third leaf rides the carry.
+Since PR 36 the list scatter sits in one conditional (the slots sorted by row
+inside the step and cut to the trainer's slot capacity, or a block over it
+whole): a scatter into syn0 in each branch, the step's own sort in the cut
+branch alone, the gather ordered before the conditional by its data (no copy).
 That the plain banded step and the subword skip-gram step are the programs
 they were is held where it is cheap, on their lowered text
 (``tests/test_cbow_subword.py``).
@@ -141,8 +145,16 @@ def test_no_table_is_copied_with_token_lists_and_position_weights(one_chip, with
     from glint_word2vec_tpu.ops.subword import SubwordShape, SubwordTable
 
     words, rows0, groups, tokens, window = 2_000_000, 4_000_000, 3 << 20, 65546, 5
-    # what the trainer derives at this size: every token slot its own list
-    shape = SubwordShape(max_groups=2, max_run=1, head_cap=tokens)
+    # what the trainer derives at this size: every token slot its own list, the
+    # lists' scatter under the slot capacity (tests/test_coalesce_runs.py holds
+    # the derivation)
+    shape = SubwordShape(max_groups=2, max_run=1, head_cap=tokens, slot_cap=393216)
+    # temp_size_in_bytes of the same compile with slot_cap=0, the parent's form
+    # (my compile for the described v5e, PR 36), and what the conditional adds
+    # whatever the capacity (1,048,736 and 360,448 read the same): 21.7 MB of
+    # 1.83 GB, five s32[1048736] arrays
+    parent_temporaries = {True: 1_833_126_400, False: 1_833_384_448}
+    conditional_adds = 22 << 20
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -157,14 +169,16 @@ def test_no_table_is_copied_with_token_lists_and_position_weights(one_chip, with
         return jax.lax.scan(body, params, (toks, left, right, center, negatives, alphas))
 
     block = spec((K, tokens), jnp.int32)
-    compiled = jax.jit(chunk, donate_argnums=(0,)).lower(
+    program = jax.jit(chunk, donate_argnums=(0,)).lower(
         EmbeddingPair(spec((rows0, D), jnp.float32), spec((words, D), jnp.float32),
                       spec((2 * window, D), jnp.float32)),
         SubwordTable(spec((words + 2,), jnp.int32), spec((groups, 8), jnp.int32),
                      spec((words + 1,), jnp.int32)),
         block, block, block, spec((K, tokens), jnp.float32), spec((K, 4096), jnp.int32),
-        spec((K,), jnp.float32)).compile().as_text()
-    assert " conditional(" not in compiled         # the capacity is known while tracing
+        spec((K,), jnp.float32)).compile()
+    compiled = program.as_text()
+    # the lists' scatter, and nothing else: the head capacity is known while tracing
+    assert compiled.count(" conditional(") == 1
     copies = [line.strip()[:120] for line in compiled.splitlines()
               if re.search(rf"= f32\[({rows0}|{words}),{D}\]\S* copy\(", line)]
     assert not copies, copies
@@ -172,9 +186,30 @@ def test_no_table_is_copied_with_token_lists_and_position_weights(one_chip, with
     def scatters(rows):
         return len(re.findall(rf"= f32\[{rows},{D}\]\S* scatter\(", compiled))
 
-    # the lists reach syn0's scatter once a step; syn1 takes the centers' rows
-    # and the pool's
-    assert (scatters(rows0), scatters(words)) == (1, 2)
+    # the lists reach syn0's scatter once a step, in either branch; syn1 takes
+    # the centers' rows and the pool's
+    assert (scatters(rows0), scatters(words)) == (2, 2)
+    # the step's own sort of the (row, slot) keys is the cut branch's: a branch
+    # is a computation of its own, and one of the two holds no stable sort (the
+    # whole form leaves its indices to XLA, which sorts them its own way)
+    branches = re.search(r"conditional\(.*branch_computations=\{%([\w.]+), %([\w.]+)\}",
+                         compiled).groups()
+    own = [bool(re.search(r" sort\([^\n]*is_stable=true", _computation(compiled, name)))
+           for name in branches]
+    assert sorted(own) == [False, True], own
+    # and hands the scatter the capacity's slots (their update rows are read
+    # in sorted order inside the scatter's own fusion: no [393216, D] block)
+    assert "s32[393216]" in _computation(compiled, branches[own.index(True)])
+    assert "s32[393216]" not in _computation(compiled, branches[own.index(False)])
+    assert (program.memory_analysis().temp_size_in_bytes
+            <= parent_temporaries[with_metrics] + conditional_adds)
+
+
+def _computation(compiled: str, name: str) -> str:
+    """The text of one named computation of a compiled module, with the fused
+    computations it calls left out (they are printed before it)."""
+    start = compiled.index(f"\n%{name} ")
+    return compiled[start:compiled.index("\n}\n", start)]
 
 
 @pytest.mark.parametrize("mesh_shape", [None, (1, 4)], ids=["one_chip", "mesh_1x4"])
