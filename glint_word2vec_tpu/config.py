@@ -308,6 +308,24 @@ class Word2VecConfig:
     subword_min_n: int = 3          # shortest and longest character n-gram of
     subword_max_n: int = 6          # "<word>" (fastText's minn / maxn)
     subword_buckets: int = 2_000_000  # hash buckets the n-grams share (its bucket)
+    loss: str = "ns"                # the output side's loss: "ns", negative
+                                    # sampling (every other knob's ground), or
+                                    # "hs", hierarchical softmax over the
+                                    # vocabulary's Huffman tree (word2vec.c
+                                    # -hs 1 -negative 0, the loss of Spark
+                                    # MLlib's Word2Vec; data/huffman.py,
+                                    # ops/hs.py): syn1's rows are the tree's
+                                    # V - 1 inner nodes and a pair's output
+                                    # side is its context's path. No sampler
+                                    # and no pool: negatives must be 0 and
+                                    # negative_pool resolves to 0. Skip-gram
+                                    # over the host pair feed on one device
+                                    # only: refused beside cbow, subword,
+                                    # device_pairgen, step_lowering=
+                                    # 'shard_map', a mesh larger than 1x1,
+                                    # duplicate_scaling, sharded_checkpoint,
+                                    # fused_logits / bf16_chain and the
+                                    # stabilizers
     shuffle: bool = True            # shuffle sentence order each iteration (reference order is
                                     # whatever repartition() produced, i.e. arbitrary; mllib:345)
 
@@ -846,7 +864,11 @@ class Word2VecConfig:
             raise ValueError(f"window must be <= 127 but got {self.window}")
         if self.batch_size <= 0:
             raise ValueError(f"batch_size must be positive but got {self.batch_size}")
-        if self.negatives <= 0:
+        if self.loss not in ("ns", "hs"):
+            raise ValueError(f"loss must be 'ns' or 'hs' but got {self.loss!r}")
+        if self.loss == "hs":
+            self._refuse_beside_hs()
+        elif self.negatives <= 0:
             raise ValueError(f"negatives must be positive but got {self.negatives}")
         # remembered so the Trainer may auto-lower an AUTO ratio into the measured
         # stability region (explicit values are refused instead, see trainer.py)
@@ -916,9 +938,12 @@ class Word2VecConfig:
                     "cbow_update='banded' with window=1 emits no contexts at "
                     "all under the reference's legacy asymmetric window "
                     "(b = nextInt(1) = 0 always) — use window >= 2")
-        # position weights: a row of the banded step alone (select_step's
-        # docstring); the scatter form's [B, 2·window] context sets carry no
-        # position, and a row-shards checkpoint has no file for the leaf
+        # position weights (select_step's table of options): the banded step
+        # alone, with or without subword:
+        #   cbow_position_weights × skip-gram / cbow "scatter" → refuse (the
+        #       scatter form's [B, 2·window] context sets carry no position)
+        #   cbow_position_weights × sharded_checkpoint → refuse (the
+        #       row-shards layout has no file for the leaf)
         if self.cbow_position_weights:
             if not (self.cbow and self.cbow_update == "banded"):
                 raise ValueError(
@@ -940,7 +965,9 @@ class Word2VecConfig:
         # changes (a resolved auto pool must not stick to a new pairs_per_batch)
         self._auto_pool = self.negative_pool == -1
         if self.negative_pool == -1:
-            if self.cbow and self.duplicate_scaling:
+            if self.loss == "hs":
+                self.negative_pool = 0      # no sampler, no pool
+            elif self.cbow and self.duplicate_scaling:
                 # mean semantics exist only on the per-example scatter path
                 self.negative_pool = 0
             elif (self.pairs_per_batch < 4096
@@ -1119,12 +1146,12 @@ class Word2VecConfig:
                     f"stay below 2^24); lower tokens_per_step or split the "
                     f"batch")
         # --- subword selection matrix (trainer.select_step's docstring has the
-        # row; Trainer.__init__ keeps the runtime twin for a mesh handed in as
-        # a plan). The row source is the shared-pool skip-gram step's center
-        # side, or the banded CBOW step's token side, on one device; every
-        # other combination is an ERROR here:
-        #   subword × cbow "scatter"     → refuse (the row source of a token
-        #       block lives in ops/cbow_banded.py; the [B, 2·window] context
+        # table of options; Trainer.__init__ keeps the runtime twin for a mesh
+        # handed in as a plan). The row source rides the shared-pool skip-gram
+        # step (the center's lists) or the banded CBOW step (a token block's,
+        # with or without cbow_position_weights), on one device; every other
+        # combination is an ERROR here:
+        #   subword × cbow "scatter"     → refuse (the [B, 2·window] context
         #       sets of the scatter forms have no list per entry)
         #   subword × negative_pool=0    → refuse (the per-pair step gathers
         #       one row a center; the row source lives in the shared-pool step)
@@ -1141,6 +1168,7 @@ class Word2VecConfig:
         #   subword × max_row_norm / row_l2 / norm_watch="recover" → refuse
         #       (the touched-row pass walks centers, not their lists' rows;
         #       recover would engage max_row_norm)
+        #   subword × loss="hs"          → refused above (_refuse_beside_hs)
         if self.subword:
             if not (0 < self.subword_min_n <= self.subword_max_n):
                 raise ValueError(
@@ -1426,6 +1454,89 @@ class Word2VecConfig:
             raise ValueError(
                 f"continual_poll_s must be positive "
                 f"but got {self.continual_poll_s}")
+
+    def _refuse_beside_hs(self) -> None:
+        """The hierarchical-softmax selection matrix (trainer.select_step's
+        docstring has the row; Trainer.__init__ keeps the runtime twin for a
+        mesh handed in as a plan). The step is skip-gram over the host pair
+        feed on one device; every other combination is an ERROR here:
+
+          hs × negatives != 0        → refuse (word2vec.c can run both losses
+              at once; this program runs one: no sampler is built)
+          hs × negative_pool > 0     → refuse (there is no pool; -1 resolves to 0)
+          hs × cbow                  → refuse (the path's logits are dots with
+              one center row; the CBOW steps' window sum has no path side)
+          hs × subword               → refuse (a list on both sides of a pair:
+              the two row sources have not been composed)
+          hs × device_pairgen        → refuse (the token-block chunk has no
+              table argument, and the paths work on the host feed's pairs)
+          hs × shard_map             → refuse (a path's nodes live on other
+              chips; ops/sgns_shard.py gathers one owner-local row a context)
+          hs × mesh > 1x1            → refuse (the same, under GSPMD)
+          hs × duplicate_scaling     → refuse (occurrence counts are per word
+              row; ops/hs.py has its own rule for a node many pairs share)
+          hs × sharded_checkpoint    → refuse (syn1's rows are nodes: the
+              row-shards layout and its loaders know words alone)
+          hs × fused_logits / bf16_chain → refuse (restructurings of the
+              [B, P] chain, which this step does not have)
+          hs × max_row_norm / row_l2 / update_clip / norm_watch="recover" →
+              refuse (the touched-row pass walks contexts as syn1 rows, and
+              here they are words, not nodes; recover would engage
+              max_row_norm)"""
+        if self.negatives != 0:
+            raise ValueError(
+                f"loss='hs' needs negatives=0 but got {self.negatives}: "
+                "word2vec.c can train both losses at once, this program "
+                "trains one, and no sampler is built beside the tree")
+        if self.negative_pool > 0:
+            raise ValueError(
+                "loss='hs' has no negative pool: leave negative_pool at -1 "
+                "(it resolves to 0) or set 0")
+        if self.cbow:
+            raise ValueError(
+                "loss='hs' does not support cbow=True: a path's logits are "
+                "dots with one center row, and the CBOW steps have no path side")
+        if self.subword:
+            raise ValueError(
+                "loss='hs' does not support subword=True: a list on both "
+                "sides of a pair (n-gram rows in, path nodes out) is not "
+                "composed yet")
+        if self.device_pairgen:
+            raise ValueError(
+                "loss='hs' does not support device_pairgen: the path side "
+                "works on the host pair feed's batches, and the token-block "
+                "chunk takes no table")
+        if self.step_lowering == "shard_map":
+            raise ValueError(
+                "loss='hs' does not support step_lowering='shard_map': a "
+                "path's nodes live on other chips, and the explicit schedule "
+                "gathers one owner-local row a context")
+        mesh = self.mesh_shape or (self.num_data_shards, self.num_model_shards)
+        if tuple(mesh) != (1, 1):
+            raise ValueError(
+                f"loss='hs' trains on one device: a {mesh[0]}x{mesh[1]} mesh "
+                f"would spread a path's nodes over chips (no sharded path "
+                f"side yet)")
+        if self.duplicate_scaling:
+            raise ValueError(
+                "loss='hs' does not support duplicate_scaling=True: "
+                "mean-update counts are per word row; the rule for a node "
+                "that many pairs share lives in ops/hs.py")
+        if self.sharded_checkpoint:
+            raise ValueError(
+                "loss='hs' does not support sharded_checkpoint=True: syn1's "
+                "rows are the tree's nodes, saved in the dense layout only")
+        if self.fused_logits or self.bf16_chain:
+            raise ValueError(
+                "loss='hs' does not support fused_logits or bf16_chain: they "
+                "restructure the [B, P] negative chain, which this step lacks")
+        if (self.max_row_norm or self.row_l2 or self.update_clip
+                or self.norm_watch == "recover"):
+            raise ValueError(
+                "loss='hs' does not support max_row_norm, row_l2, update_clip "
+                "or norm_watch='recover' (which engages max_row_norm): the "
+                "touched-row pass walks contexts as syn1 rows, and syn1's "
+                "rows are nodes; norm_watch='warn'/'halt' are available")
 
     def replace(self, **kwargs) -> "Word2VecConfig":
         if (getattr(self, "_auto_pool", False)

@@ -60,6 +60,10 @@ class HealthStats(NamedTuple):
 
     finite: jax.Array      # bool scalar over the PADDED carry (old probe bit)
     syn0: MatrixStats
+    # under config.loss="hs" syn1's rows are the Huffman tree's inner nodes,
+    # not words: max_norm is where a diverging root shows first (ops/hs.py's
+    # rule for a node many pairs share), and the last of the vocab_size rows
+    # read is the unused row V − 1, which stays zero
     syn1: MatrixStats
 
 

@@ -92,8 +92,8 @@ def _center_run_cap(window: int, batch: int) -> int:
     return -(-int(1.4 * runs_per_pair * batch) // eighth) * eighth
 
 
-# the subword row table's groups are padded to a multiple of this on the device
-# (Trainer._place_subword_table has the reason)
+# a row table's groups are padded to a multiple of this on the device
+# (Trainer._put_row_table has the reason)
 _SUBWORD_GROUPS_UNIT = 1 << 20
 
 # pairs in a piece of a context run (ops/sgns.run_sums makes one shifted add
@@ -123,11 +123,18 @@ def _expected_heads(counts: np.ndarray, train_words_count: int,
     than a run holds (``entries`` · p_w) adds a piece per run's length of
     them. None where subsampling keeps no token."""
     p = _kept_token_distribution(counts, train_words_count, subsample_ratio)
-    if p is None:
-        return None
-    distinct = -np.expm1(draws * np.log1p(-np.minimum(p, 1 - 1e-12))).sum()
+    return None if p is None else float(
+        _heads_by_word(p, draws, entries, max_run).sum())
+
+
+def _heads_by_word(p: np.ndarray, draws: float, entries: float,
+                   max_run: int) -> np.ndarray:
+    """:func:`_expected_heads` word by word (float64 [V]): the chance the
+    word is drawn at all, and a piece per run's length of its entries where
+    it is expected in more than a run holds."""
     per_word = entries * p
-    return float(distinct + per_word[per_word > max_run].sum() / max_run)
+    return (-np.expm1(draws * np.log1p(-np.minimum(p, 1 - 1e-12)))
+            + np.where(per_word > max_run, per_word / max_run, 0.0))
 
 
 def _context_run_cap(counts: np.ndarray, train_words_count: int,
@@ -216,6 +223,44 @@ def _slot_cap(counts: np.ndarray, train_words_count: int,
     return cap if cap <= 0.8 * slots else 0
 
 
+# pairs in a piece of a context word (the hierarchical-softmax step's per-word
+# form, ops/hs.py: a piece's path is gathered once and contracted with its
+# pairs)
+_HS_MAX_RUN = 8
+
+
+def _hs_caps(counts: np.ndarray, train_words_count: int,
+             subsample_ratio: float, window: int, batch: int,
+             lengths: np.ndarray, slots_per_word: int) -> Tuple[int, int]:
+    """Static capacities ``(word_cap, slot_cap)`` of the hierarchical-softmax
+    step's per-word form (ops/hs.py), (0, 0) = do not build it.
+
+    Sorted by context a batch holds one piece per distinct context word and,
+    for a word expected in more pairs than a piece holds, a piece per
+    :data:`_HS_MAX_RUN` of them (:func:`_heads_by_word`);
+    a piece lists its word's path once, ``lengths[w]`` live slots (the path
+    table's own counts). ``word_cap`` is the expected pieces with 20% of room
+    in 32nds of the batch; ``slot_cap`` the expected live slots with 20% of
+    room, to the nearest unit as :func:`_slot_cap` rounds (the power of two at
+    or under a 32nd of the block's slots). A piece count over half the batch
+    (contexts that hardly repeat) saves too little to build, and a batch
+    expected to hold no whole piece (a window wider than the batch) none."""
+    p = _kept_token_distribution(counts, train_words_count, subsample_ratio)
+    if p is None or batch < 32:
+        return 0, 0
+    pieces = _heads_by_word(p, batch / _pairs_per_kept_token(window), batch,
+                            _HS_MAX_RUN)
+    unit = batch // 32
+    word_cap = -(-int(1.2 * pieces.sum()) // unit) * unit
+    slots = word_cap * slots_per_word
+    if word_cap > batch // 2 or slots < 32:
+        return 0, 0
+    live = float(pieces @ np.asarray(lengths, np.float64)[:p.shape[0]])
+    slot_unit = 1 << ((slots // 32).bit_length() - 1)
+    slot_cap = min(int(1.2 * live / slot_unit + 0.5) * slot_unit, slots)
+    return word_cap, max(slot_cap, slot_unit)
+
+
 class StepChoice(NamedTuple):
     """One row of the step selection matrix (:func:`select_step`)."""
 
@@ -223,7 +268,8 @@ class StepChoice(NamedTuple):
     # (params, batch, negatives, alpha) -> (params, StepMetrics); ``batch`` is
     # the dict the row's chunk body builds
     step: Callable
-    neg_shape: Callable[[int, int], Tuple[int, ...]]  # (K, B) -> one chunk's negatives
+    # (K, B) -> one chunk's negatives; None: the step samples nothing
+    neg_shape: Optional[Callable[[int, int], Tuple[int, ...]]]
     # (max_run, cap) where syn0's update goes to the scatter by center runs
     center_runs: Optional[Tuple[int, int]]
     # the same for syn1's context update, by runs of the batch sorted by context
@@ -232,47 +278,52 @@ class StepChoice(NamedTuple):
 
 def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
                 context_cap: int, stabilizers: Optional[Stabilizers],
-                with_metrics: bool, subword_shape=None) -> StepChoice:
+                with_metrics: bool, subword_shape=None,
+                hs_shape=None) -> StepChoice:
     """The step selection matrix: which update one configuration trains with.
     Every legal combination is one row, read top to bottom; what is on no row
     config.__post_init__ refuses at construction, never silently downgrades
     (P = negative_pool, n = negatives, nd = the mesh's data degree):
 
-      cbow   cbow_update  P    duplicate_scaling  step_lowering  → core                      negatives
-      -----  -----------  ---  -----------------  -------------  --------------------------  ----------
-      True   "banded"     > 0  False              gspmd          cbow_step_banded_core       [K, P]
-                                                                 (+ subword, position
-                                                                 weights, below)
-      True   "scatter"    > 0  False              gspmd          cbow_step_shared_core       [K, P]
-      True   "scatter"    = 0  any                gspmd          cbow_step_core              [K, B, n]
-      False  —            = 0  any                gspmd          sgns_step_core              [K, B, n]
-      False  —            > 0  False              "shard_map"    make_shard_map_sgns_step    [K, P]
-                                                  sync_every>1   (the same, windowed)        [K, nd·P]
-      False  —            > 0  any                gspmd          sgns_step_shared_core       [K, P]
-                                                                 (+ center_runs and
-                                                                 context_runs, below)
+      cbow   cbow_update  loss  P    duplicate_scaling  step_lowering  → core                      negatives
+      -----  -----------  ----  ---  -----------------  -------------  --------------------------  ----------
+      True   "banded"     ns    > 0  False              gspmd          cbow_step_banded_core       [K, P]
+      True   "scatter"    ns    > 0  False              gspmd          cbow_step_shared_core       [K, P]
+      True   "scatter"    ns    = 0  any                gspmd          cbow_step_core              [K, B, n]
+      False  —            hs    = 0  False              gspmd          hs_step_core                none
+      False  —            ns    = 0  any                gspmd          sgns_step_core              [K, B, n]
+      False  —            ns    > 0  False              "shard_map"    make_shard_map_sgns_step    [K, P]
+                                                        sync_every>1   (the same, windowed)        [K, nd·P]
+      False  —            ns    > 0  any                gspmd          sgns_step_shared_core       [K, P]
+                                                                       (+ center_runs and
+                                                                       context_runs, below)
 
-    ``subword=True`` is no row of its own: it is the last row with the center's
-    row source on (``subword=(table, shape)``, ops/subword.py; the table rides
-    the batch as ``batch["subword_table"]``, a jit argument of the chunk), on
-    one device and the host pair feed; or the FIRST row with the token block's
-    row source on (the same argument of ``cbow_step_banded_core``: a context
-    token's vector is the mean of its word's listed rows), on one device and
-    the token feed. Beside it config refuses cbow "scatter", P = 0,
-    "shard_map", a mesh larger than 1x1, device_pairgen, duplicate_scaling,
-    sharded_checkpoint and the touched-row stabilizers.
+    Three options ride a row or are one, each on one device and each with its
+    table as jit arguments of the chunk (``_step_extra``):
 
-    ``cbow_position_weights=True`` is the first row too, with a third leaf in
-    the params (``EmbeddingPair.pos``, [2·window, D]): the banded step sums
-    the window under it by taps in place of its two prefix sums, with or
-    without ``subword``. Beside it config refuses every other row (cbow
-    "scatter", skip-gram) and sharded_checkpoint.
+      option                 its row                                 refused beside (config)
+      ---------------------  --------------------------------------  -------------------------------------
+      subword                the last (the CENTER's row source,      cbow "scatter", P = 0, "shard_map", a
+                             host pair feed) or the first (a token   mesh over 1x1, device_pairgen,
+                             BLOCK's, token feed):                   duplicate_scaling, sharded_checkpoint,
+                             ``subword=(table, shape)``,             max_row_norm, row_l2,
+                             ops/subword.py                          norm_watch="recover"
+      cbow_position_weights  the first, with or without subword: a   every other row; sharded_checkpoint
+                             third leaf ``EmbeddingPair.pos``
+                             [2·window, D], the window summed by
+                             taps in place of two prefix sums
+      loss="hs"              its own (the fourth): the context's     cbow, subword, device_pairgen,
+                             PATH as the output side, ops/hs.py;     "shard_map", a mesh over 1x1,
+                             no sampler, no alias tables, no         duplicate_scaling, sharded_checkpoint,
+                             negatives array (``neg_shape`` None)    fused_logits, bf16_chain, every
+                                                                     stabilizer, n != 0, P > 0
 
     ``context_cap`` is :func:`_context_run_cap` of the trainer's vocabulary,
     ``stabilizers`` is the trainer's state (None = all off), ``with_metrics``
     the twin; the rows without a ``with_metrics`` form have one twin.
     ``subword_shape`` is the trainer's :class:`..ops.subword.SubwordShape`
-    (None where the model is not subword)."""
+    and ``hs_shape`` its :class:`..ops.hs.HsShape` (None where the model is
+    not subword, not hierarchical softmax)."""
     compute_dtype = jnp.dtype(cfg.compute_dtype)
     logits_dtype = jnp.dtype(cfg.logits_dtype)
     n, pool = cfg.negatives, cfg.negative_pool
@@ -322,6 +373,26 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
 
         return StepChoice(cbow_step_core, step, per_example, None)
 
+    # syn0's update, one scatter row per center run (the step chooses per
+    # batch; ops/sgns.scatter_add_by_runs), where one program sees the batch
+    # whole: a batch split over a data axis or fed in per-process segments
+    # cuts runs at every seam. The shared-pool row's and the path row's
+    runs = None
+    if plan.num_data == 1 and feed_segments == 1:
+        cap = _center_run_cap(cfg.window, cfg.pairs_per_batch)
+        runs = (2 * cfg.window, cap) if cap else None
+
+    if cfg.loss == "hs":
+        from glint_word2vec_tpu.ops.hs import hs_step_core
+
+        def step(params, batch, negatives, alpha):
+            return hs_step_core(
+                params, batch["centers"], batch["contexts"], batch["mask"],
+                alpha, batch["path_table"], hs_shape, cfg.sigmoid_mode,
+                compute_dtype, with_metrics, center_runs=runs)
+
+        return StepChoice(hs_step_core, step, None, runs)
+
     fused, chain = cfg.fused_logits, cfg.bf16_chain
     if pool == 0:
         def step(params, batch, negatives, alpha):
@@ -355,14 +426,6 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
                           shared_pool if cfg.sync_every == 1 else window_pools,
                           None)
 
-    # syn0's update, one scatter row per center run (the step chooses per
-    # batch; ops/sgns.scatter_add_by_runs), where one program sees the batch
-    # whole: a batch split over a data axis or fed in per-process segments
-    # cuts runs at every seam
-    runs = None
-    if plan.num_data == 1 and feed_segments == 1:
-        cap = _center_run_cap(cfg.window, cfg.pairs_per_batch)
-        runs = (2 * cfg.window, cap) if cap else None
     # syn1's, one row per run of the pairs sorted by context: the step sorts,
     # so the feed's order and its segments do not matter, a data axis does
     context_runs = None
@@ -699,16 +762,28 @@ class Trainer:
             raise ValueError(
                 f"embedding_partition='cols' needs the padded vector dim "
                 f"{self.padded_dim} divisible by num_model={plan.num_model}")
-        self.table = build_alias_table(vocab.counts, config.sample_power,
-                                       workers=config.io_workers)
-        # replicated device copies, passed into the jitted chunk as ARGUMENTS every
-        # dispatch — closure-captured constants take a catastrophically slow gather
-        # path on TPU (see ops/prng.py)
-        tabs = put_global(plan.replicated,
-                          {"prob": np.asarray(self.table.prob),
-                           "alias": np.asarray(self.table.alias)})
-        self._table_prob = tabs["prob"]
-        self._table_alias = tabs["alias"]
+        if config.loss == "hs":
+            # runtime twin of config's mesh refusal: a plan handed in
+            if plan.mesh.devices.size > 1:
+                raise ValueError(
+                    f"loss='hs' trains on one device, and the plan holds "
+                    f"{plan.mesh.devices.size}: a path's nodes would live on "
+                    "other chips (no sharded path side yet)")
+            # no negatives are drawn: no alias table, and the chunk takes none
+            self.table = self._table_prob = self._table_alias = None
+            self._sampler_args: tuple = ()
+        else:
+            self.table = build_alias_table(vocab.counts, config.sample_power,
+                                           workers=config.io_workers)
+            # replicated device copies, passed into the jitted chunk as ARGUMENTS
+            # every dispatch — closure-captured constants take a catastrophically
+            # slow gather path on TPU (see ops/prng.py)
+            tabs = put_global(plan.replicated,
+                              {"prob": np.asarray(self.table.prob),
+                               "alias": np.asarray(self.table.alias)})
+            self._table_prob = tabs["prob"]
+            self._table_alias = tabs["alias"]
+            self._sampler_args = (self._table_prob, self._table_alias)
         self._root_key = jax.random.key(config.seed)
         if params is None:
             params = init_embeddings(
@@ -799,6 +874,16 @@ class Trainer:
         self._subword_shape = None
         self._step_extra: tuple = ()
         self.subword_table_time = 0.0
+        # the hierarchical-softmax path table (config.loss="hs"): every word's
+        # path through the vocabulary's Huffman tree, in the row table's
+        # format and placed the same way (_place_path_table)
+        self._hs_shape = None
+        self.hs_tree_time = 0.0
+        if config.loss == "hs" and self._feed_segments > 1:
+            raise ValueError(
+                "loss='hs' needs the batch whole in one program: a feed in "
+                "per-process segments (shard_input on several processes) is "
+                "not wired to the path side")
         if config.subword:
             if self._feed_segments > 1:
                 raise ValueError(
@@ -952,6 +1037,8 @@ class Trainer:
             jax.default_backend() == "cpu" and plan.mesh.devices.size > 1)
         if config.subword:
             self._place_subword_table()
+        if config.loss == "hs":
+            self._place_path_table()
         self._build_step_twins()
 
     # -- setup -------------------------------------------------------------------------
@@ -1010,6 +1097,24 @@ class Trainer:
         T = int(np.ceil(0.93 * cfg.pairs_per_batch / self.plan.num_data / rate))
         return max(T, 64)
 
+    def _put_row_table(self, table) -> tuple:
+        """A row table (data/subword.SubwordRows: the subword lists, or the
+        hierarchical-softmax paths) on the device, waited for: ``(offsets,
+        rows, counts)``, the chunk's table arguments. The groups' count is an
+        argument's shape of the step: rounded up (2^20 groups, 3% of the
+        published subword vocabulary's 10.7 M), a vocabulary that differs by
+        a few words, as the benchmark's does from seed to seed, compiles the
+        same program and finds it in the compile cache."""
+        from glint_word2vec_tpu.data.subword import NO_ROW
+        groups = np.full((-(-table.rows.shape[0] // _SUBWORD_GROUPS_UNIT)
+                          * _SUBWORD_GROUPS_UNIT, table.rows.shape[1]),
+                         NO_ROW, np.int32)
+        groups[:table.rows.shape[0]] = table.rows
+        placed = put_global(self.plan.replicated, {
+            "offsets": table.offsets, "rows": groups, "counts": table.counts})
+        jax.block_until_ready(placed)
+        return placed["offsets"], placed["rows"], placed["counts"]
+
     def _place_subword_table(self) -> None:
         """Build the vocabulary's row table (data/subword.py) and put it on
         the device: span ``vocab.subword_table``, its seconds kept in
@@ -1017,8 +1122,7 @@ class Trainer:
         center-run capacity the plain step has and, under it, the word
         capacity :func:`_word_cap` derives from the counts; a CBOW token
         block's takes the slot capacity :func:`_slot_cap` derives from them."""
-        from glint_word2vec_tpu.data.subword import (
-            GROUP, NO_ROW, build_subword_table)
+        from glint_word2vec_tpu.data.subword import GROUP, build_subword_table
         from glint_word2vec_tpu.ops import subword as sw
         cfg = self.config
         t0 = time.perf_counter()
@@ -1027,22 +1131,9 @@ class Trainer:
             rows = build_subword_table(
                 self.vocab.words, cfg.subword_min_n, cfg.subword_max_n,
                 cfg.subword_buckets)
-            # the groups' count is an argument's shape of the step: rounded
-            # up (2^20 groups, 3% of the published vocabulary's 10.7 M), a
-            # vocabulary that differs by a few words, as the benchmark's
-            # does from seed to seed, compiles the same program and finds it
-            # in the compile cache
-            groups = np.full((-(-rows.rows.shape[0] // _SUBWORD_GROUPS_UNIT)
-                              * _SUBWORD_GROUPS_UNIT, rows.rows.shape[1]),
-                             NO_ROW, np.int32)
-            groups[:rows.rows.shape[0]] = rows.rows
-            placed = put_global(self.plan.replicated, {
-                "offsets": rows.offsets, "rows": groups,
-                "counts": rows.counts})
-            jax.block_until_ready(placed)
+            self._step_extra = self._put_row_table(rows)
             span.set(slots=rows.slots)
         self.subword_table_time = time.perf_counter() - t0
-        self._step_extra = (placed["offsets"], placed["rows"], placed["counts"])
         if self._banded_cbow:
             # the row source of a token block (ops/cbow_banded.py): every
             # token slot of the block reads its own word's list, and the
@@ -1070,6 +1161,34 @@ class Trainer:
         logger.info("subword table: %d words, %d slots, %s in %.2fs",
                     self.vocab.size, rows.slots, self._subword_shape,
                     self.subword_table_time)
+
+    def _place_path_table(self) -> None:
+        """Build the vocabulary's Huffman tree and every word's path
+        (data/huffman.py) and put the path table on the device: span
+        ``vocab.huffman_tree``, its seconds kept in ``hs_tree_time``; the
+        step's shape (ops/hs.py) takes the capacities :func:`_hs_caps`
+        derives from the counts and the paths' lengths."""
+        from glint_word2vec_tpu.data.huffman import build_path_table
+        from glint_word2vec_tpu.data.subword import GROUP
+        from glint_word2vec_tpu.ops.hs import HsShape
+        cfg = self.config
+        t0 = time.perf_counter()
+        with self._tracer.span("vocab.huffman_tree",
+                               words=self.vocab.size) as span:
+            paths = build_path_table(self.vocab.counts)
+            self._step_extra = self._put_row_table(paths)
+            span.set(nodes=self.vocab.size - 1,
+                     max_code_len=int(paths.counts.max()), slots=paths.slots)
+        self.hs_tree_time = time.perf_counter() - t0
+        word_cap, slot_cap = (0, 0) if self.plan.num_data > 1 else _hs_caps(
+            self.vocab.counts, self.vocab.train_words_count,
+            cfg.subsample_ratio, cfg.window, cfg.pairs_per_batch,
+            paths.counts, paths.max_groups * GROUP)
+        self._hs_shape = HsShape(paths.max_groups, _HS_MAX_RUN, word_cap,
+                                 slot_cap)
+        logger.info("huffman tree: %d words, code lengths up to %d, %d slots, "
+                    "%s in %.2fs", self.vocab.size, int(paths.counts.max()),
+                    paths.slots, self._hs_shape, self.hs_tree_time)
 
     def _pad_params(self, params: EmbeddingPair) -> EmbeddingPair:
         def pad(a, rows):
@@ -1299,7 +1418,7 @@ class Trainer:
         construction, and again when a recovery engages ``max_row_norm``)."""
         cfg = self.config
         # select_step's SGNS shared-pool row reads it; CBOW has no such row
-        self._context_cap = 0 if cfg.cbow else _context_run_cap(
+        self._context_cap = 0 if cfg.cbow or cfg.loss == "hs" else _context_run_cap(
             self.vocab.counts, self.vocab.train_words_count,
             cfg.subsample_ratio, cfg.window, cfg.pairs_per_batch)
         self._step_fn = self._build_step()
@@ -1307,8 +1426,9 @@ class Trainer:
         # CBOW): the paths whose loss side-channel is an extra full [B, pool]
         # pass (PERF.md §4); the CBOW+duplicate_scaling and per-pair paths
         # keep full metrics (their loss chains are not the measured slice)
-        self._step_fn_fast = (self._build_step(with_metrics=False)
-                              if self._shared_pool else self._step_fn)
+        self._step_fn_fast = (
+            self._build_step(with_metrics=False)
+            if self._shared_pool or cfg.loss == "hs" else self._step_fn)
 
     def _build_step(self, with_metrics: bool = True) -> Callable:
         """Build the jitted chunk function around the step :func:`select_step`
@@ -1350,13 +1470,16 @@ class Trainer:
         stab = self._stabilizers if self._stabilizers.enabled else None
         choice = select_step(cfg, self.plan, self._feed_segments,
                              self._context_cap, stab, with_metrics,
-                             subword_shape=self._subword_shape)
+                             subword_shape=self._subword_shape,
+                             hs_shape=self._hs_shape)
         inner, neg_shape = choice.step, choice.neg_shape
         # np.uint32 (not a Python int): any negative or 64-bit seed masked to 32 bits
         # lands in [2^31, 2^32), which jnp.asarray rejects under int32 canonicalization
         seed = np.uint32(cfg.seed & 0xFFFFFFFF)
         if self._banded_cbow:
             return self._build_banded_cbow_chunk(inner, neg_shape, seed)
+        if cfg.loss == "hs":
+            return self._build_hs_chunk(inner)
 
         is_cbow = cfg.cbow
         S = self._feed_segments
@@ -1519,6 +1642,35 @@ class Trainer:
             return jax.lax.scan(body, params, xs_all)
 
         return jax.jit(chunk, donate_argnums=(0,))
+
+    def _build_hs_chunk(self, inner: Callable) -> Callable:
+        """Jitted chunk for loss="hs": the pair feed's packed [K, 2, B] pairs
+        and [2, K] meta as the skip-gram chunk takes them, no sampler and no
+        alias tables, and the path table's three arrays as arguments
+        (``_step_extra``), as the subword chunks take the row table's."""
+        from glint_word2vec_tpu.ops.subword import SubwordTable
+
+        def hs_chunk(params, arrays, meta, base_step, *path_table):
+            alphas, reals = meta[0], meta[1]
+            # tie the feed to the params carry (the skip-gram chunk has the why)
+            params, arrays = jax.lax.optimization_barrier((params, arrays))
+            pos = jnp.arange(arrays["pairs"].shape[2], dtype=jnp.float32)
+            table = SubwordTable(*path_table)
+
+            def body(p, inp):
+                xs, alpha, real = inp
+                prs = xs["pairs"].astype(jnp.int32)
+                new_p, metrics = inner(
+                    p, {"centers": prs[0], "contexts": prs[1],
+                        "mask": (pos < real).astype(jnp.float32),
+                        "path_table": table}, None, alpha)
+                new_p = jax.lax.with_sharding_constraint(
+                    new_p, self._params_sharding)
+                return new_p, metrics
+
+            return jax.lax.scan(body, params, (arrays, alphas, reals))
+
+        return jax.jit(hs_chunk, donate_argnums=(0,))
 
     def _build_banded_cbow_chunk(self, inner: Callable, neg_shape: Callable,
                                  seed: np.uint32) -> Callable:
@@ -1843,8 +1995,7 @@ class Trainer:
                     with self._tracer.span("dispatch.enqueue"):
                         self.params, metrics = self._dispatch_step_fn(real)(
                             self.params, stacked, meta_dev, base_dev,
-                            self._table_prob, self._table_alias,
-                            *self._step_extra)
+                            *self._sampler_args, *self._step_extra)
                 self.dispatch_time += time.perf_counter() - t0
                 self._after_dispatch()
                 self._finish_round(
@@ -3511,11 +3662,11 @@ class Trainer:
             # runs a probing fit under the guard to keep this path honest)
             with self._tracer.span("device_block") as blocked:
                 (loss_k, fpos_k, pairs_k, rows0_k, rows1_k, rows_sw_k,
-                 slots_sw_k, pos) = jax.device_get(
+                 slots_sw_k, nodes_hs_k, pos) = jax.device_get(
                     (metrics.loss, metrics.mean_f_pos, metrics.pairs,
                      metrics.syn0_rows, metrics.syn1_rows,
                      metrics.subword_rows, metrics.subword_slots,
-                     self.params.pos))
+                     metrics.hs_nodes, self.params.pos))
                 if rows0_k is not None and pairs_k[real - 1] > 0:
                     # how far the step coalesced each table's update: 1.0
                     # plain, heads over pairs where runs were summed first
@@ -3536,6 +3687,12 @@ class Trainer:
                     # padding: the slot capacity's engagement counter
                     blocked.set(subword_slots_per_pair=float(
                         slots_sw_k[real - 1] / pairs_k[real - 1]))
+                if nodes_hs_k is not None and pairs_k[real - 1] > 0:
+                    # live (pair, node) terms of a hierarchical-softmax step
+                    # (config.loss="hs") over its pairs: the mean path length
+                    # of the batch's contexts; a path cut short reads lower
+                    blocked.set(hs_path_nodes_per_pair=float(
+                        nodes_hs_k[real - 1] / pairs_k[real - 1]))
                 if pos is not None:
                     # how far the position weights have moved from the ones
                     # they start at, |pos − 1| / |1|: 0 = the leaf is not

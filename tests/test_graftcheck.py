@@ -244,7 +244,7 @@ def test_smoke_sweep_runs_clean_cli():
     assert len(lines) == 1, proc.stdout
     report = json.loads(lines[0])
     assert report["ok"] and report["tool"] == "graftcheck"
-    assert report["knobs"] == 98
+    assert report["knobs"] == 99
     assert report["unexplained_violations"] == 0
     assert report["configs_executed"] >= 200   # the thinned lattice
     assert report["refusal_signatures"], "refusal inventory must be nonempty"

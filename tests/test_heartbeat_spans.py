@@ -99,6 +99,8 @@ def _fit_and_check(trainer, sents):
     ("device_feed", dict(heartbeat_every_steps=4, device_pairgen=True, negative_pool=8)),
     ("banded_cbow_token_feed", dict(heartbeat_every_steps=4, cbow=True,
                                     cbow_update="banded", negative_pool=8)),
+    ("host_feed_hierarchical_softmax", dict(heartbeat_every_steps=4, loss="hs",
+                                            negatives=0)),
 ])
 def test_round_tree_in_one_process(path, cfg_kw, tmp_path):
     trainer, sents = _toy_trainer(telemetry_path=str(tmp_path / "run.jsonl"),

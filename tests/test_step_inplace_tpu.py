@@ -33,6 +33,14 @@ branch alone, the gather ordered before the conditional by its data (no copy).
 That the plain banded step and the subword skip-gram step are the programs
 they were is held where it is cheap, on their lowered text
 (``tests/test_cbow_subword.py``).
+
+The sixth (PR 37) is the hierarchical-softmax step at ``skipgram-hs-3m-300``'s
+size: syn1 (the tree's nodes) is read under one conditional (the word pieces'
+paths gathered once, or every pair's in chunks) and written under a second
+that the first's results order after it. One conditional holding a form's reads
+AND its writes copied the table in and out of the per-pair branch's scatter
+loop, once an iteration of 128 (two ``copy f32[3000000,384]`` in the loop's
+body, my compile for the described v5e, PR 37).
 """
 
 import os
@@ -235,3 +243,46 @@ def test_the_health_probe_holds_no_scatter(topo, one_chip, mesh_shape):
     if mesh_shape:
         reduced = re.findall(r"= (\S+) all-reduce\(", compiled)
         assert reduced and all(re.fullmatch(r"\(?\w+\[\]\S*", t) for t in reduced), reduced
+
+
+@pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
+def test_no_table_is_copied_under_hierarchical_softmax(one_chip, with_metrics):
+    from glint_word2vec_tpu.ops.hs import HsShape, hs_step_core
+    from glint_word2vec_tpu.ops.subword import SubwordTable
+
+    groups = 11 << 20
+    # what the trainer derives at this size (train/trainer.py _hs_caps over the
+    # benchmark's Zipf counts and the AUTO subsample; PERF.md §6, PR 37)
+    shape = HsShape(max_groups=4, max_run=8, word_cap=18432, slot_cap=311296)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def chunk(params, table, centers, contexts, alphas):
+        def body(p, xs):
+            c, x, a = xs
+            return hs_step_core(p, c, x, jnp.ones(B, jnp.float32), a, table, shape,
+                                "exact", jnp.bfloat16, with_metrics,
+                                center_runs=RUNS["center_runs"])
+        return jax.lax.scan(body, params, (centers, contexts, alphas))
+
+    table = spec((V, D), jnp.float32)
+    program = jax.jit(chunk, donate_argnums=(0,)).lower(
+        EmbeddingPair(table, table),
+        SubwordTable(spec((V + 2,), jnp.int32), spec((groups, 8), jnp.int32),
+                     spec((V + 1,), jnp.int32)),
+        spec((K, B), jnp.int32), spec((K, B), jnp.int32),
+        spec((K,), jnp.float32)).compile()
+    compiled = program.as_text()
+    # syn1's reads, syn1's writes, syn0's scatter by center runs
+    assert compiled.count(" conditional(") == 3
+    copies = [line.strip()[:120] for line in compiled.splitlines()
+              if re.search(rf"= f32\[{V},{D}\]\S* copy\(", line)]
+    assert not copies, copies
+    # syn1: the sorted slots' scatter and the per-pair loop's; syn0: by runs and plain
+    assert len(re.findall(rf"= f32\[{V},{D}\]\S* scatter\(", compiled)) == 4
+    # the pieces' paths are one [18432 · 32, 384] bfloat16 gather, and no
+    # [65536 · 32, 384] block exists in either form
+    assert re.search(r"bf16\[589824,384\]", compiled)
+    assert not re.search(r"\[2097152,384\]|\[65536,32,384\]", compiled)
+    assert program.memory_analysis().temp_size_in_bytes < 2_600_000_000

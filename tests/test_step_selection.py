@@ -169,6 +169,37 @@ def test_step_selection(row, with_metrics, cores):
                             ("params", {}, "negatives", "alpha"))
 
 
+@pytest.mark.parametrize("segments", [1, 2], ids=["whole-batch", "two-feed-segments"])
+@pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
+def test_step_selection_hierarchical_softmax(with_metrics, segments, monkeypatch):
+    """loss="hs": the path step, no negatives to draw, syn0's update by center
+    runs where one program sees the batch whole; no stabilizers reach it
+    (config refuses them beside it)."""
+    from glint_word2vec_tpu.ops import hs
+
+    calls = []
+    real = inspect.signature(hs.hs_step_core)
+
+    def stub(*args, **kwargs):
+        calls.append(real.bind(*args, **kwargs).arguments)
+        return "out"
+
+    monkeypatch.setattr(hs, "hs_step_core", stub)
+    cfg = Word2VecConfig(**{**BASE, "negatives": 0}, loss="hs", window=5)
+    assert cfg.negative_pool == 0
+    shape = hs.HsShape(2, 8, 16, 128)
+    choice = select_step(cfg, make_mesh(1, 1), segments, 0, None, with_metrics,
+                         hs_shape=shape)
+    runs = RUNS_W5 if segments == 1 else None
+    assert choice.core is stub and choice.neg_shape is None
+    assert choice.center_runs == runs and choice.context_runs is None
+    assert choice.step("params", _Batch(path_table="table"), None, "alpha") == "out"
+    bound, = calls
+    assert bound["table"] == "table" and bound["shape"] is shape
+    assert bound["with_metrics"] == with_metrics and bound["center_runs"] == runs
+    assert bound["alpha"] == "alpha" and bound["params"] == "params"
+
+
 def test_context_runs_need_a_cap():
     """A vocabulary whose estimate passes half the batch (_context_run_cap
     gives 0) builds no context coalescing; syn0's stays as it is."""

@@ -67,6 +67,18 @@ REFUSAL_GROUPS: Dict[str, Dict[str, tuple]] = {
         "duplicate_scaling": (False, True),
         "max_row_norm": (0.0, 50.0),
     },
+    "hs-matrix": {
+        "loss": ("ns", "hs"),
+        "negatives": (0, 5),
+        "cbow": (False, True),
+        "subword": (False, True),
+        "subword_buckets": (64,),
+        "device_pairgen": (False, True),
+        "step_lowering": ("gspmd", "shard_map"),
+        "duplicate_scaling": (False, True),
+        "sharded_checkpoint": (False, True),
+        "max_row_norm": (0.0, 50.0),
+    },
     "position-weights": {
         "cbow_position_weights": (False, True),
         "cbow": (False, True),

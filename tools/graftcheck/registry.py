@@ -56,7 +56,10 @@ KNOBS = {k.name: k for k in [
     _K("max_sentence_length", (10, 1000), invalid=0, dispatch_inert=True),
     _K("window", (1, 2, 5, 127), invalid=0),
     _K("batch_size", (1, 50), invalid=0, dispatch_inert=True),
-    _K("negatives", (1, 5, 25), invalid=0),
+    # 0 is the value hierarchical softmax needs (loss="hs") and negative
+    # sampling refuses: a combination, so it lives in the domain and the
+    # range tier's sample is -1
+    _K("negatives", (0, 1, 5, 25), invalid=-1),
     _K("subsample_ratio", (-1.0, 0.0, 1e-4, 1e-3, 1.0), invalid=-0.5,
        auto=-1.0),
     _K("seed", (0, 1, 2 ** 31), dispatch_inert=True),
@@ -95,6 +98,10 @@ KNOBS = {k.name: k for k in [
     _K("subword_min_n", (3, 2)),
     _K("subword_max_n", (6, 4)),
     _K("subword_buckets", (2_000_000, 64)),
+    # --- hierarchical softmax (ISSUE 37): selects the step whose output side
+    # is the context's path through the vocabulary's Huffman tree, and
+    # carries config._refuse_beside_hs's matrix
+    _K("loss", ("ns", "hs"), invalid="nce"),
     _K("shuffle", (True, False), dispatch_inert=True),
     _K("min_alpha_factor", (1e-4, 1.0), dispatch_inert=True),
     _K("decay_interval_words", (1, 10_000), dispatch_inert=True),
