@@ -19,6 +19,8 @@ on device.
 from __future__ import annotations
 
 import logging
+import math
+import os
 from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -326,7 +328,14 @@ class Word2VecModel:
         to row ids (dictionary lookups) and hands over one ``int32[Q]``
         array; the program gathers the rows from the table it already
         holds, normalises them, and runs the [chunk, V] cosine matmul and
-        the top-k (:func:`_gather_topk_batch`). No per-query device
+        the top-k (:func:`_gather_topk_batch`). The scores are ranked in
+        two exact stages: the maxima of runs of ~sqrt(V / k) columns, then
+        the k winning runs' members (:func:`_two_stage_topk`; what comes
+        back is ``lax.top_k``'s over the same scores, ties included); one
+        ``lax.top_k`` over all V where the vocabulary is small or the table
+        is partitioned by rows. On a TPU the chunk's queries are handed
+        over in whole tiles of 8 rows (:func:`_topk_dispatch`), so one
+        program serves 8 batch sizes. No per-query device
         operation: a launch costs more than this scan's share of a query.
         A chunk that holds a vector query (``np.ndarray``; analogies)
         additionally sends one float32 ``[Q, D]`` block, and the program
@@ -335,7 +344,8 @@ class Word2VecModel:
         program per batch size is compiled only where vectors are sent.
         Word queries exclude themselves (mllib:621-629); vector queries do not.
         An unknown word raises ``KeyError`` before anything is dispatched.
-        ``chunk`` bounds device memory at chunk·V·4 bytes of scores.
+        ``chunk`` bounds device memory at chunk·V·4 bytes of scores: the
+        score block is still written whole, whatever ranks it.
 
         ``ann=True`` routes the batch through the attached IVF index
         (:meth:`attach_ann`) instead of the exact full-vocab scan — the
@@ -386,13 +396,21 @@ class Word2VecModel:
             # device operations issued to build the query blocks: one put
             # per host array above, whatever the number of queries
             sp.set(ops=sum(1 + (b is not None) for _, _, b in parts))
+        # scores a query's selection ranks: every row where one top-k (on
+        # the device or the host) ranks them, far fewer where two stages do
+        topk_rows = (self._full0.shape[0] if _host_topk() else _topk_rows(
+            self._full0.shape[0], k,
+            not self._full0.sharding.is_fully_replicated))
         for lo, part_ids, part_block in parts:
-            with tracer.span("serve.scan_enqueue", queries=len(part_ids)):
+            with tracer.span("serve.scan_enqueue", queries=len(part_ids),
+                             topk_rows=topk_rows):
                 scores, idxs = _topk_dispatch(
                     self._full0, self._norms, part_ids, part_block,
                     k, self.num_words)
             with tracer.span("serve.result_fetch"):
-                scores, idxs = np.asarray(scores), np.asarray(idxs)
+                # rows past the chunk's queries are _topk_dispatch's padding
+                scores = np.asarray(scores)[:len(part_ids)]
+                idxs = np.asarray(idxs)[:len(part_ids)]
             with tracer.span("serve.reply_build"):
                 out.extend(self._replies(
                     words[lo:lo + chunk], scores, idxs, num))
@@ -687,18 +705,110 @@ def _cosine_batch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
                          cos, -jnp.inf)
 
 
-@partial(jax.jit, static_argnames=("k", "valid_rows"))
+# The two-stage selection's group sizes: whole lane tiles (128 columns) of
+# the [Q, V] score block; PR 38's probe timed 256-4,096 at 3M rows (PERF.md §6)
+_TOPK_GROUPS = (128, 256, 512, 1024, 2048, 4096)
+
+
+def _topk_group(num_rows: int, k: int, partitioned: bool) -> int:
+    """Columns a group of :func:`_two_stage_topk` holds over a ``[Q, num_rows]``
+    score block, or 0 where the single ``lax.top_k`` ranks the whole block.
+    The size is the grid's nearest to sqrt(num_rows / k), where the group
+    maxima and the k winning groups' members are together fewest. Handed
+    back: a table ``partitioned`` by rows (GSPMD shards the block along V,
+    and its shards' merge is the single top-k's), and a vocabulary that k
+    groups would hold whole anyway."""
+    if partitioned:
+        return 0
+    ideal = (num_rows / max(k, 1)) ** 0.5
+    group = min(_TOPK_GROUPS, key=lambda g: abs(math.log(g / max(ideal, 1.0))))
+    return group if k * group < num_rows else 0
+
+
+def _topk_rows(num_rows: int, k: int, partitioned: bool) -> int:
+    """Scores one query's selection ranks in the scan's program: the group
+    maxima and the k winning groups' members where the two stages run,
+    every row where the single top-k does (``serve.scan_enqueue``'s
+    ``topk_rows``)."""
+    group = _topk_group(num_rows, k, partitioned)
+    return -(-num_rows // group) + k * group if group else num_rows
+
+
+def _grouped_scores(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
+                    valid_rows: int, group: int) -> jax.Array:
+    """:func:`_cosine_batch`'s score block widened to whole groups of
+    ``group`` columns, the added columns -inf as every column past
+    ``valid_rows`` is. On the TPU it is the TABLE's rows that are padded,
+    which the compiler folds into the matmul's read of it: no copy of the
+    table, the scores are the unpadded block's bit for bit (PR 38's probe),
+    and the block is written at its final width, where a pad or a slice of
+    the block itself is a copy of 4·Q·V bytes. Elsewhere it is the padded
+    table that would be the copy, and the block is padded."""
+    extra = -syn0.shape[0] % group
+    if jax.default_backend() != "tpu":
+        return jnp.pad(_cosine_batch(syn0, norms, queries, valid_rows),
+                       ((0, 0), (0, extra)), constant_values=-jnp.inf)
+    return _cosine_batch(jnp.pad(syn0, ((0, extra), (0, 0))),
+                         jnp.pad(norms, (0, extra)), queries, valid_rows)
+
+
+def _two_stage_topk(cos: jax.Array, k: int,
+                    group: int) -> Tuple[jax.Array, jax.Array]:
+    """``lax.top_k(cos, k)``, scores and ids bit for bit, ties included,
+    ranking G + k·group scores a row and not all of them: the maximum of
+    each of the G runs of ``group`` columns, the k runs with the largest
+    maxima, then those runs' members.
+
+    Why it is exact: with t the k-th largest score, fewer than k runs have a
+    maximum over t and all of them are taken; a score equal to t that
+    ``lax.top_k`` returns (it breaks ties toward the lower index) lies in one
+    of those or in one of the lowest-numbered runs whose maximum is t, and
+    the runs are numbered in column order, so the k runs taken (ties toward
+    the lower run) hold all k answers. The members are laid out in ascending
+    column, so the last top-k breaks its ties as the single one does."""
+    rows, width = cos.shape
+    runs = width // group
+    with jax.named_scope("scan.group_max"):
+        # 8 rows by 128 columns is the tile the TPU keeps the block in, so
+        # over whole tiles of rows (what _topk_dispatch hands over) or one
+        # row this view is the block as it lies and the maxima read it once;
+        # [rows, runs, group] is first copied into another tiling (4.9 ms of
+        # a 9.3 ms scan at [32, 3M], PERF.md §6). Any split of the rows
+        # gives the same maxima.
+        sub = math.gcd(rows, 8)
+        top = cos.reshape(rows // sub, sub, runs, group // 128, 128).max(
+            axis=(3, 4)).reshape(rows, runs)
+    with jax.named_scope("scan.topk"):
+        _, won = jax.lax.top_k(top, k)
+        won = jax.lax.sort(won, dimension=1)
+        members = jax.vmap(lambda row, starts: jax.vmap(
+            lambda s: jax.lax.dynamic_slice(row, (s,), (group,)))(starts))(
+                cos, won * group)
+        scores, pos = jax.lax.top_k(members.reshape(rows, k * group), k)
+        run = jnp.take_along_axis(won, pos // group, axis=1)
+        return scores, run * group + pos % group
+
+
+@partial(jax.jit, static_argnames=("k", "valid_rows", "partitioned"))
 def _cosine_topk_batch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
-                       k: int, valid_rows: int) -> Tuple[jax.Array, jax.Array]:
+                       k: int, valid_rows: int, partitioned: bool = False
+                       ) -> Tuple[jax.Array, jax.Array]:
     """cosine(rows, q) top-k over a [Q, D] query matrix in ONE dispatch:
     normalize queries (snrm2/sscal analog, mllib:589-596), the [Q, V] cosine
     matrix as a single MXU matmul (mllib:598's matvec, batched), divide by row
     norms with zero-norm → 0 (mllib:601-609), batched device top-k instead of
     the client-side BoundedPriorityQueue scan (mllib:611-619). Rows past
-    valid_rows are sharding padding, excluded outright."""
-    cos = _cosine_batch(syn0, norms, queries, valid_rows)
-    with jax.named_scope("scan.topk"):
-        return jax.lax.top_k(cos, k)
+    valid_rows are sharding padding, excluded outright. The top-k is taken
+    in two exact stages (:func:`_two_stage_topk`) wherever
+    :func:`_topk_group` names a group size: what it returns is
+    ``lax.top_k``'s over the same scores."""
+    group = _topk_group(syn0.shape[0], k, partitioned)
+    if not group:
+        cos = _cosine_batch(syn0, norms, queries, valid_rows)
+        with jax.named_scope("scan.topk"):
+            return jax.lax.top_k(cos, k)
+    return _two_stage_topk(
+        _grouped_scores(syn0, norms, queries, valid_rows, group), k, group)
 
 
 @partial(jax.jit, static_argnames=("valid_rows", "partitioned"))
@@ -718,7 +828,7 @@ def _gather_topk_batch(syn0: jax.Array, norms: jax.Array, ids: jax.Array,
     the rows :func:`_query_block` reads from the table it already holds."""
     return _cosine_topk_batch(
         syn0, norms, _query_block(syn0, ids, block, partitioned), k,
-        valid_rows)
+        valid_rows, partitioned)
 
 
 # CPU route tiling: queries are sub-chunked so the fetched [q, V] score
@@ -747,12 +857,20 @@ def _cpu_topk_row(row: np.ndarray, k: int) -> Tuple[np.ndarray, np.ndarray]:
     return sc[order], cand[order]
 
 
+def _host_topk() -> bool:
+    """Whether :func:`_topk_dispatch` ranks the scores on the host."""
+    return (jax.default_backend() == "cpu"
+            and os.environ.get("GLINT_CPU_TOPK") == "argpartition")
+
+
 def _topk_dispatch(syn0: jax.Array, norms: jax.Array, ids: np.ndarray,
                    block: Optional[np.ndarray], k: int, valid_rows: int):
     """Route the cosine top-k of one chunk: ``ids`` (and ``block``, where
     the chunk holds vector queries) are host arrays, transferred by the one
-    call that runs the program. Default everywhere: ``lax.top_k`` in the
-    same program as the gather and the matmul. The host route — the same
+    call that runs the program. Default everywhere: the top-k in the same
+    program as the gather and the matmul; on a TPU over whole tiles of 8
+    query rows, so the result may hold padding rows after the chunk's
+    own. The host route — the same
     gather and cosine on the device, scores fetched in ~512 MB sub-chunks and
     ranked with chunked ``np.argpartition`` (:func:`_cpu_topk_row`),
     bit-identical results tie-order included (tested) — exists for CPU
@@ -761,10 +879,21 @@ def _topk_dispatch(syn0: jax.Array, norms: jax.Array, ids: np.ndarray,
     route wins 2-3x at every shape tried), so it is opt-in: set
     ``GLINT_CPU_TOPK=argpartition`` on toolchains that still exhibit the
     sort lowering."""
-    import os
     partitioned = not syn0.sharding.is_fully_replicated
-    if (jax.default_backend() != "cpu"
-            or os.environ.get("GLINT_CPU_TOPK") != "argpartition"):
+    if not _host_topk():
+        extra = -ids.shape[0] % 8
+        if jax.default_backend() == "tpu" and ids.shape[0] > 1 and extra:
+            # a TPU keeps the [Q, V] score block in tiles of 8 rows, so whole
+            # tiles cost the scan nothing; they are what the two-stage
+            # selection reads in place, and 9 programs serve the 64 batch
+            # sizes a full batcher sends where 64 did (each ~0.15 s to load
+            # and 1-4 s to compile: the benchmark's set-up). The last query
+            # is repeated; the caller keeps the first ``len(ids)`` rows. A
+            # single query is a matrix-vector product of its own and stays.
+            ids = np.concatenate([ids, np.repeat(ids[-1:], extra)])
+            if block is not None:
+                block = np.concatenate(
+                    [block, np.zeros((extra, block.shape[1]), block.dtype)])
         # device arrays: this returns once the program is enqueued, and the
         # caller's fetch is where the host waits for it
         return _gather_topk_batch(

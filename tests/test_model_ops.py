@@ -272,6 +272,145 @@ def test_gathered_batch_equals_row_by_row(batch, table, route, monkeypatch):
     model.stop()
 
 
+# -- the top-k in two exact stages: lax.top_k's scores and ids, ties included ------
+
+
+def _topk_case(case: str, rows: int):
+    """(syn0, norms, queries, k, valid_rows, two_stage) of one selection
+    case. k = 11 takes groups of 128 columns at these sizes: 1,408 members,
+    so 1,409 rows are the fewest the two stages take."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(len(case) * 100 + rows)
+    num_rows = {"multiple_of_group": 2048, "with_tail": 1700,
+                "just_over_hand_back": 1409, "just_under_hand_back": 1408,
+                }.get(case, 2100)
+    k, valid_rows, dim = 11, num_rows, 16
+    syn0 = rng.standard_normal((num_rows, dim)).astype(np.float32)
+    queries = syn0[rng.integers(0, num_rows, rows)] + 0.1
+    if case.startswith("zero_ties"):
+        # every row but a few points away from every query, so a zero-norm
+        # row's 0.0 outranks them and the k-th place falls inside the tie
+        way = rng.standard_normal(dim).astype(np.float32)
+        syn0 = (-way + 0.05 * rng.standard_normal((num_rows, dim))
+                ).astype(np.float32)
+        queries = (way + 0.05 * rng.standard_normal((rows, dim))
+                   ).astype(np.float32)
+        if case == "zero_ties_across_boundaries":
+            above = [3, 130, 700, 701, 1300, 1900, 2050, 2099]   # 3 places left
+            zero = [126, 127, 128, 129, 255, 256, 2047, 2048]
+        else:   # 14 groups whose maximum is the k-th score, and the tail
+            above = [5, 640, 1999, 2098, 2099]
+            zero = [128 * g + 127 - 9 * g for g in range(14)] + [2060]
+        syn0[above] = way
+        syn0[zero] = 0.0
+    elif case == "valid_rows_in_last_group":
+        num_rows, valid_rows = 1700, 1650       # -inf from the 13th group on
+        syn0, syn0[1640:1660] = syn0[:1700], queries[0]
+    elif case == "fewer_valid_rows_than_k":
+        valid_rows = 7
+    elif case == "zero_query":
+        queries[::2] = 0.0      # every score 0.0: the lowest ids win
+    syn0 = jnp.asarray(syn0)
+    return (syn0, jnp.linalg.norm(syn0, axis=1), jnp.asarray(queries), k,
+            valid_rows, case != "just_under_hand_back")
+
+
+@pytest.mark.parametrize("rows", [1, 34, 64])
+@pytest.mark.parametrize("case", [
+    "multiple_of_group", "with_tail", "just_over_hand_back",
+    "just_under_hand_back", "zero_ties_across_boundaries",
+    "zero_ties_in_more_than_k_groups", "valid_rows_in_last_group",
+    "fewer_valid_rows_than_k", "zero_query"])
+def test_two_stage_topk_is_lax_top_k(case, rows):
+    """Scores and ids bit-equal to ``lax.top_k`` of the same score block."""
+    import jax
+    from glint_word2vec_tpu.models import word2vec as w2v
+    syn0, norms, queries, k, valid_rows, two_stage = _topk_case(case, rows)
+    assert bool(w2v._topk_group(syn0.shape[0], k, False)) is two_stage
+    assert w2v._topk_group(syn0.shape[0], k, True) == 0
+    want_s, want_i = jax.lax.top_k(
+        w2v._cosine_batch(syn0, norms, queries, valid_rows), k)
+    if case.startswith("zero_ties"):
+        assert (np.asarray(want_s)[:, -1] == 0.0).all()    # k-th inside the tie
+    got_s, got_i = w2v._cosine_topk_batch(syn0, norms, queries, k, valid_rows)
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+    np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
+
+
+@pytest.mark.parametrize("rows", [1, 5, 34])
+def test_what_only_a_tpu_takes(rows, monkeypatch):
+    """The table's rows padded to whole groups under the matmul
+    (``_grouped_scores``) and the query rows to whole tiles of 8 on the host
+    (``_topk_dispatch``; one query stays one), the padding rows dropped after
+    the fetch. Run here by naming the backend, over a vocabulary of its own
+    so that no other test meets these traces."""
+    import jax
+    import jax.numpy as jnp
+    from glint_word2vec_tpu.models import word2vec as w2v
+    rng = np.random.default_rng(rows)
+    syn0 = rng.standard_normal((1733, 16)).astype(np.float32)
+    vocab = Vocabulary.from_words_and_counts(
+        [f"w{i}" for i in range(1733)], np.ones(1733, np.int64))
+    model = Word2VecModel(vocab, jnp.asarray(syn0))
+    queries = [f"w{i}" for i in rng.integers(0, 1733, rows - 1)] + [syn0[9]]
+    want = model.find_synonyms_batch(queries, 10)
+    whole = np.asarray(w2v._cosine_batch(
+        model._full0, model._norms, jnp.asarray(syn0[:rows]), 1700))
+    seen = []
+    real = w2v._gather_topk_batch
+    monkeypatch.setattr(w2v, "_gather_topk_batch",
+                        lambda *a: seen.append(a) or real(*a))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    block = np.asarray(w2v._grouped_scores(
+        model._full0, model._norms, jnp.asarray(syn0[:rows]), 1700, 128))
+    assert block.shape == (rows, 1792)
+    np.testing.assert_allclose(block[:, :1733], whole, rtol=0, atol=1e-6)
+    assert np.isneginf(block[:, 1700:]).all()
+    got = model.find_synonyms_batch(queries, 10)
+    (_, _, ids, vectors, k, _, _), = seen
+    padded = 1 if rows == 1 else -(-rows // 8) * 8
+    assert ids.shape == (padded,) and vectors.shape == (padded, 16)
+    assert (ids[rows - 1:] == -1).all() and not vectors[rows:].any()
+    assert len(got) == rows
+    for g, w in zip(got, want):
+        assert [word for word, _ in g] == [word for word, _ in w]
+        np.testing.assert_allclose([s for _, s in g], [s for _, s in w],
+                                   rtol=0, atol=1e-6)
+    model.stop()
+
+
+@pytest.mark.parametrize("batch", ["words_exclude_themselves", "vectors"])
+def test_two_stage_replies_are_the_single_top_k_s(batch, monkeypatch):
+    """Through ``find_synonyms_batch`` over a vocabulary the two stages
+    take: a word query asks for num + 1 and leaves itself out, a vector
+    query keeps every neighbour; both as the single ``lax.top_k`` replies."""
+    import jax
+    import jax.numpy as jnp
+    from glint_word2vec_tpu.models import word2vec as w2v
+    rng = np.random.default_rng(8)
+    syn0 = rng.standard_normal((1700, 16)).astype(np.float32)
+    syn0[[40, 900, 1699]] = 0.0
+    vocab = Vocabulary.from_words_and_counts(
+        [f"w{i}" for i in range(1700)], np.ones(1700, np.int64))
+    model = Word2VecModel(vocab, jnp.asarray(syn0))
+    queries = (["w3", "w1699", "w3", "w128"] if batch.startswith("words") else
+               [syn0[7] * 2.0, np.zeros(16, np.float32), syn0[1698] + 0.5])
+    assert w2v._topk_group(1700, 11, False) == 128
+    got = model.find_synonyms_batch(queries, 10)
+
+    def single(syn0, norms, ids, block, k, valid_rows, partitioned):
+        return jax.lax.top_k(w2v._gather_cosine_batch(
+            syn0, norms, ids, block, valid_rows, partitioned), k)
+
+    monkeypatch.setattr(w2v, "_gather_topk_batch", single)
+    assert got == model.find_synonyms_batch(queries, 10)
+    for q, reply in zip(queries, got):
+        assert len(reply) == 10
+        if isinstance(q, str):
+            assert q not in [word for word, _ in reply]
+    model.stop()
+
+
 def test_unknown_word_dispatches_nothing(monkeypatch):
     from glint_word2vec_tpu.models import word2vec as w2v
     model, _ = _scan_model("float32")

@@ -797,6 +797,20 @@ def test_row_fetch_ops_do_not_grow_with_the_batch(serve_tracer, kind, ops):
     model.stop()
 
 
+@pytest.mark.parametrize("v,topk_rows", [
+    (400, 400),                          # one lax.top_k over every row
+    (3000, -(-3000 // 128) + 6 * 128)])          # 24 run maxima, 6 runs' members
+def test_scan_enqueue_carries_topk_rows(serve_tracer, v, topk_rows):
+    """The scores one query's selection ranks, as the scan's program was
+    traced: all of a tiny vocabulary, G + k·b over the threshold."""
+    model = make_model(v=v, d=16)
+    serve_tracer.configure(enabled=True)
+    model.find_synonyms_batch(["w1", "w2", "w1"], 5)
+    assert [e["args"]["topk_rows"] for e in serve_tracer.events()
+            if e["name"] == "serve.scan_enqueue"] == [topk_rows]
+    model.stop()
+
+
 _COMPILED = []      # every backend compile of this process, by function
 
 
