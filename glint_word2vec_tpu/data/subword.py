@@ -12,7 +12,8 @@ twice, as fastText lists it. At ``min_n`` >= 2 fastText's one exclusion (a
 lone ``<`` or ``>``) never applies, so none is dropped here.
 
 :func:`ngram_buckets` is the plain form for one string (a query for a word the
-vocabulary has never seen). :func:`build_subword_table` is the same function
+vocabulary has never seen) and :func:`ngram_lists` a batch of them as one
+padded block. :func:`build_subword_table` is the same function
 over a whole vocabulary (2.5M words, 76M n-grams: seconds, where a Python loop
 takes minutes), laid out for the step (ops/subword.py).
 """
@@ -47,11 +48,47 @@ def fnv1a(data: bytes) -> int:
 
 
 def ngram_buckets(word: str, min_n: int, max_n: int, buckets: int) -> List[int]:
-    """Bucket of every n-gram of ``"<word>"``, by start then by length."""
-    marked = "<" + word + ">"
-    return [fnv1a(marked[i:i + n].encode("utf-8")) % buckets
-            for i in range(len(marked))
-            for n in range(min_n, max_n + 1) if i + n <= len(marked)]
+    """Bucket of every n-gram of ``"<word>"``, by start then by length. The
+    n-gram of n + 1 characters from a start is that of n continued, so a
+    start's characters are hashed once."""
+    chars = [c.encode("utf-8") for c in "<" + word + ">"]
+    out: List[int] = []
+    for i in range(len(chars) - min_n + 1):
+        h = FNV_OFFSET
+        for n, char in enumerate(chars[i:i + max_n], 1):
+            for b in char:
+                h = ((h ^ (b if b < 128 else b | 0xFFFFFF00)) * FNV_PRIME) & 0xFFFFFFFF
+            if n >= min_n:
+                out.append(h % buckets)
+    return out
+
+
+def list_capacity(longest: int, min_n: int, max_n: int) -> int:
+    """Slots of a query's bucket list (:func:`ngram_lists`): the n-grams of a
+    string one character longer than ``longest``, the vocabulary's longest
+    word (a misspelling inserts one), in whole groups of :data:`GROUP`."""
+    marked = longest + 3
+    count = sum(max(marked - n + 1, 0) for n in range(min_n, max_n + 1))
+    return max(GROUP, -(-count // GROUP) * GROUP)
+
+
+def ngram_lists(strings: Sequence[str], min_n: int, max_n: int, buckets: int,
+                capacity: int):
+    """The bucket lists of a batch's unseen strings as one block for the
+    scan's program (models/word2vec.py): int32 [len(strings), capacity], row
+    i the buckets of strings[i] (ids into the BUCKET rows, not into syn0),
+    :data:`NO_ROW` past them; and the positions of the strings whose list
+    is longer than ``capacity`` (their rows stay empty: the caller sends them
+    round another way)."""
+    out = np.full((len(strings), capacity), NO_ROW, np.int32)
+    over: List[int] = []
+    for i, s in enumerate(strings):
+        ids = ngram_buckets(s, min_n, max_n, buckets)
+        if len(ids) > capacity:
+            over.append(i)
+        else:
+            out[i, :len(ids)] = ids
+    return out, over
 
 
 class SubwordRows(NamedTuple):
@@ -77,6 +114,23 @@ class SubwordRows(NamedTuple):
         """Word w's live rows (the host's view; tests and the model use it)."""
         lo, hi = int(self.offsets[w]), int(self.offsets[w + 1])
         return self.rows[lo:hi].reshape(-1)[:int(self.counts[w])]
+
+
+# a row table's groups go to the device in whole units of this many: their
+# count is a shape of the programs that read the table (the trainer's step,
+# the model's composing block), and vocabularies a few words apart, as the
+# benchmark's are from seed to seed, then share one compiled program (2^20
+# groups are 3% of the published subword vocabulary's 10.7 M)
+GROUPS_UNIT = 1 << 20
+
+
+def groups_in_whole_units(rows: np.ndarray) -> np.ndarray:
+    """``rows`` [N, GROUP] padded with :data:`NO_ROW` groups to the next
+    multiple of :data:`GROUPS_UNIT`."""
+    out = np.full((-(-rows.shape[0] // GROUPS_UNIT) * GROUPS_UNIT,
+                   rows.shape[1]), NO_ROW, np.int32)
+    out[:rows.shape[0]] = rows
+    return out
 
 
 def build_subword_table(words: Sequence[str], min_n: int, max_n: int,

@@ -83,6 +83,7 @@ class Word2Vec:
             vocab=vocab, syn0=params.syn0, syn1=params.syn1,
             config=cfg, plan=trainer.plan, train_state=trainer.state,
             subword_buckets=trainer.subword_buckets(),
+            subword_rows=trainer.subword_rows(),
             position_weights=trainer.position_weights())
 
     @staticmethod
@@ -218,4 +219,5 @@ class Word2Vec:
             vocab=vocab, syn0=out.syn0, syn1=out.syn1, config=cfg,
             plan=trainer.plan, train_state=trainer.state,
             subword_buckets=trainer.subword_buckets(),
+            subword_rows=trainer.subword_rows(),
             position_weights=trainer.position_weights())

@@ -21,6 +21,7 @@ from __future__ import annotations
 import logging
 import math
 import os
+import time
 from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -28,9 +29,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from glint_word2vec_tpu.config import Word2VecConfig
+from glint_word2vec_tpu.data.subword import NO_ROW as _NO_ROW
 from glint_word2vec_tpu.data.vocab import Vocabulary
 from glint_word2vec_tpu.obs.spans import default_tracer
-from glint_word2vec_tpu.parallel.mesh import MeshPlan, pad_vocab_for_sharding
+from glint_word2vec_tpu.parallel.mesh import (
+    MeshPlan, pad_dim_to_lanes, pad_vocab_for_sharding)
 from glint_word2vec_tpu.train import checkpoint as ckpt
 
 logger = logging.getLogger("glint_word2vec_tpu")
@@ -49,6 +52,7 @@ class Word2VecModel:
         train_state: Optional["ckpt.TrainState"] = None,
         subword_buckets: Optional[jax.Array] = None,
         position_weights: Optional[np.ndarray] = None,
+        subword_rows=None,
     ):
         # a position-weighted CBOW model's third leaf
         # (config.cbow_position_weights): trained state kept so that a saved
@@ -57,12 +61,21 @@ class Word2VecModel:
                                  else np.asarray(position_weights, np.float32))
         # a subword model (config.subword) answers with COMPOSED vectors: what
         # arrives is syn0's words' own rows and its bucket rows; every query
-        # below scans h_w, the mean of a word's listed rows, made once here
+        # below scans h_w, the mean of a word's listed rows, made once here.
+        # Resident afterwards: the composed table (every scan), the bucket
+        # rows at whole lanes (an unseen string's vector is their mean, read
+        # inside the scan's program) and the words' own rows (``save`` writes
+        # what was trained, and the composed table cannot give them back)
         self._raw0 = self._buckets = None
+        self._list_cap = 0
+        self.compose_time = 0.0
+        # strings composed from n-grams inside the scan's program, the live
+        # bucket rows handed over for them, and the strings sent round
+        # through the vector block, over the model's life
+        self.query_counts = {"unseen": 0, "list_rows": 0, "overflow": 0}
         if subword_buckets is not None:
-            self._raw0 = jnp.asarray(syn0)[: vocab.size]
-            self._buckets = jnp.asarray(subword_buckets)
-            syn0 = self._compose(vocab, config, self._raw0, self._buckets)
+            syn0 = self._compose(vocab, config, syn0, subword_buckets,
+                                 subword_rows)
         Vp = (pad_vocab_for_sharding(vocab.size, plan.num_model)
               if plan is not None else vocab.size)
         if syn0.shape[0] not in (vocab.size, Vp):
@@ -105,38 +118,94 @@ class Word2VecModel:
         self._ann = None
         self._stopped = False
 
-    @staticmethod
-    def _compose(vocab: Vocabulary, config: Word2VecConfig, raw0: jax.Array,
-                 buckets: jax.Array) -> jax.Array:
-        """[V, D] h_w of every word (fastText's ``get_word_vector``), in row
-        blocks on the device (ops/subword.compose_vectors)."""
-        from glint_word2vec_tpu.data.subword import build_subword_table
-        from glint_word2vec_tpu.ops.subword import SubwordTable, compose_vectors
+    def _compose(self, vocab: Vocabulary, config: Word2VecConfig, syn0,
+                 buckets, subword_rows=None) -> jax.Array:
+        """[V, D] h_w of every word (fastText's ``get_word_vector``;
+        ``precomputeWordVectors``), in row blocks on the device
+        (ops/subword.compose_vectors): span ``model.compose``, its seconds
+        kept in ``compose_time`` (a constructor runs before any trace is
+        live). The two parts of the trained input table are read where they
+        lie, the words' own rows as slices and the bucket rows gathered from
+        their lane-padded form (ops/subword.lane_padded: made here, once,
+        and kept for the unseen strings' lists): no [V + K, D] array, and no
+        row-major copy of a table whose D is no multiple of 128. The row
+        table is ``subword_rows`` (an ops/subword.SubwordTable on the device
+        and its ``max_groups``, from a caller that has one: the estimator
+        after a fit) or built here, and freed with the call."""
+        from glint_word2vec_tpu.data.subword import (
+            build_subword_table, groups_in_whole_units, list_capacity)
+        from glint_word2vec_tpu.ops.subword import (
+            COMPOSE_BLOCK, SubwordTable, compose_vectors, lane_padded)
         if config is None or not config.subword:
             raise ValueError("subword_buckets need a config with subword=True")
         if buckets.shape[0] != config.subword_buckets:
             raise ValueError(
                 f"{buckets.shape[0]} bucket rows but config.subword_buckets is "
                 f"{config.subword_buckets}")
-        rows = build_subword_table(vocab.words, config.subword_min_n,
-                                   config.subword_max_n, config.subword_buckets)
-        table = SubwordTable(jnp.asarray(rows.offsets), jnp.asarray(rows.rows),
-                             jnp.asarray(rows.counts))
-        return compose_vectors(jnp.concatenate([raw0, buckets]), table,
-                               rows.max_groups, vocab.size)
+        t0 = time.perf_counter()
+        with default_tracer().span("model.compose", words=vocab.size) as sp:
+            raw0 = jnp.asarray(syn0)
+            self._raw0 = (raw0 if raw0.shape[0] == vocab.size
+                          else raw0[: vocab.size])
+            # [K, D] as a checkpoint holds them, or already at whole lanes
+            # (a trainer's own): then nothing is copied
+            buckets = jnp.asarray(buckets)
+            if buckets.shape[1] not in (
+                    raw0.shape[1], pad_dim_to_lanes(raw0.shape[1])):
+                raise ValueError(
+                    f"bucket rows are {buckets.shape[1]} wide but syn0's are "
+                    f"{raw0.shape[1]}")
+            self._buckets = lane_padded(buckets)
+            del raw0, buckets
+            if subword_rows is None:
+                rows = build_subword_table(
+                    vocab.words, config.subword_min_n, config.subword_max_n,
+                    config.subword_buckets)
+                subword_rows = (SubwordTable(
+                    jnp.asarray(rows.offsets),
+                    jnp.asarray(groups_in_whole_units(rows.rows)),
+                    jnp.asarray(rows.counts)), rows.max_groups)
+                del rows
+            table, max_groups = subword_rows
+            composed = compose_vectors(
+                self._raw0, self._buckets, table, max_groups)
+            composed.block_until_ready()
+            sp.set(slots=int(table.counts.sum()),
+                   blocks=-(-vocab.size // COMPOSE_BLOCK))
+        self._list_cap = list_capacity(
+            max(map(len, vocab.words)), config.subword_min_n,
+            config.subword_max_n)
+        self.compose_time = time.perf_counter() - t0
+        return composed
+
+    @property
+    def composes_unseen(self) -> bool:
+        """Whether a string the vocabulary lacks has a vector here, composed
+        from its n-grams (a subword model), and with it neighbours."""
+        return self._buckets is not None
+
+    @property
+    def subword_buckets(self) -> Optional[jax.Array]:
+        """A subword model's bucket rows [K, D] as trained, else None."""
+        if self._buckets is None:
+            return None
+        return self._buckets[:, : self._raw0.shape[1]]
 
     def _unseen_vector(self, word: str) -> np.ndarray:
         """A string the vocabulary has never seen, on a subword model: the
-        mean of its n-grams' bucket rows (zeros where it has none)."""
+        mean of its n-grams' bucket rows (zeros where it has none), fetched:
+        one device operation a string."""
         from glint_word2vec_tpu.data.subword import ngram_buckets
         cfg = self.config
         ids = ngram_buckets(word, cfg.subword_min_n, cfg.subword_max_n,
                             cfg.subword_buckets)
         if not ids:
             return np.zeros(self.vector_size, np.float32)
+        # the listed rows first, then their trained width: the other order
+        # would copy every bucket row
         return np.asarray(
-            self._buckets[jnp.asarray(ids, jnp.int32)].astype(jnp.float32)
-            .mean(axis=0))
+            self._buckets[jnp.asarray(ids, jnp.int32)][:, : self.vector_size]
+            .astype(jnp.float32).mean(axis=0))
 
     @property
     def syn0(self) -> jax.Array:
@@ -174,7 +243,7 @@ class Word2VecModel:
         the string's n-grams."""
         self._check_alive()
         idx = self.vocab.get(word)
-        if idx < 0 and self._buckets is not None:
+        if idx < 0 and self.composes_unseen:
             return self._unseen_vector(word)
         if idx < 0:
             raise KeyError(f"{word} not in vocabulary")
@@ -343,7 +412,17 @@ class Word2VecModel:
         block otherwise — chosen from what the chunk holds, so the second
         program per batch size is compiled only where vectors are sent.
         Word queries exclude themselves (mllib:621-629); vector queries do not.
-        An unknown word raises ``KeyError`` before anything is dispatched.
+        An unknown word raises ``KeyError`` before anything is dispatched,
+        except on a subword model, which answers a string its vocabulary
+        lacks as fastText's ``nn`` does: by the mean of its n-grams' bucket
+        rows, nothing excluded (it is no row of the table). The host hashes
+        the chunk's unseen strings into one ``int32[Q, L]`` block of bucket
+        ids (:meth:`_unseen_lists`) and the same one program averages the
+        listed rows where ``ids[i]`` says so: still no per-query device
+        operation, and a third (and fourth) program per batch size only
+        where such strings are sent. A string with more n-grams than the
+        block's capacity L is composed on its own and sent as a vector
+        (``query_counts["overflow"]`` counts them).
         ``chunk`` bounds device memory at chunk·V·4 bytes of scores: the
         score block is still written whole, whatever ranks it.
 
@@ -370,43 +449,70 @@ class Word2VecModel:
         # the host side of the scan, region by region
         with tracer.span("serve.row_fetch") as sp:
             words: List[Optional[str]] = []
-            ids = np.full(len(queries), -1, np.int32)
+            ids = np.full(len(queries), _VECTOR, np.int32)
             block: Optional[np.ndarray] = None
+            unseen: List[int] = []
+
+            def vector_row(i: int, row) -> None:
+                nonlocal block
+                if block is None:
+                    block = np.zeros((len(queries), self.vector_size), np.float32)
+                ids[i] = _VECTOR
+                block[i] = row
+
             for i, q in enumerate(queries):
                 if isinstance(q, str):
                     idx = self.vocab.get(q)
-                    if idx < 0:
+                    if idx < 0 and not self.composes_unseen:
                         raise KeyError(f"{q} not in vocabulary")
                     words.append(q)
-                    ids[i] = idx
+                    if idx < 0:
+                        unseen.append(i)
+                    else:
+                        ids[i] = idx
                 else:
-                    if block is None:
-                        block = np.zeros(
-                            (len(queries), self.vector_size), np.float32)
                     words.append(None)
-                    block[i] = q
-            # what each chunk's program is handed: its ids, and the vector
-            # block only where the chunk holds a vector query
+                    vector_row(i, q)
+            lists, overflow = self._unseen_lists(queries, unseen)
+            if lists is not None:
+                ids[unseen] = _LISTED
+            for i in overflow:
+                vector_row(i, self._unseen_vector(queries[i]))
+            counts = {}
+            if self.composes_unseen:
+                counts = {"unseen": len(unseen) - len(overflow),
+                          "list_rows": (0 if lists is None
+                                        else int((lists != _NO_ROW).sum())),
+                          "overflow": len(overflow)}
+                for name, n in counts.items():
+                    self.query_counts[name] += n
+            # what each chunk's program is handed: its ids, the list block
+            # only where the chunk holds an unseen string, the vector block
+            # only where it holds a vector query
             parts = []
             for lo in range(0, len(queries), chunk):
                 part_ids = ids[lo:lo + chunk]
-                vectors = block is not None and part_ids.min() < 0
-                parts.append(
-                    (lo, part_ids, block[lo:lo + chunk] if vectors else None))
+                parts.append((
+                    lo, part_ids,
+                    block[lo:lo + chunk] if (part_ids == _VECTOR).any() else None,
+                    lists[lo:lo + chunk] if (part_ids == _LISTED).any() else None))
             # device operations issued to build the query blocks: one put
             # per host array above, whatever the number of queries
-            sp.set(ops=sum(1 + (b is not None) for _, _, b in parts))
+            sp.set(ops=sum(1 + (b is not None) + (l is not None)
+                           for _, _, b, l in parts), **counts)
         # scores a query's selection ranks: every row where one top-k (on
         # the device or the host) ranks them, far fewer where two stages do
         topk_rows = (self._full0.shape[0] if _host_topk() else _topk_rows(
             self._full0.shape[0], k,
             not self._full0.sharding.is_fully_replicated))
-        for lo, part_ids, part_block in parts:
+        for lo, part_ids, part_block, part_lists in parts:
             with tracer.span("serve.scan_enqueue", queries=len(part_ids),
                              topk_rows=topk_rows):
                 scores, idxs = _topk_dispatch(
                     self._full0, self._norms, part_ids, part_block,
-                    k, self.num_words)
+                    k, self.num_words,
+                    *(() if part_lists is None
+                      else (self._buckets, part_lists)))
             with tracer.span("serve.result_fetch"):
                 # rows past the chunk's queries are _topk_dispatch's padding
                 scores = np.asarray(scores)[:len(part_ids)]
@@ -415,6 +521,30 @@ class Word2VecModel:
                 out.extend(self._replies(
                     words[lo:lo + chunk], scores, idxs, num))
         return out
+
+    def _unseen_lists(self, queries, unseen: List[int]):
+        """The batch's strings the vocabulary lacks (positions ``unseen``;
+        a subword model's) made ready for the scan's program: their n-grams
+        hashed on the host (span ``serve.ngram_hash``) into one ``int32[Q,
+        L]`` block of bucket ids, empty rows elsewhere. L is a capacity
+        derived once (data/subword.list_capacity). Returns the block (None
+        where nothing is listed) and the positions of the strings it does NOT
+        hold: those whose list is longer than L, and every unseen string of a
+        model whose table lies on several devices (its bucket rows lie on
+        one). They are the one overflow form: composed by
+        :meth:`_unseen_vector`, one device operation each, and sent in the
+        vector block."""
+        if not unseen or len(self._full0.sharding.device_set) != 1:
+            return None, unseen
+        from glint_word2vec_tpu.data.subword import ngram_lists
+        cfg = self.config
+        with default_tracer().span("serve.ngram_hash", strings=len(unseen)):
+            rows, over = ngram_lists(
+                [queries[i] for i in unseen], cfg.subword_min_n,
+                cfg.subword_max_n, cfg.subword_buckets, self._list_cap)
+        lists = np.full((len(queries), self._list_cap), _NO_ROW, np.int32)
+        lists[unseen] = rows
+        return lists, [unseen[j] for j in over]
 
     def _replies(self, words: List[Optional[str]], scores, idxs,
                  num: int) -> List[List[Tuple[str, float]]]:
@@ -449,10 +579,11 @@ class Word2VecModel:
             for q in queries:
                 if isinstance(q, str):
                     idx = self.vocab.get(q)
-                    if idx < 0:
+                    if idx < 0 and not self.composes_unseen:
                         raise KeyError(f"{q} not in vocabulary")
                     words.append(q)
-                    rows.append(index.vector(idx))
+                    rows.append(index.vector(idx) if idx >= 0
+                                else self._unseen_vector(q))
                 else:
                     words.append(None)
                     rows.append(np.asarray(q, np.float32))
@@ -571,7 +702,7 @@ class Word2VecModel:
             np.asarray(self.syn1) if self.syn1 is not None else None,
             self.config, self.train_state,
             subword_buckets=(None if self._buckets is None
-                             else np.asarray(self._buckets)),
+                             else np.asarray(self.subword_buckets)),
             position_weights=self.position_weights)
 
     @classmethod
@@ -661,13 +792,25 @@ class Word2VecModel:
 from functools import partial
 
 
+# what ``ids[i]`` says of a query that is no word of the vocabulary: row i of
+# the vector block, or the mean of row i of the list block's bucket rows
+_VECTOR, _LISTED = -1, -2
+
+
 def _query_block(syn0: jax.Array, ids: jax.Array,
-                 block: Optional[jax.Array], partitioned: bool) -> jax.Array:
+                 block: Optional[jax.Array], partitioned: bool,
+                 buckets: Optional[jax.Array] = None,
+                 lists: Optional[jax.Array] = None) -> jax.Array:
     """The [Q, D] query rows, built inside the scan's own program: row
-    ``ids[i]`` of the table, or row ``i`` of ``block`` where ``ids[i] < 0``
-    (a vector query). ``block`` is None for an all-word batch — another
-    trace, with no second operand. The dtype is what stacking the rows gave:
-    the table's for words alone, promoted with the block's float32 otherwise.
+    ``ids[i]`` of the table; or row ``i`` of ``block`` where ``ids[i]`` is
+    :data:`_VECTOR` (a vector query); or, where it is :data:`_LISTED` (a
+    string a subword model's vocabulary lacks), the mean of the rows of
+    ``buckets`` that row ``i`` of ``lists`` names (ops/subword.list_vectors).
+    ``block`` is None for a batch without vectors and ``lists`` for one
+    without such strings — other traces, without those operands: an all-word
+    batch's program is the one it was before there were lists. The dtype is
+    what stacking the rows gave: the table's for words alone, promoted with
+    the float32 of either block otherwise.
 
     The rows are read as Q slices, not as one gather op: the TPU keeps a
     [V, D] table whose D is no multiple of 128 column-major, and its gather
@@ -675,7 +818,9 @@ def _query_block(syn0: jax.Array, ids: jax.Array,
     at 3M × 300, by the v5e compiler), where a slice reads a row in place.
     A table ``partitioned`` by rows over a mesh takes the gather: GSPMD
     gives each shard its own rows and one [Q, D] all-reduce, where a slice
-    along a sharded dimension all-gathers the table."""
+    along a sharded dimension all-gathers the table. The listed bucket rows
+    ARE gathered, from rows the model keeps at whole lanes of 128 for it
+    (ops/subword.lane_padded), which the gather reads in place."""
     with jax.named_scope("scan.gather"):
         ids0 = jnp.maximum(ids, 0)
         if partitioned:
@@ -685,9 +830,14 @@ def _query_block(syn0: jax.Array, ids: jax.Array,
                 jax.lax.dynamic_slice_in_dim(
                     syn0, ids0[i], 1, allow_negative_indices=False)
                 for i in range(ids.shape[0])])
-        if block is None:
-            return rows
-        return jnp.where((ids >= 0)[:, None], rows, block)
+        if block is not None:
+            rows = jnp.where((ids >= 0)[:, None], rows, block)
+    if lists is None:
+        return rows
+    with jax.named_scope("scan.compose"):
+        from glint_word2vec_tpu.ops.subword import list_vectors
+        return jnp.where((ids == _LISTED)[:, None],
+                         list_vectors(buckets, lists, syn0.shape[1]), rows)
 
 
 @partial(jax.jit, static_argnames=("valid_rows",))
@@ -814,20 +964,26 @@ def _cosine_topk_batch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
 @partial(jax.jit, static_argnames=("valid_rows", "partitioned"))
 def _gather_cosine_batch(syn0: jax.Array, norms: jax.Array, ids: jax.Array,
                          block: Optional[jax.Array], valid_rows: int,
-                         partitioned: bool) -> jax.Array:
+                         partitioned: bool, buckets: Optional[jax.Array] = None,
+                         lists: Optional[jax.Array] = None) -> jax.Array:
     """:func:`_cosine_batch` over the rows :func:`_query_block` builds."""
     return _cosine_batch(
-        syn0, norms, _query_block(syn0, ids, block, partitioned), valid_rows)
+        syn0, norms,
+        _query_block(syn0, ids, block, partitioned, buckets, lists), valid_rows)
 
 
 @partial(jax.jit, static_argnames=("k", "valid_rows", "partitioned"))
 def _gather_topk_batch(syn0: jax.Array, norms: jax.Array, ids: jax.Array,
                        block: Optional[jax.Array], k: int, valid_rows: int,
-                       partitioned: bool) -> Tuple[jax.Array, jax.Array]:
-    """Word ids in, top-k out, ONE program: :func:`_cosine_topk_batch` over
-    the rows :func:`_query_block` reads from the table it already holds."""
+                       partitioned: bool, buckets: Optional[jax.Array] = None,
+                       lists: Optional[jax.Array] = None
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """Word ids (and lists, and vectors) in, top-k out, ONE program:
+    :func:`_cosine_topk_batch` over the rows :func:`_query_block` reads from
+    the tables the model already holds."""
     return _cosine_topk_batch(
-        syn0, norms, _query_block(syn0, ids, block, partitioned), k,
+        syn0, norms,
+        _query_block(syn0, ids, block, partitioned, buckets, lists), k,
         valid_rows, partitioned)
 
 
@@ -864,12 +1020,15 @@ def _host_topk() -> bool:
 
 
 def _topk_dispatch(syn0: jax.Array, norms: jax.Array, ids: np.ndarray,
-                   block: Optional[np.ndarray], k: int, valid_rows: int):
+                   block: Optional[np.ndarray], k: int, valid_rows: int,
+                   buckets: Optional[jax.Array] = None,
+                   lists: Optional[np.ndarray] = None):
     """Route the cosine top-k of one chunk: ``ids`` (and ``block``, where
-    the chunk holds vector queries) are host arrays, transferred by the one
-    call that runs the program. Default everywhere: the top-k in the same
-    program as the gather and the matmul; on a TPU over whole tiles of 8
-    query rows, so the result may hold padding rows after the chunk's
+    the chunk holds vector queries; and ``lists``, where it holds strings a
+    subword model composes from the ``buckets`` it keeps on the device) are
+    host arrays, transferred by the one call that runs the program. Default
+    everywhere: the top-k in the same program as the gather and the matmul;
+    on a TPU over whole tiles of 8 query rows, so the result may hold padding rows after the chunk's
     own. The host route — the same
     gather and cosine on the device, scores fetched in ~512 MB sub-chunks and
     ranked with chunked ``np.argpartition`` (:func:`_cpu_topk_row`),
@@ -894,10 +1053,13 @@ def _topk_dispatch(syn0: jax.Array, norms: jax.Array, ids: np.ndarray,
             if block is not None:
                 block = np.concatenate(
                     [block, np.zeros((extra, block.shape[1]), block.dtype)])
+            if lists is not None:
+                lists = np.concatenate([lists, np.repeat(lists[-1:], extra, 0)])
         # device arrays: this returns once the program is enqueued, and the
         # caller's fetch is where the host waits for it
         return _gather_topk_batch(
-            syn0, norms, ids, block, k, valid_rows, partitioned)
+            syn0, norms, ids, block, k, valid_rows, partitioned,
+            *(() if lists is None else (buckets, lists)))
     Q, V = ids.shape[0], syn0.shape[0]
     qsub = max(1, min(Q, _CPU_TOPK_SCORE_BYTES // max(V * 4, 1)))
     scores = np.empty((Q, k), np.float32)
@@ -906,7 +1068,8 @@ def _topk_dispatch(syn0: jax.Array, norms: jax.Array, ids: np.ndarray,
         cos = np.asarray(_gather_cosine_batch(
             syn0, norms, ids[lo:lo + qsub],
             None if block is None else block[lo:lo + qsub], valid_rows,
-            partitioned))
+            partitioned, buckets,
+            None if lists is None else lists[lo:lo + qsub]))
         for r in range(cos.shape[0]):
             scores[lo + r], idxs[lo + r] = _cpu_topk_row(cos[r], k)
     return scores, idxs

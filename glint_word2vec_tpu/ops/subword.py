@@ -78,6 +78,7 @@ import jax
 import jax.numpy as jnp
 
 from glint_word2vec_tpu.data.subword import GROUP, NO_ROW
+from glint_word2vec_tpu.parallel.mesh import pad_dim_to_lanes
 
 # pairs in a chunk of the plain form: [chunk, max_groups · 8, D] float32 is
 # 31 MB at 512 pairs, 5 groups, D = 384
@@ -388,24 +389,75 @@ def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
     return _either(plan, shape, centers.shape[0], per_word, per_run, plain, syn0)
 
 
-def compose_vectors(syn0: jax.Array, table: SubwordTable, max_groups: int,
-                    num_words: int, block: int = 1 << 13) -> jax.Array:
-    """[num_words, D] float32: h_w of every word of the vocabulary from a
-    trained syn0 ([V + K, D]), in row blocks on the device (the model's query
-    table; models/word2vec.py)."""
-    @jax.jit
-    def rows_of(syn0, table, words):
-        rows, inv = _lists(words, table, max_groups)
-        return (syn0.at[rows].get(mode="fill", fill_value=0)
-                .astype(jnp.float32).sum(axis=1) * inv[:, None])
-    out = []
-    for lo in range(0, num_words, block):
-        ids = jnp.minimum(jnp.arange(lo, lo + block, dtype=jnp.int32),
-                          num_words - 1)
-        out.append(rows_of(syn0, table, ids)[:min(block, num_words - lo)])
-    return jnp.concatenate(out)
+def lane_padded(rows: jax.Array) -> jax.Array:
+    """``rows`` [N, D] with D widened to whole lanes of 128, zeros past D: the
+    form in which the TPU gathers rows in place. It keeps a table whose D is
+    no multiple of 128 column-major, and a gather from that first copies the
+    whole table row-major (3.07 GB at 2,000,000 x 300, by the v5e compiler,
+    every call; PERF.md §6, PR 40); one-row slices read in place but only
+    unrolled, one a slot."""
+    extra = pad_dim_to_lanes(rows.shape[1]) - rows.shape[1]
+    return jnp.pad(rows, ((0, 0), (0, extra))) if extra else rows
+
+
+def list_vectors(buckets: jax.Array, lists: jax.Array, dim: int) -> jax.Array:
+    """[Q, dim] float32: the mean of the bucket rows each row of ``lists``
+    ([Q, L] ids into ``buckets``, :data:`NO_ROW` past a list) names; zeros
+    for an empty list. The vector of a string the vocabulary has never seen
+    (data/subword.ngram_lists), built inside the program that scans for it."""
+    count = (lists != NO_ROW).sum(axis=1)
+    inv = jnp.where(count > 0, 1.0 / jnp.maximum(count, 1).astype(jnp.float32), 0.0)
+    return _listed_sums(buckets, lists, dim) * inv[:, None]
+
+
+def _listed_sums(buckets: jax.Array, lists: jax.Array, dim: int) -> jax.Array:
+    """[Q, dim] float32 sums of the rows of ``buckets`` that each row of
+    ``lists`` names; an id out of bounds (:data:`NO_ROW`) adds nothing."""
+    return (buckets.at[lists].get(mode="fill", fill_value=0)
+            .astype(jnp.float32).sum(axis=1)[:, :dim])
+
+
+@partial(jax.jit, static_argnames=("max_groups", "block"), donate_argnums=0)
+def _compose_block(out: jax.Array, raw0: jax.Array, buckets: jax.Array,
+                   table: SubwordTable, lo: jax.Array, max_groups: int,
+                   block: int) -> jax.Array:
+    """``out`` with rows ``lo .. lo + block`` composed, in place (``out`` is
+    donated). A block that would pass the last word starts earlier and
+    rewrites rows of the one before with the values they have."""
+    v, d = raw0.shape
+    lo = jnp.minimum(lo, v - block)
+    rows, inv = _lists(lo + jnp.arange(block, dtype=jnp.int32), table, max_groups)
+    # a list's first row is the word's own, and the words of a block lie side
+    # by side: a slice of raw0, no gather; the rest are bucket rows
+    listed = jnp.where((rows >= v) & (rows != NO_ROW), rows - v, NO_ROW)
+    own = jax.lax.dynamic_slice_in_dim(raw0, lo, block)
+    h = (own.astype(jnp.float32) + _listed_sums(buckets, listed, d)) * inv[:, None]
+    return jax.lax.dynamic_update_slice_in_dim(out, h, lo, 0)
+
+
+# words in a block of the composed table's build: [block, max_groups · 8, 384]
+# float32 gathered at a time (0.5 GB at 5 groups)
+COMPOSE_BLOCK = 1 << 13
+
+
+def compose_vectors(raw0: jax.Array, buckets: jax.Array, table: SubwordTable,
+                    max_groups: int, block: int = COMPOSE_BLOCK) -> jax.Array:
+    """[V, D] float32: h_w of every word of the vocabulary, from a trained
+    syn0 as its two parts, the words' own rows ``raw0`` [V, D] and the bucket
+    rows ``buckets`` [K, D or more] (:func:`lane_padded` on a TPU), in blocks
+    of words written in place into one result (the model's query table;
+    models/word2vec.py). No [V + K, D] array is made: each part is read where
+    it lies."""
+    v, d = raw0.shape
+    block = min(block, v)
+    out = jnp.zeros((v, d), jnp.float32)
+    for lo in range(0, v, block):
+        out = _compose_block(out, raw0, buckets, table, jnp.int32(lo),
+                             max_groups, block)
+    return out
 
 
 __all__: Tuple[str, ...] = (
     "SubwordTable", "SubwordShape", "CenterPlan", "WordPlan", "plan_centers",
-    "center_vectors", "scatter_center_updates", "compose_vectors")
+    "center_vectors", "scatter_center_updates", "compose_vectors",
+    "lane_padded", "list_vectors", "COMPOSE_BLOCK")

@@ -464,9 +464,11 @@ class EmbeddingService:
                 op = p[0]
                 if op == "syn":
                     q, num = p[1], p[2]
-                    if isinstance(q, str) and model.vocab.get(q) < 0:
+                    if (isinstance(q, str) and model.vocab.get(q) < 0
+                            and not model.composes_unseen):
                         # per-request failure: an OOV word fails ITS caller,
-                        # never the batch (the batcher re-raises it there)
+                        # never the batch (the batcher re-raises it there);
+                        # a subword model composes it from its n-grams
                         results[i] = KeyError(f"{q} not in vocabulary")
                         continue
                     syn_pos.append(i)

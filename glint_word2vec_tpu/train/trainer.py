@@ -92,10 +92,6 @@ def _center_run_cap(window: int, batch: int) -> int:
     return -(-int(1.4 * runs_per_pair * batch) // eighth) * eighth
 
 
-# a row table's groups are padded to a multiple of this on the device
-# (Trainer._put_row_table has the reason)
-_SUBWORD_GROUPS_UNIT = 1 << 20
-
 # pairs in a piece of a context run (ops/sgns.run_sums makes one shifted add
 # for each beyond the first): named, with the cap's room, by the chip (PERF.md
 # §6, PR 30)
@@ -1100,16 +1096,10 @@ class Trainer:
     def _put_row_table(self, table) -> tuple:
         """A row table (data/subword.SubwordRows: the subword lists, or the
         hierarchical-softmax paths) on the device, waited for: ``(offsets,
-        rows, counts)``, the chunk's table arguments. The groups' count is an
-        argument's shape of the step: rounded up (2^20 groups, 3% of the
-        published subword vocabulary's 10.7 M), a vocabulary that differs by
-        a few words, as the benchmark's does from seed to seed, compiles the
-        same program and finds it in the compile cache."""
-        from glint_word2vec_tpu.data.subword import NO_ROW
-        groups = np.full((-(-table.rows.shape[0] // _SUBWORD_GROUPS_UNIT)
-                          * _SUBWORD_GROUPS_UNIT, table.rows.shape[1]),
-                         NO_ROW, np.int32)
-        groups[:table.rows.shape[0]] = table.rows
+        rows, counts)``, the chunk's table arguments, the groups in whole
+        units (data/subword.groups_in_whole_units has the reason)."""
+        from glint_word2vec_tpu.data.subword import groups_in_whole_units
+        groups = groups_in_whole_units(table.rows)
         placed = put_global(self.plan.replicated, {
             "offsets": table.offsets, "rows": groups, "counts": table.counts})
         jax.block_until_ready(placed)
@@ -4159,6 +4149,17 @@ class Trainer:
         V = self.vocab.size
         return self.params.syn0[V:V + self.config.subword_buckets,
                                 :self.config.vector_size]
+
+    def subword_rows(self):
+        """The vocabulary's row table as the step reads it (an
+        ops/subword.SubwordTable on the device) and its longest list's
+        groups, for the model built after the fit: it composes its query
+        table from them and need not build them again. None where the model
+        is not subword."""
+        if not self.config.subword:
+            return None
+        from glint_word2vec_tpu.ops.subword import SubwordTable
+        return SubwordTable(*self._step_extra), self._subword_shape.max_groups
 
     def position_weights(self) -> Optional[jax.Array]:
         """The position weights [2·window, D] (config.cbow_position_weights),

@@ -473,7 +473,7 @@ def test_fit_trains_all_three_leaves(fitted):
 
 def test_model_composes_the_fitted_vectors_and_answers_an_unseen_string(fitted):
     model, _ = fitted
-    table = np.concatenate([np.asarray(model._raw0), np.asarray(model._buckets)])
+    table = np.concatenate([np.asarray(model._raw0), np.asarray(model.subword_buckets)])
     v = model.vocab.size
     for word in model.vocab.words[:5]:
         want = subword_ref.word_vector(table, word, model.vocab.index[word], v,

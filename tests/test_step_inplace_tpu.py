@@ -286,3 +286,51 @@ def test_no_table_is_copied_under_hierarchical_softmax(one_chip, with_metrics):
     assert re.search(r"bf16\[589824,384\]", compiled)
     assert not re.search(r"\[2097152,384\]|\[65536,32,384\]", compiled)
     assert program.memory_analysis().temp_size_in_bytes < 2_600_000_000
+
+
+SUB_V, SUB_K, SUB_D, SUB_GROUPS = 2_519_370, 2_000_000, 300, 11 << 20
+
+
+def _no_table_copied(text: str):
+    tables = r"f32\[(?:%d|%d|%d),\d+\]" % (SUB_V, SUB_K, SUB_V + SUB_K)
+    assert not re.findall(r"= %s\S* copy\(" % tables, text)
+    assert not re.search(r"f32\[%d," % (SUB_V + SUB_K), text)
+
+
+def test_the_composed_tables_block_copies_no_table(one_chip):
+    from glint_word2vec_tpu.ops import subword as sw
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    table = sw.SubwordTable(spec((SUB_V + 2,), jnp.int32),
+                            spec((SUB_GROUPS, 8), jnp.int32),
+                            spec((SUB_V + 1,), jnp.int32))
+    compiled = sw._compose_block.lower(
+        spec((SUB_V, SUB_D), jnp.float32), spec((SUB_V, SUB_D), jnp.float32),
+        spec((SUB_K, 384), jnp.float32), table, spec((), jnp.int32),
+        max_groups=5, block=1 << 13).compile()
+    _no_table_copied(compiled.as_text())
+    memory = compiled.memory_analysis()
+    # the result is the donated operand, and a block's gather is what is made
+    assert memory.alias_size_in_bytes >= 4 * SUB_V * SUB_D
+    assert memory.temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("lists", [True, False], ids=["with_lists", "words_alone"])
+def test_the_subword_scan_copies_no_table(one_chip, lists, monkeypatch):
+    from glint_word2vec_tpu.models import word2vec as w2v
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    # _grouped_scores asks for the backend while it is traced: the TPU's branch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    extra = ((spec((SUB_K, 384), jnp.float32), spec((32, 48), jnp.int32))
+             if lists else ())
+    compiled = w2v._gather_topk_batch.lower(
+        spec((SUB_V, SUB_D), jnp.float32), spec((SUB_V,), jnp.float32),
+        spec((32,), jnp.int32), None, 11, SUB_V, False, *extra).compile()
+    _no_table_copied(compiled.as_text())
+    # two score blocks of [32, 2,519,552] float32 and no table beside them
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

@@ -320,13 +320,13 @@ def fitted():
 
 
 def _trained_table(model):
-    return np.concatenate([np.asarray(model._raw0), np.asarray(model._buckets)])
+    return np.concatenate([np.asarray(model._raw0), np.asarray(model.subword_buckets)])
 
 
 def test_model_answers_with_composed_vectors(fitted):
     model = fitted
     v, k = model.vocab.size, FIT["subword_buckets"]
-    assert model._buckets.shape == (k, 24) and model.syn0.shape == (v, 24)
+    assert model.subword_buckets.shape == (k, 24) and model.syn0.shape == (v, 24)
     table = _trained_table(model)
     assert np.abs(table[v:]).max() > 0                    # bucket rows trained
     for word in model.vocab.words:
