@@ -85,9 +85,12 @@ LOCK_TABLE = {
     "serve.batcher.cv": {
         "rank": 60, "kind": "condition",
         "site": "glint_word2vec_tpu/serve/batcher.py:BatchingScheduler.__init__",
-        "owner": "admission queue + counters + latency ring; NON-reentrant — "
-                 "the PR 9 dump contract (service.dump_blackbox "
-                 "include_stats=False) exists because of this lock"},
+        "owner": "admission queue + counters + latency ring, and the "
+                 "worker's hand-off to the completer (the begun-batch queue, "
+                 "the in-flight slots: both threads wait on it, no second "
+                 "lock); NON-reentrant — the PR 9 dump contract "
+                 "(service.dump_blackbox include_stats=False) exists "
+                 "because of this lock"},
     "obs.slo": {
         "rank": 70, "kind": "lock",
         "site": "glint_word2vec_tpu/obs/slo.py:SloTracker.__init__",

@@ -391,6 +391,7 @@ class Word2VecModel:
         chunk: int = 128,
         ann: bool = False,
         nprobe: Optional[int] = None,
+        begun: Optional["_PendingSynonyms"] = None,
     ) -> List[List[Tuple[str, float]]]:
         """Batched :meth:`find_synonyms`: ONE device program per ``chunk``
         queries, word ids in and the top-k out. The host resolves the words
@@ -423,8 +424,14 @@ class Word2VecModel:
         where such strings are sent. A string with more n-grams than the
         block's capacity L is composed on its own and sent as a vector
         (``query_counts["overflow"]`` counts them).
-        ``chunk`` bounds device memory at chunk·V·4 bytes of scores: the
-        score block is still written whole, whatever ranks it.
+        ``chunk`` bounds device memory at chunk·V·4 bytes of scores a part,
+        two parts at a time (the score block is still written whole,
+        whatever ranks it): the call is :meth:`find_synonyms_finish` of
+        :meth:`find_synonyms_begin`, which enqueues a part's scan while the
+        one before it is fetched. A caller that ran the first half itself
+        (the serve dispatcher, which begins the next batch meanwhile) hands
+        its result in as ``begun``, and this call is the second half alone:
+        every reply, whoever began it, is handed out here.
 
         ``ann=True`` routes the batch through the attached IVF index
         (:meth:`attach_ann`) instead of the exact full-vocab scan — the
@@ -432,23 +439,50 @@ class Word2VecModel:
         the ``nprobe`` nearest coarse cells, same result shape and the same
         self-exclusion semantics; scores remain true cosines (candidates
         are ranked exactly, only the candidate SET is approximate)."""
+        return self.find_synonyms_finish(
+            begun if begun is not None
+            else self.find_synonyms_begin(queries, num, chunk, ann, nprobe))
+
+    def find_synonyms_begin(
+        self,
+        queries: Sequence[Union[str, np.ndarray]],
+        num: int,
+        chunk: int = 128,
+        ann: bool = False,
+        nprobe: Optional[int] = None,
+    ) -> "_PendingSynonyms":
+        """The first half of :meth:`find_synonyms_batch`: everything up to
+        and including the scan's enqueue (words to row ids, the unseen
+        strings' lists, the vector block, ``_topk_dispatch``) and the start
+        of the result's copy back to the host. Returns what
+        :meth:`find_synonyms_finish` turns into the replies, on this thread
+        or another: a caller with more batches than one (the serve batcher)
+        begins the next while this one's scan runs. A call of more than one
+        ``chunk`` has at most two parts enqueued at a time; ``finish``
+        enqueues the rest as it fetches. The ANN arm and the host top-k
+        route (:func:`_host_topk`) leave nothing pending on the device: all
+        of their work is done here and ``finish`` hands it back."""
         self._check_alive()
+        tracer = default_tracer()
+        # the caller's span: parent of the spans ``finish`` records, which may
+        # run on a thread whose stack does not hold it
+        pending = _PendingSynonyms(num, tracer.current())
         if ann:
             if self._ann is None:
                 raise RuntimeError(
                     "ann=True but no index attached — build one with "
                     "serve.ann.build_ivf(np.asarray(model.syn0)) and "
                     "model.attach_ann(index)")
-            return self._find_synonyms_batch_ann(queries, num, nprobe)
+            pending.replies = self._find_synonyms_batch_ann(
+                queries, num, nprobe)
+            return pending
         if self._norms is None:
             self.norms  # materialize the cached full-row norms
-        tracer = default_tracer()
-        out: List[List[Tuple[str, float]]] = []
-        k = min(num + 1, self.num_words)
+        k = pending.k = min(num + 1, self.num_words)
         # spans of the serve table (obs/spans.py, docs/observability.md §4):
         # the host side of the scan, region by region
         with tracer.span("serve.row_fetch") as sp:
-            words: List[Optional[str]] = []
+            words = pending.words
             ids = np.full(len(queries), _VECTOR, np.int32)
             block: Optional[np.ndarray] = None
             unseen: List[int] = []
@@ -489,7 +523,7 @@ class Word2VecModel:
             # what each chunk's program is handed: its ids, the list block
             # only where the chunk holds an unseen string, the vector block
             # only where it holds a vector query
-            parts = []
+            parts = pending.parts
             for lo in range(0, len(queries), chunk):
                 part_ids = ids[lo:lo + chunk]
                 parts.append((
@@ -502,24 +536,55 @@ class Word2VecModel:
                            for _, _, b, l in parts), **counts)
         # scores a query's selection ranks: every row where one top-k (on
         # the device or the host) ranks them, far fewer where two stages do
-        topk_rows = (self._full0.shape[0] if _host_topk() else _topk_rows(
-            self._full0.shape[0], k,
-            not self._full0.sharding.is_fully_replicated))
-        for lo, part_ids, part_block, part_lists in parts:
-            with tracer.span("serve.scan_enqueue", queries=len(part_ids),
-                             topk_rows=topk_rows):
-                scores, idxs = _topk_dispatch(
-                    self._full0, self._norms, part_ids, part_block,
-                    k, self.num_words,
-                    *(() if part_lists is None
-                      else (self._buckets, part_lists)))
-            with tracer.span("serve.result_fetch"):
+        pending.topk_rows = (
+            self._full0.shape[0] if _host_topk() else _topk_rows(
+                self._full0.shape[0], k,
+                not self._full0.sharding.is_fully_replicated))
+        for _ in parts[:_PARTS_IN_FLIGHT]:
+            self._enqueue_part(pending)
+        return pending
+
+    def _enqueue_part(self, pending: "_PendingSynonyms") -> None:
+        """Enqueue the scan of ``pending``'s next part and start its result
+        on the way back to the host."""
+        _, part_ids, part_block, part_lists = pending.parts[
+            len(pending.results)]
+        with default_tracer().span(
+                "serve.scan_enqueue", parent=pending.parent,
+                queries=len(part_ids), topk_rows=pending.topk_rows):
+            result = _topk_dispatch(
+                self._full0, self._norms, part_ids, part_block,
+                pending.k, self.num_words,
+                *(() if part_lists is None
+                  else (self._buckets, part_lists)))
+            for a in result:
+                if isinstance(a, jax.Array):  # the host route's are fetched
+                    a.copy_to_host_async()
+        pending.results.append(result)
+
+    def find_synonyms_finish(
+        self, pending: "_PendingSynonyms") -> List[List[Tuple[str, float]]]:
+        """The second half of :meth:`find_synonyms_batch`: fetch the scans
+        :meth:`find_synonyms_begin` enqueued, in order, and build the
+        replies. Once for each ``pending``."""
+        if pending.replies is not None:
+            return pending.replies
+        self._check_alive()
+        tracer = default_tracer()
+        out: List[List[Tuple[str, float]]] = []
+        for at, (lo, part_ids, _, _) in enumerate(pending.parts):
+            scores, idxs = pending.results[at]
+            pending.results[at] = None
+            with tracer.span("serve.result_fetch", parent=pending.parent):
                 # rows past the chunk's queries are _topk_dispatch's padding
                 scores = np.asarray(scores)[:len(part_ids)]
                 idxs = np.asarray(idxs)[:len(part_ids)]
-            with tracer.span("serve.reply_build"):
+            if len(pending.results) < len(pending.parts):
+                self._enqueue_part(pending)
+            with tracer.span("serve.reply_build", parent=pending.parent):
                 out.extend(self._replies(
-                    words[lo:lo + chunk], scores, idxs, num))
+                    pending.words[lo:lo + len(part_ids)], scores, idxs,
+                    pending.num))
         return out
 
     def _unseen_lists(self, queries, unseen: List[int]):
@@ -795,6 +860,32 @@ from functools import partial
 # what ``ids[i]`` says of a query that is no word of the vocabulary: row i of
 # the vector block, or the mean of row i of the list block's bucket rows
 _VECTOR, _LISTED = -1, -2
+
+# parts of one call whose scans are enqueued and not yet fetched: one running
+# and one queued behind it, the bound the serve batcher keeps for its batches
+# (a [128, V] score block is 1.5 GB at 3M rows)
+_PARTS_IN_FLIGHT = 2
+
+
+class _PendingSynonyms:
+    """What ``Word2VecModel.find_synonyms_begin`` hands to
+    ``find_synonyms_finish``: the query words, the parts (one per chunk: its
+    offset and host arrays), the results of the parts enqueued so far (device
+    arrays on their way back, or the host route's arrays), and the span that
+    enclosed the begin. ``replies`` is set where begin did all the work."""
+
+    __slots__ = ("num", "k", "parent", "words", "parts", "results",
+                 "topk_rows", "replies")
+
+    def __init__(self, num: int, parent: Optional[int]):
+        self.num = num
+        self.k = 0
+        self.parent = parent
+        self.words: List[Optional[str]] = []
+        self.parts: list = []
+        self.results: list = []
+        self.topk_rows = 0
+        self.replies: Optional[List[List[Tuple[str, float]]]] = None
 
 
 def _query_block(syn0: jax.Array, ids: jax.Array,

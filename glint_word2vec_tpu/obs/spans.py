@@ -1,11 +1,13 @@
 """Host trace spans: the program's one span recorder.
 
-A span is one timed region on one thread: name, start, duration, a
-process-unique ``id``, the ``parent`` that caused it, and counts riding as
-args (``size=``, ``ops=``), so that ratios are measured where the work
+A span is one timed region on one thread (or begun on one and ended on
+another: ``detach``): name, start, duration, a process-unique ``id``, the
+``parent`` that caused it, and counts riding as args (``size=``,
+``ops=``), so that ratios are measured where the work
 happens. The fit loop, the feed threads, the checkpoint I/O, the serve
-batcher, ``EmbeddingService._dispatch`` and ``find_synonyms_batch`` all
-record through the one process-wide :func:`default_tracer`.
+batcher, ``EmbeddingService``'s dispatch and the model's
+``find_synonyms_begin`` / ``find_synonyms_finish`` all record through the
+one process-wide :func:`default_tracer`.
 
 When it records. ``Tracer.span()`` is active when ``enabled`` is set (run
 telemetry: ``config.telemetry_path`` / ``status_port``) **or while a
@@ -43,7 +45,12 @@ Design constraints:
   in another (the fit loop's heartbeat round, ``Trainer._finish_round`` to
   ``Trainer._after_dispatch``) is :meth:`Tracer.open` and ``close()``, the
   same span on the same stack and in the same trace, never a retroactive
-  :meth:`Tracer.record`, which reaches the ring only;
+  :meth:`Tracer.record`, which reaches the ring only; the one that begins
+  on one thread and ends on another (a serve batch: begun by the batcher's
+  worker, ended by its completer) is opened, ``detach()``-ed by the thread
+  that began it and ``close()``-d by the one that ends it: one span in the
+  ring, with the opener's ``t0``; in the trace, its annotation covers the
+  opener's part;
 - no ad-hoc threads (graftlint R1): this module only OBSERVES threads.
 
 The Chrome-trace export (``chrome://tracing`` / Perfetto) renders nesting
@@ -102,7 +109,7 @@ class _Span:
     exit, ``id`` from entry; ``recorded`` says whether it reached the ring."""
 
     __slots__ = ("_tracer", "name", "args", "parent", "id", "t0", "dur",
-                 "recorded", "_keep", "_ann")
+                 "recorded", "_keep", "_ann", "_on_stack")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[dict],
                  parent: Optional[int], keep: bool, live: bool):
@@ -114,6 +121,7 @@ class _Span:
         self.recorded = False
         self._keep = keep
         self._ann = TraceAnnotation(name) if live else None
+        self._on_stack = False
 
     def set(self, **args) -> None:
         """Counts known only once the work is done (``ops=``)."""
@@ -126,6 +134,7 @@ class _Span:
                 self.parent = stack[-1]
             self.id = next(_IDS)
             stack.append(self.id)
+            self._on_stack = True
             if self._ann is not None:
                 self._ann.__enter__()
         self.t0 = now()
@@ -135,6 +144,17 @@ class _Span:
         self.close()
         return None
 
+    def detach(self) -> None:
+        """Leave the calling thread without ending the region: the thread
+        that entered it steps out (its stack, its annotation) and another
+        ends it with :meth:`close`. Children recorded after this name it by
+        ``parent=``."""
+        if self._on_stack:
+            self._on_stack = False
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+            self._tracer._stack().pop()
+
     def close(self, end: Optional[float] = None, keep: bool = True) -> None:
         """Leave the region; by hand for one that :meth:`Tracer.open`
         entered. ``end`` (on :data:`now`) puts its end before the call and
@@ -143,9 +163,7 @@ class _Span:
         self.dur = (now() if end is None else end) - self.t0
         if self._keep:
             tracer = self._tracer
-            if self._ann is not None:
-                self._ann.__exit__(None, None, None)
-            tracer._stack().pop()
+            self.detach()
             # a span the trace's stop cut short has no counterpart there
             if keep and (tracer.enabled or TraceAnnotation.is_enabled()):
                 tracer._record(self.name, self.t0, self.dur, self.args,
@@ -192,6 +210,13 @@ class Tracer:
         except AttributeError:
             stack = self._local.stack = []
             return stack
+
+    def current(self) -> Optional[int]:
+        """The ``id`` of the calling thread's innermost open span, or None:
+        what a region that continues on another thread names as ``parent=``
+        there."""
+        stack = self._stack()
+        return stack[-1] if stack else None
 
     def span(self, name: str, *, parent: Optional[int] = None,
              timed: bool = False, **args):

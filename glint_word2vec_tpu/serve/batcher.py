@@ -15,14 +15,29 @@ turns N concurrent callers into that one dispatch:
 - the whole batch goes to the ``handler`` callable in one call; the handler
   returns one result per request (an ``Exception`` instance marks a
   per-request failure — an OOV word must fail ITS caller, not the batch);
+- **a handler may come in two halves** (``handler`` + ``finish``): the first
+  ends with the batch's device work enqueued and returns what is pending,
+  the second fetches it and returns the results. The worker runs the first
+  half and hands the batch to a second thread, the COMPLETER, which runs
+  the second, releases the callers and runs the hooks, in the order the
+  batches were closed. :data:`MAX_INFLIGHT` batches are between the two at
+  a time: while the device scans for one batch the worker resolves, and
+  enqueues, the next, and the chip does not wait for the host's half of a
+  batch. The worker takes its slot of that bound BEFORE it closes a batch:
+  a device program costs the same at 8 requests as at 64, so requests that
+  arrive while both slots are taken wait in the queue and leave as one
+  batch, not as pieces cut by the deadline. A handler with one half
+  (``finish=None``) has done all its work when it returns, and the
+  completer only releases its callers;
 - **backpressure is a fast refusal, never unbounded memory**: a full queue
   raises :class:`ServerOverloaded` to the caller immediately (the 429-style
   contract) instead of queueing into latency collapse.
 
-Determinism note (graftlint R1): the worker thread is a sanctioned owner —
-it only ORDERS request/response pairing (each caller gets exactly its own
-result back) and is read-only on model parameters; it never produces or
-orders training data, so the worker-count determinism contract is
+Determinism note (graftlint R1): the worker and the completer are
+sanctioned owners — they only ORDER request/response pairing (each caller
+gets exactly its own result back; one completer, so batches finish in the
+order they were closed) and are read-only on model parameters; they never
+produce or order training data, so the worker-count determinism contract is
 untouched. Batch COMPOSITION is timing-dependent by design (that is what a
 micro-batcher is); per-request results are not, because the handler maps
 item i to result i.
@@ -39,6 +54,12 @@ from glint_word2vec_tpu.lockcheck import make_condition
 from glint_word2vec_tpu.obs.spans import default_tracer
 
 logger = logging.getLogger("glint_word2vec_tpu")
+
+# batches begun and not yet finished: one whose device program runs and one
+# queued behind it. The device runs programs one after another, so a third
+# would buy nothing and cost a third score block (0.4-0.5 GB at 3M rows);
+# not a setting
+MAX_INFLIGHT = 2
 
 
 class ServerOverloaded(RuntimeError):
@@ -92,12 +113,28 @@ class _Ticket:
         self.trace = trace
 
 
+class _Begun:
+    """One batch between the worker and the completer: its tickets, its
+    ``serve.batch`` span (opened by the worker, closed by the completer),
+    the start its service time counts from, and what the handler's first
+    half returned, or the exception it raised."""
+
+    __slots__ = ("tickets", "span", "t0", "pending", "error")
+
+    def __init__(self, tickets: List[_Ticket], span, t0: float):
+        self.tickets = tickets
+        self.span = span
+        self.t0 = t0
+        self.pending: Any = None
+        self.error: Optional[Exception] = None
+
+
 class BatchingScheduler:
     """Deadline-based micro-batcher over a bounded queue (module doc)."""
 
     def __init__(
         self,
-        handler: Callable[[List[Any]], Sequence[Any]],
+        handler: Callable[[List[Any]], Any],
         max_batch: int = 64,
         max_delay_ms: float = 2.0,
         max_queue: int = 256,
@@ -106,17 +143,24 @@ class BatchingScheduler:
         straggle_ms: float = 0.0,
         span_emit: Optional[Callable[[dict, str, int, int], None]] = None,
         batch_observer: Optional[Callable[[int, float, float], None]] = None,
+        finish: Optional[Callable[[Any], Sequence[Any]]] = None,
     ):
-        """``straggle_every``/``straggle_ms`` are FAULT INJECTION (the
+        """``handler(payloads)`` returns one result per request; or, where
+        ``finish`` is given, whatever ``finish(pending)`` needs to return
+        them: the handler's two halves (module doc). ``finish`` is called
+        once for every batch whose first half returned, on the completer
+        thread, in the order the batches were closed.
+
+        ``straggle_every``/``straggle_ms`` are FAULT INJECTION (the
         serve-side analog of train/faults.py, off by default): every Nth
-        dispatched batch sleeps ``straggle_ms`` before the handler runs — a
+        closed batch sleeps ``straggle_ms`` before the handler runs — a
         deterministic tail-latency straggler. The fleet hedge A/B
         (tools/servebench.py --fleet) uses it to measure what hedging buys
         against a replica that stalls 1-in-N dispatches; production never
         sets it.
 
         ``span_emit(trace, name, start_mono_ns, dur_ns)``: the trace hook
-        (obs/trace.py) the worker calls per TRACED ticket after each batch —
+        (obs/trace.py) the completer calls per TRACED ticket after each batch —
         a ``queue_wait`` span (submit → batch pop: the admission latency the
         micro-batching deadline trades) and a ``batch_service`` span (the
         handler's wall time), both parented to the context the request
@@ -128,8 +172,9 @@ class BatchingScheduler:
         ``batch_observer(batch_size, service_s, queue_wait_s)``: called once
         per dispatched batch (success or error) — the serving flight
         recorder's dispatch-ring feed (obs/blackbox.py note_dispatch via
-        EmbeddingService). Both hooks run ON the worker thread; they must
-        not block (the sink's locked append is the intended cost)."""
+        EmbeddingService). Both hooks run ON the completer thread, after
+        the batch's callers were released; they must not block (the sink's
+        locked append is the intended cost)."""
         if max_batch <= 0:
             raise ValueError(f"max_batch must be positive but got {max_batch}")
         if max_delay_ms < 0:
@@ -138,6 +183,7 @@ class BatchingScheduler:
         if max_queue <= 0:
             raise ValueError(f"max_queue must be positive but got {max_queue}")
         self._handler = handler
+        self._finish = finish
         self.max_batch = int(max_batch)
         self.max_delay_s = float(max_delay_ms) / 1000.0
         self.max_queue = int(max_queue)
@@ -151,6 +197,12 @@ class BatchingScheduler:
         self._cv = make_condition("serve.batcher.cv")
         self._stopping = False
         self._thread: Optional[threading.Thread] = None
+        self._completer: Optional[threading.Thread] = None
+        # worker → completer, FIFO (under _cv); a None ends the completer
+        self._begun: collections.deque = collections.deque()
+        # slots of MAX_INFLIGHT taken: from before a batch is closed until
+        # its last caller is released (under _cv)
+        self._inflight = 0
         # counters (all mutated under _cv)
         self._submitted = 0
         self._refused = 0
@@ -158,12 +210,15 @@ class BatchingScheduler:
         self._errors = 0
         self._batches = 0
         self._batched_items = 0
+        self._closed_batches = 0   # handed to the completer (≥ _batches)
+        self._overlapped = 0       # begun while an earlier one was unfinished
         # recent end-to-end latencies (seconds); deque append is atomic, so
         # submitters record lock-free and stats() snapshots a copy
         self._latencies: collections.deque = collections.deque(maxlen=4096)
-        # EWMA of the handler's per-batch wall time (seconds), updated by
-        # the worker after every dispatch — feeds the ServerOverloaded
-        # retry_after_s hint. None until the first batch completes.
+        # EWMA of a batch's time from close to its results (seconds),
+        # updated by the completer after every batch — feeds the
+        # ServerOverloaded retry_after_s hint. None until the first batch
+        # completes.
         self._batch_s_ewma: Optional[float] = None
 
     # -- lifecycle ---------------------------------------------------------------------
@@ -173,26 +228,34 @@ class BatchingScheduler:
             return self
         self._thread = threading.Thread(
             target=self._run, name=self._name, daemon=True)
+        self._completer = threading.Thread(
+            target=self._complete, name=f"{self._name}-completer",
+            daemon=True)
         self._thread.start()
+        self._completer.start()
         return self
 
     def stop(self) -> int:
         """Drain-and-stop: requests already admitted are still served (the
-        worker keeps batching until the queue is empty), new submits are
-        refused. Returns the number of leaked threads (1 when the worker
-        misses the join bound) so close() paths can surface it in stats."""
+        worker keeps batching until the queue is empty, the completer
+        finishes the batches in flight), new submits are refused. Returns
+        the number of leaked threads (those that miss the join bound) so
+        close() paths can surface it in stats."""
         with self._cv:
             if self._stopping:
                 return 0
             self._stopping = True
             self._cv.notify_all()
         leaked = 0
-        t, self._thread = self._thread, None
-        if t is not None:
-            t.join(timeout=30)
-            if t.is_alive():
-                leaked = 1
-                logger.warning("batcher worker thread leaked (join timeout)")
+        threads = (self._thread, self._completer)
+        self._thread = self._completer = None
+        for t in threads:
+            if t is not None:
+                t.join(timeout=30)
+                if t.is_alive():
+                    leaked += 1
+                    logger.warning("batcher thread %s leaked (join timeout)",
+                                   t.name)
         return leaked
 
     # -- client side -------------------------------------------------------------------
@@ -277,30 +340,77 @@ class BatchingScheduler:
             return batch
 
     def _run(self) -> None:
+        """The worker: a slot, a batch, the handler's first half, over to
+        the completer."""
         while True:
+            with self._cv:
+                # the slot first (module doc): what arrives while both are
+                # taken waits in the queue and leaves as one batch
+                while self._inflight >= MAX_INFLIGHT:
+                    self._cv.wait()
+                self._inflight += 1
             batch = self._collect()
             if batch is None:
+                with self._cv:
+                    self._inflight -= 1
+                    self._begun.append(None)  # the completer's last item
+                    self._cv.notify_all()
                 return
-            # serve.batch: batch closed → last caller released. The one pair
-            # of clock reads per batch: the service-time estimate, the batch
-            # observer and the fleet's trace_span records are fed its times.
-            with self._tracer.span("serve.batch", timed=True,
-                                   size=len(batch)) as sp:
-                self._serve(batch, sp.t0)
-            self._after_batch(batch, sp)
-
-    def _serve(self, batch: List[_Ticket], t0: float) -> None:
-        """Run the handler over one batch and release its callers. ``t0`` is
-        the batch's start, from which the handler's wall time is taken."""
-        if self._straggle_every:
+            # serve.batch: batch closed → last caller released, from here to
+            # the completer. The one pair of clock reads per batch: the
+            # service-time estimate, the batch observer and the fleet's
+            # trace_span records are fed its times.
+            sp = self._tracer.open("serve.batch", timed=True, size=len(batch))
+            item = _Begun(batch, sp, sp.t0)
+            if self._straggle_every:
+                with self._cv:
+                    nth = self._closed_batches + 1
+                if nth % self._straggle_every == 0:
+                    time.sleep(self._straggle_s)  # injected straggler
+                    item.t0 = time.monotonic()
+            try:
+                item.pending = self._handler([t.payload for t in batch])
+            except Exception as e:  # noqa: BLE001 — delivered to each caller
+                item.error = e
+            sp.detach()
             with self._cv:
-                nth = self._batches + 1
-            if nth % self._straggle_every == 0:
-                time.sleep(self._straggle_s)  # injected straggler
-                t0 = time.monotonic()
+                # earlier batches whose results are not in yet: the device
+                # has this batch's work queued behind theirs
+                earlier = self._closed_batches - self._batches
+                self._closed_batches += 1
+                self._overlapped += earlier > 0
+                sp.set(inflight=earlier,
+                       inflight_share=(earlier + 1) / MAX_INFLIGHT)
+                self._begun.append(item)
+                self._cv.notify_all()
+
+    def _complete(self) -> None:
+        """The completer: each begun batch in turn, the handler's second
+        half, its callers released, its slot freed, then the hooks."""
+        while True:
+            with self._cv:
+                while not self._begun:
+                    self._cv.wait()
+                item = self._begun.popleft()
+            if item is None:
+                return
+            self._serve(item)
+            item.span.close()
+            with self._cv:
+                self._inflight -= 1
+                self._cv.notify_all()
+            self._after_batch(item.tickets, item.span)
+
+    def _serve(self, item: _Begun) -> None:
+        """The results of one begun batch into its tickets, and its callers
+        released."""
+        batch = item.tickets
         n_err = 0
         try:
-            results = self._handler([t.payload for t in batch])
+            if item.error is not None:
+                raise item.error
+            results = (item.pending if self._finish is None
+                       else self._finish(item.pending))
             if len(results) != len(batch):
                 raise RuntimeError(
                     f"handler returned {len(results)} results for a "
@@ -317,7 +427,7 @@ class BatchingScheduler:
                 else:
                     t.result = r
         with self._cv:
-            self._note_batch_seconds(time.monotonic() - t0)
+            self._note_batch_seconds(time.monotonic() - item.t0)
             self._batches += 1
             self._batched_items += len(batch)
             self._errors += n_err
@@ -326,14 +436,14 @@ class BatchingScheduler:
             t.done.set()
 
     def _after_batch(self, batch: List[_Ticket], sp) -> None:
-        """Post-batch observability (worker thread, AFTER the callers were
+        """Post-batch observability (completer thread, AFTER the callers were
         released — a slow sink must not sit inside any caller's latency),
         all from the ``serve.batch`` span ``sp``'s own times: a
         ``serve.queue_wait`` span per ticket (enqueued → its batch closed,
         child of ``sp``) where the recorder kept ``sp``, the per-batch
         dispatch observer, then queue_wait/batch_service ``trace_span``
         records for each TRACED ticket. Best-effort like every obs surface —
-        a hook failure must never kill the worker."""
+        a hook failure must never kill the completer."""
         if not (sp.recorded or self._batch_observer is not None
                 or self._span_emit is not None):
             return
@@ -363,7 +473,8 @@ class BatchingScheduler:
                            exc_info=True)
 
     def _note_batch_seconds(self, dt: float) -> None:
-        """Fold one batch's handler wall time into the EWMA (under _cv).
+        """Fold one batch's time from close to results into the EWMA (under
+        _cv).
         alpha=0.2: ~10 batches of memory — reactive enough that a reload's
         cold first dispatch doesn't poison the hint for long."""
         self._batch_s_ewma = (dt if self._batch_s_ewma is None
@@ -381,6 +492,7 @@ class BatchingScheduler:
                 "completed": self._completed,
                 "errors": self._errors,
                 "batches": self._batches,
+                "overlapped_batches": self._overlapped,
                 "queue_depth": len(self._q),
                 "max_batch": self.max_batch,
                 "max_queue": self.max_queue,

@@ -26,6 +26,7 @@ convenience; nothing requires it).
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
@@ -46,6 +47,30 @@ from glint_word2vec_tpu.serve.reload import (
 logger = logging.getLogger("glint_word2vec_tpu")
 
 Query = Union[str, np.ndarray]
+
+
+class _PendingDispatch:
+    """One batch between ``EmbeddingService._dispatch_begin`` and
+    ``_dispatch_finish``: its ``serve.dispatch`` span (or None), the lease
+    that pins its model generation, the results known already, and for its
+    ``syn`` queries their positions, the queries, counts and wire trace
+    contexts with the model's pending scan (None where no scan was begun)."""
+
+    __slots__ = ("span", "lease", "model", "traced", "results", "syn_pos",
+                 "syn_q", "syn_num", "syn_trace", "use_ann", "scan")
+
+    def __init__(self, span, lease, model, traced: bool, size: int):
+        self.span = span
+        self.lease = lease
+        self.model = model
+        self.traced = traced
+        self.results: List[Any] = [None] * size
+        self.syn_pos: List[int] = []
+        self.syn_q: List[Query] = []
+        self.syn_num: List[int] = []
+        self.syn_trace: List[Optional[dict]] = []
+        self.use_ann = False
+        self.scan: Any = None
 
 
 def _knob(model, name: str, override):
@@ -190,7 +215,7 @@ class EmbeddingService:
             self._served_vocab_size = model.num_words
             if telemetry_path:
                 # sink + trace emitter + flight recorder BEFORE the batcher:
-                # the worker thread's span/observer hooks must find them
+                # the completer thread's span/observer hooks must find them
                 # armed from the very first dispatched batch
                 from glint_word2vec_tpu.obs.blackbox import FlightRecorder
                 from glint_word2vec_tpu.obs.sink import TelemetrySink
@@ -217,7 +242,7 @@ class EmbeddingService:
                               if self._served_sig else {}),
                            **({"ann": index.stats} if index else {}))
             self._batcher = BatchingScheduler(
-                self._dispatch,
+                self._dispatch_begin, finish=self._dispatch_finish,
                 max_batch=int(_knob(model, "serve_max_batch", max_batch)),
                 max_delay_ms=float(_knob(model, "serve_max_delay_ms",
                                          max_delay_ms)),
@@ -274,7 +299,8 @@ class EmbeddingService:
         """The batcher's per-dispatch observer: feeds the flight recorder's
         dispatch ring (the finest-grained trace of what the replica was
         doing right before death — the serving analog of the trainer's
-        per-dispatch records; worker thread only, so the counter is safe)."""
+        per-dispatch records; completer thread only, so the counter is
+        safe)."""
         self._dispatch_count += 1
         self._blackbox.note_dispatch(self._dispatch_count, batch_size,
                                      service_s, wait_s)
@@ -417,17 +443,26 @@ class EmbeddingService:
             self._watcher.mark_loaded(pre_sig)
         return model
 
-    # -- the batched dispatch (runs on the batcher worker thread) ----------------------
+    # -- the batched dispatch, in two halves (the batcher's worker, then its completer) --
 
-    def _dispatch(self, payloads: List[Tuple]) -> List[Any]:
-        """One coalesced batch under ONE lease: every request in the batch
-        is answered by the same model generation, and a swap landing
-        mid-batch waits for the lease to drain before the old buffers go.
+    def _dispatch_begin(self, payloads: List[Tuple]) -> "_PendingDispatch":
+        """The first half of one coalesced batch, under ONE lease taken
+        here and released at the end of :meth:`_dispatch_finish`, on
+        whichever thread that runs: every request in the batch is answered
+        by the same model generation, and a swap landing with batches in
+        flight waits for each one's lease to drain before the old buffers
+        go. Sorts the payloads, answers what needs no scan (``vec``, an
+        unknown word or op) and hands the ``syn`` queries to
+        ``find_synonyms_begin``, which returns with the scan enqueued. What
+        is left for the second half follows from what the batch holds: a
+        device result to fetch, or (the ANN arm, the host top-k route, a
+        batch with no ``syn`` query) nothing.
 
-        The whole of it is the span ``serve.dispatch`` (obs/spans.py; child
-        of the batcher's ``serve.batch``, parent of ``find_synonyms_batch``'s
-        row_fetch / scan_enqueue / result_fetch / reply_build): its self time
-        is the lease, the payload sort and the result slicing.
+        From here to the end of the second half is the span
+        ``serve.dispatch`` (obs/spans.py; child of the batcher's
+        ``serve.batch``, parent of the model's row_fetch / scan_enqueue /
+        result_fetch / reply_build): its self time is the lease, the
+        payload sort, the result slicing, and the wait between the halves.
 
         A ``syn`` payload may carry a 4th element — the cross-process trace
         context (obs/trace.py) — in which case the dispatch's wall time is
@@ -438,63 +473,90 @@ class EmbeddingService:
         from the ``serve.dispatch`` span's own clock reads."""
         traced = (self._span_emitter is not None
                   and any(len(p) > 3 for p in payloads))
-        with self._tracer.span("serve.dispatch", timed=traced,
-                               size=len(payloads)) as sp:
-            results, scanned, use_ann = self._dispatch_leased(payloads)
-        if traced:
-            name = "ann_probe" if use_ann else "exact_scan"
+        sp = self._tracer.open("serve.dispatch", timed=traced,
+                               size=len(payloads))
+        lease = contextlib.ExitStack()
+        try:
+            model, index = lease.enter_context(self._handle.lease())
+            pending = _PendingDispatch(sp, lease, model, traced, len(payloads))
+            self._begin_leased(payloads, pending, index)
+        except BaseException:
+            lease.close()
+            if sp is not None:
+                sp.close()
+            raise
+        if sp is not None:
+            sp.detach()
+        return pending
+
+    def _begin_leased(self, payloads: List[Tuple],
+                      pending: "_PendingDispatch", index) -> None:
+        """Fill ``pending``: the results known already, the scan begun for
+        the rest, and the wire trace context (or None) of each query it
+        answers."""
+        model, results, syn_q = pending.model, pending.results, pending.syn_q
+        for i, p in enumerate(payloads):
+            op = p[0]
+            if op == "syn":
+                q, num = p[1], p[2]
+                if (isinstance(q, str) and model.vocab.get(q) < 0
+                        and not model.composes_unseen):
+                    # per-request failure: an OOV word fails ITS caller,
+                    # never the batch (the batcher re-raises it there);
+                    # a subword model composes it from its n-grams
+                    results[i] = KeyError(f"{q} not in vocabulary")
+                    continue
+                pending.syn_pos.append(i)
+                syn_q.append(q)
+                pending.syn_num.append(int(num))
+                pending.syn_trace.append(p[3] if len(p) > 3 else None)
+            elif op == "vec":
+                try:
+                    results[i] = model.transform(p[1])
+                except KeyError as e:
+                    results[i] = e
+            else:
+                results[i] = ValueError(f"unknown op {op!r}")
+        pending.use_ann = self._ann_enabled and index is not None
+        if syn_q:
+            try:
+                pending.scan = model.find_synonyms_begin(
+                    syn_q, max(pending.syn_num), ann=pending.use_ann,
+                    nprobe=self._nprobe)
+            except Exception as e:  # noqa: BLE001 — delivered per caller
+                for i in pending.syn_pos:
+                    results[i] = e
+
+    def _dispatch_finish(self, pending: "_PendingDispatch") -> List[Any]:
+        """The second half: the scan's rows fetched (``find_synonyms_batch``
+        handed what begin began: the one place replies are handed out) and
+        sliced into each caller's result, the lease released, the
+        ``serve.dispatch`` span closed."""
+        results, scan, sp = pending.results, pending.scan, pending.span
+        try:
+            if scan is not None:
+                try:
+                    rows = pending.model.find_synonyms_batch(
+                        pending.syn_q, max(pending.syn_num), begun=scan)
+                except Exception as e:  # noqa: BLE001 — delivered per caller
+                    for i in pending.syn_pos:
+                        results[i] = e
+                else:
+                    for i, res, num in zip(pending.syn_pos, rows,
+                                           pending.syn_num):
+                        results[i] = res[:num]
+        finally:
+            pending.lease.close()
+            if sp is not None:
+                sp.close()
+        if pending.traced:
+            name = "ann_probe" if pending.use_ann else "exact_scan"
             t0_ns, dur_ns = int(sp.t0 * 1e9), int(sp.dur * 1e9)
-            for tr in scanned:
+            for tr in pending.syn_trace:
                 if tr is not None:
                     self._span_emitter.emit(tr["tid"], name, t0_ns, dur_ns,
                                             parent=tr.get("ps"))
         return results
-
-    def _dispatch_leased(self, payloads: List[Tuple]
-                         ) -> Tuple[List[Any], List[Optional[dict]], bool]:
-        """The batch's results, the wire trace context (or None) of each
-        query its scan answered, and whether the scan took the ANN arm."""
-        with self._handle.lease() as (model, index):
-            results: List[Any] = [None] * len(payloads)
-            syn_pos: List[int] = []
-            syn_q: List[Query] = []
-            syn_num: List[int] = []
-            syn_trace: List[Optional[dict]] = []
-            for i, p in enumerate(payloads):
-                op = p[0]
-                if op == "syn":
-                    q, num = p[1], p[2]
-                    if (isinstance(q, str) and model.vocab.get(q) < 0
-                            and not model.composes_unseen):
-                        # per-request failure: an OOV word fails ITS caller,
-                        # never the batch (the batcher re-raises it there);
-                        # a subword model composes it from its n-grams
-                        results[i] = KeyError(f"{q} not in vocabulary")
-                        continue
-                    syn_pos.append(i)
-                    syn_q.append(q)
-                    syn_num.append(int(num))
-                    syn_trace.append(p[3] if len(p) > 3 else None)
-                elif op == "vec":
-                    try:
-                        results[i] = model.transform(p[1])
-                    except KeyError as e:
-                        results[i] = e
-                else:
-                    results[i] = ValueError(f"unknown op {op!r}")
-            use_ann = self._ann_enabled and index is not None
-            if syn_pos:
-                kmax = max(syn_num)
-                try:
-                    rows = model.find_synonyms_batch(
-                        syn_q, kmax, ann=use_ann, nprobe=self._nprobe)
-                except Exception as e:  # noqa: BLE001 — delivered per caller
-                    for i in syn_pos:
-                        results[i] = e
-                else:
-                    for i, res, num in zip(syn_pos, rows, syn_num):
-                        results[i] = res[:num]
-            return results, syn_trace, use_ann
 
     # -- client surface ----------------------------------------------------------------
 

@@ -463,3 +463,95 @@ def test_row_sharded_table_takes_the_gather(table, partitioned, monkeypatch):
                 if " all-gather(" in line and f"[{syn0.shape[0]},16]" in line]
     assert gathered == []
     model.stop()
+
+
+# -- the batch call's two halves (find_synonyms_begin / find_synonyms_finish) --------
+
+
+@pytest.mark.parametrize("route", ["device_topk", "argpartition"])
+@pytest.mark.parametrize("table", ["float32", "sharded"])
+@pytest.mark.parametrize("batch", ["words_repeated", "vectors", "mixed",
+                                   "one_word", "longer_than_chunk"])
+def test_the_two_halves_are_the_batch_call_bit_for_bit(batch, table, route,
+                                                       monkeypatch):
+    """``find_synonyms_batch(q, k)`` is ``finish(begin(q, k))``: the same
+    replies to the last bit with the second half on another thread (the
+    serve batcher's completer) and a second call begun between the two."""
+    import threading
+    if route == "argpartition":
+        monkeypatch.setenv("GLINT_CPU_TOPK", "argpartition")
+    model, syn0 = _scan_model(table)
+    queries, num, chunk = _scan_batches(syn0)[batch]
+    want = model.find_synonyms_batch(queries, num, chunk=chunk)
+    first = model.find_synonyms_begin(queries, num, chunk=chunk)
+    second = model.find_synonyms_begin(queries[::-1], num, chunk=chunk)
+    out = {}
+
+    def finish(name, pending):
+        out[name] = model.find_synonyms_finish(pending)
+
+    threads = [threading.Thread(target=finish, args=(n, p))
+               for n, p in (("first", first), ("second", second))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+    assert out["first"] == want
+    assert [[w for w, _ in r] for r in out["second"]] == [
+        [w for w, _ in r] for r in want[::-1]]
+    model.stop()
+
+
+def test_a_call_of_many_chunks_keeps_two_parts_in_flight():
+    """Begin enqueues two parts; finish enqueues the next as it fetches one:
+    never a third score block, whatever the number of chunks."""
+    from glint_word2vec_tpu.obs.spans import default_tracer
+    tracer = default_tracer()
+    model, _ = _scan_model("float32")
+    queries = [f"w{i}" for i in range(10)]
+    want = [model.find_synonyms(q, 3) for q in queries]
+    tracer.configure(enabled=True)
+    tracer.clear()
+    try:
+        pending = model.find_synonyms_begin(queries, 3, chunk=2)
+        assert len(pending.parts) == 5 and len(pending.results) == 2
+        begun = [e["name"] for e in tracer.events()]
+        got = model.find_synonyms_finish(pending)
+        names = [e["name"] for e in tracer.events()]
+    finally:
+        tracer.configure(enabled=False)
+        tracer.clear()
+    assert begun == ["serve.row_fetch", "serve.scan_enqueue", "serve.scan_enqueue"]
+    fetch_enqueue_reply = ["serve.result_fetch", "serve.scan_enqueue", "serve.reply_build"]
+    assert names[3:] == fetch_enqueue_reply * 3 + [
+        "serve.result_fetch", "serve.reply_build"] * 2
+    assert [[w for w, _ in r] for r in got] == [[w for w, _ in r] for r in want]
+    model.stop()
+
+
+def test_the_halves_spans_name_the_callers_span_on_any_thread():
+    """``finish`` on another thread records its spans under the span that
+    enclosed ``begin``: by id, not by that thread's stack."""
+    import threading
+    from glint_word2vec_tpu.obs.spans import default_tracer
+    tracer = default_tracer()
+    model, _ = _scan_model("float32")
+    tracer.configure(enabled=True)
+    tracer.clear()
+    try:
+        with tracer.span("caller") as outer:
+            pending = model.find_synonyms_begin(["w1", "w2"], 3)
+        t = threading.Thread(target=model.find_synonyms_finish, args=(pending,))
+        t.start()
+        t.join(timeout=60)
+        evs = tracer.events()
+    finally:
+        tracer.configure(enabled=False)
+        tracer.clear()
+    assert not t.is_alive()
+    parents = {e["name"]: e["parent"] for e in evs if e["name"] != "caller"}
+    assert parents == {name: outer.id for name in (
+        "serve.row_fetch", "serve.scan_enqueue", "serve.result_fetch",
+        "serve.reply_build")}
+    model.stop()

@@ -648,6 +648,67 @@ def test_span_ids_parents_and_retroactive_record_across_threads():
             == pytest.approx(evs["batch"]["ts_s"], abs=1e-6))
 
 
+def test_a_span_detached_on_one_thread_is_closed_on_another():
+    """A region begun on one thread and ended on another (a serve batch): one
+    record, the opener's start, the closer's thread; each thread's stack its
+    own throughout; children name it by id."""
+    tr = Tracer(enabled=True)
+    assert tr.current() is None
+    sp = tr.open("batch", timed=True, size=2)
+    assert tr.current() == sp.id
+    with tr.span("first_half"):
+        pass
+    sp.detach()
+    sp.detach()                         # once is enough; twice changes nothing
+    assert tr.current() is None
+    with tr.span("next_on_the_opener"):
+        pass
+
+    def other():
+        with tr.span("second_half", parent=sp.id):
+            pass
+        time.sleep(0.002)
+        sp.set(inflight=1)
+        sp.close()
+        assert tr.current() is None
+
+    t = threading.Thread(target=other, name="closer")
+    t.start()
+    t.join(timeout=30)
+    assert not t.is_alive() and sp.recorded and sp.dur >= 0.002
+    evs = {e["name"]: e for e in tr.events()}
+    assert evs["batch"]["thread"] == "closer"
+    assert evs["batch"]["args"] == {"size": 2, "inflight": 1}
+    assert evs["batch"]["dur_s"] == pytest.approx(sp.dur)
+    assert evs["first_half"]["parent"] == evs["second_half"]["parent"] == sp.id
+    assert evs["next_on_the_opener"]["parent"] is None
+    # a timed span that nothing records crosses threads the same way
+    off = Tracer(enabled=False)
+    quiet = off.open("batch", timed=True)
+    quiet.detach()
+    t = threading.Thread(target=quiet.close)
+    t.start()
+    t.join(timeout=30)
+    assert quiet.dur >= 0 and not quiet.recorded and off.events() == []
+
+
+def test_a_detached_spans_annotation_covers_the_openers_part(live_trace):
+    """Under a live trace the annotation is left on the thread that entered
+    it; the ring holds the whole region."""
+    from glint_word2vec_tpu.obs.spans import default_tracer
+    tracer = default_tracer()
+    sp = tracer.open("t41.batch")
+    time.sleep(0.002)
+    sp.detach()
+    t = threading.Thread(target=lambda: (time.sleep(0.02), sp.close()))
+    t.start()
+    t.join(timeout=30)
+    host = live_trace()
+    (ring,) = [e for e in tracer.events() if e["name"] == "t41.batch"]
+    (ann,) = [(s, e) for n, s, e in host if n == "t41.batch"]
+    assert ring["dur_s"] >= 0.022 > 0.02 > ann[1] - ann[0] >= 0.002
+
+
 def test_inactive_span_builds_nothing(monkeypatch):
     """Telemetry off and no live trace: the shared no-op, no annotation, no
     record; ``timed=True`` still hands its caller the region's times."""

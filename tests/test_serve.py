@@ -16,6 +16,7 @@
 import json
 import os
 import shutil
+import sys
 import threading
 import time
 
@@ -748,13 +749,30 @@ def test_service_records_every_serve_span_under_a_live_trace(
     evs = serve_tracer.events()
     by_id = {e["id"]: e for e in evs}
     assert {e["name"] for e in evs} >= _SERVE_TABLE
-    # parents: by the worker's stack, and across threads for the tickets
+    # parents: by the worker's stack in a batch's first half, by id in the
+    # half the completer runs, and across threads for the tickets
     parent_name = {e["name"]: by_id[e["parent"]]["name"]
                    for e in evs if e["parent"] in by_id}
     assert parent_name["serve.dispatch"] == "serve.batch"
     for leaf in ("serve.row_fetch", "serve.scan_enqueue",
                  "serve.result_fetch", "serve.reply_build"):
         assert parent_name[leaf] == "serve.dispatch"
+    # every span of the table once per batch, each child under ITS batch's
+    # dispatch; the worker records what ends on it, the completer the rest
+    dispatch_of = {e["id"]: e["parent"] for e in evs if e["name"] == "serve.dispatch"}
+    for name, thread in (("serve.dispatch", "glint-serve-batcher-completer"),
+                         ("serve.row_fetch", "glint-serve-batcher"),
+                         ("serve.scan_enqueue", "glint-serve-batcher"),
+                         ("serve.result_fetch", "glint-serve-batcher-completer"),
+                         ("serve.reply_build", "glint-serve-batcher-completer")):
+        mine = [e for e in evs if e["name"] == name]
+        assert {e["thread"] for e in mine} == {thread}
+        of_batch = sorted(dispatch_of.get(e["parent"], e["parent"]) for e in mine)
+        assert of_batch == sorted(e["id"] for e in evs if e["name"] == "serve.batch")
+    for e in evs:
+        if e["name"] == "serve.batch":
+            assert e["thread"] == "glint-serve-batcher-completer"
+            assert e["args"]["inflight"] in (0, 1)
     waits = [e for e in evs if e["name"] == "serve.queue_wait"]
     batches = {e["id"]: e for e in evs if e["name"] == "serve.batch"}
     assert len(waits) == 6 == sum(b["args"]["size"] for b in batches.values())
@@ -903,3 +921,461 @@ def test_batcher_hooks_are_fed_the_batch_spans_times(serve_tracer):
     assert by["batch_service"][1] == int(service_s * 1e9)
     assert by["queue_wait"][0] + by["queue_wait"][1] == by["batch_service"][0]
     assert serve_tracer.events() == []      # telemetry off, no live trace
+
+
+# -- the handler's two halves, overlapped (serve/batcher.py; docs/serving.md §1) -------
+
+
+class _Halves:
+    """A two-half handler that records its calls; ``hold`` maps a payload to
+    an event its batch's finish waits for."""
+
+    def __init__(self, hold=None, fail_begin=(), fail_finish=()):
+        self.hold = hold or {}
+        self.fail_begin, self.fail_finish = set(fail_begin), set(fail_finish)
+        self.log = []                   # ("begin" | "finish" | "finished", batch)
+        self.begun = {}                 # payload -> event set once its begin ran
+        self._lock = threading.Lock()
+
+    def seen(self, payload):
+        with self._lock:
+            return self.begun.setdefault(payload, threading.Event())
+
+    def begin(self, batch):
+        with self._lock:
+            self.log.append(("begin", tuple(batch)))
+        for x in batch:
+            self.seen(x).set()
+        if self.fail_begin & set(batch):
+            raise RuntimeError(f"begin failed on {batch}")
+        return list(batch)
+
+    def finish(self, pending):
+        with self._lock:
+            self.log.append(("finish", tuple(pending)))
+        for x in pending:
+            if x in self.hold:
+                assert self.hold[x].wait(30)
+        if self.fail_finish & set(pending):
+            raise RuntimeError(f"finish failed on {pending}")
+        with self._lock:
+            self.log.append(("finished", tuple(pending)))
+        return [x * 10 for x in pending]
+
+    def scheduler(self, **kw):
+        kw.setdefault("max_batch", 1)
+        kw.setdefault("max_delay_ms", 0.0)
+        return BatchingScheduler(self.begin, finish=self.finish, **kw).start()
+
+
+def _threads_alive(b):
+    return [t.is_alive() for t in (b._thread, b._completer)]
+
+
+def test_batcher_begins_the_next_batch_while_a_finish_is_held_and_never_a_third():
+    gate = threading.Event()
+    h = _Halves(hold={1: gate})
+    b = h.scheduler()
+    try:
+        t1 = b.submit_async(1)
+        assert h.seen(1).wait(30)
+        t2 = b.submit_async(2)
+        # batch 2 begins while batch 1's finish has not returned ...
+        assert h.seen(2).wait(30) and ("finished", (1,)) not in h.log
+        t3 = b.submit_async(3)
+        # ... and a third does not while two are in flight
+        assert not h.seen(3).wait(0.2)
+        assert b.stats()["queue_depth"] == 1 and not t1.done.is_set()
+        gate.set()
+        assert [b.wait(t, 30) for t in (t1, t2, t3)] == [10, 20, 30]
+        assert h.log.index(("begin", (2,))) < h.log.index(("finished", (1,)))
+        assert h.log.index(("begin", (3,))) > h.log.index(("finished", (1,)))
+        st = b.stats()
+        assert st["batches"] == 3 and st["overlapped_batches"] >= 1
+    finally:
+        gate.set()
+        b.stop()
+
+
+def test_batcher_pairs_results_and_finishes_in_close_order_under_overlap():
+    rng = np.random.default_rng(3)
+    naps = {i: float(rng.uniform(0, 0.004)) for i in range(120)}
+    begun, finished, live = [], [], []
+
+    def begin(batch):
+        begun.append(tuple(batch))
+        live.append(len(begun) - len(finished))
+        return list(batch)
+
+    def finish(pending):
+        time.sleep(naps[pending[0]])
+        finished.append(tuple(pending))
+        return [("r", x) for x in pending]
+
+    b = BatchingScheduler(begin, finish=finish, max_batch=4, max_delay_ms=0.5,
+                          max_queue=256).start()
+    results = {}
+
+    def client(i):
+        results[i] = b.submit(i, timeout=60)
+
+    threads = [threading.Thread(target=client, args=(i,)) for i in range(120)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)         # more interleavings than cores give
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+        b.stop()
+    assert results == {i: ("r", i) for i in range(120)}     # item i, result i
+    assert max(live) == 2               # two in flight, never a third
+    assert finished == begun and sum(map(len, begun)) == 120
+    st = b.stats()
+    assert st["completed"] == 120 and st["batches"] == len(begun)
+    assert 0 < st["overlapped_batches"] < st["batches"]
+
+
+@pytest.mark.parametrize("half", ["begin", "finish"])
+def test_batcher_an_exception_in_either_half_reaches_only_its_batch(half):
+    h = _Halves(**{f"fail_{half}": {2}})
+    b = h.scheduler()
+    try:
+        tickets = [b.submit_async(i) for i in (1, 2, 3)]
+        assert b.wait(tickets[0], 30) == 10
+        with pytest.raises(RuntimeError, match=f"{half} failed"):
+            b.wait(tickets[1], 30)
+        assert b.wait(tickets[2], 30) == 30
+        assert _threads_alive(b) == [True, True]            # both live on
+        assert b.submit(4) == 40
+        st = b.stats()
+        assert st["errors"] == 1 and st["completed"] == 3 and st["batches"] == 4
+    finally:
+        b.stop()
+
+
+def test_batcher_stop_with_two_batches_in_flight_serves_both_then_refuses():
+    gate = threading.Event()
+    h = _Halves(hold={1: gate})
+    b = h.scheduler()
+    t1, t2 = b.submit_async(1), None
+    try:
+        assert h.seen(1).wait(30)
+        t2 = b.submit_async(2)
+        assert h.seen(2).wait(30)
+        t3 = b.submit_async(3)                  # admitted, still in the queue
+        worker, completer = b._thread, b._completer
+        stopper = threading.Thread(target=b.stop)
+        stopper.start()
+        deadline = time.monotonic() + 5
+        while not b._stopping and time.monotonic() < deadline:
+            time.sleep(0.002)
+        with pytest.raises(ServiceClosed):
+            b.submit_async(4)                   # refused during the drain
+        assert not t1.done.is_set()
+        gate.set()
+        stopper.join(timeout=30)
+        assert not stopper.is_alive()
+        assert [b.wait(t, 5) for t in (t1, t2, t3)] == [10, 20, 30]
+        assert not worker.is_alive() and not completer.is_alive()
+        with pytest.raises(ServiceClosed):
+            b.submit(5)
+        assert b.stop() == 0                    # idempotent, nothing leaked
+    finally:
+        gate.set()
+        b.stop()
+
+
+def test_batcher_tickets_that_arrive_while_both_slots_are_full_leave_as_one_batch():
+    gate = threading.Event()
+    h = _Halves(hold={1: gate})
+    b = h.scheduler(max_batch=16, max_delay_ms=1.0)
+    try:
+        t1 = b.submit_async(1)
+        assert h.seen(1).wait(30)
+        t2 = b.submit_async(2)
+        assert h.seen(2).wait(30)
+        # both slots taken: these outlive the 1 ms deadline many times over
+        later = []
+        for x in range(3, 9):
+            later.append(b.submit_async(x))
+            time.sleep(0.004)
+        assert b.stats()["queue_depth"] == 6
+        gate.set()
+        assert [b.wait(t, 30) for t in [t1, t2] + later] == [
+            10 * x for x in range(1, 9)]
+        begins = [batch for what, batch in h.log if what == "begin"]
+        assert begins == [(1,), (2,), (3, 4, 5, 6, 7, 8)]
+    finally:
+        gate.set()
+        b.stop()
+
+
+def test_batcher_one_half_handler_goes_through_the_completer_too():
+    b = BatchingScheduler(lambda batch: [x + 1 for x in batch], max_batch=4,
+                          max_delay_ms=1.0).start()
+    try:
+        assert [b.submit(i) for i in range(5)] == [1, 2, 3, 4, 5]
+        assert _threads_alive(b) == [True, True]
+        assert b.stats()["overlapped_batches"] == 0          # one caller in turn
+    finally:
+        assert b.stop() == 0
+
+
+def test_batch_spans_overlap_with_their_parents_intact(serve_tracer):
+    """Two batches in flight: one serve.batch per batch, begun on the worker
+    and recorded by the completer, ``inflight`` 0 then 1; each ticket's wait
+    names its own batch."""
+    gate = threading.Event()
+    h = _Halves(hold={1: gate})
+    serve_tracer.configure(enabled=True)
+    b = h.scheduler()
+    try:
+        t1 = b.submit_async(1)
+        assert h.seen(1).wait(30)
+        t2 = b.submit_async(2)
+        assert h.seen(2).wait(30)
+        gate.set()
+        assert [b.wait(t1, 30), b.wait(t2, 30)] == [10, 20]
+    finally:
+        gate.set()
+        b.stop()
+    evs = serve_tracer.events()
+    batches = [e for e in evs if e["name"] == "serve.batch"]
+    assert [e["args"] for e in batches] == [
+        {"size": 1, "inflight": 0, "inflight_share": 0.5},
+        {"size": 1, "inflight": 1, "inflight_share": 1.0}]
+    first, second = batches
+    assert second["ts_s"] < first["ts_s"] + first["dur_s"]   # they overlap
+    assert {e["thread"] for e in batches} == {"glint-serve-batcher-completer"}
+    waits = [e for e in evs if e["name"] == "serve.queue_wait"]
+    assert [w["parent"] for w in waits] == [first["id"], second["id"]]
+    # the worker's stack is empty again: its next span has no parent
+    assert [e["parent"] for e in evs if e["name"] == "serve.coalesce"] == [None] * 2
+
+
+def _hold_finish(monkeypatch, model):
+    """Hold ``model.find_synonyms_finish`` behind the event returned."""
+    gate = threading.Event()
+    real = model.find_synonyms_finish
+
+    def held(pending):
+        assert gate.wait(30)
+        return real(pending)
+
+    monkeypatch.setattr(model, "find_synonyms_finish", held)
+    return gate
+
+
+def _in_flight(svc, want):
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline:
+        if svc._batcher._closed_batches - svc._batcher._batches >= want:
+            return True
+        time.sleep(0.002)
+    return False
+
+
+def test_service_swap_waits_for_the_leases_of_both_batches_in_flight(monkeypatch):
+    old, new = make_model(v=300, d=8, seed=1), make_model(v=300, d=8, seed=2)
+    want = [old.find_synonyms("w1", 3), old.find_synonyms("w2", 3)]
+    gate = _hold_finish(monkeypatch, old)
+    svc = EmbeddingService(model=old, ann=False, max_batch=1, max_delay_ms=0.0)
+    try:
+        t1 = svc.synonyms_async("w1", 3)
+        t2 = svc.synonyms_async("w2", 3)
+        assert _in_flight(svc, 2)
+        svc._handle.swap(new)
+        # two leases out: the old generation stays whole under the swap
+        assert not old._stopped and svc._handle.models_released == 0
+        assert svc.synonyms_async("w3", 3) is not None       # queued, for `new`
+        gate.set()
+        assert [svc.wait_result(t1), svc.wait_result(t2)] == want
+        deadline = time.monotonic() + 10
+        while not old._stopped and time.monotonic() < deadline:
+            time.sleep(0.002)
+        assert old._stopped and svc._handle.models_released == 1
+        assert svc.synonyms("w3", 3) == new.find_synonyms("w3", 3)
+    finally:
+        gate.set()
+        svc.close()
+        new.stop()
+
+
+def test_service_two_halves_answer_what_the_model_answers(monkeypatch):
+    """Words, a vector, an OOV word, a ``vec`` and an unknown op in one
+    batch: each caller its own result, the scan's through both halves."""
+    model = make_model(v=300, d=8)
+    vec = np.asarray(model.syn0[5])
+    svc = EmbeddingService(model=model, ann=False, max_batch=8, max_delay_ms=50.0)
+    try:
+        tickets = [svc.synonyms_async("w1", 4), svc.synonyms_async(vec, 2),
+                   svc.synonyms_async("nope", 4),
+                   svc._batcher.submit_async(("vec", "w7")),
+                   svc._batcher.submit_async(("bogus",))]
+        # the batch's own scan: the model's, through both halves
+        rows = model.find_synonyms_batch(["w1", vec], 4)
+        assert svc.wait_result(tickets[0]) == rows[0]
+        assert svc.wait_result(tickets[1]) == rows[1][:2]
+        with pytest.raises(KeyError, match="nope"):
+            svc.wait_result(tickets[2])
+        np.testing.assert_array_equal(svc.wait_result(tickets[3]),
+                                      model.transform("w7"))
+        with pytest.raises(ValueError, match="unknown op"):
+            svc.wait_result(tickets[4])
+        assert svc.stats()["overlapped_batches"] == 0
+    finally:
+        svc.close()
+        model.stop()
+
+
+def test_every_reply_of_the_service_is_handed_out_by_find_synonyms_batch(monkeypatch):
+    """The second half goes through ``find_synonyms_batch(begun=...)``: what
+    wraps that one method (the benchmark's altered-answer test does) sees
+    every reply, the service's too, and the scan is not begun twice."""
+    model = make_model(v=300, d=8)
+    want = model.find_synonyms("w4", 3)
+    real, begins = Word2VecModel.find_synonyms_batch, []
+    real_begin = Word2VecModel.find_synonyms_begin
+
+    def tagged(self, queries, num, **kw):
+        assert kw["begun"] is not None and queries == ["w4"]
+        return [[("tag", 0.0)] + row for row in real(self, queries, num, **kw)]
+
+    def counted(self, *a, **kw):
+        begins.append(threading.current_thread().name)
+        return real_begin(self, *a, **kw)
+
+    monkeypatch.setattr(Word2VecModel, "find_synonyms_batch", tagged)
+    monkeypatch.setattr(Word2VecModel, "find_synonyms_begin", counted)
+    svc = EmbeddingService(model=model, ann=False)
+    try:
+        assert svc.synonyms("w4", 3) == [("tag", 0.0)] + want[:2]
+    finally:
+        svc.close()
+        model.stop()
+    assert begins == ["glint-serve-batcher"]
+
+
+@pytest.mark.parametrize("arm", ["ann", "host_topk"])
+def test_service_arms_with_no_device_result_do_their_work_in_begin(
+        arm, monkeypatch, serve_tracer):
+    """The ANN arm and the host top-k route leave nothing to fetch: chosen
+    from what the batch holds, and the completer's finish hands it back."""
+    model = make_model(v=600, d=16)
+    if arm == "host_topk":
+        monkeypatch.setenv("GLINT_CPU_TOPK", "argpartition")
+    want = model.find_synonyms("w3", 5)
+    serve_tracer.configure(enabled=True)
+    svc = EmbeddingService(model=model, ann=arm == "ann", ann_centroids=16,
+                           nprobe=16)
+    try:
+        got = svc.synonyms("w3", 5)
+    finally:
+        svc.close()
+        model.stop()
+    assert [w for w, _ in got] == [w for w, _ in want]
+    by = {e["name"]: e["thread"] for e in serve_tracer.events()}
+    if arm == "ann":
+        assert "serve.result_fetch" not in by
+        assert by["serve.ann_search"] == by["serve.reply_build"] == "glint-serve-batcher"
+    else:
+        assert by["serve.scan_enqueue"] == "glint-serve-batcher"
+        assert by["serve.result_fetch"] == "glint-serve-batcher-completer"
+
+
+# -- the benchmark's span reader over two batches in flight ----------------------------
+# (benchmark/readers/program_spans.py is read-only to a PR that claims a gain, and so
+# is its test file: these cases live here)
+
+
+_BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                      "benchmark")
+
+
+def _program_spans():
+    """The benchmark's reader module (benchmark/readers/program_spans.py)."""
+    if _BENCH not in sys.path:
+        sys.path.insert(0, _BENCH)
+    from readers import program_spans
+    return program_spans
+
+
+def _ev(name, ts, dur, id_, parent=None, tid=1, **args):
+    e = {"name": name, "tid": tid, "thread": f"t{tid}", "ts_s": ts, "dur_s": dur,
+         "id": id_, "parent": parent}
+    if args:
+        e["args"] = args
+    return e
+
+
+# the worker (tid 1) begins batch 7 while the completer (tid 3) still fetches
+# batch 1; each span is recorded by the thread that ended it, its children
+# name it by id
+_OVERLAPPING = [
+    _ev("serve.row_fetch", 0.5, 1.0, 3, parent=2, tid=1, ops=1),
+    _ev("serve.scan_enqueue", 1.5, 0.5, 4, parent=2, tid=1, queries=4, topk_rows=100),
+    _ev("serve.row_fetch", 4.5, 1.0, 9, parent=8, tid=1, ops=1),
+    _ev("serve.scan_enqueue", 5.5, 0.5, 10, parent=8, tid=1, queries=2, topk_rows=100),
+    _ev("serve.result_fetch", 2.0, 5.0, 5, parent=2, tid=3),
+    _ev("serve.reply_build", 7.0, 1.0, 6, parent=2, tid=3),
+    _ev("serve.dispatch", 0.5, 7.5, 2, parent=1, tid=3, size=4),
+    _ev("serve.batch", 0.0, 9.0, 1, tid=3, size=4, inflight=0, inflight_share=0.5),
+    _ev("serve.queue_wait", -2.0, 2.0, 13, parent=1, tid=3, request=1),
+    _ev("serve.result_fetch", 8.0, 4.0, 11, parent=8, tid=3),
+    _ev("serve.reply_build", 12.0, 1.0, 12, parent=8, tid=3),
+    _ev("serve.dispatch", 4.5, 8.5, 8, parent=7, tid=3, size=2),
+    _ev("serve.batch", 4.0, 10.0, 7, tid=3, size=2, inflight=1, inflight_share=1.0),
+]
+
+
+@pytest.mark.parametrize("args, want", [
+    ({"span": "serve.result_fetch", "stat": "ms_per", "per": "serve.batch"}, 4500.0),
+    ({"span": "serve.row_fetch", "stat": "ms_per", "per": "serve.batch"}, 1000.0),
+    # the batches begun while an earlier one's result was not in yet: 1 of 2
+    ({"span": "serve.batch", "stat": "arg_mean", "arg": "inflight"}, 0.5),
+    ({"span": "serve.batch", "stat": "arg_mean", "arg": "inflight_share"}, 0.75),
+    ({"span": "serve.scan_enqueue", "stat": "arg_mean", "arg": "topk_rows"}, 100.0),
+    ({"span": "serve.queue_wait", "stat": "mean_ms"}, 2000.0),
+    # batch 1: 9 - 7.5; dispatch 2: 7.5 - (1 + .5 + 5 + 1); batch 7: 10 - 8.5;
+    # dispatch 8: 8.5 - (1 + .5 + 4 + 1): 5 of the 19 s of serve.batch
+    ({"span": ["serve.batch", "serve.dispatch"], "stat": "self_share",
+      "over": "serve.batch"}, 5.0 / 19.0),
+])
+def test_the_benchmarks_reader_over_two_batches_in_flight(args, want):
+    """Overlapping serve.batch spans on two threads: every stat the serve
+    layers read goes by name and by parent id, so it is finite and the same."""
+    program_spans = _program_spans()
+    assert program_spans.reduce_events(args, _OVERLAPPING) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("name", ["dispatch_overlap_share",
+                                  "subword_query_dispatch_overlap_share"])
+def test_the_overlap_layers_read_what_the_batcher_records(name, serve_tracer):
+    """The two layer files name the reader, span and arg the batcher's own
+    spans carry: fed the ring of a run with two batches in flight they read
+    its share, and nothing from a program whose spans have no such arg."""
+    program_spans = _program_spans()
+    with open(os.path.join(_BENCH, "layers", name + ".json")) as f:
+        layer = json.load(f)
+    assert layer["reader"] == "program_spans"
+    gate = threading.Event()
+    h = _Halves(hold={1: gate})
+    serve_tracer.configure(enabled=True)
+    b = h.scheduler()
+    try:
+        t1 = b.submit_async(1)
+        assert h.seen(1).wait(30)
+        t2 = b.submit_async(2)
+        assert h.seen(2).wait(30)
+        gate.set()
+        assert [b.wait(t1, 30), b.wait(t2, 30)] == [10, 20]
+    finally:
+        gate.set()
+        b.stop()
+    assert program_spans.read(layer["args"], {"slice": {"window_s": 1.0}}) == 0.75
+    parents = [_ev("serve.batch", 0.0, 1.0, 1, size=4)]
+    assert program_spans.reduce_events(layer["args"], parents) is None

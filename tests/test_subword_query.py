@@ -132,6 +132,30 @@ def test_a_mixed_chunk_is_one_call_and_every_reply_the_references(world, spans):
     assert got == {"unseen": 3, "list_rows": rows, "overflow": 1}
 
 
+@pytest.mark.parametrize("mix", ["words", "unseen", "mixed_with_a_vector_and_overflow"])
+def test_the_two_halves_are_the_batch_call_bit_for_bit_on_a_subword_model(world, mix):
+    """finish(begin(q, k)) == find_synonyms_batch(q, k), the second half on
+    another thread, over words, strings the vocabulary lacks, a vector and a
+    string over the list capacity; the counters count a batch once."""
+    import threading
+    queries = {"words": world.strings[3:9],
+               "unseen": ["zzqx", world.strings[100] + "q", "q", world.strings[7]],
+               "mixed_with_a_vector_and_overflow":
+                   [make(world) for _, make in sorted(QUERIES.items())]}[mix]
+    before = dict(world.model.query_counts)
+    want = world.model.find_synonyms_batch(queries, NUM)
+    once = {k: world.model.query_counts[k] - before[k] for k in before}
+    pending = world.model.find_synonyms_begin(queries, NUM)
+    out = []
+    t = threading.Thread(
+        target=lambda: out.append(world.model.find_synonyms_finish(pending)))
+    t.start()
+    t.join(timeout=60)
+    assert not t.is_alive() and out == [want]
+    assert {k: world.model.query_counts[k] - before[k] for k in before} == {
+        k: 2 * n for k, n in once.items()}
+
+
 @pytest.mark.parametrize("mix, ops", [
     (("a_word", "a_rare_word"), 1),
     (("a_word", "an_unseen_string", "a_single_ngram"), 2),

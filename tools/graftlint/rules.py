@@ -60,12 +60,15 @@ class R1ThreadPools:
         # training data, so the worker-count determinism contract R1 guards
         # is untouched (docs/observability.md)
         ("glint_word2vec_tpu/obs/statusd.py", "StatusServer.start"),
-        # the serving tier's two documented owners (docs/serving.md): the
-        # micro-batcher worker orders request/response PAIRING only (each
-        # caller gets exactly its own result; batch composition is
-        # timing-dependent by design), and the hot-reload watcher stats a
-        # file + invokes the swap callback — both READ-only on params, the
-        # training determinism contract untouched
+        # the serving tier's documented owners (docs/serving.md): the
+        # micro-batcher's worker and its completer, both started by
+        # BatchingScheduler.start (the worker closes a batch and runs the
+        # handler's first half, the completer runs the second and releases
+        # the callers, in the order the batches were closed), order
+        # request/response PAIRING only (each caller gets exactly its own
+        # result; batch composition is timing-dependent by design), and the
+        # hot-reload watcher stats a file + invokes the swap callback — all
+        # READ-only on params, the training determinism contract untouched
         ("glint_word2vec_tpu/serve/batcher.py", "BatchingScheduler.start"),
         ("glint_word2vec_tpu/serve/reload.py", "CheckpointWatcher.start"),
         # the serving FLEET's two documented owners (docs/serving.md §5,

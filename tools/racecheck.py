@@ -20,9 +20,9 @@ EXECUTED lock-discipline evidence:
   min-of-k kills scheduler noise, parity threshold leaves headroom for
   timer jitter).
 
-``--smoke`` builds an in-process stack — batcher + reload watcher +
-statusd + telemetry sink — and hammers it from query/scrape/dump/publish
-threads for a bounded, seeded burst (tier-1 + the CI concurrency job).
+``--smoke`` builds an in-process stack — batcher (its worker and its
+completer) + reload watcher + statusd + telemetry sink — and hammers it from
+query/scrape/dump/publish threads for a bounded, seeded burst (tier-1 + the CI concurrency job).
 The full run additionally drives the serve-reload and fleet-kill chaos
 phases (tools/chaos_run.py) with instrumentation on, exported to replica
 subprocesses via the environment.
@@ -140,8 +140,12 @@ def _smoke_stack(workdir: str, seed: int, perturb: float,
         return run
 
     def queries():
+        # a vector is answered in the batch's first half; a synonym query's
+        # scan is begun by the batcher's worker and fetched by its completer,
+        # which also hands back the lease: both halves run under the reloads
         for w in words:
             service.vector(w, timeout=30.0)
+            service.synonyms(w, 3, timeout=30.0)
 
     def scrapes():
         urllib.request.urlopen(
