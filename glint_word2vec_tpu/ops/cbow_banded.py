@@ -246,7 +246,11 @@ def cbow_step_banded_core(
     (PERF.md §6, PR 33). Where ``shape.slot_cap`` is set the scatter takes the
     lists' slots sorted by row and cut to that many (a block with more live
     slots takes them all; :mod:`.subword`), and ``StepMetrics.subword_slots``
-    says how many it was handed. With neither, the program is the one it was.
+    says how many it was handed; where ``shape.tail_cap`` is set the gather
+    reads every token's first group of rows and, that many tokens a pass, the
+    later groups of the tokens that have them, and
+    ``StepMetrics.subword_gather_slots`` says how many it was handed. With
+    neither, the program is the one it was.
     """
     syn0, syn1, pos_w = params
     T = tokens.shape[0]
@@ -382,5 +386,7 @@ def cbow_step_banded_core(
         subword_rows=None if subword is None else sw_plan.live_rows,
         subword_slots=(None if subword is None
                        else sw.scatter_slots(sw_plan, sw_shape)),
+        subword_gather_slots=(None if subword is None
+                              else sw.gather_slots(sw_plan, sw_shape)),
     )
     return EmbeddingPair(new_syn0, new_syn1, new_pos), metrics

@@ -265,6 +265,10 @@ class StepMetrics(NamedTuple):
     # scatter_slots: the slot capacity, or every slot of the block); None
     # everywhere but the banded subword step
     subword_slots: Optional[jax.Array] = None
+    # slots the same block's list gather was handed (ops/subword.py
+    # gather_slots: every token's first group and the tail capacity's later
+    # groups a pass, or every slot of the block); None everywhere but that step
+    subword_gather_slots: Optional[jax.Array] = None
     # live (pair, node) terms of a hierarchical-softmax step: the summed path
     # lengths of its real pairs' contexts (config.loss="hs"; ops/hs.py); None
     # on every other step

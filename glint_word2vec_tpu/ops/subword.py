@@ -64,8 +64,23 @@ in that order. A sorted scatter's padding slot costs ~19.6 ns on the chip and
 a live one ~82: 28.2 ms a step at 393,216 slots where all 1,048,736 cost 41.7
 and the broadcast block 3.0 more (PERF.md §6, PR 36). A block with more live
 slots than the capacity (``plan.live_rows`` counts them) takes the whole form
-under one ``lax.cond``: same rows, same sums. The gather keeps every slot:
-its sum needs token order (ROADMAP A11 (a)).
+under one ``lax.cond``: same rows, same sums.
+
+Its gather takes ``tail_cap`` where the trainer derives one. The sum needs
+token order, so the scatter's sort cannot serve it; but a list's FIRST group of
+8 rows holds nearly all that is live (n-grams of 5: only a word of 10 letters
+or more has a second, 5% of the kept tokens), so every token's first group is
+gathered densely ([T, 8] slots) and summed, and the tokens with later groups
+(``plan.tails`` counts them) are compacted as the heads are, their later
+groups' row ids read from the block, gathered as a second small block
+([tail_cap, (max_groups - 1) · 8] slots), summed, and added into their tokens'
+sums by a scatter-add of sorted rows, before the one division by |G(w)|:
+557,136 slots handed to the gather where 1,048,736 were, 7.5 ms of a 63.7 ms
+step (PERF.md §6, PR 43). The second block sits in a loop of dynamic trip
+count, ``tail_cap`` tokens a pass: one pass where the trainer's rule held,
+another for a block with more, so no block has a whole form of its own and no
+second branch is built (the loop came in 0.05 ms under the same block beside a
+whole-gather branch under ``lax.cond``, and 0.4 under passes of 2,048).
 """
 
 from __future__ import annotations
@@ -105,6 +120,11 @@ class SubwordShape(NamedTuple):
     # slots the per-run form's scatter takes, sorted by row and cut to their
     # live prefix; 0: not built (the trainer sets it for a CBOW token block)
     slot_cap: int = 0
+    # heads whose later groups a pass of the per-run form's gather reads as a
+    # second block, beside every head's first group; 0: not built, the gather
+    # reads the whole block (the trainer sets it for a CBOW token block; it
+    # is not read beside word_cap)
+    tail_cap: int = 0
 
 
 # CenterPlan.form: which of the three forms the batch takes
@@ -137,6 +157,9 @@ class CenterPlan(NamedTuple):
     # lists of that form's heads (the first word_cap of them per word)
     form: Optional[jax.Array] = None
     words: Optional[WordPlan] = None
+    # where shape.tail_cap is set (and lists have more than one group): int32,
+    # heads whose list has a second group
+    tails: Optional[jax.Array] = None
 
 
 def _acc(syn0: jax.Array):
@@ -224,7 +247,9 @@ def plan_centers(centers: jax.Array, table: SubwordTable,
         pair_head=jnp.minimum(jnp.cumsum(head.astype(jnp.int32)) - 1, hcap - 1),
         src=src, rows=rows, inv=inv, heads=heads,
         live_rows=jnp.where(fits, table.counts[word].sum(),
-                            table.counts[centers].sum()).astype(jnp.float32))
+                            table.counts[centers].sum()).astype(jnp.float32),
+        tails=((table.counts[word] > GROUP).sum(dtype=jnp.int32)
+               if shape.tail_cap and shape.max_groups > 1 else None))
 
 
 def _plan_by_word(centers, table, shape, dtype, pos, head, heads, src, word):
@@ -286,6 +311,22 @@ def scatter_slots(plan: CenterPlan, shape: SubwordShape) -> jax.Array:
         plan.pos.shape[0] * shape.max_groups * GROUP))
 
 
+def gather_slots(plan: CenterPlan, shape: SubwordShape) -> jax.Array:
+    """float32: slots the list gather of the per-run or the plain form is
+    handed, live or padding: every slot of the heads' block (of every pair's
+    list, plain), or, where the plan counts the heads with later groups
+    (``shape.tail_cap``), every head's first group and ``tail_cap`` heads'
+    later groups a pass of the loop over them."""
+    heads, slots = plan.rows.shape
+    handed = jnp.float32(plan.rows.size)
+    if plan.tails is not None:
+        passes = -(-plan.tails // shape.tail_cap)
+        handed = (heads * GROUP + passes * (shape.tail_cap * (slots - GROUP))
+                  ).astype(jnp.float32)
+    return jnp.where(plan.fits, handed, jnp.float32(
+        plan.pos.shape[0] * shape.max_groups * GROUP))
+
+
 def center_vectors(syn0: jax.Array, centers: jax.Array, table: SubwordTable,
                    shape: SubwordShape, plan: CenterPlan,
                    compute_dtype: jnp.dtype) -> jax.Array:
@@ -294,19 +335,51 @@ def center_vectors(syn0: jax.Array, centers: jax.Array, table: SubwordTable,
     the table's dtype if wider)."""
     d, acc = syn0.shape[1], _acc(syn0)
 
-    def mean_of(syn0, rows, inv):
+    def sums_of(syn0, rows):
         with jax.named_scope("subword.gather"):
             got = syn0.at[rows].get(mode="fill", fill_value=0)
         with jax.named_scope("subword.mean"):
-            return (got.astype(acc).sum(axis=1) * inv[:, None]
-                    ).astype(compute_dtype)
+            return got.astype(acc).sum(axis=1)
+
+    def mean_of(syn0, rows, inv):
+        return (sums_of(syn0, rows) * inv[:, None]).astype(compute_dtype)
 
     def per_word(syn0):
         w = shape.word_cap
         return mean_of(syn0, plan.rows[:w], plan.inv[:w])[plan.words.pair_head]
 
+    def mean_by_groups(syn0):
+        """:func:`mean_of` of the heads' block in two parts: every head's
+        first group, gathered densely, and the later groups of the (few)
+        heads that have them, ``tail_cap`` heads a pass of a loop that runs
+        while there are more (one pass where the trainer's rule held; none
+        for a block without one), each pass's sums added into its heads' by
+        a scatter-add of sorted rows."""
+        n, c, g = plan.rows.shape[0], shape.tail_cap, shape.max_groups
+        sums = sums_of(syn0, plan.rows[:, :GROUP])
+        has_later = plan.rows[:, GROUP] != NO_ROW       # lists are contiguous
+        heads = jnp.pad(jnp.sort(jnp.where(
+            has_later, jnp.arange(n, dtype=jnp.int32), n)), (0, c), constant_values=n)
+        # whole rows of the block's [H · max_groups, GROUP] view, the gather
+        # the lists themselves were read by: a window of a row's columns is a
+        # loop of slices to the TPU's compiler, an iteration a head
+        groups = plan.rows.reshape(-1, GROUP)
+        later = jnp.arange(1, g, dtype=jnp.int32)
+
+        def one_pass(i, sums):
+            at = jax.lax.dynamic_slice_in_dim(heads, i * c, c)      # n past them
+            rows = groups.at[at[:, None] * g + later[None, :]].get(
+                mode="fill", fill_value=NO_ROW).reshape(c, -1)
+            return sums.at[at].add(sums_of(syn0, rows), mode="drop",
+                                   indices_are_sorted=True, unique_indices=True)
+
+        sums = jax.lax.fori_loop(0, -(-plan.tails // c), one_pass, sums)
+        return (sums * plan.inv[:, None]).astype(compute_dtype)
+
     def per_run(syn0):
-        return mean_of(syn0, plan.rows, plan.inv)[plan.pair_head]
+        mean = (mean_of(syn0, plan.rows, plan.inv) if plan.tails is None
+                else mean_by_groups(syn0))
+        return mean[plan.pair_head]
 
     def plain(syn0):
         c = math.gcd(centers.shape[0], _PLAIN_CHUNK)

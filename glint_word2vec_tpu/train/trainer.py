@@ -219,6 +219,35 @@ def _slot_cap(counts: np.ndarray, train_words_count: int,
     return cap if cap <= 0.8 * slots else 0
 
 
+def _tail_cap(counts: np.ndarray, train_words_count: int,
+              subsample_ratio: float, list_rows: np.ndarray, tokens: int) -> int:
+    """Static capacity of a CBOW token block's tail gather (ops/subword.py:
+    every token's first group of rows gathered densely, the later groups of
+    the tokens that have them as a second block of this many tokens a pass;
+    a block with more takes another pass), 0 = do not build it. A block's tails are its tokens whose word lists more rows
+    than a group holds (``list_rows`` [V], the row table's own counts):
+    ``tokens`` · Σ p_w · [list_rows[w] > GROUP] over the kept-token
+    distribution, with 20% of room, to the NEAREST unit (a 32nd of the block's
+    tokens, and at least one), as :func:`_slot_cap` rounds and for its reason:
+    every seed of the benchmark compiles one program. At cc.en.300's shape
+    (n-grams of 5: a word of 10 letters or more) 3,306-3,317 are expected over
+    the benchmark's seeds where feed blocks hold 3,107-3,505: 4,096 = two
+    units of 2,048 of the block's 65,546 tokens (PERF.md §4, PR 43). Lists of
+    one group have no tails, and where more than a quarter of the tokens have
+    one the second block saves too little: not built, and the gather reads
+    every slot of the block."""
+    from glint_word2vec_tpu.data.subword import GROUP
+    p = _kept_token_distribution(counts, train_words_count, subsample_ratio)
+    if p is None or tokens < 32:
+        return 0
+    tails = tokens * float(
+        p @ (np.asarray(list_rows)[:p.shape[0]] > GROUP).astype(np.float64))
+    if not 0 < tails <= tokens / 4:
+        return 0
+    unit = tokens // 32
+    return max(int(1.2 * tails / unit + 0.5), 1) * unit
+
+
 # pairs in a piece of a context word (the hierarchical-softmax step's per-word
 # form, ops/hs.py: a piece's path is gathered once and contracted with its
 # pairs)
@@ -1111,7 +1140,8 @@ class Trainer:
         ``subword_table_time``; the step's shape (ops/subword.py) takes the
         center-run capacity the plain step has and, under it, the word
         capacity :func:`_word_cap` derives from the counts; a CBOW token
-        block's takes the slot capacity :func:`_slot_cap` derives from them."""
+        block's takes the slot capacity :func:`_slot_cap` and the tail
+        capacity :func:`_tail_cap` derive from them."""
         from glint_word2vec_tpu.data.subword import GROUP, build_subword_table
         from glint_word2vec_tpu.ops import subword as sw
         cfg = self.config
@@ -1126,15 +1156,17 @@ class Trainer:
         self.subword_table_time = time.perf_counter() - t0
         if self._banded_cbow:
             # the row source of a token block (ops/cbow_banded.py): every
-            # token slot of the block reads its own word's list, and the
-            # lists' scatter takes the live slots alone where the counts
-            # promise few enough of them
+            # token slot of the block reads its own word's list; the lists'
+            # scatter takes the live slots alone, and their gather the first
+            # group of each and the few later ones, where the counts promise
+            # few enough of them
             t = self._tokens_per_step
+            kept = (self.vocab.counts, self.vocab.train_words_count,
+                    cfg.subsample_ratio, rows.counts, t)
             self._subword_shape = sw.SubwordShape(
-                rows.max_groups, 1, t, slot_cap=_slot_cap(
-                    self.vocab.counts, self.vocab.train_words_count,
-                    cfg.subsample_ratio, rows.counts, t,
-                    t * rows.max_groups * GROUP))
+                rows.max_groups, 1, t,
+                slot_cap=_slot_cap(*kept, t * rows.max_groups * GROUP),
+                tail_cap=_tail_cap(*kept))
         else:
             # center runs as the plain step's (one head per run of a center's
             # pairs); where none are built every pair is its own head
@@ -3652,11 +3684,12 @@ class Trainer:
             # runs a probing fit under the guard to keep this path honest)
             with self._tracer.span("device_block") as blocked:
                 (loss_k, fpos_k, pairs_k, rows0_k, rows1_k, rows_sw_k,
-                 slots_sw_k, nodes_hs_k, pos) = jax.device_get(
+                 slots_sw_k, gather_sw_k, nodes_hs_k, pos) = jax.device_get(
                     (metrics.loss, metrics.mean_f_pos, metrics.pairs,
                      metrics.syn0_rows, metrics.syn1_rows,
                      metrics.subword_rows, metrics.subword_slots,
-                     metrics.hs_nodes, self.params.pos))
+                     metrics.subword_gather_slots, metrics.hs_nodes,
+                     self.params.pos))
                 if rows0_k is not None and pairs_k[real - 1] > 0:
                     # how far the step coalesced each table's update: 1.0
                     # plain, heads over pairs where runs were summed first
@@ -3677,6 +3710,10 @@ class Trainer:
                     # padding: the slot capacity's engagement counter
                     blocked.set(subword_slots_per_pair=float(
                         slots_sw_k[real - 1] / pairs_k[real - 1]))
+                if gather_sw_k is not None and pairs_k[real - 1] > 0:
+                    # and its list gather: the tail capacity's
+                    blocked.set(subword_gather_slots_per_pair=float(
+                        gather_sw_k[real - 1] / pairs_k[real - 1]))
                 if nodes_hs_k is not None and pairs_k[real - 1] > 0:
                     # live (pair, node) terms of a hierarchical-softmax step
                     # (config.loss="hs") over its pairs: the mean path length

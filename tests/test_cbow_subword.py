@@ -47,17 +47,27 @@ from glint_word2vec_tpu.ops.subword import SubwordShape, SubwordTable  # noqa: E
 
 V, BUCKETS, D, T, W, P, NEG, STEPS = 3000, 2000, 24, 1034, 5, 64, 10, 3
 
-CASE = {}
+CASES = {}
 
 
-def _case():
+def _case(long_words=False):
     """A small vocabulary of seeded strings, its row table at n-grams of 5, and
     the halo packer's own first blocks over a seeded Zipf corpus in sentences of
     40 with the device's own window draws: repeated words, sentence ends, empty
-    windows, halo slots that are no centers."""
+    windows, halo slots that are no centers. The seeded strings of 3,000 ranks
+    have 8 letters at most, 7 rows, one group; ``long_words`` draws every
+    eighth rank's out to 10-20 letters (a word of L letters lists L - 1 rows),
+    so that lists have a second and a third group and every block tail tokens
+    (133 / 130 / 135 of 1,034 in the three blocks)."""
+    CASE = CASES.setdefault(long_words, {})
     if CASE:
         return CASE
     strings = bench_words.make_words(7, V)
+    if long_words:
+        letters = np.random.default_rng(23).choice(list("etaoinshr"), (V, 20))
+        for r in range(3, V, 8):
+            strings[r] += "".join(letters[r, :10 + (r // 8) % 11 - len(strings[r])])
+        assert len(set(strings)) == V
     tokens = zipf.draw(np.random.default_rng(3), V, 8 * T).astype(np.int32)
     starts = np.arange(tokens.shape[0]) % 40 == 0
     win_base = stream_base(1, STREAM_WINDOW, 1, 0)
@@ -75,8 +85,10 @@ def _case():
     assert np.unique(tokens[0]).shape[0] < 0.8 * T      # words repeat within a block
     assert any((np.asarray(b.left) + np.asarray(b.right) == 0)[W:-W].any() for b in bands)
     rng = np.random.default_rng(17)
+    table = build_subword_table(strings, 5, 5, BUCKETS)
+    assert table.max_groups == (3 if long_words else 1)
     CASE.update(
-        strings=strings, table=build_subword_table(strings, 5, 5, BUCKETS),
+        strings=strings, table=table,
         tokens=tokens, bands=bands,
         negatives=rng.integers(0, V, (STEPS, P)).astype(np.int32),
         syn0=rng.uniform(-0.3, 0.3, (V + BUCKETS, D)), syn1=rng.uniform(-0.3, 0.3, (V, D)),
@@ -238,21 +250,34 @@ def test_taps_transpose_is_the_adjoint():
 
 # -- the row source's forms ----------------------------------------------------------
 
-# (max_run, head_cap, slot_cap): every token slot the head of its own list,
-# with room for all of them (one branch, known while tracing), and with too few
-# heads (the chunked plain form, chosen by the step); under the first, the
-# lists' slots sorted by row and cut to a capacity over every block's live
+# (max_run, head_cap, slot_cap, tail_cap): every token slot the head of its own
+# list, with room for all of them (one branch, known while tracing), and with
+# too few heads (the chunked plain form, chosen by the step); under the first,
+# the lists' slots sorted by row and cut to a capacity over every block's live
 # slots (what the trainer builds where the counts promise one), to one that
 # every block overflows (the whole form, chosen by the step), and to one
-# between the blocks' 3,531 / 3,636 / 3,592 live slots (a form a step)
-FORMS = {"capacity_held": (1, T, 0), "capacity_overflowed": (1, 16, 0),
-         "slots_cut": (1, T, 4096), "slot_capacity_overflowed": (1, T, 2048),
-         "slot_capacity_straddled": (1, T, 3600)}
+# between the blocks' 3,531 / 3,636 / 3,592 live slots (a form a step). The
+# ``tail`` forms run on the table with long words (three groups a list, 4,807 /
+# 4,902 / 4,888 live slots a block): every token's first group gathered densely
+# and the later groups of the blocks' 133 / 130 / 135 tail tokens in passes of
+# a capacity over them all (one pass, beside the slot capacity: what the trainer
+# builds), of one that every block overflows (three passes), and of one
+# between them (one pass or two, chosen by the step)
+FORMS = {"capacity_held": (1, T, 0, 0), "capacity_overflowed": (1, 16, 0, 0),
+         "slots_cut": (1, T, 4096, 0), "slot_capacity_overflowed": (1, T, 2048, 0),
+         "slot_capacity_straddled": (1, T, 3600, 0),
+         "tails_cut": (1, T, 8192, 192), "tail_capacity_overflowed": (1, T, 0, 64),
+         "tail_capacity_straddled": (1, T, 0, 132)}
+
+
+def _form_case(form):
+    return _case(long_words=form.startswith("tail"))
 
 
 def _form_shape(table, form):
-    max_run, head_cap, slot_cap = FORMS[form]
-    return SubwordShape(table.max_groups, max_run, head_cap, slot_cap=slot_cap)
+    max_run, head_cap, slot_cap, tail_cap = FORMS[form]
+    return SubwordShape(table.max_groups, max_run, head_cap, slot_cap=slot_cap,
+                        tail_cap=tail_cap)
 
 
 def _slots_handed(case, form, step=0):
@@ -263,9 +288,23 @@ def _slots_handed(case, form, step=0):
     return cap if cap and live <= cap else T * table.max_groups * 8
 
 
+def _tail_tokens(case, step):
+    return int((case["table"].counts[case["tokens"][step]] > 8).sum())
+
+
+def _gather_slots_handed(case, form, step=0):
+    """What ``subword_gather_slots`` must read: every slot of the block, or,
+    under a tail capacity, every token's first group and the capacity's later
+    groups a pass of the loop over the block's tail tokens."""
+    groups, cap = case["table"].max_groups, FORMS[form][3]
+    if cap:
+        return T * 8 + -(-_tail_tokens(case, step) // cap) * cap * (groups - 1) * 8
+    return T * groups * 8
+
+
 @pytest.mark.parametrize("form", list(FORMS))
 def test_row_source_branches_give_the_same_sums(form):
-    case = _case()
+    case = _form_case(form)
     table = case["table"]
     want, _ = _run_reference(case, True, True)
     got, metrics = _run_program(case, True, True, jnp.float32,
@@ -278,6 +317,16 @@ def test_row_source_branches_give_the_same_sums(form):
     assert [float(m.subword_rows) for m in metrics] == live
     assert [float(m.subword_slots) for m in metrics] == [
         _slots_handed(case, form, k) for k in range(STEPS)]
+    assert [float(m.subword_gather_slots) for m in metrics] == [
+        _gather_slots_handed(case, form, k) for k in range(STEPS)]
+    tails = [_tail_tokens(case, k) for k in range(STEPS)]
+    if form == "tails_cut":
+        assert 0 < min(tails) and max(tails) <= FORMS[form][3] < T // 4
+        assert max(live) <= FORMS[form][2]
+    if form == "tail_capacity_overflowed":
+        assert min(tails) > FORMS[form][3]
+    if form == "tail_capacity_straddled":
+        assert min(tails) <= FORMS[form][3] < max(tails)
     if form == "slots_cut":
         assert max(live) <= FORMS[form][2] < 0.5 * T * table.max_groups * 8
     if form == "slot_capacity_overflowed":
@@ -286,12 +335,14 @@ def test_row_source_branches_give_the_same_sums(form):
         assert min(live) <= FORMS[form][2] < max(live)
 
 
-@pytest.mark.parametrize("form", ["slots_cut", "slot_capacity_overflowed"])
+@pytest.mark.parametrize("form", ["slots_cut", "slot_capacity_overflowed",
+                                  "tails_cut", "tail_capacity_overflowed"])
 def test_slot_capacity_branches_give_the_same_sums_in_float64(form):
-    """The cut and its overflow against the reference with float64 tables and
-    sums: a live slot the cut dropped, or a padding slot it kept, is a row's
-    whole update, a million times what this can hold."""
-    case = _case()
+    """The cut and its overflow, the gather's two parts and theirs, against the
+    reference with float64 tables and sums: a live slot the cut dropped, a
+    padding slot it kept, a tail group dropped or read twice, is a row's whole
+    update or vector, a million times what this can hold."""
+    case = _form_case(form)
     with jax.enable_x64():
         params, metrics = _run_program(case, True, True, jnp.float64,
                                        shape=_form_shape(case["table"], form))
@@ -300,22 +351,27 @@ def test_slot_capacity_branches_give_the_same_sums_in_float64(form):
                           (params.pos, ref["d"])):
             np.testing.assert_allclose(got, want, rtol=1e-7, atol=2e-8)
         assert float(metrics[0].subword_slots) == _slots_handed(case, form)
+        assert float(metrics[0].subword_gather_slots) == _gather_slots_handed(case, form)
 
 
 def test_the_trainers_row_source_builds_one_branch():
-    """With room for every token slot and no slot capacity the step holds no
-    conditional (the head capacity is known while tracing); with the slot
-    capacity it holds exactly one, around the lists' scatter, and the gather
-    stays outside any."""
-    case = _case()
+    """With room for every token slot and no capacity the step holds no
+    conditional (the head capacity is known while tracing) and no loop; with
+    the slot capacity it holds exactly one conditional, around the lists'
+    scatter, and the gather stays outside any; the tail capacity adds no
+    conditional, alone or beside the slot capacity (what the trainer builds
+    where the counts promise both), but the one loop over the passes of the
+    tail tokens' later groups: a block over the capacity has no form of its
+    own."""
+    case = _case(long_words=True)
     table = case["table"]
     band = case["bands"][0]
 
-    def lowered(cap, slot_cap=0):
+    def lowered(cap, slot_cap=0, tail_cap=0):
         return jax.jit(lambda p, dev, tk, n: cbow_step_banded_core(
             p, tk, band.left, band.right, band.center, band.token, n,
             jnp.float32(0.05), NEG, W, subword=(dev, SubwordShape(
-                table.max_groups, 1, cap, slot_cap=slot_cap)))).lower(
+                table.max_groups, 1, cap, slot_cap=slot_cap, tail_cap=tail_cap)))).lower(
             EmbeddingPair(jnp.asarray(case["syn0"], jnp.float32),
                           jnp.asarray(case["syn1"], jnp.float32),
                           jnp.asarray(case["pos"], jnp.float32)),
@@ -325,21 +381,38 @@ def test_the_trainers_row_source_builds_one_branch():
     def conditionals(text):
         return text.count("stablehlo.case") + text.count("stablehlo.if")
 
-    assert conditionals(lowered(T)) == 0
+    def sorts(text):
+        return text.count("stablehlo.sort")
+
+    def loops(text):
+        return text.count("stablehlo.while")
+
+    _, _, slot_cap, tail_cap = FORMS["tails_cut"]
+    neither = lowered(T)
+    assert (conditionals(neither), loops(neither)) == (0, 0)
     assert conditionals(lowered(16)) >= 1
-    cut = lowered(T, FORMS["slots_cut"][2])
-    assert conditionals(cut) == 1
+    cut = lowered(T, slot_cap)
+    assert (conditionals(cut), loops(cut)) == (1, 0)
     # one sort more than the program without the capacity: the cut branch's own
-    assert cut.count("stablehlo.sort") == lowered(T).count("stablehlo.sort") + 1
+    assert sorts(cut) == sorts(neither) + 1
+    tails = lowered(T, tail_cap=tail_cap)
+    assert (conditionals(tails), loops(tails)) == (0, 1)
+    both = lowered(T, slot_cap, tail_cap)
+    # (the tail tokens' positions are compacted by the sort the heads' are: one
+    # function of the lowered text, called twice)
+    assert (conditionals(both), loops(both), sorts(both)) == (1, 1, sorts(cut))
 
 
-@pytest.mark.parametrize("slot_cap", [0, 2048], ids=["every_slot", "slots_cut"])
-def test_masked_slots_and_the_lane_padding_stay_zero(slot_cap):
+@pytest.mark.parametrize("slot_cap, tail_cap", [(0, 0), (2048, 0), (2048, 64)],
+                         ids=["every_slot", "slots_cut", "tail_cap"])
+def test_masked_slots_and_the_lane_padding_stay_zero(slot_cap, tail_cap):
     """A block whose tail is not valid (token_mask 0) lists nothing and moves
     nothing for it, and zero columns stay exactly zero, the weights' too: with
-    every slot of the block handed to the scatter, and with the slots sorted
-    by row and cut (a third of the block is live: its slots fit 2,048)."""
-    case = _case()
+    every slot of the block handed to the gather and the scatter, with the
+    slots sorted by row and cut (a third of the block is live: its slots fit
+    2,048), and with the gather in its two parts besides (on the table with
+    long words: 49 tail tokens and 1,627 slots of the block's third are live)."""
+    case = _case(long_words=bool(tail_cap))
     table, pad, real = case["table"], 8, T // 3
     tokens = np.where(np.arange(T) < real, case["tokens"][0], 0).astype(np.int32)
     starts = np.packbits(np.arange(T) % 40 == 0, bitorder="little")
@@ -357,12 +430,18 @@ def test_masked_slots_and_the_lane_padding_stay_zero(slot_cap):
         params, jnp.asarray(tokens), band.left, band.right, band.center, band.token,
         jnp.asarray(case["negatives"][0]), jnp.float32(0.05), NEG, W, "exact",
         jnp.bfloat16, jnp.bfloat16,
-        subword=(_device_table(table), SubwordShape(table.max_groups, 1, T,
-                                                    slot_cap=slot_cap)))
+        subword=(_device_table(table), SubwordShape(
+            table.max_groups, 1, T, slot_cap=slot_cap, tail_cap=tail_cap)))
     for leaf in got:
         assert not np.asarray(leaf[:, D:]).any()
+    groups = table.max_groups
     assert float(metrics.subword_rows) == table.counts[tokens[:real]].sum()
-    assert float(metrics.subword_slots) == (slot_cap or T * table.max_groups * 8)
+    assert float(metrics.subword_slots) == (slot_cap or T * groups * 8)
+    if tail_cap:
+        # a masked slot is no word and has no tail: the live third's fit one pass
+        assert 0 < (table.counts[tokens[:real]] > 8).sum() <= tail_cap
+    assert float(metrics.subword_gather_slots) == (
+        T * 8 + tail_cap * (groups - 1) * 8 if tail_cap else T * groups * 8)
     touched = np.unique(np.concatenate([table.rows_of(w) for w in np.unique(tokens[:real])]))
     still = np.setdiff1d(np.arange(V + BUCKETS), touched)
     np.testing.assert_array_equal(got.syn0[still], params.syn0[still])
@@ -382,15 +461,20 @@ def test_masked_slots_and_the_lane_padding_stay_zero(slot_cap):
 # row source shares `plan_centers` and took none of it: `cbow-subword-2m-300.train`'s
 # two were the parent commit of PR 34's (1b95d5f) until PR 36, which changed that
 # step on purpose (its lists' scatter under a slot capacity, which the trainer's
-# rule derives at the tiny sizes too: 10,240 of 16,464 slots) and took these two
-# from its own tree; the other four are as they were.
+# rule derives at the tiny sizes too: 10,240 of 16,464 slots), and PR 43, which
+# changed it again (its lists' gather in two parts under a tail capacity; at the
+# tiny sizes the strings' law gives words of 9 letters at most, one group of 8
+# rows, so the rule derives none and the program gathers its 2,058 x 8 slots
+# whole: what the digests see of PR 43 is the step's new counter,
+# `StepMetrics.subword_gather_slots`). These two are PR 43's tree's; the other
+# four are as they were.
 PARENT_STEP_TEXT = {
     ("cbow-3m-300.train", "train_cbow", "_step_fn"): "7f02f0da70d07c76",
     ("cbow-3m-300.train", "train_cbow", "_step_fn_fast"): "436c26f275ae9be0",
     ("subword-sgns-2.5m-300.train", "train_subword", "_step_fn"): "f53dceb464f6b6b9",
     ("subword-sgns-2.5m-300.train", "train_subword", "_step_fn_fast"): "188e2230a80d8e87",
-    ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn"): "eb7f0f696d8380eb",
-    ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn_fast"): "93fb95a8e57c8cab",
+    ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn"): "fba803c477e57fb4",
+    ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn_fast"): "6570001c4575640b",
 }
 
 
@@ -408,14 +492,17 @@ def test_steps_without_the_new_parts_lower_to_the_parents_text(cell_name, kind_n
         cell, 0, tiny=True)
     shape = trainer._subword_shape
     if kind_name == "train_cbow_subword":
-        # every token slot its own list, no second level under them, and the
-        # lists' scatter under a slot capacity
+        # every token slot its own list, no second level under them, the
+        # lists' scatter under a slot capacity, and no tail capacity for lists
+        # of one group
         assert (shape.max_run, shape.head_cap, shape.word_cap) == (
             1, trainer._tokens_per_step, 0)
         assert 0 < shape.slot_cap < trainer._tokens_per_step * shape.max_groups * 8
+        assert (shape.max_groups, shape.tail_cap) == (1, 0)
     else:
         assert trainer.params.pos is None
-        assert shape is None or (shape.word_cap > 0 and shape.slot_cap == 0)
+        assert shape is None or (
+            shape.word_cap > 0 and (shape.slot_cap, shape.tail_cap) == (0, 0))
     cfg = trainer.config
     k, b = cfg.steps_per_dispatch, cfg.pairs_per_batch
     zeros = np.zeros((2, k), np.float32)
@@ -532,6 +619,9 @@ def test_heartbeat_reports_the_rows_and_the_drift(fitted):
     # slots handed to the lists' scatter, live or padding: never fewer than the
     # live rows, and at most every slot of a block over the live examples
     assert all(e["args"]["subword_rows_per_pair"] <= e["args"]["subword_slots_per_pair"]
+               < 200 for e in blocks)
+    # and to their gather: every slot of a block of one-group lists
+    assert all(e["args"]["subword_rows_per_pair"] <= e["args"]["subword_gather_slots_per_pair"]
                < 200 for e in blocks)
     assert any(json.loads(line).get("event", json.loads(line).get("kind")) for line in open(path))
 

@@ -39,6 +39,7 @@ from glint_word2vec_tpu.train.trainer import (
     _center_run_cap,
     _context_run_cap,
     _slot_cap,
+    _tail_cap,
     _word_cap,
     _word_pieces,
 )
@@ -567,6 +568,68 @@ def test_slot_cap_is_derived_from_the_counts():
     # a vocabulary subsampling keeps nothing of, or a block of a few slots
     assert _slot_cap(np.zeros(8, np.int64), 0, 0.0, np.ones(9, np.int32), 64, 512) == 0
     assert _slot_cap(counts, total, ratio, table.counts, 1, 16) == 0
+
+
+# (seed of the strings, tiny): seeds of the benchmark's runs, a large one among
+# them, at ``cbow-subword-2m-300``'s shape and at its ``--tiny`` sizes
+@pytest.mark.parametrize("seed, tiny", [(7, False), (11, False), (2147483653, False),
+                                        (7, True), (2147483653, True)])
+def test_tail_cap_is_derived_from_the_counts(seed, tiny):
+    """The capacity of a CBOW token block's tail gather (ops/subword.py): at
+    ``cbow-subword-2m-300``'s counts, strings and resolved subsample every
+    seed's strings give 4,096 (1.15 to 1.3 times the tail tokens expected of a
+    block, a sixteenth of its tokens), and blocks of kept tokens hold what was
+    expected; at the ``--tiny`` sizes (20,000 ranks: words of 9 letters at
+    most, one group) nothing is built."""
+    from harness import words, zipf
+
+    from glint_word2vec_tpu.data.pipeline import keep_probabilities
+    from glint_word2vec_tpu.data.subword import build_subword_table
+
+    v, buckets, t, ratio = ((20_000, 10_000, 2058, 1e-3) if tiny
+                            else (2_000_000, 2_000_000, 65546, 6.45e-4))
+    counts = zipf.zipf_counts(v).astype(np.int64)
+    total = int(counts.sum())
+    table = build_subword_table(words.make_words(seed, v), 5, 5, buckets)
+    cap = _tail_cap(counts, total, ratio, table.counts, t)
+    if tiny:
+        assert table.max_groups == 1 and cap == 0
+        return
+    kept = counts * keep_probabilities(counts, total, ratio)
+    p = kept / kept.sum()
+    expected = t * float(p @ (table.counts[:v] > 8))
+    assert table.max_groups == 2 and cap == 4096 == 2 * (t // 32)
+    assert 1.15 * expected < cap < 1.3 * expected
+    rng = np.random.default_rng(11)
+    for _ in range(3):
+        tails = int((table.counts[rng.choice(v, t, p=p)] > 8).sum())
+        assert abs(tails - expected) < 0.08 * expected and tails <= cap
+    # a block of another size takes the same share of its tokens
+    for n in (4096, 16384):
+        assert _tail_cap(counts, total, ratio, table.counts, n) == 2 * (n // 32)
+
+
+def test_tail_cap_builds_nothing_where_it_saves_nothing():
+    v, t = 50_000, 65546
+    counts = np.full(v, 100, np.int64)
+    total = int(counts.sum())
+    rows = np.full(v + 1, 6, np.int32)
+    # lists of one group: no tails
+    assert _tail_cap(counts, total, 0.0, rows, t) == 0
+    # a word in 200 with a second group: the nearest unit, and never under one
+    rows[:v:200] = 12
+    assert _tail_cap(counts, total, 0.0, rows, t) == 2048
+    rows[:v:20] = 12
+    assert _tail_cap(counts, total, 0.0, rows, t) == 4096        # 3,277 expected
+    # up to a quarter of the tokens with a tail it is built, past it not
+    rows[:v:5] = 12
+    assert _tail_cap(counts, total, 0.0, rows, t) == 16384       # 13,109 expected
+    rows[:v:3] = 12
+    assert _tail_cap(counts, total, 0.0, rows, t) == 0
+    assert _tail_cap(counts, total, 0.0, np.full(v + 1, 16, np.int32), t) == 0
+    # a vocabulary subsampling keeps nothing of, or a block of a few tokens
+    assert _tail_cap(np.zeros(8, np.int64), 0, 0.0, np.full(9, 12, np.int32), 64) == 0
+    assert _tail_cap(counts, total, 0.0, rows, 16) == 0
 
 
 def test_context_cap_is_derived_from_the_counts():

@@ -30,6 +30,13 @@ Since PR 36 the list scatter sits in one conditional (the slots sorted by row
 inside the step and cut to the trainer's slot capacity, or a block over it
 whole): a scatter into syn0 in each branch, the step's own sort in the cut
 branch alone, the gather ordered before the conditional by its data (no copy).
+Since PR 43 the list gather is in two parts where the trainer derives a tail
+capacity: every token's first group (f32[524368,384]) and, in a loop of dynamic
+trip count, the capacity's later groups a pass (f32[32768,384]; no conditional,
+no whole form beside it). The loop READS syn0 before the scatter's conditional
+writes it, its result orders the two, and neither copies the table; the tail
+tokens' row ids are read by a gather and not by a loop of slices, an iteration
+a token.
 That the plain banded step and the subword skip-gram step are the programs
 they were is held where it is cheap, on their lowered text
 (``tests/test_cbow_subword.py``).
@@ -147,16 +154,20 @@ def test_no_table_is_copied_with_the_subword_row_source(one_chip, with_metrics):
             <= parent_temporaries[with_metrics])
 
 
+@pytest.mark.parametrize("tail_cap", [0, 4096], ids=["slots_cut", "both_capacities"])
 @pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
-def test_no_table_is_copied_with_token_lists_and_position_weights(one_chip, with_metrics):
+def test_no_table_is_copied_with_token_lists_and_position_weights(one_chip, with_metrics,
+                                                                  tail_cap):
     from glint_word2vec_tpu.ops.cbow_banded import cbow_step_banded_core
     from glint_word2vec_tpu.ops.subword import SubwordShape, SubwordTable
 
     words, rows0, groups, tokens, window = 2_000_000, 4_000_000, 3 << 20, 65546, 5
     # what the trainer derives at this size: every token slot its own list, the
-    # lists' scatter under the slot capacity (tests/test_coalesce_runs.py holds
-    # the derivation)
-    shape = SubwordShape(max_groups=2, max_run=1, head_cap=tokens, slot_cap=393216)
+    # lists' scatter under the slot capacity and (PR 43) their gather under the
+    # tail capacity (tests/test_coalesce_runs.py holds the derivations);
+    # without the second the program is PR 36's
+    shape = SubwordShape(max_groups=2, max_run=1, head_cap=tokens, slot_cap=393216,
+                         tail_cap=tail_cap)
     # temp_size_in_bytes of the same compile with slot_cap=0, the parent's form
     # (my compile for the described v5e, PR 36), and what the conditional adds
     # whatever the capacity (1,048,736 and 360,448 read the same): 21.7 MB of
@@ -185,7 +196,8 @@ def test_no_table_is_copied_with_token_lists_and_position_weights(one_chip, with
         block, block, block, spec((K, tokens), jnp.float32), spec((K, 4096), jnp.int32),
         spec((K,), jnp.float32)).compile()
     compiled = program.as_text()
-    # the lists' scatter, and nothing else: the head capacity is known while tracing
+    # the lists' scatter, and nothing else: the head capacity is known while
+    # tracing, and the tail capacity builds a loop, not a branch
     assert compiled.count(" conditional(") == 1
     copies = [line.strip()[:120] for line in compiled.splitlines()
               if re.search(rf"= f32\[({rows0}|{words}),{D}\]\S* copy\(", line)]
@@ -211,6 +223,15 @@ def test_no_table_is_copied_with_token_lists_and_position_weights(one_chip, with
     assert "s32[393216]" not in _computation(compiled, branches[own.index(False)])
     assert (program.memory_analysis().temp_size_in_bytes
             <= parent_temporaries[with_metrics] + conditional_adds)
+    # the scan, and under a tail capacity the loop over the passes of the tail
+    # tokens' later groups: every token's first group is gathered outside it,
+    # the capacity's later groups inside, and the block is never gathered whole
+    assert compiled.count(" while(") == (2 if tail_cap else 1)
+    def gathers(heads, slots):
+        return len(re.findall(rf"= f32\[{heads},{slots},{D}\]\S* gather\(", compiled))
+
+    assert (gathers(tokens, 16), gathers(tokens, 8), gathers(tail_cap, 8)) == (
+        (0, 1, 1) if tail_cap else (1, 0, 0))
 
 
 def _computation(compiled: str, name: str) -> str:
