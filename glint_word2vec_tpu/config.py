@@ -388,7 +388,7 @@ class Word2VecConfig:
                                     # blocks for its own data segments and the
                                     # iteration-barrier allgather keeps training
                                     # bit-identical to single-process
-                                    # (trainer._fit_device_feed_sharded)
+                                    # (train/feeds.GatheredTokenBlocks)
     tokens_per_step: int = 0        # device_pairgen: raw token slots per step; 0 sizes
                                     # automatically from pairs_per_batch, window, and the
                                     # subsample keep ratio (targeting ~93% pair-slot fill;
@@ -451,7 +451,7 @@ class Word2VecConfig:
                                     # keeps ONE deterministic program-launch order
                                     # (allgather_r, touch_r, dispatch_r, ...) — the
                                     # invariant that makes cross-host collectives
-                                    # deadlock-free (see trainer._one_ahead_iter).
+                                    # deadlock-free (see feeds._one_ahead_iter).
                                     # False = the pre-round-8 consumer-thread put.
                                     # No effect single-process or at
                                     # prefetch_chunks=0

@@ -442,7 +442,7 @@ def iter_sentence_slabs(
 ) -> Iterator[List[np.ndarray]]:
     """Whole-sentence slabs of ~``block_words`` raw tokens in the given order — the
     vectorization granule shared by the host pair pipeline (:func:`epoch_batches`)
-    and the device-feed packer (train/trainer._fit_device_feed), so their stream
+    and the device-feed packer (Trainer._device_seg_blocks), so their stream
     contracts stay aligned on one slab rule."""
     slab: List[np.ndarray] = []
     nwords = 0

@@ -12,8 +12,8 @@ load needed.
 
 Phases (one histogram each, docs/observability.md):
 
-- ``producer_wait`` — fit() blocked on the next chunk/round (the host-wait
-  sites of all four fit paths);
+- ``producer_wait`` — fit() blocked on the next chunk/round (the fit
+  loop's wait, or a gathering feed's own for its local chunk);
 - ``stage``         — feed device-put + transfer-forcing touch
   (``stage_put``) and the sharded handshake's ``allgather_fetch``;
 - ``dispatch``      — per-round step dispatch (incl. meta staging);

@@ -11,7 +11,7 @@ Input-feed strategy: by default (``config.shard_input=True``) each process gener
 only its own 1/N of the sentence stream — ``epoch_batches(shard=process_index,
 num_shards=process_count)``, the repartition analog (mllib:345) — and one
 ``process_allgather`` per dispatch round assembles the identical global batch on every
-process (``Trainer._fit_sharded``: the gather rides the device interconnect; word-clock
+process (``train/feeds.GatheredPairs``: the gather rides the device interconnect; word-clock
 deltas travel with it so every process computes identical alphas, and per-process alive
 flags give deadlock-free lockstep when streams end unevenly). Host pipeline work
 therefore scales 1/N with hosts. ``shard_input=False`` selects the zero-coordination
@@ -104,7 +104,7 @@ def allgather_start(host_tree):
     numpy result (leading [process_count] axis, exactly the process_allgather
     layout).
 
-    Why split: the one-round-ahead feed stager (trainer._one_ahead_iter) must
+    Why split: the one-round-ahead feed stager (feeds._one_ahead_iter) must
     LAUNCH the next round's gather at a pinned point in the cross-host
     program-launch order — before the current round's step dispatch — and only
     later block for its bytes, so the gather's wire transfer and the host-side

@@ -191,7 +191,7 @@ class TrainState:
     ``shard_progress`` records sharded stream positions; what an entry indexes depends
     on ``shard_feed``:
 
-    - ``"pairs"`` (host-feed sharded runs, _fit_sharded): per-PROCESS
+    - ``"pairs"`` (host-feed sharded runs, feeds.GatheredPairs): per-PROCESS
       ``[[iteration, local pair-batches done], ...]`` indexed by process id — resume
       needs the same process count.
     - ``"tokens"`` (device-feed runs): per-SEGMENT
@@ -210,7 +210,7 @@ class TrainState:
     global_step: int = 0
     batches_done: int = 0
     shard_progress: Optional[List[List[int]]] = None
-    # which stream shard_progress positions index: "pairs" (_fit_sharded's
+    # which stream shard_progress positions index: "pairs" (feeds.GatheredPairs'
     # per-process pair-batch streams) or "tokens" (per-SEGMENT device-feed
     # block positions — written by EVERY device-feed run, single-process
     # included, for elastic resume). The two count different things, so

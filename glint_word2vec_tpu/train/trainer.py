@@ -36,7 +36,6 @@ from glint_word2vec_tpu.ops.sgns import (
     EmbeddingPair,
     Stabilizers,
     StepMetrics,
-    alpha_schedule,
     cbow_step_core,
     cbow_step_shared_core,
     init_embeddings,
@@ -46,7 +45,7 @@ from glint_word2vec_tpu.ops.sgns import (
 from glint_word2vec_tpu.parallel.distributed import put_global
 from glint_word2vec_tpu.parallel.mesh import (
     MeshPlan, make_mesh, pad_dim_to_lanes, pad_vocab_for_sharding)
-from glint_word2vec_tpu.train import faults
+from glint_word2vec_tpu.train import faults, feeds
 from glint_word2vec_tpu.train.checkpoint import TrainState, save_model
 from glint_word2vec_tpu.train.faults import NonFiniteParamsError
 
@@ -522,189 +521,6 @@ class HeartbeatRecord:
                                    # -1 when sync_every == 1 (no windows)
 
 
-class _threaded_iter:
-    """Run a generator on a background thread with a bounded buffer.
-
-    Exceptions raised by the generator re-raise at the consumer's ``next()``.
-    ``close()`` (also called on garbage collection) stops the producer promptly even
-    if it is blocked on a full buffer.
-    """
-
-    _DONE = object()
-
-    def __init__(self, gen, maxsize: int):
-        import queue
-        import threading
-
-        self._q: "queue.Queue" = queue.Queue(maxsize=maxsize)
-        self._stop = threading.Event()
-        self._queue_mod = queue
-
-        def put_checked(item) -> bool:
-            """Bounded put that gives up once the consumer signals stop — every put
-            (including the terminal DONE/exception) must be preemptible or an
-            abandoned iterator leaks a blocked producer thread."""
-            while not self._stop.is_set():
-                try:
-                    self._q.put(item, timeout=0.1)
-                    return True
-                except queue.Full:
-                    continue
-            return False
-
-        def run():
-            try:
-                for item in gen:
-                    if not put_checked(item):
-                        return
-                put_checked(self._DONE)
-            except BaseException as e:  # noqa: BLE001 — relayed to the consumer
-                put_checked(e)
-
-        self._thread = threading.Thread(
-            target=run, daemon=True, name="glint-batch-producer")
-        self._thread.start()
-
-    def __iter__(self):
-        return self
-
-    def __next__(self):
-        if self._stop.is_set():
-            raise StopIteration
-        item = self._q.get()
-        if item is self._DONE:
-            self._stop.set()
-            raise StopIteration
-        if isinstance(item, BaseException):
-            self._stop.set()
-            raise item
-        return item
-
-    def close(self) -> None:
-        self._stop.set()
-        try:  # unblock a producer waiting on a full queue
-            while True:
-                self._q.get_nowait()
-        except self._queue_mod.Empty:
-            pass
-        self._thread.join(timeout=5.0)
-
-    def __del__(self):
-        try:
-            self.close()
-        except Exception:
-            pass
-
-
-class _one_ahead_iter:
-    """Run a generator on a background thread exactly ONE item ahead of the
-    consumer, under an explicit ``ack()`` ticket: after delivering item r the
-    producer does not start producing item r+1 until the consumer acks r.
-
-    This is the multi-process staging primitive (PERF.md §10). Producing a
-    round launches device programs (the next round's allgather, the staging
-    touch) and consuming one launches more (the step dispatch, heartbeat
-    fetches, checkpoint collectives). Cross-host deadlock-freedom requires
-    every process to enqueue collective programs in the same order, so the
-    ticket serializes the two threads into ONE deterministic per-process
-    launch order — [stage_r, dispatch_r + bookkeeping_r, stage_{r+1}, ...] —
-    identical on every process because both sides are pure functions of
-    allgathered values. The overlap win survives: stage_{r+1}'s HOST work
-    (allgather result decode, feed assembly, device-put DMA) runs while chunk
-    r executes on device.
-
-    Generator exceptions re-raise at the consumer's ``next()``; ``close()``
-    unblocks and joins the producer."""
-
-    _DONE = object()
-
-    def __init__(self, gen):
-        import queue
-        import threading
-
-        self._out: "queue.Queue" = queue.Queue(maxsize=1)
-        self._ack: "queue.Queue" = queue.Queue()
-        self._stop = threading.Event()
-        self._queue_mod = queue
-
-        def put_checked(item) -> bool:
-            while not self._stop.is_set():
-                try:
-                    self._out.put(item, timeout=0.1)
-                    return True
-                except queue.Full:
-                    continue
-            return False
-
-        def wait_ack() -> bool:
-            while not self._stop.is_set():
-                try:
-                    self._ack.get(timeout=0.1)
-                    return True
-                except queue.Empty:
-                    continue
-            return False
-
-        def run():
-            it = iter(gen)
-            try:
-                first = True
-                while True:
-                    # the ack gate sits BEFORE producing item r+1 (before
-                    # re-entering the generator), so stage r+1's program
-                    # launches come after the consumer's round-r launches
-                    # everywhere
-                    if not first and not wait_ack():
-                        return
-                    first = False
-                    try:
-                        item = next(it)
-                    except StopIteration:
-                        put_checked(self._DONE)
-                        return
-                    if not put_checked(item):
-                        return
-            except BaseException as e:  # noqa: BLE001 — relayed to the consumer
-                put_checked(e)
-
-        self._thread = threading.Thread(
-            target=run, daemon=True, name="glint-round-stager")
-        self._thread.start()
-
-    def __iter__(self):
-        return self
-
-    def __next__(self):
-        if self._stop.is_set():
-            raise StopIteration
-        item = self._out.get()
-        if item is self._DONE:
-            self._stop.set()
-            raise StopIteration
-        if isinstance(item, BaseException):
-            self._stop.set()
-            raise item
-        return item
-
-    def ack(self) -> None:
-        self._ack.put(None)
-
-    def close(self) -> None:
-        self._stop.set()
-        try:
-            while True:
-                self._out.get_nowait()
-        except self._queue_mod.Empty:
-            pass
-        self._thread.join(timeout=5.0)
-
-    def __del__(self):
-        try:
-            self.close()
-        except Exception:
-            pass
-
-
 class Trainer:
     """Owns the sharded embedding pair and runs the synchronous SGNS/CBOW loop."""
 
@@ -869,7 +685,7 @@ class Trainer:
         # continual loop records its vocab_lineage chain here); empty = the
         # pre-continual metadata, byte-identical.
         self.extra_checkpoint_meta: dict = {}
-        # Chunk transfer layout (see chunk_stream in fit): pairs ride in ONE packed
+        # Chunk transfer layout (feeds.HostPairs): pairs ride in ONE packed
         # array per dispatch — through a narrow host→device link the per-transfer
         # overhead dominates, so fewer/larger puts win. Indices ship as uint16 when the
         # vocab allows (halves feed bytes; upcast on device is free).
@@ -882,7 +698,7 @@ class Trainer:
             self._chunk_shardings = {"pairs": plan.pairs_stacked}
         # Sharded input feed (the repartition analog, mllib:345): each process
         # generates only its 1/N of the sentence stream; the global batch is assembled
-        # from per-process segments by a per-round allgather (see _fit_sharded). The
+        # from per-process segments by a per-round allgather (feeds.GatheredPairs). The
         # batch's B axis is composed of N per-process segments, each prefix-masked.
         self._feed_segments = 1
         if config.shard_input and jax.process_count() > 1:
@@ -930,7 +746,8 @@ class Trainer:
                     "always, and the right bound is exclusive) — use window >= 2")
             self._init_token_block_feed(
                 "device_pairgen",
-                config.tokens_per_step or self._auto_tokens_per_step())
+                config.tokens_per_step or self._auto_tokens_per_step(),
+                _pairs_per_kept_token(config.window))
             # ops/pairgen._cumsum_i32 is exact only while prefix sums stay below
             # 2^24 (f32 mantissa); the largest sum is T * (2*window - 1) pair counts
             if self._tokens_per_step * (2 * config.window - 1) >= 1 << 24:
@@ -954,7 +771,8 @@ class Trainer:
             self._init_token_block_feed(
                 "cbow_update='banded'",
                 config.pairs_per_batch // self.plan.num_data
-                + 2 * self._block_halo)
+                + 2 * self._block_halo,
+                _cbow_examples_per_kept_token(config.window))
         # bound the duplicate-overload divergence channel (EVAL.md measured
         # boundary): auto-lower an AUTO subsample_ratio or refuse an explicit
         # unstable one. Idempotent — the device-feed path already resolved it
@@ -1051,7 +869,7 @@ class Trainer:
         # other and everything stops at 0% CPU. Observed live on the forced
         # 8-device mesh (either step lowering, ~200-dispatch fits): the
         # racers were the producer-thread feed-touch program
-        # (_stage_to_device — its cross-shard reduction lowers to
+        # (feeds._stage_to_device — its cross-shard reduction lowers to
         # collectives; now skipped on this backend) and the finiteness probe
         # (now dispatched only after draining the carry). This flag guards
         # both and gates _after_dispatch, which drains the carry after every
@@ -1068,13 +886,17 @@ class Trainer:
 
     # -- setup -------------------------------------------------------------------------
 
-    def _init_token_block_feed(self, feature: str, tokens_per_step: int) -> None:
+    def _init_token_block_feed(self, feature: str, tokens_per_step: int,
+                               est_pairs_per_token: float) -> None:
         """Shared feed setup of the two token-block feeds (device_pairgen and
         banded CBOW): multi-process segment-ownership checks, duplicate-channel
         resolution BEFORE keep-probability derivation (an AUTO subsample may be
         lowered there; feature-specific shape errors fire before this runs),
-        the replicated keep table, T, and the chunk shardings. One owner so the
-        two feeds cannot drift on these invariants."""
+        the replicated keep table, T, the chunk shardings, and the analytic
+        pairs (or CBOW examples) a kept token makes: a round's estimate for
+        the heartbeat's display, settled against the device's exact totals at
+        the end of the fit. One owner so the two feeds cannot drift on these
+        invariants."""
         config = self.config
         plan = self.plan
         if jax.process_count() > 1:
@@ -1105,6 +927,7 @@ class Trainer:
         kp[:self.vocab.size] = keep
         self._keep_prob_dev = put_global(plan.replicated, {"k": kp})["k"]
         self._tokens_per_step = tokens_per_step
+        self._est_pairs_per_token = est_pairs_per_token
         self._chunk_shardings = {"tokens": plan.tokens_stacked,
                                  "starts": plan.tokens_stacked,
                                  "obase": plan.tokens_stacked}
@@ -1770,8 +1593,8 @@ class Trainer:
         auditor exists to catch. Cost: a few hundred replicated bytes per
         dispatch through the same put_global discipline as the feed arrays.
 
-        This is also the single owner of the recovery lr backoff: every fit
-        path's alphas ride meta row 0 through here, so one multiplicative
+        This is also the single owner of the recovery lr backoff: every
+        feed's alphas ride meta row 0 through here, so one multiplicative
         ``_lr_scale`` (1.0 until a norm_watch="recover" firing backs it off)
         covers the host feed, both device feeds, and the sharded paths
         without touching any producer. Identical on every process — the
@@ -1788,13 +1611,6 @@ class Trainer:
             placed = put_global(self.plan.replicated, host)
             return (placed["meta"], placed["base"],
                     *[placed[f"b{i}"] for i in range(len(bases))])
-
-    def _put_chunk(self, arrays):
-        """Place one unstaged chunk's arrays for the step (span
-        ``dispatch.put``); a staged feed placed them a round ahead under
-        ``stage_put``."""
-        with self._tracer.span("dispatch.put"):
-            return put_global(self._chunk_shardings, arrays)
 
     def _after_dispatch(self) -> None:
         """Collective-program serialization gate (see __init__): on the
@@ -1853,7 +1669,7 @@ class Trainer:
         cfg = self.config
         # where this fit publishes checkpoints — the SIGTERM preemption hook
         # (config.checkpoint_on_preempt) drains its emergency save here, so
-        # the handler needs it before any fit path's bookkeeping runs
+        # the handler needs it before the run's bookkeeping starts
         self._active_checkpoint_path = checkpoint_path
         from glint_word2vec_tpu.data.pipeline import expected_kept_words
         train_words = expected_kept_words(
@@ -1866,178 +1682,81 @@ class Trainer:
                            * float(corpus_words))
         total_words = float(cfg.num_iterations * train_words + 1)
         K = max(1, cfg.steps_per_dispatch)
-        # banded CBOW rides the token-block feed paths (same chunk plumbing as
-        # device_pairgen; its blocks overlap by ±window — see __init__)
-        token_feed = cfg.device_pairgen or self._banded_cbow
-        if self._feed_segments > 1 and token_feed:
-            return self._fit_device_feed_sharded(
-                sentences, checkpoint_path, checkpoint_every_steps, on_heartbeat,
-                total_words, float(train_words), K)
-        if self._feed_segments > 1:
-            return self._fit_sharded(
-                sentences, checkpoint_path, checkpoint_every_steps, on_heartbeat,
-                total_words, K)
-        if token_feed:
-            return self._fit_device_feed(
-                sentences, checkpoint_path, checkpoint_every_steps, on_heartbeat,
-                total_words, float(train_words), K)
-        if self.state.shard_progress is not None and not self.state.finished:
-            # the recorded positions index a different stream than the
-            # replicated pair feed — resuming here would silently mis-position
-            if self.state.shard_feed == "tokens":
-                raise ValueError(
-                    "checkpoint was written by a token-block-feed run (its "
-                    "positions index per-segment token streams); resume it "
-                    "with the same feed — device_pairgen=True, or "
-                    "cbow_update='banded' if it was a banded-CBOW run")
-            raise ValueError(
-                "checkpoint was written by a sharded-input multi-process run "
-                f"({len(self.state.shard_progress)} shards); resume it with the "
-                "same process count and shard_input=True, not on the "
-                "replicated feed")
-        start_iter = self.state.iteration
-        # exact-step resume: the batch stream is deterministic per (seed, iteration,
-        # shard), so skipping the recorded number of already-trained batches reproduces
-        # the interrupted run's position instead of replaying the whole iteration
-        skip_batches = self.state.batches_done if not self.state.finished else 0
-
-        def chunk_stream():
-            """Pure-numpy chunk assembly: batch generation, K-stacking, padding, alpha
-            schedule. No JAX calls — safe to run on the producer thread."""
-            for k in range(start_iter, cfg.num_iterations + 1):
-                prev_words = (k - 1) * train_words
-                pending: List[dict] = []
-                pending_words: List[int] = []
-                batches_in_iter = skip_batches if k == start_iter else 0
-                to_skip = skip_batches if k == start_iter else 0
-
-                def flush():
-                    nonlocal pending, pending_words, batches_in_iter
-                    real = len(pending)
-                    while len(pending) < K:  # pad to the compiled chunk len, masked out
-                        dummy = {name: (0 if name == "real" else np.zeros_like(arr))
-                                 for name, arr in pending[0].items()}
-                        pending.append(dummy)
-                        pending_words.append(pending_words[-1])
-                    reals = np.asarray([b["real"] for b in pending], np.float32)
-                    if cfg.cbow:
-                        # filled in place like the pairs branch below: stack+astype
-                        # double-copies measurably throttle the producer
-                        B0 = pending[0]["centers"].shape[0]
-                        C0 = pending[0]["contexts"].shape[1]
-                        arrays = {
-                            "centers": np.empty((K, B0), self._pair_dtype),
-                            "contexts": np.empty((K, B0, C0), self._pair_dtype),
-                            "nctx": np.empty((K, B0), np.uint8),
-                        }
-                        for j, b in enumerate(pending):
-                            arrays["centers"][j] = b["centers"]
-                            arrays["contexts"][j] = b["contexts"]
-                            arrays["nctx"][j] = b["nctx"]
-                    else:
-                        # one contiguous [K, 2, B] feed array (see _build_step notes),
-                        # filled in place: nested np.stack + astype costs three copies
-                        # of the chunk and measurably throttled the producer (~2x the
-                        # raw pair-generation time at B=64k)
-                        pairs = np.empty(
-                            (K, 2, pending[0]["centers"].shape[0]), self._pair_dtype)
-                        for j, b in enumerate(pending):
-                            pairs[j, 0] = b["centers"]
-                            pairs[j, 1] = b["contexts"]
-                        arrays = {"pairs": pairs}
-                    alphas = np.asarray([
-                        alpha_schedule(float(w), total_words, cfg.learning_rate,
-                                       cfg.min_alpha_factor)
-                        for w in pending_words], np.float32)
-                    meta = np.stack([alphas, reals])  # [2, K] — rides with the dispatch
-                    # throughput counts real (unmasked) pairs, not padded batch slots
-                    real_pairs = float(reals.sum())
-                    batches_in_iter += real
-                    chunk = dict(
-                        arrays=arrays, meta=meta, real=real, iteration=k,
-                        words_processed=int(pending_words[real - 1]),
-                        batches_done=batches_in_iter, real_pairs=real_pairs)
-                    pending, pending_words = [], []
-                    return chunk
-
-                for batch in self._batch_stream(sentences, k):
-                    if to_skip:  # fast-forward already-trained batches (exact resume)
-                        to_skip -= 1
-                        continue
-                    pending_words.append(prev_words + batch.pop("words_seen"))
-                    pending.append(batch)
-                    if len(pending) == K:
-                        yield flush()
-                if pending:
-                    yield flush()
-
-        # The reference pipelines one minibatch ahead of its RPC round-trips for the
-        # same reason (mllib:428-429): host work must overlap accelerator work. Here a
-        # producer thread keeps a bounded buffer of ready chunks; numpy releases the
-        # GIL in its hot loops, so production genuinely overlaps dispatch. Device
-        # staging rides the same thread (_stage_to_device) so the feed's wire
-        # transfer overlaps device compute too — single-process prefetching only:
-        # multi-process runs must keep one cross-host dispatch order (see
-        # _stage_to_device), and with prefetch off the put stays in the consumer so
-        # the host-wait/dispatch split keeps its documented meaning.
-        staged = cfg.prefetch_chunks > 0 and jax.process_count() == 1
-        # span-wrap the producer so each chunk's assembly is timed ON the
-        # thread that runs it (the _threaded_iter producer when prefetching)
-        stream = self._tracer.wrap_iter("producer", chunk_stream())
-        if staged:
-            chunks = _threaded_iter(
-                self._stage_to_device(stream), cfg.prefetch_chunks)
-        elif cfg.prefetch_chunks > 0:
-            chunks = _threaded_iter(stream, cfg.prefetch_chunks)
+        # ONE loop over ONE feed (train/feeds.py): how a round's arrays are
+        # made is the feed's; everything from here on is the same for all.
+        # Banded CBOW rides the token-block feeds (same chunk plumbing as
+        # device_pairgen; its blocks overlap by ±window — see __init__).
+        gathered = self._feed_segments > 1
+        if cfg.device_pairgen or self._banded_cbow:
+            make = (feeds.GatheredTokenBlocks if gathered
+                    else feeds.TokenBlocks)
         else:
-            chunks = stream
+            make = feeds.GatheredPairs if gathered else feeds.HostPairs
+        feed = make(self, sentences, float(train_words), total_words, K)
 
         self._start_run_bookkeeping()
-        chunks = iter(chunks)
+        beacons = (self._start_peer_beacons(checkpoint_path) if gathered
+                   else None)
+        rounds = feed.rounds(beacons)
+        # a feed that counts on the device: [K] per round, summed at the end
+        pairs_arrays: List[jax.Array] = []
+        dropped_arrays: List[jax.Array] = []
+        est_total = 0.0
         try:
             while True:
                 t0 = time.perf_counter()
-                chunk = next(chunks, None)
-                wait = time.perf_counter() - t0
-                self.host_wait_time += wait
-                self._phases.add("producer_wait", wait)
-                if chunk is None:
+                rnd = next(rounds, None)
+                if not feed.books_own_time:
+                    wait = time.perf_counter() - t0
+                    self.host_wait_time += wait
+                    self._phases.add("producer_wait", wait)
+                if rnd is None:
                     break
                 t0 = time.perf_counter()
-                if cfg.feed_consistency_check and jax.process_count() > 1:
-                    # the replicated feed is the path where divergence CAN
-                    # happen: every process regenerated the stream itself
-                    self._assert_feed_consistent(chunk["arrays"], chunk["meta"])
-                real = chunk["real"]
-                with self._tracer.span("dispatch", steps=real):
-                    stacked = (chunk["arrays"] if staged else
-                               self._put_chunk(chunk["arrays"]))
-                    meta_dev, base_dev = self._stage_dispatch_meta(
-                        chunk["meta"], self.global_step + 1)
+                if (cfg.feed_consistency_check and not feed.placed
+                        and jax.process_count() > 1):
+                    # host arrays every process made (the replicated feed, where
+                    # divergence CAN happen: each regenerated the stream itself)
+                    # or assembled; a feed that places its own checks them first
+                    self._assert_feed_consistent(rnd.arrays, rnd.meta)
+                with self._tracer.span("dispatch", steps=rnd.real):
+                    stacked = rnd.arrays
+                    if not feed.placed:  # else a round ahead, under stage_put
+                        with self._tracer.span("dispatch.put"):
+                            stacked = put_global(self._chunk_shardings, stacked)
+                    meta_dev, base_dev, *bases_dev = self._stage_dispatch_meta(
+                        rnd.meta, self.global_step + 1, *rnd.bases)
                     with self._tracer.span("dispatch.enqueue"):
-                        self.params, metrics = self._dispatch_step_fn(real)(
+                        self.params, out = self._dispatch_step_fn(rnd.real)(
                             self.params, stacked, meta_dev, base_dev,
-                            *self._sampler_args, *self._step_extra)
+                            *feed.step_args, *bases_dev, *self._step_extra)
                 self.dispatch_time += time.perf_counter() - t0
                 self._after_dispatch()
+                metrics = out
+                if feed.counts_on_device:
+                    metrics, dropped = out
+                    pairs_arrays.append(metrics.pairs)
+                    dropped_arrays.append(dropped)
+                    est_total += rnd.pairs
                 self._finish_round(
-                    real, chunk["real_pairs"], chunk["meta"][0], metrics,
-                    TrainState(iteration=chunk["iteration"],
-                               words_processed=chunk["words_processed"],
-                               batches_done=chunk["batches_done"]),
+                    rnd.real, rnd.pairs, rnd.meta[0], metrics, rnd.state,
                     checkpoint_path, checkpoint_every_steps, on_heartbeat)
+                feed.ack()
         except BaseException:
             self._abort_run()  # its docstring has the why-not-sys.exc_info
             raise
         finally:
             self._stop_profiler()
-            closer = getattr(chunks, "close", None)
-            if closer is not None:
-                closer()
+            if beacons is not None:
+                beacons.stop()
+            feed.close()
 
+        if feed.counts_on_device:
+            self._settle_device_pairgen_books(
+                pairs_arrays, dropped_arrays, est_total)
         self.state = TrainState(
             iteration=cfg.num_iterations,
-            words_processed=int(cfg.num_iterations * train_words),
+            words_processed=feed.final_words,
             finished=True, global_step=self.global_step)
         if checkpoint_path:
             self.save_checkpoint(checkpoint_path)
@@ -2153,335 +1872,6 @@ class Trainer:
         if rest_tok.shape[0]:
             yield emit(rest_tok, rest_start)
 
-    def _device_step_rows(self, sentences: Sequence[np.ndarray], k: int, segs,
-                          skips=None, counts=None):
-        """One entry per step-row over the given data segments, stacked across
-        them: (tokens [n, T], start_bits [n, ·], nvalid [n] f32, obase [n, 2]
-        i32, exp_kept). A segment that exhausts before the others rides as zero
-        blocks (nvalid 0 — masked on device); the stream ends when every listed
-        segment is exhausted. The uint64→2×int32 ordinal-base split packing
-        lives only here; both the single-process and the sharded device-feed
-        chunk streams consume this shape.
-
-        ``skips`` (resume): per-segment block counts to fast-forward before
-        joining — -1 means the segment already finished this iteration (empty
-        from the start, no production cost). ``counts``: optional list updated
-        in place with each segment's consumed-block total (skips included) —
-        the per-SEGMENT positions elastic resume persists.
-
-        Parallelism (config.producer_workers > 1): with multiple segments the
-        per-segment block streams run on their own prefetching threads, gated
-        by a shared semaphore so at most ``producer_workers`` segments produce
-        concurrently (the ISSUE-3 multi-worker producer: segments are
-        independent and deterministic per (seed, k, s), and the merge below
-        consumes them in fixed segment order, so the joined step-row stream is
-        bit-identical to the serial one). Single-segment calls parallelize at
-        the slab level inside _device_seg_blocks instead."""
-        segs = list(segs)
-        T = self._tokens_per_step
-        tok_dt = self._pair_dtype
-        nbytes = (T + 7) // 8
-        workers = self.config.producer_workers
-        multi_seg = workers > 1 and len(segs) > 1
-        # split the worker budget: up to `workers` segments produce at once
-        # (the semaphore below), and each segment's slab work gets the
-        # leftover share — with fewer segments than workers the slab fan-out
-        # uses the rest instead of idling (workers=8 over 2 segments → 2
-        # segment threads × 4 slab workers, not 2 × 1)
-        inner_workers = max(1, workers // len(segs)) if multi_seg else workers
-        iters = []
-        for i, s in enumerate(segs):
-            skip = 0 if skips is None else skips[i]
-            if skip < 0:
-                iters.append(iter(()))
-                continue
-            it = self._device_seg_blocks(sentences, k, s,
-                                         workers=inner_workers)
-            consumed = 0
-            for _ in range(skip):
-                if next(it, None) is None:
-                    # shorter stream than the checkpointed position can only
-                    # mean the corpus changed since the checkpoint — replaying
-                    # silently would train the wrong data with wrong books
-                    raise ValueError(
-                        f"device-feed resume: segment {s} iteration {k} has "
-                        f"only {consumed} blocks but the checkpoint recorded "
-                        f"{skip} — the corpus does not match the checkpoint")
-                consumed += 1
-            iters.append(it)
-            if counts is not None:
-                counts[i] += consumed
-        closers: List[_threaded_iter] = []
-        if multi_seg:
-            import threading
-            sem = threading.Semaphore(workers)
-            _DONE = object()
-
-            def gated(gen):
-                # hold the semaphore only while producing one block, so at
-                # most `workers` segment streams burn CPU at once
-                while True:
-                    with sem:
-                        item = next(gen, _DONE)
-                    if item is _DONE:
-                        return
-                    yield item
-
-            wrapped = []
-            for it in iters:
-                ti = _threaded_iter(gated(it), maxsize=2)
-                closers.append(ti)
-                wrapped.append(iter(ti))
-            iters = wrapped
-        try:
-            while True:
-                rows = []
-                exp_kept = 0.0
-                exhausted = 0
-                for i, it in enumerate(iters):
-                    blk = next(it, None)
-                    if blk is None:
-                        exhausted += 1
-                        rows.append((np.zeros(T, tok_dt),
-                                     np.zeros(nbytes, np.uint8), 0, 0, 0.0))
-                    else:
-                        rows.append(blk)
-                        exp_kept += blk[4]
-                        if counts is not None:
-                            counts[i] += 1
-                if exhausted == len(iters):
-                    return
-                tokens = np.stack([r[0] for r in rows])
-                starts = np.stack([r[1] for r in rows])
-                nvalid = np.asarray([r[2] for r in rows], np.float32)
-                obase = np.asarray(
-                    [[r[3] & 0xFFFFFFFF, r[3] >> 32] for r in rows],
-                    np.uint32).view(np.int32)
-                yield (tokens, starts, nvalid, obase, exp_kept)
-        finally:
-            for c in closers:
-                c.close()
-
-    def _fit_device_feed(
-        self,
-        sentences: Sequence[np.ndarray],
-        checkpoint_path: Optional[str],
-        checkpoint_every_steps: Optional[int],
-        on_heartbeat: Optional[Callable[[HeartbeatRecord], None]],
-        total_words: float,
-        train_words: float,
-        K: int,
-    ) -> EmbeddingPair:
-        """fit() for the token-block feeds: the on-device pair generator
-        (config.device_pairgen) and banded CBOW (config.cbow_update="banded",
-        whose blocks overlap by ±window and whose "pairs" are CBOW examples —
-        the chunk/step plumbing below is shared unchanged).
-
-        The host packs whole sentences into fixed [T]-token blocks per (step,
-        data-segment) and ships raw tokens + packed sentence-start bits + ordinal
-        bases — ~2.1 bytes/token ≈ 1 byte/pair vs 4 for packed pairs. Subsampling
-        and window expansion happen inside the jitted chunk (ops/pairgen.py, same
-        hash lattice → bit-identical stream). The lr clock advances on the
-        *expected* kept-word count per step (keep_prob summed over shipped tokens) —
-        deterministic, and no worse an approximation than the reference's
-        ``numPartitions · wordCount`` clock (mllib:406-410); exact trained-pair and
-        dropped-pair totals come back from the device at the end of the run.
-        """
-        cfg = self.config
-        from glint_word2vec_tpu.data.hashrng import (
-            STREAM_SUBSAMPLE, STREAM_WINDOW, stream_base)
-        Sd = self.plan.num_data
-        T = self._tokens_per_step
-        tok_dt = self._pair_dtype
-        seg_state = None
-        if self.state.shard_progress is not None and not self.state.finished:
-            if self.state.shard_feed != "tokens":
-                raise ValueError(
-                    "checkpoint was written by a host-feed sharded-input run "
-                    "(its positions index per-process pair streams); resume it "
-                    "with the same process count and device_pairgen=False")
-            # elastic shrink: a multi-process device-feed checkpoint records
-            # per-SEGMENT (iteration, blocks) positions — one process can pick
-            # all of them up (_device_seg_resume_state validates the count).
-            # Single-process-written checkpoints (batches_done > 0) keep the
-            # legacy row-level skip: it rebuilds the lr clock exactly, where
-            # the per-segment path is exact to < 1 clock word
-            if self.state.batches_done == 0:
-                seg_state = self._device_seg_resume_state()
-        start_iter = (min(it for it, _ in seg_state) if seg_state
-                      else self.state.iteration)
-        skip_steps = (self.state.batches_done
-                      if not (self.state.finished or seg_state) else 0)
-        # analytic pairs/step estimate — heartbeat display only; exact totals come
-        # back from the device (see end of method)
-        rate_per_kept = (_cbow_examples_per_kept_token(cfg.window)
-                         if self._banded_cbow
-                         else _pairs_per_kept_token(cfg.window))
-
-        def chunk_stream():
-            for k in range(start_iter, cfg.num_iterations + 1):
-                prev_words = (k - 1) * train_words
-                sub_bases = np.asarray(
-                    [stream_base(cfg.seed, STREAM_SUBSAMPLE, k, s)
-                     for s in range(Sd)], np.uint32)
-                win_bases = np.asarray(
-                    [stream_base(cfg.seed, STREAM_WINDOW, k, s)
-                     for s in range(Sd)], np.uint32)
-                if seg_state:
-                    # Elastic resume from per-segment positions: fast-forward
-                    # each segment's block stream independently — recomputed
-                    # for EVERY k (entries may sit at different iterations,
-                    # e.g. an exhausted process frozen an iteration behind the
-                    # rest). The skipped rows' kept counts (the within-
-                    # iteration lr clock) are rebuilt from the saved word
-                    # count (exact to < 1 word) for the iteration the
-                    # checkpoint was saved in; earlier catch-up iterations
-                    # yield no rows at all, later ones start fresh.
-                    skips = [blocks if it == k else (-1 if it > k else 0)
-                             for it, blocks in seg_state]
-                    clock = (max(0.0, float(self.state.words_processed)
-                                 - prev_words)
-                             if k == self.state.iteration else 0.0)
-                    steps_in_iter = max(
-                        [b for it, b in seg_state if it == k], default=0)
-                    to_skip = 0
-                else:
-                    skips = None
-                    clock = 0.0
-                    steps_in_iter = skip_steps if k == start_iter else 0
-                    to_skip = skip_steps if k == start_iter else 0
-                counts = [0] * Sd  # filled in place by _device_step_rows
-                pending: List[tuple] = []
-                pending_words: List[float] = []
-
-                def flush():
-                    nonlocal pending, pending_words, steps_in_iter
-                    real = len(pending)
-                    while len(pending) < K:
-                        pending.append((np.zeros((Sd, T), tok_dt),
-                                        np.zeros((Sd, (T + 7) // 8), np.uint8),
-                                        np.zeros(Sd, np.float32),
-                                        np.zeros((Sd, 2), np.int32), 0.0))
-                        pending_words.append(pending_words[-1])
-                    arrays = {
-                        "tokens": np.stack([p[0] for p in pending]),
-                        "starts": np.stack([p[1] for p in pending]),
-                        "obase": np.stack([p[3] for p in pending]),
-                    }
-                    nvalid = np.stack([p[2] for p in pending])       # [K, Sd]
-                    alphas = np.asarray([
-                        alpha_schedule(w, total_words, cfg.learning_rate,
-                                       cfg.min_alpha_factor)
-                        for w in pending_words], np.float32)
-                    meta = np.concatenate([alphas[None, :], nvalid.T])  # [1+Sd, K]
-                    est_pairs = sum(p[4] for p in pending) * rate_per_kept
-                    steps_in_iter += real
-                    # per-segment positions after this chunk — what elastic
-                    # resume (any process count) reads back
-                    sprog = [(seg_state[s] if skips and skips[s] < 0
-                              else [k, counts[s]]) for s in range(Sd)]
-                    out = dict(
-                        arrays=arrays, meta=meta, real=real, iteration=k,
-                        words_processed=int(pending_words[real - 1]),
-                        # after an elastic (per-segment) resume the joined rows
-                        # are offset from the canonical stream, so a row count
-                        # would mis-position a later legacy resume — persist 0
-                        # and let sprog stay the authoritative position
-                        batches_done=0 if seg_state else steps_in_iter,
-                        est_pairs=est_pairs,
-                        sub_bases=sub_bases, win_bases=win_bases, sprog=sprog)
-                    pending, pending_words = [], []
-                    return out
-
-                for row in self._device_step_rows(sentences, k, range(Sd),
-                                                  skips=skips, counts=counts):
-                    clock += row[4]
-                    if to_skip:
-                        to_skip -= 1
-                        continue
-                    pending.append(row)
-                    pending_words.append(prev_words + clock)
-                    if len(pending) == K:
-                        yield flush()
-                if pending:
-                    yield flush()
-
-        staged = cfg.prefetch_chunks > 0  # this method is the single-process path
-                                          # (multi-process device feed goes through
-                                          # _fit_device_feed_sharded)
-        stream = self._tracer.wrap_iter("producer", chunk_stream())
-        if staged:
-            chunks = _threaded_iter(
-                self._stage_to_device(stream), cfg.prefetch_chunks)
-        else:
-            chunks = stream
-
-        self._start_run_bookkeeping()
-        chunks = iter(chunks)
-        pairs_arrays: List[jax.Array] = []      # [K] per chunk, summed at the end
-        dropped_arrays: List[jax.Array] = []
-        est_total = 0.0
-        try:
-            while True:
-                t0 = time.perf_counter()
-                chunk = next(chunks, None)
-                wait = time.perf_counter() - t0
-                self.host_wait_time += wait
-                self._phases.add("producer_wait", wait)
-                if chunk is None:
-                    break
-                t0 = time.perf_counter()
-                real = chunk["real"]
-                with self._tracer.span("dispatch", steps=real):
-                    stacked = (chunk["arrays"] if staged else
-                               self._put_chunk(chunk["arrays"]))
-                    meta_dev, base_dev, sub_dev, win_dev = \
-                        self._stage_dispatch_meta(
-                            chunk["meta"], self.global_step + 1,
-                            chunk["sub_bases"], chunk["win_bases"])
-                    with self._tracer.span("dispatch.enqueue"):
-                        self.params, (metrics, dropped) = \
-                            self._dispatch_step_fn(real)(
-                                self.params, stacked, meta_dev, base_dev,
-                                self._table_prob, self._table_alias,
-                                self._keep_prob_dev, sub_dev, win_dev,
-                                *self._step_extra)
-                self.dispatch_time += time.perf_counter() - t0
-                self._after_dispatch()
-                pairs_arrays.append(metrics.pairs)
-                dropped_arrays.append(dropped)
-                est_total += chunk["est_pairs"]
-                self._finish_round(
-                    real, chunk["est_pairs"], chunk["meta"][0], metrics,
-                    TrainState(iteration=chunk["iteration"],
-                               words_processed=chunk["words_processed"],
-                               batches_done=chunk["batches_done"],
-                               # per-segment positions so a multi-process run
-                               # can pick this checkpoint up (elastic grow);
-                               # this path's own resume uses batches_done
-                               shard_progress=[[int(a), int(b)]
-                                               for a, b in chunk["sprog"]],
-                               shard_feed="tokens"),
-                    checkpoint_path, checkpoint_every_steps, on_heartbeat)
-        except BaseException:
-            self._abort_run()  # its docstring has the why-not-sys.exc_info
-            raise
-        finally:
-            self._stop_profiler()
-            closer = getattr(chunks, "close", None)
-            if closer is not None:
-                closer()
-
-        self._settle_device_pairgen_books(pairs_arrays, dropped_arrays, est_total)
-        self.state = TrainState(
-            iteration=cfg.num_iterations,
-            words_processed=int(cfg.num_iterations * train_words),
-            finished=True, global_step=self.global_step)
-        if checkpoint_path:
-            self.save_checkpoint(checkpoint_path)
-        self._end_run("ok")
-        return self.params
-
     def _settle_device_pairgen_books(
         self,
         pairs_arrays: List[jax.Array],
@@ -2536,442 +1926,11 @@ class Trainer:
                 "input ordering or clock drift); training would silently "
                 "diverge from here")
 
-    def _device_seg_resume_state(self) -> List[List[int]]:
-        """Validated per-SEGMENT (iteration, blocks-consumed) resume positions
-        for the device feed — [plan.num_data] entries in segment order. Fresh
-        runs (and finished states) start every segment at (state.iteration, 0).
-        Entries are per segment, not per process, so any process count dividing
-        the mesh data degree can consume them (elastic restart)."""
-        Sd = self.plan.num_data
-        st = self.state
-        if st.shard_progress is None or st.finished:
-            if st.batches_done and not st.finished and jax.process_count() > 1:
-                # a pre-elastic single-process position counts joined step ROWS
-                # (zero-filled segments included) — not mappable to per-segment
-                # block positions
-                raise ValueError(
-                    "checkpoint was written mid-iteration by a pre-elastic "
-                    "device-feed run (no per-segment positions); resume it "
-                    "single-process (or from an iteration boundary)")
-            return [[st.iteration, 0] for _ in range(Sd)]
-        if st.shard_feed != "tokens":
-            # pairs-sharded positions count b_local PAIR-batches per process,
-            # not token blocks; pre-round-4 checkpoints (shard_feed None) too
-            raise ValueError(
-                "checkpoint shard_progress indexes the host-feed pair streams "
-                f"(shard_feed={st.shard_feed!r}); resume it with "
-                "device_pairgen=False — token positions are a different stream")
-        if len(st.shard_progress) != Sd:
-            raise ValueError(
-                f"checkpoint shard_progress has {len(st.shard_progress)} "
-                f"entries but the mesh data degree is {Sd}; device-feed "
-                "positions are per data segment — resume on a mesh with the "
-                "same data degree")
-        return [[int(a), int(b)] for a, b in st.shard_progress]
-
-    def _fit_device_feed_sharded(
-        self,
-        sentences: Sequence[np.ndarray],
-        checkpoint_path: Optional[str],
-        checkpoint_every_steps: Optional[int],
-        on_heartbeat: Optional[Callable[[HeartbeatRecord], None]],
-        total_words: float,
-        train_words: float,
-        K: int,
-    ) -> EmbeddingPair:
-        """Multi-process fit with BOTH input sharding and the on-device pair
-        generator: each process packs token blocks for its plan.num_data /
-        process_count data segments only; one process_allgather per dispatch round
-        ships (tokens, starts, ordinal bases, valid counts, expected-kept clock
-        deltas, alive flags, stream positions) to every process, which assembles
-        the identical [K, Sd, T] global token feed and derives identical alphas —
-        the _fit_sharded lockstep protocol (see its docstring) carrying ~1
-        byte/pair of raw tokens instead of 4 bytes/pair of packed pairs.
-
-        Segment streams are deterministic per (seed, iteration, segment) and
-        independent of the producing process (_device_seg_blocks), so the
-        assembled feed — and therefore training — is bit-identical to the
-        single-process device-feed run on the same mesh (tested:
-        tests/test_multiprocess.py).
-
-        Unlike _fit_sharded (which lets local streams cross iteration boundaries
-        freely), this path holds an ITERATION BARRIER so the update sequence is
-        bit-identical to the single-process run: every round, each process offers
-        its next chunk, the round's iteration is the minimum over live offers,
-        and only chunks AT that iteration are consumed — a process already in
-        iteration k+1 contributes zeroed segments (exactly the zero blocks the
-        single-process stream pads exhausted segments with) and retains its chunk
-        for a later round. Alphas use the single-process convention
-        ((k-1)·train_words + within-iteration kept cumsum), reconstructed
-        identically everywhere from allgathered kept sums.
-
-        ELASTIC RESUME: TrainState.shard_progress records, per DATA SEGMENT (not
-        per process), the last consumed (iteration, blocks) position. Segments
-        are the real stream unit — deterministic and process-independent — so a
-        checkpoint written on N processes resumes on ANY M with
-        mesh data degree % M == 0, including M=1 (the single-process device-feed
-        path reads the same entries). The reference has no analog: its recovery
-        story is Spark task retry against mutated PS state (SURVEY §5).
-
-        STAGING (config.sharded_prefetch, PERF.md §10): with prefetching on,
-        the per-round allgather/assembly/device-put runs one round ahead on a
-        background thread under the _one_ahead_iter ticket handshake, which
-        pins ONE deterministic per-process program-launch order — the
-        determinism contract above is untouched because every staged value is
-        still a pure function of allgathered data; only WHEN the host does the
-        work moves.
-        """
-        from glint_word2vec_tpu.data.hashrng import (
-            STREAM_SUBSAMPLE, STREAM_WINDOW, stream_base)
-        cfg = self.config
-        S = jax.process_count()
-        pid = jax.process_index()
-        Sd = self.plan.num_data
-        spp = Sd // S
-        own = list(range(pid * spp, (pid + 1) * spp))
-        T = self._tokens_per_step
-        tok_dt = self._pair_dtype
-        nbytes = (T + 7) // 8
-
-        # per-own-segment last consumed (iteration, blocks) — the elastic-resume
-        # positions; fresh runs start every segment at (state.iteration, 0)
-        seg_state = self._device_seg_resume_state()[pid * spp:(pid + 1) * spp]
-        start_iter = min(it for it, _ in seg_state)
-
-        rate_per_kept = (_cbow_examples_per_kept_token(cfg.window)
-                         if self._banded_cbow
-                         else _pairs_per_kept_token(cfg.window))
-
-        def local_stream():
-            """This process's chunks: K step-rows of spp [T]-token segment blocks
-            + per-row expected-kept counts, this iteration's hash bases, and the
-            per-own-segment (iteration, blocks) positions AFTER the chunk (the
-            elastic-resume snapshot). Pure numpy — safe on the producer thread
-            (the allgather, a device collective, must run on the main thread in
-            identical order everywhere)."""
-            for k in range(start_iter, cfg.num_iterations + 1):
-                sub_b = np.asarray(
-                    [stream_base(cfg.seed, STREAM_SUBSAMPLE, k, s) for s in own],
-                    np.uint32)
-                win_b = np.asarray(
-                    [stream_base(cfg.seed, STREAM_WINDOW, k, s) for s in own],
-                    np.uint32)
-                # -1 = segment already past iteration k (finished it before the
-                # checkpoint); its entry must survive the snapshot untouched
-                skips = [blocks if it == k else (-1 if it > k else 0)
-                         for it, blocks in seg_state]
-                counts = [0] * spp  # filled in place by _device_step_rows
-                pending: List[tuple] = []
-
-                def flush():
-                    nonlocal pending
-                    real = len(pending)
-                    while len(pending) < K:
-                        pending.append((np.zeros((spp, T), tok_dt),
-                                        np.zeros((spp, nbytes), np.uint8),
-                                        np.zeros(spp, np.float32),
-                                        np.zeros((spp, 2), np.int32), 0.0))
-                    sprog = np.asarray(
-                        [seg_state[i] if skips[i] < 0 else [k, counts[i]]
-                         for i in range(spp)], np.int64)
-                    out = dict(
-                        tokens=np.stack([p[0] for p in pending]),
-                        starts=np.stack([p[1] for p in pending]),
-                        nvalid=np.stack([p[2] for p in pending]),
-                        obase=np.stack([p[3] for p in pending]),
-                        kept=np.asarray([p[4] for p in pending], np.float32),
-                        sub_bases=sub_b, win_bases=win_b,
-                        iteration=k, sprog=sprog, real=real)
-                    pending = []
-                    return out
-
-                for row in self._device_step_rows(
-                        sentences, k, own, skips=skips, counts=counts):
-                    pending.append(row[:4] + (np.float32(row[4]),))
-                    if len(pending) == K:
-                        yield flush()
-                if pending:
-                    yield flush()
-
-        lstream = self._tracer.wrap_iter("producer", local_stream())
-        if cfg.prefetch_chunks > 0:
-            chunks = _threaded_iter(lstream, cfg.prefetch_chunks)
-        else:
-            chunks = iter(lstream)
-
-        # stage one round ahead (config.sharded_prefetch): the round generator
-        # below runs on a _one_ahead_iter thread and launches the NEXT round's
-        # allgather before yielding the current one, so the gather's wire
-        # transfer sits ahead of the step dispatch in the device queue and the
-        # host-side decode/assembly/put-DMA overlap chunk compute. The ticket
-        # handshake keeps one deterministic cross-host launch order:
-        # [gather_1, touch_1, gather_2], dispatch_1 + bookkeeping_1,
-        # [touch_2, gather_3], dispatch_2, ... — identical on every process.
-        staged = bool(cfg.sharded_prefetch and cfg.prefetch_chunks > 0)
-        est_total = 0.0
-        pairs_arrays: List[jax.Array] = []
-        dropped_arrays: List[jax.Array] = []
-        self._start_run_bookkeeping()
-        beacons = self._start_peer_beacons(checkpoint_path)
-
-        def round_stream():
-            from glint_word2vec_tpu.parallel.distributed import (
-                allgather_fetch, allgather_start)
-            cur_sprog = np.asarray(seg_state, np.int64)  # [spp, 2] last CONSUMED
-            # barrier state: the iteration currently training and its cumulative
-            # kept-word clock. On resume the within-iteration clock is rebuilt
-            # from the saved word count (exact to < 1 word — the int()
-            # truncation of the analytic iteration base; same approximation
-            # class as the saved clock itself, and resumed runs match
-            # uninterrupted ones to the suite's 1e-4 standard, not bitwise)
-            round_iter = self.state.iteration
-            iter_kept = max(0.0, float(self.state.words_processed)
-                            - (round_iter - 1) * train_words)
-            held = None         # produced-but-not-yet-consumed local chunk
-            exhausted = False
-            zero = dict(tokens=np.zeros((K, spp, T), tok_dt),
-                        starts=np.zeros((K, spp, nbytes), np.uint8),
-                        nvalid=np.zeros((K, spp), np.float32),
-                        obase=np.zeros((K, spp, 2), np.int32),
-                        kept=np.zeros(K, np.float32),
-                        sub_bases=np.zeros(spp, np.uint32),
-                        win_bases=np.zeros(spp, np.uint32))
-
-            def start_gather():
-                """Collect this process's next offer and LAUNCH (not fetch) its
-                allgather. The offer protocol is byte-identical to the
-                pre-staging loop; only the launch/fetch split is new."""
-                nonlocal held, exhausted
-                if held is None and not exhausted:
-                    t0 = time.perf_counter()
-                    held = next(chunks, None)
-                    if not staged:
-                        wait = time.perf_counter() - t0
-                        self.host_wait_time += wait
-                        self._phases.add("producer_wait", wait)
-                    if held is None:
-                        exhausted = True
-                offer = held if held is not None else dict(
-                    zero, iteration=int(cur_sprog[:, 0].max()),
-                    sprog=cur_sprog, real=0)
-                return allgather_start({
-                    "tokens": offer["tokens"], "starts": offer["starts"],
-                    "nvalid": offer["nvalid"], "obase": offer["obase"],
-                    "kept": offer["kept"],
-                    "sub": offer["sub_bases"], "win": offer["win_bases"],
-                    "real": np.asarray([offer["real"]], np.int32),
-                    "iter": np.asarray([offer["iteration"]], np.int64),
-                    "sprog": np.asarray(offer["sprog"], np.int64),
-                    "alive": np.asarray([0 if exhausted else 1], np.int32),
-                    "prog": cur_sprog,
-                })
-
-            pending = start_gather()
-            while True:
-                if beacons is not None:
-                    # see _fit_sharded: a dead peer's collective never comes;
-                    # check (a file stat — safe on this producer thread)
-                    # before blocking on the fetch
-                    beacons.check_or_raise()
-                t0 = time.perf_counter()
-                with self._tracer.span("allgather_fetch"):
-                    g = allgather_fetch(pending)  # leading [S] process axis
-                alive = g["alive"][:, 0] > 0                        # [S]
-                if not alive.any():
-                    # every process observes the same all-dead round and stops
-                    # here; a pipelined gather for the round after may already
-                    # be launched — every process launched it identically, so
-                    # it executes consistently and nobody reads it
-                    return
-                # iteration barrier: this round trains the minimum live
-                # iteration; offers from a later iteration are NOT consumed —
-                # their segments ride as zeros (exactly the zero blocks the
-                # single-process stream pads exhausted segments with) and
-                # their owners re-offer them next round
-                round_it = int(g["iter"][alive, 0].min())
-                use = alive & (g["iter"][:, 0] == round_it)         # [S]
-                if round_it != round_iter:
-                    round_iter, iter_kept = round_it, 0.0
-                usef = use.astype(np.float32)
-                # segment axis assembly: [S, K, spp, ...] -> [K, S*spp=Sd, ...]
-                arrays = {
-                    "tokens": np.transpose(
-                        g["tokens"] * use[:, None, None, None].astype(tok_dt),
-                        (1, 0, 2, 3)).reshape(K, Sd, T),
-                    "starts": np.transpose(
-                        g["starts"] * use[:, None, None, None].astype(np.uint8),
-                        (1, 0, 2, 3)).reshape(K, Sd, nbytes),
-                    "obase": np.transpose(
-                        g["obase"] * use[:, None, None, None].astype(np.int32),
-                        (1, 0, 2, 3)).reshape(K, Sd, 2),
-                }
-                nvalid = np.transpose(
-                    g["nvalid"] * usef[:, None, None], (1, 0, 2)).reshape(K, Sd)
-                sub_bases = g["sub"].reshape(Sd)
-                win_bases = g["win"].reshape(Sd)
-                kept_step = (g["kept"].astype(np.float64)
-                             * usef[:, None]).sum(axis=0)           # [K]
-                # the single-process alpha convention: analytic iteration base
-                # plus the within-iteration kept cumsum (identical on every
-                # process — all inputs are allgathered values)
-                clocks = ((round_it - 1) * train_words + iter_kept
-                          + np.cumsum(kept_step))
-                iter_kept += float(kept_step.sum())
-                alphas = np.asarray(
-                    [alpha_schedule(float(w), total_words, cfg.learning_rate,
-                                    cfg.min_alpha_factor) for w in clocks],
-                    np.float32)
-                meta = np.concatenate([alphas[None, :], nvalid.T])  # [1+Sd, K]
-                # used processes pad only their final chunk per iteration, so
-                # real rows are prefixes; the longest prefix is the row count
-                real = int(g["real"][use, 0].max())
-                est_pairs = float(kept_step.sum()) * rate_per_kept
-
-                if cfg.feed_consistency_check:
-                    self._assert_feed_consistent(
-                        dict(arrays, sub=sub_bases, win=win_bases), meta)
-                with self._tracer.span("stage_put"):
-                    stacked = put_global(self._chunk_shardings, arrays)
-                    if staged and not self._sync_collectives:
-                        # force the upload DMA now, overlapped with chunk
-                        # compute (skipped on the CPU mesh — see
-                        # _stage_to_device; the gate condition is identical on
-                        # every process, so the pinned cross-process launch
-                        # order stays consistent)
-                        self._touch(stacked)
-                if use[pid] and held is not None:
-                    cur_sprog = np.asarray(held["sprog"], np.int64)
-                    held = None
-                # prog in THIS round's allgather predates the consumption
-                # above, so each SEGMENT's persisted position comes from its
-                # owner's offer if consumed, else from its last consumed
-                # snapshot — a held offer was not trained
-                prog = [[int(a), int(b)]
-                        for s in range(S)
-                        for a, b in (g["sprog"][s] if use[s] else g["prog"][s])]
-                if staged:
-                    # pipelining: LAUNCH the next round's gather before
-                    # yielding, so it precedes this round's dispatch in every
-                    # process's launch order and its transfer rides ahead of
-                    # the chunk in the device queue
-                    pending = start_gather()
-                else:
-                    self.dispatch_time += time.perf_counter() - t0
-                yield dict(
-                    stacked=stacked, meta=meta, real=real, est_pairs=est_pairs,
-                    sub_bases=sub_bases, win_bases=win_bases, round_it=round_it,
-                    words=int(clocks[max(real - 1, 0)]), prog=prog)
-                if not staged:
-                    pending = start_gather()
-
-        rounds = round_stream()
-        if staged:
-            rounds = _one_ahead_iter(rounds)
-        rounds_it = iter(rounds)
-        try:
-            while True:
-                t0 = time.perf_counter()
-                rnd = next(rounds_it, None)
-                if staged:
-                    # unstaged, the wait IS the round assembly — its stage/
-                    # dispatch splits are attributed inside round_stream
-                    wait = time.perf_counter() - t0
-                    self.host_wait_time += wait
-                    self._phases.add("producer_wait", wait)
-                if rnd is None:
-                    break
-                t0 = time.perf_counter()
-                with self._tracer.span("dispatch", steps=rnd["real"]):
-                    meta_dev, base_dev, sub_dev, win_dev = \
-                        self._stage_dispatch_meta(
-                            rnd["meta"], self.global_step + 1,
-                            rnd["sub_bases"], rnd["win_bases"])
-                    with self._tracer.span("dispatch.enqueue"):
-                        self.params, (metrics, dropped) = \
-                            self._dispatch_step_fn(rnd["real"])(
-                                self.params, rnd["stacked"], meta_dev,
-                                base_dev, self._table_prob, self._table_alias,
-                                self._keep_prob_dev, sub_dev, win_dev)
-                self.dispatch_time += time.perf_counter() - t0
-                self._after_dispatch()
-                pairs_arrays.append(metrics.pairs)
-                dropped_arrays.append(dropped)
-                est_total += rnd["est_pairs"]
-                self._finish_round(
-                    rnd["real"], rnd["est_pairs"], rnd["meta"][0], metrics,
-                    TrainState(
-                        iteration=rnd["round_it"],
-                        words_processed=rnd["words"],
-                        # meaningless across segments — resume uses the
-                        # per-segment shard_progress
-                        batches_done=0,
-                        shard_progress=rnd["prog"], shard_feed="tokens"),
-                    checkpoint_path, checkpoint_every_steps, on_heartbeat)
-                if staged:
-                    # round fully consumed (dispatch + any heartbeat fetch /
-                    # checkpoint collectives launched) — release the stager
-                    rounds.ack()
-        except BaseException:
-            self._abort_run()  # its docstring has the why-not-sys.exc_info
-            raise
-        finally:
-            self._stop_profiler()
-            if beacons is not None:
-                beacons.stop()
-            closer = getattr(rounds, "close", None)
-            if closer is not None:
-                closer()
-            closer = getattr(chunks, "close", None)
-            if closer is not None:
-                closer()
-
-        self._settle_device_pairgen_books(pairs_arrays, dropped_arrays, est_total)
-        self.state = TrainState(
-            iteration=cfg.num_iterations,
-            words_processed=int(cfg.num_iterations * train_words),
-            finished=True, global_step=self.global_step)
-        if checkpoint_path:
-            self.save_checkpoint(checkpoint_path)
-        self._end_run("ok")
-        return self.params
-
-    def _stage_to_device(self, chunks):
-        """Generator stage: place each chunk's feed arrays on device and dispatch a
-        tiny consuming op so the host→device wire transfer happens HERE — on the
-        producer thread when prefetching — overlapped with the main thread's step
-        dispatches. Argument upload is otherwise lazy and serializes with compute
-        at dispatch time, which shows wherever the feed link is thin (a DCN feed,
-        a slow PCIe hop).
-
-        Single-process free-running only: with multiple processes, a
-        producer-thread dispatch would race the main thread's step dispatch for
-        cross-host program launch order and can deadlock the collectives — the
-        multi-process device-feed path instead stages through the
-        ``_one_ahead_iter`` ticket handshake (see _fit_device_feed_sharded),
-        which pins one deterministic launch order; the remaining multi-process
-        feeds keep the consumer-thread put."""
-        for chunk in chunks:
-            with self._tracer.span("stage_put"):
-                stacked = put_global(self._chunk_shardings, chunk["arrays"])
-            chunk["arrays"] = stacked
-            # retain the forcing op's output with the chunk (never fetched — a
-            # blocking fetch here stalls the producer behind the device queue,
-            # measured slower; the dispatch is enough to enqueue the upload).
-            # NOT on the multi-device CPU mesh: the touch's tiny cross-shard
-            # reduction lowers to collectives, and a producer-THREAD program
-            # racing the main thread's chunk is exactly the rendezvous-
-            # starvation deadlock __init__ documents (this touch was the
-            # racer observed live). There is no lazy-upload wire to force on
-            # that backend anyway — device_put is a host memcpy.
-            if not self._sync_collectives:
-                chunk["_touch"] = self._touch(stacked)
-            yield chunk
-
     def _touch(self, stacked):
         """Dispatch a tiny consuming op over staged feed arrays so their
         host→device upload is enqueued NOW (on the calling thread) instead of
         lazily at step-dispatch time — the transfer-forcing half of
-        :meth:`_stage_to_device`, shared with the sharded round stager."""
+        ``feeds._stage_to_device``, shared with the gathered round stager."""
         if not hasattr(self, "_touch_fn"):
             import operator
 
@@ -3092,7 +2051,7 @@ class Trainer:
                 span.close(end=end)
 
     def _stop_profiler(self) -> None:
-        # every fit loop leaves through here (its ``finally``), before the
+        # the fit loop leaves through here (its ``finally``), before the
         # fit's last save: a heartbeat round no dispatch followed ends too
         if self._round:
             self._close_round(dispatched=False)
@@ -3376,7 +2335,7 @@ class Trainer:
     def _install_run_signals(self) -> None:
         """Arm the flight recorder's SIGTERM hook for the duration of fit():
         SIGTERM is the first thing a preemption/k8s eviction sends and, unlike
-        SIGINT (delivered as KeyboardInterrupt, which the fit paths' abort
+        SIGINT (delivered as KeyboardInterrupt, which the fit loop's abort
         handler already turns into a dump), it would otherwise kill the
         process with no artifact. Main-thread only (the signal module's
         rule); restored by _teardown_run_inspection.
@@ -3549,7 +2508,7 @@ class Trainer:
         return self._tracer.export_chrome_trace(path)
 
     def _abort_run(self) -> None:
-        """Sits in every fit path's ``except BaseException: ...; raise``:
+        """Sits in the fit loop's ``except BaseException: ...; raise``:
         run_end with status="error" before the raise unwinds (guardrail
         halt, watchdog halt, feed error). An ``except`` clause — NOT
         ``sys.exc_info()`` in the ``finally`` — because exc_info also
@@ -3582,7 +2541,7 @@ class Trainer:
         checkpoint_every_steps: Optional[int],
         on_heartbeat: Optional[Callable[[HeartbeatRecord], None]],
     ) -> None:
-        """Post-dispatch bookkeeping shared by both feed modes: progress counters,
+        """Post-dispatch bookkeeping of the fit loop, whatever the feed: progress counters,
         heartbeat cadence (the reference's every-10k-words line, mllib:404-413 —
         fetching device metrics forces a sync, so it runs on a chunked cadence to keep
         the async dispatch pipeline full), the non-finite guardrail + scripted fault
@@ -3879,285 +2838,15 @@ class Trainer:
         board.start()
         return board
 
-    def _fit_sharded(
-        self,
-        sentences: Sequence[np.ndarray],
-        checkpoint_path: Optional[str],
-        checkpoint_every_steps: Optional[int],
-        on_heartbeat: Optional[Callable[[HeartbeatRecord], None]],
-        total_words: float,
-        K: int,
-    ) -> EmbeddingPair:
-        """Multi-process fit with the sentence stream sharded across processes — the
-        repartition analog (mllib:345), replacing the every-process-regenerates-
-        everything feed.
-
-        Protocol, one dispatch round at a time (all processes in lockstep):
-
-        1. each process pulls its next LOCAL chunk — K batches of B/N pairs from
-           ``epoch_batches(shard=pid, num_shards=N)`` — off its producer thread;
-           an exhausted process substitutes a zero chunk;
-        2. ONE ``process_allgather`` ships every process's (pairs, real counts, word
-           deltas, alive flag, stream position) to every process — the data rides the
-           fast device interconnect, not a host-side side channel;
-        3. every process deterministically assembles the identical global batch
-           ([K, 2, B]: N contiguous per-process segments), derives the global word
-           clock from the summed deltas, and computes identical per-batch alphas —
-           SPMD consistency holds because every input to the jitted step is a pure
-           function of allgathered values;
-        4. the round ends when the allgathered alive flags are all zero. Processes
-           whose stream ended early keep dispatching fully-masked segments, so there
-           is no "process 3 ran out one step early" deadlock class.
-
-        Unequal per-process streams make a single (iteration, batches_done) pair
-        meaningless, so TrainState.shard_progress records every process's position
-        (from step 2, free) and resume requires the same process count.
-        """
-        import jax
-        from jax.experimental import multihost_utils
-
-        cfg = self.config
-        S = self._feed_segments
-        pid = jax.process_index()
-        B = cfg.pairs_per_batch
-        b_local = B // S
-
-        start_iter = self.state.iteration
-        skip = self.state.batches_done if not self.state.finished else 0
-        if self.state.shard_progress is not None:
-            sp = self.state.shard_progress
-            if self.state.shard_feed not in (None, "pairs"):
-                # device-feed positions count token-step rows, not b_local
-                # pair-batches (None = pre-round-4 checkpoint, always pairs)
-                raise ValueError(
-                    "checkpoint shard_progress indexes the device-feed token "
-                    f"streams (shard_feed={self.state.shard_feed!r}); resume "
-                    "it with device_pairgen=True — pair-batch positions are a "
-                    "different stream")
-            if len(sp) != S:
-                raise ValueError(
-                    f"checkpoint shard_progress has {len(sp)} entries but this run "
-                    f"has {S} processes; resume sharded-input runs with the same "
-                    "process count")
-            start_iter, skip = int(sp[pid][0]), int(sp[pid][1])
-        elif skip:
-            # a replicated-feed checkpoint's batches_done counts full-B batches of the
-            # unsharded stream — there is no exact mapping onto per-process local
-            # streams, so refuse rather than silently mis-position the resume
-            raise ValueError(
-                "checkpoint was written mid-iteration by a replicated-feed run; it "
-                "cannot be resumed exactly with shard_input=True — resume with "
-                "shard_input=False (or from an iteration-boundary checkpoint)")
-
-        C = 2 * cfg.window
-
-        def empty_feed() -> dict:
-            """One schema for the local per-chunk feed arrays — used zeroed for the
-            exhausted-process placeholder and as the fill target in flush()."""
-            if cfg.cbow:
-                return {"centers": np.zeros((K, b_local), np.int32),
-                        "contexts": np.zeros((K, b_local, C), np.int32),
-                        "nctx": np.zeros((K, b_local), np.int32)}
-            return {"pairs": np.zeros((K, 2, b_local), np.int32)}
-
-        def local_stream():
-            """Local chunks ([K, 2, b_local] pairs, or centers/contexts/nctx arrays
-            for CBOW) + per-batch real counts and word deltas. Pure numpy — safe on
-            the producer thread (the allgather, a device collective, must run on the
-            main thread in identical order everywhere)."""
-            for k in range(start_iter, cfg.num_iterations + 1):
-                pending: List[tuple] = []
-                reals: List[int] = []
-                deltas: List[int] = []
-                prev_ws = 0
-                batches_in_iter = skip if k == start_iter else 0
-                to_skip = skip if k == start_iter else 0
-
-                def flush():
-                    nonlocal pending, reals, deltas, batches_in_iter
-                    real = len(pending)
-                    batches_in_iter += real
-                    # filled in place, like the replicated flush: stacked copies
-                    # throttle the producer
-                    arrays = empty_feed()
-                    if cfg.cbow:
-                        for j, (c, x, nc) in enumerate(pending):
-                            arrays["centers"][j] = c
-                            arrays["contexts"][j] = x
-                            arrays["nctx"][j] = nc
-                    else:
-                        for j, (c, x) in enumerate(pending):
-                            arrays["pairs"][j, 0] = c
-                            arrays["pairs"][j, 1] = x
-                    while len(reals) < K:
-                        reals.append(0)
-                        deltas.append(0)
-                    out = dict(
-                        arrays=arrays,
-                        reals=np.asarray(reals, np.int32),
-                        deltas=np.asarray(deltas, np.int64),
-                        iteration=k, batches_done=batches_in_iter)
-                    pending, reals, deltas = [], [], []
-                    return out
-
-                if cfg.cbow:
-                    stream = epoch_batches_cbow(
-                        sentences, self.vocab, pairs_per_batch=b_local,
-                        window=cfg.window, subsample_ratio=cfg.subsample_ratio,
-                        seed=cfg.seed, iteration=k, shard=pid, num_shards=S,
-                        shuffle=cfg.shuffle,
-                        producer_workers=cfg.producer_workers)
-                else:
-                    stream = epoch_batches(
-                        sentences, self.vocab, pairs_per_batch=b_local,
-                        window=cfg.window, subsample_ratio=cfg.subsample_ratio,
-                        seed=cfg.seed, iteration=k, shard=pid, num_shards=S,
-                        shuffle=cfg.shuffle,
-                        producer_workers=cfg.producer_workers)
-                for b in stream:
-                    ws = b.words_seen
-                    if to_skip:  # exact resume: fast-forward already-trained batches
-                        to_skip -= 1
-                        prev_ws = ws
-                        continue
-                    if cfg.cbow:
-                        pending.append((b.centers, b.contexts, b.n_ctx))
-                        reals.append(b.num_real)
-                    else:
-                        pending.append((b.centers, b.contexts))
-                        reals.append(b.num_real_pairs)
-                    deltas.append(ws - prev_ws)
-                    prev_ws = ws
-                    if len(pending) == K:
-                        yield flush()
-                if pending:
-                    yield flush()
-
-        lstream = self._tracer.wrap_iter("producer", local_stream())
-        if cfg.prefetch_chunks > 0:
-            chunks = _threaded_iter(lstream, cfg.prefetch_chunks)
-        else:
-            chunks = iter(lstream)
-
-        clock = float(self.state.words_processed)
-        cur_iter, cur_batches = start_iter, skip
-        exhausted = False
-        self._start_run_bookkeeping()
-        beacons = self._start_peer_beacons(checkpoint_path)
-        zero_arrays = empty_feed()
-        try:
-            while True:
-                t0 = time.perf_counter()
-                local = None if exhausted else next(chunks, None)
-                wait = time.perf_counter() - t0
-                self.host_wait_time += wait
-                self._phases.add("producer_wait", wait)
-                if local is None:
-                    exhausted = True
-                    local = dict(arrays=zero_arrays,
-                                 reals=np.zeros(K, np.int32),
-                                 deltas=np.zeros(K, np.int64),
-                                 iteration=cur_iter, batches_done=cur_batches)
-                else:
-                    cur_iter = local["iteration"]
-                    cur_batches = local["batches_done"]
-
-                if beacons is not None:
-                    # a dead peer never reaches its allgather — entering ours
-                    # would hang forever; the beacon check converts that into
-                    # a clean abort the supervisor restarts the gang from
-                    beacons.check_or_raise()
-                t0 = time.perf_counter()
-                g = multihost_utils.process_allgather({
-                    **local["arrays"],
-                    "reals": local["reals"],
-                    "deltas": local["deltas"],
-                    "alive": np.asarray([0 if exhausted else 1], np.int32),
-                    "prog": np.asarray([cur_iter, cur_batches], np.int64),
-                })  # every leaf gains a leading [S] process axis
-                if int(g["alive"].sum()) == 0:
-                    break
-                reals_all = g["reals"]                              # [S, K]
-                # segment s of every batch is process s's slice, matching the
-                # device-side per-segment prefix masks
-                if cfg.cbow:
-                    feed = {
-                        # [S, K, b(, C)] -> [K, S, b(, C)] -> [K, B(, C)]
-                        "centers": np.transpose(g["centers"], (1, 0, 2)).reshape(
-                            K, B).astype(self._pair_dtype),
-                        "contexts": np.transpose(
-                            g["contexts"], (1, 0, 2, 3)).reshape(
-                                K, B, C).astype(self._pair_dtype),
-                        "nctx": np.transpose(g["nctx"], (1, 0, 2)).reshape(
-                            K, B).astype(np.uint8),
-                    }
-                else:
-                    # [S, K, 2, b] -> [K, 2, S, b] -> [K, 2, B]
-                    feed = {"pairs": np.transpose(
-                        g["pairs"], (1, 2, 0, 3)).reshape(K, 2, B).astype(
-                            self._pair_dtype)}
-                clocks = clock + np.cumsum(g["deltas"].sum(axis=0))
-                clock = float(clocks[-1])
-                alphas = np.asarray(
-                    [alpha_schedule(float(w), total_words, cfg.learning_rate,
-                                    cfg.min_alpha_factor) for w in clocks], np.float32)
-                meta = np.concatenate(
-                    [alphas[None, :], reals_all.astype(np.float32)], axis=0)
-                # each local stream pads only its final chunk, so per-process real
-                # slots are prefixes and "any segment live" is a prefix too
-                real = int((reals_all > 0).any(axis=0).sum())
-                real_pairs = float(reals_all.sum())
-
-                if cfg.feed_consistency_check:
-                    self._assert_feed_consistent(feed, meta)
-                with self._tracer.span("dispatch", steps=real):
-                    stacked = self._put_chunk(feed)
-                    meta_dev, base_dev = self._stage_dispatch_meta(
-                        meta, self.global_step + 1)
-                    with self._tracer.span("dispatch.enqueue"):
-                        self.params, metrics = self._dispatch_step_fn(real)(
-                            self.params, stacked, meta_dev, base_dev,
-                            self._table_prob, self._table_alias)
-                self.dispatch_time += time.perf_counter() - t0
-                self._after_dispatch()
-                self._finish_round(
-                    real, real_pairs, meta[0], metrics,
-                    TrainState(
-                        iteration=int(g["prog"][:, 0].min()),
-                        words_processed=int(clock),
-                        # batches_done is meaningless across shards (each process's
-                        # local stream advances at its own rate); sharded-input
-                        # resume MUST use shard_progress, so persist 0 here rather
-                        # than the writing process's local count
-                        batches_done=0,
-                        shard_progress=[[int(a), int(b_)] for a, b_ in g["prog"]],
-                        shard_feed="pairs"),
-                    checkpoint_path, checkpoint_every_steps, on_heartbeat)
-        except BaseException:
-            self._abort_run()  # its docstring has the why-not-sys.exc_info
-            raise
-        finally:
-            self._stop_profiler()
-            if beacons is not None:
-                beacons.stop()
-            closer = getattr(chunks, "close", None)
-            if closer is not None:
-                closer()
-
-        self.state = TrainState(
-            iteration=cfg.num_iterations,
-            words_processed=int(clock),
-            finished=True, global_step=self.global_step)
-        if checkpoint_path:
-            self.save_checkpoint(checkpoint_path)
-        self._end_run("ok")
-        return self.params
-
-    def _batch_stream(self, sentences: Sequence[np.ndarray], iteration: int):
+    def _batch_stream(self, sentences: Sequence[np.ndarray], iteration: int,
+                      shard: int = 0, num_shards: int = 1):
+        """One iteration's batches: the whole stream, or one process's shard of
+        it (feeds.GatheredPairs), a ``num_shards``-th of the batch each."""
         cfg = self.config
         common = dict(
-            pairs_per_batch=cfg.pairs_per_batch, window=cfg.window,
+            pairs_per_batch=cfg.pairs_per_batch // num_shards, window=cfg.window,
             subsample_ratio=cfg.subsample_ratio, seed=cfg.seed, iteration=iteration,
+            shard=shard, num_shards=num_shards,
             shuffle=cfg.shuffle, producer_workers=cfg.producer_workers)
         # batches are prefix-masked by construction (PairBatcher pads only the tail),
         # so only the real count ships — the device rebuilds mask = (iota < real)

@@ -321,6 +321,9 @@ def test_chaos_runner_smoke(tmp_path):
         [sys.executable, os.path.join(_REPO, "tools", "chaos_run.py"),
          "--smoke", "--workdir", str(tmp_path / "chaos")],
         env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        cwd=_REPO, capture_output=True, timeout=500, text=True)
+        # fourteen phases, each a fit or a fleet in subprocesses of its own:
+        # ~80 s alone, and under the suite's six workers several times that
+        # (500 s was not enough on the driver's run of PR 40)
+        cwd=_REPO, capture_output=True, timeout=900, text=True)
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
     assert "[chaos] OK" in proc.stdout

@@ -377,3 +377,11 @@ class Ring:
                       ("snapshot_ring", "")]:
         out = engine.lint_text(tmpl.format(name=name, doc=doc), _VPATH["R11"])
         assert any(f.rule == "R11" for f in out), (name, out)
+
+
+def test_r6_covers_the_feeds_too():
+    """The fit loop's feeds moved to train/feeds.py (PR 44): a raw device
+    placement there is the same finding it is in the trainer."""
+    bad = engine.lint_text(_fixture("r6_bad.py"),
+                           "glint_word2vec_tpu/train/feeds.py")
+    assert any(f.rule == "R6" and not f.suppressed for f in bad), bad

@@ -152,12 +152,12 @@ with open(os.path.join(workdir, f"spans{pid}.json"), "w") as f:
 """
 
 
-@pytest.mark.parametrize("mode, loop", [("pairs", "_fit_sharded"),
-                                        ("tokens", "_fit_device_feed_sharded")])
-def test_round_tree_in_the_sharded_loops(mode, loop, tmp_path):
+@pytest.mark.parametrize("mode, feed", [("pairs", "GatheredPairs"),
+                                        ("tokens", "GatheredTokenBlocks")])
+def test_round_tree_in_the_sharded_loops(mode, feed, tmp_path):
     """Two processes of four virtual devices over one 2x4 CPU mesh, as
-    tests/test_multiprocess.py runs them: ``shard_input`` takes the fit through
-    ``loop``. On the CPU mesh ``_after_dispatch`` also drains after every
+    tests/test_multiprocess.py runs them: ``shard_input`` takes the fit loop over
+    ``feeds.<feed>``. On the CPU mesh ``_after_dispatch`` also drains after every
     dispatch (``device_block``), after the round has ended: nobody's child."""
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))

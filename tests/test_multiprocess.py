@@ -68,7 +68,7 @@ cfg = Word2VecConfig(vector_size=16, min_count=1, pairs_per_batch=128,
                      # detector on its real feeds (must stay silent)
                      feed_consistency_check=True)
 # spans both processes: 8 global devices; device42 uses a 4-wide data axis so
-# each process owns TWO token segments (spp=2 in _fit_device_feed_sharded)
+# each process owns TWO token segments (spp=2 in feeds.GatheredTokenBlocks)
 plan = make_mesh(4, 2) if mode in ("device42", "varlen") else make_mesh(2, 4)
 encoded = encode_sentences(sentences, vocab, cfg.max_sentence_length)
 
@@ -84,8 +84,8 @@ def stop_after_first_checkpoint(trainer, encoded, ck):
     class Stop(Exception):
         pass
     orig = Trainer.save_checkpoint
-    def save_once(self, path):
-        orig(self, path)
+    def save_once(self, path, **kw):
+        orig(self, path, **kw)
         seen.append(self.state.global_step)
         if len(seen) == 1:
             raise Stop()
@@ -241,8 +241,8 @@ def _interrupt_at_first_checkpoint(trainer, encoded, ck):
 
     orig = Trainer.save_checkpoint
 
-    def save_once(self, path):
-        orig(self, path)
+    def save_once(self, path, **kw):
+        orig(self, path, **kw)
         seen.append(self.state.global_step)
         if len(seen) == 1:
             raise Stop()
@@ -257,12 +257,10 @@ def _interrupt_at_first_checkpoint(trainer, encoded, ck):
     assert seen, "no mid-run checkpoint happened"
 
 
-@pytest.mark.slow
 def test_two_process_training_replicated_feed(tmp_path):
     _run_two(tmp_path, "replicated")
 
 
-@pytest.mark.slow
 def test_two_process_training_sharded_feed(tmp_path):
     """Default mode: per-process sentence shards + allgather assembly (mllib:345
     analog). Cross-process checksum agreement proves SPMD consistency of the
@@ -270,14 +268,12 @@ def test_two_process_training_sharded_feed(tmp_path):
     _run_two(tmp_path, "sharded")
 
 
-@pytest.mark.slow
 def test_two_process_cbow_sharded_feed(tmp_path):
     """CBOW on the sharded-input feed (round-4: the allgather protocol carries the
     grouped centers/contexts/count arrays, not just packed pairs)."""
     _run_two(tmp_path, "cbow")
 
 
-@pytest.mark.slow
 def test_two_process_banded_cbow_bit_identity(tmp_path):
     """Banded CBOW (cbow_update='banded') on the sharded token-block feed: the
     halo-overlapped segment streams are deterministic and process-independent
@@ -303,13 +299,12 @@ def test_two_process_banded_cbow_bit_identity(tmp_path):
     assert abs(got - want) < 1e-6 * max(1.0, abs(want)), (got, want)
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("mode,mesh", [("device", (2, 4)), ("device42", (4, 2)),
                                        ("varlen", (4, 2))])
 def test_two_process_device_pairgen_bit_identity(tmp_path, mode, mesh):
     """device_pairgen across processes (round-4): each process packs token blocks
     for its own data segments only; the iteration-barrier allgather protocol
-    (trainer._fit_device_feed_sharded) makes the 2-process run train on the
+    (feeds.GatheredTokenBlocks) makes the 2-process run train on the
     byte-identical feed the single-process device-feed run sees — asserted here
     by matching the single-process run's checksum and exact pair count. The
     (4, 2) mesh gives each process TWO token segments (spp=2 — exercises the
@@ -335,7 +330,6 @@ def test_two_process_device_pairgen_bit_identity(tmp_path, mode, mesh):
     assert abs(got - want) < 1e-6 * max(1.0, abs(want)), (got, want)
 
 
-@pytest.mark.slow
 def test_elastic_resume_shrink_two_to_one(tmp_path):
     """ELASTIC restart, N -> 1: interrupt a 2-process device-feed run at its
     first checkpoint, then resume it on a SINGLE process. Device-feed positions
@@ -388,7 +382,6 @@ def test_elastic_resume_shrink_two_to_one(tmp_path):
     assert abs(got2 - want) < 1e-4 * max(1.0, abs(want)), (got2, want)
 
 
-@pytest.mark.slow
 def test_elastic_resume_grow_one_to_two(tmp_path):
     """ELASTIC restart, 1 -> N: interrupt a single-process device-feed run at
     its first checkpoint (which now records per-segment positions alongside its
@@ -410,7 +403,6 @@ def test_elastic_resume_grow_one_to_two(tmp_path):
     assert abs(got - want) < 1e-4 * max(1.0, abs(want)), (got, want)
 
 
-@pytest.mark.slow
 def test_two_process_device_pairgen_resume(tmp_path):
     """Interrupt a 2-process device-feed run at its first mid-run checkpoint and
     resume from the row-shards checkpoint: shard_progress indexes token-step rows
@@ -419,7 +411,6 @@ def test_two_process_device_pairgen_resume(tmp_path):
     _run_two(tmp_path, "dresume")
 
 
-@pytest.mark.slow
 def test_feed_consistency_detector_catches_divergence(tmp_path):
     """The SPMD feed-divergence detector (config.feed_consistency_check) must
     flag process-dependent feed content; its silent pass on real feeds is
@@ -428,7 +419,6 @@ def test_feed_consistency_detector_catches_divergence(tmp_path):
     assert line == "DIVERGE caught"
 
 
-@pytest.mark.slow
 def test_two_process_sharded_resume(tmp_path):
     """Interrupt a sharded-feed run at its first mid-run checkpoint, resume from the
     row-shards checkpoint (per-process stream positions from shard_progress), and

@@ -25,8 +25,8 @@ from glint_word2vec_tpu.data.pipeline import (  # noqa: E402
 from glint_word2vec_tpu.data.vocab import (  # noqa: E402
     build_vocab, count_words, count_words_parallel)
 from glint_word2vec_tpu.train import checkpoint as ckpt  # noqa: E402
-from glint_word2vec_tpu.train.trainer import (  # noqa: E402
-    Trainer, _one_ahead_iter)
+from glint_word2vec_tpu.train.feeds import _one_ahead_iter  # noqa: E402
+from glint_word2vec_tpu.train.trainer import Trainer  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

@@ -15,6 +15,7 @@ chaos --smoke)."""
 
 import json
 import os
+import signal
 import subprocess
 import sys
 import time
@@ -161,13 +162,25 @@ def test_peer_death_restarts_whole_gang(tmp_path):
     """In a gang, one member exiting with the peer-abort code (a survivor
     fleeing a dead peer's collective) is NOT the root cause: the attempt
     classifies as peer-death and the WHOLE gang restarts together."""
-    script = ("import os\n"
-              "raise SystemExit(int(os.environ['MY_RC']))\n")
+    # the clean attempt's two children leave together (each waits until both
+    # have checked in): started on a loaded host they would otherwise exit
+    # as far apart as their interpreters came up
+    script = ("import os, time\n"
+              "rc = int(os.environ['MY_RC'])\n"
+              "if rc == 0:\n"
+              "    d = os.environ['BARRIER']\n"
+              "    os.makedirs(d, exist_ok=True)\n"
+              "    open(os.path.join(d, str(os.getpid())), 'w').close()\n"
+              "    until = time.time() + 20\n"
+              "    while len(os.listdir(d)) < 2 and time.time() < until:\n"
+              "        time.sleep(0.005)\n"
+              "raise SystemExit(rc)\n")
     calls = []
 
     def env_for(attempt):
         calls.append(attempt)
-        return {"MY_RC": str(PEER_ABORT_EXIT) if attempt == 0 else "0"}
+        return {"MY_RC": str(PEER_ABORT_EXIT) if attempt == 0 else "0",
+                "BARRIER": str(tmp_path / f"barrier{attempt}")}
 
     # poll_s well above the few ms between the two children's exits: a poll
     # that lands between two CLEAN exits trips the gang rule (the rc-0 race
@@ -177,8 +190,14 @@ def test_peer_death_restarts_whole_gang(tmp_path):
                       max_restarts=3, stall_s=30.0, env_for_attempt=env_for,
                       poll_s=0.5)
     v = sup.run()
-    assert v.status == "ok" and v.attempts == 2
-    assert v.history[0]["cls"] == "peer-death"
+    assert v.status == "ok" and v.history[0]["cls"] == "peer-death"
+    assert v.history[-1]["cls"] == "ok" and calls == list(range(v.attempts))
+    # two attempts, or one more for each time the rc-0 race still landed (a
+    # loaded host starts the two children further apart than any poll): such
+    # an attempt failed by the gang rule's own TERM and by nothing else
+    assert 2 <= v.attempts <= 4
+    assert all(h["cls"] == "crash" and h["rc"] == -signal.SIGTERM
+               for h in v.history[1:-1]), v.history
 
 
 def test_gang_partial_death_kills_survivors(tmp_path):

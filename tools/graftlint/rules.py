@@ -9,7 +9,7 @@ docs/static-analysis.md.
 | R3 | no host-sync ops inside jit/shard_map-wrapped functions         | PERF.md §4 |
 | R4 | prefix accumulation reachable from params must carry ≥f32 proof | cbow_banded |
 | R5 | data-plane reads go through retry_io                            | robustness  |
-| R6 | trainer device placement only via the staging discipline        | sharding.md |
+| R6 | trainer and feed placement only via the staging discipline      | sharding.md |
 | R7 | contract tools print exactly one JSON line to stdout            | BASELINE.md |
 | R8 | every knob-pair refused at dispatch is refused in config too    | config.py   |
 """
@@ -44,8 +44,8 @@ def _walk_names(node: ast.AST) -> Set[str]:
 # ---------------------------------------------------------------------------
 # R1 — determinism contract: no ad-hoc thread pools / threads in library code.
 # The only blessed owners: pipeline.ordered_pool_map (the ordered-merge pool
-# primitive every parallel host path routes through) and the trainer's two
-# documented producer/stager iterators. Anything else re-introduces the
+# primitive every parallel host path routes through) and the feeds' documented
+# producer/stager iterator (train/feeds.py). Anything else re-introduces the
 # unordered-merge nondeterminism PERF.md §10 paid to remove.
 # ---------------------------------------------------------------------------
 class R1ThreadPools:
@@ -53,8 +53,9 @@ class R1ThreadPools:
     _POOLS = {"ThreadPoolExecutor", "ProcessPoolExecutor", "Pool"}
     _ALLOW = {
         ("glint_word2vec_tpu/data/pipeline.py", "ordered_pool_map"),
-        ("glint_word2vec_tpu/train/trainer.py", "_threaded_iter.__init__"),
-        ("glint_word2vec_tpu/train/trainer.py", "_one_ahead_iter.__init__"),
+        # the feeds' producer thread; _one_ahead_iter, the round stager, is
+        # the same thread under an ack ticket (a subclass: no Thread of its own)
+        ("glint_word2vec_tpu/train/feeds.py", "_threaded_iter.__init__"),
         # the status endpoint's serving thread (obs/statusd.py): READ-only —
         # it renders snapshots of trainer state and never produces or orders
         # training data, so the worker-count determinism contract R1 guards
@@ -411,8 +412,9 @@ class R5RetryIO:
 
 
 # ---------------------------------------------------------------------------
-# R6 — dispatch discipline: the trainer places host data on device ONLY via
-# put_global / the _stage_to_device staging path, so every placement respects
+# R6 — dispatch discipline: the trainer and its feeds (train/feeds.py) place
+# host data on device ONLY via put_global / the _stage_to_device staging path,
+# so every placement respects
 # the collective-program serialization gate (_sync_collectives /
 # _after_dispatch — the rendezvous-starvation deadlock, docs/sharding.md) and
 # stays an EXPLICIT transfer under the stepaudit transfer contract.
@@ -425,7 +427,7 @@ class R6DispatchDiscipline:
     _ALLOW_FNS = {"_stage_to_device"}
 
     def applies(self, path: str) -> bool:
-        return path == _LIB + "train/trainer.py"
+        return path in (_LIB + "train/trainer.py", _LIB + "train/feeds.py")
 
     def check(self, ctx: ModuleContext) -> List[Finding]:
         out: List[Finding] = []

@@ -49,10 +49,12 @@ if REPO not in sys.path:
 BASELINE = os.path.join(REPO, "tools", "racecheck_baseline.json")
 
 # parity threshold for the off-mode A/B: the factories return the raw
-# primitive so the true ratio is 1.0; min-of-k still jitters a few percent
-# on a busy host, and anything under 1.25x is indistinguishable from
-# rerunning the same loop twice. A wrapper would cost 3-10x.
-_ZERO_COST_RATIO = 1.25
+# primitive so the true ratio is 1.0 (raw_types and wrappers_allocated say so
+# without a clock); the timing is the backstop for a wrapper those miss, which
+# would cost 3-10x. Interleaved min-of-k of two identical loops still reads up
+# to ~1.5x on a host whose other cores run a test suite (1.25x turned tier-1
+# red there, PR 40), so the bound sits between the two.
+_ZERO_COST_RATIO = 2.5
 
 
 def _free_port() -> int:
@@ -87,8 +89,10 @@ def _zero_cost_probe() -> dict:
     raw = threading.Lock()  # graftlint: disable=R9 -- raw primitive is the A/B control
     made = lockcheck.make_lock("serve.handle")  # graftlint: disable=R9 -- off-mode probe: off-site construction is the test
     bench(raw), bench(made)  # warm both code paths before timing
-    a = min(bench(raw) for _ in range(7))
-    b = min(bench(made) for _ in range(7))
+    # interleaved, so a burst of load falls on both arms
+    runs = [(bench(raw), bench(made)) for _ in range(7)]
+    a = min(r[0] for r in runs)
+    b = min(r[1] for r in runs)
     ratio = b / a if a else float("inf")
     return {
         "raw_types": raw_types,
