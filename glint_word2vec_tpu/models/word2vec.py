@@ -27,6 +27,7 @@ from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple, Un
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from glint_word2vec_tpu.config import Word2VecConfig
 from glint_word2vec_tpu.data.subword import NO_ROW as _NO_ROW
@@ -337,10 +338,14 @@ class Word2VecModel:
 
     def multiply(self, vector: np.ndarray) -> np.ndarray:
         """Full matrix–vector product syn0 @ v (the PS ``multiply`` powering cosine
-        search, mllib:598). One sharded matvec on device."""
+        search, mllib:598). One matvec on device; over a mesh one sharded
+        matvec, every shard over the rows it holds: the table as it lies, its
+        padding rows' zeros dropped from the fetched vector (the ``syn0`` view
+        of a vocabulary that does not divide over the mesh is a slice along
+        the partitioned rows, which all-gathers the table)."""
         self._check_alive()
         v = jnp.asarray(vector, jnp.float32)
-        return np.asarray(self.syn0 @ v)
+        return np.asarray(self._full0 @ v)[: self.vocab.size]
 
     # -- ANN index attach (serving tier, serve/ann.py) ---------------------------------
 
@@ -402,8 +407,11 @@ class Word2VecModel:
         two exact stages: the maxima of runs of ~sqrt(V / k) columns, then
         the k winning runs' members (:func:`_two_stage_topk`; what comes
         back is ``lax.top_k``'s over the same scores, ties included); one
-        ``lax.top_k`` over all V where the vocabulary is small or the table
-        is partitioned by rows. On a TPU the chunk's queries are handed
+        ``lax.top_k`` over all V where the vocabulary is small. Over a table
+        partitioned by rows on a mesh the one program runs under
+        ``shard_map`` (:func:`_sharded_scan`): every shard scans and ranks
+        its own rows so, and the shards' k candidates each are merged; the
+        replies are the one-device program's. On a TPU the chunk's queries are handed
         over in whole tiles of 8 rows (:func:`_topk_dispatch`), so one
         program serves 8 batch sizes. No per-query device
         operation: a launch costs more than this scan's share of a query.
@@ -534,12 +542,7 @@ class Word2VecModel:
             # per host array above, whatever the number of queries
             sp.set(ops=sum(1 + (b is not None) + (l is not None)
                            for _, _, b, l in parts), **counts)
-        # scores a query's selection ranks: every row where one top-k (on
-        # the device or the host) ranks them, far fewer where two stages do
-        pending.topk_rows = (
-            self._full0.shape[0] if _host_topk() else _topk_rows(
-                self._full0.shape[0], k,
-                not self._full0.sharding.is_fully_replicated))
+        pending.scan = _scan_counts(self._full0, k)
         for _ in parts[:_PARTS_IN_FLIGHT]:
             self._enqueue_part(pending)
         return pending
@@ -551,7 +554,7 @@ class Word2VecModel:
             len(pending.results)]
         with default_tracer().span(
                 "serve.scan_enqueue", parent=pending.parent,
-                queries=len(part_ids), topk_rows=pending.topk_rows):
+                queries=len(part_ids), **pending.scan):
             result = _topk_dispatch(
                 self._full0, self._norms, part_ids, part_block,
                 pending.k, self.num_words,
@@ -875,7 +878,7 @@ class _PendingSynonyms:
     enclosed the begin. ``replies`` is set where begin did all the work."""
 
     __slots__ = ("num", "k", "parent", "words", "parts", "results",
-                 "topk_rows", "replies")
+                 "scan", "replies")
 
     def __init__(self, num: int, parent: Optional[int]):
         self.num = num
@@ -884,12 +887,22 @@ class _PendingSynonyms:
         self.words: List[Optional[str]] = []
         self.parts: list = []
         self.results: list = []
-        self.topk_rows = 0
+        self.scan: Dict[str, int] = {}
         self.replies: Optional[List[List[Tuple[str, float]]]] = None
 
 
+def _row_slices(table: jax.Array, at: jax.Array) -> jax.Array:
+    """Rows ``at`` (in range) of ``table`` as one-row slices, stacked: each
+    reads its row in place, where a gather op first copies a table whose D
+    is no multiple of 128 row-major (:func:`_query_block`)."""
+    return jnp.concatenate([
+        jax.lax.dynamic_slice_in_dim(
+            table, at[i], 1, allow_negative_indices=False)
+        for i in range(at.shape[0])])
+
+
 def _query_block(syn0: jax.Array, ids: jax.Array,
-                 block: Optional[jax.Array], partitioned: bool,
+                 block: Optional[jax.Array],
                  buckets: Optional[jax.Array] = None,
                  lists: Optional[jax.Array] = None) -> jax.Array:
     """The [Q, D] query rows, built inside the scan's own program: row
@@ -907,20 +920,12 @@ def _query_block(syn0: jax.Array, ids: jax.Array,
     [V, D] table whose D is no multiple of 128 column-major, and its gather
     first copies the whole table row-major (4.6 GB and a second pass over it
     at 3M × 300, by the v5e compiler), where a slice reads a row in place.
-    A table ``partitioned`` by rows over a mesh takes the gather: GSPMD
-    gives each shard its own rows and one [Q, D] all-reduce, where a slice
-    along a sharded dimension all-gathers the table. The listed bucket rows
-    ARE gathered, from rows the model keeps at whole lanes of 128 for it
+    (A table partitioned by rows over a mesh is read the same way, each
+    shard its own rows: :func:`_owner_rows`.) The listed bucket rows ARE
+    gathered, from rows the model keeps at whole lanes of 128 for it
     (ops/subword.lane_padded), which the gather reads in place."""
     with jax.named_scope("scan.gather"):
-        ids0 = jnp.maximum(ids, 0)
-        if partitioned:
-            rows = syn0[ids0]
-        else:
-            rows = jnp.concatenate([
-                jax.lax.dynamic_slice_in_dim(
-                    syn0, ids0[i], 1, allow_negative_indices=False)
-                for i in range(ids.shape[0])])
+        rows = _row_slices(syn0, jnp.maximum(ids, 0))
         if block is not None:
             rows = jnp.where((ids >= 0)[:, None], rows, block)
     if lists is None:
@@ -951,28 +956,42 @@ def _cosine_batch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
 _TOPK_GROUPS = (128, 256, 512, 1024, 2048, 4096)
 
 
-def _topk_group(num_rows: int, k: int, partitioned: bool) -> int:
+def _topk_group(num_rows: int, k: int) -> int:
     """Columns a group of :func:`_two_stage_topk` holds over a ``[Q, num_rows]``
-    score block, or 0 where the single ``lax.top_k`` ranks the whole block.
-    The size is the grid's nearest to sqrt(num_rows / k), where the group
+    score block (a whole table's, or one shard's of a table partitioned by
+    rows), or 0 where the single ``lax.top_k`` ranks the whole block. The
+    size is the grid's nearest to sqrt(num_rows / k), where the group
     maxima and the k winning groups' members are together fewest. Handed
-    back: a table ``partitioned`` by rows (GSPMD shards the block along V,
-    and its shards' merge is the single top-k's), and a vocabulary that k
-    groups would hold whole anyway."""
-    if partitioned:
-        return 0
+    back: rows that k groups would hold whole anyway."""
     ideal = (num_rows / max(k, 1)) ** 0.5
     group = min(_TOPK_GROUPS, key=lambda g: abs(math.log(g / max(ideal, 1.0))))
     return group if k * group < num_rows else 0
 
 
-def _topk_rows(num_rows: int, k: int, partitioned: bool) -> int:
-    """Scores one query's selection ranks in the scan's program: the group
-    maxima and the k winning groups' members where the two stages run,
-    every row where the single top-k does (``serve.scan_enqueue``'s
-    ``topk_rows``)."""
-    group = _topk_group(num_rows, k, partitioned)
+def _topk_rows(num_rows: int, k: int) -> int:
+    """Scores one query's selection ranks over ``num_rows`` rows (a table's,
+    or one shard's) in the scan's program: the group maxima and the k
+    winning groups' members where the two stages run, every row where the
+    single top-k does (``serve.scan_enqueue``'s ``topk_rows``)."""
+    group = _topk_group(num_rows, k)
     return -(-num_rows // group) + k * group if group else num_rows
+
+
+def _scan_counts(table: jax.Array, k: int) -> Dict[str, int]:
+    """What ``serve.scan_enqueue`` says of the program a scan of ``table``
+    runs: ``shards``, the partitions of its rows the program runs over (1 on
+    one device); ``topk_rows``, the scores one query's selection ranks on
+    each (:func:`_topk_rows` of a shard's rows; every row of the table where
+    the host ranks them); ``merge_rows``, the candidates one query's merge
+    ranks after the shards' selections (shards · k; 0 where nothing is
+    merged: one device, or the host's top-k over the fetched block)."""
+    shards = _row_shards(table)
+    n = shards.mesh.shape[shards.spec[0]] if shards else 1
+    if _host_topk():
+        return dict(shards=n, topk_rows=table.shape[0], merge_rows=0)
+    per = table.shape[0] // n
+    return dict(shards=n, topk_rows=_topk_rows(per, k),
+                merge_rows=n * min(k, per) if shards else 0)
 
 
 def _grouped_scores(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
@@ -1030,20 +1049,20 @@ def _two_stage_topk(cos: jax.Array, k: int,
         return scores, run * group + pos % group
 
 
-@partial(jax.jit, static_argnames=("k", "valid_rows", "partitioned"))
+@partial(jax.jit, static_argnames=("k", "valid_rows"))
 def _cosine_topk_batch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
-                       k: int, valid_rows: int, partitioned: bool = False
+                       k: int, valid_rows: int
                        ) -> Tuple[jax.Array, jax.Array]:
     """cosine(rows, q) top-k over a [Q, D] query matrix in ONE dispatch:
     normalize queries (snrm2/sscal analog, mllib:589-596), the [Q, V] cosine
     matrix as a single MXU matmul (mllib:598's matvec, batched), divide by row
     norms with zero-norm → 0 (mllib:601-609), batched device top-k instead of
     the client-side BoundedPriorityQueue scan (mllib:611-619). Rows past
-    valid_rows are sharding padding, excluded outright. The top-k is taken
-    in two exact stages (:func:`_two_stage_topk`) wherever
-    :func:`_topk_group` names a group size: what it returns is
-    ``lax.top_k``'s over the same scores."""
-    group = _topk_group(syn0.shape[0], k, partitioned)
+    valid_rows are padding (a mesh's row count, rounded up), excluded
+    outright. The top-k is taken in two exact stages
+    (:func:`_two_stage_topk`) wherever :func:`_topk_group` names a group
+    size: what it returns is ``lax.top_k``'s over the same scores."""
+    group = _topk_group(syn0.shape[0], k)
     if not group:
         cos = _cosine_batch(syn0, norms, queries, valid_rows)
         with jax.named_scope("scan.topk"):
@@ -1052,30 +1071,134 @@ def _cosine_topk_batch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
         _grouped_scores(syn0, norms, queries, valid_rows, group), k, group)
 
 
-@partial(jax.jit, static_argnames=("valid_rows", "partitioned"))
+def _row_shards(table: jax.Array) -> Optional[NamedSharding]:
+    """The sharding of a table whose rows are partitioned over one axis of a
+    mesh (``MeshPlan.embedding`` where the model axis holds more than one
+    device), else None: the table lies on one device, or whole on each."""
+    sh = table.sharding
+    if (isinstance(sh, NamedSharding) and len(sh.spec) > 0
+            and isinstance(sh.spec[0], str) and sh.mesh.shape[sh.spec[0]] > 1
+            and all(axis is None for axis in sh.spec[1:])):
+        return sh
+    return None
+
+
+def _owner_rows(syn0: jax.Array, ids: jax.Array, first: jax.Array,
+                axis: str) -> jax.Array:
+    """The [Q, D] rows ``ids`` name (by GLOBAL row; zeros for a negative
+    id), inside ``shard_map``: this shard, whose block ``syn0`` starts at
+    row ``first``, reads the ids it owns as one-row slices of its block, in
+    place as :func:`_query_block` does on one device (no gather op, so no
+    row-major copy of a 300-wide shard), and contributes zeros for the
+    others; one ``psum`` over ``axis`` hands every shard the whole block.
+    Exact: one addend of each row is not zero."""
+    at = ids - first
+    mine = (at >= 0) & (at < syn0.shape[0])
+    rows = _row_slices(syn0, jnp.clip(at, 0, syn0.shape[0] - 1))
+    return jax.lax.psum(jnp.where(mine[:, None], rows, 0), axis)
+
+
+def _sharded_scan(shards: NamedSharding, syn0: jax.Array, norms: jax.Array,
+                  ids: jax.Array, block: Optional[jax.Array],
+                  valid_rows: int, k: Optional[int]):
+    """The scan over a table partitioned by rows (``shards``: its sharding),
+    as ONE program whose body runs under ``shard_map`` over the axis that
+    partitions them, each shard on its own ``[V/n, D]`` rows and ``[V/n]``
+    norms; the queries are replicated (along a data axis too). The table
+    never moves and nothing V wide leaves a chip:
+
+    - the query rows by :func:`_owner_rows` (the vector block's rows where
+      ``ids[i]`` is :data:`_VECTOR`, as on one device);
+    - the shard's ``[Q, V/n]`` scores as on one device
+      (:func:`_grouped_scores` at the group size :func:`_topk_group` gives
+      for the SHARD's rows), columns whose global row is past
+      ``valid_rows`` at -inf;
+    - ``k`` None (the host top-k route): that block, the result sharded
+      along V. Else the shard's own top-k in two exact stages
+      (:func:`_two_stage_topk`; the single ``lax.top_k`` where its rows are
+      too few, all of them where they are fewer than k), its ids moved to
+      global rows by the shard's first row; one ``all_gather`` each of the
+      ``[Q, k]`` scores and ids; and ``lax.top_k`` over the ``[Q, n·k]``
+      candidates, replicated.
+
+    Why the merge is ``lax.top_k``'s over the whole [Q, V] block, ties
+    included: that one orders by (score, lower row first), each of its k
+    answers is among its own shard's best k under the same order, and the
+    candidates lie shard by shard in ascending row, each shard's of equal
+    score in ascending row too (its own top-k's order): among candidates of
+    equal score a lower position is a lower global row."""
+    axis = shards.spec[0]
+    n = shards.mesh.shape[axis]
+    per = syn0.shape[0] // n
+    group = _topk_group(per, k) if k else 0
+
+    def shard(syn0, norms, ids, block):
+        first = jax.lax.axis_index(axis) * per
+        with jax.named_scope("scan.owner_rows"):
+            queries = _owner_rows(syn0, ids, first, axis)
+            if block is not None:
+                queries = jnp.where((ids >= 0)[:, None], queries, block)
+        cos = (_grouped_scores(syn0, norms, queries, per, group) if group
+               else _cosine_batch(syn0, norms, queries, per))
+        if valid_rows < n * per:
+            # the mesh's padding rows, at the end of the last shards; where
+            # the vocabulary divides there are none and no pass is added
+            with jax.named_scope("scan.cosine"):
+                cos = jnp.where(
+                    first + jnp.arange(cos.shape[1])[None, :] < valid_rows,
+                    cos, -jnp.inf)
+        if k is None:
+            return cos
+        if group:
+            scores, rows = _two_stage_topk(cos, k, group)
+        else:
+            with jax.named_scope("scan.topk"):
+                scores, rows = jax.lax.top_k(cos, min(k, per))
+        with jax.named_scope("scan.merge"):
+            scores = jax.lax.all_gather(scores, axis, axis=1, tiled=True)
+            rows = jax.lax.all_gather(rows + first, axis, axis=1, tiled=True)
+            best, at = jax.lax.top_k(scores, k)
+            return best, jnp.take_along_axis(rows, at, axis=1)
+
+    return jax.shard_map(
+        shard, mesh=shards.mesh,
+        in_specs=(P(axis, None), P(axis), P(), P()),
+        out_specs=P(None, axis) if k is None else (P(), P()),
+        # every shard holds the same gathered candidates and ranks them
+        # alike, but an all_gather's result is typed as varying
+        check_vma=k is None)(syn0, norms, ids, block)
+
+
+@partial(jax.jit, static_argnames=("valid_rows", "shards"))
 def _gather_cosine_batch(syn0: jax.Array, norms: jax.Array, ids: jax.Array,
                          block: Optional[jax.Array], valid_rows: int,
-                         partitioned: bool, buckets: Optional[jax.Array] = None,
+                         shards: Optional[NamedSharding],
+                         buckets: Optional[jax.Array] = None,
                          lists: Optional[jax.Array] = None) -> jax.Array:
-    """:func:`_cosine_batch` over the rows :func:`_query_block` builds."""
+    """:func:`_cosine_batch` over the rows :func:`_query_block` builds; of a
+    table partitioned by rows (``shards``), :func:`_sharded_scan`'s."""
+    if shards:
+        return _sharded_scan(shards, syn0, norms, ids, block, valid_rows, None)
     return _cosine_batch(
-        syn0, norms,
-        _query_block(syn0, ids, block, partitioned, buckets, lists), valid_rows)
+        syn0, norms, _query_block(syn0, ids, block, buckets, lists), valid_rows)
 
 
-@partial(jax.jit, static_argnames=("k", "valid_rows", "partitioned"))
+@partial(jax.jit, static_argnames=("k", "valid_rows", "shards"))
 def _gather_topk_batch(syn0: jax.Array, norms: jax.Array, ids: jax.Array,
                        block: Optional[jax.Array], k: int, valid_rows: int,
-                       partitioned: bool, buckets: Optional[jax.Array] = None,
+                       shards: Optional[NamedSharding],
+                       buckets: Optional[jax.Array] = None,
                        lists: Optional[jax.Array] = None
                        ) -> Tuple[jax.Array, jax.Array]:
     """Word ids (and lists, and vectors) in, top-k out, ONE program:
     :func:`_cosine_topk_batch` over the rows :func:`_query_block` reads from
-    the tables the model already holds."""
+    the tables the model already holds; over a table partitioned by rows
+    (``shards``: :func:`_row_shards`'s answer), :func:`_sharded_scan`."""
+    if shards:
+        return _sharded_scan(shards, syn0, norms, ids, block, valid_rows, k)
     return _cosine_topk_batch(
-        syn0, norms,
-        _query_block(syn0, ids, block, partitioned, buckets, lists), k,
-        valid_rows, partitioned)
+        syn0, norms, _query_block(syn0, ids, block, buckets, lists), k,
+        valid_rows)
 
 
 # CPU route tiling: queries are sub-chunked so the fetched [q, V] score
@@ -1129,7 +1252,7 @@ def _topk_dispatch(syn0: jax.Array, norms: jax.Array, ids: np.ndarray,
     route wins 2-3x at every shape tried), so it is opt-in: set
     ``GLINT_CPU_TOPK=argpartition`` on toolchains that still exhibit the
     sort lowering."""
-    partitioned = not syn0.sharding.is_fully_replicated
+    shards = _row_shards(syn0)
     if not _host_topk():
         extra = -ids.shape[0] % 8
         if jax.default_backend() == "tpu" and ids.shape[0] > 1 and extra:
@@ -1149,7 +1272,7 @@ def _topk_dispatch(syn0: jax.Array, norms: jax.Array, ids: np.ndarray,
         # device arrays: this returns once the program is enqueued, and the
         # caller's fetch is where the host waits for it
         return _gather_topk_batch(
-            syn0, norms, ids, block, k, valid_rows, partitioned,
+            syn0, norms, ids, block, k, valid_rows, shards,
             *(() if lists is None else (buckets, lists)))
     Q, V = ids.shape[0], syn0.shape[0]
     qsub = max(1, min(Q, _CPU_TOPK_SCORE_BYTES // max(V * 4, 1)))
@@ -1159,7 +1282,7 @@ def _topk_dispatch(syn0: jax.Array, norms: jax.Array, ids: np.ndarray,
         cos = np.asarray(_gather_cosine_batch(
             syn0, norms, ids[lo:lo + qsub],
             None if block is None else block[lo:lo + qsub], valid_rows,
-            partitioned, buckets,
+            shards, buckets,
             None if lists is None else lists[lo:lo + qsub]))
         for r in range(cos.shape[0]):
             scores[lo + r], idxs[lo + r] = _cpu_topk_row(cos[r], k)

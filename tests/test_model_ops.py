@@ -326,8 +326,7 @@ def test_two_stage_topk_is_lax_top_k(case, rows):
     import jax
     from glint_word2vec_tpu.models import word2vec as w2v
     syn0, norms, queries, k, valid_rows, two_stage = _topk_case(case, rows)
-    assert bool(w2v._topk_group(syn0.shape[0], k, False)) is two_stage
-    assert w2v._topk_group(syn0.shape[0], k, True) == 0
+    assert bool(w2v._topk_group(syn0.shape[0], k)) is two_stage
     want_s, want_i = jax.lax.top_k(
         w2v._cosine_batch(syn0, norms, queries, valid_rows), k)
     if case.startswith("zero_ties"):
@@ -377,6 +376,9 @@ def test_what_only_a_tpu_takes(rows, monkeypatch):
         np.testing.assert_allclose([s for _, s in g], [s for _, s in w],
                                    rtol=0, atol=1e-6)
     model.stop()
+    # the inner jits' traces took the TPU's branch and are cached by shape:
+    # they must not outlive the patch (another file lowers 1,733 rows too)
+    jax.clear_caches()
 
 
 @pytest.mark.parametrize("batch", ["words_exclude_themselves", "vectors"])
@@ -395,7 +397,7 @@ def test_two_stage_replies_are_the_single_top_k_s(batch, monkeypatch):
     model = Word2VecModel(vocab, jnp.asarray(syn0))
     queries = (["w3", "w1699", "w3", "w128"] if batch.startswith("words") else
                [syn0[7] * 2.0, np.zeros(16, np.float32), syn0[1698] + 0.5])
-    assert w2v._topk_group(1700, 11, False) == 128
+    assert w2v._topk_group(1700, 11) == 128
     got = model.find_synonyms_batch(queries, 10)
 
     def single(syn0, norms, ids, block, k, valid_rows, partitioned):
@@ -431,37 +433,202 @@ def test_block_dtype_is_what_stacking_gave():
     from glint_word2vec_tpu.models.word2vec import _query_block
     table = jnp.ones((8, 4), jnp.bfloat16)
     ids = jnp.asarray([2, -1], jnp.int32)
-    for partitioned in (False, True):
-        words = jax.eval_shape(
-            lambda t, i: _query_block(t, i, None, partitioned), table, ids)
-        mixed = jax.eval_shape(
-            lambda t, i, b: _query_block(t, i, b, partitioned), table, ids,
-            jnp.zeros((2, 4), jnp.float32))
-        assert words.dtype == jnp.bfloat16 and mixed.dtype == jnp.float32
-        assert words.shape == mixed.shape == (2, 4)
+    words = jax.eval_shape(lambda t, i: _query_block(t, i, None), table, ids)
+    mixed = jax.eval_shape(lambda t, i, b: _query_block(t, i, b), table, ids,
+                           jnp.zeros((2, 4), jnp.float32))
+    assert words.dtype == jnp.bfloat16 and mixed.dtype == jnp.float32
+    assert words.shape == mixed.shape == (2, 4)
 
 
 @pytest.mark.parametrize("table,partitioned", [("float32", False),
                                                ("sharded", True)])
 def test_row_sharded_table_takes_the_gather(table, partitioned, monkeypatch):
     """What the program reads rows with follows the table it is handed: Q
-    slices of a table on one device, one gather of a row-partitioned one —
-    which no program all-gathers."""
+    slices of a table on one device; of a row-partitioned one, each shard's
+    slices of its own rows and one [Q, D] all-reduce. No program gathers
+    from the table, and the partitioned one all-gathers neither the table
+    nor anything V (or V / n) wide: the candidates, [Q, n * k]."""
+    import re
     from glint_word2vec_tpu.models import word2vec as w2v
-    model, _ = _scan_model(table)
+    model, _ = _mesh_model((1, 4)) if partitioned else _scan_model(table)
     seen = []
     real = w2v._gather_topk_batch
     monkeypatch.setattr(
         w2v, "_gather_topk_batch",
         lambda *a: seen.append(a) or real(*a))
     model.find_synonyms_batch(["w1", "w2"], 3)
-    (syn0, norms, ids, block, k, valid_rows, flag), = seen
-    assert flag is partitioned and block is None and ids.dtype == np.int32
-    hlo = real.lower(syn0, norms, ids, block, k, valid_rows, flag
+    (syn0, norms, ids, block, k, valid_rows, shards), = seen
+    assert (shards is not None) is partitioned
+    assert block is None and ids.dtype == np.int32
+    hlo = real.lower(syn0, norms, ids, block, k, valid_rows, shards
                      ).compile().as_text()
-    gathered = [line for line in hlo.splitlines()
-                if " all-gather(" in line and f"[{syn0.shape[0]},16]" in line]
-    assert gathered == []
+    ops = [line for line in hlo.splitlines()
+           if re.search(r" (all-gather|gather|all-to-all)(-start)?\(", line)]
+    rows = syn0.shape[0] // (4 if partitioned else 1)
+    wide = [line for line in ops for dims in re.findall(
+                r"[fs]\d+\[([\d,]+)\]", line.split(" metadata=")[0])
+            if any(int(d) >= rows // 2 for d in dims.split(","))]
+    assert wide == []
+    assert (" all-reduce(" in hlo or " all-reduce-start(" in hlo) is partitioned
+    if partitioned:
+        gathered = [line for line in ops if "all-gather" in line]
+        assert gathered and all(f"[2,{4 * k}]" in line or f"[4,2,{k}]" in line
+                                or f"[4,{k},2]" in line for line in gathered)
+    model.stop()
+
+
+# -- a table partitioned by rows: one program under shard_map ------------------------
+
+_MESH_ROWS = 12003      # 12,008 with the mesh's padding rows; 3,002 a shard of 4
+_MESHES = [(1, 2), (1, 4), (1, 8), (2, 2)]
+_MESH_TABLE = {}
+
+
+def _mesh_table():
+    """12,003 words x 16 dims: a zero-norm row, and one row planted on three
+    shards of every mesh (rows 100, 7000 and 11990: ties across shards)."""
+    if not _MESH_TABLE:
+        rng = np.random.default_rng(12)
+        syn0 = rng.standard_normal((_MESH_ROWS, 16)).astype(np.float32)
+        syn0[11] = 0.0
+        syn0[7000] = syn0[11990] = syn0[100]
+        _MESH_TABLE["syn0"] = syn0
+        _MESH_TABLE["vocab"] = Vocabulary.from_words_and_counts(
+            [f"w{i}" for i in range(_MESH_ROWS)], np.ones(_MESH_ROWS, np.int64))
+    return _MESH_TABLE["vocab"], _MESH_TABLE["syn0"]
+
+
+def _mesh_model(mesh):
+    """The table on ``mesh`` (data, model), or on one device for None."""
+    import jax.numpy as jnp
+    from glint_word2vec_tpu.parallel.mesh import make_mesh
+    vocab, syn0 = _mesh_table()
+    if mesh is None:
+        return Word2VecModel(vocab, jnp.asarray(syn0)), syn0
+    return Word2VecModel(vocab, syn0, plan=make_mesh(*mesh)), syn0
+
+
+def _mesh_batches(syn0):
+    rng = np.random.default_rng(13)
+    vec = [rng.standard_normal(16).astype(np.float32) for _ in range(2)]
+    return {
+        # (queries, num)
+        "words": (["w5", "w9000", "w5", "w3001"], 10),
+        "vectors": ([syn0[4] * 3.0, vec[0], np.zeros(16, np.float32)], 10),
+        "mixed": (["w5", vec[1], "w11999", syn0[9]], 10),
+        # the planted row's copies score 1.0 on three shards: lower row first
+        "ties_across_shards": (["w100", "w7000", syn0[100] * 2.0], 10),
+        # every score 0: the lowest rows of the whole table, all on shard 0
+        "zero_norm_word": (["w11", "w12"], 10),
+        "last_shard_last_row": ([f"w{_MESH_ROWS - 1}", "w11990"], 10),
+        "one_word": (["w6001"], 3),
+    }
+
+
+def _assert_same_replies(got, want):
+    """Ids equal, ties included; scores equal to 1e-6 (the CPU's matmul
+    gives a row other last bits in a block of another width)."""
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert [x for x, _ in g] == [x for x, _ in w]
+        np.testing.assert_allclose([s for _, s in g], [s for _, s in w],
+                                   rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("route", ["device_topk", "argpartition"])
+@pytest.mark.parametrize("mesh", _MESHES, ids=lambda m: f"{m[0]}x{m[1]}")
+@pytest.mark.parametrize("batch", [
+    "words", "vectors", "mixed", "ties_across_shards", "zero_norm_word",
+    "last_shard_last_row", "one_word"])
+def test_sharded_scan_is_the_one_device_programs(batch, mesh, route, monkeypatch):
+    """``Word2VecModel(plan=make_mesh(d, n))`` answers with the one-device
+    program's replies, ties included: the table never gathered, each shard
+    ranked by its own two stages (1,501 to 6,004 rows a shard, in runs of
+    128), the mesh's padding rows never returned."""
+    from glint_word2vec_tpu.models import word2vec as w2v
+    one, syn0 = _mesh_model(None)
+    queries, num = _mesh_batches(syn0)[batch]
+    want = one.find_synonyms_batch(queries, num)
+    if route == "argpartition":
+        monkeypatch.setenv("GLINT_CPU_TOPK", "argpartition")
+    model, _ = _mesh_model(mesh)
+    assert model._full0.shape[0] == 12008
+    counts = w2v._scan_counts(model._full0, num + 1)
+    rows = 12008 // mesh[1]
+    assert counts["shards"] == mesh[1]
+    if route == "argpartition":
+        assert counts["topk_rows"] == 12008 and counts["merge_rows"] == 0
+    else:
+        assert counts["merge_rows"] == mesh[1] * (num + 1)
+        assert counts["topk_rows"] == w2v._topk_rows(rows, num + 1) <= rows
+        assert w2v._topk_group(rows, num + 1) == 128
+    got = model.find_synonyms_batch(queries, num)
+    _assert_same_replies(got, want)
+    if batch == "ties_across_shards":
+        assert [w for w, _ in got[0][:2]] == ["w7000", "w11990"]
+        assert [w for w, _ in got[2][:3]] == ["w100", "w7000", "w11990"]
+    if batch == "zero_norm_word":
+        assert [w for w, _ in got[0]] == [f"w{i}" for i in range(10)]
+    for reply in got:
+        assert all(int(w[1:]) < _MESH_ROWS for w, _ in reply)
+    one.stop()
+    model.stop()
+
+
+@pytest.mark.parametrize("mesh", [(1, 8), (2, 2)], ids=lambda m: f"{m[0]}x{m[1]}")
+def test_sharded_scan_with_k_over_a_shards_rows(mesh):
+    """More neighbours asked for than a shard has rows (26 and 104 of 208),
+    which one ``lax.top_k`` ranks: every shard hands over all of its rows
+    and the merge ranks them."""
+    from glint_word2vec_tpu.parallel.mesh import make_mesh
+    one, syn0 = _scan_model("float32")
+    vocab = one.vocab
+    model = Word2VecModel(vocab, syn0, plan=make_mesh(*mesh))
+    for queries, num in (["w5", "w202", syn0[9]], 150), (["w7"], 300):
+        _assert_same_replies(model.find_synonyms_batch(queries, num),
+                             one.find_synonyms_batch(queries, num))
+    one.stop()
+    model.stop()
+
+
+@pytest.mark.parametrize("mesh", [(1, 4), (2, 2)], ids=lambda m: f"{m[0]}x{m[1]}")
+def test_sharded_scan_keeps_two_batches_in_flight(mesh):
+    """Both halves over a mesh, as the serve batcher runs them: two batches
+    begun before either is finished, finished on other threads, each the
+    batch call's replies to the last bit."""
+    import threading
+    model, syn0 = _mesh_model(mesh)
+    batches = _mesh_batches(syn0)
+    first_q, num = batches["mixed"]
+    second_q, _ = batches["ties_across_shards"]
+    want = [model.find_synonyms_batch(q, num) for q in (first_q, second_q)]
+    begun = [model.find_synonyms_begin(q, num) for q in (first_q, second_q)]
+    out = [None, None]
+
+    def finish(i):
+        out[i] = model.find_synonyms_finish(begun[i])
+
+    threads = [threading.Thread(target=finish, args=(i,)) for i in (1, 0)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(60)
+    assert out == want
+    model.stop()
+
+
+def test_multiply_on_a_mesh_is_one_sharded_matvec():
+    """Every shard multiplies the rows it holds, over a vocabulary that does
+    not divide too: the table is never gathered (the ``syn0`` view's slice
+    along the partitioned rows would), the padding rows' zeros are dropped."""
+    import jax
+    model, syn0 = _mesh_model((1, 4))
+    v = syn0[3] * 0.5
+    got = model.multiply(v)
+    assert got.shape == (_MESH_ROWS,)
+    np.testing.assert_allclose(got, syn0 @ v, rtol=0, atol=1e-5)
+    hlo = jax.jit(lambda t, x: t @ x).lower(model._full0, v).compile().as_text()
+    assert " all-gather(" not in hlo and f"f32[{12008 // 4}]" in hlo
     model.stop()
 
 

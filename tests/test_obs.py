@@ -808,20 +808,37 @@ def _cosine_topk_case():
 
 def _gather_topk_case(mixed=False):
     """The served program: word ids in, top-k out (``scan.gather`` too); the
-    mixed form takes the gather a row-partitioned table takes."""
+    mixed form holds a vector query, and so the vector block."""
     import jax.numpy as jnp
     from glint_word2vec_tpu.models.word2vec import _gather_topk_batch
     scan, (syn0, norms, queries) = _cosine_topk_case()
     ids = jnp.asarray([3, -1, 95, 3] if mixed else [3, 0, 95, 3], jnp.int32)
 
     def served(syn0, norms, ids, block):
-        return _gather_topk_batch(syn0, norms, ids, block, 5, 90, mixed)
+        return _gather_topk_batch(syn0, norms, ids, block, 5, 90, None)
 
     return served, (syn0, norms, ids, queries if mixed else None)
 
 
 def _gather_topk_mixed_case():
     return _gather_topk_case(mixed=True)
+
+
+def _gather_topk_sharded_case():
+    """The same program over a table partitioned by rows on four of the
+    virtual devices (``scan.owner_rows``, ``scan.merge`` too)."""
+    import jax
+    from glint_word2vec_tpu.models.word2vec import _gather_topk_batch
+    from glint_word2vec_tpu.parallel.mesh import make_mesh
+    _, (syn0, norms, _) = _cosine_topk_case()
+    plan = make_mesh(1, 4)
+    syn0 = jax.device_put(syn0, plan.embedding)
+    ids = np.asarray([3, 0, 95, 3], np.int32)
+
+    def served(syn0, norms, ids):
+        return _gather_topk_batch(syn0, norms, ids, None, 5, 90, plan.embedding)
+
+    return served, (syn0, norms, ids)
 
 
 CBOW_SCOPES = ("cbow.gather", "cbow.context_sum", "cbow.pool_matmul",
@@ -915,6 +932,7 @@ def test_cbow_pack_span_is_recorded_with_its_args_only_when_on(update, tmp_path)
 
 @pytest.mark.parametrize("case", [_sgns_shared_step_case, _cosine_topk_case,
                                   _gather_topk_case, _gather_topk_mixed_case,
+                                  _gather_topk_sharded_case,
                                   _cbow_scatter_step_case, _cbow_banded_step_case])
 def test_named_scopes_change_metadata_only(case, monkeypatch):
     """The compiled step and scan with the scopes are the programs without

@@ -824,9 +824,50 @@ def test_scan_enqueue_carries_topk_rows(serve_tracer, v, topk_rows):
     model = make_model(v=v, d=16)
     serve_tracer.configure(enabled=True)
     model.find_synonyms_batch(["w1", "w2", "w1"], 5)
-    assert [e["args"]["topk_rows"] for e in serve_tracer.events()
-            if e["name"] == "serve.scan_enqueue"] == [topk_rows]
+    assert [e["args"] for e in serve_tracer.events()
+            if e["name"] == "serve.scan_enqueue"] == [
+                dict(queries=3, topk_rows=topk_rows, shards=1, merge_rows=0)]
     model.stop()
+
+
+@pytest.mark.parametrize("mesh", [(1, 4), (2, 2)], ids=lambda m: f"{m[0]}x{m[1]}")
+def test_service_over_a_mesh_answers_as_one_device_does(serve_tracer, mesh):
+    """``EmbeddingService(model=...)`` over a table partitioned by rows: the
+    batcher, the lease and the two halves as they are; concurrent callers'
+    replies are the one-device model's; ``serve.scan_enqueue`` says what the
+    program ran over (the per-shard ``topk_rows``, ``shards``,
+    ``merge_rows``) and ``serve.row_fetch`` issued one put a batch."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from glint_word2vec_tpu.models.word2vec import _topk_rows
+    from glint_word2vec_tpu.parallel.mesh import make_mesh
+    one = make_model(v=3001, d=16)
+    sharded = Word2VecModel(one.vocab, np.asarray(one.syn0), plan=make_mesh(*mesh))
+    words = [f"w{i}" for i in (0, 1, 750, 751, 1502, 2253, 3000, 3000, 17)]
+    want = [one.find_synonyms(w, 5) for w in words]
+    svc = EmbeddingService(model=sharded, ann=False)
+    try:
+        serve_tracer.configure(enabled=True)
+        with ThreadPoolExecutor(len(words)) as pool:
+            got = list(pool.map(lambda w: svc.synonyms(w, 5), words))
+        serve_tracer.configure(enabled=False)
+        stats = svc.stats()
+    finally:
+        svc.close()
+    for g, w in zip(got, want):
+        assert [x for x, _ in g] == [x for x, _ in w]
+        np.testing.assert_allclose([s for _, s in g], [s for _, s in w],
+                                   rtol=0, atol=1e-6)
+    assert stats["completed"] == len(words) and stats["batches"] < len(words)
+    events = serve_tracer.events()
+    scans = [e["args"] for e in events if e["name"] == "serve.scan_enqueue"]
+    shards, rows = mesh[1], 3008 // mesh[1]
+    assert scans and all(
+        (a["shards"], a["topk_rows"], a["merge_rows"])
+        == (shards, _topk_rows(rows, 6), shards * 6) for a in scans)
+    assert {e["args"]["ops"] for e in events if e["name"] == "serve.row_fetch"} == {1}
+    one.stop()
+    sharded.stop()
 
 
 _COMPILED = []      # every backend compile of this process, by function

@@ -48,6 +48,13 @@ that the first's results order after it. One conditional holding a form's reads
 AND its writes copied the table in and out of the per-pair branch's scatter
 loop, once an iteration of 128 (two ``copy f32[3000000,384]`` in the loop's
 body, my compile for the described v5e, PR 37).
+
+The seventh (PR 45) is the query scan over ``sgns-nn-10m-300-x4``'s table:
+f32[10000000,300] partitioned by rows over the four described chips, ONE
+program under ``shard_map``. A chip's f32[2500000,300] shard is neither copied
+(the owner's reads are slices, no gather) nor gathered; the only all-reduce is
+the [Q, 300] query block's and the only all-gathers the [Q, 11] candidates';
+the one large temporary is a shard's own [Q, 2,500,096] score block.
 """
 
 import os
@@ -355,3 +362,39 @@ def test_the_subword_scan_copies_no_table(one_chip, lists, monkeypatch):
     _no_table_copied(compiled.as_text())
     # two score blocks of [32, 2,519,552] float32 and no table beside them
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("queries", [32, 64])
+def test_the_sharded_scan_moves_no_table_and_nothing_v_wide(topo, queries, monkeypatch):
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from glint_word2vec_tpu.models import word2vec as w2v
+
+    rows, dim, k = 10_000_000, 300, 11
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    shards = NamedSharding(mesh, PartitionSpec("model", None))
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, PartitionSpec(*axes)))
+
+    # _grouped_scores asks for the backend while it is traced: the TPU's branch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = w2v._gather_topk_batch.lower(
+        spec((rows, dim), jnp.float32, "model", None),
+        spec((rows,), jnp.float32, "model"), spec((queries,), jnp.int32),
+        None, k, rows, shards).compile()
+    text = compiled.as_text()
+    assert not re.findall(r"= f32\[2500\d{3},300\]\S* (?:copy|gather|all-gather)\(", text)
+    assert " all-to-all(" not in text and " collective-permute(" not in text
+    reduced = re.findall(r"= (\S+?)\{\S* all-reduce(?:-start)?\(", text)
+    assert reduced == [f"f32[{queries},{dim}]"], reduced
+    gathered = re.findall(r"= \(?(\w+)\[([\d,]+)\]\S* all-gather(?:-start)?\(", text)
+    assert len(gathered) == 2, gathered
+    for _, dims in gathered:
+        assert int(np.prod([int(d) for d in dims.split(",")])) == 4 * k * queries
+    memory = compiled.memory_analysis()
+    # a chip's shard and its norms are the arguments; one score block beside them
+    assert memory.argument_size_in_bytes < 3.1e9
+    assert 4 * queries * 2_500_000 < memory.temp_size_in_bytes < 4 * queries * 2_500_000 * 1.2
