@@ -3,8 +3,10 @@
 The scatter formulation (:func:`glint_word2vec_tpu.ops.sgns.cbow_step_shared_core`)
 treats each example's context window as an unordered [B, C] index set: it gathers
 ``syn0[contexts]`` and scatters ``d_ctx`` as **B·C rows** (~655k at B=64k, C≈10).
-PERF.md §2 prices the scatter emitter at ~27–39 ns per update row, so those rows —
-not compute — are the measured 33.6 ms CBOW step (BENCH_r05).
+XLA's TPU scatter into f32[3000000,384] costs 0.04 ms + 0.104 ms per 1,000 update
+rows handed over, whether they repeat or are dropped out of bounds (TPU v5 lite;
+PERF.md §6, PRs 28, 30 and 46), so those rows, not compute, are that form's step:
+0.85 M examples/s on the chip where this form ran 2.17 M (PERF.md §6, PR 27).
 
 But CBOW batches are sliding windows over the *kept-token stream*: when batch
 position b holds kept token b (sentence-contiguous feed), both directions of the
@@ -19,10 +21,15 @@ context traffic are **banded sums over batch positions**:
   self-term ``g_b`` at b.
 
 Cost: ONE [T]-row ``syn0`` gather + two [T, D] prefix sums (the two-level
-triangular-matmul form from ops/pairgen, ~0.5 ms each at 64k×384 on v5e) + the
-interval-endpoint accumulation + [T]-row scatters back into syn0/syn1 — ~3–4·B
-update rows total instead of ~11·B, which the §2 cost model prices at ≥2× CBOW
-examples/s (PERF.md §9 has the full accounting).
+triangular-matmul form from ops/pairgen) + the interval-endpoint accumulation +
+two [T]-row scatters back into syn0 and syn1: ~2·B update rows instead of ~11·B.
+Measured at B = 65,536, D = 384, V = 3M (TPU v5 lite; PERF.md §5): a 21.7 ms
+step of which the two 65,546-row token scatters were 13.7 ms and everything
+else under 0.4 ms an op (the context mean and its spread 1.0, the backward
+prefix at HIGHEST 0.39, the pool rows 0.34). Since PR 46 the two scatters are
+handed one row per piece of a word's run of the block's tokens sorted inside
+the step (``token_runs``; PERF.md §6 has the probe's prices and the step's
+account since).
 
 Window intervals never cross sentence boundaries (``device_cbow_windows`` clamps
 them via the start bits), so prefix-sum *differences* are exact per sentence even
@@ -57,6 +64,7 @@ from glint_word2vec_tpu.ops.sgns import (
     _mask_sentinel,
     _sigmoid,
     clip_update_rows,
+    scatter_add_by_runs,
     stabilize_rows,
 )
 
@@ -211,6 +219,7 @@ def cbow_step_banded_core(
     with_metrics: bool = True,
     stabilizers: Optional[Stabilizers] = None,
     subword: Optional[tuple] = None,
+    token_runs: Optional[Tuple[int, int, int]] = None,
 ) -> Tuple[EmbeddingPair, StepMetrics]:
     """Banded CBOW update — mathematically the shared-pool scatter step
     (:func:`~glint_word2vec_tpu.ops.sgns.cbow_step_shared_core`) on the example
@@ -251,6 +260,25 @@ def cbow_step_banded_core(
     later groups of the tokens that have them, and
     ``StepMetrics.subword_gather_slots`` says how many it was handed. With
     neither, the program is the one it was.
+
+    ``token_runs`` ``(max_run, cap0, cap1)``: both token scatters are keyed
+    by ``tokens`` (syn0's rows by token as context, syn1's by token as
+    center), and a block of kept tokens holds about half as many distinct
+    words as slots (65,546 slots, ~30,700 words at V = 3M: PERF.md §6, PR
+    46). Each goes through
+    :func:`~glint_word2vec_tpu.ops.sgns.scatter_add_by_runs` on the block's
+    tokens sorted by word inside the step: one summed row per piece of a
+    word's run, cut every ``max_run``. syn0's by every slot's token, under
+    ``cap0`` (not beside the token row source, whose lists are syn0's
+    update); syn1's by the tokens of the slots that train an example alone
+    (``live``: the others' rows are zero), under ``cap1``. A block with more
+    pieces than a cap takes that table's plain scatter on the unsorted rows;
+    the trainer derives the three numbers from the vocabulary's counts, and
+    ``StepMetrics.syn0_rows`` / ``.syn1_rows`` say what each scatter was
+    handed. Masked slots still give syn0 zero rows (in word 0's run); the
+    pool rows' scatter, the stabilizers' post-pass and the taps are as
+    without it.
+    None: the program it was.
     """
     syn0, syn1, pos_w = params
     T = tokens.shape[0]
@@ -344,14 +372,29 @@ def cbow_step_banded_core(
     d_ctx = d_ctx * token_mask[:, None].astype(pf)
 
     dtype = syn0.dtype
+    syn0_rows = syn1_rows = None
+    if token_runs is not None:
+        max_run, cap0, cap1 = token_runs
+        # a conditional updates its table in place only where every read of
+        # that table is ordered before it: d_out does not depend on the pool
+        # rows' gather (Z), so tie the two (ops/sgns.py has the same barrier
+        # for the same reason; PERF.md §6, PR 30)
+        d_out, _ = jax.lax.optimization_barrier((d_out, Z))
     with jax.named_scope("cbow.scatter_syn0"):
-        if subword is None:
-            new_syn0 = syn0.at[tokens].add(d_ctx.astype(dtype))
-        else:
+        if subword is not None:
             new_syn0 = sw.scatter_center_updates(
                 syn0, words, d_ctx, sw_table, sw_shape, sw_plan)
+        elif token_runs is None:
+            new_syn0 = syn0.at[tokens].add(d_ctx.astype(dtype))
+        else:
+            new_syn0, syn0_rows = scatter_add_by_runs(
+                syn0, tok_i, d_ctx, max_run, cap0, sort=True)
     with jax.named_scope("cbow.scatter_syn1"):
-        new_syn1 = syn1.at[tokens].add(d_out.astype(dtype))
+        if token_runs is None:
+            new_syn1 = syn1.at[tokens].add(d_out.astype(dtype))
+        else:
+            new_syn1, syn1_rows = scatter_add_by_runs(
+                syn1, tok_i, d_out, max_run, cap1, sort=True, keep=live > 0)
         new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
     if stabilizers is not None and stabilizers.post_pass:
         # touched sets of THIS formulation: syn0 at every valid token slot
@@ -383,6 +426,8 @@ def cbow_step_banded_core(
         loss=loss,
         mean_f_pos=mean_f_pos,
         pairs=live.sum(),
+        syn0_rows=syn0_rows,
+        syn1_rows=syn1_rows,
         subword_rows=None if subword is None else sw_plan.live_rows,
         subword_slots=(None if subword is None
                        else sw.scatter_slots(sw_plan, sw_shape)),

@@ -183,9 +183,15 @@ def run_sums(rows: jax.Array, pos: jax.Array, max_run: int,
     return sums
 
 
+# the sort key of an entry scatter_add_by_runs leaves out (``keep``): past
+# every row id, so such entries sort last
+_NO_KEY = int(jnp.iinfo(jnp.int32).max)
+
+
 def scatter_add_by_runs(mat: jax.Array, idx: jax.Array, rows: jax.Array,
-                        max_run: int, cap: int,
-                        sort: bool = False) -> Tuple[jax.Array, jax.Array]:
+                        max_run: int, cap: int, sort: bool = False,
+                        keep: Optional[jax.Array] = None,
+                        ) -> Tuple[jax.Array, jax.Array]:
     """``mat.at[idx].add(rows)`` that hands the scatter ONE row per run of
     equal neighbouring ``idx``: ``(new_mat, rows_handed_over)``.
 
@@ -207,13 +213,20 @@ def scatter_add_by_runs(mat: jax.Array, idx: jax.Array, rows: jax.Array,
     scatter, the same op on the same unsorted ``rows`` as without this
     function. A row of ``mat`` receives the same sum of the same ``rows``
     either way, in the order the batch holds them (the sort is stable);
-    coalesced, the float additions run over the run first, then into the row."""
+    coalesced, the float additions run over the run first, then into the row.
+
+    ``keep`` (bool [N], with ``sort``): the entries whose ``rows`` are not
+    zero by construction (the banded CBOW step's slots that train an example,
+    four fifths of a block). The others sort last under a key no word has and
+    head no run, so the coalesced scatter is handed the kept entries' runs
+    alone; the plain branch is the same either way."""
     n, v = idx.shape[0], mat.shape[0]
     at = jnp.arange(n, dtype=jnp.int32)
-    keys, order = (jax.lax.sort((idx, at), num_keys=1, is_stable=True)
-                   if sort else (idx, None))
+    by = idx if keep is None else jnp.where(keep, idx, _NO_KEY)
+    keys, order = (jax.lax.sort((by, at), num_keys=1, is_stable=True)
+                   if sort else (by, None))
     pos = run_positions(keys, max_run)
-    head = pos == 0
+    head = pos == 0 if keep is None else (pos == 0) & (keys != _NO_KEY)
     heads = head.sum(dtype=jnp.int32)
 
     def coalesced(mat):
@@ -253,10 +266,13 @@ class StepMetrics(NamedTuple):
     mean_f_pos: jax.Array  # mean positive dot product (gradient-health signal)
     pairs: jax.Array      # number of real (unmasked) pairs in the batch
     # update rows that reached syn0's scatter with a live index (the shared-pool
-    # SGNS step: B plain, one per center run coalesced); None = not counted
+    # SGNS step: B plain, one per center run coalesced; the banded CBOW step
+    # under ``token_runs``: T plain, one per piece of a word's run of the
+    # block's tokens coalesced); None = not counted
     syn0_rows: Optional[jax.Array] = None
     # the same for syn1's context scatter (one per context run of the batch
-    # sorted by context); the pool rows' scatter is not counted
+    # sorted by context; the banded CBOW step: one per piece of a word's run
+    # of the tokens that train an example); the pool rows' scatter is not counted
     syn1_rows: Optional[jax.Array] = None
     # rows of the centers' subword lists that reached syn0's scatter with a
     # live index (config.subword; ops/subword.py); None = not a subword step

@@ -154,6 +154,44 @@ def _context_run_cap(counts: np.ndarray, train_words_count: int,
     return cap if cap <= batch // 2 else 0
 
 
+# tokens in a piece of a word's run in a banded CBOW block sorted by word:
+# named, with the caps' room and the break-even, by the chip (PERF.md §6, PR 46)
+_TOKEN_MAX_RUN = 6
+
+
+def _token_run_caps(counts: np.ndarray, train_words_count: int,
+                    subsample_ratio: float, tokens: int,
+                    window: int) -> Tuple[int, int]:
+    """Static row caps ``(syn0's, syn1's)`` of the banded CBOW step's two
+    coalesced token scatters (ops/cbow_banded.py ``token_runs``: the block's
+    ``tokens`` kept tokens sorted by word inside the step, one row per piece
+    of a word's run), (0, 0) = do not build them. Every slot of a block is a
+    kept token, so sorted by word it holds one run per distinct word, cut
+    every :data:`_TOKEN_MAX_RUN` (:func:`_expected_heads` with the block's
+    tokens as draws and as entries): syn0's rows. syn1's are the runs of the
+    slots that train an example, :func:`_cbow_examples_per_kept_token` of
+    them. At V = 3M that reads 35,338 and 29,053 where 550 feed blocks of
+    five seeds hold 34,555-35,246 and 28,187-28,851 pieces: 15% of room to
+    the NEAREST 32nd of the block (as :func:`_slot_cap` rounds and for its
+    reason: every seed compiles one program; a block's count moves ±1%),
+    40,960 and 32,768 of 65,546. About half the rows, not a quarter as SGNS
+    contexts: a coalesced scatter still wins far above
+    :func:`_context_run_cap`'s half-batch (sort, row gather, run sums and
+    compaction cost what ~16,000 rows do at this run length), so the rule
+    builds nothing only where syn0's cap passes 0.75 of the block (a flat
+    vocabulary, every token another word)."""
+    if tokens < 32:
+        return 0, 0
+    live = tokens * _cbow_examples_per_kept_token(window)
+    heads = [_expected_heads(counts, train_words_count, subsample_ratio,
+                             n, n, _TOKEN_MAX_RUN) for n in (tokens, live)]
+    if heads[0] is None:
+        return 0, 0
+    unit = tokens // 32
+    cap0, cap1 = (int(1.15 * h / unit + 0.5) * unit for h in heads)
+    return (cap0, cap1) if cap0 <= 0.75 * tokens else (0, 0)
+
+
 # run heads in a piece of a word (the subword row source's second level,
 # ops/subword.py): named, with the cap's unit, by the chip (PERF.md §6, PR 34)
 _WORD_MAX_RUN = 8
@@ -298,12 +336,16 @@ class StepChoice(NamedTuple):
     center_runs: Optional[Tuple[int, int]]
     # the same for syn1's context update, by runs of the batch sorted by context
     context_runs: Optional[Tuple[int, int]] = None
+    # (max_run, syn0's cap, syn1's cap) where the banded CBOW step's two token
+    # scatters go by runs of the block's tokens sorted by word
+    token_runs: Optional[Tuple[int, int, int]] = None
 
 
 def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
                 context_cap: int, stabilizers: Optional[Stabilizers],
                 with_metrics: bool, subword_shape=None,
-                hs_shape=None) -> StepChoice:
+                hs_shape=None,
+                token_caps: Tuple[int, int] = (0, 0)) -> StepChoice:
     """The step selection matrix: which update one configuration trains with.
     Every legal combination is one row, read top to bottom; what is on no row
     config.__post_init__ refuses at construction, never silently downgrades
@@ -347,7 +389,10 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
     the twin; the rows without a ``with_metrics`` form have one twin.
     ``subword_shape`` is the trainer's :class:`..ops.subword.SubwordShape`
     and ``hs_shape`` its :class:`..ops.hs.HsShape` (None where the model is
-    not subword, not hierarchical softmax)."""
+    not subword, not hierarchical softmax). ``token_caps`` is
+    :func:`_token_run_caps` of the trainer's vocabulary and block: the first
+    row's ``token_runs`` where one program sees the block whole (no data
+    axis), as ``context_cap`` is the last row's ``context_runs``."""
     compute_dtype = jnp.dtype(cfg.compute_dtype)
     logits_dtype = jnp.dtype(cfg.logits_dtype)
     n, pool = cfg.negatives, cfg.negative_pool
@@ -360,6 +405,9 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
 
     if cfg.cbow and cfg.cbow_update == "banded":
         from glint_word2vec_tpu.ops.cbow_banded import cbow_step_banded_core
+        token_runs = None
+        if plan.num_data == 1 and all(token_caps):
+            token_runs = (_TOKEN_MAX_RUN, *token_caps)
 
         def step(params, batch, negatives, alpha):
             band = batch["band"]
@@ -371,9 +419,11 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
                 compute_dtype, logits_dtype, with_metrics,
                 stabilizers=stabilizers,
                 subword=(None if subword_shape is None else
-                         (batch["subword_table"], subword_shape)))
+                         (batch["subword_table"], subword_shape)),
+                token_runs=token_runs)
 
-        return StepChoice(cbow_step_banded_core, step, shared_pool, None)
+        return StepChoice(cbow_step_banded_core, step, shared_pool, None,
+                          token_runs=token_runs)
 
     if cfg.cbow and pool > 0 and not cfg.duplicate_scaling:
         def step(params, batch, negatives, alpha):
@@ -1266,6 +1316,11 @@ class Trainer:
         self._context_cap = 0 if cfg.cbow or cfg.loss == "hs" else _context_run_cap(
             self.vocab.counts, self.vocab.train_words_count,
             cfg.subsample_ratio, cfg.window, cfg.pairs_per_batch)
+        # select_step's banded CBOW row reads it; no other row has a token block
+        self._token_caps = _token_run_caps(
+            self.vocab.counts, self.vocab.train_words_count,
+            cfg.subsample_ratio, self._tokens_per_step,
+            cfg.window) if self._banded_cbow else (0, 0)
         self._step_fn = self._build_step()
         # fast twin (metrics elided) for the shared-pool paths (skip-gram and
         # CBOW): the paths whose loss side-channel is an extra full [B, pool]
@@ -1316,7 +1371,8 @@ class Trainer:
         choice = select_step(cfg, self.plan, self._feed_segments,
                              self._context_cap, stab, with_metrics,
                              subword_shape=self._subword_shape,
-                             hs_shape=self._hs_shape)
+                             hs_shape=self._hs_shape,
+                             token_caps=self._token_caps)
         inner, neg_shape = choice.step, choice.neg_shape
         # np.uint32 (not a Python int): any negative or 64-bit seed masked to 32 bits
         # lands in [2^31, 2^32), which jnp.asarray rejects under int32 canonicalization
@@ -2649,15 +2705,18 @@ class Trainer:
                      metrics.subword_rows, metrics.subword_slots,
                      metrics.subword_gather_slots, metrics.hs_nodes,
                      self.params.pos))
-                if rows0_k is not None and pairs_k[real - 1] > 0:
+                if pairs_k[real - 1] > 0:
                     # how far the step coalesced each table's update: 1.0
                     # plain, heads over pairs where runs were summed first
-                    # (syn0's by center, syn1's by context)
-                    blocked.set(
-                        syn0_rows_per_pair=float(
-                            rows0_k[real - 1] / pairs_k[real - 1]),
-                        syn1_rows_per_pair=float(
-                            rows1_k[real - 1] / pairs_k[real - 1]))
+                    # (syn0's by center, syn1's by context; the banded CBOW
+                    # step's both by token over its live examples: ~0.67 and
+                    # ~0.55 coalesced, ~1.26 a block over a cap, and syn1's
+                    # alone beside the token row source)
+                    for name, rows_k in (("syn0_rows_per_pair", rows0_k),
+                                         ("syn1_rows_per_pair", rows1_k)):
+                        if rows_k is not None:
+                            blocked.set(**{name: float(
+                                rows_k[real - 1] / pairs_k[real - 1])})
                 if rows_sw_k is not None and pairs_k[real - 1] > 0:
                     # rows of the subword lists (the centers', or a CBOW
                     # block's tokens') that reached syn0's scatter live

@@ -341,6 +341,203 @@ def test_banded_scatter_fallback_for_large_windows():
 
 
 # ---------------------------------------------------------------------------
+# the two token scatters by runs of the block's tokens sorted once (token_runs)
+# ---------------------------------------------------------------------------
+
+RUN_V, RUN_D, RUN_P, RUN_W, RUN_NEG, RUN_T = 300, 16, 32, 3, 4, 256
+
+
+def _run_block(tokens, real=None, seed=0):
+    """One block of RUN_T slots holding ``tokens`` (the rest masked), in
+    sentences of 12, with the device's own window draws."""
+    real = len(tokens) if real is None else real
+    tb = np.zeros(RUN_T, np.int32)
+    tb[:len(tokens)] = tokens
+    bits = np.packbits(np.arange(RUN_T) % 12 == 0, bitorder="little")
+    band = device_cbow_windows(
+        jnp.asarray(tb), jnp.asarray(bits), jnp.int32(real), jnp.uint32(seed),
+        jnp.uint32(0), jnp.uint32(stream_base(SEED, STREAM_WINDOW, IT, SHARD)),
+        window=RUN_W, halo=RUN_W)
+    return jnp.asarray(tb), band
+
+
+def _run_params(dtype=jnp.float32, seed=4):
+    rng = np.random.default_rng(seed)
+    return EmbeddingPair(jnp.asarray(rng.normal(0, 0.1, (RUN_V, RUN_D)), dtype),
+                         jnp.asarray(rng.normal(0, 0.05, (RUN_V, RUN_D)), dtype))
+
+
+def _run_step(params, tb, band, token_runs, compute_dtype=jnp.float32, **kw):
+    negs = jnp.asarray(np.random.default_rng(8).integers(0, RUN_V, RUN_P), jnp.int32)
+    fn = jax.jit(lambda p: cbow_step_banded_core(
+        p, tb, band.left, band.right, band.center, band.token, negs,
+        jnp.asarray(0.05, p.syn0.dtype), RUN_NEG, RUN_W, "exact", compute_dtype,
+        compute_dtype, token_runs=token_runs, **kw))
+    # strict bfloat16, as tests/test_coalesce_runs.py: the two programs fuse
+    # differently, and XLA may keep a fused bfloat16 value in float32 in one
+    return fn.lower(params).compile(
+        compiler_options={"xla_allow_excess_precision": False})(params)
+
+
+def _pieces(tokens, max_run, keep=None):
+    """Pieces of the runs of ``tokens`` sorted by word and cut every
+    ``max_run``; of the slots ``keep`` marks alone where given."""
+    tokens = np.asarray(tokens)
+    if keep is not None:
+        tokens = tokens[np.asarray(keep) > 0]
+    _, counts = np.unique(tokens, return_counts=True)
+    return int((-(-counts // max_run)).sum())
+
+
+def _live(band):
+    return np.asarray(band.center) * ((np.asarray(band.left) + np.asarray(band.right)) > 0)
+
+
+def _zipf_tokens(n, seed):
+    rng = np.random.default_rng(seed)
+    p = 1.0 / (np.arange(RUN_V) + 3.0)
+    return rng.choice(RUN_V, n, p=p / p.sum()).astype(np.int32)
+
+
+# name -> (tokens of the block, real slots, (max_run, syn0's cap, syn1's cap));
+# every block has RUN_T slots, the slots past ``real`` are masked and hold word 0
+TOKEN_RUN_CASES = {
+    # a Zipf block: about half as many words as slots, runs of every length
+    "zipf_block": (_zipf_tokens(RUN_T, 1), RUN_T, (4, 224, 192)),
+    # runs longer than max_run are cut: a word in several pieces
+    "a_word_in_several_pieces": (
+        np.concatenate([np.full(37, 7), _zipf_tokens(RUN_T - 37, 2)]).astype(np.int32),
+        RUN_T, (4, 224, 192)),
+    # one piece a word exactly at max_run, one more at max_run + 1
+    "pieces_cut_at_max_run": (
+        np.concatenate([np.full(4, 5), np.full(5, 9), np.arange(20, 20 + RUN_T - 9)]
+                       ).astype(np.int32), RUN_T, (4, RUN_T, RUN_T)),
+    # the masked tail rides in word 0's run with zero rows
+    "masked_tail": (_zipf_tokens(150, 3), 150, (4, 224, 192)),
+    "all_masked": (np.zeros(0, np.int32), 0, (4, 224, 192)),
+    # no word twice: as many heads as slots, under a cap that holds them
+    "every_token_another_word": (np.arange(RUN_T, dtype=np.int32) + 10, RUN_T,
+                                 (4, RUN_T, RUN_T)),
+}
+
+
+@pytest.mark.parametrize("compute_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(TOKEN_RUN_CASES))
+def test_token_runs_against_the_plain_banded_step(case, compute_dtype):
+    tokens, real, runs = TOKEN_RUN_CASES[case]
+    tb, band = _run_block(tokens, real)
+    params = _run_params()
+    plain, m0 = _run_step(params, tb, band, None, compute_dtype)
+    got, m1 = _run_step(params, tb, band, runs, compute_dtype)
+    heads0, heads1 = _pieces(tb, runs[0]), _pieces(tb, runs[0], _live(band))
+    assert heads0 <= runs[1] and heads1 <= runs[2] and heads1 <= heads0
+    # syn0's scatter was handed one row a piece of a word's run, masked slots
+    # (word 0's) among them; syn1's the pieces of the slots that train an
+    # example alone; the plain step counts nothing
+    assert (float(m1.syn0_rows), float(m1.syn1_rows)) == (heads0, heads1)
+    assert m0.syn0_rows is None and m0.syn1_rows is None
+    assert float(m1.pairs) == float(m0.pairs) and float(m1.loss) == float(m0.loss)
+    for new, old, start in zip(got[:2], plain[:2], params[:2]):
+        want = np.asarray(old) - np.asarray(start)
+        diff = np.asarray(new) - np.asarray(old)
+        # the same rows summed in another order: float32 roundings of a row
+        assert np.linalg.norm(diff) <= 1e-5 * max(np.linalg.norm(want), 1e-30)
+    # a word the block does not hold keeps its syn0 row (syn1 also moves at
+    # the pool's rows)
+    untouched = np.setdiff1d(np.arange(RUN_V), np.asarray(tb))
+    np.testing.assert_array_equal(np.asarray(got.syn0)[untouched],
+                                  np.asarray(params.syn0)[untouched])
+
+
+@pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
+@pytest.mark.parametrize("compute_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_a_block_over_the_token_cap_is_the_parents_step_bit_for_bit(compute_dtype,
+                                                                   with_metrics):
+    tb, band = _run_block(_zipf_tokens(RUN_T, 1))
+    params = _run_params()
+    heads0, heads1 = _pieces(tb, 4), _pieces(tb, 4, _live(band))
+    plain, _ = _run_step(params, tb, band, None, compute_dtype,
+                         with_metrics=with_metrics)
+    got, m = _run_step(params, tb, band, (4, heads0 - 1, heads1 - 1), compute_dtype,
+                       with_metrics=with_metrics)
+    assert float(m.syn0_rows) == float(m.syn1_rows) == RUN_T
+    np.testing.assert_array_equal(np.asarray(got.syn0), np.asarray(plain.syn0))
+    np.testing.assert_array_equal(np.asarray(got.syn1), np.asarray(plain.syn1))
+    # each table decides for itself, and at its cap exactly it coalesces
+    got, m = _run_step(params, tb, band, (4, heads0, heads1 - 1), compute_dtype,
+                       with_metrics=with_metrics)
+    assert (float(m.syn0_rows), float(m.syn1_rows)) == (heads0, RUN_T)
+    np.testing.assert_array_equal(np.asarray(got.syn1), np.asarray(plain.syn1))
+    _, m = _run_step(params, tb, band, (4, heads0 - 1, heads1), compute_dtype,
+                     with_metrics=with_metrics)
+    assert (float(m.syn0_rows), float(m.syn1_rows)) == (RUN_T, heads1)
+
+
+def _three_steps(dtype, compute_dtype, token_runs, rtol, atol):
+    """Three sequential blocks of one kept stream (windows crossing the cuts)
+    through the banded step with ``token_runs`` against the scatter oracle."""
+    rng = np.random.default_rng(3)
+    V, D, P, W, NEG = 60, 16, 32, 3, 4
+    ktoks, starts = _kept_stream(rng, 36, 15, V)
+    left_h, right_h = _host_windows(ktoks, starts, W)
+    live = np.flatnonzero(left_h + right_h > 0)
+    T = -(-ktoks.shape[0] // 3) + 2 * W
+    blocks = _banded_blocks(ktoks, starts, T, W)
+    assert len(blocks) == 3
+    p_cur = p_ref = EmbeddingPair(
+        jnp.asarray(rng.normal(0, 0.1, (V, D)), dtype),
+        jnp.asarray(rng.normal(0, 0.05, (V, D)), dtype))
+    negs = jnp.asarray(rng.integers(0, V, P), jnp.int32)
+    alpha = jnp.asarray(0.05, dtype)
+    covered, handed = 0, []
+    for tb, band, nc in blocks:
+        p_cur, m = cbow_step_banded_core(
+            p_cur, jnp.asarray(tb), band.left, band.right, band.center, band.token,
+            negs, alpha, NEG, W, "exact", compute_dtype, token_runs=token_runs)
+        handed.append((float(m.syn0_rows), _pieces(tb, token_runs[0])))
+        assert float(m.syn1_rows) == _pieces(tb, token_runs[0], _live(band))
+        sel = live[(live >= covered) & (live < covered + nc)]
+        covered += nc
+        p_ref, _ = _scatter_reference(p_ref, ktoks, left_h, right_h, sel, negs,
+                                      alpha, NEG, W, compute_dtype)
+    # every block held repeats and coalesced
+    assert all(got == want < T for got, want in handed), handed
+    for got, want in zip(p_cur[:2], p_ref[:2]):
+        np.testing.assert_allclose(np.asarray(got, np.float64),
+                                   np.asarray(want, np.float64), rtol=rtol, atol=atol)
+
+
+def test_token_runs_three_steps_against_the_float64_reference():
+    with jax.enable_x64():
+        _three_steps(jnp.float64, jnp.float64, (3, 64, 64), 5e-12, 5e-14)
+
+
+@pytest.mark.parametrize("compute_dtype, rtol, atol",
+                         [(jnp.float32, 1e-4, 1e-5), (jnp.bfloat16, 5e-2, 5e-3)],
+                         ids=["float32", "bfloat16"])
+def test_token_runs_three_steps_in_both_compute_dtypes(compute_dtype, rtol, atol):
+    _three_steps(jnp.float32, compute_dtype, (3, 64, 64), rtol, atol)
+
+
+def test_token_runs_leave_the_stabilizers_and_the_pool_rows_alone():
+    """update_clip and the post-pass read ``tokens`` and the masks, not the
+    order: with them on the coalesced step is the plain one to rounding."""
+    from glint_word2vec_tpu.ops.sgns import Stabilizers
+
+    tb, band = _run_block(_zipf_tokens(200, 5), 200)
+    params = _run_params()
+    stab = Stabilizers(max_row_norm=0.35, update_clip=0.01, row_l2=1e-3)
+    plain, _ = _run_step(params, tb, band, None, stabilizers=stab)
+    got, m = _run_step(params, tb, band, (4, 224, 192), stabilizers=stab)
+    assert float(m.syn1_rows) < RUN_T
+    for new, old in zip(got[:2], plain[:2]):
+        np.testing.assert_allclose(np.asarray(new), np.asarray(old), rtol=1e-5,
+                                   atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
 # trainer integration + config matrix
 # ---------------------------------------------------------------------------
 
@@ -376,6 +573,79 @@ def test_trainer_fit_banded_smoke():
     assert t.heartbeats and np.isfinite(t.heartbeats[-1].loss)
     # the metrics-elided fast twin is actually wired for this path
     assert t._step_fn_fast is not t._step_fn
+
+
+@pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
+def test_trainer_fit_both_twins_coalesce_the_token_scatters(compute_dtype):
+    """Through Trainer.fit: the rule derives a cap from the vocabulary's
+    counts, both twins take it and compile once, every heartbeat's
+    ``device_block`` span says how many rows each token scatter was handed
+    over the step's live examples, and the fit trains what the plain step
+    trains (the rule giving 0)."""
+    import os
+    import shutil
+    import tempfile
+    from dataclasses import replace as dc_replace
+
+    from glint_word2vec_tpu.config import Word2VecConfig
+    from glint_word2vec_tpu.data.vocab import Vocabulary
+    from glint_word2vec_tpu.train import trainer as trainer_mod
+    from glint_word2vec_tpu.train.trainer import _TOKEN_MAX_RUN, Trainer
+
+    V = 2000
+    counts = np.maximum(1e6 / (np.arange(V) + 10.0) ** 1.07, 5.0).astype(np.int64)
+    vocab = Vocabulary.from_words_and_counts([f"w{i}" for i in range(V)], counts)
+    rng = np.random.default_rng(0)
+    toks = rng.choice(V, 30_000, p=counts / counts.sum()).astype(np.int32)
+    sents = [toks[i:i + 40] for i in range(0, toks.shape[0], 40)]
+    cfg = Word2VecConfig(
+        vector_size=24, window=5, negatives=5, min_count=1, cbow=True,
+        cbow_update="banded", compute_dtype=compute_dtype, logits_dtype=compute_dtype,
+        pairs_per_batch=1024, steps_per_dispatch=2, heartbeat_every_steps=4,
+        negative_pool=32, subsample_ratio=0.0, num_iterations=1, seed=1)
+
+    def fit(coalesce):
+        run_dir = tempfile.mkdtemp(prefix="token_runs_")
+        rule = trainer_mod._token_run_caps
+        if not coalesce:
+            trainer_mod._token_run_caps = lambda *a: (0, 0)
+        try:
+            t = Trainer(dc_replace(cfg, telemetry_path=os.path.join(run_dir, "run.jsonl")),
+                        vocab)
+        finally:
+            trainer_mod._token_run_caps = rule
+        try:
+            t.fit(sents)
+            spans = [e.get("args") or {} for e in t._tracer.events()
+                     if e["name"] == "device_block"]
+        finally:
+            shutil.rmtree(run_dir, ignore_errors=True)
+        return t, spans
+
+    start = jax.device_get(Trainer(cfg, vocab).params)
+    (on, on_spans), (off, off_spans) = fit(True), fit(False)
+    tokens = on._tokens_per_step
+    cap0, cap1 = on._token_caps
+    assert tokens == 1024 + 2 * 5 and 0 < cap1 < cap0 <= 0.75 * tokens
+    assert cap0 % (tokens // 32) == cap1 % (tokens // 32) == 0
+    assert off._token_caps == (0, 0)
+    assert on._step_fn_fast is not on._step_fn
+    assert on._step_fn._cache_size() == 1 and on._step_fn_fast._cache_size() == 1
+    assert on.global_step == off.global_step and on.global_step >= 8
+    # heads over live examples: a block of 1,034 tokens over 2,000 Zipf words
+    # holds ~560 pieces, ~470 of them of its ~820 live examples' slots; plain
+    # would read 1,034 / 820 for both
+    shares0 = [a["syn0_rows_per_pair"] for a in on_spans]
+    shares1 = [a["syn1_rows_per_pair"] for a in on_spans]
+    assert shares0 and max(shares0) < 0.9 and min(shares1) > 0.3
+    assert all(s1 < s0 for s0, s1 in zip(shares0, shares1))
+    assert off_spans and not any("syn1_rows_per_pair" in a or "syn0_rows_per_pair" in a
+                                 for a in off_spans)
+    assert _TOKEN_MAX_RUN >= 2
+    limit = 1e-4 if compute_dtype == "float32" else 5e-3
+    for a, b, s in zip(on.params[:2], off.params[:2], start[:2]):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.linalg.norm(a - b) <= limit * np.linalg.norm(b - s)
 
 
 def test_trainer_fit_banded_deterministic():

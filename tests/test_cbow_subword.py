@@ -102,7 +102,7 @@ def _device_table(table):
 
 
 def _run_program(case, subword, positions, dtype, shape=None, with_metrics=True,
-                 compute_dtype=None, steps=STEPS):
+                 compute_dtype=None, steps=STEPS, token_runs=None):
     """``steps`` steps of the banded core from the case's tables: the params
     and each step's metrics."""
     table = case["table"]
@@ -120,7 +120,7 @@ def _run_program(case, subword, positions, dtype, shape=None, with_metrics=True,
                 params, tokens, band.left, band.right, band.center, band.token,
                 negatives, jnp.asarray(0.05, dtype), NEG, W, "exact",
                 compute_dtype or dtype, compute_dtype or dtype, with_metrics,
-                subword=None if dev is None else (dev, shape))
+                subword=None if dev is None else (dev, shape), token_runs=token_runs)
 
     out = []
     for k in range(steps):
@@ -302,6 +302,35 @@ def _gather_slots_handed(case, form, step=0):
     return T * groups * 8
 
 
+@pytest.mark.parametrize("subword", [True, False], ids=["token_lists", "words_alone"])
+@pytest.mark.parametrize("cap1", [T, 64], ids=["coalesced", "over_the_cap"])
+def test_token_runs_beside_the_row_source_give_the_same_sums(cap1, subword):
+    """``token_runs`` (PR 46): beside the token row source syn1's scatter alone
+    goes by runs of the tokens that train an example, and syn0's update stays
+    the lists' (no ``syn0_rows``); without it both tables' do. To the
+    reference either way, position weights on."""
+    case = _case()
+    want, _ = _run_reference(case, subword, True)
+    got, metrics = _run_program(case, subword, True, jnp.float32,
+                                token_runs=(4, cap1, cap1))
+    v = V + BUCKETS if subword else V
+    np.testing.assert_allclose(got.syn0, want["syn0"][:v], rtol=3e-5, atol=3e-7)
+    np.testing.assert_allclose(got.syn1, want["syn1"], rtol=3e-5, atol=3e-7)
+    plain, _ = _run_program(case, subword, True, jnp.float32)
+    if cap1 < T:      # a plain branch is the step's own scatter, bit for bit
+        np.testing.assert_array_equal(np.asarray(got.syn0), np.asarray(plain.syn0))
+        np.testing.assert_array_equal(np.asarray(got.syn1), np.asarray(plain.syn1))
+    for m, tokens, band in zip(metrics, case["tokens"], case["bands"]):
+        live = np.asarray(band.center) * (
+            (np.asarray(band.left) + np.asarray(band.right)) > 0)
+        pieces = [int((-(-np.unique(t, return_counts=True)[1] // 4)).sum())
+                  for t in (tokens, tokens[live > 0])]
+        assert 64 < pieces[1] < pieces[0] < T
+        assert float(m.syn1_rows) == (pieces[1] if cap1 == T else T)
+        assert (m.syn0_rows is None) if subword else (
+            float(m.syn0_rows) == (pieces[0] if cap1 == T else T))
+
+
 @pytest.mark.parametrize("form", list(FORMS))
 def test_row_source_branches_give_the_same_sums(form):
     case = _form_case(form)
@@ -466,15 +495,25 @@ def test_masked_slots_and_the_lane_padding_stay_zero(slot_cap, tail_cap):
 # tiny sizes the strings' law gives words of 9 letters at most, one group of 8
 # rows, so the rule derives none and the program gathers its 2,058 x 8 slots
 # whole: what the digests see of PR 43 is the step's new counter,
-# `StepMetrics.subword_gather_slots`). These two are PR 43's tree's; the other
-# four are as they were.
+# `StepMetrics.subword_gather_slots`). PR 46 changed both banded steps on
+# purpose (the token scatters by runs of the block's tokens sorted inside the
+# step, whose caps the trainer's rule derives at the tiny sizes too: 1,536 and
+# 1,280 of 2,058 slots; beside the token row source syn1's alone): those four
+# are PR 46's tree's. The subword skip-gram step's two are as they were, and
+# the SGNS and hierarchical-softmax steps' four, which share
+# `ops/sgns.scatter_add_by_runs` with the banded step, are the parent commit of
+# PR 46's (9decdaa): the helper's new `keep` adds no op where it is not given.
 PARENT_STEP_TEXT = {
-    ("cbow-3m-300.train", "train_cbow", "_step_fn"): "7f02f0da70d07c76",
-    ("cbow-3m-300.train", "train_cbow", "_step_fn_fast"): "436c26f275ae9be0",
+    ("cbow-3m-300.train", "train_cbow", "_step_fn"): "b004263a353a0230",
+    ("cbow-3m-300.train", "train_cbow", "_step_fn_fast"): "93b9ca55222cf02a",
     ("subword-sgns-2.5m-300.train", "train_subword", "_step_fn"): "f53dceb464f6b6b9",
     ("subword-sgns-2.5m-300.train", "train_subword", "_step_fn_fast"): "188e2230a80d8e87",
-    ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn"): "fba803c477e57fb4",
-    ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn_fast"): "6570001c4575640b",
+    ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn"): "3652dd4987fcefa7",
+    ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn_fast"): "b4492ee4d2d96c50",
+    ("sgns-3m-300.train", "train", "_step_fn"): "f7f4fe22a5785c49",
+    ("sgns-3m-300.train", "train", "_step_fn_fast"): "b994716300289c09",
+    ("skipgram-hs-3m-300.train", "train_hs", "_step_fn"): "1ca84c1f23489d27",
+    ("skipgram-hs-3m-300.train", "train_hs", "_step_fn_fast"): "6841e75acfc947f2",
 }
 
 
@@ -503,6 +542,8 @@ def test_steps_without_the_new_parts_lower_to_the_parents_text(cell_name, kind_n
         assert trainer.params.pos is None
         assert shape is None or (
             shape.word_cap > 0 and (shape.slot_cap, shape.tail_cap) == (0, 0))
+    # the banded steps' token scatters coalesce at the tiny sizes too
+    assert all(trainer._token_caps) == trainer._banded_cbow
     cfg = trainer.config
     k, b = cfg.steps_per_dispatch, cfg.pairs_per_batch
     zeros = np.zeros((2, k), np.float32)
@@ -520,8 +561,9 @@ def test_steps_without_the_new_parts_lower_to_the_parents_text(cell_name, kind_n
         staged = put_global(trainer._chunk_shardings,
                             {"pairs": np.zeros((k, 2, b), trainer._pair_dtype)})
         meta, base = trainer._stage_dispatch_meta(zeros, 0)
-        args = (staged, meta, base, trainer._table_prob, trainer._table_alias,
-                *trainer._step_extra)
+        # the sampler's tables (none under hierarchical softmax), then the row
+        # table's or the path table's arrays
+        args = (staged, meta, base, *trainer._sampler_args, *trainer._step_extra)
     text = getattr(trainer, twin).lower(trainer.params, *args).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         PARENT_STEP_TEXT[cell_name, kind_name, twin]

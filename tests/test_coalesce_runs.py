@@ -34,12 +34,14 @@ from glint_word2vec_tpu.ops.sgns import (
 from glint_word2vec_tpu.train import trainer as trainer_mod
 from glint_word2vec_tpu.train.trainer import (
     _CONTEXT_MAX_RUN,
+    _TOKEN_MAX_RUN,
     Trainer,
     _WORD_MAX_RUN,
     _center_run_cap,
     _context_run_cap,
     _slot_cap,
     _tail_cap,
+    _token_run_caps,
     _word_cap,
     _word_pieces,
 )
@@ -412,6 +414,45 @@ def case_context_update_clip_and_duplicate_scaling(dtype):
         assert float(m1.syn1_rows) == _ctx_heads(x)
 
 
+def _kept_against_add_at(x, dtype, cap):
+    """The helper with ``keep`` (ops/cbow_banded.py ``token_runs``, syn1's
+    side): the entries it leaves out carry zero rows by construction."""
+    idx = jnp.asarray(x, jnp.int32)
+    keep = jnp.asarray(np.random.default_rng(13).random(B) < 0.8)
+    rows = _rows(dtype) * keep[:, None].astype(dtype)
+    got, handed = jax.jit(scatter_add_by_runs, static_argnums=(3, 4, 5))(
+        jnp.zeros((V, D), jnp.float32), idx, rows, CTX_RUN, cap, True, keep)
+    return idx, keep, rows, got, int(handed)
+
+
+def case_kept_entries_against_add_at(dtype):
+    _, x = feed_batches(1)[0]
+    idx, keep, rows, got, handed = _kept_against_add_at(x, dtype, CTX_CAP)
+    # the runs of the kept entries alone, fewer than the batch's
+    assert handed == _ctx_heads(x[np.asarray(keep)]) < _ctx_heads(x)
+    want = _add_at(idx, rows)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-6,
+                               atol=2e-6 * np.abs(want).max())
+
+
+def case_kept_entries_over_cap_bit_equal(dtype):
+    _, x = feed_batches(1)[0]
+    heads = _ctx_heads(x[np.asarray(np.random.default_rng(13).random(B) < 0.8)])
+    idx, keep, rows, got, handed = _kept_against_add_at(x, dtype, heads - 1)
+    assert handed == B
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        np.asarray(jnp.zeros((V, D), jnp.float32).at[idx].add(rows.astype(jnp.float32))))
+
+
+def case_nothing_kept_hands_over_nothing(dtype):
+    idx = jnp.asarray(feed_batches(1)[0][1], jnp.int32)
+    got, handed = jax.jit(scatter_add_by_runs, static_argnums=(3, 4, 5))(
+        jnp.ones((V, D), jnp.float32), idx, jnp.zeros((B, D), dtype), CTX_RUN,
+        CTX_CAP, True, jnp.zeros(B, bool))
+    assert int(handed) == 0 and (np.asarray(got) == 1.0).all()
+
+
 def _fit_with_and_without(dtype, cap_fn, arg):
     """Through Trainer.fit: both twins coalesce, compile once, report the share
     on the heartbeat's device_block span as ``arg``, and train what the step
@@ -653,3 +694,44 @@ def test_context_cap_is_derived_from_the_counts():
     assert _context_run_cap(flat, int(flat.sum()), 0.0, 2, B) == 0
     assert _context_run_cap(COUNTS, total, 0.0, 1, B) == 0
     assert _context_run_cap(COUNTS, total, 0.0, WINDOW, 8) == 0
+
+
+def test_token_caps_are_derived_from_the_counts():
+    """The banded CBOW block's rule at ``cbow-3m-300``'s shape: the benchmark's
+    Zipf counts, the AUTO subsample the trainer resolves there (6.606e-4) and
+    a block of 65,536 + 2 x 5 kept tokens, every slot a token of its own and
+    four fifths of them an example (window 5)."""
+    from harness import zipf
+
+    tokens = 65536 + 2 * 5
+    counts = zipf.zipf_counts(3_000_000).astype(np.int64)
+    total = int(counts.sum())
+    estimates = [trainer_mod._expected_heads(counts, total, 6.606e-4, n, n, _TOKEN_MAX_RUN)
+                 for n in (tokens, 0.8 * tokens)]
+    # 550 feed blocks of five seeds hold 34,555-35,246 pieces at a run length
+    # of 6 (30,314-31,132 distinct words), 28,187-28,851 of them of slots that
+    # train an example: my CPU count, PERF.md section 6, PR 46
+    assert _TOKEN_MAX_RUN == 6
+    assert 35_250 < estimates[0] < 35_450 and 28_950 < estimates[1] < 29_150
+    caps = _token_run_caps(counts, total, 6.606e-4, tokens, 5)
+    # 15% of room to the nearest 32nd of the block, one program for every
+    # seed: at least 12% above the largest block counted, and far under the
+    # break-even (0.75 of the block)
+    assert caps == (40960, 32768) == (20 * (tokens // 32), 16 * (tokens // 32))
+    assert caps[0] >= 1.12 * 35_246 and caps[1] >= 1.12 * 28_851
+    # the subsample a little off (another corpus size) moves the estimates by
+    # under half a unit: the same program
+    assert {_token_run_caps(counts, total, s, tokens, 5) for s in (6e-4, 8e-4)} == {caps}
+    # cc.en.300's 2M words: fewer distinct words a block, a unit fewer for syn0
+    counts2 = zipf.zipf_counts(2_000_000).astype(np.int64)
+    assert _token_run_caps(counts2, int(counts2.sum()), 6.606e-4, tokens, 5) == (38912, 32768)
+    # a flat vocabulary: every token another word, nothing to sum, not built
+    flat = np.full(1_000_000, 5, np.int64)
+    assert _token_run_caps(flat, int(flat.sum()), 0.0, tokens, 5) == (0, 0)
+    # a vocabulary subsampling keeps nothing of, or a block of a few tokens
+    assert _token_run_caps(np.zeros(8, np.int64), 0, 0.0, tokens, 5) == (0, 0)
+    assert _token_run_caps(counts, total, 6.606e-4, 16, 5) == (0, 0)
+    # the harness's tiny sizes build them too (a rehearsal runs the branches)
+    tiny = zipf.zipf_counts(20_000).astype(np.int64)
+    cap0, cap1 = _token_run_caps(tiny, int(tiny.sum()), 1e-3, 2048 + 10, 5)
+    assert 0 < cap1 < cap0 <= 0.75 * 2058

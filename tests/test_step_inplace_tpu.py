@@ -37,24 +37,18 @@ no whole form beside it). The loop READS syn0 before the scatter's conditional
 writes it, its result orders the two, and neither copies the table; the tail
 tokens' row ids are read by a gather and not by a loop of slices, an iteration
 a token.
-That the plain banded step and the subword skip-gram step are the programs
-they were is held where it is cheap, on their lowered text
-(``tests/test_cbow_subword.py``).
+That the subword skip-gram step is the program it was is held where it is
+cheap, on its lowered text (``tests/test_cbow_subword.py``).
 
-The sixth (PR 37) is the hierarchical-softmax step at ``skipgram-hs-3m-300``'s
-size: syn1 (the tree's nodes) is read under one conditional (the word pieces'
-paths gathered once, or every pair's in chunks) and written under a second
-that the first's results order after it. One conditional holding a form's reads
-AND its writes copied the table in and out of the per-pair branch's scatter
-loop, once an iteration of 128 (two ``copy f32[3000000,384]`` in the loop's
-body, my compile for the described v5e, PR 37).
-
-The seventh (PR 45) is the query scan over ``sgns-nn-10m-300-x4``'s table:
-f32[10000000,300] partitioned by rows over the four described chips, ONE
-program under ``shard_map``. A chip's f32[2500000,300] shard is neither copied
-(the owner's reads are slices, no gather) nor gathered; the only all-reduce is
-the [Q, 300] query block's and the only all-gathers the [Q, 11] candidates';
-the one large temporary is a shard's own [Q, 2,500,096] score block.
+The plain banded step at ``cbow-3m-300``'s size (PR 46) has a row of its own:
+each token scatter goes through a conditional of its own on the block's tokens
+sorted inside the step (``token_runs``: syn0's by every slot's token, syn1's
+by the tokens of the slots that train an example; the sorts lie outside the
+conditionals, the heads' compaction inside each coalesced branch). syn0 is
+read by the tokens' gather and syn1 by the tokens' and the pool rows' gathers
+before them, and neither table is copied: one scatter into its table in each
+branch, and the pool rows' after syn1's. With the token row source syn1's
+conditional stands beside the lists'.
 """
 
 import os
@@ -161,6 +155,74 @@ def test_no_table_is_copied_with_the_subword_row_source(one_chip, with_metrics):
             <= parent_temporaries[with_metrics])
 
 
+# what the trainer derives for a block of 65,546 kept tokens at V = 3M: run
+# length, syn0's cap, syn1's (tests/test_coalesce_runs.py holds the derivation;
+# at V = 2M syn0's would be a unit less, and the token row source takes its place)
+TOKEN_RUNS = (6, 40960, 32768)
+
+
+def _branches(compiled: str):
+    """The two branch computations' names of each conditional, in text order."""
+    return re.findall(r"conditional\(.*branch_computations=\{%([\w.]+), %([\w.]+)\}",
+                      compiled)
+
+
+@pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
+def test_no_table_is_copied_in_the_plain_banded_step(one_chip, with_metrics):
+    from glint_word2vec_tpu.ops.cbow_banded import cbow_step_banded_core
+
+    tokens, window = 65546, 5
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def chunk(params, toks, left, right, center, negatives, alphas):
+        def body(p, xs):
+            tk, l, r, c, n, a = xs
+            return cbow_step_banded_core(
+                p, tk, l, r, c, jnp.ones(tokens, jnp.float32), n, a, 5, window,
+                "exact", jnp.bfloat16, jnp.bfloat16, with_metrics,
+                token_runs=TOKEN_RUNS)
+        return jax.lax.scan(body, params, (toks, left, right, center, negatives, alphas))
+
+    block, table = spec((K, tokens), jnp.int32), spec((V, D), jnp.float32)
+    program = jax.jit(chunk, donate_argnums=(0,)).lower(
+        EmbeddingPair(table, table), block, block, block,
+        spec((K, tokens), jnp.float32), spec((K, P), jnp.int32),
+        spec((K,), jnp.float32)).compile()
+    compiled = program.as_text()
+    copies = [line.strip()[:120] for line in compiled.splitlines()
+              if re.search(rf"= f32\[{V},{D}\]\S* copy\(", line)]
+    assert not copies, copies
+    # a conditional a table
+    conditionals = _branches(compiled)
+    assert len(conditionals) == 2
+    caps = []
+    for branches in conditionals:
+        texts = [_computation(compiled, name) for name in branches]
+        # one scatter into the table in each branch (inside a branch it is a
+        # fusion that gives the table back)
+        assert [len(re.findall(rf"= f32\[{V},{D}\]\S* fusion\(", t)) for t in texts] == [1, 1]
+        # the tokens are sorted before the conditional; the coalesced branch
+        # compacts its heads by a sort of its own and hands the scatter its
+        # cap's rows, the other the block's
+        sorts = [t.count(" sort(") for t in texts]
+        assert sorted(sorts) == [0, 1], sorts
+        coalesced = texts[sorts.index(1)]
+        caps += [cap for cap in TOKEN_RUNS[1:] if f"f32[{cap},{D}]" in coalesced]
+        assert f"f32[{tokens},{D}]" in texts[sorts.index(0)]
+    assert sorted(caps) == sorted(TOKEN_RUNS[1:]), caps
+    # the two stable sorts that carry the positions (by token, and by token
+    # with the slots that train nothing sent last) and the two compactions
+    assert compiled.count(" sort(") == 4
+    # syn0's two, syn1's two and the pool rows'
+    assert len(re.findall(rf"= f32\[{V},{D}\]\S* scatter\(", compiled)) == 5
+    # what the conditionals add to the parent's temporaries (444.5 / 427.2 MB
+    # with token_runs=None: my compile for the described v5e, PR 46): the
+    # sorts' s32[65546] arrays, and nothing [T, D] wide that outlives a branch
+    assert program.memory_analysis().temp_size_in_bytes < 460_000_000
+
+
 @pytest.mark.parametrize("tail_cap", [0, 4096], ids=["slots_cut", "both_capacities"])
 @pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
 def test_no_table_is_copied_with_token_lists_and_position_weights(one_chip, with_metrics,
@@ -178,9 +240,10 @@ def test_no_table_is_copied_with_token_lists_and_position_weights(one_chip, with
     # temp_size_in_bytes of the same compile with slot_cap=0, the parent's form
     # (my compile for the described v5e, PR 36), and what the conditional adds
     # whatever the capacity (1,048,736 and 360,448 read the same): 21.7 MB of
-    # 1.83 GB, five s32[1048736] arrays
+    # 1.83 GB, five s32[1048736] arrays; syn1's conditional (PR 46) adds its
+    # sorts' s32[65546] arrays
     parent_temporaries = {True: 1_833_126_400, False: 1_833_384_448}
-    conditional_adds = 22 << 20
+    conditional_adds = 24 << 20
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -191,7 +254,7 @@ def test_no_table_is_copied_with_token_lists_and_position_weights(one_chip, with
             return cbow_step_banded_core(
                 p, tk, l, r, c, jnp.ones(tokens, jnp.float32), n, a, 10, window,
                 "exact", jnp.bfloat16, jnp.bfloat16, with_metrics,
-                subword=(table, shape))
+                subword=(table, shape), token_runs=(6, 38912, 32768))
         return jax.lax.scan(body, params, (toks, left, right, center, negatives, alphas))
 
     block = spec((K, tokens), jnp.int32)
@@ -203,9 +266,11 @@ def test_no_table_is_copied_with_token_lists_and_position_weights(one_chip, with
         block, block, block, spec((K, tokens), jnp.float32), spec((K, 4096), jnp.int32),
         spec((K,), jnp.float32)).compile()
     compiled = program.as_text()
-    # the lists' scatter, and nothing else: the head capacity is known while
-    # tracing, and the tail capacity builds a loop, not a branch
-    assert compiled.count(" conditional(") == 1
+    # the lists' scatter and (PR 46) syn1's token scatter, and nothing else:
+    # the head capacity is known while tracing, and the tail capacity builds a
+    # loop, not a branch
+    conditionals = _branches(compiled)
+    assert len(conditionals) == 2
     copies = [line.strip()[:120] for line in compiled.splitlines()
               if re.search(rf"= f32\[({rows0}|{words}),{D}\]\S* copy\(", line)]
     assert not copies, copies
@@ -214,20 +279,24 @@ def test_no_table_is_copied_with_token_lists_and_position_weights(one_chip, with
         return len(re.findall(rf"= f32\[{rows},{D}\]\S* scatter\(", compiled))
 
     # the lists reach syn0's scatter once a step, in either branch; syn1 takes
-    # the centers' rows and the pool's
-    assert (scatters(rows0), scatters(words)) == (2, 2)
+    # the centers' rows (in either branch of its own conditional) and the pool's
+    assert (scatters(rows0), scatters(words)) == (2, 3)
+    lists, = [b for b in conditionals
+              if f"f32[{rows0},{D}]" in _computation(compiled, b[0])]
+    syn1s, = [b for b in conditionals if b != lists]
     # the step's own sort of the (row, slot) keys is the cut branch's: a branch
     # is a computation of its own, and one of the two holds no stable sort (the
     # whole form leaves its indices to XLA, which sorts them its own way)
-    branches = re.search(r"conditional\(.*branch_computations=\{%([\w.]+), %([\w.]+)\}",
-                         compiled).groups()
     own = [bool(re.search(r" sort\([^\n]*is_stable=true", _computation(compiled, name)))
-           for name in branches]
+           for name in lists]
     assert sorted(own) == [False, True], own
     # and hands the scatter the capacity's slots (their update rows are read
     # in sorted order inside the scatter's own fusion: no [393216, D] block)
-    assert "s32[393216]" in _computation(compiled, branches[own.index(True)])
-    assert "s32[393216]" not in _computation(compiled, branches[own.index(False)])
+    assert "s32[393216]" in _computation(compiled, lists[own.index(True)])
+    assert "s32[393216]" not in _computation(compiled, lists[own.index(False)])
+    # syn1's: the cap's rows in the coalesced branch, the block's in the other
+    texts = [_computation(compiled, name) for name in syn1s]
+    assert sorted(f"f32[32768,{D}]" in t for t in texts) == [False, True]
     assert (program.memory_analysis().temp_size_in_bytes
             <= parent_temporaries[with_metrics] + conditional_adds)
     # the scan, and under a tail capacity the loop over the passes of the tail

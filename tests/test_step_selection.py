@@ -1,8 +1,8 @@
 """The step selection matrix (train/trainer.select_step): which ops-level core
 one configuration trains with, the shape of the negatives its chunk draws and
 whether syn0's update goes to the scatter by center runs and syn1's by context
-runs — every row the function can return, both twins, without building a
-Trainer."""
+runs (the banded CBOW row: both by runs of the block's tokens) — every row the
+function can return, both twins, without building a Trainer."""
 
 import collections
 import inspect
@@ -20,6 +20,7 @@ from glint_word2vec_tpu.parallel.mesh import make_mesh
 from glint_word2vec_tpu.train import trainer as trainer_mod
 from glint_word2vec_tpu.train.trainer import (
     _CONTEXT_MAX_RUN,
+    _TOKEN_MAX_RUN,
     _center_run_cap,
     select_step,
 )
@@ -37,6 +38,9 @@ RUNS_W5 = (10, _center_run_cap(5, B))
 # what a trainer derived from its vocabulary (_context_run_cap); 0 = nothing
 CONTEXT_CAP = 24
 BY_CONTEXT = (_CONTEXT_MAX_RUN, CONTEXT_CAP)
+# the same for a banded CBOW block's tokens (_token_run_caps: syn0's, syn1's)
+TOKEN_CAPS = (44, 36)
+BY_TOKEN = (_TOKEN_MAX_RUN, *TOKEN_CAPS)
 
 # where each core is looked up when select_step runs
 CORE_HOME = {
@@ -49,7 +53,8 @@ CORE_HOME = {
 }
 
 # id: (config beside BASE, mesh, feed_segments)
-#     -> (core, negatives, center_runs[, context_runs: None where left out])
+#     -> (core, negatives, center_runs[, context_runs[, token_runs]]: None
+#        where left out)
 ROWS = {
     "sgns-shared-gspmd-runs": (
         dict(negative_pool=P, window=5), (1, 1), 1,
@@ -85,9 +90,15 @@ ROWS = {
     "sgns-per-pair": (
         dict(negative_pool=0, window=5), (1, 1), 1,
         ("sgns_step_core", PER_EXAMPLE, None)),
-    "cbow-banded": (
+    "cbow-banded": (   # both token scatters by runs of the block's tokens
         dict(cbow=True, cbow_update="banded", negative_pool=P, window=5),
-        (1, 1), 1, ("cbow_step_banded_core", POOL, None)),
+        (1, 1), 1, ("cbow_step_banded_core", POOL, None, None, BY_TOKEN)),
+    "cbow-banded-model-axis": (   # rows over 1x4: the block is still whole
+        dict(cbow=True, cbow_update="banded", negative_pool=P, window=5),
+        (1, 4), 1, ("cbow_step_banded_core", POOL, None, None, BY_TOKEN)),
+    "cbow-banded-data-axis": (   # a block a data shard: no program sorts it whole
+        dict(cbow=True, cbow_update="banded", negative_pool=P, window=5),
+        (2, 4), 1, ("cbow_step_banded_core", POOL, None)),
     "cbow-scatter-shared": (
         dict(cbow=True, negative_pool=P, window=5), (1, 1), 1,
         ("cbow_step_shared_core", POOL, None)),
@@ -138,18 +149,19 @@ def cores(monkeypatch):
 @pytest.mark.parametrize("row", list(ROWS))
 def test_step_selection(row, with_metrics, cores):
     calls, stubs = cores
-    kw, mesh, segments, (core, negatives, runs, *by_context) = ROWS[row]
-    by_context = by_context[0] if by_context else None
+    kw, mesh, segments, (core, negatives, runs, *more) = ROWS[row]
+    by_context, by_token = (more + [None, None])[:2]
     cfg = Word2VecConfig(**BASE, **kw)
     stab = Stabilizers(update_clip=0.5)
 
     choice = select_step(cfg, make_mesh(*mesh), segments, CONTEXT_CAP, stab,
-                         with_metrics)
+                         with_metrics, token_caps=TOKEN_CAPS)
 
     assert choice.core is stubs[core]
     assert choice.neg_shape(K, B) == negatives
     assert choice.center_runs == runs
     assert choice.context_runs == by_context
+    assert choice.token_runs == by_token
     assert choice.step("params", _Batch(), "negatives", "alpha") == "out"
     # the chosen core ran, once, and no other
     name, bound = calls[0]
@@ -163,6 +175,8 @@ def test_step_selection(row, with_metrics, cores):
         assert bound["center_runs"] == runs
         assert bound["context_runs"] == by_context
         assert bound["duplicate_scaling"] == cfg.duplicate_scaling
+    if core == "cbow_step_banded_core":
+        assert bound["token_runs"] == by_token
     if core == "make_shard_map_sgns_step":
         assert bound["sync_every"] == cfg.sync_every
         assert calls[1] == ("shard_map_step",
@@ -206,6 +220,15 @@ def test_context_runs_need_a_cap():
     cfg = Word2VecConfig(**BASE, negative_pool=P, window=5)
     choice = select_step(cfg, make_mesh(1, 1), 1, 0, None, True)
     assert choice.context_runs is None and choice.center_runs == RUNS_W5
+
+
+def test_token_runs_need_a_cap():
+    """A vocabulary whose estimate passes 0.75 of the block (_token_run_caps
+    gives (0, 0): the default of ``token_caps``) builds no token coalescing."""
+    cfg = Word2VecConfig(**BASE, cbow=True, cbow_update="banded", negative_pool=P,
+                         window=5)
+    choice = select_step(cfg, make_mesh(1, 1), 1, 0, None, True)
+    assert choice.token_runs is None and choice.center_runs is None
 
 
 COLLECTIVE = re.compile(r"\b(all-reduce|all-gather|reduce-scatter|all-to-all|"
