@@ -277,9 +277,10 @@ class StepMetrics(NamedTuple):
     # rows of the centers' subword lists that reached syn0's scatter with a
     # live index (config.subword; ops/subword.py); None = not a subword step
     subword_rows: Optional[jax.Array] = None
-    # slots a CBOW token block's list scatter was handed (ops/subword.py
-    # scatter_slots: the slot capacity, or every slot of the block); None
-    # everywhere but the banded subword step
+    # slots the subword lists' scatter was handed (ops/subword.py
+    # scatter_slots: the slot capacity, or every slot of the heads' block: a
+    # CBOW token block's, the word heads' of a skip-gram batch); None = not a
+    # subword step
     subword_slots: Optional[jax.Array] = None
     # slots the same block's list gather was handed (ops/subword.py
     # gather_slots: every token's first group and the tail capacity's later
@@ -764,7 +765,7 @@ def sgns_step_shared_core(
         d_pos = clip_update_rows(d_pos, stabilizers.update_clip)
 
     dtype = syn0.dtype
-    subword_rows = None
+    subword_rows = subword_slots = None
     with jax.named_scope("sgns.scatter_syn0"):
         if subword is not None:
             new_syn0 = sw.scatter_center_updates(
@@ -772,6 +773,7 @@ def sgns_step_shared_core(
             syn0_rows = jnp.where(sw_plan.fits, sw_plan.heads,
                                   centers.shape[0]).astype(jnp.float32)
             subword_rows = sw_plan.live_rows
+            subword_slots = sw.scatter_slots(sw_plan, sw_shape)
         elif center_runs is None:
             new_syn0 = syn0.at[centers].add(d_in.astype(dtype))
             syn0_rows = jnp.float32(centers.shape[0])
@@ -816,6 +818,7 @@ def sgns_step_shared_core(
         syn0_rows=syn0_rows,
         syn1_rows=syn1_rows,
         subword_rows=subword_rows,
+        subword_slots=subword_slots,
     )
     return EmbeddingPair(new_syn0, new_syn1), metrics
 

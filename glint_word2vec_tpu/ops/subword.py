@@ -40,8 +40,20 @@ and 66.5 for the flat one (58.3 with no room in its capacity), where ISSUE
 31 asked the flat form to come in under half before its index work (a
 search over group ends, a second run sum, a second overflow case) was worth
 having (PERF.md §6, PR 31: XLA sorts this form's scatter indices, so its
-padding rows cost it little). Fewer word heads make the flat form's capacity
-smaller too: it is next (ROADMAP A11 (c)).
+padding rows cost it little). What was built of it instead (PR 47) is the
+flat form's capacity without its index work: the padded block's 491,520 slots
+are sorted by row inside the step anyway, NO_ROW sorts last, so the ~241,000
+live ones are a prefix, and the scatter takes the first ``slot_cap`` = 278,528
+of them (the trainer derives it from the counts and the lists' lengths:
+train/trainer.py ``_word_slot_cap``; 2,000 feed batches hold 235,178-247,787
+live). On the chip a step reads 52.31 ms whole, 48.54 cut (17.7 ns a padding
+slot gone; 48.70 at 286,720) and 48.29 with the cut slots scattered WITHOUT
+``indices_are_sorted``, which is what runs: under an eighth of the table's
+rows XLA takes every slot at one price, and with the padding gone that price
+is the lower (PERF.md §6, PR 47). A batch with more live slots than the
+capacity takes the whole form, the fourth entry of the scatter's switch: a
+conditional or a loop of passes inside the per-word entry made the TPU's
+compiler copy the table in the plain entry's scan, 6.46 GB it has no room for.
 
 Three forms, chosen by the step from its own batch. More word pieces than
 ``word_cap`` (a batch whose centers hardly repeat): the per-RUN form, a run
@@ -50,7 +62,9 @@ center runs than ``head_cap`` (centers that all differ): the plain form,
 every pair its own list, in chunks of pairs under ``lax.map`` /
 ``lax.scan``, so that no [B, G, D] block is ever made. Same sums, same rows.
 ``word_cap`` 0 (the trainer's rule found no saving; a CBOW token block)
-builds the last two alone, as before there were three.
+builds the last two alone, as before there were three. The per-word form's
+scatter has two entries where ``slot_cap`` is set beside ``word_cap``: cut to
+the capacity, and whole for a batch over it.
 
 A CBOW token block (ops/cbow_banded.py; ``max_run`` 1 with room for every
 token slot, so the per-run form alone is built) has no runs and no word
@@ -117,8 +131,10 @@ class SubwordShape(NamedTuple):
     head_cap: int        # center runs of a batch the per-run form holds
     word_run: int = 1    # a word's run heads are cut every word_run into pieces
     word_cap: int = 0    # word pieces of a batch the per-word form holds; 0: not built
-    # slots the per-run form's scatter takes, sorted by row and cut to their
-    # live prefix; 0: not built (the trainer sets it for a CBOW token block)
+    # slots the list scatter takes, sorted by row and cut to their live
+    # prefix: of the word heads' block where word_cap is set (the subword
+    # skip-gram step), else of the run heads' (a CBOW token block); 0: not
+    # built. The trainer derives either from the counts and the lists' lengths
     slot_cap: int = 0
     # heads whose later groups a pass of the per-run form's gather reads as a
     # second block, beside every head's first group; 0: not built, the gather
@@ -283,32 +299,48 @@ def _plan_by_word(centers, table, shape, dtype, pos, head, heads, src, word):
 
 
 def _either(plan: CenterPlan, shape: SubwordShape, n: int, per_word, per_run,
-            plain, syn0: jax.Array) -> jax.Array:
+            plain, syn0: jax.Array, per_word_cut=None) -> jax.Array:
     """``per_word`` where both levels of the plan fit their capacities,
     ``per_run`` where the runs alone do, else ``plain``; only the branches
-    that can run, where that is known while tracing."""
+    that can run, where that is known while tracing. ``per_word_cut``: in
+    ``per_word``'s place where the block's live slots fit ``slot_cap`` too."""
     if shape.max_run == 1 and shape.head_cap >= n:
         return per_run(syn0)
     if plan.words is None:
         return jax.lax.cond(plan.fits, per_run, plain, syn0)
-    return jax.lax.switch(plan.form, (plain, per_run, per_word), syn0)
+    forms, form = (plain, per_run, per_word), plan.form
+    if per_word_cut is not None:
+        # a fourth entry, not a choice inside the third: under a conditional
+        # nested there, or a loop of passes, the TPU's compiler copies the
+        # table in every pass of the plain form's scan (PERF.md §6, PR 47)
+        forms += (per_word_cut,)
+        form += ((form == PER_WORD) & _cut_holds(plan, shape)).astype(jnp.int32)
+    return jax.lax.switch(form, forms, syn0)
 
 
 def _cut_holds(plan: CenterPlan, shape: SubwordShape) -> jax.Array:
-    """bool: the live slots of the per-run form's block fit ``slot_cap``."""
+    """bool: the live slots of the block ``slot_cap`` is the capacity of (the
+    word heads' where the shape has a word level, else the run heads') fit
+    it."""
     return plan.live_rows <= shape.slot_cap
 
 
 def scatter_slots(plan: CenterPlan, shape: SubwordShape) -> jax.Array:
-    """float32: slots the list scatter of the per-run or the plain form is
-    handed, live or padding: ``slot_cap`` where the block's live slots fit
-    it, else every slot of the heads' block (of every pair's list, plain)."""
-    handed = jnp.float32(plan.rows.size)
+    """float32: slots the list scatter of the form the batch takes is handed,
+    live or padding: ``slot_cap`` where the live slots of the block it is the
+    capacity of fit it (the word heads', or the run heads' in a shape without
+    a word level), else every slot of that block; every slot of the run
+    heads' block beside a word level; every pair's list, plain."""
+    plain = jnp.float32(plan.pos.shape[0] * shape.max_groups * GROUP)
+    heads, slots = plan.rows.shape
+    handed = jnp.float32((shape.word_cap or heads) * slots)
     if shape.slot_cap:
         handed = jnp.where(_cut_holds(plan, shape),
                            jnp.float32(shape.slot_cap), handed)
-    return jnp.where(plan.fits, handed, jnp.float32(
-        plan.pos.shape[0] * shape.max_groups * GROUP))
+    if plan.form is None:
+        return jnp.where(plan.fits, handed, plain)
+    return jnp.select([plan.form == PER_WORD, plan.form == PER_RUN],
+                      [handed, jnp.float32(plan.rows.size)], plain)
 
 
 def gather_slots(plan: CenterPlan, shape: SubwordShape) -> jax.Array:
@@ -407,7 +439,7 @@ def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
             jnp.broadcast_to(d_h.astype(syn0.dtype)[:, None, :], rows.shape + (d,)),
             mode="drop")
 
-    def spread_sorted(syn0, rows, d_h, cut=None):
+    def spread_sorted(syn0, rows, d_h, cut=None, told=True):
         """:func:`spread` with the slots handed over sorted by row, each with
         its head's update row read in that order: what XLA's TPU scatter makes
         of a scatter of more update rows than an eighth of the table's rows,
@@ -415,16 +447,18 @@ def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
         cost 95 ns each, dropped or not, 46.8 ms; sorted, the padding sorts
         last and costs little, 32.1 ms, and no broadcast block is made).
         ``cut``: the first ``cut`` sorted slots alone, for a caller who knows
-        the live ones (NO_ROW sorts last) are no more."""
+        the live ones (NO_ROW sorts last) are no more. ``told`` False: the
+        scatter is not told that they are sorted (the sort still finds the
+        prefix): with the padding gone, at one price a slot."""
         keys, slot = jax.lax.sort(
             (rows.reshape(-1), jnp.arange(rows.size, dtype=jnp.int32)), num_keys=1)
         if cut is not None:
             keys, slot = keys[:cut], slot[:cut]
         return syn0.at[keys].add(
             d_h.astype(syn0.dtype)[slot // rows.shape[1]], mode="drop",
-            indices_are_sorted=True)
+            indices_are_sorted=told)
 
-    def per_word(syn0):
+    def per_word(syn0, cut=None):
         w, by = shape.word_cap, plan.words
         with jax.named_scope("subword.mean"):
             sums = run_sums(d_in, plan.pos, shape.max_run, acc)
@@ -433,14 +467,17 @@ def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
             sums = run_sums(sums[by.src], by.pos, shape.word_run, acc)
             d_h = sums[by.head] * plan.inv[:w, None]
         with jax.named_scope("subword.scatter"):
-            return spread_sorted(syn0, plan.rows[:w], d_h)
+            # the cut slots untold: 48.29 ms a step on the chip against 48.54
+            # told, and 52.31 for all 491,520 told (PERF.md §6, PR 47)
+            return spread_sorted(syn0, plan.rows[:w], d_h, cut, told=cut is None)
 
     def per_run(syn0):
         with jax.named_scope("subword.mean"):
             sums = run_sums(d_in, plan.pos, shape.max_run, acc)
             d_h = sums[plan.src] * plan.inv[:, None]
         with jax.named_scope("subword.scatter"):
-            if not shape.slot_cap:
+            # beside a word level the capacity is the word heads' block's
+            if not shape.slot_cap or shape.word_cap:
                 return spread(syn0, plan.rows, d_h)
             # a block with more live slots than the capacity takes the whole
             # form: same rows, same sums
@@ -459,7 +496,9 @@ def scatter_center_updates(syn0: jax.Array, centers: jax.Array, d_in: jax.Array,
         return jax.lax.scan(
             chunk, syn0, (centers.reshape(-1, c), d_in.reshape(-1, c, d)))[0]
 
-    return _either(plan, shape, centers.shape[0], per_word, per_run, plain, syn0)
+    cut = shape.slot_cap if shape.word_cap else 0
+    return _either(plan, shape, centers.shape[0], per_word, per_run, plain, syn0,
+                   per_word_cut=partial(per_word, cut=cut) if cut else None)
 
 
 def lane_padded(rows: jax.Array) -> jax.Array:

@@ -172,7 +172,7 @@ def _token_run_caps(counts: np.ndarray, train_words_count: int,
     slots that train an example, :func:`_cbow_examples_per_kept_token` of
     them. At V = 3M that reads 35,338 and 29,053 where 550 feed blocks of
     five seeds hold 34,555-35,246 and 28,187-28,851 pieces: 15% of room to
-    the NEAREST 32nd of the block (as :func:`_slot_cap` rounds and for its
+    the NEAREST 32nd of the block (as :func:`_nearest_units` rounds and for its
     reason: every seed compiles one program; a block's count moves ±1%),
     40,960 and 32,768 of 65,546. About half the rows, not a quarter as SGNS
     contexts: a coalesced scatter still wins far above
@@ -197,18 +197,28 @@ def _token_run_caps(counts: np.ndarray, train_words_count: int,
 _WORD_MAX_RUN = 8
 
 
-def _word_pieces(counts: np.ndarray, train_words_count: int,
-                 subsample_ratio: float, window: int, batch: int) -> float:
-    """Word pieces a batch's center runs are expected to make, sorted by word
-    and cut every :data:`_WORD_MAX_RUN` heads (:func:`_expected_heads`): a
-    batch holds one center run per kept token that emits a pair, ``batch`` ·
-    runs a pair of them (:func:`_center_run_cap` has the share), and a run
-    has one head."""
+def _word_pieces_by_word(counts: np.ndarray, train_words_count: int,
+                          subsample_ratio: float, window: int,
+                          batch: int) -> Optional[np.ndarray]:
+    """Word pieces a batch's center runs are expected to make, word by word
+    (float64 [V]; None where subsampling keeps no token), sorted by word and
+    cut every :data:`_WORD_MAX_RUN` heads (:func:`_heads_by_word`): a batch
+    holds one center run per kept token that emits a pair, ``batch`` · runs a
+    pair of them (:func:`_center_run_cap` has the share), and a run has one
+    head."""
+    p = _kept_token_distribution(counts, train_words_count, subsample_ratio)
     runs = batch * (_cbow_examples_per_kept_token(window)
                     / _pairs_per_kept_token(window))
-    heads = _expected_heads(counts, train_words_count, subsample_ratio,
-                            runs, runs, _WORD_MAX_RUN)
-    return float(batch) if heads is None else heads
+    return None if p is None else _heads_by_word(p, runs, runs, _WORD_MAX_RUN)
+
+
+def _word_pieces(counts: np.ndarray, train_words_count: int,
+                 subsample_ratio: float, window: int, batch: int) -> float:
+    """:func:`_word_pieces_by_word` summed; every pair its own piece where
+    subsampling keeps no token."""
+    pieces = _word_pieces_by_word(counts, train_words_count, subsample_ratio,
+                                  window, batch)
+    return float(batch) if pieces is None else float(pieces.sum())
 
 
 def _word_cap(counts: np.ndarray, train_words_count: int,
@@ -218,9 +228,10 @@ def _word_cap(counts: np.ndarray, train_words_count: int,
     (ops/subword.py: one list per distinct center word of the batch, not one
     per center run), 0 = do not build it. :func:`_word_pieces` with 20% of
     room, in 32nds of the batch: at wiki.en's shape (V = 2,519,370, Zipf
-    counts, the AUTO subsample) it reads 10,100 where feed batches hold
-    10,300-10,600 pieces (sentence ends clip windows, so a batch holds ~5%
-    more runs), 12,288 of 65,536. Over 0.8 of ``run_cap`` (a flat
+    counts, the AUTO subsample) it reads 10,100 where 2,000 feed batches of
+    five seeds hold 10,200-10,637 pieces (sentence ends clip windows, so a
+    batch holds ~4% more runs; their lists 235,178-247,787 live slots:
+    :func:`_word_slot_cap`), 12,288 of 65,536. Over 0.8 of ``run_cap`` (a flat
     distribution: every center another word) the second level saves nothing
     and is not built."""
     if not run_cap or batch < 32:
@@ -231,6 +242,29 @@ def _word_cap(counts: np.ndarray, train_words_count: int,
     return cap if cap <= 0.8 * run_cap else 0
 
 
+def _nearest_units(live: float, slots: int) -> Tuple[int, int]:
+    """``live`` expected live slots of a block of ``slots`` (32 or more) with
+    20% of room, to the NEAREST unit, and the unit: the power of two at or
+    under a 32nd of the block's slots, so 15-25% of room. Nearest and not up:
+    the estimate moves a fraction of a percent with the seed's strings, and
+    rounding up gives some seeds a unit more, another program (PERF.md §6,
+    PR 36)."""
+    unit = 1 << ((slots // 32).bit_length() - 1)
+    return int(1.2 * live / unit + 0.5) * unit, unit
+
+
+def _live_slot_cap(live: float, slots: int) -> int:
+    """Static capacity of a list scatter that sorts its block's ``slots`` by
+    row inside the step and takes the live prefix (ops/subword.py), 0 = do not
+    build it: :func:`_nearest_units` of the ``live`` ones expected; where the
+    cut saves under a fifth of the slots (a vocabulary whose lists are full)
+    it is not built."""
+    if slots < 32:
+        return 0
+    cap, _ = _nearest_units(live, slots)
+    return cap if cap <= 0.8 * slots else 0
+
+
 def _slot_cap(counts: np.ndarray, train_words_count: int,
               subsample_ratio: float, list_rows: np.ndarray, tokens: int,
               slots: int) -> int:
@@ -239,21 +273,40 @@ def _slot_cap(counts: np.ndarray, train_words_count: int,
     the step and cut to the live ones), 0 = do not build it. A block's live
     slots are its tokens' list lengths (``list_rows`` [V], the row table's
     own counts): ``tokens`` · Σ p_w · list_rows[w] over the kept-token
-    distribution, with 20% of room, to the NEAREST unit (the power of two at
-    or under a 32nd of the slots, so 15-25% of room). At cc.en.300's shape
+    distribution, through :func:`_live_slot_cap`. At cc.en.300's shape
     326,700-331,300 are expected over the benchmark's seeds where feed blocks
     hold 329,900-332,600: 393,216 = 12 units of 1,048,736 slots for every
     seed, where rounding UP gives 12 units to some seeds and 13 to others, two
-    programs 0.64 ms a step apart (PERF.md §6, PR 36). Where the cut saves
-    under a fifth of the slots (a vocabulary whose lists are full) it is not
-    built."""
+    programs 0.64 ms a step apart (PERF.md §6, PR 36)."""
     p = _kept_token_distribution(counts, train_words_count, subsample_ratio)
-    if p is None or slots < 32:
+    if p is None:
         return 0
     live = tokens * float(p @ np.asarray(list_rows, np.float64)[:p.shape[0]])
-    unit = 1 << ((slots // 32).bit_length() - 1)
-    cap = int(1.2 * live / unit + 0.5) * unit
-    return cap if cap <= 0.8 * slots else 0
+    return _live_slot_cap(live, slots)
+
+
+def _word_slot_cap(counts: np.ndarray, train_words_count: int,
+                   subsample_ratio: float, window: int, batch: int,
+                   list_rows: np.ndarray, slots: int) -> int:
+    """Static capacity of the per-word form's list scatter (ops/subword.py:
+    the ``slots`` = word_cap · max_groups · 8 slots of the word heads' block
+    sorted by row inside the step and cut to the live ones), 0 = do not
+    build it. A piece lists its word's rows once, so the block's live slots
+    are Σ pieces_w · list_rows[w] (:func:`_word_pieces_by_word`, the sum
+    :func:`_word_cap` takes; ``list_rows`` [V], the row table's own counts),
+    through :func:`_live_slot_cap`. At wiki.en's shape 231,800-233,000 are
+    expected over the benchmark's seeds, 278,528 = 34 units of 8,192 of the
+    block's 491,520 slots for every seed, where 2,000 feed batches of five
+    seeds hold 235,178-247,787 (medians 240,800-242,000: sentence ends clip
+    windows, so a batch holds ~4% more runs than the estimate, as
+    :func:`_word_cap` found of the pieces; my CPU count, PR 47): the largest
+    is 11% under the capacity, and a batch over it takes the whole form."""
+    pieces = _word_pieces_by_word(counts, train_words_count, subsample_ratio,
+                                  window, batch)
+    if pieces is None:
+        return 0
+    live = float(pieces @ np.asarray(list_rows, np.float64)[:pieces.shape[0]])
+    return _live_slot_cap(live, slots)
 
 
 def _tail_cap(counts: np.ndarray, train_words_count: int,
@@ -265,7 +318,7 @@ def _tail_cap(counts: np.ndarray, train_words_count: int,
     than a group holds (``list_rows`` [V], the row table's own counts):
     ``tokens`` · Σ p_w · [list_rows[w] > GROUP] over the kept-token
     distribution, with 20% of room, to the NEAREST unit (a 32nd of the block's
-    tokens, and at least one), as :func:`_slot_cap` rounds and for its reason:
+    tokens, and at least one), as :func:`_nearest_units` rounds and for its reason:
     every seed of the benchmark compiles one program. At cc.en.300's shape
     (n-grams of 5: a word of 10 letters or more) 3,306-3,317 are expected over
     the benchmark's seeds where feed blocks hold 3,107-3,505: 4,096 = two
@@ -303,7 +356,7 @@ def _hs_caps(counts: np.ndarray, train_words_count: int,
     a piece lists its word's path once, ``lengths[w]`` live slots (the path
     table's own counts). ``word_cap`` is the expected pieces with 20% of room
     in 32nds of the batch; ``slot_cap`` the expected live slots with 20% of
-    room, to the nearest unit as :func:`_slot_cap` rounds (the power of two at
+    room, to the nearest unit (:func:`_nearest_units`: the power of two at
     or under a 32nd of the block's slots). A piece count over half the batch
     (contexts that hardly repeat) saves too little to build, and a batch
     expected to hold no whole piece (a window wider than the batch) none."""
@@ -318,9 +371,8 @@ def _hs_caps(counts: np.ndarray, train_words_count: int,
     if word_cap > batch // 2 or slots < 32:
         return 0, 0
     live = float(pieces @ np.asarray(lengths, np.float64)[:p.shape[0]])
-    slot_unit = 1 << ((slots // 32).bit_length() - 1)
-    slot_cap = min(int(1.2 * live / slot_unit + 0.5) * slot_unit, slots)
-    return word_cap, max(slot_cap, slot_unit)
+    slot_cap, slot_unit = _nearest_units(live, slots)
+    return word_cap, max(min(slot_cap, slots), slot_unit)
 
 
 class StepChoice(NamedTuple):
@@ -1012,9 +1064,10 @@ class Trainer:
         the device: span ``vocab.subword_table``, its seconds kept in
         ``subword_table_time``; the step's shape (ops/subword.py) takes the
         center-run capacity the plain step has and, under it, the word
-        capacity :func:`_word_cap` derives from the counts; a CBOW token
-        block's takes the slot capacity :func:`_slot_cap` and the tail
-        capacity :func:`_tail_cap` derive from them."""
+        capacity :func:`_word_cap` derives from the counts and the slot
+        capacity :func:`_word_slot_cap` derives from them and the lists'
+        lengths; a CBOW token block's takes the slot capacity
+        :func:`_slot_cap` and the tail capacity :func:`_tail_cap` derive."""
         from glint_word2vec_tpu.data.subword import GROUP, build_subword_table
         from glint_word2vec_tpu.ops import subword as sw
         cfg = self.config
@@ -1047,12 +1100,15 @@ class Trainer:
                    if self.plan.num_data == 1 else 0)
             # and under them one head per distinct word of the batch's
             # centers, where the vocabulary's counts promise fewer
+            kept = (self.vocab.counts, self.vocab.train_words_count,
+                    cfg.subsample_ratio, cfg.window, cfg.pairs_per_batch)
+            word_cap = _word_cap(*kept, cap)
+            # and of that block's slots the live ones alone to the scatter
             self._subword_shape = sw.SubwordShape(
                 rows.max_groups, *((2 * cfg.window, cap) if cap
                                    else (1, cfg.pairs_per_batch)),
-                _WORD_MAX_RUN, _word_cap(
-                    self.vocab.counts, self.vocab.train_words_count,
-                    cfg.subsample_ratio, cfg.window, cfg.pairs_per_batch, cap))
+                _WORD_MAX_RUN, word_cap, slot_cap=_word_slot_cap(
+                    *kept, rows.counts, word_cap * rows.max_groups * GROUP))
         logger.info("subword table: %d words, %d slots, %s in %.2fs",
                     self.vocab.size, rows.slots, self._subword_shape,
                     self.subword_table_time)
@@ -2724,8 +2780,9 @@ class Trainer:
                     blocked.set(subword_rows_per_pair=float(
                         rows_sw_k[real - 1] / pairs_k[real - 1]))
                 if slots_sw_k is not None and pairs_k[real - 1] > 0:
-                    # slots a token block's list scatter was handed, live or
-                    # padding: the slot capacity's engagement counter
+                    # slots the lists' scatter was handed, live or padding
+                    # (a token block's, the word heads' of a skip-gram batch):
+                    # the slot capacity's engagement counter
                     blocked.set(subword_slots_per_pair=float(
                         slots_sw_k[real - 1] / pairs_k[real - 1]))
                 if gather_sw_k is not None and pairs_k[real - 1] > 0:

@@ -13,7 +13,13 @@ The third program is the subword step at ``subword-sgns-2.5m-300``'s size (PR
 31; PR 34: one list per distinct center word): syn0 is read by one conditional
 (the centers' listed rows: per word, per run or plain) and written by another,
 and neither may copy f32[4519376,384]; each branch scatters into syn0 once; the
-temporaries are no larger than with the per-run form alone.
+temporaries are no larger than with the per-run form alone. Since PR 47 the
+writing conditional has a fourth entry, the per-word form with its sorted slots
+cut to the trainer's slot capacity (278,528 of 491,520). An entry of the switch
+and not a conditional or a loop of passes inside the per-word entry: either of
+those made the compiler copy the table inside the PLAIN entry's scan, once
+before and once after each chunk's scatter, 6.46 GB of temporaries on a 16 GB
+chip (the compile is refused: PERF.md §6, PR 47).
 
 The fourth is no step at all: the health probe every heartbeat runs between two
 dispatches (obs/probe.py). Its p99 bucket used to come from a histogram built by
@@ -112,10 +118,11 @@ def test_no_table_is_copied_with_the_subword_row_source(one_chip, with_metrics):
     from glint_word2vec_tpu.ops.subword import SubwordShape, SubwordTable
 
     words, rows0, groups = 2_519_376, 4_519_376, 11 << 20
-    # what the trainer derives at this size (PERF.md §6, PR 31 and PR 34;
-    # tests/test_coalesce_runs.py holds the word cap's derivation)
+    # what the trainer derives at this size (PERF.md §6, PR 31, PR 34 and PR
+    # 47; tests/test_coalesce_runs.py holds the word cap's derivation,
+    # tests/test_subword.py the slot capacity's)
     shape = SubwordShape(max_groups=5, max_run=10, head_cap=24576,
-                         word_run=8, word_cap=12288)
+                         word_run=8, word_cap=12288, slot_cap=278528)
     # temp_size_in_bytes of the same compile with word_cap=0, the parent's
     # form (my compile for the described v5e, PR 34): the per-run branch's
     # [24576, 40, 384] float32 block is the largest of either program
@@ -146,8 +153,13 @@ def test_no_table_is_copied_with_the_subword_row_source(one_chip, with_metrics):
     copies = [line.strip()[:120] for line in compiled.splitlines()
               if re.search(rf"= f32\[({rows0}|{words}),{D}\]\S* copy\(", line)]
     assert not copies, copies
-    # one scatter into syn0 in each of the three branches, and nowhere else
-    assert len(re.findall(rf"= f32\[{rows0},{D}\]\S* scatter\(", compiled)) == 3
+    # one scatter into syn0 in each of the four branches (plain, per run, per
+    # word whole and cut to the slot capacity), and nowhere else
+    assert len(re.findall(rf"= f32\[{rows0},{D}\]\S* scatter\(", compiled)) == 4
+    # the cut entry's scatter takes the capacity's slots; no loop but the
+    # chunk's scan and the plain form's two
+    assert re.search(r"= f32\[278528,384\]\S* ", compiled)
+    assert compiled.count(" while(") == 3
     # the per-word form reads its heads' row ids alone: [12288 · 5, 8], inside
     # a branch, beside the per-run form's [24576 · 5, 8]
     assert re.search(r"= s32\[61440,8\]\S* fusion\(", compiled)

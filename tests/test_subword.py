@@ -169,6 +169,18 @@ def _word_pieces_of(centers, word_run):
     return words, -(-count // word_run)
 
 
+def _reference_lists(strings, centers, table):
+    """Every pair's center as the reference sees it: the rows ITS n-gram
+    function lists, [steps, B, longest] with the count."""
+    lists = np.zeros(centers.shape + (table.max_groups * GROUP,), np.int32)
+    nrows = np.zeros(centers.shape, np.int32)
+    for k in range(centers.shape[0]):
+        for i, w in enumerate(centers[k]):
+            rows = subword_ref.word_rows(strings[w], w, V, BUCKETS)
+            lists[k, i, :len(rows)], nrows[k, i] = rows, len(rows)
+    return jnp.asarray(lists), jnp.asarray(nrows)
+
+
 @pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
 @pytest.mark.parametrize("branch", list(BRANCHES))
 def test_step_follows_the_reference_on_feed_batches(branch, with_metrics):
@@ -200,14 +212,9 @@ def test_step_follows_the_reference_on_feed_batches(branch, with_metrics):
         losses.append(float(metrics.loss))
         handed.append((float(metrics.syn0_rows), float(metrics.subword_rows)))
 
-    lists = np.zeros((STEPS, B, table.max_groups * GROUP), np.int32)
-    nrows = np.zeros((STEPS, B), np.int32)
-    for k in range(STEPS):
-        for i, w in enumerate(centers[k]):
-            rows = subword_ref.word_rows(strings[w], w, V, BUCKETS)
-            lists[k, i, :len(rows)], nrows[k, i] = rows, len(rows)
+    lists, nrows = _reference_lists(strings, centers, table)
     ref = subword_ref.follow_steps(
-        syn0, syn1, jnp.asarray(lists), jnp.asarray(nrows), jnp.asarray(contexts),
+        syn0, syn1, lists, nrows, jnp.asarray(contexts),
         jnp.asarray(negatives), [0.05] * STEPS, NEG, np.arange(V + BUCKETS) < V)
     # float32 on both sides, sums in another order
     np.testing.assert_allclose(params.syn0, ref["syn0"], rtol=2e-5, atol=2e-7)
@@ -226,6 +233,174 @@ def test_step_follows_the_reference_on_feed_batches(branch, with_metrics):
         assert handed[0] == (heads.shape[0], table.counts[heads].sum())
     else:
         assert handed[0] == (B, table.counts[centers[0]].sum())
+
+
+def _live_slots(centers, table, word_run=8):
+    """Live slots of the per-word block of one batch: a piece lists its
+    word's rows once."""
+    words, pieces = _word_pieces_of(centers, word_run)
+    return int((table.counts[words] * pieces).sum())
+
+
+def _slot_capacity(table, centers, kind):
+    """A slot capacity of the per-word block [768, max_groups · 8] that every
+    one of the three batches is ``under``, every one is ``over`` (the whole
+    form), or that falls between two of them (``straddled``)."""
+    live = sorted(_live_slots(c, table) for c in centers)
+    assert live[0] < live[1]
+    cap = {"under": live[2] + 64, "over": live[0] - 64,
+           "straddled": (live[0] + live[1]) // 2}[kind]
+    assert cap < 768 * table.max_groups * GROUP
+    return cap
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+@pytest.mark.parametrize("kind", ["under", "over", "straddled"])
+def test_word_slot_capacity_gives_the_same_rows_and_sums(kind, dtype):
+    """The per-word form's scatter under ``slot_cap`` (PR 47): the block's
+    slots sorted by row and the first ``slot_cap`` of them scattered, a batch
+    with more live ones whole. Against the reference in the tables' own dtype
+    (a live slot the cut dropped, or a padding slot it kept, is a row's whole
+    update: a hundred thousand times what float64 holds), and bit for bit
+    against the form without a capacity: the cut is a prefix of the same
+    sorted order, and the slots it leaves out were out of bounds."""
+    strings, table, centers, contexts = _case()
+    cap = _slot_capacity(table, centers, kind)
+    rng = np.random.default_rng(17)
+    init0 = rng.uniform(-0.3, 0.3, (V + BUCKETS, D))
+    init1 = rng.uniform(-0.3, 0.3, (V, D))
+    negatives = rng.integers(0, V, (STEPS, P)).astype(np.int32)
+    # float64: 1 / |G| is still a float32 (plan_centers' default)
+    tol = dict(rtol=2e-5, atol=2e-7) if dtype == "float32" else dict(rtol=1e-7, atol=2e-8)
+
+    with jax.enable_x64(dtype == "float64"):
+        dt = jnp.dtype(dtype)
+        dev = SubwordTable(jnp.asarray(table.offsets), jnp.asarray(table.rows),
+                           jnp.asarray(table.counts))
+
+        def run(slot_cap):
+            shape = SubwordShape(table.max_groups, 10, 1024, 8, 768, slot_cap=slot_cap)
+
+            @jax.jit
+            def step(params, dev, c, x, n):
+                with jax.default_matmul_precision("highest"):
+                    return sgns_step_shared_core(
+                        params, c, x, jnp.ones(B, jnp.float32), n, jnp.float32(0.05),
+                        NEG, "exact", dt, context_runs=(6, B // 2),
+                        subword=(dev, shape))
+
+            params, slots = EmbeddingPair(jnp.asarray(init0, dt), jnp.asarray(init1, dt)), []
+            for k in range(STEPS):
+                params, metrics = step(params, dev, centers[k], contexts[k], negatives[k])
+                assert float(metrics.syn0_rows) == _word_pieces_of(centers[k], 8)[1].sum()
+                assert float(metrics.subword_rows) == _live_slots(centers[k], table)
+                slots.append(float(metrics.subword_slots))
+            return params, slots
+
+        got, slots = run(cap)
+        whole, whole_slots = run(0)
+        s0, s1 = jnp.asarray(init0, dt), jnp.asarray(init1, dt)
+        lists, nrows = _reference_lists(strings, centers, table)
+        for k in range(STEPS):
+            s0, s1, _ = subword_ref.subword_step(
+                s0, s1, lists[k], nrows[k], jnp.asarray(contexts[k]),
+                jnp.asarray(negatives[k]), jnp.float32(0.05), NEG)
+        np.testing.assert_allclose(got.syn0, s0, **tol)
+        np.testing.assert_allclose(got.syn1, s1, **tol)
+        np.testing.assert_array_equal(np.asarray(got.syn0), np.asarray(whole.syn0))
+        np.testing.assert_array_equal(np.asarray(got.syn1), np.asarray(whole.syn1))
+    # the counter says what the scatter was handed: the capacity, or every
+    # slot of the word heads' block (a batch over it; a shape without one)
+    block = 768 * table.max_groups * GROUP
+    fits = [_live_slots(c, table) <= cap for c in centers]
+    assert sorted(set(fits)) == {"under": [True], "over": [False],
+                                 "straddled": [False, True]}[kind]
+    assert slots == [cap if f else block for f in fits]
+    assert whole_slots == [block] * STEPS
+
+
+# seeds of the benchmark's runs, a large one among them
+@pytest.mark.parametrize("seed", [7, 2147483653])
+def test_word_slot_cap_is_derived_from_the_counts_and_the_lists(seed):
+    """The capacity of the per-word block's list scatter
+    (train/trainer.py ``_word_slot_cap``): at ``subword-sgns-2.5m-300``'s
+    counts, strings and resolved subsample every seed's strings give 34 units
+    of 8,192 of the block's 491,520 slots, 1.1 to 1.3 times what the pair
+    feed's batches hold; lists that fill their groups, a shape without a word
+    level and a vocabulary subsampling keeps nothing of build none."""
+    from harness import zipf
+
+    from glint_word2vec_tpu.data.pipeline import epoch_batches
+    from glint_word2vec_tpu.data.vocab import Vocabulary
+    from glint_word2vec_tpu.train.trainer import (
+        _center_run_cap, _word_cap, _word_pieces_by_word, _word_slot_cap)
+
+    v, b, window, ratio = 2_519_370, 65536, 5, 6.54e-4
+    counts = zipf.zipf_counts(v).astype(np.int64)
+    total = int(counts.sum())
+    kept = (counts, total, ratio, window, b)
+    strings = bench_words.make_words(seed, v)
+    table = build_subword_table(strings, 3, 6, 2_000_000)
+    word_cap = _word_cap(*kept, _center_run_cap(window, b))
+    slots = word_cap * table.max_groups * GROUP
+    cap = _word_slot_cap(*kept, table.counts, slots)
+    assert (word_cap, slots, cap) == (12288, 491_520, 34 * 8192)
+    expected = float(_word_pieces_by_word(*kept) @ table.counts[:v])
+    assert 1.15 * expected < cap < 1.25 * expected
+
+    vocab = Vocabulary.from_words_and_counts(strings, counts)
+    tokens = zipf.draw(np.random.default_rng(11), v, 1_200_000)
+    held = []
+    for batch in epoch_batches([tokens[i:i + 40] for i in range(0, tokens.shape[0], 40)],
+                               vocab, pairs_per_batch=b, window=window,
+                               subsample_ratio=ratio, seed=1, iteration=1):
+        if batch.num_real_pairs == b:
+            held.append(_live_slots(np.asarray(batch.centers), table))
+        if len(held) == 3:
+            break
+    assert len(held) == 3 and all(1.1 * live < cap < 1.3 * live for live in held), held
+
+    # a block of another size takes the same share of its slots
+    for n in (4096, 16384):
+        small_cap = _word_cap(counts, total, ratio, window, n, _center_run_cap(window, n))
+        small = _word_slot_cap(counts, total, ratio, window, n, table.counts,
+                               small_cap * 40)
+        assert 0 < small < 0.8 * small_cap * 40
+        assert small % (1 << ((small_cap * 40 // 32).bit_length() - 1)) == 0
+    # lists that fill their groups: the cut saves under a fifth, not built
+    assert _word_slot_cap(*kept, np.full(v + 1, 40, np.int32), slots) == 0
+    assert _word_slot_cap(*kept, np.full(v + 1, 36, np.int32), slots) == 0
+    assert _word_slot_cap(*kept, np.full(v + 1, 30, np.int32), slots) == 44 * 8192
+    # no word level (a data axis, a flat vocabulary), or nothing kept: not built
+    assert _word_slot_cap(*kept, table.counts, 0) == 0
+    assert _word_slot_cap(np.zeros(8, np.int64), 0, 0.0, window, b,
+                          np.ones(9, np.int32), 512) == 0
+
+
+def test_slot_capacity_beside_a_word_capacity_is_the_per_word_forms_alone():
+    """A batch over the word capacity takes the per-run form's plain scatter
+    whole, with or without a slot capacity in the shape (the capacity is the
+    per-word block's; the per-run form has one of its own only in a shape
+    without a word level, a CBOW token block's), and the counter reads the
+    run heads' block."""
+    _, table, centers, contexts = _case()
+    rng = np.random.default_rng(17)
+    syn0 = jnp.asarray(rng.uniform(-0.3, 0.3, (V + BUCKETS, D)), jnp.float32)
+    syn1 = jnp.asarray(rng.uniform(-0.3, 0.3, (V, D)), jnp.float32)
+    negatives = jnp.asarray(rng.integers(0, V, P), jnp.int32)
+    dev = SubwordTable(jnp.asarray(table.offsets), jnp.asarray(table.rows),
+                       jnp.asarray(table.counts))
+    out = {}
+    for slot_cap in (0, 64):
+        shape = SubwordShape(table.max_groups, 10, 1024, 8, 16, slot_cap=slot_cap)
+        out[slot_cap] = jax.jit(lambda p, dev, c, x, n: sgns_step_shared_core(
+            p, c, x, jnp.ones(B, jnp.float32), n, jnp.float32(0.05), NEG, "exact",
+            jnp.float32, subword=(dev, shape)))(
+            EmbeddingPair(syn0, syn1), dev, centers[0], contexts[0], negatives)
+    (with_cap, m), (without, _) = out[64], out[0]
+    np.testing.assert_array_equal(np.asarray(with_cap.syn0), np.asarray(without.syn0))
+    assert float(m.subword_slots) == 1024 * table.max_groups * GROUP
+    assert float(m.subword_rows) == table.counts[_run_head_words(centers[0])].sum()
 
 
 @pytest.mark.parametrize("branch", ["per_run", "per_word"])
