@@ -12,6 +12,9 @@ single-pass host-side counter. Multi-host corpora shard by file and merge counte
 from __future__ import annotations
 
 import collections
+import itertools
+import os
+import weakref
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, Iterator, List, Sequence
 
@@ -31,6 +34,9 @@ class Vocabulary:
     counts: np.ndarray  # int64 [vocab_size]
     index: Dict[str, int] = field(repr=False)
     train_words_count: int = 0
+    # the words' table in native/lookup.cpp, built at the first large lookup
+    _native: "_NativeTable" = field(default=None, init=False, repr=False,
+                                    compare=False)
 
     @property
     def size(self) -> int:
@@ -44,6 +50,50 @@ class Vocabulary:
 
     def get(self, word: str, default: int = -1) -> int:
         return self.index.get(word, default)
+
+    def lookup(self, tokens: Sequence[str]) -> np.ndarray:
+        """``int32[len(tokens)]``: every token's index, -1 for a token the
+        vocabulary lacks. The batch form of :meth:`get`, with no Python
+        statement a token, for callers that resolve a slide of sentences at
+        a time (``Word2VecModel.transform_sentences``: ~330,000 tokens a
+        call). A batch of :data:`NATIVE_LOOKUP_TOKENS` or more goes to the
+        native table (``native/lookup.cpp``, built from the words at the
+        first such batch): the tokens joined and encoded once, then looked
+        up OUTSIDE the interpreter lock, so callers on several threads
+        resolve their slides side by side (``dict.get`` mapped over the
+        list holds the lock for ~220 ns a token over 3M words: 73 ms a
+        slide, every caller in turn; PERF.md §6, PR 48). Smaller batches,
+        tokens that hold the separator or do not encode, and a host
+        without the toolchain take the mapped ``dict.get``: the same ids."""
+        if len(tokens) >= NATIVE_LOOKUP_TOKENS:
+            ids = self._native_lookup(tokens)
+            if ids is not None:
+                return ids
+        return np.fromiter(
+            map(self.index.get, tokens, itertools.repeat(-1)), np.int32,
+            count=len(tokens))
+
+    def _native_lookup(self, tokens: Sequence[str]):
+        """:meth:`lookup` through ``native/lookup.cpp``, or None where that
+        cannot answer for the dict."""
+        from glint_word2vec_tpu.data.native import default_threads
+        lib = _load_native()
+        if lib is None:
+            return None
+        try:
+            blob = _SEP.join(tokens).encode("utf-8")
+        except (TypeError, UnicodeEncodeError):
+            return None     # not all str, or a lone surrogate
+        table = self._native
+        if table is None:
+            table = self._native = _NativeTable(lib, self.words)
+        if table.handle is None:
+            return None
+        out = np.empty(len(tokens), np.int32)
+        done = lib.glint_lookup_tokens(
+            table.handle, blob, len(blob), _SEP.encode(), len(tokens),
+            out.ctypes.data, min(default_threads(), _LOOKUP_THREADS))
+        return out if done == len(tokens) else None   # a token holds the separator
 
     @classmethod
     def from_words_and_counts(cls, words: Sequence[str], counts: Sequence[int]) -> "Vocabulary":
@@ -67,6 +117,73 @@ class Vocabulary:
         index = {w: i for i, w in enumerate(words)}
         return cls(words=words, counts=counts, index=index,
                    train_words_count=int(counts.sum()))
+
+
+# tokens in a Vocabulary.lookup batch from which the native table answers:
+# under it the join and the call cost more than the mapped dict.get saves,
+# and a model that only looks up a few words never builds the table
+NATIVE_LOOKUP_TOKENS = 4096
+
+# threads one native lookup splits its tokens over, at most: four callers'
+# slides side by side read 540,000 sentences/s at 1, 622,000 at 2, 633,000 at
+# 4 and 618,000 at 8 on the chip's 13-core host (PERF.md §6, PR 48)
+_LOOKUP_THREADS = 4
+
+# what joins a batch's tokens for the native lookup; a token that holds it
+# sends the batch to the dict
+_SEP = "\n"
+
+_lib = None
+_lib_failed = False
+
+
+def _load_native():
+    """``native/lookup.cpp`` under data/native.py's build-on-first-use
+    contract, or None (``dict.get`` then answers every batch)."""
+    global _lib, _lib_failed
+    if _lib is not None or _lib_failed:
+        return _lib
+    import ctypes
+
+    from glint_word2vec_tpu.data.native import build_or_reload
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                       "native", "lookup.cpp")
+    lib = None
+    if not os.environ.get("GLINT_DISABLE_NATIVE"):
+        lib = build_or_reload(src, os.path.join(os.path.dirname(src), "liblookup"),
+                              "glint_lookup_abi_version", 1, "c++17", "lookup")
+    if lib is None:
+        _lib_failed = True
+        return None
+    lib.glint_lookup_build.restype = ctypes.c_void_p
+    lib.glint_lookup_build.argtypes = [
+        ctypes.c_char_p, ctypes.c_void_p, ctypes.c_int64]   # bytes, end, words
+    lib.glint_lookup_free.restype = None
+    lib.glint_lookup_free.argtypes = [ctypes.c_void_p]
+    lib.glint_lookup_tokens.restype = ctypes.c_int64
+    lib.glint_lookup_tokens.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64,   # table, buf, len
+        ctypes.c_char, ctypes.c_int64, ctypes.c_void_p,     # sep, tokens, out
+        ctypes.c_int32]                                     # threads
+    _lib = lib
+    return _lib
+
+
+class _NativeTable:
+    """One vocabulary's table in ``native/lookup.cpp``, word i at id i (of a
+    word the list holds twice the last position, as ``Vocabulary.index``
+    keeps it): ``handle`` (None where a word does not encode), freed when the
+    last reference to this object goes."""
+
+    def __init__(self, lib, words: List[str]):
+        self.handle = None
+        try:
+            encoded = [w.encode("utf-8") for w in words]
+        except UnicodeEncodeError:
+            return
+        end = np.cumsum(np.fromiter(map(len, encoded), np.int64, count=len(encoded)))
+        self.handle = lib.glint_lookup_build(b"".join(encoded), end.ctypes.data, len(encoded))
+        weakref.finalize(self, lib.glint_lookup_free, self.handle)
 
 
 def count_words(sentences: Iterable[Sequence[str]]) -> "collections.Counter[str]":

@@ -66,6 +66,12 @@ LOCK_TABLE = {
         "rank": 20, "kind": "lock",
         "site": "glint_word2vec_tpu/serve/reload.py:ServingHandle.__init__",
         "owner": "atomic (model, index) swap + lease counts (serve/reload.py)"},
+    "model.rows": {
+        "rank": 25, "kind": "lock",
+        "site": "glint_word2vec_tpu/models/word2vec.py:Word2VecModel.__init__",
+        "owner": "the row reads' whole-lane form of syn0 (built once, at the "
+                 "first transform / pull) and the count of transform slides "
+                 "in flight; a leaf: nothing is acquired under it"},
     "fleet.router": {
         "rank": 30, "kind": "lock",
         "site": "glint_word2vec_tpu/serve/fleet.py:FleetRouter.__init__",

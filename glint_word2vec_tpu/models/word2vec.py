@@ -18,6 +18,8 @@ on device.
 
 from __future__ import annotations
 
+import collections
+import itertools
 import logging
 import math
 import os
@@ -32,6 +34,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from glint_word2vec_tpu.config import Word2VecConfig
 from glint_word2vec_tpu.data.subword import NO_ROW as _NO_ROW
 from glint_word2vec_tpu.data.vocab import Vocabulary
+from glint_word2vec_tpu.lockcheck import make_lock
 from glint_word2vec_tpu.obs.spans import default_tracer
 from glint_word2vec_tpu.parallel.mesh import (
     MeshPlan, pad_dim_to_lanes, pad_vocab_for_sharding)
@@ -41,7 +44,15 @@ logger = logging.getLogger("glint_word2vec_tpu")
 
 
 class Word2VecModel:
-    """Trained word embeddings with the full reference model-op surface."""
+    """Trained word embeddings with the full reference model-op surface.
+
+    Resident on the device, until :meth:`stop`: syn0 ``[V, D]`` (a subword
+    model's composed table) and syn1 as they were handed over, the row norms
+    once a scan has asked for them, a subword model's own rows and bucket rows
+    (``_compose``), and, once ``transform_sentences``, ``transform_words`` or
+    ``pull`` has read rows, syn0 again with D widened to whole lanes of 128
+    (:meth:`_row_table`: 4.61 GB beside syn0's 3.60 at 3M x 300 float32; none
+    where D is a multiple of 128, or on a mesh)."""
 
     def __init__(
         self,
@@ -118,6 +129,11 @@ class Word2VecModel:
         self._norms: Optional[jax.Array] = None
         self._ann = None
         self._stopped = False
+        # the row reads' whole-lane form of syn0 (_row_table) and the transform
+        # slides whose result is not in yet, both under the one lock
+        self._lock = make_lock("model.rows")
+        self._lanes: Optional[jax.Array] = None
+        self._slides_inflight = 0
 
     def _compose(self, vocab: Vocabulary, config: Word2VecConfig, syn0,
                  buckets, subword_rows=None) -> jax.Array:
@@ -253,19 +269,16 @@ class Word2VecModel:
     def transform_words(self, words: Iterable[str], batch_size: int = 10_000
                         ) -> Iterator[np.ndarray]:
         """Batched word → vector stream (the reference's 10k-word batched iterator path,
-        mllib:529-546, noted there as the efficient variant)."""
+        mllib:529-546, noted there as the efficient variant). Raises ``KeyError``
+        naming the first word of a batch the vocabulary lacks."""
         self._check_alive()
         buf: List[str] = []
 
         def emit(buf: List[str]) -> Iterator[np.ndarray]:
-            idxs = []
-            for w in buf:
-                i = self.vocab.get(w)
-                if i < 0:
-                    raise KeyError(f"{w} not in vocabulary")
-                idxs.append(i)
-            rows = np.asarray(self.syn0[jnp.asarray(idxs, jnp.int32)])
-            yield from rows
+            ids = self.vocab.lookup(buf)
+            if (ids < 0).any():
+                raise KeyError(f"{buf[int(np.argmax(ids < 0))]} not in vocabulary")
+            yield from self._read_rows(ids)
 
         for w in words:
             buf.append(w)
@@ -279,54 +292,161 @@ class Word2VecModel:
         self, sentences: Sequence[Sequence[str]], batch_size: int = 10_000
     ) -> np.ndarray:
         """Sentence → mean of in-vocab word vectors (the ML transform semantics,
-        ml:428-460). OOV words are silently dropped (ml:451-452); a sentence with no
-        in-vocab words maps to the zero vector. Processed in fixed-size row batches like
-        the reference's 10k-row mapPartitions slides (ml:449-450)."""
+        ml:428-460): ``float32[len(sentences), D]``, rows in the order sent. OOV
+        words are dropped from sum and count alike (ml:451-452), repeats count,
+        and a sentence with no in-vocab word maps to the zero vector. Processed
+        in slides of ``batch_size`` sentences like the reference's 10k-row
+        mapPartitions slides (ml:449-450), each ONE device program of fixed
+        shapes (:func:`_segment_means`; the server-side ``pullAverage``,
+        ml:453):
+
+        - *encode* (host, :meth:`_encode_slide`): the slide's tokens to row ids
+          in one ``Vocabulary.lookup``, OOV tokens dropped here, and every
+          sentence's count of live ids;
+        - *enqueue*: the live ids padded to a row capacity derived from the
+          slide (:func:`_grid_up`: a whole number of tiles, a sixteenth of the
+          power of two under the live ids each, so slides of like size share a
+          program and at most 1/16 of the rows handed over is padding), the
+          rows gathered in place from the whole-lane form of syn0
+          (:meth:`_row_table`), summed by sentence and divided on the device,
+          trimmed to ``[S, D]`` there, the copy to the host begun. A slide
+          with more live ids than ``_TRANSFORM_MAX_ROWS`` runs the same
+          program in further passes over equal parts of them, the sums
+          carried from pass to pass;
+        - *fetch*: one ``np.asarray`` of the ``[S, D]`` block, written
+          straight into the result's rows.
+
+        A call of several slides encodes and enqueues slide n + 1 while slide
+        n's program and fetch are outstanding (``_SLIDES_IN_FLIGHT``); several
+        threads may call at once. Spans: ``transform.slide`` and its three
+        children (docs/observability.md §4)."""
         self._check_alive()
-        out = np.zeros((len(sentences), self.vector_size), dtype=np.float32)
-        flat: List[int] = []
-        seg: List[int] = []
-        row = 0
-        rows_in_batch: List[int] = []
-
-        def flush():
-            nonlocal flat, seg, rows_in_batch
-            if not rows_in_batch:
-                return
-            if flat:
-                idx = jnp.asarray(flat, jnp.int32)
-                seg_ids = jnp.asarray(seg, jnp.int32)
-                sums = jax.ops.segment_sum(
-                    self.syn0[idx].astype(jnp.float32), seg_ids,
-                    num_segments=len(rows_in_batch))
-                counts = jax.ops.segment_sum(
-                    jnp.ones(len(flat), jnp.float32), seg_ids,
-                    num_segments=len(rows_in_batch))
-                means = np.asarray(sums / jnp.maximum(counts, 1.0)[:, None])
-                for local, global_row in enumerate(rows_in_batch):
-                    out[global_row] = means[local]
-            flat, seg, rows_in_batch = [], [], []
-
-        for sent in sentences:
-            local = len(rows_in_batch)
-            rows_in_batch.append(row)
-            for w in sent:
-                i = self.vocab.get(w)
-                if i >= 0:
-                    flat.append(i)
-                    seg.append(local)
-            row += 1
-            if len(rows_in_batch) >= batch_size:
-                flush()
-        flush()
+        out = np.empty((len(sentences), self.vector_size), np.float32)
+        pending: "collections.deque[_PendingSlide]" = collections.deque()
+        try:
+            for lo in range(0, len(sentences), batch_size):
+                pending.append(self._transform_begin(
+                    sentences[lo:lo + batch_size], lo, batch_size))
+                if len(pending) >= _SLIDES_IN_FLIGHT:
+                    self._transform_finish(pending.popleft(), out)
+            while pending:
+                self._transform_finish(pending.popleft(), out)
+        finally:
+            with self._lock:  # slides an exception left unfetched
+                self._slides_inflight -= sum(p.result is not None for p in pending)
         return out
+
+    def _encode_slide(self, slide: Sequence[Sequence[str]]
+                      ) -> Tuple[np.ndarray, np.ndarray, int]:
+        """One slide's tokens as the program wants them: the ``int32`` row ids
+        of its in-vocabulary tokens, sentence after sentence in the order
+        sent, every sentence's count of them (``int32[len(slide)]``; 0 for an
+        empty or all-OOV sentence), and the tokens dropped as OOV. One pass
+        over the flattened tokens with no Python statement a token."""
+        lengths = np.fromiter(map(len, slide), np.int64, count=len(slide))
+        ids = self.vocab.lookup(list(itertools.chain.from_iterable(slide)))
+        live = ids >= 0
+        before = np.concatenate([[0], np.cumsum(live)])
+        ends = np.cumsum(lengths)
+        counts = (before[ends] - before[ends - lengths]).astype(np.int32)
+        ids = ids[live]
+        return ids, counts, int(live.shape[0] - ids.shape[0])
+
+    def _transform_begin(self, slide: Sequence[Sequence[str]], lo: int,
+                         batch_size: int) -> "_PendingSlide":
+        """The first half of one slide: encode, and every pass of its program
+        enqueued with the result on its way back. A slide shorter than
+        ``batch_size`` (a call's last) is handed over at :func:`_grid_up` of
+        its sentences."""
+        tracer = default_tracer()
+        n = len(slide)
+        span = tracer.open("transform.slide", sentences=n)
+        pending = _PendingSlide(lo, n, span)
+        with tracer.span("transform.encode"):
+            ids, counts, oov = self._encode_slide(slide)
+        live = int(ids.shape[0])
+        if span is not None:
+            span.set(words=live, oov=oov, empty=int((counts == 0).sum()))
+        if live:
+            table = self._row_table()
+            segments = batch_size if n == batch_size else _grid_up(n, 8)
+            passes = -(-live // _TRANSFORM_MAX_ROWS)
+            cap = _grid_up(-(-live // passes), 128)
+            with self._lock:
+                inflight = self._slides_inflight
+                self._slides_inflight += 1
+            with tracer.span("transform.enqueue", rows=live, rows_cap=cap,
+                             passes=passes, inflight=inflight):
+                seg = np.repeat(np.arange(n, dtype=np.int32), counts)
+                counts = np.concatenate(
+                    [counts, np.zeros(segments - n, np.int32)])
+                sums = None
+                for at in range(0, passes * cap, cap):
+                    # past the live ids: a row no table has (read as zeros)
+                    # in a sentence no slide has (dropped)
+                    part_ids = np.full(cap, table.shape[0], np.int32)
+                    part_seg = np.full(cap, segments, np.int32)
+                    part_ids[:live - at] = ids[at:at + cap]
+                    part_seg[:live - at] = seg[at:at + cap]
+                    sums = _segment_means(
+                        table, part_ids, part_seg,
+                        counts if at + cap >= live else None, sums,
+                        segments, self.vector_size)
+                sums.copy_to_host_async()
+            pending.result = sums
+        if span is not None:
+            span.detach()  # the next slide's spans are no children of this one
+        return pending
+
+    def _transform_finish(self, pending: "_PendingSlide",
+                          out: np.ndarray) -> None:
+        """The second half of one slide: its means fetched into its rows of
+        ``out`` (zeros where it held no in-vocabulary token at all)."""
+        rows = out[pending.lo:pending.lo + pending.sentences]
+        if pending.result is None:
+            rows[:] = 0.0
+        else:
+            parent = None if pending.span is None else pending.span.id
+            with default_tracer().span("transform.fetch", parent=parent):
+                rows[:] = np.asarray(pending.result)[:pending.sentences]
+            pending.result = None
+            with self._lock:
+                self._slides_inflight -= 1
+        if pending.span is not None:
+            pending.span.close()
 
     # -- pull / norms / multiply (G5, mllib:486,514,598) -------------------------------
 
     def pull(self, indices: Sequence[int]) -> np.ndarray:
         """Row gather — the PS ``pull`` (mllib:514,539)."""
         self._check_alive()
-        return np.asarray(self.syn0[jnp.asarray(indices, jnp.int32)])
+        return self._read_rows(indices)
+
+    def _row_table(self) -> jax.Array:
+        """The table ``transform_sentences``, ``transform_words`` and ``pull``
+        gather their rows from. On one device: syn0 with D widened to whole
+        lanes of 128 (ops/subword.lane_padded, as the bucket rows are kept),
+        which the TPU's gather reads in place, where a gather from the
+        [V, 300] table first copies ALL of it row-major (3.6 GB of
+        temporaries and ~13 ms a call at 3M rows, before one row is read:
+        PERF.md §6). Made at the first such read, under the model's lock (a
+        model that only answers ``find_synonyms*`` never holds it: the scan
+        reads the table as it lies), kept until :meth:`stop`. A table on a
+        mesh is gathered as it lies, the ``[:V]`` view (ROADMAP B14 (c))."""
+        self._check_alive()
+        if len(self._full0.sharding.device_set) != 1:
+            return self.syn0
+        if self._lanes is None:
+            from glint_word2vec_tpu.ops.subword import lane_padded
+            with self._lock:
+                if self._lanes is None:
+                    self._lanes = lane_padded(self._full0)
+        return self._lanes
+
+    def _read_rows(self, ids: Sequence[int]) -> np.ndarray:
+        """Rows ``ids`` of syn0, fetched: one gather from :meth:`_row_table`."""
+        return np.asarray(self._row_table()[jnp.asarray(ids, jnp.int32)]
+                          [:, : self.vector_size])
 
     @property
     def norms(self) -> jax.Array:
@@ -843,7 +963,7 @@ class Word2VecModel:
         if self._stopped:
             return
         for arr in (self._full0, self._full1, self._norms, self._raw0,
-                    self._buckets):
+                    self._buckets, self._lanes):
             if arr is not None:
                 try:
                     arr.delete()
@@ -852,7 +972,7 @@ class Word2VecModel:
         self._full0 = None  # type: ignore[assignment]
         self._full1 = None
         self._norms = None
-        self._raw0 = self._buckets = None
+        self._raw0 = self._buckets = self._lanes = None
         self._ann = None
         self._stopped = True
 
@@ -889,6 +1009,69 @@ class _PendingSynonyms:
         self.results: list = []
         self.scan: Dict[str, int] = {}
         self.replies: Optional[List[List[Tuple[str, float]]]] = None
+
+
+# rows one pass of a transform slide's program gathers at most: where the
+# gathered block is written (off the TPU, whose sorted scatter-add takes the
+# gather as a producer) it is [rows, 384] float32, 0.8 GB, and several slides
+# may be in flight
+_TRANSFORM_MAX_ROWS = 1 << 19
+
+# slides of one transform_sentences call that are encoded and enqueued before
+# the oldest is fetched: one running, one queued behind it
+_SLIDES_IN_FLIGHT = 2
+
+
+class _PendingSlide:
+    """One slide of ``Word2VecModel.transform_sentences`` between its halves:
+    where its rows go (``lo``, ``sentences``), its ``[S, D]`` means on their
+    way back (None where it held no in-vocabulary token) and its
+    ``transform.slide`` span (None where nothing records)."""
+
+    __slots__ = ("lo", "sentences", "span", "result")
+
+    def __init__(self, lo: int, sentences: int, span):
+        self.lo = lo
+        self.sentences = sentences
+        self.span = span
+        self.result: Optional[jax.Array] = None
+
+
+def _grid_up(n: int, floor: int) -> int:
+    """``n`` rounded up to a whole number of tiles, a tile a sixteenth of the
+    power of two at or under ``n`` and at least ``floor``: the sizes a
+    transform slide is handed over at. Sixteen sizes an octave: slides of
+    real text, whose lengths differ, share a few programs, and at most 1/16
+    of what is handed over is padding (327,680 rows for 313,000 live ids)."""
+    tile = max(floor, (1 << (max(n, 1).bit_length() - 1)) // 16)
+    return -(-n // tile) * tile
+
+
+@partial(jax.jit, static_argnames=("segments", "dim"))
+def _segment_means(table: jax.Array, ids: jax.Array, seg: jax.Array,
+                   counts: Optional[jax.Array], carried: Optional[jax.Array],
+                   segments: int, dim: int) -> jax.Array:
+    """One pass of a transform slide, ONE program: rows ``ids`` of ``table``
+    (an id past its rows reads zeros) summed into the sentences ``seg`` names
+    (ascending, as the slide's ids lie; one past ``segments`` is dropped),
+    on top of the sums ``carried`` from the pass before. The last pass is
+    handed the sentences' ``counts`` and returns their means ``[segments,
+    dim]`` float32 (zeros where the count is 0); a pass before it returns the
+    sums at the table's width. The sums are taken in float32 (a wider
+    table's in its own precision). On the TPU the gather is fused into the
+    sorted scatter-add: the ``[rows, lanes]`` block is never written."""
+    with jax.named_scope("transform.gather"):
+        rows = table.at[ids].get(mode="fill", fill_value=0)
+        rows = rows.astype(jnp.promote_types(rows.dtype, jnp.float32))
+    with jax.named_scope("transform.segment_mean"):
+        sums = jax.ops.segment_sum(rows, seg, num_segments=segments,
+                                   indices_are_sorted=True)
+        if carried is not None:
+            sums = sums + carried
+        if counts is None:
+            return sums
+        return (sums[:, :dim] / jnp.maximum(counts, 1)[:, None].astype(
+            sums.dtype)).astype(jnp.float32)
 
 
 def _row_slices(table: jax.Array, at: jax.Array) -> jax.Array:

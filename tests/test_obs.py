@@ -761,7 +761,8 @@ def _hlo_without_metadata(fn, args, monkeypatch, scoped: bool) -> str:
     text = jax.jit(fn).lower(*args).compile().as_text()
     monkeypatch.undo()
     assert any(name in text for name in (
-        "sgns.scatter_syn0", "cbow.scatter_syn0", "scan.topk")) == scoped
+        "sgns.scatter_syn0", "cbow.scatter_syn0", "scan.topk",
+        "transform.segment_mean")) == scoped
     # metadata is each instruction's ``metadata={...}`` and the module's
     # tables of the files, functions and stack frames those point into
     text = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n"
@@ -822,6 +823,22 @@ def _gather_topk_case(mixed=False):
 
 def _gather_topk_mixed_case():
     return _gather_topk_case(mixed=True)
+
+
+def _transform_slide_case():
+    """A transform slide's one program (``transform.gather``,
+    ``transform.segment_mean``), a further pass of it."""
+    import jax.numpy as jnp
+    from glint_word2vec_tpu.models.word2vec import _segment_means
+    _, (syn0, _, _) = _cosine_topk_case()
+    ids = jnp.asarray([3, 0, 95, 3, 7, syn0.shape[0]], jnp.int32)
+    seg = jnp.asarray([0, 0, 1, 3, 3, 4], jnp.int32)
+
+    def slide(syn0, ids, seg, counts, carried):
+        return _segment_means(syn0, ids, seg, counts, carried, 4, syn0.shape[1])
+
+    return slide, (syn0, ids, seg, jnp.asarray([2, 1, 0, 2], jnp.int32),
+                   jnp.ones((4, syn0.shape[1]), jnp.float32))
 
 
 def _gather_topk_sharded_case():
@@ -932,7 +949,7 @@ def test_cbow_pack_span_is_recorded_with_its_args_only_when_on(update, tmp_path)
 
 @pytest.mark.parametrize("case", [_sgns_shared_step_case, _cosine_topk_case,
                                   _gather_topk_case, _gather_topk_mixed_case,
-                                  _gather_topk_sharded_case,
+                                  _gather_topk_sharded_case, _transform_slide_case,
                                   _cbow_scatter_step_case, _cbow_banded_step_case])
 def test_named_scopes_change_metadata_only(case, monkeypatch):
     """The compiled step and scan with the scopes are the programs without

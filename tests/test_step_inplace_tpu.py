@@ -479,3 +479,45 @@ def test_the_sharded_scan_moves_no_table_and_nothing_v_wide(topo, queries, monke
     # a chip's shard and its norms are the arguments; one score block beside them
     assert memory.argument_size_in_bytes < 3.1e9
     assert 4 * queries * 2_500_000 < memory.temp_size_in_bytes < 4 * queries * 2_500_000 * 1.2
+
+
+@pytest.mark.parametrize("carried", [False, True], ids=["one_pass", "a_further_pass"])
+def test_the_transform_slide_copies_no_table_and_writes_no_gathered_block(one_chip, carried):
+    """``transform_sentences``' one program a slide (PR 48) at
+    ``sgns-transform-3m-300``'s size: 327,680 ids gathered from the whole-lane
+    form of syn0 into 10,000 sentences. No copy of the table, and the sorted
+    scatter-add takes the gather as a producer: nothing ``[rows, 384]`` is
+    written (what is made is the ``[10000, 384]`` sums)."""
+    from glint_word2vec_tpu.models import word2vec as w2v
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, sentences = 327_680, 10_000
+    compiled = w2v._segment_means.lower(
+        spec((V, D), jnp.float32), spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+        spec((sentences,), jnp.int32),
+        spec((sentences, D), jnp.float32) if carried else None,
+        segments=sentences, dim=300).compile()
+    text = compiled.as_text()
+    assert not re.findall(r"= f32\[%d,\d+\]\S* copy\(" % V, text)
+    assert " sort(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_the_same_gather_from_the_300_wide_table_copies_all_of_it(one_chip):
+    """Why the model keeps a whole-lane form for its row reads: handed syn0 as
+    the scan reads it, the same program first copies the whole table row-major
+    (the parent's ``self.syn0[idx]``: 3.6 GB and ~13 ms a call before one row
+    is read)."""
+    from glint_word2vec_tpu.models import word2vec as w2v
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = w2v._segment_means.lower(
+        spec((V, 300), jnp.float32), spec((327_680,), jnp.int32),
+        spec((327_680,), jnp.int32), spec((10_000,), jnp.int32), None,
+        segments=10_000, dim=300).compile()
+    assert re.findall(r"= f32\[%d,300\]\S* copy\(" % V, compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes > 3 << 30
