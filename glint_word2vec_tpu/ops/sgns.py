@@ -183,14 +183,62 @@ def run_sums(rows: jax.Array, pos: jax.Array, max_run: int,
     return sums
 
 
-# the sort key of an entry scatter_add_by_runs leaves out (``keep``): past
-# every row id, so such entries sort last
+# the sort key of an entry plan_runs leaves out (``keep``): past every row id,
+# so such entries sort last
 _NO_KEY = int(jnp.iinfo(jnp.int32).max)
+
+
+class RunPlan(NamedTuple):
+    """One table's runs of a batch (:func:`plan_runs`): what its coalesced
+    scatter (:func:`scatter_add_by_runs`) and its coalesced gather
+    (:func:`gather_by_runs`) both read, so a step that does both makes the
+    sort, the heads and their compaction once."""
+
+    at: jax.Array     # int32 [N] — 0..N−1, the entries' places in the batch
+    keys: jax.Array   # int32 [N] — ``idx`` in run order (sorted where asked)
+    # int32 [N] — the batch place of every entry in run order; None = the
+    # batch's own order
+    order: Optional[jax.Array]
+    pos: jax.Array    # int32 [N] — place inside the piece (run_positions)
+    head: jax.Array   # bool [N] — the entry heads a piece
+    heads: jax.Array  # int32 scalar — pieces in the batch
+    # int32 [cap] — the first ``cap`` heads' places in run order, N for
+    # padding, and the same clamped into the batch (:func:`compact_heads`);
+    # None until a caller asks for them
+    live: Optional[jax.Array] = None
+    src: Optional[jax.Array] = None
+
+
+def plan_runs(idx: jax.Array, max_run: int, sort: bool = False,
+              keep: Optional[jax.Array] = None) -> RunPlan:
+    """The runs of equal neighbouring ``idx``, cut every ``max_run``; with
+    ``sort`` those a stable 1-D sort of ``idx`` makes (it carries the places,
+    so rows can be read in that order). ``keep`` (bool [N], with ``sort``):
+    the other entries sort last under a key no word has and head no run."""
+    at = jnp.arange(idx.shape[0], dtype=jnp.int32)
+    by = idx if keep is None else jnp.where(keep, idx, _NO_KEY)
+    keys, order = (jax.lax.sort((by, at), num_keys=1, is_stable=True)
+                   if sort else (by, None))
+    pos = run_positions(keys, max_run)
+    head = pos == 0 if keep is None else (pos == 0) & (keys != _NO_KEY)
+    return RunPlan(at, keys, order, pos, head, head.sum(dtype=jnp.int32))
+
+
+def compact_heads(plan: RunPlan, cap: int) -> RunPlan:
+    """``plan`` with its heads compacted to a static ``cap`` places (a sort of
+    their positions; ``jnp.nonzero`` is itself a 65,536-row scatter); the plan
+    itself where it already holds them."""
+    if plan.live is not None:
+        return plan
+    n = plan.at.shape[0]
+    live = jnp.sort(jnp.where(plan.head, plan.at, n))[:cap]
+    return plan._replace(live=live, src=jnp.minimum(live, n - 1))
 
 
 def scatter_add_by_runs(mat: jax.Array, idx: jax.Array, rows: jax.Array,
                         max_run: int, cap: int, sort: bool = False,
                         keep: Optional[jax.Array] = None,
+                        plan: Optional[RunPlan] = None,
                         ) -> Tuple[jax.Array, jax.Array]:
     """``mat.at[idx].add(rows)`` that hands the scatter ONE row per run of
     equal neighbouring ``idx``: ``(new_mat, rows_handed_over)``.
@@ -199,9 +247,9 @@ def scatter_add_by_runs(mat: jax.Array, idx: jax.Array, rows: jax.Array,
     f32[3000000,384], whether rows repeat or are dropped out of bounds (PERF.md
     §6, PR 28: 65,536 rows 6.65 ms, 24,576 rows 2.52 ms, 65,536 rows with 74%
     sent out of bounds 6.66 ms). So the runs are summed first
-    (:func:`run_sums`), the heads are compacted to a static ``cap`` rows (a
-    sort of their positions; ``jnp.nonzero`` is itself a 65,536-row scatter)
-    and only those reach the scatter, the padding dropped out of bounds.
+    (:func:`run_sums`), the heads are compacted to a static ``cap`` rows
+    (:func:`compact_heads`) and only those reach the scatter, the padding
+    dropped out of bounds.
 
     The native pair feed emits a center's pairs consecutively
     (native/pairgen.cpp), so by ``centers`` a batch has ~1/4 as many heads as
@@ -219,30 +267,73 @@ def scatter_add_by_runs(mat: jax.Array, idx: jax.Array, rows: jax.Array,
     zero by construction (the banded CBOW step's slots that train an example,
     four fifths of a block). The others sort last under a key no word has and
     head no run, so the coalesced scatter is handed the kept entries' runs
-    alone; the plain branch is the same either way."""
+    alone; the plain branch is the same either way.
+
+    ``plan``: :func:`plan_runs` of the same ``idx``, ``max_run``, ``sort``
+    and ``keep``, where the caller made it already (a step whose gather goes
+    by the same runs); made here where not."""
     n, v = idx.shape[0], mat.shape[0]
-    at = jnp.arange(n, dtype=jnp.int32)
-    by = idx if keep is None else jnp.where(keep, idx, _NO_KEY)
-    keys, order = (jax.lax.sort((by, at), num_keys=1, is_stable=True)
-                   if sort else (by, None))
-    pos = run_positions(keys, max_run)
-    head = pos == 0 if keep is None else (pos == 0) & (keys != _NO_KEY)
-    heads = head.sum(dtype=jnp.int32)
+    if plan is None:
+        plan = plan_runs(idx, max_run, sort, keep)
 
     def coalesced(mat):
-        by_run = rows if order is None else rows[order]
-        sums = run_sums(by_run, pos, max_run, mat.dtype)
-        live = jnp.sort(jnp.where(head, at, n))[:cap]
-        src = jnp.minimum(live, n - 1)
-        return mat.at[jnp.where(live < n, keys[src], v)].add(
-            sums[src], mode="drop")
+        by_run = rows if plan.order is None else rows[plan.order]
+        sums = run_sums(by_run, plan.pos, max_run, mat.dtype)
+        at_heads = compact_heads(plan, cap)
+        return mat.at[jnp.where(at_heads.live < n, plan.keys[at_heads.src], v)
+                      ].add(sums[at_heads.src], mode="drop")
 
     def plain(mat):
         return mat.at[idx].add(rows.astype(mat.dtype))
 
-    fits = heads <= cap
+    fits = plan.heads <= cap
     return (jax.lax.cond(fits, coalesced, plain, mat),
-            jnp.where(fits, heads, n).astype(jnp.float32))
+            jnp.where(fits, plan.heads, n).astype(jnp.float32))
+
+
+def gather_by_runs(tables, dtype: jnp.dtype) -> Tuple[tuple, jax.Array]:
+    """``mat[idx].astype(dtype)`` for every ``(mat, idx, plan, cap)`` of
+    ``tables``, made from ONE gathered row per piece of ``plan``'s runs:
+    ``(the [N, D] blocks, whether the batch went by runs)``.
+
+    Over a model axis a table's rows lie on several chips, and a gather of
+    ``mat[idx]`` is assembled by an all-reduce of the whole ``[N, D]`` block,
+    every chip adding the rows it owns and zeros for the rest (GSPMD's
+    schedule; docs/sharding.md). That collective is bandwidth-bound and
+    carries a repeated row once per repeat: ~3.8 times at window 5. Here the
+    heads' rows alone are gathered and cast (``[cap, D]``: that block is what
+    crosses the mesh), and every entry then reads its piece's row out of the
+    replicated block, a gather local to each chip. For a sorted plan the
+    pieces' ids go back to the batch's order by a second sort (a 1-D scatter
+    of N ids is priced like a row scatter on the TPU, PERF.md §6, PR 28). A
+    piece of a cut run gathers its row again: the cap counts pieces.
+
+    One conditional holds every table's gather, so the compiler can combine
+    the branch's all-reduces: a batch with more pieces than a table's ``cap``
+    takes the plain gathers, the same ops as without this function. The rows
+    are the same either way, bit for bit (the other chips add zeros). Plans
+    made with ``keep`` are not for this: an entry left out has no piece."""
+    mats = tuple(mat for mat, _, _, _ in tables)
+
+    def by_runs(mats):
+        out = []
+        for mat, (_, _, plan, cap) in zip(mats, tables):
+            # the padding reads the last entry's row, which no piece names
+            block = mat[plan.keys[compact_heads(plan, cap).src]].astype(dtype)
+            piece = jnp.cumsum(plan.head.astype(jnp.int32)) - 1
+            if plan.order is not None:
+                _, piece = jax.lax.sort((plan.order, piece), num_keys=1)
+            out.append(block[piece])
+        return tuple(out)
+
+    def plain(mats):
+        return tuple(mat[idx].astype(dtype)
+                     for mat, (_, idx, _, _) in zip(mats, tables))
+
+    fits = jnp.bool_(True)
+    for _, _, plan, cap in tables:
+        fits = fits & (plan.heads <= cap)
+    return jax.lax.cond(fits, by_runs, plain, mats), fits
 
 
 class EmbeddingPair(NamedTuple):
@@ -290,6 +381,11 @@ class StepMetrics(NamedTuple):
     # lengths of its real pairs' contexts (config.loss="hs"; ops/hs.py); None
     # on every other step
     hs_nodes: Optional[jax.Array] = None
+    # rows handed to the forward assembly of the gathered rows over a model
+    # axis (sgns_step_shared_core ``assemble_by_runs``): both scatters' caps
+    # and the pool where the batch went by runs, 2B + P where it did not; None
+    # where that form is not compiled
+    assembly_rows: Optional[jax.Array] = None
 
 
 def init_embeddings(
@@ -640,6 +736,7 @@ def sgns_step_shared_core(
     center_runs: Optional[Tuple[int, int]] = None,
     context_runs: Optional[Tuple[int, int]] = None,
     subword: Optional[tuple] = None,
+    assemble_by_runs: bool = False,
 ) -> Tuple[EmbeddingPair, StepMetrics]:
     """:func:`sgns_step_shared` with the pool supplied by the caller (see
     :func:`sgns_step_core` for why sampling lives outside the jitted scan).
@@ -667,6 +764,17 @@ def sgns_step_shared_core(
     §6, PR 30); the trainer derives the cap from the vocabulary's counts. The
     pool rows' scatter, the stabilizers' post-pass and ``duplicate_scaling``
     read ``contexts``, not the order, and are as without it.
+
+    ``assemble_by_runs`` (with both of the above): ``e_in`` and ``e_pos`` are
+    made through :func:`gather_by_runs` under the two scatters' own plans and
+    caps, one gathered row a piece and an expansion by piece id. For tables
+    sharded by rows over a model axis, where the gathered rows are assembled
+    by an all-reduce and a batch holds each center's row ~3.8 times and each
+    context's ~3.9 (the trainer engages it there and nowhere else: on one
+    chip there is no collective to shrink and the expansion costs what the
+    gather saves). The values are the same rows, so every later op, the
+    tables and the loss are bit for bit those of the step without it;
+    ``StepMetrics.assembly_rows`` says which branch a batch took.
 
     ``fused``/``bf16_chain`` (config.fused_logits / config.bf16_chain —
     ISSUE 14): the fused coefficient chain and the f32-accumulating dot
@@ -713,6 +821,10 @@ def sgns_step_shared_core(
     if duplicate_scaling and fused:
         raise ValueError("duplicate_scaling has no fused form "
                          "(refused at config construction)")
+    if assemble_by_runs and (subword is not None or not center_runs
+                             or not context_runs):
+        raise ValueError("assemble_by_runs goes by both scatters' runs: it "
+                         "needs center_runs and context_runs, and no subword")
     # named scopes are metadata for a profile's reader (docs/observability.md
     # §4); the compiled step is the same program without them (tested)
     if subword is not None:
@@ -721,11 +833,24 @@ def sgns_step_shared_core(
         sw_plan = sw.plan_centers(centers, sw_table, sw_shape)
         e_in = sw.center_vectors(syn0, centers, sw_table, sw_shape, sw_plan,
                                  compute_dtype)              # [B, D]
+    plan0 = plan1 = assembly_rows = None
     with jax.named_scope("sgns.gather"):
-        if subword is None:
-            e_in = syn0[centers].astype(compute_dtype)      # [B, D]
-        e_pos = syn1[contexts].astype(compute_dtype)        # [B, D]
-        Z = syn1[negatives].astype(compute_dtype)           # [P, D]
+        if assemble_by_runs:
+            # the plans the two scatters go by, compacted here once for both
+            (run0, cap0), (run1, cap1) = center_runs, context_runs
+            plan0 = compact_heads(plan_runs(centers, run0), cap0)
+            plan1 = compact_heads(plan_runs(contexts, run1, sort=True), cap1)
+            (e_in, e_pos), by_runs = gather_by_runs(
+                ((syn0, centers, plan0, cap0), (syn1, contexts, plan1, cap1)),
+                compute_dtype)
+            B, P = centers.shape[0], negatives.shape[0]
+            assembly_rows = jnp.where(
+                by_runs, cap0 + cap1 + P, 2 * B + P).astype(jnp.float32)
+        else:
+            if subword is None:
+                e_in = syn0[centers].astype(compute_dtype)      # [B, D]
+            e_pos = syn1[contexts].astype(compute_dtype)        # [B, D]
+        Z = syn1[negatives].astype(compute_dtype)               # [P, D]
 
     with jax.named_scope("sgns.pool_matmul"):
         f_pos, f_neg, neg_valid, g_pos, g_neg = shared_pool_coeffs(
@@ -779,7 +904,7 @@ def sgns_step_shared_core(
             syn0_rows = jnp.float32(centers.shape[0])
         else:
             new_syn0, syn0_rows = scatter_add_by_runs(
-                syn0, centers, d_in, *center_runs)
+                syn0, centers, d_in, *center_runs, plan=plan0)
     with jax.named_scope("sgns.scatter_syn1"):
         if context_runs is None:
             new_syn1 = syn1.at[contexts].add(d_pos.astype(dtype))
@@ -792,7 +917,7 @@ def sgns_step_shared_core(
             # (PERF.md §6, PR 30)
             d_pos, _ = jax.lax.optimization_barrier((d_pos, Z))
             new_syn1, syn1_rows = scatter_add_by_runs(
-                syn1, contexts, d_pos, *context_runs, sort=True)
+                syn1, contexts, d_pos, *context_runs, sort=True, plan=plan1)
         new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
     if stabilizers is not None and stabilizers.post_pass:
         enable = (mask.sum() > 0).astype(jnp.float32)
@@ -819,6 +944,7 @@ def sgns_step_shared_core(
         syn1_rows=syn1_rows,
         subword_rows=subword_rows,
         subword_slots=subword_slots,
+        assembly_rows=assembly_rows,
     )
     return EmbeddingPair(new_syn0, new_syn1), metrics
 

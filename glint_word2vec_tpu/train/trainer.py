@@ -391,6 +391,9 @@ class StepChoice(NamedTuple):
     # (max_run, syn0's cap, syn1's cap) where the banded CBOW step's two token
     # scatters go by runs of the block's tokens sorted by word
     token_runs: Optional[Tuple[int, int, int]] = None
+    # the forward gathers go by the same runs (one row a piece over the model
+    # axis, expanded on each chip): the last row on a mesh with a model axis
+    assemble_by_runs: bool = False
 
 
 def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
@@ -413,8 +416,9 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
       False  —            ns    > 0  False              "shard_map"    make_shard_map_sgns_step    [K, P]
                                                         sync_every>1   (the same, windowed)        [K, nd·P]
       False  —            ns    > 0  any                gspmd          sgns_step_shared_core       [K, P]
-                                                                       (+ center_runs and
-                                                                       context_runs, below)
+                                                                       (+ center_runs,
+                                                                       context_runs and
+                                                                       assemble_by_runs, below)
 
     Three options ride a row or are one, each on one device and each with its
     table as jit arguments of the chunk (``_step_extra``):
@@ -572,16 +576,25 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
             sgns_step_shared_core, step, shared_pool,
             (subword_shape.max_run, subword_shape.head_cap), context_runs)
 
+    # the forward gathers by the same runs, where the tables' rows lie over a
+    # model axis: the gathered rows are then assembled by an all-reduce, which
+    # carries one row a piece in place of one a pair (ops/sgns.gather_by_runs;
+    # 35% of the bytes at window 5). On one chip nothing is assembled and the
+    # expansion costs what the smaller gather saves (PERF.md §6, PR 49): the
+    # step there is the one it was
+    assemble = bool(plan.num_model > 1 and runs and context_runs)
+
     def step(params, batch, negatives, alpha):
         return sgns_step_shared_core(
             params, batch["centers"], batch["contexts"], batch["mask"],
             negatives, alpha, n, cfg.sigmoid_mode, compute_dtype,
             cfg.duplicate_scaling, logits_dtype, with_metrics,
             stabilizers=stabilizers, fused=fused, bf16_chain=chain,
-            center_runs=runs, context_runs=context_runs)
+            center_runs=runs, context_runs=context_runs,
+            assemble_by_runs=assemble)
 
     return StepChoice(sgns_step_shared_core, step, shared_pool, runs,
-                      context_runs)
+                      context_runs, assemble_by_runs=assemble)
 
 
 @dataclass
@@ -2755,12 +2768,13 @@ class Trainer:
             # runs a probing fit under the guard to keep this path honest)
             with self._tracer.span("device_block") as blocked:
                 (loss_k, fpos_k, pairs_k, rows0_k, rows1_k, rows_sw_k,
-                 slots_sw_k, gather_sw_k, nodes_hs_k, pos) = jax.device_get(
+                 slots_sw_k, gather_sw_k, nodes_hs_k, assembly_k,
+                 pos) = jax.device_get(
                     (metrics.loss, metrics.mean_f_pos, metrics.pairs,
                      metrics.syn0_rows, metrics.syn1_rows,
                      metrics.subword_rows, metrics.subword_slots,
                      metrics.subword_gather_slots, metrics.hs_nodes,
-                     self.params.pos))
+                     metrics.assembly_rows, self.params.pos))
                 if pairs_k[real - 1] > 0:
                     # how far the step coalesced each table's update: 1.0
                     # plain, heads over pairs where runs were summed first
@@ -2768,8 +2782,12 @@ class Trainer:
                     # step's both by token over its live examples: ~0.67 and
                     # ~0.55 coalesced, ~1.26 a block over a cap, and syn1's
                     # alone beside the token row source)
+                    # and the forward assembly over a model axis, where the
+                    # step gathers by the same runs: both caps and the pool
+                    # over the pairs (~0.72), 2B + P over them plain (2.03)
                     for name, rows_k in (("syn0_rows_per_pair", rows0_k),
-                                         ("syn1_rows_per_pair", rows1_k)):
+                                         ("syn1_rows_per_pair", rows1_k),
+                                         ("assembly_rows_per_pair", assembly_k)):
                         if rows_k is not None:
                             blocked.set(**{name: float(
                                 rows_k[real - 1] / pairs_k[real - 1])})

@@ -8,6 +8,11 @@ takes the plain scatter, bit for bit. Contexts come in no order: the helper
 sorts them inside the step (``sort=True``) and does the same with their runs
 (the ``context_*`` cases). Every case runs in float32 and in the benchmark
 cell's bfloat16 compute dtype (the tables stay float32).
+
+Where the tables' rows lie over a model axis the step's forward gathers go by
+the same runs (``gather_by_runs``, ``assemble_by_runs``: one gathered row a
+piece, expanded by piece id): the same rows, so the step is the step without
+it bit for bit, on one device and on a 1x4 mesh (the ``assembly_*`` cases).
 """
 
 import os
@@ -27,10 +32,13 @@ from glint_word2vec_tpu.data.vocab import Vocabulary
 from glint_word2vec_tpu.ops.sgns import (
     EmbeddingPair,
     Stabilizers,
+    gather_by_runs,
+    plan_runs,
     run_positions,
     scatter_add_by_runs,
     sgns_step_shared_core,
 )
+from glint_word2vec_tpu.parallel.mesh import make_mesh
 from glint_word2vec_tpu.train import trainer as trainer_mod
 from glint_word2vec_tpu.train.trainer import (
     _CONTEXT_MAX_RUN,
@@ -504,6 +512,116 @@ def case_both_step_twins_one_program_each(dtype):
 
 def case_context_both_step_twins_one_program_each(dtype):
     _fit_with_and_without(dtype, "_context_run_cap", "syn1_rows_per_pair")
+
+
+# ---- the forward gathers by the same runs (gather_by_runs, assemble_by_runs) --------------
+
+def _gathered(idx, dtype, sort, went_by_runs):
+    """gather_by_runs of one table against ``mat[idx]``, bit for bit, by the
+    runs ``idx`` comes in or (``sort``) by those the plan's sort makes."""
+    idx = jnp.asarray(idx, jnp.int32)
+    max_run, cap = (CTX_RUN, CTX_CAP) if sort else (MAX_RUN, CAP)
+    table = _tables().syn1 if sort else _tables().syn0
+
+    def both(mat):
+        plan = plan_runs(idx, max_run, sort=sort)
+        (got,), by_runs = gather_by_runs(((mat, idx, plan, cap),), dtype)
+        return got, by_runs, mat[idx].astype(dtype)
+
+    got, by_runs, want = jax.jit(both)(table)
+    assert bool(by_runs) == went_by_runs
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def case_gather_by_runs_against_indexing(dtype):
+    for c, x in feed_batches():
+        _gathered(c, dtype, False, True)
+        _gathered(x, dtype, True, True)      # the pieces' ids back in feed order
+    # pieces of a cut run gather their row again; a batch of one word
+    _gathered(np.repeat(np.arange(100, 100 + B // 25 + 1), 25)[:B], dtype, False, True)
+    _gathered(np.full(B, 17), dtype, True, True)
+    # more pieces than the cap: the plain gather
+    rng = np.random.default_rng(3)
+    _gathered(rng.permutation(V)[:B], dtype, False, False)
+    _gathered(rng.permutation(V)[:B], dtype, True, False)
+
+
+def _assembled(c, x, mask, dtype, went_by_runs, model_axis=False, **kw):
+    """The step with both updates coalesced against the same step with its
+    forward gathers by the same runs: both tables, the loss and the pairs bit
+    for bit; ``assembly_rows`` says which branch the batch took. On one
+    device, or (``model_axis``) with the tables' rows over a 1x4 mesh, where
+    the gathered rows are assembled by an all-reduce."""
+    params = _tables()
+    if model_axis:
+        params = jax.device_put(params, make_mesh(1, 4).embedding)
+    kw = dict(kw, context_runs=(CTX_RUN, CTX_CAP))
+    want, m0 = _step(params, c, x, mask, dtype, (MAX_RUN, CAP), **kw)
+    got, m1 = _step(params, c, x, mask, dtype, (MAX_RUN, CAP),
+                    assemble_by_runs=True, **kw)
+    np.testing.assert_array_equal(np.asarray(got.syn0), np.asarray(want.syn0))
+    np.testing.assert_array_equal(np.asarray(got.syn1), np.asarray(want.syn1))
+    assert float(m1.loss) == float(m0.loss) and float(m1.pairs) == float(m0.pairs)
+    assert float(m1.mean_f_pos) == float(m0.mean_f_pos)
+    assert (float(m1.syn0_rows), float(m1.syn1_rows)) == (
+        float(m0.syn0_rows), float(m0.syn1_rows))
+    assert m0.assembly_rows is None
+    assert float(m1.assembly_rows) == (
+        CAP + CTX_CAP + P if went_by_runs else 2 * B + P)
+    return m1
+
+
+def _assembly_batches():
+    """(centers, contexts, mask, whether both tables fit their caps)."""
+    (c, x), (c2, x2) = feed_batches(2)
+    ones = np.ones(B, np.float32)
+    yield c, x, ones, True
+    yield c2, x2, ones, True
+    # a masked tail: the batcher pads with zeros, one run of row 0
+    real = 700
+    ct, xt = c.copy(), x.copy()
+    ct[real:], xt[real:] = 0, 0
+    yield ct, xt, (np.arange(B) < real).astype(np.float32), True
+    # runs longer than max_run on both sides: 25 pairs a center, and one
+    # context in every 8th pair (cut every CTX_RUN of the sorted batch)
+    long_c = np.repeat(np.arange(100, 100 + B // 25 + 1), 25)[:B].astype(np.int32)
+    long_x = np.where(np.arange(B) % 8 == 0, 5, x).astype(np.int32)
+    yield long_c, long_x, ones, True
+    # over syn0's cap (runs of two), then over syn1's (contexts that all
+    # differ): the plain gathers for both tables
+    rng = np.random.default_rng(4)
+    pairs_c = np.repeat(rng.permutation(V)[:B // 2], 2).astype(np.int32)
+    assert B // 2 > CAP
+    yield pairs_c, x, ones, False
+    yield c, rng.permutation(V)[:B].astype(np.int32), ones, False
+
+
+def case_assembly_by_runs_bit_equal(dtype):
+    for c, x, mask, fits in _assembly_batches():
+        _assembled(c, x, mask, dtype, fits)
+
+
+def case_assembly_by_runs_on_a_model_axis_bit_equal(dtype):
+    for c, x, mask, fits in _assembly_batches():
+        _assembled(c, x, mask, dtype, fits, model_axis=True)
+
+
+def case_assembly_by_runs_with_stabilizers_and_duplicate_scaling(dtype):
+    # both read the per-pair rows after the gathers: nothing of them changes
+    c, x = feed_batches(1)[0]
+    for kw in (dict(stabilizers=Stabilizers(update_clip=0.002, max_row_norm=2.0)),
+               dict(duplicate_scaling=True)):
+        _assembled(c, x, np.ones(B, np.float32), dtype, True, **kw)
+
+
+def case_assembly_needs_both_runs(dtype):
+    c, x = feed_batches(1)[0]
+    for runs, kw in ((None, dict(context_runs=(CTX_RUN, CTX_CAP))),
+                     ((MAX_RUN, CAP), {})):
+        with pytest.raises(ValueError, match="needs center_runs and context_runs"):
+            _step(_tables(), c, x, np.ones(B), dtype, runs,
+                  assemble_by_runs=True, **kw)
 
 
 CASES = {name[len("case_"):]: fn for name, fn in sorted(globals().items())

@@ -46,6 +46,13 @@ a token.
 That the subword skip-gram step is the program it was is held where it is
 cheap, on its lowered text (``tests/test_cbow_subword.py``).
 
+The same step at ``sgns-10m-300-x4``'s size on the described 2x2 as a 1x4 mesh
+(PR 49): the forward gathers go by the scatters' runs under a conditional of
+their own (``assemble_by_runs``), which READS both tables' shards before the
+scatters' conditionals write them: no f32[2500000,384] shard is copied or
+moved, and what crosses the model axis is bfloat16 rows in all-reduces alone,
+45,056 where the batch goes by runs, 131,072 where it does not, 2,048 beside.
+
 The plain banded step at ``cbow-3m-300``'s size (PR 46) has a row of its own:
 each token scatter goes through a conditional of its own on the block's tokens
 sorted inside the step (``token_runs``: syn0's by every slot's token, syn1's
@@ -111,6 +118,52 @@ def test_no_table_is_copied(one_chip, with_metrics):
     copies = [line.strip()[:120] for line in compiled.splitlines()
               if re.search(rf"= f32\[{V},{D}\]\S* copy\(", line)]
     assert not copies, copies
+
+
+@pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
+def test_no_table_shard_is_copied_on_the_model_axis(topo, with_metrics):
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    rows, shard = 10_000_000, 2_500_000
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    by_rows = NamedSharding(mesh, PartitionSpec("model", None))
+
+    def spec(shape, dtype, sharding=NamedSharding(mesh, PartitionSpec())):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def chunk(params, centers, contexts, negatives, alphas):
+        def body(p, xs):
+            c, x, n, a = xs
+            new_p, metrics = sgns_step_shared_core(
+                p, c, x, jnp.ones(B, jnp.float32), n, a, 5, "exact",
+                jnp.bfloat16, logits_dtype=jnp.bfloat16,
+                with_metrics=with_metrics, assemble_by_runs=True, **RUNS)
+            return jax.lax.with_sharding_constraint(
+                new_p, EmbeddingPair(by_rows, by_rows)), metrics
+        return jax.lax.scan(body, params, (centers, contexts, negatives, alphas))
+
+    table = spec((rows, D), jnp.float32, by_rows)
+    compiled = jax.jit(chunk, donate_argnums=(0,)).lower(
+        EmbeddingPair(table, table), spec((K, B), jnp.int32),
+        spec((K, B), jnp.int32), spec((K, P), jnp.int32),
+        spec((K,), jnp.float32)).compile().as_text()
+    # the gathers' conditional and one a scatter
+    assert compiled.count(" conditional(") == 3
+    moved = [line.strip()[:120] for line in compiled.splitlines()
+             if re.search(rf"= \(?f32\[{shard},{D}\]\S* (?:copy|all-gather|all-to-all|"
+                          r"collective-permute)(?:-start)?\(", line)]
+    assert not moved, moved
+    # what crosses the mesh: bfloat16 rows, the caps' in one branch of the
+    # gathers' conditional, 2B in the other, the pool's beside both
+    carried = [re.findall(r"(\w+)\[(\d+),\d+\]", line.split(" all-reduce")[0])
+               for line in compiled.splitlines()
+               if re.search(r"= \S.* all-reduce(?:-start)?\(", line)
+               and re.search(rf"\[\d+,{D}\]", line.split(" all-reduce")[0])]
+    assert all(dtype == "bf16" for op in carried for dtype, _ in op), carried
+    caps = RUNS["center_runs"][1] + RUNS["context_runs"][1]
+    assert sorted(sum(int(r) for _, r in op) for op in carried) == sorted(
+        [caps, 2 * B, P]), carried
 
 
 @pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])

@@ -59,9 +59,15 @@ ROWS = {
     "sgns-shared-gspmd-runs": (
         dict(negative_pool=P, window=5), (1, 1), 1,
         ("sgns_step_shared_core", POOL, RUNS_W5, BY_CONTEXT)),
-    "sgns-shared-gspmd-model-axis": (   # rows over 1x4: the batch is still whole
-        dict(negative_pool=P, window=5), (1, 4), 1,
+    "sgns-shared-gspmd-model-axis": (   # rows over 1x4: the batch is still whole,
+        dict(negative_pool=P, window=5), (1, 4), 1,   # and its gathers go by runs too
         ("sgns_step_shared_core", POOL, RUNS_W5, BY_CONTEXT)),
+    "sgns-shared-gspmd-model-axis-window2": (   # no center runs: gathers by pair
+        dict(negative_pool=P, window=2), (1, 4), 1,
+        ("sgns_step_shared_core", POOL, None, BY_CONTEXT)),
+    "sgns-shared-gspmd-model-axis-two-feed-segments": (   # the same
+        dict(negative_pool=P, window=5), (1, 4), 2,
+        ("sgns_step_shared_core", POOL, None, BY_CONTEXT)),
     "sgns-shared-gspmd-window2": (   # two pairs a run or fewer: not built
         dict(negative_pool=P, window=2), (1, 1), 1,   # (contexts: the corpus's)
         ("sgns_step_shared_core", POOL, None, BY_CONTEXT)),
@@ -109,6 +115,12 @@ ROWS = {
         dict(cbow=True, duplicate_scaling=True, window=5), (1, 1), 1,
         ("cbow_step_core", PER_EXAMPLE, None)),
 }
+
+
+# the rows whose forward gathers go by the scatters' runs (assemble_by_runs): a
+# model axis, no data axis, both runs built. Not on one chip, where no
+# collective assembles the gathered rows
+ASSEMBLES = {"sgns-shared-gspmd-model-axis"}
 
 
 class _Operand:
@@ -162,6 +174,7 @@ def test_step_selection(row, with_metrics, cores):
     assert choice.center_runs == runs
     assert choice.context_runs == by_context
     assert choice.token_runs == by_token
+    assert choice.assemble_by_runs == (row in ASSEMBLES)
     assert choice.step("params", _Batch(), "negatives", "alpha") == "out"
     # the chosen core ran, once, and no other
     name, bound = calls[0]
@@ -174,6 +187,7 @@ def test_step_selection(row, with_metrics, cores):
     if core == "sgns_step_shared_core":
         assert bound["center_runs"] == runs
         assert bound["context_runs"] == by_context
+        assert bound["assemble_by_runs"] == (row in ASSEMBLES)
         assert bound["duplicate_scaling"] == cfg.duplicate_scaling
     if core == "cbow_step_banded_core":
         assert bound["token_runs"] == by_token
@@ -238,11 +252,13 @@ COLLECTIVE = re.compile(r"\b(all-reduce|all-gather|reduce-scatter|all-to-all|"
 @pytest.mark.parametrize("twin", ["_step_fn", "_step_fn_fast"])
 def test_x4_step_holds_the_parents_collectives(twin):
     """``sgns-10m-300-x4`` at ``tiny`` on its 1x4 mesh (over the 8 virtual CPU
-    devices): the step with both updates coalesced compiles to the collective
-    ops the step before PR 30 compiled to, ONE all-reduce (the forward
-    assembly of the gathered rows over the model axis) — the sort, the row
-    gather and the conditional bring none. A count of the compiled module's
-    ops, not a time."""
+    devices): the step with both updates coalesced and its forward gathers by
+    the same runs compiles to all-reduces alone (the forward assembly of the
+    gathered rows over the model axis) — the sorts, the row gathers and the
+    conditionals bring no other collective. The gathers' conditional holds the
+    assembly once in each branch: by runs, both scatters' caps of rows; by
+    pair, 2B as before PR 49; the pool's P rows beside both. A count of the
+    compiled module's ops and their operands' rows, not a time."""
     from harness import loader
     from kinds import train as train_kind
 
@@ -253,7 +269,8 @@ def test_x4_step_holds_the_parents_collectives(twin):
     cfg = trainer.config
     assert (trainer.plan.num_data, trainer.plan.num_model) == (1, 4)
     choice = select_step(cfg, trainer.plan, 1, trainer._context_cap, None, True)
-    assert choice.context_runs and choice.center_runs   # both engage here
+    assert choice.context_runs and choice.center_runs   # both engage here,
+    assert choice.assemble_by_runs                      # and so do the gathers
 
     k, b = cfg.steps_per_dispatch, cfg.pairs_per_batch
     staged = put_global(trainer._chunk_shardings,
@@ -264,4 +281,17 @@ def test_x4_step_holds_the_parents_collectives(twin):
         trainer._table_prob, trainer._table_alias).compile().as_text()
     assert " sort(" in compiled
     found = collections.Counter(m.group(1) for m in COLLECTIVE.finditer(compiled))
-    assert found == {"all-reduce": 1}
+    assert set(found) == {"all-reduce"}
+    # the rows each all-reduce carries, by the computation that holds it
+    rows_in, computation = collections.defaultdict(int), None
+    for line in compiled.splitlines():
+        opened = re.match(r"(?:ENTRY )?%(\S+) \(", line)
+        if opened:
+            computation = opened.group(1)
+        if re.search(r" all-reduce(?:-start)?\(", line):
+            result = line.split(" all-reduce")[0].split("=", 1)[1]
+            rows_in[computation] += sum(
+                int(r) for r in re.findall(r"\w+\[(\d+),\d+\]", result))
+    (cap0, cap1), pool = (choice.center_runs[1], choice.context_runs[1]), cfg.negative_pool
+    assert sorted(rows_in.values()) == sorted([cap0 + cap1, 2 * b, pool])
+    assert cap0 + cap1 + pool < 0.4 * (2 * b + pool)
