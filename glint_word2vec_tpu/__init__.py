@@ -29,6 +29,14 @@ sampler), ``parallel/`` (mesh + sharding), ``models/`` (model & estimator API),
 ``train/`` (trainer, checkpoint).
 """
 
+# the pinned span ``import`` (obs/spans.py): this file's first line to its
+# last, and whether jax was imported before it (then jax's own import lies
+# outside the span)
+import sys as _sys
+import time as _time
+
+_t0, _jax_preloaded = _time.monotonic(), "jax" in _sys.modules
+
 from glint_word2vec_tpu.config import Word2VecConfig
 from glint_word2vec_tpu.data.vocab import Vocabulary, build_vocab
 from glint_word2vec_tpu.models import (
@@ -50,3 +58,16 @@ __all__ = [
     "ServerSideGlintWord2VecModel",
     "__version__",
 ]
+
+
+def _record_import() -> None:
+    from glint_word2vec_tpu.obs import compile_spans
+    from glint_word2vec_tpu.obs.spans import default_tracer, now
+    # every compilation from here on is a pinned span too
+    compile_spans.install()
+    default_tracer().record(
+        "import", _t0, now() - _t0, pinned=True, jax_preloaded=_jax_preloaded,
+        modules=sum(m.startswith("glint_word2vec_tpu.") for m in list(_sys.modules)))
+
+
+_record_import()

@@ -46,59 +46,64 @@ def build_or_reload(src: str, lib_stem: str, abi_symbol: str, abi_version: int,
     content hash does), load, verify the ABI symbol, and rebuild once on a
     broken cache. Returns the CDLL or None (with a logged warning — callers
     fall back to their pure-Python path). Argtype configuration and caching
-    stay with the calling module."""
+    stay with the calling module. The whole of it is the pinned span
+    ``native.load`` (obs/spans.py): ``lib``, and ``built``, whether g++ ran."""
+    from glint_word2vec_tpu.obs.spans import default_tracer
     from glint_word2vec_tpu.train.faults import retry_io
-    with retry_io(lambda: open(src, "rb"), what=f"native source {src!r}") as f:
-        lib_path = f"{lib_stem}.{hashlib.sha256(f.read()).hexdigest()[:16]}.so"
+    with default_tracer().span("native.load", pinned=True, lib=what,
+                               built=False) as span:
+        with retry_io(lambda: open(src, "rb"), what=f"native source {src!r}") as f:
+            lib_path = f"{lib_stem}.{hashlib.sha256(f.read()).hexdigest()[:16]}.so"
 
-    def build() -> bool:
-        # per-process temp name: co-hosted builders (multi-process JAX workers,
-        # parallel pytest) must not interleave g++ output into one file before
-        # the atomic publish below
-        tmp = f"{lib_path}.tmp.{os.getpid()}"
-        # sweep temp objects orphaned by builders killed mid-compile (unique
-        # names mean nothing ever overwrites them); only files older than the
-        # build timeout — younger ones may belong to a live concurrent builder
-        # glob.escape: a cache path containing [, ?, * must match literally —
-        # unescaped it would silently sweep nothing (orphans accumulate) or
-        # match unrelated files for deletion
-        for stale in glob.glob(glob.escape(lib_stem) + ".*.tmp*"):
+        def build() -> bool:
+            span.set(built=True)
+            # per-process temp name: co-hosted builders (multi-process JAX workers,
+            # parallel pytest) must not interleave g++ output into one file before
+            # the atomic publish below
+            tmp = f"{lib_path}.tmp.{os.getpid()}"
+            # sweep temp objects orphaned by builders killed mid-compile (unique
+            # names mean nothing ever overwrites them); only files older than the
+            # build timeout — younger ones may belong to a live concurrent builder
+            # glob.escape: a cache path containing [, ?, * must match literally —
+            # unescaped it would silently sweep nothing (orphans accumulate) or
+            # match unrelated files for deletion
+            for stale in glob.glob(glob.escape(lib_stem) + ".*.tmp*"):
+                try:
+                    if time.time() - os.path.getmtime(stale) > 300:
+                        os.unlink(stale)
+                except OSError:
+                    pass
+            # _FILE_OFFSET_BITS=64: the ingest loader seeks with fseeko/off_t,
+            # which is only 64-bit on ILP32 glibc with this macro
+            cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", f"-std={std}",
+                   "-D_FILE_OFFSET_BITS=64", "-o", tmp, src]
             try:
-                if time.time() - os.path.getmtime(stale) > 300:
-                    os.unlink(stale)
-            except OSError:
-                pass
-        # _FILE_OFFSET_BITS=64: the ingest loader seeks with fseeko/off_t,
-        # which is only 64-bit on ILP32 glibc with this macro
-        cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", f"-std={std}",
-               "-D_FILE_OFFSET_BITS=64", "-o", tmp, src]
-        try:
-            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        except (OSError, subprocess.SubprocessError) as e:
-            err = getattr(e, "stderr", b"") or b""
-            logger.warning("native %s build failed (%s); using the Python "
-                           "path. stderr: %s", what, e,
-                           err.decode(errors="replace")[-500:])
-            try:
-                os.unlink(tmp)
-            except OSError:
-                pass
-            return False
-        os.replace(tmp, lib_path)
-        return True
+                subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+            except (OSError, subprocess.SubprocessError) as e:
+                err = getattr(e, "stderr", b"") or b""
+                logger.warning("native %s build failed (%s); using the Python "
+                               "path. stderr: %s", what, e,
+                               err.decode(errors="replace")[-500:])
+                try:
+                    os.unlink(tmp)
+                except OSError:
+                    pass
+                return False
+            os.replace(tmp, lib_path)
+            return True
 
-    if not os.path.exists(lib_path) and not build():
-        return None
-    try:
-        lib = ctypes.CDLL(lib_path)
-        if getattr(lib, abi_symbol)() != abi_version:
-            raise OSError(f"stale {os.path.basename(lib_path)} ABI; rebuild")
-    except OSError:
-        # stale or broken cache: rebuild once
-        if not build():
+        if not os.path.exists(lib_path) and not build():
             return None
-        lib = ctypes.CDLL(lib_path)
-    return lib
+        try:
+            lib = ctypes.CDLL(lib_path)
+            if getattr(lib, abi_symbol)() != abi_version:
+                raise OSError(f"stale {os.path.basename(lib_path)} ABI; rebuild")
+        except OSError:
+            # stale or broken cache: rebuild once
+            if not build():
+                return None
+            lib = ctypes.CDLL(lib_path)
+        return lib
 
 
 def _load() -> Optional[ctypes.CDLL]:

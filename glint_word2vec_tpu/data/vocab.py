@@ -86,7 +86,10 @@ class Vocabulary:
             return None     # not all str, or a lone surrogate
         table = self._native
         if table is None:
-            table = self._native = _NativeTable(lib, self.words)
+            # once a vocabulary: a pinned span (obs/spans.py)
+            with _tracer().span("vocab.native_table", pinned=True,
+                                words=len(self.words)):
+                table = self._native = _NativeTable(lib, self.words)
         if table.handle is None:
             return None
         out = np.empty(len(tokens), np.int32)
@@ -97,10 +100,11 @@ class Vocabulary:
 
     @classmethod
     def from_words_and_counts(cls, words: Sequence[str], counts: Sequence[int]) -> "Vocabulary":
-        counts = np.asarray(counts, dtype=np.int64)
-        index = {w: i for i, w in enumerate(words)}
-        return cls(words=list(words), counts=counts, index=index,
-                   train_words_count=int(counts.sum()))
+        with _tracer().span("vocab.build", pinned=True, words=len(words)):
+            counts = np.asarray(counts, dtype=np.int64)
+            index = {w: i for i, w in enumerate(words)}
+            return cls(words=list(words), counts=counts, index=index,
+                       train_words_count=int(counts.sum()))
 
     @classmethod
     def from_counter(cls, counter: "collections.Counter[str]", min_count: int) -> "Vocabulary":
@@ -135,6 +139,14 @@ _SEP = "\n"
 
 _lib = None
 _lib_failed = False
+
+
+def _tracer():
+    """The one span recorder (obs/spans.py), imported where a span is taken:
+    it brings ``jax.profiler`` with it, and this module has no other use
+    for jax."""
+    from glint_word2vec_tpu.obs.spans import default_tracer
+    return default_tracer()
 
 
 def _load_native():
@@ -259,22 +271,27 @@ def build_vocab(sentences: Iterable[Sequence[str]], min_count: int = 5,
     the same first-seen order a Python ``Counter`` iterates, so the
     filter/sort below is shared and the vocabulary is identical either way.
     ``workers > 1`` routes the Python path through
-    :func:`count_words_parallel` (bit-identical vocabulary, see there)."""
+    :func:`count_words_parallel` (bit-identical vocabulary, see there).
+    The whole of it is the pinned span ``vocab.build`` (obs/spans.py)."""
     from glint_word2vec_tpu.data.corpus import TokenFileCorpus
-    if isinstance(sentences, TokenFileCorpus) and not sentences.lowercase:
-        from glint_word2vec_tpu.data import ingest_native, native
-        if ingest_native.ingest_available():
-            res = ingest_native.count_words_native(
-                sentences.path, native.default_threads())
-            if res is not None:
-                words, counts = res
-                counter = collections.Counter(
-                    dict(zip(words, (int(c) for c in counts))))
-                return Vocabulary.from_counter(counter, min_count)
-    if parallel_counting_profitable(workers):
-        return Vocabulary.from_counter(
-            count_words_parallel(sentences, workers), min_count)
-    return Vocabulary.from_counter(count_words(sentences), min_count)
+    with _tracer().span("vocab.build", pinned=True) as span:
+        counter = None
+        if isinstance(sentences, TokenFileCorpus) and not sentences.lowercase:
+            from glint_word2vec_tpu.data import ingest_native, native
+            if ingest_native.ingest_available():
+                res = ingest_native.count_words_native(
+                    sentences.path, native.default_threads())
+                if res is not None:
+                    words, counts = res
+                    counter = collections.Counter(
+                        dict(zip(words, (int(c) for c in counts))))
+        if counter is None:
+            counter = (count_words_parallel(sentences, workers)
+                       if parallel_counting_profitable(workers)
+                       else count_words(sentences))
+        vocab = Vocabulary.from_counter(counter, min_count)
+        span.set(words=vocab.size)
+    return vocab
 
 
 def parallel_counting_profitable(workers: int = 2) -> bool:

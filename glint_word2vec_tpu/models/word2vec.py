@@ -23,7 +23,6 @@ import itertools
 import logging
 import math
 import os
-import time
 from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -35,7 +34,7 @@ from glint_word2vec_tpu.config import Word2VecConfig
 from glint_word2vec_tpu.data.subword import NO_ROW as _NO_ROW
 from glint_word2vec_tpu.data.vocab import Vocabulary
 from glint_word2vec_tpu.lockcheck import make_lock
-from glint_word2vec_tpu.obs.spans import default_tracer
+from glint_word2vec_tpu.obs.spans import default_tracer, pinned_call
 from glint_word2vec_tpu.parallel.mesh import (
     MeshPlan, pad_dim_to_lanes, pad_vocab_for_sharding)
 from glint_word2vec_tpu.train import checkpoint as ckpt
@@ -54,6 +53,8 @@ class Word2VecModel:
     (:meth:`_row_table`: 4.61 GB beside syn0's 3.60 at 3M x 300 float32; none
     where D is a multiple of 128, or on a mesh)."""
 
+    @pinned_call("model.init", lambda self: dict(
+        words=self.vocab.size, subword=int(self._buckets is not None)))
     def __init__(
         self,
         vocab: Vocabulary,
@@ -66,6 +67,8 @@ class Word2VecModel:
         position_weights: Optional[np.ndarray] = None,
         subword_rows=None,
     ):
+        """The whole of it is the pinned span ``model.init`` (obs/spans.py),
+        over ``model.compose`` where the model is a subword one."""
         # a position-weighted CBOW model's third leaf
         # (config.cbow_position_weights): trained state kept so that a saved
         # model can be resumed; no query reads it and no export writes it
@@ -139,10 +142,10 @@ class Word2VecModel:
                  buckets, subword_rows=None) -> jax.Array:
         """[V, D] h_w of every word (fastText's ``get_word_vector``;
         ``precomputeWordVectors``), in row blocks on the device
-        (ops/subword.compose_vectors): span ``model.compose``, its seconds
-        kept in ``compose_time`` (a constructor runs before any trace is
-        live). The two parts of the trained input table are read where they
-        lie, the words' own rows as slices and the bucket rows gathered from
+        (ops/subword.compose_vectors): the pinned span ``model.compose``,
+        whose ``dur`` is ``compose_time`` (a constructor runs before any
+        trace is live). The two parts of the trained input table are read
+        where they lie, the words' own rows as slices and the bucket rows gathered from
         their lane-padded form (ops/subword.lane_padded: made here, once,
         and kept for the unseen strings' lists): no [V + K, D] array, and no
         row-major copy of a table whose D is no multiple of 128. The row
@@ -159,8 +162,8 @@ class Word2VecModel:
             raise ValueError(
                 f"{buckets.shape[0]} bucket rows but config.subword_buckets is "
                 f"{config.subword_buckets}")
-        t0 = time.perf_counter()
-        with default_tracer().span("model.compose", words=vocab.size) as sp:
+        with default_tracer().span("model.compose", pinned=True,
+                                   words=vocab.size) as sp:
             raw0 = jnp.asarray(syn0)
             self._raw0 = (raw0 if raw0.shape[0] == vocab.size
                           else raw0[: vocab.size])
@@ -189,10 +192,10 @@ class Word2VecModel:
             composed.block_until_ready()
             sp.set(slots=int(table.counts.sum()),
                    blocks=-(-vocab.size // COMPOSE_BLOCK))
-        self._list_cap = list_capacity(
-            max(map(len, vocab.words)), config.subword_min_n,
-            config.subword_max_n)
-        self.compose_time = time.perf_counter() - t0
+            self._list_cap = list_capacity(
+                max(map(len, vocab.words)), config.subword_min_n,
+                config.subword_max_n)
+        self.compose_time = sp.dur
         return composed
 
     @property
@@ -440,7 +443,12 @@ class Word2VecModel:
             from glint_word2vec_tpu.ops.subword import lane_padded
             with self._lock:
                 if self._lanes is None:
-                    self._lanes = lane_padded(self._full0)
+                    # once a model: a pinned span (obs/spans.py)
+                    with default_tracer().span(
+                            "model.row_table", pinned=True,
+                            rows=int(self._full0.shape[0])):
+                        self._lanes = lane_padded(self._full0)
+                        self._lanes.block_until_ready()
         return self._lanes
 
     def _read_rows(self, ids: Sequence[int]) -> np.ndarray:
@@ -453,7 +461,11 @@ class Word2VecModel:
         """Per-row Euclidean norms, computed once and cached (mllib:486,600-609)."""
         self._check_alive()
         if self._norms is None:
-            self._norms = jnp.linalg.norm(self._full0, axis=1)
+            # once a model: a pinned span (obs/spans.py)
+            with default_tracer().span("model.norms", pinned=True,
+                                       rows=int(self._full0.shape[0])):
+                self._norms = jnp.linalg.norm(self._full0, axis=1)
+                self._norms.block_until_ready()
         return self._norms[: self.vocab.size]
 
     def multiply(self, vector: np.ndarray) -> np.ndarray:

@@ -11,7 +11,9 @@ Seven layers, each usable alone, all off by default and zero-cost when off:
   (rotating file, never stdout — graftlint R7).
 - :mod:`.spans` — the one host span recorder (ids, parents, counts as
   args): on under telemetry or while a ``jax.profiler`` trace is live, when
-  the spans are in the profiler's trace too; Chrome-trace JSON export.
+  the spans are in the profiler's trace too; always on for its pinned
+  spans, the once-a-process regions of the set-up (:mod:`.compile_spans`
+  makes every compilation one); Chrome-trace JSON export.
 - :mod:`.phases` — host-side per-phase log2 duration histograms (producer
   wait / stage / dispatch / device block), the "where did the time go"
   attribution without a trace viewer.

@@ -269,6 +269,10 @@ KINDS_OPTIONAL: Dict[str, Dict[str, tuple]] = {
     "run_start": {
         "wall_ns": (int,),       # time.time_ns() at the same instant as...
         "mono_ns": (int,),       # ...time.monotonic_ns() (the anchor pair)
+        "setup": (dict,),        # the pinned set-up spans up to the fit
+                                 # (Tracer.setup_summary: spans by name
+                                 # {count, total_s}; compiles {programs,
+                                 # cache_hits, cache_misses})
     },
     "heartbeat": {
         "norms": (dict,),        # probe channels, when the probe ran

@@ -23,6 +23,22 @@ takes seconds; spans taken then would have no counterpart in the trace). A
 span that is open when the trace stops is not kept. tests/test_obs.py holds
 that rule against a JAX upgrade.
 
+The third case: a **pinned** span (``pinned=True`` on :meth:`Tracer.span`,
+:meth:`Tracer.open` and :meth:`Tracer.record`) records ALWAYS, telemetry or
+none, trace or none. It is for a region that happens once a process or once
+an object and not once a round: the package's import, the vocabulary,
+``Trainer()`` and its children, every compilation (obs/compile_spans.py), a
+fit up to its first heartbeat, the model's constructor. Those run before any
+trace is live and before a fit's ``run_start`` clears the ring, and there
+are tens of them a process, so each reads the clock twice and appends once
+to a small store of its own (:meth:`Tracer.setup_events`; the last
+``max_setup``, oldest dropped) that :meth:`Tracer.clear` does not empty.
+While telemetry is on or a trace is live a pinned span goes the normal way
+as well, once: the ring, and for a ``with`` block the trace. Both stores
+give ``ts_s`` against the one epoch, so their records can be put in order: a
+pinned span that began before the ring's first record began before the
+trace did. Nothing pinned belongs in a steady-state round, batch or slide.
+
 The two aggregate timers of the trainer (``host_wait_time``,
 ``dispatch_time``) predate this and feed the sink's ``heartbeat`` /
 ``run_end`` records; spans are what says where the time of a dispatch, a
@@ -32,7 +48,8 @@ Design constraints:
 
 - near-zero cost when inactive: ``span()`` returns a shared no-op context
   manager after one attribute read and one ``is_enabled()`` call (no
-  allocation, no clock read): every path can instrument unconditionally;
+  allocation, no clock read): every path can instrument unconditionally
+  (a pinned span is never inactive: it is not for a hot path);
 - one clock: ``time.monotonic`` (:data:`now`), the clock of the serve
   tier's tickets and of the fleet's ``trace_span`` records, so a region is
   timed once and every consumer is fed the span's own ``t0`` / ``dur``;
@@ -59,6 +76,7 @@ and cross-thread overlap without a custom viewer.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import json
 import threading
@@ -171,13 +189,28 @@ class _Span:
                 self.recorded = True
 
 
+class _PinnedSpan(_Span):
+    """A once-a-process or once-an-object region (module docstring): a span
+    that reaches the set-up store whether or not it reached the ring."""
+
+    __slots__ = ()
+
+    def close(self, end: Optional[float] = None, keep: bool = True) -> None:
+        super().close(end, keep)
+        if keep:
+            self._tracer._pin(self.name, self.t0, self.dur, self.args,
+                              self.id, self.parent)
+
+
 class Tracer:
     """Collects complete ("X") spans; exports the Chrome trace event format."""
 
-    def __init__(self, enabled: bool = False, max_events: int = 200_000):
+    def __init__(self, enabled: bool = False, max_events: int = 200_000,
+                 max_setup: int = 1024):
         from collections import deque
         self.enabled = enabled
         self.max_events = int(max_events)
+        self.max_setup = int(max_setup)
         # RLock: the flight recorder's SIGTERM dump (main thread) reads
         # span_summary() — a plain Lock held by the interrupted thread's
         # own _record() would deadlock the handler (obs/blackbox.py)
@@ -185,6 +218,9 @@ class Tracer:
         # deque(maxlen): appending past capacity drops the OLDEST in O(1) —
         # the tail of a long run is what a hang/slowdown investigation needs
         self._events: "deque" = deque(maxlen=self.max_events)
+        # the pinned spans' store (module docstring): start kept on ``now``
+        # itself, so that clear() moving the epoch leaves them in order
+        self._setup: "deque" = deque(maxlen=self.max_setup)
         self._epoch = now()
         self._phases = None  # PhaseAccumulator of the running trainer, or None
         self._local = threading.local()  # per-thread stack of open span ids
@@ -200,6 +236,8 @@ class Tracer:
         self._phases = acc
 
     def clear(self) -> None:
+        """Empty the ring and move the epoch: a run's trace then describes
+        that run. The pinned spans stay (module docstring)."""
         with self._lock:
             self._events.clear()
             self._epoch = now()
@@ -219,37 +257,50 @@ class Tracer:
         return stack[-1] if stack else None
 
     def span(self, name: str, *, parent: Optional[int] = None,
-             timed: bool = False, **args):
+             timed: bool = False, pinned: bool = False, **args):
         """Context manager timing one region on the calling thread. Its
         parent is the enclosing span of this thread, or ``parent`` (a span's
         ``id``) for work another thread caused. ``timed=True`` is for a
         caller that needs the region's ``t0`` / ``dur`` itself (the batcher's
         service-time estimate, the fleet's ``trace_span`` records): the clock
-        is then read even when nothing is recorded, once, by the span."""
+        is then read even when nothing is recorded, once, by the span.
+        ``pinned=True`` is for a once-a-process or once-an-object region
+        (module docstring): it records whatever else does."""
         live = TraceAnnotation.is_enabled()
+        if pinned:
+            return _PinnedSpan(self, name, args or None, parent, True, live)
         if self.enabled or live:
             return _Span(self, name, args or None, parent, True, live)
         if timed:
             return _Span(self, name, None, None, False, False)
         return _NOOP
 
-    def open(self, name: str, **args) -> Optional[_Span]:
+    def open(self, name: str, *, pinned: bool = False,
+             **args) -> Optional[_Span]:
         """A span already entered, for a region that begins in one method
         and ends in another, where no ``with`` block can hold it: the owner
         ends it with :meth:`_Span.close`, innermost first. ``None`` when
         nothing records."""
-        span = self.span(name, **args)
+        span = self.span(name, pinned=pinned, **args)
         if span is _NOOP:
             return None
         return span.__enter__()
 
     def record(self, name: str, t0: float, dur: float, *,
-               parent: Optional[int] = None, **args) -> None:
+               parent: Optional[int] = None, pinned: bool = False,
+               **args) -> int:
         """A retroactive span: start (on :data:`now`) and duration known only
         afterwards, as a ticket's queue wait is. To the ring only; the caller
         asks first whether anything records (its enclosing span's
-        ``recorded``)."""
-        self._record(name, t0, dur, args or None, next(_IDS), parent)
+        ``recorded``). A ``pinned`` one asks nothing: it goes to the set-up
+        store, and to the ring where telemetry is on or a trace is live.
+        Returns the span's ``id``, for a child recorded after it."""
+        span_id = next(_IDS)
+        if not pinned or self.enabled or TraceAnnotation.is_enabled():
+            self._record(name, t0, dur, args or None, span_id, parent)
+        if pinned:
+            self._pin(name, t0, dur, args or None, span_id, parent)
+        return span_id
 
     def wrap_iter(self, name: str, it):
         """Wrap an iterator so each ``next()`` is a span ON THE CONSUMING
@@ -283,15 +334,55 @@ class Tracer:
         with self._lock:
             self._events.append(ev)
 
+    def _pin(self, name: str, t0: float, dur: float, args: Optional[dict],
+             span_id: int, parent: Optional[int]) -> None:
+        ev = (name, threading.get_ident(), threading.current_thread().name,
+              t0, dur, args, span_id, parent)
+        with self._lock:
+            self._setup.append(ev)
+
     # -- introspection / export -------------------------------------------------
+
+    @staticmethod
+    def _dicts(evs, epoch: float = 0.0) -> List[dict]:
+        return [{"name": n, "tid": tid, "thread": tname, "ts_s": ts - epoch,
+                 "dur_s": dur, "id": sid, "parent": parent,
+                 **({"args": a} if a else {})}
+                for n, tid, tname, ts, dur, a, sid, parent in evs]
 
     def events(self) -> List[dict]:
         with self._lock:
             evs = list(self._events)
-        return [{"name": n, "tid": tid, "thread": tname, "ts_s": ts,
-                 "dur_s": dur, "id": sid, "parent": parent,
-                 **({"args": a} if a else {})}
-                for n, tid, tname, ts, dur, a, sid, parent in evs]
+        return self._dicts(evs)
+
+    def setup_events(self) -> List[dict]:
+        """The pinned spans, oldest first, as :meth:`events` gives the
+        ring's: ``ts_s`` is against the ring's epoch as it stands now, so it
+        is negative for what began before the last :meth:`clear`, and the
+        two lists can be put in order."""
+        with self._lock:
+            evs, epoch = list(self._setup), self._epoch
+        return self._dicts(evs, epoch)
+
+    def setup_summary(self) -> Dict[str, dict]:
+        """The pinned store's digest (the ``setup`` of a ``run_start`` record
+        and of ``status_snapshot()``): ``spans`` by name {count, total_s},
+        and ``compiles``: the programs built or loaded (``xla.compile`` at
+        stage ``backend``), how many of them the persistent cache held and
+        how many it lacked."""
+        spans: Dict[str, dict] = {}
+        compiles = {"programs": 0, "cache_hits": 0, "cache_misses": 0}
+        for ev in self.setup_events():
+            s = spans.setdefault(ev["name"], {"count": 0, "total_s": 0.0})
+            s["count"] += 1
+            s["total_s"] = round(s["total_s"] + ev["dur_s"], 6)
+            args = ev.get("args", {})
+            if ev["name"] == "xla.compile" and args.get("stage") == "backend":
+                compiles["programs"] += 1
+                if args.get("cache") in ("hit", "miss"):
+                    key = "cache_hits" if args["cache"] == "hit" else "cache_misses"
+                    compiles[key] += 1
+        return {"spans": spans, "compiles": compiles}
 
     def span_summary(self) -> Dict[str, dict]:
         """Per-span-name {count, total_s, max_s} — the run_end digest."""
@@ -306,10 +397,19 @@ class Tracer:
 
     def export_chrome_trace(self, path: str) -> int:
         """Write the collected spans as a Chrome-trace JSON file; returns the
-        event count. Thread ids are remapped to small ints in first-seen
-        order, with metadata events naming each thread."""
+        event count. The pinned spans come first (one that the ring holds as
+        well is written once), then the ring's. Thread ids are remapped to
+        small ints in first-seen order, with metadata events naming each
+        thread. Where a pinned span began before the ring's epoch, every
+        ``ts`` is moved by the same amount, so that the earliest reads 0."""
         with self._lock:
-            evs = list(self._events)
+            evs, epoch = list(self._events), self._epoch
+            pinned = list(self._setup)
+        in_store = {ev[6] for ev in pinned}
+        evs = [(n, tid, tname, t0 - epoch, *rest)
+               for n, tid, tname, t0, *rest in pinned] + [
+            ev for ev in evs if ev[6] not in in_store]
+        base = min([0.0] + [ev[3] for ev in evs])
         tid_map: Dict[int, int] = {}
         names: Dict[int, str] = {}
         trace = []
@@ -317,7 +417,8 @@ class Tracer:
             small = tid_map.setdefault(tid, len(tid_map))
             names.setdefault(small, tname)
             trace.append({"ph": "X", "name": n, "pid": 0, "tid": small,
-                          "ts": round(ts * 1e6, 1), "dur": round(dur * 1e6, 1),
+                          "ts": round((ts - base) * 1e6, 1),
+                          "dur": round(dur * 1e6, 1),
                           "args": {"id": sid, "parent": parent, **(a or {})}})
         meta = [{"ph": "M", "name": "thread_name", "pid": 0, "tid": small,
                  "args": {"name": tname}} for small, tname in names.items()]
@@ -330,7 +431,27 @@ class Tracer:
 _default = Tracer()
 
 
+def pinned_call(name: str, args_of=None):
+    """Decorator: the whole of a method (a constructor) as one pinned span
+    of the process-wide tracer, the parent of whatever the method records.
+    ``args_of(self)`` gives the span's args once the method has returned."""
+
+    def wrap(method):
+        @functools.wraps(method)
+        def inner(self, *a, **kw):
+            with _default.span(name, pinned=True) as span:
+                out = method(self, *a, **kw)
+                if args_of is not None:
+                    span.set(**args_of(self))
+            return out
+
+        return inner
+
+    return wrap
+
+
 def default_tracer() -> Tracer:
     """The process-wide tracer: records under run telemetry or a live
-    ``jax.profiler`` trace, and is a no-op otherwise."""
+    ``jax.profiler`` trace, and is a no-op otherwise, but for its pinned
+    spans."""
     return _default

@@ -147,7 +147,10 @@ class EmbeddingService:
         ``process_name``: the fleet-timeline track label stamped on this
         service's clock anchor, trace spans, and blackbox dump (default
         ``serve-<pid>``; the fleet spawner passes the replica name so the
-        collector's tracks read r0/r1/... instead of pids)."""
+        collector's tracks read r0/r1/... instead of pids).
+
+        From here to its threads up it is the pinned span ``service.start``
+        (obs/spans.py), the parent of a loaded model's ``model.init``."""
         # pure argument validation FIRST — nothing acquired yet
         if (checkpoint is None) == (model is None):
             raise ValueError("pass exactly one of checkpoint= or model=")
@@ -176,31 +179,32 @@ class EmbeddingService:
         self._span_emitter = None
         self._tracer = default_tracer()
         self._dispatch_count = 0
-        t0 = time.perf_counter()
-        # signature BEFORE the load: a publish landing during the slow
-        # load/index build below must still read as unserved afterwards
-        # (reload.publish_signature has the capture rule)
-        pre_sig = (publish_signature(checkpoint)
-                   if checkpoint is not None else None)
-        if model is None:
-            model = load_with_retry(checkpoint, plan=plan)
-        self._nprobe = (int(nprobe) if nprobe
-                        else _knob(model, "serve_ann_nprobe", None)) or None
-        self._ann_centroids = int(
-            _knob(model, "serve_ann_centroids", ann_centroids))
-        # quantized-index knobs (docs/serving.md §6): resolved ONCE here,
-        # then every reload rebuilds at the same arm — a V-grew publish
-        # must not silently change quantization mid-fleet
-        self._ann_quant = str(_knob(model, "serve_ann_quant", ann_quant))
-        self._ann_pq_m = int(_knob(model, "serve_ann_pq_m", ann_pq_m))
-        self._ann_rerank = int(_knob(model, "serve_ann_rerank", ann_rerank))
-        self._ann_recall_floor = float(
-            _knob(model, "serve_ann_recall_floor", ann_recall_floor))
-        self._ann_max_densify = int(
-            _knob(model, "serve_ann_max_densify_bytes",
-                  ann_max_densify_bytes))
-        self._ann_from_shards = bool(ann_from_shards)
+        starting = self._tracer.open("service.start", pinned=True)
         try:
+            t0 = time.perf_counter()
+            # signature BEFORE the load: a publish landing during the slow
+            # load/index build below must still read as unserved afterwards
+            # (reload.publish_signature has the capture rule)
+            pre_sig = (publish_signature(checkpoint)
+                       if checkpoint is not None else None)
+            if model is None:
+                model = load_with_retry(checkpoint, plan=plan)
+            self._nprobe = (int(nprobe) if nprobe
+                            else _knob(model, "serve_ann_nprobe", None)) or None
+            self._ann_centroids = int(
+                _knob(model, "serve_ann_centroids", ann_centroids))
+            # quantized-index knobs (docs/serving.md §6): resolved ONCE here,
+            # then every reload rebuilds at the same arm — a V-grew publish
+            # must not silently change quantization mid-fleet
+            self._ann_quant = str(_knob(model, "serve_ann_quant", ann_quant))
+            self._ann_pq_m = int(_knob(model, "serve_ann_pq_m", ann_pq_m))
+            self._ann_rerank = int(_knob(model, "serve_ann_rerank", ann_rerank))
+            self._ann_recall_floor = float(
+                _knob(model, "serve_ann_recall_floor", ann_recall_floor))
+            self._ann_max_densify = int(
+                _knob(model, "serve_ann_max_densify_bytes",
+                      ann_max_densify_bytes))
+            self._ann_from_shards = bool(ann_from_shards)
             index = self._build_index(model)
             self._handle = ServingHandle(model, index)
             self._load_seconds = time.perf_counter() - t0
@@ -265,12 +269,15 @@ class EmbeddingService:
                     poll_s=float(_knob(model, "serve_reload_poll_s",
                                        reload_poll_s)),
                     loaded_signature=pre_sig).start()
+            starting.set(words=model.num_words, ann=int(index is not None))
+            starting.close()
         except BaseException:
+            starting.close(keep=False)
             # a failed init must not leak the batcher thread, the bound
             # status socket, the sink file, or the loaded model's buffers
             # (the caller has no service reference to close())
             if self._handle is None:
-                if self._owns_model:
+                if self._owns_model and model is not None:
                     model.stop()
             self.close()
             raise

@@ -31,6 +31,8 @@ import numpy as np
 from glint_word2vec_tpu.config import Word2VecConfig
 from glint_word2vec_tpu.data.pipeline import epoch_batches, epoch_batches_cbow
 from glint_word2vec_tpu.data.vocab import Vocabulary
+from glint_word2vec_tpu.obs.spans import (
+    TraceAnnotation, default_tracer, now as span_clock, pinned_call)
 from glint_word2vec_tpu.ops.sampler import build_alias_table, sample_negatives_hash
 from glint_word2vec_tpu.ops.sgns import (
     EmbeddingPair,
@@ -639,6 +641,9 @@ class HeartbeatRecord:
 class Trainer:
     """Owns the sharded embedding pair and runs the synchronous SGNS/CBOW loop."""
 
+    @pinned_call("trainer.init", lambda self: dict(
+        words=self.vocab.size,
+        mesh=f"{self.plan.num_data}x{self.plan.num_model}"))
     def __init__(
         self,
         config: Word2VecConfig,
@@ -647,13 +652,23 @@ class Trainer:
         params: Optional[EmbeddingPair] = None,
         train_state: Optional[TrainState] = None,
     ):
+        """The whole of it is the pinned span ``trainer.init`` (obs/spans.py,
+        docs/observability.md §4), and each of its phases a pinned child:
+        ``trainer.resolve_auto``, ``sampler.alias_table``, ``params.place``,
+        ``vocab.subword_table`` / ``vocab.huffman_tree``,
+        ``trainer.capacities``, ``trainer.build_step``."""
+        self._tracer = default_tracer()
         self.config = config
         self.vocab = vocab
+        # passes over the counts that the AUTO resolutions made
+        # (_duplicate_load): the ``passes`` of ``trainer.resolve_auto``
+        self._auto_passes = 0
         # vocab-scaled AUTO pool (EVAL.md round-5): config resolved the pool
         # without seeing the vocabulary; at > 500k words the measured safe
         # load band tightens 600 -> 160, so a still-AUTO pool re-resolves
         # upward here. Must run before anything reads config.negative_pool.
-        self._resolve_vocab_scaled_pool()
+        with self._tracer.span("trainer.resolve_auto", pinned=True, passes=0):
+            self._resolve_vocab_scaled_pool()
         config = self.config
         if plan is None:
             shape = config.mesh_shape or (config.num_data_shards, config.num_model_shards)
@@ -729,70 +744,81 @@ class Trainer:
             self.table = self._table_prob = self._table_alias = None
             self._sampler_args: tuple = ()
         else:
-            self.table = build_alias_table(vocab.counts, config.sample_power,
-                                           workers=config.io_workers)
-            # replicated device copies, passed into the jitted chunk as ARGUMENTS
-            # every dispatch — closure-captured constants take a catastrophically
-            # slow gather path on TPU (see ops/prng.py)
-            tabs = put_global(plan.replicated,
-                              {"prob": np.asarray(self.table.prob),
-                               "alias": np.asarray(self.table.alias)})
+            with self._tracer.span("sampler.alias_table", pinned=True,
+                                   words=vocab.size):
+                self.table = build_alias_table(
+                    vocab.counts, config.sample_power,
+                    workers=config.io_workers)
+                # replicated device copies, passed into the jitted chunk as
+                # ARGUMENTS every dispatch — closure-captured constants take a
+                # catastrophically slow gather path on TPU (see ops/prng.py)
+                tabs = put_global(plan.replicated,
+                                  {"prob": np.asarray(self.table.prob),
+                                   "alias": np.asarray(self.table.alias)})
             self._table_prob = tabs["prob"]
             self._table_alias = tabs["alias"]
             self._sampler_args = (self._table_prob, self._table_alias)
-        self._root_key = jax.random.key(config.seed)
-        if params is None:
-            params = init_embeddings(
-                self._syn0_rows, config.vector_size,
-                jax.random.fold_in(self._root_key, 0),
-                dtype=jnp.dtype(config.param_dtype))
-            if config.subword:      # syn1 has the vocabulary's rows alone
-                params = EmbeddingPair(params.syn0,
-                                       params.syn1[:self.padded_vocab])
-        if config.cbow_position_weights and params.pos is None:
-            # the model starts as plain CBOW: every position weighs one
-            params = params._replace(pos=jnp.ones(
-                (2 * config.window, config.vector_size),
-                jnp.dtype(config.param_dtype)))
-        if (params.pos is not None) != config.cbow_position_weights or (
-                params.pos is not None
-                and params.pos.shape[0] != 2 * config.window):
-            raise ValueError(
-                f"cbow_position_weights={config.cbow_position_weights} with "
-                f"window={config.window} needs position weights of "
-                f"{2 * config.window} rows, and the params hold "
-                f"{None if params.pos is None else params.pos.shape}")
-        # the carry's shardings: the tables by rows, the position weights (a
-        # few rows every example reads) on every device
-        self._params_sharding = EmbeddingPair(
-            self._emb_sharding, self._emb_sharding,
-            None if params.pos is None else plan.replicated)
-        pos = params.pos
-        if (isinstance(params.syn0, jax.Array)
-                and params.syn0.shape == (self._syn0_rows, self.padded_dim)
-                and params.syn0.dtype == jnp.dtype(config.param_dtype)
-                and params.syn0.sharding.is_equivalent_to(self._emb_sharding, 2)):
-            # already padded and placed (e.g. streamed in by load_params_into_plan)
-            self.params = params
-        else:
-            params = self._pad_params(params)
-            placed = put_global(
-                self._emb_sharding,
-                # every process computes the same deterministic init (same key), so
-                # the callback assembly is consistent across hosts
-                {"syn0": np.asarray(params.syn0), "syn1": np.asarray(params.syn1)})
-            self.params = EmbeddingPair(placed["syn0"], placed["syn1"])
-        if pos is not None and not (
-                isinstance(pos, jax.Array)
-                and pos.shape == (2 * config.window, self.padded_dim)
-                and pos.dtype == jnp.dtype(config.param_dtype)
-                and pos.sharding.is_equivalent_to(plan.replicated, 2)):
-            # lane-padded as the tables are, the padding exactly 0
-            padded = np.zeros((2 * config.window, self.padded_dim),
-                              jnp.dtype(config.param_dtype))
-            padded[:, :pos.shape[1]] = np.asarray(pos)
-            pos = put_global(plan.replicated, {"pos": padded})["pos"]
-        self.params = self.params._replace(pos=pos)
+        # ``placed``: 0 where the tables came in padded and placed
+        with self._tracer.span("params.place", pinned=True,
+                               placed=1) as place:
+            self._root_key = jax.random.key(config.seed)
+            if params is None:
+                params = init_embeddings(
+                    self._syn0_rows, config.vector_size,
+                    jax.random.fold_in(self._root_key, 0),
+                    dtype=jnp.dtype(config.param_dtype))
+                if config.subword:      # syn1 has the vocabulary's rows alone
+                    params = EmbeddingPair(params.syn0,
+                                           params.syn1[:self.padded_vocab])
+            if config.cbow_position_weights and params.pos is None:
+                # the model starts as plain CBOW: every position weighs one
+                params = params._replace(pos=jnp.ones(
+                    (2 * config.window, config.vector_size),
+                    jnp.dtype(config.param_dtype)))
+            if (params.pos is not None) != config.cbow_position_weights or (
+                    params.pos is not None
+                    and params.pos.shape[0] != 2 * config.window):
+                raise ValueError(
+                    f"cbow_position_weights={config.cbow_position_weights} "
+                    f"with window={config.window} needs position weights of "
+                    f"{2 * config.window} rows, and the params hold "
+                    f"{None if params.pos is None else params.pos.shape}")
+            # the carry's shardings: the tables by rows, the position weights
+            # (a few rows every example reads) on every device
+            self._params_sharding = EmbeddingPair(
+                self._emb_sharding, self._emb_sharding,
+                None if params.pos is None else plan.replicated)
+            pos = params.pos
+            if (isinstance(params.syn0, jax.Array)
+                    and params.syn0.shape == (self._syn0_rows, self.padded_dim)
+                    and params.syn0.dtype == jnp.dtype(config.param_dtype)
+                    and params.syn0.sharding.is_equivalent_to(
+                        self._emb_sharding, 2)):
+                # already padded and placed (e.g. streamed in by
+                # load_params_into_plan)
+                self.params = params
+                place.set(placed=0)
+            else:
+                params = self._pad_params(params)
+                placed = put_global(
+                    self._emb_sharding,
+                    # every process computes the same deterministic init
+                    # (same key), so the callback assembly is consistent
+                    # across hosts
+                    {"syn0": np.asarray(params.syn0),
+                     "syn1": np.asarray(params.syn1)})
+                self.params = EmbeddingPair(placed["syn0"], placed["syn1"])
+            if pos is not None and not (
+                    isinstance(pos, jax.Array)
+                    and pos.shape == (2 * config.window, self.padded_dim)
+                    and pos.dtype == jnp.dtype(config.param_dtype)
+                    and pos.sharding.is_equivalent_to(plan.replicated, 2)):
+                # lane-padded as the tables are, the padding exactly 0
+                padded = np.zeros((2 * config.window, self.padded_dim),
+                                  jnp.dtype(config.param_dtype))
+                padded[:, :pos.shape[1]] = np.asarray(pos)
+                pos = put_global(plan.replicated, {"pos": padded})["pos"]
+            self.params = self.params._replace(pos=pos)
         self.state = train_state or TrainState()
         # additive checkpoint-metadata keys (train/checkpoint.py
         # extra_metadata) merged into EVERY save this trainer performs —
@@ -928,13 +954,14 @@ class Trainer:
         self._scale_fn: Optional[Callable] = None   # scripted finite blowup
         # run-telemetry layer (docs/observability.md) — all lazy/no-op when
         # config.telemetry_path is empty and norm_watch is "off"
-        from glint_word2vec_tpu.obs.spans import default_tracer
         from glint_word2vec_tpu.obs.watch import NormWatchdog
-        self._tracer = default_tracer()
         # the open spans of a heartbeat round, outermost first (heartbeat,
         # then heartbeat.refill): begun in _finish_round, ended in
         # _after_dispatch; empty whenever nothing records
         self._round: List = []
+        # a fit up to its first heartbeat (fit(), _record_first_heartbeat)
+        self._first_beat: Optional[List[Optional[float]]] = None
+        self._first_beat_ann = None
         self._telemetry = None
         if config.telemetry_path:
             from glint_word2vec_tpu.obs.sink import TelemetrySink
@@ -997,7 +1024,8 @@ class Trainer:
             self._place_subword_table()
         if config.loss == "hs":
             self._place_path_table()
-        self._build_step_twins()
+        with self._tracer.span("trainer.build_step", pinned=True):
+            self._build_step_twins()
 
     # -- setup -------------------------------------------------------------------------
 
@@ -1074,25 +1102,35 @@ class Trainer:
 
     def _place_subword_table(self) -> None:
         """Build the vocabulary's row table (data/subword.py) and put it on
-        the device: span ``vocab.subword_table``, its seconds kept in
+        the device: the pinned span ``vocab.subword_table``, whose ``dur`` is
         ``subword_table_time``; the step's shape (ops/subword.py) takes the
         center-run capacity the plain step has and, under it, the word
         capacity :func:`_word_cap` derives from the counts and the slot
         capacity :func:`_word_slot_cap` derives from them and the lists'
         lengths; a CBOW token block's takes the slot capacity
         :func:`_slot_cap` and the tail capacity :func:`_tail_cap` derive."""
-        from glint_word2vec_tpu.data.subword import GROUP, build_subword_table
-        from glint_word2vec_tpu.ops import subword as sw
+        from glint_word2vec_tpu.data.subword import build_subword_table
         cfg = self.config
-        t0 = time.perf_counter()
-        with self._tracer.span("vocab.subword_table",
+        with self._tracer.span("vocab.subword_table", pinned=True,
                                words=self.vocab.size) as span:
             rows = build_subword_table(
                 self.vocab.words, cfg.subword_min_n, cfg.subword_max_n,
                 cfg.subword_buckets)
             self._step_extra = self._put_row_table(rows)
             span.set(slots=rows.slots)
-        self.subword_table_time = time.perf_counter() - t0
+        self.subword_table_time = span.dur
+        with self._tracer.span("trainer.capacities", pinned=True):
+            self._derive_subword_shape(rows)
+        logger.info("subword table: %d words, %d slots, %s in %.2fs",
+                    self.vocab.size, rows.slots, self._subword_shape,
+                    self.subword_table_time)
+
+    def _derive_subword_shape(self, rows) -> None:
+        """The subword step's shape from the row table and the counts: the
+        capacity derivations of :meth:`_place_subword_table`'s docstring."""
+        from glint_word2vec_tpu.data.subword import GROUP
+        from glint_word2vec_tpu.ops import subword as sw
+        cfg = self.config
         if self._banded_cbow:
             # the row source of a token block (ops/cbow_banded.py): every
             # token slot of the block reads its own word's list; the lists'
@@ -1122,32 +1160,29 @@ class Trainer:
                                    else (1, cfg.pairs_per_batch)),
                 _WORD_MAX_RUN, word_cap, slot_cap=_word_slot_cap(
                     *kept, rows.counts, word_cap * rows.max_groups * GROUP))
-        logger.info("subword table: %d words, %d slots, %s in %.2fs",
-                    self.vocab.size, rows.slots, self._subword_shape,
-                    self.subword_table_time)
 
     def _place_path_table(self) -> None:
         """Build the vocabulary's Huffman tree and every word's path
-        (data/huffman.py) and put the path table on the device: span
-        ``vocab.huffman_tree``, its seconds kept in ``hs_tree_time``; the
+        (data/huffman.py) and put the path table on the device: the pinned
+        span ``vocab.huffman_tree``, whose ``dur`` is ``hs_tree_time``; the
         step's shape (ops/hs.py) takes the capacities :func:`_hs_caps`
         derives from the counts and the paths' lengths."""
         from glint_word2vec_tpu.data.huffman import build_path_table
         from glint_word2vec_tpu.data.subword import GROUP
         from glint_word2vec_tpu.ops.hs import HsShape
         cfg = self.config
-        t0 = time.perf_counter()
-        with self._tracer.span("vocab.huffman_tree",
+        with self._tracer.span("vocab.huffman_tree", pinned=True,
                                words=self.vocab.size) as span:
             paths = build_path_table(self.vocab.counts)
             self._step_extra = self._put_row_table(paths)
             span.set(nodes=self.vocab.size - 1,
                      max_code_len=int(paths.counts.max()), slots=paths.slots)
-        self.hs_tree_time = time.perf_counter() - t0
-        word_cap, slot_cap = (0, 0) if self.plan.num_data > 1 else _hs_caps(
-            self.vocab.counts, self.vocab.train_words_count,
-            cfg.subsample_ratio, cfg.window, cfg.pairs_per_batch,
-            paths.counts, paths.max_groups * GROUP)
+        self.hs_tree_time = span.dur
+        with self._tracer.span("trainer.capacities", pinned=True):
+            word_cap, slot_cap = (0, 0) if self.plan.num_data > 1 else _hs_caps(
+                self.vocab.counts, self.vocab.train_words_count,
+                cfg.subsample_ratio, cfg.window, cfg.pairs_per_batch,
+                paths.counts, paths.max_groups * GROUP)
         self._hs_shape = HsShape(paths.max_groups, _HS_MAX_RUN, word_cap,
                                  slot_cap)
         logger.info("huffman tree: %d words, code lengths up to %d, %d slots, "
@@ -1244,6 +1279,7 @@ class Trainer:
         subsample ratio — the divergence channel's driving quantity (EVAL.md)."""
         from glint_word2vec_tpu.data.pipeline import keep_probabilities
         cfg = self.config
+        self._auto_passes += 1
         keep = keep_probabilities(
             self.vocab.counts, self.vocab.train_words_count, subsample_ratio)
         eff = np.asarray(self.vocab.counts, np.float64) * keep
@@ -1273,7 +1309,14 @@ class Trainer:
         (config.allow_unstable overrides to the old warn-only behavior). The
         reference never faces this channel — its async 50-pair minibatches
         interleave a frequent word's updates instead of summing them
-        (mllib:417-429)."""
+        (mllib:417-429). The pinned span ``trainer.resolve_auto``, with the
+        passes over the counts it made as ``passes``."""
+        before = self._auto_passes
+        with self._tracer.span("trainer.resolve_auto", pinned=True) as span:
+            self._bound_duplicate_channel()
+            span.set(passes=self._auto_passes - before)
+
+    def _bound_duplicate_channel(self) -> None:
         cfg = self.config
         if cfg.duplicate_scaling:
             return  # mean-update semantics bound the channel by construction
@@ -1792,6 +1835,12 @@ class Trainer:
         source (every non-continual fit), behavior unchanged.
         """
         cfg = self.config
+        # the fit up to its first heartbeat, once a fit: the pinned spans
+        # ``fit.first_heartbeat`` and its child ``fit.first_dispatch``, both
+        # recorded by the first heartbeat round (_record_first_heartbeat)
+        # from these two readings of the recorder's clock: this one, and the
+        # first ``dispatch.enqueue``'s return; None once they are recorded
+        self._first_beat = [span_clock(), None]
         # where this fit publishes checkpoints — the SIGTERM preemption hook
         # (config.checkpoint_on_preempt) drains its emergency save here, so
         # the handler needs it before the run's bookkeeping starts
@@ -1820,6 +1869,13 @@ class Trainer:
         feed = make(self, sentences, float(train_words), total_words, K)
 
         self._start_run_bookkeeping()
+        # under a live trace (config.profile_dir starts one just above)
+        # ``fit.first_heartbeat`` is in the trace too, from here: on the
+        # device's clock beside the first step's operations
+        self._first_beat_ann = None
+        if TraceAnnotation.is_enabled():
+            self._first_beat_ann = TraceAnnotation("fit.first_heartbeat")
+            self._first_beat_ann.__enter__()
         beacons = (self._start_peer_beacons(checkpoint_path) if gathered
                    else None)
         rounds = feed.rounds(beacons)
@@ -1855,6 +1911,9 @@ class Trainer:
                         self.params, out = self._dispatch_step_fn(rnd.real)(
                             self.params, stacked, meta_dev, base_dev,
                             *feed.step_args, *bases_dev, *self._step_extra)
+                    if (self._first_beat is not None
+                            and self._first_beat[1] is None):
+                        self._first_beat[1] = span_clock()
                 self.dispatch_time += time.perf_counter() - t0
                 self._after_dispatch()
                 metrics = out
@@ -2145,6 +2204,9 @@ class Trainer:
                 # wall/monotonic reading so tools/obs_collect.py can place
                 # this process's spans on the fleet timeline
                 **clock_anchor(),
+                # what the process did before this fit, by pinned span
+                # (obs/spans.py): Trainer() and its phases, the compiles
+                setup=self._tracer.setup_summary(),
                 mesh=[self.plan.num_data, self.plan.num_model],
                 config={k: getattr(cfg, k) for k in (
                     "vector_size", "learning_rate", "pairs_per_batch",
@@ -2155,6 +2217,29 @@ class Trainer:
                     "norm_watch_frac", "heartbeat_every_steps",
                     "max_row_norm", "update_clip", "row_l2",
                     "recover_lr_backoff", "max_recoveries")})
+
+    def _record_first_heartbeat(self) -> None:
+        """``fit.first_heartbeat``: ``fit()``'s entry to the return of the
+        first heartbeat's ``device_block`` (the feed's construction, the feed
+        thread's first chunk, the first dispatch's compile or load and its
+        run, the probe's). Its child ``fit.first_dispatch`` ends where the
+        first ``dispatch.enqueue`` returned. Both pinned and retroactive
+        (obs/spans.py): the first round's ``dispatch`` and ``heartbeat`` keep
+        the parents every round's have. One pair a fit."""
+        t_fit, t_enqueued = self._first_beat
+        self._first_beat = None
+        self._end_first_beat_annotation()
+        beat = self._tracer.record(
+            "fit.first_heartbeat", t_fit, span_clock() - t_fit, pinned=True,
+            step=self.global_step,
+            steps=self.global_step - self._last_log_step)
+        self._tracer.record("fit.first_dispatch", t_fit, t_enqueued - t_fit,
+                            parent=beat, pinned=True)
+
+    def _end_first_beat_annotation(self) -> None:
+        ann, self._first_beat_ann = self._first_beat_ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
     def _open_round_span(self, name: str, **args) -> None:
         span = self._tracer.open(name, **args)
@@ -2180,6 +2265,7 @@ class Trainer:
         # fit's last save: a heartbeat round no dispatch followed ends too
         if self._round:
             self._close_round(dispatched=False)
+        self._end_first_beat_annotation()   # a fit that never reached one
         if getattr(self, "_profiling", False):
             import jax.profiler
             jax.profiler.stop_trace()
@@ -2572,6 +2658,7 @@ class Trainer:
                 getattr(self, "dispatch_time", 0.0), 3),
             "norms": self._last_probe_channels,
             "phases": self._phases.summary(),
+            "setup": self._tracer.setup_summary(),
         }
 
     @property
@@ -2627,9 +2714,10 @@ class Trainer:
 
     def export_trace(self, path: str) -> int:
         """Export the collected host trace spans as a Chrome-trace JSON file
-        (Perfetto / chrome://tracing loadable); returns the event count. Runs
-        automatically at run end when telemetry is on; callable any time for
-        an on-demand snapshot of a live run."""
+        (Perfetto / chrome://tracing loadable), the pinned set-up spans
+        first; returns the event count. Runs automatically at run end when
+        telemetry is on; callable any time for an on-demand snapshot of a
+        live run."""
         return self._tracer.export_chrome_trace(path)
 
     def _abort_run(self) -> None:
@@ -2821,6 +2909,8 @@ class Trainer:
                         pos, np.float64)[:, :self.config.vector_size] - 1.0
                     blocked.set(position_drift=float(
                         np.sqrt((away * away).sum() / away.size)))
+            if self._first_beat is not None:
+                self._record_first_heartbeat()
             # per-phase attribution over THIS heartbeat window (obs/
             # phases.py): delta of the accumulator the spans + wait sites
             # have been feeding since the previous heartbeat

@@ -261,6 +261,9 @@ def test_nothing_recording_builds_no_span(monkeypatch):
             super().__init__(*a, **kw)
 
     monkeypatch.setattr(spans, "_Span", Counting)
+    # a telemetry-on trainer of an earlier test leaves the recorder armed
+    # until the next trainer is built, and the vocabulary is built before it
+    default_tracer().configure(enabled=False)
     default_tracer().clear()
     trainer, sents = _toy_trainer()
     seen = []

@@ -42,6 +42,11 @@ def _clean_faults():
     faults.reset()
     yield
     faults.reset()
+    # a telemetry-on trainer arms the process-wide recorder until the next
+    # trainer is built; a vocabulary built before that one would put its
+    # pinned span in the next test's ring
+    from glint_word2vec_tpu.obs.spans import default_tracer
+    default_tracer().configure(enabled=False)
 
 
 def _toy_trainer(seed=0, n=250, **cfg_kw):
@@ -745,6 +750,121 @@ def test_wrap_iter_and_export_share_the_id_parent_path(tmp_path):
         xs = [e for e in json.load(f)["traceEvents"] if e["ph"] == "X"]
     assert {e["args"]["parent"] for e in xs} == {None, outer.id}
     assert len({e["args"]["id"] for e in xs}) == 4
+
+
+# -- pinned spans: the once-a-process regions (ISSUE 50) -------------------------------
+
+
+def test_pinned_span_records_with_telemetry_off_and_no_trace_live():
+    tr = Tracer(enabled=False)
+    with tr.span("setup.outer", pinned=True, words=3) as outer:
+        assert tr.current() == outer.id
+        with tr.span("round.inner"):        # unpinned, nothing on: a no-op
+            pass
+        with tr.span("setup.inner", pinned=True) as inner:
+            inner.set(passes=2)
+    tr.record("setup.late", outer.t0, 0.25, parent=outer.id, pinned=True, n=1)
+    assert tr.events() == [] and not outer.recorded
+    evs = {e["name"]: e for e in tr.setup_events()}
+    assert list(evs) == ["setup.inner", "setup.outer", "setup.late"]
+    assert set(evs["setup.outer"]) == {"name", "tid", "thread", "ts_s", "dur_s",
+                                      "id", "parent", "args"}
+    assert evs["setup.outer"]["args"] == {"words": 3}
+    assert evs["setup.inner"]["args"] == {"passes": 2}
+    assert evs["setup.inner"]["parent"] == evs["setup.outer"]["id"]
+    assert evs["setup.late"]["parent"] == evs["setup.outer"]["id"]
+    assert evs["setup.late"]["dur_s"] == 0.25 and outer.dur > 0
+    assert tr.current() is None
+
+
+def test_pinned_spans_survive_clear_and_stay_ordered_against_the_ring():
+    tr = Tracer(enabled=False)
+    with tr.span("before", pinned=True):
+        time.sleep(0.002)
+    tr.clear()          # a fit's run_start: the ring's epoch moves
+    tr.configure(enabled=True)
+    with tr.span("round"):
+        pass
+    with tr.span("after", pinned=True):
+        pass
+    setup = {e["name"]: e for e in tr.setup_events()}
+    ring = {e["name"]: e for e in tr.events()}
+    assert set(setup) == {"before", "after"}
+    # one epoch for both: what began before the clear reads negative, and
+    # the three are in the order they happened
+    assert setup["before"]["ts_s"] + setup["before"]["dur_s"] < 0
+    assert (setup["before"]["ts_s"] < ring["round"]["ts_s"]
+            < setup["after"]["ts_s"])
+    assert ring["after"]["ts_s"] == pytest.approx(setup["after"]["ts_s"],
+                                                  abs=1e-9)
+
+
+def test_pinned_store_is_bounded_and_the_ring_is_what_it_was():
+    tr = Tracer(enabled=True, max_events=8, max_setup=4)
+    for i in range(10):
+        with tr.span("pinned", pinned=True, i=i):
+            with tr.span("plain", i=i):
+                pass
+    assert [e["args"]["i"] for e in tr.setup_events()] == [6, 7, 8, 9]
+    ring = tr.events()
+    # events() is the ring alone: the pinned spans once each among the
+    # plain ones, oldest dropped, no record of the store's added
+    assert len(ring) == 8
+    assert [e["name"] for e in ring] == ["plain", "pinned"] * 4
+    assert len({e["id"] for e in ring}) == 8
+    summary = tr.setup_summary()
+    assert summary["spans"]["pinned"]["count"] == 4
+    assert summary["compiles"] == {"programs": 0, "cache_hits": 0,
+                                   "cache_misses": 0}
+
+
+def test_unpinned_child_of_a_pinned_parent_and_back_by_id():
+    tr = Tracer(enabled=True)
+    with tr.span("setup", pinned=True) as setup:
+        with tr.span("plain") as plain:
+            with tr.span("setup.deep", pinned=True):
+                pass
+    ring = {e["name"]: e for e in tr.events()}
+    store = {e["name"]: e for e in tr.setup_events()}
+    assert set(ring) == {"setup", "plain", "setup.deep"}
+    assert set(store) == {"setup", "setup.deep"}
+    assert ring["plain"]["parent"] == setup.id
+    assert store["setup.deep"]["parent"] == plain.id
+    assert store["setup"]["id"] == ring["setup"]["id"] == setup.id
+
+
+def test_pinned_span_under_a_live_trace_is_once_in_each(live_trace, tmp_path):
+    from glint_word2vec_tpu.obs.spans import default_tracer
+    tracer = default_tracer()
+    mark = len(tracer.setup_events())
+    with tracer.span("t50.plain"):
+        pass
+    with tracer.span("t50.pinned", pinned=True, size=2):
+        pass
+    sid = tracer.record("t50.retro", time.monotonic() - 0.1, 0.1, pinned=True)
+    host = live_trace()
+    ring = [e for e in tracer.events() if e["name"].startswith("t50.")]
+    store = [e for e in tracer.setup_events()[mark:]
+             if e["name"].startswith("t50.")]
+    assert [e["name"] for e in ring] == ["t50.plain", "t50.pinned",
+                                         "t50.retro"]
+    assert [e["name"] for e in store] == ["t50.pinned", "t50.retro"]
+    assert [e["id"] for e in ring[1:]] == [e["id"] for e in store]
+    assert store[1]["id"] == sid
+    # the ``with`` block is in the profiler's host plane too; a retroactive
+    # record reaches the ring only
+    names = [n for n, _, _ in host]
+    assert names.count("t50.pinned") == 1 and "t50.retro" not in names
+    # and the export writes the pinned records first, each once
+    p = str(tmp_path / "trace.json")
+    tracer.export_chrome_trace(p)
+    with open(p) as f:
+        xs = [e for e in json.load(f)["traceEvents"] if e["ph"] == "X"]
+    assert sum(e["name"] == "t50.pinned" for e in xs) == 1
+    assert min(e["ts"] for e in xs) == 0.0
+    pinned_ids = {e["id"] for e in tracer.setup_events()}
+    kinds = [e["args"]["id"] in pinned_ids for e in xs]
+    assert kinds == sorted(kinds, reverse=True) and not kinds[-1]
 
 
 # -- device scopes are metadata only ---------------------------------------------------
