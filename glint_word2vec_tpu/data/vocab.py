@@ -12,13 +12,18 @@ single-pass host-side counter. Multi-host corpora shard by file and merge counte
 from __future__ import annotations
 
 import collections
+import contextlib
+import ctypes
 import itertools
 import os
 import weakref
 from dataclasses import dataclass, field
-from typing import Dict, Iterable, Iterator, List, Sequence
+from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+# around the native walk where the caller hands over no span for it
+_NO_SPAN = contextlib.nullcontext()
 
 
 @dataclass
@@ -54,36 +59,65 @@ class Vocabulary:
     def lookup(self, tokens: Sequence[str]) -> np.ndarray:
         """``int32[len(tokens)]``: every token's index, -1 for a token the
         vocabulary lacks. The batch form of :meth:`get`, with no Python
-        statement a token, for callers that resolve a slide of sentences at
-        a time (``Word2VecModel.transform_sentences``: ~330,000 tokens a
-        call). A batch of :data:`NATIVE_LOOKUP_TOKENS` or more goes to the
-        native table (``native/lookup.cpp``, built from the words at the
-        first such batch): the tokens joined and encoded once, then looked
-        up OUTSIDE the interpreter lock, so callers on several threads
-        resolve their slides side by side (``dict.get`` mapped over the
-        list holds the lock for ~220 ns a token over 3M words: 73 ms a
-        slide, every caller in turn; PERF.md §6, PR 48). Smaller batches,
-        tokens that hold the separator or do not encode, and a host
-        without the toolchain take the mapped ``dict.get``: the same ids."""
+        statement a token. A batch of :data:`NATIVE_LOOKUP_TOKENS` or more
+        goes to the native table (``native/lookup.cpp``, built from the words
+        at the first such batch): one C pass over the list as it lies copies
+        the tokens' UTF-8 bytes under the interpreter lock (~11 ns a token on
+        the chip's host), and the lookup runs OUTSIDE it, so callers on several threads resolve their batches
+        side by side (``dict.get`` mapped over the list holds the lock for
+        ~220 ns a token over 3M words; PERF.md §6, PR 48 and PR 51). Smaller
+        batches, a batch with a token that is no ``str`` or does not encode
+        (a lone surrogate), and a host without the toolchain take the mapped
+        ``dict.get``: the same ids. The C pass asks every ``str`` for its
+        UTF-8 form, which CPython keeps ON a non-ASCII ``str`` from then on:
+        memory that lives as long as the caller's string."""
         if len(tokens) >= NATIVE_LOOKUP_TOKENS:
-            ids = self._native_lookup(tokens)
-            if ids is not None:
-                return ids
+            found = self._native_lookup(tokens, None, _NO_SPAN)
+            if found is not None:
+                return found[0]
+        return self._dict_lookup(tokens)
+
+    def lookup_sentences(self, sentences: Sequence[Sequence[str]], walk_span=_NO_SPAN
+                         ) -> Tuple[np.ndarray, np.ndarray, int, bool]:
+        """:meth:`lookup` of a slide of sentences as the caller holds it, no
+        flattened list made, as a reader of rows wants it: the ``int32`` ids
+        of its in-vocabulary tokens, sentence after sentence in the order
+        sent; every sentence's count of them (``int32[len(sentences)]``; 0
+        for an empty or all-missing one); the tokens the vocabulary lacks;
+        and whether the native table answered (False: ``dict.get`` did, by
+        :meth:`lookup`'s rule over the slide's tokens). A sentence is a
+        list, or any sequence (a tuple, an array), which the C pass makes a
+        list of. ``walk_span``: a context manager to enter around the C pass
+        alone, the part of a native lookup that holds the interpreter lock."""
+        n = len(sentences)
+        if n >= NATIVE_LOOKUP_TOKENS or sum(map(len, sentences)) >= NATIVE_LOOKUP_TOKENS:
+            counts = np.empty(n, np.int32)
+            found = self._native_lookup(sentences, counts, walk_span)
+            if found is not None:
+                ids, tokens = found
+                return ids, counts, tokens - len(ids), True
+        lengths = np.fromiter(map(len, sentences), np.int64, count=n)
+        ids = self._dict_lookup(list(itertools.chain.from_iterable(sentences)))
+        live = ids >= 0
+        before = np.concatenate([[0], np.cumsum(live)])
+        ends = np.cumsum(lengths)
+        counts = (before[ends] - before[ends - lengths]).astype(np.int32)
+        return ids[live], counts, int(len(ids) - live.sum()), False
+
+    def _dict_lookup(self, tokens: Sequence[str]) -> np.ndarray:
         return np.fromiter(
             map(self.index.get, tokens, itertools.repeat(-1)), np.int32,
             count=len(tokens))
 
-    def _native_lookup(self, tokens: Sequence[str]):
-        """:meth:`lookup` through ``native/lookup.cpp``, or None where that
-        cannot answer for the dict."""
+    def _native_lookup(self, batch, counts: Optional[np.ndarray], walk_span):
+        """``batch`` through ``native/lookup.cpp``: its ids and the number of
+        its tokens, or None where that cannot answer for the dict. Without
+        ``counts`` a list of tokens, an id each; with ``counts`` to fill,
+        sentences, whose missing tokens' ids are dropped."""
         from glint_word2vec_tpu.data.native import default_threads
         lib = _load_native()
         if lib is None:
             return None
-        try:
-            blob = _SEP.join(tokens).encode("utf-8")
-        except (TypeError, UnicodeEncodeError):
-            return None     # not all str, or a lone surrogate
         table = self._native
         if table is None:
             # once a vocabulary: a pinned span (obs/spans.py)
@@ -92,11 +126,22 @@ class Vocabulary:
                 table = self._native = _NativeTable(lib, self.words)
         if table.handle is None:
             return None
-        out = np.empty(len(tokens), np.int32)
-        done = lib.glint_lookup_tokens(
-            table.handle, blob, len(blob), _SEP.encode(), len(tokens),
-            out.ctypes.data, min(default_threads(), _LOOKUP_THREADS))
-        return out if done == len(tokens) else None   # a token holds the separator
+        n_tokens = ctypes.c_int64()
+        with walk_span:
+            walk = lib.glint_lookup_walk(
+                batch, -1 if counts is None else len(counts), ctypes.byref(n_tokens))
+        if not walk:
+            return None     # a token that is no str, or a lone surrogate
+        try:
+            out = np.empty(n_tokens.value, np.int32)
+        except BaseException:
+            lib.glint_lookup_walked(None, walk, None, None, 0)  # frees the walk
+            raise
+        kept = lib.glint_lookup_walked(
+            table.handle, walk, out.ctypes.data,
+            None if counts is None else counts.ctypes.data,
+            min(default_threads(), _LOOKUP_THREADS))
+        return out[:kept], n_tokens.value
 
     @classmethod
     def from_words_and_counts(cls, words: Sequence[str], counts: Sequence[int]) -> "Vocabulary":
@@ -124,18 +169,25 @@ class Vocabulary:
 
 
 # tokens in a Vocabulary.lookup batch from which the native table answers:
-# under it the join and the call cost more than the mapped dict.get saves,
-# and a model that only looks up a few words never builds the table
+# under it the two calls cost more than the mapped dict.get saves, and a
+# model that only looks up a few words never builds the table
 NATIVE_LOOKUP_TOKENS = 4096
 
-# threads one native lookup splits its tokens over, at most: four callers'
-# slides side by side read 540,000 sentences/s at 1, 622,000 at 2, 633,000 at
-# 4 and 618,000 at 8 on the chip's 13-core host (PERF.md §6, PR 48)
+# threads one native lookup splits its tokens over, at most. Four callers'
+# slides side by side on the chip's 13-core host, since the walk took the
+# interpreter lock out of the way (PERF.md §6, PR 51): 837,000 sentences/s at
+# 1, 1,025,000 at 2, 1,193,000-1,221,000 at 4, 1,198,000 at 6, 1,197,000-
+# 1,225,000 at 8, 1,212,000 at 13: the lookup matters again up to 4 and is
+# level from there (with the lock as the wall, PR 48: 540,000 / 622,000 /
+# 633,000 / 618,000 at 1 / 2 / 4 / 8). One caller alone encodes a slide in
+# 28.9 / 19.8 / 12.9 / 11.6 ms at 1 / 2 / 4 / 8.
 _LOOKUP_THREADS = 4
 
-# what joins a batch's tokens for the native lookup; a token that holds it
-# sends the batch to the dict
-_SEP = "\n"
+# the interpreter's entry points native/lookup.cpp's walk calls, in the order
+# of its struct Interpreter: stable-ABI symbols of the running process
+_INTERPRETER_SYMBOLS = (
+    "PyList_Type", "PyObject_Type", "Py_DecRef", "PyList_Size", "PyList_GetItem",
+    "PySequence_Check", "PySequence_List", "PyUnicode_AsUTF8AndSize", "PyErr_Clear")
 
 _lib = None
 _lib_failed = False
@@ -151,11 +203,11 @@ def _tracer():
 
 def _load_native():
     """``native/lookup.cpp`` under data/native.py's build-on-first-use
-    contract, or None (``dict.get`` then answers every batch)."""
+    contract, its walk bound to the interpreter, or None (``dict.get`` then
+    answers every batch)."""
     global _lib, _lib_failed
     if _lib is not None or _lib_failed:
         return _lib
-    import ctypes
 
     from glint_word2vec_tpu.data.native import build_or_reload
     src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -163,8 +215,18 @@ def _load_native():
     lib = None
     if not os.environ.get("GLINT_DISABLE_NATIVE"):
         lib = build_or_reload(src, os.path.join(os.path.dirname(src), "liblookup"),
-                              "glint_lookup_abi_version", 1, "c++17", "lookup")
-    if lib is None:
+                              "glint_lookup_abi_version", 2, "c++17", "lookup")
+    if lib is not None:
+        try:
+            symbols = (ctypes.c_void_p * len(_INTERPRETER_SYMBOLS))(*(
+                ctypes.cast(getattr(ctypes.pythonapi, name), ctypes.c_void_p)
+                for name in _INTERPRETER_SYMBOLS))
+            # the walk reads Python objects: a second handle on the same
+            # library, whose calls keep the interpreter lock
+            lib.glint_lookup_walk = ctypes.PyDLL(lib._name).glint_lookup_walk
+        except (AttributeError, OSError):   # an interpreter without them
+            lib = None
+    if lib is None or not lib.glint_lookup_bind(symbols, len(symbols)):
         _lib_failed = True
         return None
     lib.glint_lookup_build.restype = ctypes.c_void_p
@@ -172,11 +234,13 @@ def _load_native():
         ctypes.c_char_p, ctypes.c_void_p, ctypes.c_int64]   # bytes, end, words
     lib.glint_lookup_free.restype = None
     lib.glint_lookup_free.argtypes = [ctypes.c_void_p]
-    lib.glint_lookup_tokens.restype = ctypes.c_int64
-    lib.glint_lookup_tokens.argtypes = [
-        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64,   # table, buf, len
-        ctypes.c_char, ctypes.c_int64, ctypes.c_void_p,     # sep, tokens, out
-        ctypes.c_int32]                                     # threads
+    lib.glint_lookup_walk.restype = ctypes.c_void_p
+    lib.glint_lookup_walk.argtypes = [
+        ctypes.py_object, ctypes.c_int64, ctypes.c_void_p]  # batch, sentences, tokens (out)
+    lib.glint_lookup_walked.restype = ctypes.c_int64
+    lib.glint_lookup_walked.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,  # table, walk, out
+        ctypes.c_void_p, ctypes.c_int32]                    # counts, threads
     _lib = lib
     return _lib
 

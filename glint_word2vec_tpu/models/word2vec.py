@@ -19,7 +19,6 @@ on device.
 from __future__ import annotations
 
 import collections
-import itertools
 import logging
 import math
 import os
@@ -304,8 +303,8 @@ class Word2VecModel:
         ml:453):
 
         - *encode* (host, :meth:`_encode_slide`): the slide's tokens to row ids
-          in one ``Vocabulary.lookup``, OOV tokens dropped here, and every
-          sentence's count of live ids;
+          in one ``Vocabulary.lookup_sentences`` of the slide as it lies, OOV
+          tokens dropped there, and every sentence's count of live ids;
         - *enqueue*: the live ids padded to a row capacity derived from the
           slide (:func:`_grid_up`: a whole number of tiles, a sixteenth of the
           power of two under the live ids each, so slides of like size share a
@@ -321,8 +320,9 @@ class Word2VecModel:
 
         A call of several slides encodes and enqueues slide n + 1 while slide
         n's program and fetch are outstanding (``_SLIDES_IN_FLIGHT``); several
-        threads may call at once. Spans: ``transform.slide`` and its three
-        children (docs/observability.md §4)."""
+        threads may call at once. Spans: ``transform.slide``, its three
+        children and the encode's ``transform.encode.walk``
+        (docs/observability.md §4)."""
         self._check_alive()
         out = np.empty((len(sentences), self.vector_size), np.float32)
         pending: "collections.deque[_PendingSlide]" = collections.deque()
@@ -340,20 +340,16 @@ class Word2VecModel:
         return out
 
     def _encode_slide(self, slide: Sequence[Sequence[str]]
-                      ) -> Tuple[np.ndarray, np.ndarray, int]:
+                      ) -> Tuple[np.ndarray, np.ndarray, int, bool]:
         """One slide's tokens as the program wants them: the ``int32`` row ids
         of its in-vocabulary tokens, sentence after sentence in the order
         sent, every sentence's count of them (``int32[len(slide)]``; 0 for an
-        empty or all-OOV sentence), and the tokens dropped as OOV. One pass
-        over the flattened tokens with no Python statement a token."""
-        lengths = np.fromiter(map(len, slide), np.int64, count=len(slide))
-        ids = self.vocab.lookup(list(itertools.chain.from_iterable(slide)))
-        live = ids >= 0
-        before = np.concatenate([[0], np.cumsum(live)])
-        ends = np.cumsum(lengths)
-        counts = (before[ends] - before[ends - lengths]).astype(np.int32)
-        ids = ids[live]
-        return ids, counts, int(live.shape[0] - ids.shape[0])
+        empty or all-OOV sentence), the tokens dropped as OOV, and whether the
+        native table resolved them: ``Vocabulary.lookup_sentences``, which
+        takes the slide as it lies, with no Python statement a token, under
+        ``transform.encode.walk`` the part that holds the interpreter lock."""
+        return self.vocab.lookup_sentences(
+            slide, default_tracer().span("transform.encode.walk"))
 
     def _transform_begin(self, slide: Sequence[Sequence[str]], lo: int,
                          batch_size: int) -> "_PendingSlide":
@@ -365,8 +361,9 @@ class Word2VecModel:
         n = len(slide)
         span = tracer.open("transform.slide", sentences=n)
         pending = _PendingSlide(lo, n, span)
-        with tracer.span("transform.encode"):
-            ids, counts, oov = self._encode_slide(slide)
+        with tracer.span("transform.encode") as encode:
+            ids, counts, oov, by_objects = self._encode_slide(slide)
+            encode.set(by_objects=int(by_objects))
         live = int(ids.shape[0])
         if span is not None:
             span.set(words=live, oov=oov, empty=int((counts == 0).sum()))

@@ -3,13 +3,23 @@
 // the dict form this must agree with: a token's id is the LAST position of
 // that word in the vocabulary, -1 where no word is the token).
 //
-// Plain C ABI, no Python headers; the table is built once and only read
-// afterwards, so any number of threads may look up at once, and one call
-// splits its tokens over threads whose outputs are disjoint.
+// A batch is taken as the caller holds it, in two calls. glint_lookup_walk,
+// WITH the interpreter lock held (data/vocab.py calls it through a
+// ctypes.PyDLL handle), goes once over the list of str, or the list of
+// sentences of str, and copies every token's UTF-8 bytes into a buffer of
+// its own: no pointer into a Python object outlives the call. Then
+// glint_lookup_walked, with the lock released, looks the buffer's tokens up.
+//
+// Plain C ABI, no Python headers: the walk's handful of interpreter entry
+// points are stable-ABI symbols the process already exports, handed over by
+// address once at load (glint_lookup_bind). The table is built once and only
+// read afterwards, so any number of threads may look up at once, and one
+// call splits its tokens over threads whose outputs are disjoint.
 
 #include <algorithm>
 #include <cstdint>
 #include <cstring>
+#include <memory>
 #include <thread>
 #include <vector>
 
@@ -50,14 +60,110 @@ inline int32_t find(const Table& t, const char* p, int64_t n) {
     }
 }
 
-// tokens of buf[lo, hi): hi ends a token (a separator or the buffer's end)
-void lookup_range(const Table* t, const char* buf, int64_t lo, int64_t hi,
-                  char sep, int32_t* out) {
-    while (lo <= hi) {
-        const char* e = static_cast<const char*>(std::memchr(buf + lo, sep, size_t(hi - lo)));
-        const int64_t stop = e ? e - buf : hi;
-        *out++ = find(*t, buf + lo, stop - lo);
-        lo = stop + 1;
+// The interpreter's entry points, in the order data/vocab.py hands them over
+// (_INTERPRETER_SYMBOLS): a struct of nine pointers.
+struct Interpreter {
+    void* list_type;                              // &PyList_Type
+    void* (*type_of)(void*);                      // PyObject_Type: a new reference
+    void (*drop)(void*);                          // Py_DecRef
+    intptr_t (*list_size)(void*);                 // PyList_Size
+    void* (*list_item)(void*, intptr_t);          // PyList_GetItem: borrowed, nullptr past the end
+    int (*is_sequence)(void*);                    // PySequence_Check
+    void* (*as_list)(void*);                      // PySequence_List: a new reference
+    const char* (*utf8)(void*, intptr_t*);        // PyUnicode_AsUTF8AndSize
+    void (*clear_error)();                        // PyErr_Clear
+};
+Interpreter py{};
+
+// One walked batch: what the lookup reads with the lock released.
+struct Walk {
+    std::vector<char> bytes;      // every token back to back
+    std::vector<int64_t> end;     // end[i]: byte end of token i in bytes
+    std::vector<int64_t> lengths; // a batch of sentences: the tokens of each
+};
+
+bool is_list(void* obj) {
+    void* type = py.type_of(obj);
+    py.drop(type);                // obj keeps its type alive
+    return type == py.list_type;
+}
+
+// obj as a list the walk may index: obj where it is exactly a list, else a
+// new list of the items of a sequence (a tuple, an array, a subclass by its
+// own iterator); nullptr, with or without an error set, for anything else (a
+// generator, a set: the dict's route says what they are worth). `made` says
+// which.
+void* listed(void* obj, bool* made) {
+    *made = !is_list(obj);
+    if (!*made) return obj;
+    return py.is_sequence(obj) ? py.as_list(obj) : nullptr;
+}
+
+// room for n tokens more: a batch grown from nothing moves its bytes at every
+// doubling, which costs as much again as the walk (~10 ns a token)
+void reserve_tokens(Walk& w, int64_t n) {
+    w.end.reserve(w.end.size() + size_t(n));
+    w.bytes.reserve(w.bytes.size() + size_t(n) * 8);
+}
+
+// the str items of list appended to w: their count, or -1 with an error set at
+// an item that is no str or does not encode (a lone surrogate)
+int64_t take_tokens(void* list, Walk& w) {
+    const intptr_t n = py.list_size(list);
+    for (intptr_t i = 0; i < n; ++i) {
+        void* item = py.list_item(list, i);
+        intptr_t len = 0;
+        const char* p = item ? py.utf8(item, &len) : nullptr;
+        if (!p) return -1;
+        w.bytes.insert(w.bytes.end(), p, p + len);
+        w.end.push_back(int64_t(w.bytes.size()));
+    }
+    return n;
+}
+
+// seq's tokens into w: a sequence of str where n_sentences < 0, else a sequence
+// of n_sentences sequences of str. False, with or without an error set, where
+// it is anything else.
+bool take_batch(void* seq, int64_t n_sentences, Walk& w) {
+    bool made = false;
+    void* outer = listed(seq, &made);
+    if (!outer) return false;
+    bool ok = true;
+    if (n_sentences < 0) {
+        reserve_tokens(w, py.list_size(outer));
+        ok = take_tokens(outer, w) >= 0;
+    } else {
+        ok = py.list_size(outer) == n_sentences;
+        int64_t in_lists = 0;
+        for (int64_t s = 0; ok && s < n_sentences; ++s) {
+            void* sentence = py.list_item(outer, intptr_t(s));
+            if (sentence && is_list(sentence)) in_lists += py.list_size(sentence);
+        }
+        reserve_tokens(w, in_lists);
+        w.lengths.reserve(size_t(n_sentences));
+        for (int64_t s = 0; ok && s < n_sentences; ++s) {
+            // making a list of a sentence may run the caller's code: the
+            // outer list is asked anew for every sentence
+            void* sentence = py.list_item(outer, intptr_t(s));
+            bool inner_made = false;
+            void* inner = sentence ? listed(sentence, &inner_made) : nullptr;
+            const int64_t taken = inner ? take_tokens(inner, w) : -1;
+            if (inner && inner_made) py.drop(inner);
+            w.lengths.push_back(taken);
+            ok = taken >= 0;
+        }
+    }
+    if (made) py.drop(outer);
+    return ok;
+}
+
+// tokens [lo, hi) of a walked batch
+void lookup_range(const Table* t, const Walk* w, int64_t lo, int64_t hi, int32_t* out) {
+    const char* bytes = w->bytes.data();
+    int64_t at = lo ? w->end[lo - 1] : 0;
+    for (int64_t i = lo; i < hi; ++i) {
+        out[i] = find(*t, bytes + at, w->end[i] - at);
+        at = w->end[i];
     }
 }
 
@@ -91,47 +197,61 @@ void* glint_lookup_build(const char* bytes, const int64_t* end, int64_t n_words)
 
 void glint_lookup_free(void* table) { delete static_cast<Table*>(table); }
 
-// buf: n_tokens tokens joined by sep; out[i]: the id of token i. Returns
-// n_tokens, or -1 with nothing written where buf holds another number of
-// separators than n_tokens - 1 (a token holds one: the caller's dict answers).
-int64_t glint_lookup_tokens(const void* table, const char* buf, int64_t len,
-                            char sep, int64_t n_tokens, int32_t* out,
-                            int32_t n_threads) {
-    const Table* t = static_cast<const Table*>(table);
-    if (n_tokens <= 0 || std::count(buf, buf + len, sep) != n_tokens - 1) return -1;
-    n_threads = int32_t(std::max<int64_t>(1, std::min<int64_t>(n_threads, n_tokens / 32768 + 1)));
-    if (n_threads == 1) {
-        lookup_range(t, buf, 0, len, sep, out);
-        return n_tokens;
-    }
-    // cut the bytes into n_threads parts at separators, count each part's
-    // tokens, then look the parts up side by side
-    std::vector<int64_t> cut(n_threads + 1, len);
-    cut[0] = -1;                                    // a part starts past its cut
-    for (int32_t k = 1; k < n_threads; ++k) {
-        const int64_t at = std::max(cut[k - 1] + 1, len * k / n_threads);
-        const char* e = at < len
-            ? static_cast<const char*>(std::memchr(buf + at, sep, size_t(len - at))) : nullptr;
-        cut[k] = e ? e - buf : len;
-    }
-    std::vector<int64_t> first(n_threads + 1, 0);
-    std::vector<std::thread> threads;
-    for (int32_t k = 0; k < n_threads; ++k)
-        threads.emplace_back([&, k] {
-            const int64_t lo = cut[k] + 1, hi = cut[k + 1];
-            first[k + 1] = lo > hi ? 0 : 1 + std::count(buf + lo, buf + hi, sep);
-        });
-    for (auto& th : threads) th.join();
-    threads.clear();
-    for (int32_t k = 0; k < n_threads; ++k) first[k + 1] += first[k];
-    for (int32_t k = 0; k < n_threads; ++k)
-        if (cut[k] + 1 <= cut[k + 1])
-            threads.emplace_back(lookup_range, t, buf, cut[k] + 1, cut[k + 1], sep,
-                                 out + first[k]);
-    for (auto& th : threads) th.join();
-    return n_tokens;
+// symbols: the Interpreter's pointers in its order. Returns whether n was
+// their number (nothing is bound otherwise).
+int32_t glint_lookup_bind(const void* const* symbols, int32_t n) {
+    if (size_t(n) * sizeof(void*) != sizeof py) return 0;
+    std::memcpy(&py, symbols, sizeof py);
+    return 1;
 }
 
-int32_t glint_lookup_abi_version() { return 1; }
+// CALLED WITH THE INTERPRETER LOCK HELD. seq: a sequence of str where
+// n_sentences < 0, else a sequence of n_sentences sequences of str. Returns
+// the walked batch, for glint_lookup_walked to look up and free, and its
+// tokens in *n_tokens; or nullptr, any error cleared, where the batch is not
+// this file's to answer (an item that is no str or does not encode, no
+// sequence, another number of sentences): the caller's dict answers.
+void* glint_lookup_walk(void* seq, int64_t n_sentences, int64_t* n_tokens) {
+    std::unique_ptr<Walk> w(new Walk);
+    if (!take_batch(seq, n_sentences, *w)) {
+        py.clear_error();
+        return nullptr;
+    }
+    *n_tokens = int64_t(w->end.size());
+    return w.release();
+}
+
+// out[i]: the id of token i of the walked batch, which is freed (a null table
+// only frees it). With counts, one for every sentence the batch was walked
+// as: the ids under 0 are then dropped from out, the rest moved up in their
+// order, and counts[s] is what sentence s keeps. Returns the ids left in out.
+int64_t glint_lookup_walked(const void* table, void* walk, int32_t* out, int32_t* counts,
+                            int32_t n_threads) {
+    const std::unique_ptr<Walk> w(static_cast<Walk*>(walk));
+    const Table* t = static_cast<const Table*>(table);
+    if (!t) return 0;
+    const int64_t n = int64_t(w->end.size());
+    n_threads = int32_t(std::max<int64_t>(1, std::min<int64_t>(n_threads, n / 32768 + 1)));
+    if (n_threads == 1) {
+        lookup_range(t, w.get(), 0, n, out);
+    } else {
+        std::vector<std::thread> threads;
+        for (int32_t k = 0; k < n_threads; ++k)
+            threads.emplace_back(lookup_range, t, w.get(), n * k / n_threads,
+                                 n * (k + 1) / n_threads, out);
+        for (auto& th : threads) th.join();
+    }
+    if (!counts) return n;
+    int64_t kept = 0, at = 0;
+    for (size_t s = 0; s < w->lengths.size(); ++s) {
+        const int64_t before = kept;
+        for (const int64_t stop = at + w->lengths[s]; at < stop; ++at)
+            if (out[at] >= 0) out[kept++] = out[at];
+        counts[s] = int32_t(kept - before);
+    }
+    return kept;
+}
+
+int32_t glint_lookup_abi_version() { return 2; }
 
 }  // extern "C"

@@ -318,6 +318,25 @@ def _dict_ids(vocab, tokens):
     return [vocab.get(t) for t in tokens]
 
 
+def _dict_sentences(vocab, slide):
+    """What ``lookup_sentences`` owes, from ``dict.get`` a token: the live
+    ids, every sentence's count of them, the tokens that have none."""
+    ids = [[vocab.get(t) for t in sentence] for sentence in slide]
+    live = [[i for i in s if i >= 0] for s in ids]
+    return (sum(live, []), [len(s) for s in live],
+            sum(map(len, ids)) - sum(map(len, live)))
+
+
+def _assert_sentences(vocab, slide, native=None):
+    ids, counts, missing, by_objects = vocab.lookup_sentences(slide)
+    want_ids, want_counts, want_missing = _dict_sentences(vocab, slide)
+    assert ids.dtype == np.int32 and counts.dtype == np.int32
+    assert ids.tolist() == want_ids and counts.tolist() == want_counts
+    assert missing == want_missing and isinstance(missing, int)
+    if native is not None:
+        assert by_objects is native
+
+
 @pytest.fixture()
 def small_batches_go_native(monkeypatch):
     from glint_word2vec_tpu.data import vocab as vocab_module
@@ -326,49 +345,177 @@ def small_batches_go_native(monkeypatch):
     monkeypatch.setattr(vocab_module, "NATIVE_LOOKUP_TOKENS", 1)
 
 
-def test_the_native_table_answers_as_the_dict(small_batches_go_native):
-    words = ["a", "é", "", "a", "b c", "ab", "日本語", "w" * 300]
-    vocab = Vocabulary.from_words_and_counts(words, np.ones(len(words), np.int64))
+@pytest.fixture()
+def odd_vocab():
+    """Words of every width of UTF-8, the empty string, one that holds a line
+    break, one held twice (the last position is its id)."""
+    words = ["a", "é", "", "a", "b c", "ab", "日本語", "w" * 300, "x\ny", "😀", "𝔘𝔫"]
+    return Vocabulary.from_words_and_counts(words, np.ones(len(words), np.int64))
+
+
+def test_the_native_table_answers_as_the_dict(small_batches_go_native, odd_vocab):
     tokens = ["a", "é", "", "zz", "b c", "b", "ab", "abc", "日本語", "日本", "w" * 300,
-              "w" * 299, "A"]
-    got = vocab.lookup(tokens)
-    assert vocab._native.handle is not None
-    assert got.dtype == np.int32 and got.tolist() == _dict_ids(vocab, tokens)
-    assert vocab.get("a") == 3          # a word twice keeps its last position
+              "w" * 299, "A", "x\ny", "x", "\n", "😀", "😀😀", "𝔘𝔫", "𝔘"]
+    got = odd_vocab.lookup(tokens)
+    assert odd_vocab._native.handle is not None
+    assert got.dtype == np.int32 and got.tolist() == _dict_ids(odd_vocab, tokens)
+    assert odd_vocab.get("a") == 3          # a word twice keeps its last position
+    assert odd_vocab.get("x\ny") == 8 and got[13] == 8     # no separator: no rule for it
     rng = np.random.default_rng(3)
     many = [f"w{int(i)}" for i in rng.integers(0, 2 * V, 100_000)]
     big = Vocabulary.from_words_and_counts(zipf.words_of(V), np.ones(V, np.int64))
     assert big.lookup(many).tolist() == _dict_ids(big, many)    # several threads' parts
     assert big.lookup(["w1"] * 70_000 + [""] * 3).tolist() == [1] * 70_000 + [-1] * 3
+    assert big.lookup(tuple(many[:100])).tolist() == _dict_ids(big, many[:100])
+
+
+SLIDES = {
+    # empty and all-OOV sentences at both ends and side by side
+    "ragged": lambda: [[], ["a", "zz", "é"], [], [], ["zz"], ["zz", "nope"], ["ab"] * 5, []],
+    "a_line_break_in_a_token": lambda: [["x\ny", "x", "y"], ["\n"], ["a\n", "a"]],
+    "the_empty_string": lambda: [[""], ["", "", "zz"], []],
+    "non_ascii_and_four_byte_code_points": lambda: [
+        ["日本語", "日本", "é"], ["😀", "😀😀", "𝔘𝔫", "𝔘"], ["é" * 3]],
+    "a_word_the_vocabulary_holds_twice": lambda: [["a"], ["a", "a", "b c"]],
+    "one_long_sentence": lambda: [["w" * 300, "w" * 299] * 500],
+    "tuples_arrays_and_a_tuple_of_them": lambda: (
+        ("a", "zz"), np.array(["é", "zz", "ab"]), ["a"], (), np.array([], dtype=str)),
+    "a_str_is_its_characters": lambda: ["ab", "", "aé"],
+    "a_list_subclass_goes_by_its_own_iterator": lambda: [_Backwards(["a", "zz", "é"]), ["ab"]],
+}
+
+
+class _Backwards(list):
+    def __iter__(self):
+        return reversed(list(super().__iter__()))
+
+
+@pytest.mark.parametrize("case", sorted(SLIDES))
+def test_the_nested_form_answers_as_the_dict(small_batches_go_native, odd_vocab, case):
+    _assert_sentences(odd_vocab, SLIDES[case](), native=True)
+
+
+def test_a_ragged_seeded_slide_in_the_nested_form(small_batches_go_native, model):
+    slide = sentences(71, 400, oov_share=0.2, empty_share=0.1)
+    assert any(not s for s in slide)
+    _assert_sentences(model.vocab, slide, native=True)
+
+
+def test_under_the_threshold_the_dict_answers(model):
+    _assert_sentences(model.vocab, sentences(72, 20), native=False)
+    ids, counts, missing, by_objects = model.vocab.lookup_sentences([])
+    assert ids.shape == (0,) and counts.shape == (0,) and missing == 0 and not by_objects
 
 
 @pytest.mark.parametrize("tokens", [
-    ["w1", "w2\nw3", "w4"],             # a token holds the separator
+    ["w1", "w2\nw3", "w4"],             # no separator any more: the table's own answer
     ["w1", "\udc80", "w2"],             # a lone surrogate does not encode
     ["w1", b"w2", "w3"],                # not a string: the dict's own answer
-], ids=["separator", "surrogate", "bytes"])
+    ["w1", None, 7, ("w2",)],           # nor these, and all of them hash
+], ids=["line_break", "surrogate", "bytes", "none_int_tuple"])
+@pytest.mark.parametrize("nested", [False, True], ids=["flat", "nested"])
 def test_what_the_native_table_cannot_answer_goes_to_the_dict(
-        small_batches_go_native, model, tokens):
-    assert model.vocab.lookup(tokens).tolist() == _dict_ids(model.vocab, tokens)
+        small_batches_go_native, model, tokens, nested):
+    if not nested:
+        assert model.vocab.lookup(tokens).tolist() == _dict_ids(model.vocab, tokens)
+        return
+    slide = [["w5"], tokens, [], tokens[::-1]]
+    _assert_sentences(model.vocab, slide,
+                      native=all(isinstance(t, str) and t.isprintable() or t == "w2\nw3"
+                                 for t in tokens))
 
 
-def test_lookup_without_the_native_library(monkeypatch, model):
+@pytest.mark.parametrize("slide", [
+    lambda: [["w1"], [["w2"]]],                 # a token that does not hash
+    lambda: [["w1"], None],                     # a sentence with no length
+    lambda: [["w1"], 7],
+    lambda: [["w1"], (t for t in ["w2"])],      # nor has a generator: no sequence
+], ids=["unhashable_token", "none_sentence", "int_sentence", "generator_sentence"])
+@pytest.mark.parametrize("native", [False, True], ids=["dict", "native"])
+def test_what_nothing_can_answer_raises_as_the_dict_does(
+        monkeypatch, model, slide, native):
+    from glint_word2vec_tpu.data import vocab as vocab_module
+    if native and vocab_module._load_native() is None:
+        pytest.skip("no toolchain for native/lookup.cpp here")
+    monkeypatch.setattr(vocab_module, "NATIVE_LOOKUP_TOKENS", 1 if native else 1 << 30)
+    with pytest.raises(TypeError):
+        model.vocab.lookup_sentences(slide())
+
+
+def test_a_set_is_no_sequence_and_the_dict_takes_it_as_it_did(
+        small_batches_go_native, model):
+    _assert_sentences(model.vocab, [["w1"], {"w2"}, {"w3": 1}], native=False)
+
+
+def test_a_sentence_that_changes_the_slide_under_the_walk(small_batches_go_native, model):
+    """Making a list of an odd sentence runs its code, which may shrink the
+    slide: the walk asks the slide anew and hands the batch to the dict."""
+    slide = [["w1", "w2"], None, ["w3"], ["w4"]]
+
+    class Shrinks(tuple):
+        def __iter__(self):
+            del slide[2:]
+            return iter(["w9"])
+
+    slide[1] = Shrinks(["w9"])
+    # the dict's route then trips over its own count of a slide that shrank
+    with pytest.raises((ValueError, IndexError)):
+        model.vocab.lookup_sentences(slide)
+    assert len(slide) == 2
+    assert model.vocab.lookup_sentences([["w1"], ["w2", "zz"]])[0].tolist() == [1, 2]
+
+
+@pytest.mark.parametrize("how", ["no_library", "disabled_by_environment", "no_compiler"])
+def test_lookup_without_the_native_library(monkeypatch, model, how):
+    from glint_word2vec_tpu.data import native as native_module
     from glint_word2vec_tpu.data import vocab as vocab_module
     monkeypatch.setattr(vocab_module, "NATIVE_LOOKUP_TOKENS", 1)
-    monkeypatch.setattr(vocab_module, "_load_native", lambda: None)
+    if how == "no_library":
+        monkeypatch.setattr(vocab_module, "_load_native", lambda: None)
+    else:       # the loader itself, from nothing, finds no library to load
+        monkeypatch.setattr(vocab_module, "_lib", None)
+        monkeypatch.setattr(vocab_module, "_lib_failed", False)
+        if how == "disabled_by_environment":
+            monkeypatch.setenv("GLINT_DISABLE_NATIVE", "1")
+        else:
+            monkeypatch.setattr(native_module, "build_or_reload", lambda *a, **k: None)
+        assert vocab_module._load_native() is None
     tokens = ["w5", "nope", "w0"] * 10
     assert model.vocab.lookup(tokens).tolist() == _dict_ids(model.vocab, tokens)
+    _assert_sentences(model.vocab, [tokens, [], ["nope"], tokens[:4]], native=False)
+    got = model.transform_sentences([["w5", "nope"], ["nope"]])
+    assert np.abs(got - expected([["w5", "nope"], ["nope"]])).max() <= F32_TOL
 
 
-def test_four_threads_look_up_at_once(small_batches_go_native):
+def test_an_interpreter_without_the_entry_points_takes_the_dict(monkeypatch, model):
+    import ctypes
+
+    from glint_word2vec_tpu.data import vocab as vocab_module
+    if vocab_module._load_native() is None:
+        pytest.skip("no toolchain for native/lookup.cpp here")
+    monkeypatch.setattr(vocab_module, "_lib", None)
+    monkeypatch.setattr(vocab_module, "_lib_failed", False)
+    monkeypatch.setattr(vocab_module, "_INTERPRETER_SYMBOLS",
+                        vocab_module._INTERPRETER_SYMBOLS[:-1] + ("PyNo_SuchEntryPoint",))
+    assert vocab_module._load_native() is None
+    assert not hasattr(ctypes.pythonapi, "PyNo_SuchEntryPoint")
+    monkeypatch.setattr(vocab_module, "NATIVE_LOOKUP_TOKENS", 1)
+    _assert_sentences(model.vocab, [["w5", "nope"], ["w1"]], native=False)
+
+
+@pytest.mark.parametrize("nested", [False, True], ids=["flat", "nested"])
+def test_four_threads_look_up_at_once(small_batches_go_native, nested):
     vocab = Vocabulary.from_words_and_counts(zipf.words_of(V), np.ones(V, np.int64))
     rng = np.random.default_rng(5)
     sets = [[f"w{int(i)}" for i in rng.integers(0, 2 * V, 50_000)] for _ in range(4)]
+    if nested:      # four ragged slides, each thread its own
+        sets = [[s[a:a + n] for a, n in zip(range(0, 50_000, 50), [0, 50, 7, 50, 1] * 200)]
+                for s in sets]
     got = [None] * 4
 
     def call(i):
         for _ in range(3):
-            got[i] = vocab.lookup(sets[i])
+            got[i] = vocab.lookup_sentences(sets[i]) if nested else vocab.lookup(sets[i])
 
     threads = [threading.Thread(target=call, args=(i,)) for i in range(4)]
     for t in threads:
@@ -377,4 +524,58 @@ def test_four_threads_look_up_at_once(small_batches_go_native):
         t.join(timeout=120)
     assert not any(t.is_alive() for t in threads)
     for i in range(4):
-        assert got[i].tolist() == _dict_ids(vocab, sets[i])
+        if nested:
+            want_ids, want_counts, want_missing = _dict_sentences(vocab, sets[i])
+            assert got[i][0].tolist() == want_ids and got[i][1].tolist() == want_counts
+            assert got[i][2:] == (want_missing, True)
+        else:
+            assert got[i].tolist() == _dict_ids(vocab, sets[i])
+
+
+def _encode_slide_as_it_was(vocab, slide):
+    """``_encode_slide`` before the nested form: lengths, the flattened list,
+    one lookup, the drop and the counts in numpy."""
+    lengths = np.fromiter(map(len, slide), np.int64, count=len(slide))
+    ids = np.asarray([vocab.get(t) for s in slide for t in s], np.int32)
+    live = ids >= 0
+    before = np.concatenate([[0], np.cumsum(live)])
+    ends = np.cumsum(lengths)
+    counts = (before[ends] - before[ends - lengths]).astype(np.int32)
+    ids = ids[live]
+    return ids, counts, int(live.shape[0] - ids.shape[0])
+
+
+@pytest.mark.parametrize("route", ["native", "dict"])
+def test_encode_slide_gives_what_it_gave(monkeypatch, model, route):
+    from glint_word2vec_tpu.data import vocab as vocab_module
+    if route == "native" and vocab_module._load_native() is None:
+        pytest.skip("no toolchain for native/lookup.cpp here")
+    monkeypatch.setattr(vocab_module, "NATIVE_LOOKUP_TOKENS",
+                        1 if route == "native" else 1 << 30)
+    slide = sentences(2**31 + 51, 500, oov_share=0.15, empty_share=0.1)
+    ids, counts, oov, by_objects = model._encode_slide(slide)
+    want_ids, want_counts, want_oov = _encode_slide_as_it_was(model.vocab, slide)
+    assert ids.dtype == want_ids.dtype and np.array_equal(ids, want_ids)
+    assert counts.dtype == want_counts.dtype and np.array_equal(counts, want_counts)
+    assert oov == want_oov and by_objects is (route == "native")
+
+
+def test_the_walk_is_a_child_span_and_the_encode_says_which_way(
+        small_batches_go_native, model, tracer):
+    model.transform_sentences(sentences(81, 40), batch_size=32)
+    events = tracer.events()
+    encodes = [e for e in events if e["name"] == "transform.encode"]
+    walks = [e for e in events if e["name"] == "transform.encode.walk"]
+    assert [e["args"] for e in encodes] == [{"by_objects": 1}] * 2
+    assert [w["parent"] for w in walks] == [e["id"] for e in encodes]
+    for walk, encode in zip(walks, encodes):
+        assert encode["ts_s"] <= walk["ts_s"]
+        assert walk["ts_s"] + walk["dur_s"] <= encode["ts_s"] + encode["dur_s"]
+
+
+def test_under_the_threshold_no_walk_is_recorded(model, tracer):
+    model.transform_sentences(sentences(82, 10))
+    events = tracer.events()
+    assert [e["args"] for e in events if e["name"] == "transform.encode"] == [
+        {"by_objects": 0}]
+    assert not [e for e in events if e["name"] == "transform.encode.walk"]
