@@ -12,15 +12,17 @@ twice, as fastText lists it. At ``min_n`` >= 2 fastText's one exclusion (a
 lone ``<`` or ``>``) never applies, so none is dropped here.
 
 :func:`ngram_buckets` is the plain form for one string (a query for a word the
-vocabulary has never seen) and :func:`ngram_lists` a batch of them as one
-padded block. :func:`build_subword_table` is the same function
+vocabulary has never seen), :func:`ngram_lists` a batch of them as one
+padded block and :func:`ngram_rows` a batch as one flat list (a slide's
+unseen tokens: ``native/subword.cpp`` where it builds). :func:`build_subword_table` is the same function
 over a whole vocabulary (2.5M words, 76M n-grams: seconds, where a Python loop
 takes minutes), laid out for the step (ops/subword.py).
 """
 
 import ctypes
+import itertools
 import os
-from typing import List, NamedTuple, Sequence
+from typing import List, NamedTuple, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -89,6 +91,50 @@ def ngram_lists(strings: Sequence[str], min_n: int, max_n: int, buckets: int,
         else:
             out[i, :len(ids)] = ids
     return out, over
+
+
+def ngram_rows(strings: Union[Sequence[str], Tuple[np.ndarray, np.ndarray]],
+               min_n: int, max_n: int, buckets: int):
+    """The buckets of a batch of strings as ONE FLAT ``int32`` array (ids into
+    the BUCKET rows), string after string in :func:`ngram_buckets`' order, no
+    padded block; every string's count of them (``int32[len(strings)]``; 0
+    for one too short to have an n-gram, ``""`` at ``min_n`` 3); and whether
+    ``native/subword.cpp`` hashed them (with the interpreter lock released:
+    no Python statement a string, a character or an n-gram) or, where it does
+    not build, :func:`ngram_buckets` a string. ``strings`` is a sequence of
+    ``str``, or the strings' UTF-8 bytes back to back and every string's byte
+    end (``uint8[...]``, ``int64[n]``: what ``Vocabulary.lookup_sentences_misses``
+    hands on from its walk)."""
+    lib = _load_native()
+    packed = isinstance(strings, tuple)
+    if lib is None:
+        if packed:
+            raw, end = strings
+            data = raw.tobytes()
+            strings = [data[s:e].decode("utf-8")
+                       for s, e in zip([0] + end[:-1].tolist(), end.tolist())]
+        lists = [ngram_buckets(s, min_n, max_n, buckets) for s in strings]
+        counts = np.fromiter(map(len, lists), np.int32, count=len(lists))
+        return (np.fromiter(itertools.chain.from_iterable(lists), np.int32,
+                            count=int(counts.sum())), counts, False)
+    if packed:
+        raw, end = strings
+    else:
+        encoded = [s.encode("utf-8") for s in strings]
+        raw = np.frombuffer(b"".join(encoded), np.uint8)
+        end = np.cumsum(np.fromiter(map(len, encoded), np.int64, count=len(encoded)))
+    n = int(end.shape[0])
+    raw, end = np.ascontiguousarray(raw), np.ascontiguousarray(end, np.int64)
+    if n and int(end[-1]) > raw.shape[0]:
+        raise ValueError("ngram_rows: the strings' ends pass their bytes")
+    # an n-gram of every length from every byte of every marked string, at most
+    ids = np.empty(max(max_n - min_n + 1, 0) * ((int(end[-1]) if n else 0) + 2 * n),
+                   np.int32)
+    counts = np.empty(n, np.int32)
+    total = lib.glint_subword_hash_strings(
+        raw.ctypes.data, end.ctypes.data, n, min_n, max_n, ctypes.c_uint32(buckets),
+        ids.ctypes.data, counts.ctypes.data)
+    return ids[:total], counts, True
 
 
 class SubwordRows(NamedTuple):
@@ -241,7 +287,7 @@ def _load_native():
     lib = None
     if not os.environ.get("GLINT_DISABLE_NATIVE"):
         lib = build_or_reload(src, os.path.join(os.path.dirname(src), "libsubword"),
-                              "glint_subword_abi_version", 1, "c++17", "subword")
+                              "glint_subword_abi_version", 2, "c++17", "subword")
     if lib is None:
         _lib_failed = True
         return None
@@ -251,5 +297,10 @@ def _load_native():
         ctypes.c_int32, ctypes.c_int32, ctypes.c_uint32,       # min_n, max_n, buckets
         ctypes.c_int32, ctypes.c_void_p, ctypes.c_void_p,      # row0, slot0, flat
         ctypes.c_int32]                                        # threads
+    lib.glint_subword_hash_strings.restype = ctypes.c_int64
+    lib.glint_subword_hash_strings.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,      # bytes, end, strings
+        ctypes.c_int32, ctypes.c_int32, ctypes.c_uint32,       # min_n, max_n, buckets
+        ctypes.c_void_p, ctypes.c_void_p]                      # ids, counts (out)
     _lib = lib
     return _lib

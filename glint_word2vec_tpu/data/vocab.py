@@ -96,24 +96,59 @@ class Vocabulary:
             if found is not None:
                 ids, tokens = found
                 return ids, counts, tokens - len(ids), True
-        lengths = np.fromiter(map(len, sentences), np.int64, count=n)
-        ids = self._dict_lookup(list(itertools.chain.from_iterable(sentences)))
+        _, ids, counts, _ = self._dict_lookup_sentences(sentences, n)
         live = ids >= 0
-        before = np.concatenate([[0], np.cumsum(live)])
-        ends = np.cumsum(lengths)
-        counts = (before[ends] - before[ends - lengths]).astype(np.int32)
         return ids[live], counts, int(len(ids) - live.sum()), False
+
+    def lookup_sentences_misses(self, sentences: Sequence[Sequence[str]],
+                                walk_span=_NO_SPAN):
+        """:meth:`lookup_sentences` with the tokens the vocabulary lacks
+        handed on and not only dropped, for a reader that gives them a vector
+        of their own (a subword model's ``sentence_vectors``): the ids and
+        the sentences' counts as there; every sentence's count of missing
+        tokens (``int32[len(sentences)]``); the missing tokens themselves in
+        the order sent; and whether the native table answered. Where it did,
+        the tokens are their UTF-8 bytes back to back and every token's byte
+        end (``uint8[...]``, ``int64[n]``), copied out of the walk's buffer
+        with the interpreter lock released: what ``data/subword.ngram_rows``
+        hashes without making a ``str``. Where ``dict.get`` did, a list of
+        the ``str`` objects."""
+        n = len(sentences)
+        if n >= NATIVE_LOOKUP_TOKENS or sum(map(len, sentences)) >= NATIVE_LOOKUP_TOKENS:
+            counts, missing = np.empty(n, np.int32), np.empty(n, np.int32)
+            found = self._native_lookup(sentences, counts, walk_span, missing)
+            if found is not None:
+                return found[0], counts, missing, found[2], True
+        tokens, ids, counts, lengths = self._dict_lookup_sentences(sentences, n)
+        return (ids[ids >= 0], counts, (lengths - counts).astype(np.int32),
+                [tokens[i] for i in np.flatnonzero(ids < 0).tolist()], False)
+
+    def _dict_lookup_sentences(self, sentences: Sequence[Sequence[str]], n: int):
+        """``dict.get``'s route over a slide of ``n`` sentences (counted before
+        a native walk that may have run the caller's code): its flattened
+        tokens, an id each (-1: missing), every sentence's count of ids found
+        (``int32``) and of tokens (``int64``)."""
+        lengths = np.fromiter(map(len, sentences), np.int64, count=n)
+        tokens = list(itertools.chain.from_iterable(sentences))
+        ids = self._dict_lookup(tokens)
+        before = np.concatenate([[0], np.cumsum(ids >= 0)])
+        ends = np.cumsum(lengths)
+        return (tokens, ids, (before[ends] - before[ends - lengths]).astype(np.int32),
+                lengths)
 
     def _dict_lookup(self, tokens: Sequence[str]) -> np.ndarray:
         return np.fromiter(
             map(self.index.get, tokens, itertools.repeat(-1)), np.int32,
             count=len(tokens))
 
-    def _native_lookup(self, batch, counts: Optional[np.ndarray], walk_span):
+    def _native_lookup(self, batch, counts: Optional[np.ndarray], walk_span,
+                       missing: Optional[np.ndarray] = None):
         """``batch`` through ``native/lookup.cpp``: its ids and the number of
         its tokens, or None where that cannot answer for the dict. Without
         ``counts`` a list of tokens, an id each; with ``counts`` to fill,
-        sentences, whose missing tokens' ids are dropped."""
+        sentences, whose missing tokens' ids are dropped; with ``missing`` to
+        fill too (every sentence's count of them), a third result: the
+        missing tokens' bytes and byte ends."""
         from glint_word2vec_tpu.data.native import default_threads
         lib = _load_native()
         if lib is None:
@@ -134,14 +169,26 @@ class Vocabulary:
             return None     # a token that is no str, or a lone surrogate
         try:
             out = np.empty(n_tokens.value, np.int32)
+            if missing is not None:
+                raw = np.empty(lib.glint_lookup_walk_bytes(walk), np.uint8)
+                end = np.empty(n_tokens.value, np.int64)
         except BaseException:
             lib.glint_lookup_walked(None, walk, None, None, 0)  # frees the walk
             raise
-        kept = lib.glint_lookup_walked(
-            table.handle, walk, out.ctypes.data,
-            None if counts is None else counts.ctypes.data,
-            min(default_threads(), _LOOKUP_THREADS))
-        return out[:kept], n_tokens.value
+        threads = min(default_threads(), _LOOKUP_THREADS)
+        if missing is None:
+            kept = lib.glint_lookup_walked(
+                table.handle, walk, out.ctypes.data,
+                None if counts is None else counts.ctypes.data, threads)
+            return out[:kept], n_tokens.value
+        n_missed = ctypes.c_int64()
+        kept = lib.glint_lookup_walked_misses(
+            table.handle, walk, out.ctypes.data, counts.ctypes.data,
+            missing.ctypes.data, raw.ctypes.data, end.ctypes.data,
+            ctypes.byref(n_missed), threads)
+        end = end[:n_missed.value]
+        return (out[:kept], n_tokens.value,
+                (raw[:int(end[-1]) if n_missed.value else 0], end))
 
     @classmethod
     def from_words_and_counts(cls, words: Sequence[str], counts: Sequence[int]) -> "Vocabulary":
@@ -215,7 +262,7 @@ def _load_native():
     lib = None
     if not os.environ.get("GLINT_DISABLE_NATIVE"):
         lib = build_or_reload(src, os.path.join(os.path.dirname(src), "liblookup"),
-                              "glint_lookup_abi_version", 2, "c++17", "lookup")
+                              "glint_lookup_abi_version", 3, "c++17", "lookup")
     if lib is not None:
         try:
             symbols = (ctypes.c_void_p * len(_INTERPRETER_SYMBOLS))(*(
@@ -241,6 +288,14 @@ def _load_native():
     lib.glint_lookup_walked.argtypes = [
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,  # table, walk, out
         ctypes.c_void_p, ctypes.c_int32]                    # counts, threads
+    lib.glint_lookup_walk_bytes.restype = ctypes.c_int64
+    lib.glint_lookup_walk_bytes.argtypes = [ctypes.c_void_p]
+    lib.glint_lookup_walked_misses.restype = ctypes.c_int64
+    lib.glint_lookup_walked_misses.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,  # table, walk, out
+        ctypes.c_void_p, ctypes.c_void_p,                   # counts, missing
+        ctypes.c_void_p, ctypes.c_void_p,                   # the missing tokens' bytes, ends
+        ctypes.c_void_p, ctypes.c_int32]                    # tokens missed (out), threads
     _lib = lib
     return _lib
 

@@ -22,7 +22,8 @@ import collections
 import logging
 import math
 import os
-from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple, Union
+from typing import (
+    Dict, Iterable, Iterator, List, NamedTuple, Optional, Sequence, Tuple, Union)
 
 import jax
 import jax.numpy as jnp
@@ -50,10 +51,20 @@ class Word2VecModel:
     (``_compose``), and, once ``transform_sentences``, ``transform_words`` or
     ``pull`` has read rows, syn0 again with D widened to whole lanes of 128
     (:meth:`_row_table`: 4.61 GB beside syn0's 3.60 at 3M x 300 float32; none
-    where D is a multiple of 128, or on a mesh)."""
+    where D is a multiple of 128, or on a mesh).
+
+    ``resident="rows"`` builds a subword model that holds only what a row
+    read reads: the composed table written straight at whole lanes, the
+    bucket rows at whole lanes and the rows' norms (6.95 GB at wiki.en's
+    2,519,370 + 2,000,000 rows x 300, where the five tables above are 16.0).
+    ``sentence_vectors``, ``transform_sentences``, ``transform_words``,
+    ``pull`` and ``transform(word)`` work on it; what scans, saves or exports
+    a table it does not hold (``find_synonyms*``, ``multiply``, ``save``,
+    ``syn0``, ``syn1``, the exports) raises, naming the argument."""
 
     @pinned_call("model.init", lambda self: dict(
-        words=self.vocab.size, subword=int(self._buckets is not None)))
+        words=self.vocab.size, subword=int(self._buckets is not None),
+        resident=self.resident))
     def __init__(
         self,
         vocab: Vocabulary,
@@ -65,9 +76,21 @@ class Word2VecModel:
         subword_buckets: Optional[jax.Array] = None,
         position_weights: Optional[np.ndarray] = None,
         subword_rows=None,
+        resident: str = "all",
     ):
         """The whole of it is the pinned span ``model.init`` (obs/spans.py),
-        over ``model.compose`` where the model is a subword one."""
+        over ``model.compose`` where the model is a subword one.
+        ``resident``: ``"all"``, or ``"rows"`` for a subword model on one
+        device that only reads rows (the class docstring): ``syn1`` is not
+        placed, and the words' own rows are let go once the composed table
+        is written (a caller that lets go of ``syn0`` too frees them)."""
+        if resident not in ("all", "rows"):
+            raise ValueError(f"resident is 'all' or 'rows', not {resident!r}")
+        if resident == "rows" and (subword_buckets is None or plan is not None):
+            raise ValueError(
+                "resident='rows' builds a subword model on one device for row "
+                "reads alone: it needs subword_buckets and takes no plan")
+        self.resident = resident
         # a position-weighted CBOW model's third leaf
         # (config.cbow_position_weights): trained state kept so that a saved
         # model can be resumed; no query reads it and no export writes it
@@ -87,18 +110,38 @@ class Word2VecModel:
         # bucket rows handed over for them, and the strings sent round
         # through the vector block, over the model's life
         self.query_counts = {"unseen": 0, "list_rows": 0, "overflow": 0}
+        # the row reads' whole-lane form of syn0 (_row_table) and the transform
+        # slides whose result is not in yet, both under the one lock
+        self._lock = make_lock("model.rows")
+        self._lanes: Optional[jax.Array] = None
+        self._slides_inflight = 0
+        self._norms: Optional[jax.Array] = None
+        # sentence_vectors' scale of a word's row, 1 / its norm (0 for a row
+        # of zero norm), and the ids of those rows, on the host
+        self._inv_norms: Optional[jax.Array] = None
+        self._zero_rows: Optional[np.ndarray] = None
+        self._ann = None
+        self._stopped = False
+        self.vocab = vocab
+        self.plan = plan
+        self.train_state = train_state
         if subword_buckets is not None:
             syn0 = self._compose(vocab, config, syn0, subword_buckets,
                                  subword_rows)
+        self.config = config or Word2VecConfig(vector_size=int(syn0.shape[1]))
+        if resident == "rows":
+            # syn0 is the composed table at whole lanes: the one table kept
+            self._dim = int(self._raw0.shape[1])
+            self._raw0 = self._full0 = self._full1 = None
+            self._lanes = syn0
+            self._inverse_norms()
+            return
+        self._dim = int(syn0.shape[1])
         Vp = (pad_vocab_for_sharding(vocab.size, plan.num_model)
               if plan is not None else vocab.size)
         if syn0.shape[0] not in (vocab.size, Vp):
             raise ValueError(
                 f"syn0 has {syn0.shape[0]} rows but vocabulary has {vocab.size} words")
-        self.vocab = vocab
-        self.config = config or Word2VecConfig(vector_size=int(syn0.shape[1]))
-        self.plan = plan
-        self.train_state = train_state
         if plan is not None:
             # Row-sharding needs rows % num_model == 0: pad with zero rows (zero norm →
             # cosine 0 and explicitly masked out of top-k), the model-ops analog of the
@@ -128,14 +171,6 @@ class Word2VecModel:
             syn1 = jnp.asarray(syn1) if syn1 is not None else None
         self._full0 = syn0
         self._full1 = syn1
-        self._norms: Optional[jax.Array] = None
-        self._ann = None
-        self._stopped = False
-        # the row reads' whole-lane form of syn0 (_row_table) and the transform
-        # slides whose result is not in yet, both under the one lock
-        self._lock = make_lock("model.rows")
-        self._lanes: Optional[jax.Array] = None
-        self._slides_inflight = 0
 
     def _compose(self, vocab: Vocabulary, config: Word2VecConfig, syn0,
                  buckets, subword_rows=None) -> jax.Array:
@@ -150,7 +185,10 @@ class Word2VecModel:
         row-major copy of a table whose D is no multiple of 128. The row
         table is ``subword_rows`` (an ops/subword.SubwordTable on the device
         and its ``max_groups``, from a caller that has one: the estimator
-        after a fit) or built here, and freed with the call."""
+        after a fit) or built here, and freed with the call. A model with
+        ``resident="rows"`` gets the table written straight at whole lanes
+        ([V, 384] for D = 300; the span's ``lanes``), the form its row reads
+        gather from: no [V, D] table is made beside it."""
         from glint_word2vec_tpu.data.subword import (
             build_subword_table, groups_in_whole_units, list_capacity)
         from glint_word2vec_tpu.ops.subword import (
@@ -187,10 +225,12 @@ class Word2VecModel:
                 del rows
             table, max_groups = subword_rows
             composed = compose_vectors(
-                self._raw0, self._buckets, table, max_groups)
+                self._raw0, self._buckets, table, max_groups,
+                whole_lanes=self.resident == "rows")
             composed.block_until_ready()
             sp.set(slots=int(table.counts.sum()),
-                   blocks=-(-vocab.size // COMPOSE_BLOCK))
+                   blocks=-(-vocab.size // COMPOSE_BLOCK),
+                   lanes=int(composed.shape[1]))
             self._list_cap = list_capacity(
                 max(map(len, vocab.words)), config.subword_min_n,
                 config.subword_max_n)
@@ -208,7 +248,7 @@ class Word2VecModel:
         """A subword model's bucket rows [K, D] as trained, else None."""
         if self._buckets is None:
             return None
-        return self._buckets[:, : self._raw0.shape[1]]
+        return self._buckets[:, : self._dim]
 
     def _unseen_vector(self, word: str) -> np.ndarray:
         """A string the vocabulary has never seen, on a subword model: the
@@ -230,29 +270,38 @@ class Word2VecModel:
     def syn0(self) -> jax.Array:
         """Input embeddings, unpadded view [vocab_size, D] (a subword model's
         composed vectors)."""
-        self._check_alive()
+        self._check_alive("syn0")
         return self._full0[: self.vocab.size]
 
     @property
     def syn1(self) -> Optional[jax.Array]:
+        self._check_alive("syn1")
         if self._full1 is None:
             return None
-        self._check_alive()
         return self._full1[: self.vocab.size]
 
     # -- basic properties --------------------------------------------------------------
 
     @property
     def vector_size(self) -> int:
-        return int(self._full0.shape[1])
+        return self._dim
 
     @property
     def num_words(self) -> int:
         return self.vocab.size
 
-    def _check_alive(self) -> None:
+    def _check_alive(self, tables_of: Optional[str] = None) -> None:
+        """Raises where the model was stopped; and, for ``tables_of`` (the
+        name of an operation that scans, saves or exports syn0 or syn1),
+        where it was built with ``resident="rows"`` and holds neither."""
         if self._stopped:
             raise RuntimeError("model has been stopped; its buffers were released")
+        if tables_of is not None and self.resident == "rows":
+            raise RuntimeError(
+                f"{tables_of} needs a table this model does not hold: it was "
+                "built with resident='rows' (the composed table at whole "
+                "lanes, the bucket rows and the norms, for row reads alone); "
+                "build it with resident='all'")
 
     # -- transform (C8 mllib:511-546; C12 ml:432-460) ----------------------------------
 
@@ -266,6 +315,8 @@ class Word2VecModel:
             return self._unseen_vector(word)
         if idx < 0:
             raise KeyError(f"{word} not in vocabulary")
+        if self._full0 is None:     # resident="rows": the one table it holds
+            return self._read_rows([idx])[0]
         return np.asarray(self.syn0[idx])
 
     def transform_words(self, words: Iterable[str], batch_size: int = 10_000
@@ -322,14 +373,71 @@ class Word2VecModel:
         n's program and fetch are outstanding (``_SLIDES_IN_FLIGHT``); several
         threads may call at once. Spans: ``transform.slide``, its three
         children and the encode's ``transform.encode.walk``
-        (docs/observability.md §4)."""
+        (docs/observability.md §4).
+
+        On a subword model a token the vocabulary lacks is dropped here too
+        (upstream's rule); :meth:`sentence_vectors` is the operation that
+        composes it from its n-grams."""
+        return self._slides(sentences, batch_size, self._transform_begin)
+
+    def sentence_vectors(
+        self, sentences: Sequence[Sequence[str]], batch_size: int = 10_000
+    ) -> np.ndarray:
+        """fastText's sentence vector (``FastText::getSentenceVector``, the
+        branch for unsupervised models; Python ``get_sentence_vector``, CLI
+        ``print-sentence-vectors``): ``float32[len(sentences), D]``, rows in
+        the order sent, each the mean of its tokens' UNIT vectors,
+
+            v(s) = (1 / c) * sum over the tokens t of s with |h(t)| > 0 of h(t) / |h(t)|
+
+        c their number (zeros where it is 0: an empty sentence). h(t) is the
+        model's vector of the string t: a word's row of syn0 (a subword
+        model's composed row), and on a subword model, for a token the
+        vocabulary lacks, the mean of ALL its n-grams' bucket rows, however
+        many (:meth:`transform`'s vector of it). A token of zero norm is left
+        out of sum and count alike; on a model without subwords that is every
+        token the vocabulary lacks (it has no rows), so nothing else is
+        dropped as out of vocabulary. Every token counts each time it occurs;
+        the caller splits the text and no end-of-sentence token is added.
+
+        Slides of ``batch_size`` sentences on :meth:`transform_sentences`'
+        machinery (its halves, capacity rule, in-flight bound and spans),
+        each ONE device program (:func:`_sentence_means`), a two-level
+        ragged reduction:
+
+        - *encode* (host, :meth:`_encode_tokens`): one walk of the slide as
+          it lies, the lookup with the interpreter lock released, and in the
+          same lock-free stretch the missing tokens' n-grams hashed from the
+          bytes the walk kept (``native/subword.cpp``; span
+          ``transform.ngram_hash``) into ONE FLAT list of bucket ids and
+          every token's count of them: no ``[U, L]`` block, no Python
+          statement a token, a character or an n-gram;
+        - *enqueue*: three capacities derived from the slide, the word rows'
+          and the list rows' by :func:`_grid_up` and the unseen tokens' the
+          power of two over them (coarse, so slides share programs: its
+          block is a twentieth of the others). The word rows are gathered in
+          place from the whole-lane table and scaled by the inverse norm of
+          their id; the list rows are gathered in place from the bucket rows,
+          summed by token (sorted segments), divided by |G|, normalised; both
+          are summed by sentence and divided by the live count on the device,
+          trimmed to ``[S, D]`` there. A slide with more word rows or list
+          rows than ``_TRANSFORM_MAX_ROWS`` runs further passes of the same
+          program, the lists cut between tokens, the sums and the composed
+          tokens' counts carried;
+        - *fetch*: as :meth:`transform_sentences`."""
+        return self._slides(sentences, batch_size, self._sentvec_begin)
+
+    def _slides(self, sentences: Sequence[Sequence[str]], batch_size: int,
+                begin) -> np.ndarray:
+        """``sentences`` in slides of ``batch_size`` through ``begin`` (one
+        slide's first half: :meth:`_transform_begin`, :meth:`_sentvec_begin`)
+        and :meth:`_transform_finish`, ``_SLIDES_IN_FLIGHT`` at a time."""
         self._check_alive()
         out = np.empty((len(sentences), self.vector_size), np.float32)
         pending: "collections.deque[_PendingSlide]" = collections.deque()
         try:
             for lo in range(0, len(sentences), batch_size):
-                pending.append(self._transform_begin(
-                    sentences[lo:lo + batch_size], lo, batch_size))
+                pending.append(begin(sentences[lo:lo + batch_size], lo, batch_size))
                 if len(pending) >= _SLIDES_IN_FLIGHT:
                     self._transform_finish(pending.popleft(), out)
             while pending:
@@ -398,6 +506,132 @@ class Word2VecModel:
             span.detach()  # the next slide's spans are no children of this one
         return pending
 
+    def _encode_tokens(self, slide: Sequence[Sequence[str]]) -> "_SlideTokens":
+        """One slide's tokens as :func:`_sentence_means` wants them
+        (:class:`_SlideTokens`). A model without subwords takes
+        :meth:`_encode_slide`'s lookup and leaves the missing tokens out (they
+        have no rows: h = 0). A subword model takes
+        ``Vocabulary.lookup_sentences_misses``, which hands the missing
+        tokens on, and hashes their n-grams (``data/subword.ngram_rows``,
+        span ``transform.ngram_hash``). Left out here, and counted as
+        ``zero_norm``: a string with no n-gram at all (``""``), and a word
+        whose row has zero norm (:meth:`_inverse_norms` keeps their ids; a
+        trained table has none). A composed token whose rows sum to zero is
+        left out by the program."""
+        tracer = default_tracer()
+        walk = tracer.span("transform.encode.walk")
+        n = len(slide)
+        if not self.composes_unseen:
+            ids, counts, zero, by_objects = self.vocab.lookup_sentences(slide, walk)
+            unseen = np.zeros(n, np.int32)
+            list_rows = list_counts = np.zeros(0, np.int32)
+        else:
+            from glint_word2vec_tpu.data.subword import ngram_rows
+            cfg = self.config
+            ids, counts, unseen, missing, by_objects = (
+                self.vocab.lookup_sentences_misses(slide, walk))
+            with tracer.span("transform.ngram_hash") as sp:
+                list_rows, list_counts, native = ngram_rows(
+                    missing, cfg.subword_min_n, cfg.subword_max_n,
+                    cfg.subword_buckets)
+                sp.set(strings=int(list_counts.shape[0]),
+                       list_rows=int(list_rows.shape[0]), native=int(native))
+            bare = list_counts == 0
+            zero = int(bare.sum())
+            if zero:
+                of = np.repeat(np.arange(n, dtype=np.int32), unseen)
+                unseen = np.bincount(of[~bare], minlength=n).astype(np.int32)
+                list_counts = list_counts[~bare]
+        self._inverse_norms()
+        if self._zero_rows.size:
+            dead = np.isin(ids, self._zero_rows)
+            if dead.any():
+                of = np.repeat(np.arange(n, dtype=np.int32), counts)
+                counts = np.bincount(of[~dead], minlength=n).astype(np.int32)
+                ids, zero = ids[~dead], zero + int(dead.sum())
+        return _SlideTokens(ids, counts, unseen, list_rows, list_counts, zero,
+                            by_objects)
+
+    def _sentvec_begin(self, slide: Sequence[Sequence[str]], lo: int,
+                       batch_size: int) -> "_PendingSlide":
+        """:meth:`_transform_begin` of a :meth:`sentence_vectors` slide."""
+        tracer = default_tracer()
+        n = len(slide)
+        span = tracer.open("transform.slide", sentences=n)
+        pending = _PendingSlide(lo, n, span)
+        with tracer.span("transform.encode") as encode:
+            tokens = self._encode_tokens(slide)
+            encode.set(by_objects=int(tokens.by_objects))
+        live, listed = int(tokens.ids.shape[0]), int(tokens.list_rows.shape[0])
+        composed = int(tokens.list_counts.shape[0])
+        if span is not None:
+            span.set(words=live, oov=0, unseen=composed, zero_norm=tokens.zero_norm,
+                     empty=int(((tokens.counts + tokens.unseen) == 0).sum()))
+        if live or composed:
+            table, scale = self._row_table(), self._inverse_norms()
+            segments = batch_size if n == batch_size else _grid_up(n, 8)
+            passes = max(-(-live // _TRANSFORM_MAX_ROWS),
+                         -(-listed // _TRANSFORM_MAX_ROWS), 1)
+            cap = _grid_up(-(-live // passes), 128)
+            # the lists in ``passes`` parts cut between tokens, of about as
+            # many rows each: tokens [cut[i], cut[i + 1]) and their rows
+            row_end = np.cumsum(tokens.list_counts, dtype=np.int64)
+            cut = np.searchsorted(
+                row_end, -(-listed // passes) * np.arange(passes + 1), side="right")
+            cut[-1] = composed
+            row_cut = np.concatenate([[0], row_end])[cut]
+            list_cap = _grid_up(int(np.diff(row_cut).max()), 128)
+            token_cap = max(128, 1 << (int(np.diff(cut).max()) - 1).bit_length())
+            with self._lock:
+                inflight = self._slides_inflight
+                self._slides_inflight += 1
+            with tracer.span("transform.enqueue", rows=live, rows_cap=cap,
+                             passes=passes, inflight=inflight, list_rows=listed,
+                             list_cap=list_cap if composed else 0,
+                             unseen=composed,
+                             unseen_cap=token_cap if composed else 0):
+                seg = np.repeat(np.arange(n, dtype=np.int32), tokens.counts)
+                counts = np.concatenate(
+                    [tokens.counts, np.zeros(segments - n, np.int32)])
+                if composed:
+                    token_seg = np.repeat(np.arange(n, dtype=np.int32), tokens.unseen)
+                    token_of_row = np.repeat(
+                        np.arange(composed, dtype=np.int32), tokens.list_counts)
+
+
+                def part(values, lo, hi, size, fill):
+                    out = np.full(size, fill, np.int32)
+                    out[:max(hi - lo, 0)] = values[lo:hi]
+                    return out
+
+                carried = None
+                for i in range(passes):
+                    # past the live ids: a row no table has (read as zeros)
+                    # in a sentence no slide has (dropped)
+                    lo, hi = i * cap, min((i + 1) * cap, live)
+                    lists = None
+                    if composed:
+                        # past a part's list rows and tokens: a bucket row no
+                        # table has, in a token the part does not hold, of a
+                        # sentence no slide has
+                        (t0, t1), (r0, r1) = cut[i:i + 2], row_cut[i:i + 2]
+                        lists = (
+                            self._buckets,
+                            part(tokens.list_rows, r0, r1, list_cap,
+                                 self._buckets.shape[0]),
+                            part(token_of_row - t0, r0, r1, list_cap, token_cap),
+                            part(token_seg, t0, t1, token_cap, segments))
+                    carried = _sentence_means(
+                        table, scale, part(tokens.ids, lo, hi, cap, table.shape[0]),
+                        part(seg, lo, hi, cap, segments), lists,
+                        counts if i == passes - 1 else None, carried,
+                        segments, self.vector_size)
+                carried.copy_to_host_async()
+            pending.result = carried
+        if span is not None:
+            span.detach()  # the next slide's spans are no children of this one
+        return pending
+
     def _transform_finish(self, pending: "_PendingSlide",
                           out: np.ndarray) -> None:
         """The second half of one slide: its means fetched into its rows of
@@ -434,18 +668,19 @@ class Word2VecModel:
         reads the table as it lies), kept until :meth:`stop`. A table on a
         mesh is gathered as it lies, the ``[:V]`` view (ROADMAP B14 (c))."""
         self._check_alive()
+        if self._lanes is not None:     # made, or all a resident="rows" model holds
+            return self._lanes
         if len(self._full0.sharding.device_set) != 1:
             return self.syn0
-        if self._lanes is None:
-            from glint_word2vec_tpu.ops.subword import lane_padded
-            with self._lock:
-                if self._lanes is None:
-                    # once a model: a pinned span (obs/spans.py)
-                    with default_tracer().span(
-                            "model.row_table", pinned=True,
-                            rows=int(self._full0.shape[0])):
-                        self._lanes = lane_padded(self._full0)
-                        self._lanes.block_until_ready()
+        from glint_word2vec_tpu.ops.subword import lane_padded
+        with self._lock:
+            if self._lanes is None:
+                # once a model: a pinned span (obs/spans.py)
+                with default_tracer().span(
+                        "model.row_table", pinned=True,
+                        rows=int(self._full0.shape[0])):
+                    self._lanes = lane_padded(self._full0)
+                    self._lanes.block_until_ready()
         return self._lanes
 
     def _read_rows(self, ids: Sequence[int]) -> np.ndarray:
@@ -458,12 +693,31 @@ class Word2VecModel:
         """Per-row Euclidean norms, computed once and cached (mllib:486,600-609)."""
         self._check_alive()
         if self._norms is None:
+            # a resident="rows" model's one table is the whole-lane form,
+            # whose rows have the same norms
+            table = self._lanes if self._full0 is None else self._full0
             # once a model: a pinned span (obs/spans.py)
             with default_tracer().span("model.norms", pinned=True,
-                                       rows=int(self._full0.shape[0])):
-                self._norms = jnp.linalg.norm(self._full0, axis=1)
+                                       rows=int(table.shape[0])):
+                self._norms = jnp.linalg.norm(table, axis=1)
                 self._norms.block_until_ready()
         return self._norms[: self.vocab.size]
+
+    def _inverse_norms(self) -> jax.Array:
+        """``sentence_vectors``' scale of every row of :meth:`_row_table`:
+        1 / :attr:`norms`, 0 for a row of zero norm; made once, under the
+        model's lock, with the ids of those rows kept on the host
+        (``_zero_rows``: the encode leaves their tokens out of the count)."""
+        if self._inv_norms is None:
+            self.norms  # materialize the cached full-row norms
+            with self._lock:
+                if self._inv_norms is None:
+                    norms = self._norms
+                    inv = jnp.where(norms > 0, 1.0 / jnp.where(norms > 0, norms, 1.0), 0.0)
+                    self._zero_rows = np.flatnonzero(
+                        np.asarray(inv[: self.vocab.size]) == 0).astype(np.int32)
+                    self._inv_norms = inv
+        return self._inv_norms
 
     def multiply(self, vector: np.ndarray) -> np.ndarray:
         """Full matrix–vector product syn0 @ v (the PS ``multiply`` powering cosine
@@ -472,7 +726,7 @@ class Word2VecModel:
         padding rows' zeros dropped from the fetched vector (the ``syn0`` view
         of a vocabulary that does not divide over the mesh is a slice along
         the partitioned rows, which all-gathers the table)."""
-        self._check_alive()
+        self._check_alive("multiply")
         v = jnp.asarray(vector, jnp.float32)
         return np.asarray(self._full0 @ v)[: self.vocab.size]
 
@@ -599,7 +853,7 @@ class Word2VecModel:
         enqueues the rest as it fetches. The ANN arm and the host top-k
         route (:func:`_host_topk`) leave nothing pending on the device: all
         of their work is done here and ``finish`` hands it back."""
-        self._check_alive()
+        self._check_alive("find_synonyms")
         tracer = default_tracer()
         # the caller's span: parent of the spans ``finish`` records, which may
         # run on a thread whose stack does not hold it
@@ -803,7 +1057,7 @@ class Word2VecModel:
     def get_vectors(self) -> Dict[str, np.ndarray]:
         """word → vector for the whole vocabulary (mllib:638-649; mind the reference's
         caveat that this pulls everything to the client, mllib:635-637)."""
-        self._check_alive()
+        self._check_alive("get_vectors")
         mat = np.asarray(self.syn0)
         return {w: mat[i] for i, w in enumerate(self.vocab.words)}
 
@@ -811,7 +1065,7 @@ class Word2VecModel:
                      ) -> Iterator[Tuple[str, np.ndarray]]:
         """Streaming variant of get_vectors — the analog of the ML layer's distributed
         per-partition pulls (ml:342-364) for vocabularies too large for one dict."""
-        self._check_alive()
+        self._check_alive("iter_vectors")
         for start in range(0, self.num_words, batch_size):
             stop = min(start + batch_size, self.num_words)
             block = np.asarray(self.syn0[start:stop])
@@ -823,7 +1077,7 @@ class Word2VecModel:
         (mllib:651-662) without the Spark model wrapper. For the ecosystem
         hand-off the reference's Spark ``Word2VecModel`` provided (usable by
         downstream tooling), see :meth:`export_word2vec`."""
-        self._check_alive()
+        self._check_alive("to_local")
         return list(self.vocab.words), np.asarray(self.syn0)
 
     def export_word2vec(self, path: str, binary: bool = False,
@@ -846,7 +1100,7 @@ class Word2VecModel:
         REGRESSED under allocator churn, hostbench). Device fetches stay on
         the calling thread, and the bytes written are identical at any worker
         count."""
-        self._check_alive()
+        self._check_alive("export_word2vec")
         import io
 
         from glint_word2vec_tpu.data.pipeline import ordered_pool_map
@@ -889,7 +1143,7 @@ class Word2VecModel:
     # -- persistence (G9/C13) ----------------------------------------------------------
 
     def save(self, path: str) -> None:
-        self._check_alive()
+        self._check_alive("save")
         # a subword model saves what it trained (own rows and bucket rows),
         # not the composed table its queries scan
         raw0 = self.syn0 if self._raw0 is None else self._raw0
@@ -905,7 +1159,8 @@ class Word2VecModel:
     @classmethod
     def load(cls, path: str, plan: Optional[MeshPlan] = None,
              verify: bool = True,
-             io_workers: Optional[int] = None) -> "Word2VecModel":
+             io_workers: Optional[int] = None,
+             resident: str = "all") -> "Word2VecModel":
         """Load a saved model; ``plan`` retargets the arrays onto a different mesh — the
         analog of the reference's load-onto-different-PS-topology overloads
         (mllib:696-725, ml:584-599).
@@ -923,7 +1178,11 @@ class Word2VecModel:
 
         ``io_workers``: thread fan-out for digest hashing and shard reads on
         THIS host (default: the worker count recorded in the checkpoint's
-        config — pass your own on hosts that differ from the writer's)."""
+        config — pass your own on hosts that differ from the writer's).
+
+        ``resident="rows"`` (a subword model's checkpoint, no ``plan``): the
+        constructor's, for a process that only reads rows; syn1 is read from
+        the file and never placed on the device."""
         header = None
         if plan is not None:
             header = ckpt.load_model_header(path)
@@ -943,12 +1202,14 @@ class Word2VecModel:
         return cls(
             vocab=vocab,
             syn0=jnp.asarray(data["syn0"]),
-            syn1=jnp.asarray(data["syn1"]) if data["syn1"] is not None else None,
+            syn1=(jnp.asarray(data["syn1"])
+                  if data["syn1"] is not None and resident != "rows" else None),
             config=data["config"],
             plan=plan,
             train_state=data["train_state"],
             subword_buckets=data.get("subword_buckets"),
             position_weights=data.get("position_weights"),
+            resident=resident,
         )
 
     @classmethod
@@ -971,8 +1232,8 @@ class Word2VecModel:
         (client.terminateOnSpark + matrix.destroy, mllib:655-667). Idempotent."""
         if self._stopped:
             return
-        for arr in (self._full0, self._full1, self._norms, self._raw0,
-                    self._buckets, self._lanes):
+        for arr in (self._full0, self._full1, self._norms, self._inv_norms,
+                    self._raw0, self._buckets, self._lanes):
             if arr is not None:
                 try:
                     arr.delete()
@@ -980,7 +1241,7 @@ class Word2VecModel:
                     pass
         self._full0 = None  # type: ignore[assignment]
         self._full1 = None
-        self._norms = None
+        self._norms = self._inv_norms = None
         self._raw0 = self._buckets = self._lanes = None
         self._ann = None
         self._stopped = True
@@ -1046,6 +1307,19 @@ class _PendingSlide:
         self.result: Optional[jax.Array] = None
 
 
+class _SlideTokens(NamedTuple):
+    """One slide of ``Word2VecModel.sentence_vectors``, encoded
+    (``_encode_tokens``): what its program is handed, before the capacities."""
+
+    ids: np.ndarray          # int32 [W] rows of the in-vocabulary tokens, as sent
+    counts: np.ndarray       # int32 [S] how many of them each sentence holds
+    unseen: np.ndarray       # int32 [S] composed tokens each sentence holds
+    list_rows: np.ndarray    # int32 [L] the composed tokens' bucket rows, flat
+    list_counts: np.ndarray  # int32 [U] bucket rows of each composed token (> 0)
+    zero_norm: int           # tokens left out here: no vector, or one of zero norm
+    by_objects: bool         # the native table resolved the slide
+
+
 def _grid_up(n: int, floor: int) -> int:
     """``n`` rounded up to a whole number of tiles, a tile a sixteenth of the
     power of two at or under ``n`` and at least ``floor``: the sizes a
@@ -1081,6 +1355,66 @@ def _segment_means(table: jax.Array, ids: jax.Array, seg: jax.Array,
             return sums
         return (sums[:, :dim] / jnp.maximum(counts, 1)[:, None].astype(
             sums.dtype)).astype(jnp.float32)
+
+
+@partial(jax.jit, static_argnames=("segments", "dim"))
+def _sentence_means(table: jax.Array, scale: jax.Array, ids: jax.Array,
+                    seg: jax.Array, lists: Optional[tuple],
+                    counts: Optional[jax.Array], carried: Optional[tuple],
+                    segments: int, dim: int):
+    """One pass of a ``sentence_vectors`` slide, ONE program, a two-level
+    ragged reduction. Words, as :func:`_segment_means`: rows ``ids`` of
+    ``table``, each times ``scale[id]`` (1 / its norm: a unit vector),
+    summed into the sentences ``seg`` names. Composed tokens, where ``lists``
+    is handed over (``buckets``, ``rows``, ``token``, ``token_seg``): rows
+    ``rows`` of ``buckets`` (one past them reads zeros) summed into the
+    tokens ``token`` names (ascending: a token's rows lie together; one past
+    the token capacity is dropped), every token's sum divided by its own
+    norm (h / |h| whatever |G| divided the mean by; a sum of zero norm is
+    left out), the unit vectors summed into the sentences ``token_seg``
+    names and the tokens kept counted there. ``carried``: the sums and that
+    count from the pass before. The last pass is handed the sentences'
+    ``counts`` of words and returns the means ``[segments, dim]`` float32
+    over words and kept tokens together (zeros where there are none); a pass
+    before it returns (sums, kept). Sums, norms and the division in float32
+    (a wider table's in its own precision)."""
+    with jax.named_scope("transform.gather"):
+        rows = table.at[ids].get(mode="fill", fill_value=0)
+        acc = jnp.promote_types(rows.dtype, jnp.float32)
+        unit = rows.astype(acc) * scale.at[ids].get(
+            mode="fill", fill_value=0).astype(acc)[:, None]
+    with jax.named_scope("transform.segment_mean"):
+        sums = jax.ops.segment_sum(unit, seg, num_segments=segments,
+                                   indices_are_sorted=True)
+    kept = None
+    if lists is not None:
+        buckets, list_rows, token, token_seg = lists
+        with jax.named_scope("transform.list_gather"):
+            listed = buckets.at[list_rows].get(mode="fill", fill_value=0).astype(acc)
+        with jax.named_scope("transform.compose"):
+            h = jax.ops.segment_sum(
+                listed, token, num_segments=token_seg.shape[0],
+                indices_are_sorted=True)[:, :sums.shape[1]]
+            norm = jnp.sqrt((h * h).sum(axis=1))
+            live = norm > 0
+            h = jnp.where(live[:, None], h / jnp.where(live, norm, 1)[:, None], 0)
+        with jax.named_scope("transform.segment_mean"):
+            sums = sums + jax.ops.segment_sum(
+                h.astype(acc), token_seg, num_segments=segments,
+                indices_are_sorted=True)
+            kept = jax.ops.segment_sum(
+                live.astype(jnp.int32), token_seg, num_segments=segments,
+                indices_are_sorted=True)
+    if carried is not None:
+        sums = sums + carried[0]
+        if kept is not None:
+            kept = kept + carried[1]
+    if counts is None:
+        return sums, kept
+    if kept is not None:
+        counts = counts + kept
+    return (sums[:, :dim] / jnp.maximum(counts, 1)[:, None].astype(
+        sums.dtype)).astype(jnp.float32)
 
 
 def _row_slices(table: jax.Array, at: jax.Array) -> jax.Array:

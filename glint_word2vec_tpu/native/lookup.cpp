@@ -8,7 +8,10 @@
 // ctypes.PyDLL handle), goes once over the list of str, or the list of
 // sentences of str, and copies every token's UTF-8 bytes into a buffer of
 // its own: no pointer into a Python object outlives the call. Then
-// glint_lookup_walked, with the lock released, looks the buffer's tokens up.
+// glint_lookup_walked, with the lock released, looks the buffer's tokens up;
+// glint_lookup_walked_misses does the same and hands on the bytes of the
+// tokens no word is, for a caller that hashes their n-grams (a subword
+// model's sentence vectors), still with the lock released.
 //
 // Plain C ABI, no Python headers: the walk's handful of interpreter entry
 // points are stable-ABI symbols the process already exports, handed over by
@@ -167,6 +170,21 @@ void lookup_range(const Table* t, const Walk* w, int64_t lo, int64_t hi, int32_t
     }
 }
 
+// every token of a walked batch, split over at most n_threads threads
+void lookup_all(const Table* t, const Walk* w, int32_t* out, int32_t n_threads) {
+    const int64_t n = int64_t(w->end.size());
+    n_threads = int32_t(std::max<int64_t>(1, std::min<int64_t>(n_threads, n / 32768 + 1)));
+    if (n_threads == 1) {
+        lookup_range(t, w, 0, n, out);
+        return;
+    }
+    std::vector<std::thread> threads;
+    for (int32_t k = 0; k < n_threads; ++k)
+        threads.emplace_back(lookup_range, t, w, n * k / n_threads,
+                             n * (k + 1) / n_threads, out);
+    for (auto& th : threads) th.join();
+}
+
 }  // namespace
 
 extern "C" {
@@ -230,18 +248,8 @@ int64_t glint_lookup_walked(const void* table, void* walk, int32_t* out, int32_t
     const std::unique_ptr<Walk> w(static_cast<Walk*>(walk));
     const Table* t = static_cast<const Table*>(table);
     if (!t) return 0;
-    const int64_t n = int64_t(w->end.size());
-    n_threads = int32_t(std::max<int64_t>(1, std::min<int64_t>(n_threads, n / 32768 + 1)));
-    if (n_threads == 1) {
-        lookup_range(t, w.get(), 0, n, out);
-    } else {
-        std::vector<std::thread> threads;
-        for (int32_t k = 0; k < n_threads; ++k)
-            threads.emplace_back(lookup_range, t, w.get(), n * k / n_threads,
-                                 n * (k + 1) / n_threads, out);
-        for (auto& th : threads) th.join();
-    }
-    if (!counts) return n;
+    lookup_all(t, w.get(), out, n_threads);
+    if (!counts) return int64_t(w->end.size());
     int64_t kept = 0, at = 0;
     for (size_t s = 0; s < w->lengths.size(); ++s) {
         const int64_t before = kept;
@@ -252,6 +260,45 @@ int64_t glint_lookup_walked(const void* table, void* walk, int32_t* out, int32_t
     return kept;
 }
 
-int32_t glint_lookup_abi_version() { return 2; }
+// The bytes a walked batch holds: the room glint_lookup_walked_misses needs
+// in miss_bytes at most.
+int64_t glint_lookup_walk_bytes(const void* walk) {
+    return int64_t(static_cast<const Walk*>(walk)->bytes.size());
+}
+
+// glint_lookup_walked over a batch walked as sentences, the tokens under 0
+// handed on and not only dropped: miss_counts[s] of them in sentence s, their
+// bytes back to back in miss_bytes in the order sent (room:
+// glint_lookup_walk_bytes) and miss_end[i] the byte end of the i-th (room: a
+// token each). Returns the ids left in out; *n_missed the tokens handed on.
+int64_t glint_lookup_walked_misses(const void* table, void* walk, int32_t* out,
+                                   int32_t* counts, int32_t* miss_counts,
+                                   uint8_t* miss_bytes, int64_t* miss_end,
+                                   int64_t* n_missed, int32_t n_threads) {
+    const std::unique_ptr<Walk> w(static_cast<Walk*>(walk));
+    lookup_all(static_cast<const Table*>(table), w.get(), out, n_threads);
+    const char* bytes = w->bytes.data();
+    int64_t kept = 0, missed = 0, at = 0, byte_at = 0, miss_at = 0;
+    for (size_t s = 0; s < w->lengths.size(); ++s) {
+        const int64_t before = kept, missed_before = missed;
+        for (const int64_t stop = at + w->lengths[s]; at < stop; ++at) {
+            const int64_t byte_end = w->end[at];
+            if (out[at] >= 0) {
+                out[kept++] = out[at];
+            } else {
+                std::memcpy(miss_bytes + miss_at, bytes + byte_at, size_t(byte_end - byte_at));
+                miss_at += byte_end - byte_at;
+                miss_end[missed++] = miss_at;
+            }
+            byte_at = byte_end;
+        }
+        counts[s] = int32_t(kept - before);
+        miss_counts[s] = int32_t(missed - missed_before);
+    }
+    *n_missed = missed;
+    return kept;
+}
+
+int32_t glint_lookup_abi_version() { return 3; }
 
 }  // extern "C"

@@ -535,7 +535,8 @@ def _compose_block(out: jax.Array, raw0: jax.Array, buckets: jax.Array,
                    block: int) -> jax.Array:
     """``out`` with rows ``lo .. lo + block`` composed, in place (``out`` is
     donated). A block that would pass the last word starts earlier and
-    rewrites rows of the one before with the values they have."""
+    rewrites rows of the one before with the values they have. An ``out``
+    wider than D (whole lanes: :func:`compose_vectors`) gets zeros past D."""
     v, d = raw0.shape
     lo = jnp.minimum(lo, v - block)
     rows, inv = _lists(lo + jnp.arange(block, dtype=jnp.int32), table, max_groups)
@@ -544,6 +545,8 @@ def _compose_block(out: jax.Array, raw0: jax.Array, buckets: jax.Array,
     listed = jnp.where((rows >= v) & (rows != NO_ROW), rows - v, NO_ROW)
     own = jax.lax.dynamic_slice_in_dim(raw0, lo, block)
     h = (own.astype(jnp.float32) + _listed_sums(buckets, listed, d)) * inv[:, None]
+    if out.shape[1] > d:
+        h = jnp.pad(h, ((0, 0), (0, out.shape[1] - d)))
     return jax.lax.dynamic_update_slice_in_dim(out, h, lo, 0)
 
 
@@ -553,16 +556,19 @@ COMPOSE_BLOCK = 1 << 13
 
 
 def compose_vectors(raw0: jax.Array, buckets: jax.Array, table: SubwordTable,
-                    max_groups: int, block: int = COMPOSE_BLOCK) -> jax.Array:
+                    max_groups: int, block: int = COMPOSE_BLOCK,
+                    whole_lanes: bool = False) -> jax.Array:
     """[V, D] float32: h_w of every word of the vocabulary, from a trained
     syn0 as its two parts, the words' own rows ``raw0`` [V, D] and the bucket
     rows ``buckets`` [K, D or more] (:func:`lane_padded` on a TPU), in blocks
     of words written in place into one result (the model's query table;
     models/word2vec.py). No [V + K, D] array is made: each part is read where
-    it lies."""
+    it lies. ``whole_lanes``: the result is written straight at
+    :func:`lane_padded`'s width, zeros past D (the form row reads gather from
+    in place; a model that only reads rows never holds the [V, D] one)."""
     v, d = raw0.shape
     block = min(block, v)
-    out = jnp.zeros((v, d), jnp.float32)
+    out = jnp.zeros((v, pad_dim_to_lanes(d) if whole_lanes else d), jnp.float32)
     for lo in range(0, v, block):
         out = _compose_block(out, raw0, buckets, table, jnp.int32(lo),
                              max_groups, block)

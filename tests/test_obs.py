@@ -961,6 +961,28 @@ def _transform_slide_case():
                    jnp.ones((4, syn0.shape[1]), jnp.float32))
 
 
+def _sentence_slide_case():
+    """A ``sentence_vectors`` slide's one program (those two scopes and
+    ``transform.list_gather``, ``transform.compose``), a further pass of it."""
+    import jax.numpy as jnp
+    from glint_word2vec_tpu.models.word2vec import _sentence_means
+    _, (syn0, norms, _) = _cosine_topk_case()
+    ids = jnp.asarray([3, 0, 95, 3, 7, syn0.shape[0]], jnp.int32)
+    seg = jnp.asarray([0, 0, 1, 3, 3, 4], jnp.int32)
+    rows = jnp.asarray([5, 9, 9, 2, 40, syn0.shape[0]], jnp.int32)
+    token = jnp.asarray([0, 0, 0, 1, 1, 4], jnp.int32)
+    token_seg = jnp.asarray([1, 2, 4, 4], jnp.int32)
+
+    def slide(syn0, scale, ids, seg, rows, token, token_seg, counts, sums, kept):
+        return _sentence_means(syn0, scale, ids, seg, (syn0, rows, token, token_seg),
+                               counts, (sums, kept), 4, syn0.shape[1])
+
+    return slide, (syn0, 1.0 / norms, ids, seg, rows, token, token_seg,
+                   jnp.asarray([2, 1, 0, 2], jnp.int32),
+                   jnp.ones((4, syn0.shape[1]), jnp.float32),
+                   jnp.asarray([0, 1, 0, 0], jnp.int32))
+
+
 def _gather_topk_sharded_case():
     """The same program over a table partitioned by rows on four of the
     virtual devices (``scan.owner_rows``, ``scan.merge`` too)."""
@@ -1070,6 +1092,7 @@ def test_cbow_pack_span_is_recorded_with_its_args_only_when_on(update, tmp_path)
 @pytest.mark.parametrize("case", [_sgns_shared_step_case, _cosine_topk_case,
                                   _gather_topk_case, _gather_topk_mixed_case,
                                   _gather_topk_sharded_case, _transform_slide_case,
+                                  _sentence_slide_case,
                                   _cbow_scatter_step_case, _cbow_banded_step_case])
 def test_named_scopes_change_metadata_only(case, monkeypatch):
     """The compiled step and scan with the scopes are the programs without

@@ -197,7 +197,7 @@ def test_model_and_service_leave_their_spans():
     rng = np.random.default_rng(1)
     model = Word2VecModel(vocab, rng.standard_normal((V, 8)).astype(np.float32))
     (init,) = since.events("model.init")
-    assert init["args"] == {"words": V, "subword": 0}
+    assert init["args"] == {"words": V, "subword": 0, "resident": "all"}
     assert not since.events("model.norms") and not since.events("model.row_table")
     service = EmbeddingService(model=model, ann=False)
     try:

@@ -459,7 +459,10 @@ def _no_table_copied(text: str):
     assert not re.search(r"f32\[%d," % (SUB_V + SUB_K), text)
 
 
-def test_the_composed_tables_block_copies_no_table(one_chip):
+@pytest.mark.parametrize("width", [SUB_D, 384], ids=["as_trained", "whole_lanes"])
+def test_the_composed_tables_block_copies_no_table(one_chip, width):
+    """``width`` 384: the table a ``resident="rows"`` model composes straight
+    at whole lanes (PR 52), written in place as the [V, 300] one is."""
     from glint_word2vec_tpu.ops import subword as sw
 
     def spec(shape, dtype):
@@ -469,13 +472,13 @@ def test_the_composed_tables_block_copies_no_table(one_chip):
                             spec((SUB_GROUPS, 8), jnp.int32),
                             spec((SUB_V + 1,), jnp.int32))
     compiled = sw._compose_block.lower(
-        spec((SUB_V, SUB_D), jnp.float32), spec((SUB_V, SUB_D), jnp.float32),
+        spec((SUB_V, width), jnp.float32), spec((SUB_V, SUB_D), jnp.float32),
         spec((SUB_K, 384), jnp.float32), table, spec((), jnp.int32),
         max_groups=5, block=1 << 13).compile()
     _no_table_copied(compiled.as_text())
     memory = compiled.memory_analysis()
     # the result is the donated operand, and a block's gather is what is made
-    assert memory.alias_size_in_bytes >= 4 * SUB_V * SUB_D
+    assert memory.alias_size_in_bytes >= 4 * SUB_V * width
     assert memory.temp_size_in_bytes < 1 << 30
 
 
@@ -554,6 +557,36 @@ def test_the_transform_slide_copies_no_table_and_writes_no_gathered_block(one_ch
         segments=sentences, dim=300).compile()
     text = compiled.as_text()
     assert not re.findall(r"= f32\[%d,\d+\]\S* copy\(" % V, text)
+    assert " sort(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("carried", [False, True], ids=["one_pass", "a_further_pass"])
+def test_the_sentence_vector_slide_copies_no_table_and_writes_no_block(one_chip, carried):
+    """``sentence_vectors``' one program a slide (PR 52) at
+    ``subword-sentvec-2.5m-300``'s size: 327,680 word rows gathered from the
+    composed table at whole lanes and scaled by their inverse norms, 294,912
+    list rows gathered from the bucket rows into 32,768 tokens, normalised,
+    both summed into 10,000 sentences. No copy of either table, no sort, and
+    every gather is its sorted scatter-add's producer: neither gathered block
+    nor the token block is written (what is made is the ``[10000, 384]``
+    sums)."""
+    from glint_word2vec_tpu.models import word2vec as w2v
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, listed, tokens, sentences = 327_680, 294_912, 32_768, 10_000
+    lists = (spec((SUB_K, 384), jnp.float32), spec((listed,), jnp.int32),
+             spec((listed,), jnp.int32), spec((tokens,), jnp.int32))
+    before = ((spec((sentences, 384), jnp.float32), spec((sentences,), jnp.int32))
+              if carried else None)
+    compiled = w2v._sentence_means.lower(
+        spec((SUB_V, 384), jnp.float32), spec((SUB_V,), jnp.float32),
+        spec((rows,), jnp.int32), spec((rows,), jnp.int32), lists,
+        spec((sentences,), jnp.int32), before, segments=sentences, dim=300).compile()
+    text = compiled.as_text()
+    _no_table_copied(text)
     assert " sort(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
