@@ -117,6 +117,60 @@ def keep_probabilities(
     return np.minimum(keep, 1.0)
 
 
+# keep_probabilities is sqrt(y) + y with y = ratio * T / count: under 1
+# exactly where sqrt(y) < (sqrt(5) - 1) / 2, i.e. where count > ratio * T / this
+_KEEP_SPLIT = ((5.0 ** 0.5 - 1.0) / 2.0) ** 2
+
+
+class KeptCountTable:
+    """``(counts * keep_probabilities(counts, T, ratio))``'s sum and max at any
+    ratio, from two running sums over the counts made once.
+
+    A word's kept count is ``sqrt(ratio*T*c) + ratio*T`` where its keep
+    probability is under 1 (``c > ratio*T / _KEEP_SPLIT``: the HEAD of the
+    counts in descending order) and ``c`` where it is 1 (the tail), so with
+    ``j`` the head's length, one ``searchsorted``,
+
+        sum = sqrt(ratio*T) * sum_{i<j} sqrt(c_i) + ratio*T * j + sum_{i>=j} c_i
+
+    and the max is the most frequent word's: the kept count grows with the
+    count in the head, and meets the tail's ``c`` at the split. Float64
+    throughout, like the pass it stands for; three arrays of V doubles."""
+
+    def __init__(self, counts: np.ndarray, train_words_count: int):
+        c = np.asarray(counts, np.float64)
+        # a Vocabulary's counts lie in descending order; a hand-made one's may not
+        if not np.all(c[:-1] >= c[1:]):
+            c = np.ascontiguousarray(np.sort(c)[::-1])
+        self._desc = c
+        self._train_words_count = train_words_count
+        self._sqrt_before = np.zeros(c.size + 1)
+        np.sqrt(c, out=self._sqrt_before[1:])
+        np.cumsum(self._sqrt_before[1:], out=self._sqrt_before[1:])
+        # whole counts add exactly in float64 (below 2**53 words), so the
+        # tail's sum, total - before, loses nothing
+        self._count_before = np.zeros(c.size + 1)
+        np.cumsum(c, out=self._count_before[1:])
+
+    def kept(self, subsample_ratio: float) -> Tuple[float, float]:
+        """``(sum, max)`` of the kept counts under ``subsample_ratio``;
+        subsampling off (``<= 0``) is all tail."""
+        c = self._desc
+        if subsample_ratio <= 0:
+            head, scale = 0, 0.0
+        else:
+            scale = float(subsample_ratio) * float(self._train_words_count)
+            # the ascending view of the descending counts: how many are <= the split
+            head = c.size - int(np.searchsorted(
+                c[::-1], scale / _KEEP_SPLIT, side="right"))
+        total = (scale ** 0.5 * self._sqrt_before[head] + scale * head
+                 + (self._count_before[-1] - self._count_before[head]))
+        # the first word's, as the pass computed it (0 over no words at all)
+        top = (c[:1] * keep_probabilities(
+            c[:1], self._train_words_count, subsample_ratio)).max(initial=0.0)
+        return float(total), float(top)
+
+
 def expected_kept_words(
     counts: np.ndarray, train_words_count: int, subsample_ratio: float
 ) -> int:

@@ -660,14 +660,19 @@ class Trainer:
         self._tracer = default_tracer()
         self.config = config
         self.vocab = vocab
-        # passes over the counts that the AUTO resolutions made
-        # (_duplicate_load): the ``passes`` of ``trainer.resolve_auto``
+        # the duplicate load's table (_duplicate_load makes it, the
+        # constructor's last reader, _stability_warnings, drops it), the passes
+        # over the counts made for it and the ratios evaluated from it: the
+        # ``passes`` and ``evaluations`` of ``trainer.resolve_auto``
+        self._load_table = None
         self._auto_passes = 0
+        self._auto_evaluations = 0
         # vocab-scaled AUTO pool (EVAL.md round-5): config resolved the pool
         # without seeing the vocabulary; at > 500k words the measured safe
         # load band tightens 600 -> 160, so a still-AUTO pool re-resolves
         # upward here. Must run before anything reads config.negative_pool.
-        with self._tracer.span("trainer.resolve_auto", pinned=True, passes=0):
+        with self._tracer.span("trainer.resolve_auto", pinned=True, passes=0,
+                               evaluations=0):
             self._resolve_vocab_scaled_pool()
         config = self.config
         if plan is None:
@@ -1256,6 +1261,7 @@ class Trainer:
                 "(EVAL.md)", pool_load,
                 max(64, int(cfg.pairs_per_batch * cfg.negatives / 1300)))
         dup_load = self._duplicate_load(cfg.subsample_ratio)
+        self._load_table = None  # its last reader: three arrays of V doubles go
         if dup_load > 300:
             logger.warning(
                 "expected duplicates of the most frequent word per %d-pair batch "
@@ -1276,14 +1282,26 @@ class Trainer:
 
     def _duplicate_load(self, subsample_ratio: float) -> float:
         """Expected in-batch duplicates of the most frequent word under the given
-        subsample ratio — the divergence channel's driving quantity (EVAL.md)."""
-        from glint_word2vec_tpu.data.pipeline import keep_probabilities
+        subsample ratio — the divergence channel's driving quantity (EVAL.md).
+
+        The top word's share of the kept counts, ``max(c*keep) / sum(c*keep)``,
+        times the batch's real pairs. Both come from a
+        :class:`~glint_word2vec_tpu.data.pipeline.KeptCountTable` in O(log V):
+        with ``y = ratio*T/c`` a keep probability is ``min(sqrt(y) + y, 1)``,
+        so the words split at ONE count and the sum is two running sums read
+        at the split (the closed form is the table's docstring). The table is
+        made at the first call (the one pass over the counts) and dropped by
+        ``_stability_warnings``, its last reader in the constructor: the AUTO
+        search's 62 evaluations cost no pass. A later caller makes it again
+        and it stays until the next ``_stability_warnings``."""
+        from glint_word2vec_tpu.data.pipeline import KeptCountTable
         cfg = self.config
-        self._auto_passes += 1
-        keep = keep_probabilities(
-            self.vocab.counts, self.vocab.train_words_count, subsample_ratio)
-        eff = np.asarray(self.vocab.counts, np.float64) * keep
-        s = float(eff.sum())
+        if self._load_table is None:
+            self._load_table = KeptCountTable(
+                self.vocab.counts, self.vocab.train_words_count)
+            self._auto_passes += 1
+        self._auto_evaluations += 1
+        s, top = self._load_table.kept(subsample_ratio)
         if s <= 0.0:
             return 0.0
         # a batch cannot hold more REAL pairs than one epoch supplies — on
@@ -1293,7 +1311,7 @@ class Trainer:
         # NB: a max(s, 1.0) floor on the denominator would deflate the SHARE
         # whenever strong subsampling drives the total effective count below 1
         # (the share is scale-free; only s == 0 needs guarding)
-        return float(eff.max()) / s * real_pairs
+        return top / s * real_pairs
 
     # the measured NaN boundary is ~300 expected top-word duplicates per batch
     # (EVAL.md round-4 addendum: 336 trains to NaN at 60M words); auto-lowering
@@ -1310,11 +1328,16 @@ class Trainer:
         reference never faces this channel — its async 50-pair minibatches
         interleave a frequent word's updates instead of summing them
         (mllib:417-429). The pinned span ``trainer.resolve_auto``, with the
-        passes over the counts it made as ``passes``."""
-        before = self._auto_passes
+        passes over the counts it made as ``passes`` (one, the making of
+        ``_duplicate_load``'s table, or none where an earlier resolution left
+        it) and the ratios it evaluated from the table as ``evaluations``
+        (62 where the search ran: two probes and 60 halvings; 1 where the
+        configured ratio already holds)."""
+        passes, evaluations = self._auto_passes, self._auto_evaluations
         with self._tracer.span("trainer.resolve_auto", pinned=True) as span:
             self._bound_duplicate_channel()
-            span.set(passes=self._auto_passes - before)
+            span.set(passes=self._auto_passes - passes,
+                     evaluations=self._auto_evaluations - evaluations)
 
     def _bound_duplicate_channel(self) -> None:
         cfg = self.config
