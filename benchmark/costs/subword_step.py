@@ -10,8 +10,9 @@ of P negatives, on tables of padded width D:
   hundred center runs of the batch list is counted a hundred times, because
   the step reads and adds it a hundred times; a form that summed a batch's
   duplicate rows first would move fewer and read above this roofline's share.
-  Where the step works once per center run that is ~5 rows a pair, where it
-  worked per pair it would be ~19;
+  Where the step works once per distinct word of the batch (a piece of its run
+  heads: what the window runs since PR 34) that is ~3.7 rows a pair, once per
+  center run (a batch over the word cap) ~5, per pair ~19;
 - B context rows and P pool rows of syn1;
 - three passes over all of them in the tables' dtype: the gather, and the
   update's read and its write;

@@ -4,6 +4,10 @@ Every cell of BENCHMARK.json resolves to files that exist; every per-layer
 metric moves an end-to-end metric that each of its cells reports; names and
 units keep to the allowed characters; ``run.py --tiny`` ends in one JSON line
 with the contract's keys; a throw-away cell needs new files and one entry only.
+
+A layer file names a READING (a reader and its args), never a kind of traffic:
+which cells report it is the manifest's ``workloads`` list alone, and no two
+names of one layer stand for the same reading (PR 54).
 """
 
 import importlib
@@ -27,6 +31,34 @@ MANIFEST = loader.load_manifest(ROOT)
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+# the benchmark's contract (the builder's instructions of every `benchmark` PR:
+# "`per_layer`: 1 to 128 metrics of single layers"); PR 52 met it at 128 of 128
+PER_LAYER_LIMIT = 128
+ENTRIES = {m["name"]: m for m in MANIFEST["per_layer"]}
+# the one reading that stands under two names: tests/test_serve.py, outside the
+# benchmark's paths and so not a `benchmark` PR's to edit, opens this file by
+# name; a PR that may edit it retires the name into dispatch_overlap_share
+PINNED_FROM_OUTSIDE = {"subword_query_dispatch_overlap_share"}
+
+
+def _layer_files() -> dict:
+    layer_dir = os.path.join(BENCH, "layers")
+    specs = {}
+    for f in sorted(os.listdir(layer_dir)):
+        with open(os.path.join(layer_dir, f)) as fh:
+            specs[f[:-len(".json")]] = json.load(fh)
+    return specs
+
+
+LAYER_FILES = _layer_files()
+
+
+def _reading(name):
+    """What a layer file reads: its reader and args, a roofline's pointer at
+    the time it divides by aside."""
+    spec = LAYER_FILES[name]
+    args = {k: v for k, v in spec["args"].items() if k != "time_ms"}
+    return spec["reader"], json.dumps(args, sort_keys=True)
 
 
 @pytest.mark.parametrize("cell_name", CELLS)
@@ -36,7 +68,6 @@ def test_cell_resolves_to_files(cell_name):
     assert os.path.exists(os.path.join(BENCH, "kinds", cell["kind"] + ".py"))
     assert cell["layers"], "a cell reports at least one per-layer metric"
     for layer in cell["layers"]:
-        assert cell["kind"] in layer["kinds"]
         assert os.path.exists(os.path.join(BENCH, "readers", layer["reader"] + ".py"))
         if layer["reader"] == "roofline":
             assert os.path.exists(
@@ -88,9 +119,43 @@ def test_names_units_and_limits_of_the_manifest():
                                "host_clock")
         assert "\n" not in m["layer"] and 1 <= len(m["layer"]) <= 200
     assert any(m["name"] == "setup_s" for m in MANIFEST["end_to_end"])
+    assert len(MANIFEST["per_layer"]) <= PER_LAYER_LIMIT
     cells = 24
     assert (2 + 14 * cells) * (MANIFEST["run_seconds"] + 60) + cells * 180 + 1200 <= 43200
     assert len(json.dumps(MANIFEST)) < 64 * 1024
+
+
+def test_every_layer_file_has_an_entry_and_every_entry_a_file():
+    assert sorted(LAYER_FILES) == sorted(ENTRIES)
+
+
+@pytest.mark.parametrize("name", sorted(LAYER_FILES))
+def test_a_layer_file_names_a_reading_no_other_name_of_its_layer_has(name):
+    spec = LAYER_FILES[name]
+    assert set(spec) == {"reader", "args", "what"} and spec["what"], (
+        "a layer file names a reading; which cells report it (no `kinds`) is the "
+        "manifest's `workloads`")
+    if name in PINNED_FROM_OUTSIDE:
+        return
+    twins = [other for other in LAYER_FILES
+             if other != name and other not in PINNED_FROM_OUTSIDE
+             and _reading(other) == _reading(name)
+             and ENTRIES[other]["layer"] == ENTRIES[name]["layer"]]
+    assert not twins, (f"{name} and {twins} are one reading of layer "
+                       f"{ENTRIES[name]['layer']!r}: one name, and the cells in its `workloads`")
+
+
+def test_a_roofline_divides_by_a_time_its_cells_report_before_it():
+    order = [m["name"] for m in MANIFEST["per_layer"]]
+    rooflines = [n for n in order if LAYER_FILES[n]["reader"] == "roofline"]
+    assert rooflines
+    for name in rooflines:
+        time_ms = LAYER_FILES[name]["args"]["time_ms"]
+        assert time_ms in ENTRIES, (name, time_ms)
+        # run.py reads the list in order; readers/roofline.py takes layer_values[time_ms]
+        assert order.index(time_ms) < order.index(name), (name, time_ms)
+        cells = ENTRIES[name].get("workloads", CELLS)
+        assert all(loader.metric_applies(ENTRIES[time_ms], c) for c in cells), (name, time_ms)
 
 
 def _run(args, cwd=ROOT):
@@ -161,7 +226,8 @@ def test_unknown_device_kind_has_no_peaks():
 def test_a_new_cell_needs_new_files_and_one_entry_only(tmp_path):
     """A throw-away configuration, mix and per-layer metric, added as files of
     their own beside a copy of the benchmark, resolve with no edit to a file
-    that was there."""
+    that was there; a reading the benchmark already has (``feed_wait_share``)
+    the new cell joins by that entry's ``workloads`` list, under no new name."""
     root = str(tmp_path)
     shutil.copytree(BENCH, os.path.join(root, "benchmark"),
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -174,8 +240,8 @@ def test_a_new_cell_needs_new_files_and_one_entry_only(tmp_path):
     mix = json.load(open(os.path.join(bench, "traffic", "train-zipf-b64k.json")))
     mix.update(pairs_per_batch=1024)
     json.dump(mix, open(os.path.join(bench, "traffic", "train-throwaway.json"), "w"))
-    json.dump({"kinds": ["train"], "reader": "counter",
-               "args": {"num": "host_wait_s", "den": "window_s"}},
+    json.dump({"reader": "counter", "args": {"num": "batch_items", "den": "window_s"},
+               "what": "test"},
               open(os.path.join(bench, "layers", "throwaway_share.json"), "w"))
     manifest = json.loads(json.dumps(MANIFEST))
     manifest["configs"].append({"name": "sgns-throwaway", "source": "none",
@@ -192,13 +258,16 @@ def test_a_new_cell_needs_new_files_and_one_entry_only(tmp_path):
                                   "layer": "fit loop and feed",
                                   "moves": "train_pairs_per_s",
                                   "workloads": ["sgns-throwaway.train"]})
+    for m in manifest["per_layer"]:
+        if m["name"] == "feed_wait_share":
+            m["workloads"].append("sgns-throwaway.train")
     cell = loader.resolve(manifest, "sgns-throwaway.train", root)
     assert cell["config"]["vocab_size"] == 50000
     assert cell["traffic"]["pairs_per_batch"] == 1024 and cell["kind"] == "train"
-    assert [l["name"] for l in cell["layers"]] == ["throwaway_share"]
-    assert importlib.import_module("readers." + cell["layers"][0]["reader"]).read(
-        cell["layers"][0]["args"], {"counters": {"host_wait_s": 1.0, "window_s": 4.0}}
-    ) == 0.25
+    assert [l["name"] for l in cell["layers"]] == ["feed_wait_share", "throwaway_share"]
+    counters = {"counters": {"host_wait_s": 1.0, "batch_items": 2.0, "window_s": 4.0}}
+    assert [importlib.import_module("readers." + l["reader"]).read(l["args"], counters)
+            for l in cell["layers"]] == [0.25, 0.5]
     for p, data in before.items():
         hits = [os.path.join(dp, p) for dp, _, fs in os.walk(bench) if p in fs]
         assert open(hits[0], "rb").read() == data

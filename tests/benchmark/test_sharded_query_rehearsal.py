@@ -52,8 +52,8 @@ def test_tiny_run_on_four_virtual_devices_ends_in_the_contracts_line(trace):
         assert len(line["breakdown"]["idle_gaps"]) <= 10
         # the engagement counters, where the sharded scan runs
         assert line["metrics"]["sharded_query_merge_rows"]["value"] == 44.0
-        assert line["metrics"]["sharded_query_scan_topk_rows"]["value"] == 1448.0
-        assert line["metrics"]["sharded_query_row_fetch_ops"]["value"] == 1.0
+        assert line["metrics"]["scan_topk_rows"]["value"] == 1448.0
+        assert line["metrics"]["row_fetch_ops"]["value"] == 1.0
     assert "check " in out.stdout and "(limit " in out.stdout
     assert "check scan_shards_off: 0 " in out.stdout
 
