@@ -19,6 +19,7 @@ on device.
 from __future__ import annotations
 
 import collections
+import itertools
 import logging
 import math
 import os
@@ -114,6 +115,8 @@ class Word2VecModel:
         # slides whose result is not in yet, both under the one lock
         self._lock = make_lock("model.rows")
         self._lanes: Optional[jax.Array] = None
+        # the analogy scan's bfloat16 form of syn0 (_scan_table), on a TPU
+        self._scan0: Optional[jax.Array] = None
         self._slides_inflight = 0
         self._norms: Optional[jax.Array] = None
         # sentence_vectors' scale of a word's row, 1 / its norm (0 for a row
@@ -719,6 +722,30 @@ class Word2VecModel:
                     self._inv_norms = inv
         return self._inv_norms
 
+    def _scan_table(self) -> jax.Array:
+        """The table the analogy scan's matmul reads. On a TPU a float32
+        syn0's bfloat16 rounding, made once at the first :meth:`analogies`
+        call under the model's lock and kept until :meth:`stop` (1.8 GB
+        beside syn0's 3.6 at 3M x 300): at the default precision the MXU
+        takes float32 operands rounded to bfloat16 (the compiler converts the
+        table itself, WHOLE and once a program where the blocks are scored
+        under a loop: 1.8 GB of temporaries a program in flight and a second
+        pass over the table; PERF.md §6, PR 55), so the scores are the ones
+        the float32 table gives, accumulated and returned in float32, and a
+        program reads half the bytes. Elsewhere, and for a table of another
+        dtype, syn0 as it lies (a CPU's float32 matmul rounds nothing)."""
+        if jax.default_backend() != "tpu" or self._full0.dtype != jnp.float32:
+            return self._full0
+        with self._lock:
+            if self._scan0 is None:
+                # once a model: a pinned span (obs/spans.py)
+                with default_tracer().span(
+                        "model.scan_table", pinned=True,
+                        rows=int(self._full0.shape[0])):
+                    self._scan0 = self._full0.astype(jnp.bfloat16)
+                    self._scan0.block_until_ready()
+        return self._scan0
+
     def multiply(self, vector: np.ndarray) -> np.ndarray:
         """Full matrix–vector product syn0 @ v (the PS ``multiply`` powering cosine
         search, mllib:598). One matvec on device; over a mesh one sharded
@@ -958,20 +985,31 @@ class Word2VecModel:
         self._check_alive()
         tracer = default_tracer()
         out: List[List[Tuple[str, float]]] = []
-        for at, (lo, part_ids, _, _) in enumerate(pending.parts):
-            scores, idxs = pending.results[at]
-            pending.results[at] = None
-            with tracer.span("serve.result_fetch", parent=pending.parent):
-                # rows past the chunk's queries are _topk_dispatch's padding
-                scores = np.asarray(scores)[:len(part_ids)]
-                idxs = np.asarray(idxs)[:len(part_ids)]
-            if len(pending.results) < len(pending.parts):
-                self._enqueue_part(pending)
+        for (lo, part_ids, _, _), (scores, idxs) in self._fetched_parts(
+                pending, self._enqueue_part, "serve.result_fetch"):
             with tracer.span("serve.reply_build", parent=pending.parent):
+                # rows past the chunk's queries are _topk_dispatch's padding
                 out.extend(self._replies(
-                    pending.words[lo:lo + len(part_ids)], scores, idxs,
+                    pending.words[lo:lo + len(part_ids)],
+                    scores[:len(part_ids)], idxs[:len(part_ids)],
                     pending.num))
         return out
+
+    def _fetched_parts(self, pending: "_PendingSynonyms", enqueue,
+                       fetch_span: str):
+        """Every part of ``pending`` with its results fetched (span
+        ``fetch_span``), in order; as one is fetched ``enqueue(pending)``
+        sends the next that is not enqueued yet, so a call of many parts
+        keeps :data:`_PARTS_IN_FLIGHT` on the device until its last."""
+        tracer = default_tracer()
+        for at, part in enumerate(pending.parts):
+            result = pending.results[at]
+            pending.results[at] = None
+            with tracer.span(fetch_span, parent=pending.parent):
+                fetched = tuple(np.asarray(a) for a in result)
+            if len(pending.results) < len(pending.parts):
+                enqueue(pending)
+            yield part, fetched
 
     def _unseen_lists(self, queries, unseen: List[int]):
         """The batch's strings the vocabulary lacks (positions ``unseen``;
@@ -1051,6 +1089,160 @@ class Word2VecModel:
         va, vb, vc = self.transform(a), self.transform(b), self.transform(c)
         res = self.find_synonyms(vb - va + vc, num + 3)
         return [(w, s) for w, s in res if w not in (a, b, c)][:num]
+
+    def analogies(
+        self,
+        questions: Union[Sequence[Sequence[str]], np.ndarray],
+        num: int = 1,
+        restrict_vocab: Optional[int] = None,
+    ) -> List[Optional[List[Tuple[str, float]]]]:
+        """Batched 3CosAdd, word2vec's ``compute-accuracy.c`` and gensim's
+        ``most_similar(positive=[b, c], negative=[a], topn=num)``
+        (https://code.google.com/archive/p/word2vec/; Mikolov et al. 2013,
+        arXiv:1301.3781 §4.1) as ONE operation over every question of the
+        call. With û_w row w of syn0 over its norm (0 for a row of zero
+        norm), a question (a, b, c) asks for the ``num`` rows w, a, b and c
+        excluded, of the largest cos(q, û_w), q = û_b − û_a + û_c, over the
+        first ``restrict_vocab`` rows of the vocabulary (the tool's
+        ``threshold``; all of them where None). ``questions``: (a, b, c)
+        strings, or an ``int32[N, 3]`` of row ids. Returns, in the order
+        asked, the ``(word, cosine)`` pairs best first, and ``None`` for a
+        question with a word outside those rows (skipped, never an error).
+
+        Exact: every candidate row is scored, in float32 at the scan's own
+        matmul precision (:meth:`find_synonyms_batch`'s), and what comes back
+        is ``lax.top_k``'s over the masked scores, ties toward the lower row.
+        Departures from the C tool: it upper-cases the vocabulary and the
+        questions (the caller's business here); it answers nothing where no
+        score is positive (its ``bestd`` starts at 0), where this returns the
+        best row whatever its sign. Its strict ``>`` over an ascending loop
+        breaks ties toward the lower row, as here.
+
+        Per call: one :meth:`Vocabulary.lookup` of all the words (span
+        ``eval.encode``); then programs of at most
+        :data:`_ANALOGY_MAX_QUESTIONS` questions each, at a capacity on
+        :func:`_grid_up`'s grid (:func:`_analogy_topk`: the question rows
+        read in place, each DISTINCT word once, the table scored in blocks
+        of rows so that no ``[Q, V]`` block is ever resident, a, b and c
+        masked by row id before the selection), at most
+        :data:`_PARTS_IN_FLIGHT` enqueued at a time (``eval.enqueue``) and
+        the next sent as one is fetched (``eval.fetch``): the machinery of
+        :meth:`find_synonyms_begin` / :meth:`find_synonyms_finish`. A table
+        partitioned by rows over a mesh raises ``NotImplementedError``
+        (``find_synonyms_batch`` of host-built vectors answers there).
+        :meth:`analogy` is the one-question form on RAW rows (upstream's
+        integration spec) and is left as it was."""
+        ids, live, scores, rows = self._analogy_scan(questions, 3, num, restrict_vocab)
+        out: List[Optional[List[Tuple[str, float]]]] = [None] * len(ids)
+        words = self.vocab.words
+        for at, srow, irow in zip(np.flatnonzero(live).tolist(), scores, rows):
+            # -inf: an excluded row, ranked only where fewer than ``num``
+            # candidates were left
+            out[at] = [(words[int(i)], float(sc)) for sc, i in zip(srow, irow)
+                       if sc != -np.inf]
+        return out
+
+    def analogy_accuracy(
+        self,
+        questions: Union[Sequence[Sequence[str]], np.ndarray],
+        restrict_vocab: Optional[int] = None,
+    ) -> Dict[str, Union[int, float]]:
+        """The accuracy test of ``compute-accuracy.c`` / gensim's
+        ``evaluate_word_analogies`` over (a, b, c, d) questions (strings, or
+        an ``int32[N, 4]`` of row ids): :meth:`analogies` at ``num`` = 1; a
+        question is correct where the answer is d. A question any of whose
+        four words lies outside the first ``restrict_vocab`` rows is skipped:
+        counted as ``seen``, never scored. Returns ``seen``, ``scored``,
+        ``skipped``, ``correct`` and ``accuracy`` = correct / scored (0.0
+        where nothing was scored). Sections are the caller's: one call a
+        section, as both public tools report."""
+        ids, live, _, rows = self._analogy_scan(questions, 4, 1, restrict_vocab)
+        scored = len(rows)
+        correct = int((rows[:, 0] == ids[live, 3]).sum())
+        return {"seen": len(ids), "scored": scored, "skipped": len(ids) - scored,
+                "correct": correct,
+                "accuracy": correct / scored if scored else 0.0}
+
+    def _analogy_scan(self, questions, width: int, num: int,
+                      restrict_vocab: Optional[int]):
+        """Both analogy operations up to the fetched answers: the questions'
+        row ids (``int32[N, width]``, -1 a word the vocabulary lacks), which
+        of them were scored, and the live ones' ``[L, k]`` cosines and rows."""
+        self._check_alive("analogies")
+        if _row_shards(self._full0) is not None:
+            raise NotImplementedError(
+                "analogies / analogy_accuracy scan a table that lies on one "
+                "device; this one is partitioned by rows over a mesh")
+        if num < 1:
+            raise ValueError(f"num must be at least 1, not {num}")
+        tracer = default_tracer()
+        candidates = self.num_words if restrict_vocab is None else max(
+            0, min(int(restrict_vocab), self.num_words))
+        with tracer.span("eval.call", num=num, candidates=candidates) as call:
+            with tracer.span("eval.encode") as sp:
+                if isinstance(questions, np.ndarray):
+                    ids = np.ascontiguousarray(questions, np.int32)
+                    if ids.ndim != 2 or ids.shape[1] != width:
+                        raise ValueError(f"expected int32[N, {width}] row ids, "
+                                         f"got {ids.shape}")
+                else:
+                    if any(len(q) != width for q in questions):
+                        raise ValueError(f"every question holds {width} words")
+                    ids = self.vocab.lookup(list(
+                        itertools.chain.from_iterable(questions))).reshape(-1, width)
+                live = ((ids >= 0) & (ids < candidates)).all(axis=1)
+                # every span of the call is taken on this thread, inside
+                # ``eval.call``: the stack names their parent
+                pending = _PendingSynonyms(num, None)
+                pending.k = max(1, min(num, candidates))
+                asked = ids[live, :3]
+                distinct = 0
+                for lo in range(0, len(asked), _ANALOGY_MAX_QUESTIONS):
+                    part = asked[lo:lo + _ANALOGY_MAX_QUESTIONS]
+                    cap = min(_grid_up(len(part), _ANALOGY_CAP_FLOOR),
+                              _ANALOGY_MAX_QUESTIONS)
+                    # each distinct word's row is read once; a question names
+                    # its three by their place among them
+                    words, at = np.unique(part, return_inverse=True)
+                    distinct += len(words)
+                    held = np.zeros(3 * cap, np.int32)
+                    held[:len(words)] = words
+                    pos = np.zeros((cap, 3), np.int32)
+                    pos[:len(part)] = at.reshape(-1, 3)
+                    pending.parts.append((lo, len(part), cap, held,
+                                          np.int32(len(words)), pos))
+                pending.scan = dict(candidates=candidates)
+                sp.set(words=ids.size, distinct=distinct)
+            call.set(questions=len(ids), scored=len(asked),
+                     skipped=len(ids) - len(asked))
+            scores = np.empty((len(asked), pending.k), np.float32)
+            rows = np.empty((len(asked), pending.k), np.int32)
+            if pending.parts:
+                self._inverse_norms()
+                for _ in pending.parts[:_PARTS_IN_FLIGHT]:
+                    self._enqueue_analogy_part(pending)
+            for (lo, n, *_), (part_scores, part_rows) in self._fetched_parts(
+                    pending, self._enqueue_analogy_part, "eval.fetch"):
+                # rows past the part's questions are the capacity's padding
+                scores[lo:lo + n] = part_scores[:n]
+                rows[lo:lo + n] = part_rows[:n]
+        return ids, live, scores, rows
+
+    def _enqueue_analogy_part(self, pending: "_PendingSynonyms") -> None:
+        """Enqueue the program of ``pending``'s next part and start its
+        answers on the way back to the host."""
+        _, n, cap, held, distinct, pos = pending.parts[len(pending.results)]
+        with default_tracer().span(
+                "eval.enqueue", parent=pending.parent, questions=n, cap=cap,
+                programs=len(pending.parts),
+                inflight=sum(r is not None for r in pending.results)):
+            result = _analogy_topk(
+                self._full0, self._scan_table(), self._inv_norms, held,
+                distinct, pos, pending.k, pending.scan["candidates"],
+                _ANALOGY_BLOCK_ROWS)
+            for a in result:
+                a.copy_to_host_async()
+        pending.results.append(result)
 
     # -- exports (C8 mllib:638-662) ----------------------------------------------------
 
@@ -1233,7 +1425,7 @@ class Word2VecModel:
         if self._stopped:
             return
         for arr in (self._full0, self._full1, self._norms, self._inv_norms,
-                    self._raw0, self._buckets, self._lanes):
+                    self._raw0, self._buckets, self._lanes, self._scan0):
             if arr is not None:
                 try:
                     arr.delete()
@@ -1242,7 +1434,7 @@ class Word2VecModel:
         self._full0 = None  # type: ignore[assignment]
         self._full1 = None
         self._norms = self._inv_norms = None
-        self._raw0 = self._buckets = self._lanes = None
+        self._raw0 = self._buckets = self._lanes = self._scan0 = None
         self._ann = None
         self._stopped = True
 
@@ -1279,6 +1471,19 @@ class _PendingSynonyms:
         self.results: list = []
         self.scan: Dict[str, int] = {}
         self.replies: Optional[List[List[Tuple[str, float]]]] = None
+
+
+# questions one program of the analogy scan answers at most; a call of more
+# runs further programs, two in flight. With _ANALOGY_BLOCK_ROWS it sizes the
+# [questions, rows] float32 score block (0.54 GB) a program of num > 1 holds;
+# at num = 1 the TPU's compiler keeps the block inside the matmul's own fusion
+_ANALOGY_MAX_QUESTIONS = 2048
+# rows of the table one block of the analogy scan scores
+_ANALOGY_BLOCK_ROWS = 1 << 16
+# the least capacity, and so the tile of _grid_up's grid up to 4,096 questions:
+# the public file's 14 sections share six programs (512, 1,024, 1,280, 1,536,
+# 1,792, 2,048) at nine tenths of their capacity live
+_ANALOGY_CAP_FLOOR = 256
 
 
 # rows one pass of a transform slide's program gathers at most: where the
@@ -1595,6 +1800,121 @@ def _cosine_topk_batch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
             return jax.lax.top_k(cos, k)
     return _two_stage_topk(
         _grouped_scores(syn0, norms, queries, valid_rows, group), k, group)
+
+
+def _block_topk(cos: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
+    """``lax.top_k(cos, k)`` of one block of the analogy scan, columns as
+    block-local ids. ``k`` = 1 (the accuracy test) is one variadic reduce,
+    the maximum and the lowest column that holds it in ONE pass over the
+    block, which at thousands of rows is all the block's reading there is;
+    a block narrower than ``k`` is widened with -inf."""
+    if cos.shape[1] < k:
+        cos = jnp.pad(cos, ((0, 0), (0, k - cos.shape[1])),
+                      constant_values=-jnp.inf)
+    if k > 1:
+        with jax.named_scope("scan.topk"):
+            return jax.lax.top_k(cos, k)
+
+    def better(a, b):
+        (sa, ia), (sb, ib) = a, b
+        keep = (sa > sb) | ((sa == sb) & (ia < ib))
+        return jnp.where(keep, sa, sb), jnp.where(keep, ia, ib)
+
+    with jax.named_scope("scan.group_max"):
+        col = jax.lax.broadcasted_iota(jnp.int32, cos.shape, 1)
+        best, at = jax.lax.reduce(
+            (cos, col), (jnp.array(-jnp.inf, cos.dtype), jnp.int32(2 ** 31 - 1)),
+            better, (1,))
+        return best[:, None], at[:, None]
+
+
+@partial(jax.jit, static_argnames=("k", "candidates", "block_rows"))
+def _analogy_topk(syn0: jax.Array, scanned: jax.Array, inv_norms: jax.Array,
+                  words: jax.Array, num_words: jax.Array, pos: jax.Array,
+                  k: int, candidates: int, block_rows: int
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """The analogy scan, ONE program a (question capacity, ``k``,
+    ``candidates``): for each of ``pos.shape[0]`` questions the ``k`` best
+    cosines of q = û_b − û_a + û_c over rows [0, ``candidates``) of ``syn0``,
+    a, b and c excluded, and their row ids: ``lax.top_k``'s over the masked
+    scores, ties toward the lower row. The question rows are read from
+    ``syn0``; the matmul reads ``scanned``
+    (:meth:`Word2VecModel._scan_table`: ``syn0`` itself, or on a TPU its
+    bfloat16 rounding, which is what the MXU multiplies either way).
+
+    ``words`` (``int32[3·capacity]``) holds the part's distinct row ids, the
+    first ``num_words`` live; ``pos`` (``int32[capacity, 3]``) names each
+    question's a, b and c by their place in it (padding questions: 0, 0, 0).
+    The rows are read in place, each DISTINCT word once under a ``while``:
+    the whole lane tile of 128 rows that holds it, and the row picked out of
+    that (a gather from a table whose D is no multiple of 128 first copies
+    all of it, :func:`_row_slices`' reason, and so does a one-row slice
+    under a ``while``); then scaled by ``inv_norms``, the
+    cached 1 / norm (0 for a row of zero norm, whose score is then 0). The
+    table is scored ``block_rows`` rows at a time, ``candidates //
+    block_rows`` whole blocks under one ``fori_loop`` and the rest as a block
+    of its own; rows past ``candidates`` are never read. Per block: the
+    ``[capacity, block_rows]`` cosines at :func:`_cosine_batch`'s precision,
+    a, b and c set to -inf by row id, the block's own top-k
+    (:func:`_block_topk`), and a merge with the running answers (the
+    earlier block's first, so that equal scores keep the lower row). No
+    score block wider than ``block_rows`` is ever resident."""
+    dim = syn0.shape[1]
+    with jax.named_scope("scan.analogy_rows"):
+        def read(i, rows):
+            # the whole lane tile of 128 rows that holds the word's, as the
+            # table lies, and the one row picked out of it: exact
+            first = jnp.minimum(words[i] // 128 * 128, max(syn0.shape[0] - 128, 0))
+            tile = jax.lax.dynamic_slice_in_dim(
+                syn0, first, min(128, syn0.shape[0]), allow_negative_indices=False)
+            mine = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0) == words[i] - first
+            row = jnp.sum(jnp.where(mine, tile, 0), axis=0, keepdims=True)
+            return jax.lax.dynamic_update_slice_in_dim(rows, row, i, 0)
+
+        rows = jax.lax.fori_loop(
+            0, num_words, read, jnp.zeros((words.shape[0], dim), syn0.dtype))
+        unit = rows * inv_norms[words][:, None].astype(rows.dtype)
+        q = unit[pos[:, 1]] - unit[pos[:, 0]] + unit[pos[:, 2]]
+        q = q / jnp.maximum(jnp.linalg.norm(q, axis=1, keepdims=True), 1e-12)
+        ids = words[pos]
+
+    def merged(best, lo: jax.Array, table: jax.Array, inv: jax.Array,
+               cut: bool):
+        with jax.named_scope("scan.cosine"):
+            cos = jax.lax.dot_general(
+                q.astype(table.dtype), table, (((1,), (1,)), ((), ())),
+                preferred_element_type=q.dtype) * inv[None, :].astype(q.dtype)
+            col = lo + jax.lax.broadcasted_iota(jnp.int32, cos.shape, 1)
+            out = ((col == ids[:, 0:1]) | (col == ids[:, 1:2])
+                   | (col == ids[:, 2:3]))
+            if cut:
+                out = out | (col >= candidates)
+            cos = jnp.where(out, -jnp.inf, cos)
+        scores, at = _block_topk(cos, k)
+        with jax.named_scope("scan.block_merge"):
+            scores = jnp.concatenate([best[0], scores], axis=1)
+            at = jnp.concatenate([best[1], lo + at.astype(jnp.int32)], axis=1)
+            scores, won = jax.lax.top_k(scores, k)
+            return scores, jnp.take_along_axis(at, won, axis=1)
+
+    whole = candidates // block_rows
+    best = (jnp.full((pos.shape[0], k), -jnp.inf, q.dtype),
+            jnp.zeros((pos.shape[0], k), jnp.int32))
+    if whole:
+        best = jax.lax.fori_loop(0, whole, lambda i, best: merged(
+            best, i * block_rows,
+            jax.lax.dynamic_slice_in_dim(scanned, i * block_rows, block_rows),
+            jax.lax.dynamic_slice_in_dim(inv_norms, i * block_rows, block_rows),
+            False), best)
+    if candidates > whole * block_rows:
+        lo = whole * block_rows
+        # the last block's rows up to a whole lane tile: a slice of the
+        # table's rows folds into the matmul's read; what lies past
+        # ``candidates`` in it is masked
+        hi = min(-(-candidates // 128) * 128, syn0.shape[0])
+        best = merged(best, jnp.int32(lo), scanned[lo:hi], inv_norms[lo:hi],
+                      hi > candidates)
+    return best[0].astype(jnp.float32), best[1]
 
 
 def _row_shards(table: jax.Array) -> Optional[NamedSharding]:

@@ -607,3 +607,58 @@ def test_the_same_gather_from_the_300_wide_table_copies_all_of_it(one_chip):
         segments=10_000, dim=300).compile()
     assert re.findall(r"= f32\[%d,300\]\S* copy\(" % V, compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes > 3 << 30
+
+
+@pytest.mark.parametrize("cap,k", [(512, 1), (2048, 1), (2048, 10)])
+def test_the_analogy_scan_copies_no_table_and_holds_no_block_wider_than_a_tile(one_chip, cap, k):
+    """``Word2VecModel.analogies``' one program a (capacity, k) (PR 55) at
+    ``sgns-analogy-3m-300``'s size: the question rows read a lane tile at a
+    time in place from the float32 table, the bfloat16 form of it that the
+    model keeps (``_scan_table``) scored 65,536 rows a block. No copy or
+    conversion of a [3,000,000, 300] table (a one-row slice under a ``while``
+    made a row-major copy; a float32 table handed to the matmul is converted
+    whole, once a program, 1.8 GB of temporaries), no score block wider than a
+    tile, and at k = 1 not even that: the matmul, the masks and the variadic
+    reduce are ONE output fusion, so the block never leaves the chip's fast
+    memory. For k > 1 one [capacity, 65,536] float32 block is held."""
+    from glint_word2vec_tpu.models import word2vec as w2v
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, dim, block = 3_000_000, 300, 1 << 16
+    compiled = w2v._analogy_topk.lower(
+        spec((rows, dim), jnp.float32), spec((rows, dim), jnp.bfloat16),
+        spec((rows,), jnp.float32), spec((3 * cap,), jnp.int32), spec((), jnp.int32),
+        spec((cap, 3), jnp.int32), k=k, candidates=rows, block_rows=block).compile()
+    text = compiled.as_text()
+    assert not re.findall(
+        r"= \w+\[%d,%d\]\S* (?:copy|transpose|gather|convert)\(" % (rows, dim), text)
+    widths = [int(w) for w in re.findall(r"= \w+\[%d,(\d+)\]" % cap, text)]
+    assert max(widths) <= block, max(widths)
+    if k == 1:
+        fused = re.findall(r"-> \(f32\[%d\], s32\[%d\]\) \{" % (cap, cap), text)
+        assert fused, "the block's maximum is no longer the matmul's own output fusion"
+    memory = compiled.memory_analysis()
+    held = 4 * cap * block if k > 1 else 0
+    assert memory.temp_size_in_bytes < held * 1.05 + (64 << 20), memory.temp_size_in_bytes
+
+
+def test_a_float32_table_at_the_default_precision_is_multiplied_as_bfloat16(one_chip):
+    """Why ``_scan_table`` keeps a bfloat16 form: handed the float32 table, the
+    compiler converts all of it to bfloat16 itself, outside the blocks' loop,
+    once a program, and the matmul's operands are bfloat16 either way."""
+    from glint_word2vec_tpu.models import word2vec as w2v
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, dim, cap = 3_000_000, 300, 512
+    compiled = w2v._analogy_topk.lower(
+        spec((rows, dim), jnp.float32), spec((rows, dim), jnp.float32),
+        spec((rows,), jnp.float32), spec((3 * cap,), jnp.int32), spec((), jnp.int32),
+        spec((cap, 3), jnp.int32), k=1, candidates=rows, block_rows=1 << 16).compile()
+    text = compiled.as_text()
+    assert re.search(r"= bf16\[%d,%d\]\S* convert\(" % (rows, dim), text)
+    assert not re.search(r"convolution\(\S*f32\[", text)
+    assert compiled.memory_analysis().temp_size_in_bytes > 2 * rows * dim
