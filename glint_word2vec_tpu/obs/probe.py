@@ -1,12 +1,20 @@
-"""On-device health probe: ONE fused stats reduction over the params carry.
+"""On-device health probe: ONE pass over each table of the params carry.
 
 Extends the trainer's round-6 finiteness probe (a tiny ``isfinite().all()``
 jit) into the instrumentation ROADMAP item 2 presupposes: the 1.6M-vocab
 quality collapse is a FINITE norm blowup (purity 0.99 → 0.14, no NaN —
 EVAL.md round-5 ladder), so the finiteness bit alone observes nothing until
-long after the geometry is wrecked. The probe reads each matrix once and
-returns, per matrix, over the REAL vocab rows (padding rows are zero by
-construction and would poison every channel):
+long after the geometry is wrecked. The probe reads each matrix once: ONE
+``lax.reduce`` over two operands a table (``_row_sums``) gives, per PADDED
+row, the float32 sum of squares and the "any element not finite" bit, one
+fusion with two results on the TPU; everything after it reads those vectors
+(12 MB at 3M rows against the table's 4.6 GB). Written as two reductions,
+each table is read twice: the compiler keeps the finite bit's reduce and
+the sum's in fusions of their own, also where the bit is taken row-wise
+beside the sum (24.4 of 26.3 ms a probe at 3M rows went to four such
+passes: PERF.md §6, PRs 32 and 56). It returns, per matrix, over the first
+``vocab_size`` entries of the sums (padding rows are zero by construction and
+would poison every channel):
 
 - ``max_norm`` / ``mean_norm`` — extremes and scale of the L2 row norms;
 - ``p99_norm`` — bucketed (quarter-octave log2 buckets): an exact
@@ -17,10 +25,12 @@ construction and would poison every channel):
 - ``frac_over`` — fraction of rows with norm above the watchdog threshold
   (the channel the round-5 collapse is visible in long before the max).
 
-Plus a whole-carry ``finite`` bit (over the PADDED matrices — identical
-semantics to the old probe). The update-magnitude proxy (delta of
-``mean_norm`` between consecutive probes) is computed host-side by the
-trainer — it needs cross-probe state the pure device function cannot hold.
+Plus a whole-carry ``finite`` bit (over the PADDED matrices and over their
+ELEMENTS — identical semantics to the old probe; never ``isfinite(norm)``: a
+finite row of elements over 1.8e19 squares to inf and is a finite row). The
+update-magnitude proxy (delta of ``mean_norm`` between consecutive probes) is
+computed host-side by the trainer — it needs cross-probe state the pure
+device function cannot hold.
 
 Collective discipline: on a sharded mesh the reductions lower to collectives,
 so the caller must drain the params carry before dispatching the probe and
@@ -67,10 +77,25 @@ class HealthStats(NamedTuple):
     syn1: MatrixStats
 
 
-def _matrix_stats(m: jax.Array, vocab_size: int, threshold: float) -> MatrixStats:
-    rows = m[:vocab_size]
-    norms = jnp.sqrt(jnp.sum(
-        rows.astype(jnp.float32) * rows.astype(jnp.float32), axis=1))
+def _row_sums(m: jax.Array):
+    """One pass over a PADDED table: per row, the float32 sum of squares and
+    whether any element is not finite.
+
+    Keep it ONE ``lax.reduce`` over two operands (one fusion with two results
+    on the TPU). ``jnp.sum(x * x, axis=1)`` beside ``jnp.any(~isfinite(m),
+    axis=1)`` reads the table twice: the compiler folds the row-wise ``any``
+    and the ``any`` over rows into a whole-table ``reduce_or`` of its own
+    (tests/test_step_inplace_tpu.py compiles both forms)."""
+    x = m.astype(jnp.float32)
+    return jax.lax.reduce(
+        (x * x, ~jnp.isfinite(m)), (jnp.float32(0.0), jnp.bool_(False)),
+        lambda a, b: (a[0] + b[0], a[1] | b[1]), dimensions=(1,))
+
+
+def _matrix_stats(sums: jax.Array, vocab_size: int, threshold: float) -> MatrixStats:
+    # the vector is sliced, not the table: 12 MB at 3M rows, and the padding
+    # rows stay out of every channel
+    norms = jnp.sqrt(sums[:vocab_size])
     # bucketed p99: a row's bucket from log2(norm); the p99 bucket is the first
     # k with #{rows: bucket <= k} >= 99% of rows. No [V] sort/top-k, and no
     # histogram either: k is found by bisection over the 128 buckets, 7 counts
@@ -105,16 +130,19 @@ def make_health_probe(vocab_size: int, threshold: float) -> Callable:
     recompile churn) defines the ``frac_over`` channel."""
 
     def probe(params) -> HealthStats:
-        finite = (jnp.isfinite(params.syn0).all()
-                  & jnp.isfinite(params.syn1).all())
+        sums0, bad0 = _row_sums(params.syn0)
+        sums1, bad1 = _row_sums(params.syn1)
+        # a test of the ELEMENTS, never of the sums: a finite row whose squares
+        # overflow float32 has an infinite norm and is a finite row
+        finite = ~(bad0.any() | bad1.any())
         if params.pos is not None:
             # the position weights (config.cbow_position_weights) are part of
             # the carry: a diverged leaf is a non-finite carry
             finite = finite & jnp.isfinite(params.pos).all()
         return HealthStats(
             finite=finite,
-            syn0=_matrix_stats(params.syn0, vocab_size, threshold),
-            syn1=_matrix_stats(params.syn1, vocab_size, threshold),
+            syn0=_matrix_stats(sums0, vocab_size, threshold),
+            syn1=_matrix_stats(sums1, vocab_size, threshold),
         )
 
     return jax.jit(probe)

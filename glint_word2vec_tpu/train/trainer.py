@@ -2306,9 +2306,13 @@ class Trainer:
     def _health_stats(self) -> dict:
         """Run the fused on-device health probe (obs/probe.py) and return its
         channel dict: the old finiteness bit PLUS per-matrix row-norm
-        channels (max/mean/p99, frac over the watchdog threshold) from ONE
-        reduction pass, and the host-side update-magnitude proxy (delta of
-        mean_norm between consecutive probes).
+        channels (max/mean/p99, frac over the watchdog threshold), and the
+        host-side update-magnitude proxy (delta of mean_norm between
+        consecutive probes). Each table is read ONCE: one variadic reduce a
+        table gives the bit per padded row beside the row's sum of squares,
+        and the norm channels come from the sums' first vocab.size entries
+        (obs/probe.py ``_row_sums``; two reductions written apart compile to
+        two passes a table).
 
         Drains in-flight chunk dispatches BEFORE launching the probe: on a
         multi-device mesh the probe's cross-shard reductions are themselves a

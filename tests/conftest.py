@@ -33,3 +33,18 @@ def toy_corpus_path():
     if not os.path.exists(REFERENCE_CORPUS):
         pytest.skip("reference toy corpus not available")
     return REFERENCE_CORPUS
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _span_recorder_starts_each_file_as_a_fresh_process_has_it():
+    """Off and empty. The recorder is process-wide and a telemetry-on trainer
+    leaves it armed until the next trainer is built, so under xdist a file's
+    first tests could record into the ring of whichever file the scheduler
+    ran before it on the same worker: a test that reads the ring then counted
+    calls it had not made (PR 56: ``tests/benchmark/test_analogy_reference.py::
+    test_the_call_is_recorded_span_by_span``, one whole run in three)."""
+    from glint_word2vec_tpu.obs.spans import default_tracer
+
+    tracer = default_tracer()
+    tracer.configure(enabled=False)
+    tracer.clear()

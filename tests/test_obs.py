@@ -354,6 +354,104 @@ def test_probe_finite_bit_matches_old_semantics():
     assert stats_to_channels(jax.device_get(probe(params)))["finite"] is False
 
 
+# What the probe's one pass a table (PR 56: the row sums and the "any element
+# not finite" bit from ONE reduce over the PADDED table) must keep. Tables as
+# the trainer pads them: V real rows of Vpad, D real lanes of Dpad.
+_ONE_PASS = dict(V=500, Vpad=512, D=12, Dpad=16)
+
+
+def _one_pass_tables():
+    rng = np.random.default_rng(56)
+    p = _ONE_PASS
+    tables = []
+    for _ in range(2):
+        m = np.zeros((p["Vpad"], p["Dpad"]), np.float32)
+        m[:p["V"], :p["D"]] = rng.normal(size=(p["V"], p["D"]))
+        tables.append(m)
+    return tables
+
+
+@pytest.fixture(scope="module")
+def one_pass_channels():
+    """One compiled probe for every case below: tables -> channels."""
+    probe = make_health_probe(_ONE_PASS["V"], 2.0)
+    return lambda syn0, syn1: stats_to_channels(jax.device_get(probe(
+        EmbeddingPair(jax.numpy.asarray(syn0), jax.numpy.asarray(syn1)))))
+
+
+@pytest.fixture(scope="module")
+def one_pass_clean(one_pass_channels):
+    clean = one_pass_channels(*_one_pass_tables())
+    assert clean["finite"] is True
+    return clean
+
+
+@pytest.mark.parametrize("table", [0, 1], ids=["syn0", "syn1"])
+@pytest.mark.parametrize("lane", [0, _ONE_PASS["Dpad"] - 1], ids=["real_lane", "padding_lane"])
+@pytest.mark.parametrize("row", [_ONE_PASS["V"], _ONE_PASS["Vpad"] - 1],
+                         ids=["first_padding_row", "last_padding_row"])
+@pytest.mark.parametrize("value", [np.nan, np.inf, -np.inf], ids=["nan", "inf", "minus_inf"])
+def test_probe_a_padding_rows_element_clears_the_bit_and_moves_no_channel(
+        one_pass_channels, one_pass_clean, value, row, lane, table):
+    """The finite bit is over the PADDED carry; every norm channel is over the
+    first V entries of the row sums, so a padding row reaches none of them:
+    the channels are the clean tables' to the last bit."""
+    tables, clean = _one_pass_tables(), one_pass_clean
+    tables[table][row, lane] = value
+    got = one_pass_channels(*tables)
+    assert got["finite"] is False
+    assert (got["syn0"], got["syn1"]) == (clean["syn0"], clean["syn1"])
+
+
+@pytest.mark.parametrize("value", [np.nan, np.inf], ids=["nan", "inf"])
+def test_probe_reads_a_real_rows_padding_lanes(one_pass_channels, one_pass_clean, value):
+    """All Dpad lanes of a row are the row the probe reads: an element that is
+    not finite in a padding lane of a REAL row clears the bit and is in that
+    row's norm; the other table's channels are the clean ones."""
+    tables, clean = _one_pass_tables(), one_pass_clean
+    tables[0][7, _ONE_PASS["Dpad"] - 1] = value
+    got = one_pass_channels(*tables)
+    assert got["finite"] is False
+    assert not np.isfinite(got["syn0"]["max_norm"])
+    assert got["syn1"] == clean["syn1"]
+
+
+@pytest.mark.parametrize("table", [0, 1], ids=["syn0", "syn1"])
+def test_probe_a_finite_row_whose_squares_overflow_is_finite(
+        one_pass_channels, one_pass_clean, table):
+    """The bit tests the ELEMENTS, never the norms: 2e19 squared is past
+    float32, so the row's norm is inf and the row is finite."""
+    tables, clean = _one_pass_tables(), one_pass_clean
+    tables[table][3, :_ONE_PASS["D"]] = 2e19
+    got = one_pass_channels(*tables)
+    name, other = ("syn0", "syn1") if table == 0 else ("syn1", "syn0")
+    assert got["finite"] is True
+    assert got[name]["max_norm"] == np.inf and got[name]["mean_norm"] == np.inf
+    assert got[name]["frac_over"] >= clean[name]["frac_over"]
+    assert got[other] == clean[other]
+
+
+def test_probe_accumulates_a_bfloat16_carry_in_float32():
+    """Rows of 0.1 over 4,096 lanes: a bfloat16 running sum of the squares
+    stalls near 2 (0.01 is under half a place of it); the float32 sum of the
+    bfloat16 elements' squares is 40.99."""
+    import jax.numpy as jnp
+
+    V, D = 64, 4096
+    m = np.full((V, D), 0.1, np.float32)
+    stats = jax.device_get(make_health_probe(V, 2.0)(
+        EmbeddingPair(jnp.asarray(m, jnp.bfloat16), jnp.asarray(m * 2, jnp.bfloat16))))
+    assert stats.syn0.max_norm.dtype == np.float32
+    ch = stats_to_channels(stats)
+    assert ch["finite"] is True
+    for name, scale in (("syn0", 1.0), ("syn1", 2.0)):
+        elem = float(jnp.asarray(0.1 * scale, jnp.bfloat16).astype(jnp.float32))
+        want = np.sqrt(D) * elem
+        assert ch[name]["max_norm"] == pytest.approx(want, rel=1e-5)
+        assert ch[name]["mean_norm"] == pytest.approx(want, rel=1e-5)
+        assert ch[name]["frac_over"] == 1.0
+
+
 # -- watchdog --------------------------------------------------------------------------
 
 

@@ -26,7 +26,10 @@ dispatches (obs/probe.py). Its p99 bucket used to come from a histogram built by
 a scatter-add of V indices into s32[128], 26.2 ms a table on the chip and 1.64
 ms of every training step (PERF.md §6, PR 32: the ``fusion_s32_128`` pair of the
 ledger's breakdowns). The compiled probe holds no scatter, and on the 1x4 mesh
-of ``sgns-10m-300-x4`` its reductions stay all-reduces of scalars.
+of ``sgns-10m-300-x4`` its reductions stay all-reduces of scalars. Since PR 56
+it reads each table ONCE: one fusion fed by each table parameter, with the row
+sums and the rows' finite bits as its two results (one variadic reduce; two
+reductions written apart compile to two passes a table, and a row holds that).
 
 The fifth (PR 33) is the banded CBOW step with the token row source and the
 position weights at ``cbow-subword-2m-300``'s size: syn0 (f32[4000000,384]) is
@@ -382,29 +385,104 @@ def _computation(compiled: str, name: str) -> str:
     return compiled[start:compiled.index("\n}\n", start)]
 
 
-@pytest.mark.parametrize("mesh_shape", [None, (1, 4)], ids=["one_chip", "mesh_1x4"])
-def test_the_health_probe_holds_no_scatter(topo, one_chip, mesh_shape):
+def _sibling_probe(vocab_size: int, threshold: float):
+    """The probe with a table's two reductions written apart (ROADMAP A10 (a)'s
+    form: the row-wise ``any`` beside the row-wise sum), everything after them
+    the probe's own."""
+    from glint_word2vec_tpu.obs import probe as P
+
+    def row_sums(m):
+        x = m.astype(jnp.float32)
+        return jnp.sum(x * x, axis=1), jnp.any(~jnp.isfinite(m), axis=1)
+
+    def probe(params):
+        (sums0, bad0), (sums1, bad1) = row_sums(params.syn0), row_sums(params.syn1)
+        return P.HealthStats(
+            finite=~(bad0.any() | bad1.any()),
+            syn0=P._matrix_stats(sums0, vocab_size, threshold),
+            syn1=P._matrix_stats(sums1, vocab_size, threshold))
+
+    return jax.jit(probe)
+
+
+# (rows of syn0, rows of syn1, vocab_size, chips the rows are sharded over)
+PROBE_SHAPES = {
+    "one_chip": (V, V, V, 1),
+    "mesh_1x4": (10_000_000, 10_000_000, 10_000_000, 4),
+    # subword-sgns-2.5m-300's carry: syn0 holds the bucket rows too
+    "subword": (4_519_376, 2_519_376, 2_519_370, 1),
+}
+
+
+@pytest.fixture(scope="module")
+def probe_text(topo, one_chip):
+    """The probe compiled for the described v5e, once a (shape, form)."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     from glint_word2vec_tpu.obs.probe import make_health_probe
 
-    rows, sharding = V, one_chip
-    if mesh_shape:
-        rows = 10_000_000
-        mesh = Mesh(np.array(topo.devices).reshape(mesh_shape), ("data", "model"))
-        sharding = NamedSharding(mesh, PartitionSpec("model", None))
-    table = jax.ShapeDtypeStruct((rows, D), jnp.float32, sharding=sharding)
-    compiled = make_health_probe(rows, 100.0).lower(
-        EmbeddingPair(table, table)).compile().as_text()
+    texts = {}
+
+    def compiled(shape: str, form: str = "one_reduce") -> str:
+        if (shape, form) not in texts:
+            rows0, rows1, vocab, shards = PROBE_SHAPES[shape]
+            sharding = one_chip
+            if shards > 1:
+                mesh = Mesh(np.array(topo.devices).reshape(1, shards), ("data", "model"))
+                sharding = NamedSharding(mesh, PartitionSpec("model", None))
+            make = make_health_probe if form == "one_reduce" else _sibling_probe
+            texts[shape, form] = make(vocab, 100.0).lower(EmbeddingPair(
+                jax.ShapeDtypeStruct((rows0, D), jnp.float32, sharding=sharding),
+                jax.ShapeDtypeStruct((rows1, D), jnp.float32, sharding=sharding),
+            )).compile().as_text()
+        return texts[shape, form]
+
+    return compiled
+
+
+@pytest.mark.parametrize("shape", ["one_chip", "mesh_1x4"])
+def test_the_health_probe_holds_no_scatter(probe_text, shape):
+    compiled = probe_text(shape)
     assert " reduce(" in compiled
     for op in ("scatter", "sort", "all-gather", "all-to-all", "collective-permute"):
         assert f" {op}(" not in compiled, op
     # what the histogram's scatter produced: one s32[128] a table
     assert not re.search(r"= \(?s32\[128\]", compiled)
-    if mesh_shape:
+    if shape == "mesh_1x4":
         reduced = re.findall(r"= (\S+) all-reduce\(", compiled)
         assert reduced and all(re.fullmatch(r"\(?\w+\[\]\S*", t) for t in reduced), reduced
+
+
+@pytest.mark.parametrize("form,shape,passes", [
+    ("one_reduce", "one_chip", 1), ("one_reduce", "mesh_1x4", 1),
+    ("one_reduce", "subword", 1), ("siblings", "one_chip", 2)])
+def test_the_health_probe_reads_each_table_once(probe_text, form, shape, passes):
+    """PR 56. A table's row sums of squares and its "any element not finite"
+    bit come from ONE ``lax.reduce`` over two operands, which the compiler keeps
+    as one fusion with two results, ``(f32[rows], pred[rows])``: the entry
+    computation holds ONE fusion fed by each table (a shard's rows on the mesh),
+    and everything after it reads the vectors. Written apart, the compiler
+    folds the row-wise ``any`` and the ``any`` over rows into a whole-table
+    ``reduce_or`` of its own and each table is read twice, as it was before
+    (24.4 of the probe's 26.3 ms in four passes: PERF.md §6, PR 32): the last
+    row pins that, so that nobody simplifies the reduce back."""
+    compiled = probe_text(shape, form)
+    rows0, rows1, _, shards = PROBE_SHAPES[shape]
+    entry = compiled[compiled.index("\nENTRY "):]
+    tables = re.findall(rf"%(\S+) = f32\[(\d+),{D}\]\S* parameter\(", entry)
+    assert sorted(int(r) for _, r in tables) == sorted([rows0 // shards, rows1 // shards])
+    for name, rows in tables:
+        readers = [line for line in entry.splitlines()
+                   if re.search(rf"[(,] ?%{re.escape(name)}[,)]", line)]
+        assert len(readers) == passes and all(" fusion(" in r for r in readers), readers
+        results = sorted(t for r in readers
+                         for t in re.findall(rf"(\w+)\[{rows}\]", r.split(" fusion(")[0]))
+        assert results == (["f32", "pred"] if passes == 1 else ["f32"]), readers
+    # no whole table (or shard) is copied or sliced, inside a fusion or out of one
+    moved = [line.strip()[:120] for line in compiled.splitlines()
+             if re.search(rf"= f32\[\d+,{D}\]\S* (copy|slice|dynamic-slice)\(", line)]
+    assert not moved, moved
 
 
 @pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
