@@ -19,23 +19,24 @@ on device.
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import logging
-import math
-import os
 from typing import (
     Dict, Iterable, Iterator, List, NamedTuple, Optional, Sequence, Tuple, Union)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from glint_word2vec_tpu.config import Word2VecConfig
 from glint_word2vec_tpu.data.subword import NO_ROW as _NO_ROW
 from glint_word2vec_tpu.data.vocab import Vocabulary
 from glint_word2vec_tpu.lockcheck import make_lock
 from glint_word2vec_tpu.obs.spans import default_tracer, pinned_call
+from glint_word2vec_tpu.ops.scan import (
+    _LISTED, _VECTOR, _analogy_topk, _row_shards, _scan_counts, _topk_dispatch)
+from glint_word2vec_tpu.ops.transform import _segment_means, _sentence_means
 from glint_word2vec_tpu.parallel.mesh import (
     MeshPlan, pad_dim_to_lanes, pad_vocab_for_sharding)
 from glint_word2vec_tpu.train import checkpoint as ckpt
@@ -353,8 +354,8 @@ class Word2VecModel:
         and a sentence with no in-vocab word maps to the zero vector. Processed
         in slides of ``batch_size`` sentences like the reference's 10k-row
         mapPartitions slides (ml:449-450), each ONE device program of fixed
-        shapes (:func:`_segment_means`; the server-side ``pullAverage``,
-        ml:453):
+        shapes (:func:`..ops.transform._segment_means`; the server-side
+        ``pullAverage``, ml:453):
 
         - *encode* (host, :meth:`_encode_slide`): the slide's tokens to row ids
           in one ``Vocabulary.lookup_sentences`` of the slide as it lies, OOV
@@ -405,8 +406,8 @@ class Word2VecModel:
 
         Slides of ``batch_size`` sentences on :meth:`transform_sentences`'
         machinery (its halves, capacity rule, in-flight bound and spans),
-        each ONE device program (:func:`_sentence_means`), a two-level
-        ragged reduction:
+        each ONE device program (:func:`..ops.transform._sentence_means`), a
+        two-level ragged reduction:
 
         - *encode* (host, :meth:`_encode_tokens`): one walk of the slide as
           it lies, the lookup with the interpreter lock released, and in the
@@ -510,8 +511,8 @@ class Word2VecModel:
         return pending
 
     def _encode_tokens(self, slide: Sequence[Sequence[str]]) -> "_SlideTokens":
-        """One slide's tokens as :func:`_sentence_means` wants them
-        (:class:`_SlideTokens`). A model without subwords takes
+        """One slide's tokens as :func:`..ops.transform._sentence_means` wants
+        them (:class:`_SlideTokens`). A model without subwords takes
         :meth:`_encode_slide`'s lookup and leaves the missing tokens out (they
         have no rows: h = 0). A subword model takes
         ``Vocabulary.lookup_sentences_misses``, which hands the missing
@@ -676,15 +677,31 @@ class Word2VecModel:
         if len(self._full0.sharding.device_set) != 1:
             return self.syn0
         from glint_word2vec_tpu.ops.subword import lane_padded
+        return self._once("_lanes", lane_padded, "model.row_table")
+
+    def _once(self, attr: str, make, span: Optional[str] = None) -> jax.Array:
+        """The resident form ``attr`` of the model's table: what is there, or
+        ``make(table)`` of the table the model holds (syn0; the whole-lane
+        form where ``resident="rows"`` kept nothing else, whose rows have the
+        same norms), kept until :meth:`stop`. Made once a model whatever the
+        callers' threads: under the model's lock (not re-entrant: ``make``
+        asks for no other form), looked for again there, waited for before
+        it is stored, inside the pinned span ``span`` (obs/spans.py; its
+        ``rows`` the table's) where one is named."""
+        made = getattr(self, attr)
+        if made is not None:
+            return made
         with self._lock:
-            if self._lanes is None:
-                # once a model: a pinned span (obs/spans.py)
-                with default_tracer().span(
-                        "model.row_table", pinned=True,
-                        rows=int(self._full0.shape[0])):
-                    self._lanes = lane_padded(self._full0)
-                    self._lanes.block_until_ready()
-        return self._lanes
+            made = getattr(self, attr)
+            if made is None:
+                table = self._lanes if self._full0 is None else self._full0
+                with (default_tracer().span(span, pinned=True,
+                                            rows=int(table.shape[0]))
+                      if span else contextlib.nullcontext()):
+                    made = make(table)
+                    made.block_until_ready()
+                setattr(self, attr, made)
+        return made
 
     def _read_rows(self, ids: Sequence[int]) -> np.ndarray:
         """Rows ``ids`` of syn0, fetched: one gather from :meth:`_row_table`."""
@@ -695,32 +712,25 @@ class Word2VecModel:
     def norms(self) -> jax.Array:
         """Per-row Euclidean norms, computed once and cached (mllib:486,600-609)."""
         self._check_alive()
-        if self._norms is None:
-            # a resident="rows" model's one table is the whole-lane form,
-            # whose rows have the same norms
-            table = self._lanes if self._full0 is None else self._full0
-            # once a model: a pinned span (obs/spans.py)
-            with default_tracer().span("model.norms", pinned=True,
-                                       rows=int(table.shape[0])):
-                self._norms = jnp.linalg.norm(table, axis=1)
-                self._norms.block_until_ready()
-        return self._norms[: self.vocab.size]
+        return self._once("_norms", lambda table: jnp.linalg.norm(table, axis=1),
+                          "model.norms")[: self.vocab.size]
 
     def _inverse_norms(self) -> jax.Array:
         """``sentence_vectors``' scale of every row of :meth:`_row_table`:
-        1 / :attr:`norms`, 0 for a row of zero norm; made once, under the
-        model's lock, with the ids of those rows kept on the host
+        1 / :attr:`norms`, 0 for a row of zero norm; made once
+        (:meth:`_once`), with the ids of those rows kept on the host
         (``_zero_rows``: the encode leaves their tokens out of the count)."""
         if self._inv_norms is None:
-            self.norms  # materialize the cached full-row norms
-            with self._lock:
-                if self._inv_norms is None:
-                    norms = self._norms
-                    inv = jnp.where(norms > 0, 1.0 / jnp.where(norms > 0, norms, 1.0), 0.0)
-                    self._zero_rows = np.flatnonzero(
-                        np.asarray(inv[: self.vocab.size]) == 0).astype(np.int32)
-                    self._inv_norms = inv
-        return self._inv_norms
+            self.norms  # the norms first: _once's lock is not re-entrant
+
+        def make(_table) -> jax.Array:
+            norms = self._norms
+            inv = jnp.where(norms > 0, 1.0 / jnp.where(norms > 0, norms, 1.0), 0.0)
+            self._zero_rows = np.flatnonzero(
+                np.asarray(inv[: self.vocab.size]) == 0).astype(np.int32)
+            return inv
+
+        return self._once("_inv_norms", make)
 
     def _scan_table(self) -> jax.Array:
         """The table the analogy scan's matmul reads. On a TPU a float32
@@ -736,15 +746,8 @@ class Word2VecModel:
         dtype, syn0 as it lies (a CPU's float32 matmul rounds nothing)."""
         if jax.default_backend() != "tpu" or self._full0.dtype != jnp.float32:
             return self._full0
-        with self._lock:
-            if self._scan0 is None:
-                # once a model: a pinned span (obs/spans.py)
-                with default_tracer().span(
-                        "model.scan_table", pinned=True,
-                        rows=int(self._full0.shape[0])):
-                    self._scan0 = self._full0.astype(jnp.bfloat16)
-                    self._scan0.block_until_ready()
-        return self._scan0
+        return self._once("_scan0", lambda table: table.astype(jnp.bfloat16),
+                          "model.scan_table")
 
     def multiply(self, vector: np.ndarray) -> np.ndarray:
         """Full matrix–vector product syn0 @ v (the PS ``multiply`` powering cosine
@@ -813,18 +816,20 @@ class Word2VecModel:
         to row ids (dictionary lookups) and hands over one ``int32[Q]``
         array; the program gathers the rows from the table it already
         holds, normalises them, and runs the [chunk, V] cosine matmul and
-        the top-k (:func:`_gather_topk_batch`). The scores are ranked in
-        two exact stages: the maxima of runs of ~sqrt(V / k) columns, then
-        the k winning runs' members (:func:`_two_stage_topk`; what comes
-        back is ``lax.top_k``'s over the same scores, ties included); one
+        the top-k (:func:`..ops.scan._gather_topk_batch`). The scores are
+        ranked in two exact stages: the maxima of runs of ~sqrt(V / k)
+        columns, then the k winning runs' members
+        (:func:`..ops.scan._two_stage_topk`; what comes back is
+        ``lax.top_k``'s over the same scores, ties included); one
         ``lax.top_k`` over all V where the vocabulary is small. Over a table
         partitioned by rows on a mesh the one program runs under
-        ``shard_map`` (:func:`_sharded_scan`): every shard scans and ranks
-        its own rows so, and the shards' k candidates each are merged; the
-        replies are the one-device program's. On a TPU the chunk's queries are handed
-        over in whole tiles of 8 rows (:func:`_topk_dispatch`), so one
-        program serves 8 batch sizes. No per-query device
-        operation: a launch costs more than this scan's share of a query.
+        ``shard_map`` (:func:`..ops.scan._sharded_scan`): every shard scans
+        and ranks its own rows so, and the shards' k candidates each are
+        merged; the replies are the one-device program's. On a TPU the
+        chunk's queries are handed over in whole tiles of 8 rows
+        (:func:`..ops.scan._topk_dispatch`), so one program serves 8 batch
+        sizes. No per-query device operation: a launch costs more than this
+        scan's share of a query.
         A chunk that holds a vector query (``np.ndarray``; analogies)
         additionally sends one float32 ``[Q, D]`` block, and the program
         takes row ``i`` from the gather where ``ids[i] >= 0`` and from the
@@ -877,9 +882,9 @@ class Word2VecModel:
         or another: a caller with more batches than one (the serve batcher)
         begins the next while this one's scan runs. A call of more than one
         ``chunk`` has at most two parts enqueued at a time; ``finish``
-        enqueues the rest as it fetches. The ANN arm and the host top-k
-        route (:func:`_host_topk`) leave nothing pending on the device: all
-        of their work is done here and ``finish`` hands it back."""
+        enqueues the rest as it fetches. The ANN arm leaves nothing pending
+        on the device: all of its work is done here and ``finish`` hands it
+        back."""
         self._check_alive("find_synonyms")
         tracer = default_tracer()
         # the caller's span: parent of the spans ``finish`` records, which may
@@ -971,8 +976,7 @@ class Word2VecModel:
                 *(() if part_lists is None
                   else (self._buckets, part_lists)))
             for a in result:
-                if isinstance(a, jax.Array):  # the host route's are fetched
-                    a.copy_to_host_async()
+                a.copy_to_host_async()
         pending.results.append(result)
 
     def find_synonyms_finish(
@@ -1121,10 +1125,10 @@ class Word2VecModel:
         Per call: one :meth:`Vocabulary.lookup` of all the words (span
         ``eval.encode``); then programs of at most
         :data:`_ANALOGY_MAX_QUESTIONS` questions each, at a capacity on
-        :func:`_grid_up`'s grid (:func:`_analogy_topk`: the question rows
-        read in place, each DISTINCT word once, the table scored in blocks
-        of rows so that no ``[Q, V]`` block is ever resident, a, b and c
-        masked by row id before the selection), at most
+        :func:`_grid_up`'s grid (:func:`..ops.scan._analogy_topk`: the
+        question rows read in place, each DISTINCT word once, the table scored
+        in blocks of rows so that no ``[Q, V]`` block is ever resident, a, b
+        and c masked by row id before the selection), at most
         :data:`_PARTS_IN_FLIGHT` enqueued at a time (``eval.enqueue``) and
         the next sent as one is fetched (``eval.fetch``): the machinery of
         :meth:`find_synonyms_begin` / :meth:`find_synonyms_finish`. A table
@@ -1439,13 +1443,6 @@ class Word2VecModel:
         self._stopped = True
 
 
-from functools import partial
-
-
-# what ``ids[i]`` says of a query that is no word of the vocabulary: row i of
-# the vector block, or the mean of row i of the list block's bucket rows
-_VECTOR, _LISTED = -1, -2
-
 # parts of one call whose scans are enqueued and not yet fetched: one running
 # and one queued behind it, the bound the serve batcher keeps for its batches
 # (a [128, V] score block is 1.5 GB at 3M rows)
@@ -1456,8 +1453,8 @@ class _PendingSynonyms:
     """What ``Word2VecModel.find_synonyms_begin`` hands to
     ``find_synonyms_finish``: the query words, the parts (one per chunk: its
     offset and host arrays), the results of the parts enqueued so far (device
-    arrays on their way back, or the host route's arrays), and the span that
-    enclosed the begin. ``replies`` is set where begin did all the work."""
+    arrays on their way back), and the span that enclosed the begin.
+    ``replies`` is set where begin did all the work (the ANN arm)."""
 
     __slots__ = ("num", "k", "parent", "words", "parts", "results",
                  "scan", "replies")
@@ -1533,603 +1530,3 @@ def _grid_up(n: int, floor: int) -> int:
     of what is handed over is padding (327,680 rows for 313,000 live ids)."""
     tile = max(floor, (1 << (max(n, 1).bit_length() - 1)) // 16)
     return -(-n // tile) * tile
-
-
-@partial(jax.jit, static_argnames=("segments", "dim"))
-def _segment_means(table: jax.Array, ids: jax.Array, seg: jax.Array,
-                   counts: Optional[jax.Array], carried: Optional[jax.Array],
-                   segments: int, dim: int) -> jax.Array:
-    """One pass of a transform slide, ONE program: rows ``ids`` of ``table``
-    (an id past its rows reads zeros) summed into the sentences ``seg`` names
-    (ascending, as the slide's ids lie; one past ``segments`` is dropped),
-    on top of the sums ``carried`` from the pass before. The last pass is
-    handed the sentences' ``counts`` and returns their means ``[segments,
-    dim]`` float32 (zeros where the count is 0); a pass before it returns the
-    sums at the table's width. The sums are taken in float32 (a wider
-    table's in its own precision). On the TPU the gather is fused into the
-    sorted scatter-add: the ``[rows, lanes]`` block is never written."""
-    with jax.named_scope("transform.gather"):
-        rows = table.at[ids].get(mode="fill", fill_value=0)
-        rows = rows.astype(jnp.promote_types(rows.dtype, jnp.float32))
-    with jax.named_scope("transform.segment_mean"):
-        sums = jax.ops.segment_sum(rows, seg, num_segments=segments,
-                                   indices_are_sorted=True)
-        if carried is not None:
-            sums = sums + carried
-        if counts is None:
-            return sums
-        return (sums[:, :dim] / jnp.maximum(counts, 1)[:, None].astype(
-            sums.dtype)).astype(jnp.float32)
-
-
-@partial(jax.jit, static_argnames=("segments", "dim"))
-def _sentence_means(table: jax.Array, scale: jax.Array, ids: jax.Array,
-                    seg: jax.Array, lists: Optional[tuple],
-                    counts: Optional[jax.Array], carried: Optional[tuple],
-                    segments: int, dim: int):
-    """One pass of a ``sentence_vectors`` slide, ONE program, a two-level
-    ragged reduction. Words, as :func:`_segment_means`: rows ``ids`` of
-    ``table``, each times ``scale[id]`` (1 / its norm: a unit vector),
-    summed into the sentences ``seg`` names. Composed tokens, where ``lists``
-    is handed over (``buckets``, ``rows``, ``token``, ``token_seg``): rows
-    ``rows`` of ``buckets`` (one past them reads zeros) summed into the
-    tokens ``token`` names (ascending: a token's rows lie together; one past
-    the token capacity is dropped), every token's sum divided by its own
-    norm (h / |h| whatever |G| divided the mean by; a sum of zero norm is
-    left out), the unit vectors summed into the sentences ``token_seg``
-    names and the tokens kept counted there. ``carried``: the sums and that
-    count from the pass before. The last pass is handed the sentences'
-    ``counts`` of words and returns the means ``[segments, dim]`` float32
-    over words and kept tokens together (zeros where there are none); a pass
-    before it returns (sums, kept). Sums, norms and the division in float32
-    (a wider table's in its own precision)."""
-    with jax.named_scope("transform.gather"):
-        rows = table.at[ids].get(mode="fill", fill_value=0)
-        acc = jnp.promote_types(rows.dtype, jnp.float32)
-        unit = rows.astype(acc) * scale.at[ids].get(
-            mode="fill", fill_value=0).astype(acc)[:, None]
-    with jax.named_scope("transform.segment_mean"):
-        sums = jax.ops.segment_sum(unit, seg, num_segments=segments,
-                                   indices_are_sorted=True)
-    kept = None
-    if lists is not None:
-        buckets, list_rows, token, token_seg = lists
-        with jax.named_scope("transform.list_gather"):
-            listed = buckets.at[list_rows].get(mode="fill", fill_value=0).astype(acc)
-        with jax.named_scope("transform.compose"):
-            h = jax.ops.segment_sum(
-                listed, token, num_segments=token_seg.shape[0],
-                indices_are_sorted=True)[:, :sums.shape[1]]
-            norm = jnp.sqrt((h * h).sum(axis=1))
-            live = norm > 0
-            h = jnp.where(live[:, None], h / jnp.where(live, norm, 1)[:, None], 0)
-        with jax.named_scope("transform.segment_mean"):
-            sums = sums + jax.ops.segment_sum(
-                h.astype(acc), token_seg, num_segments=segments,
-                indices_are_sorted=True)
-            kept = jax.ops.segment_sum(
-                live.astype(jnp.int32), token_seg, num_segments=segments,
-                indices_are_sorted=True)
-    if carried is not None:
-        sums = sums + carried[0]
-        if kept is not None:
-            kept = kept + carried[1]
-    if counts is None:
-        return sums, kept
-    if kept is not None:
-        counts = counts + kept
-    return (sums[:, :dim] / jnp.maximum(counts, 1)[:, None].astype(
-        sums.dtype)).astype(jnp.float32)
-
-
-def _row_slices(table: jax.Array, at: jax.Array) -> jax.Array:
-    """Rows ``at`` (in range) of ``table`` as one-row slices, stacked: each
-    reads its row in place, where a gather op first copies a table whose D
-    is no multiple of 128 row-major (:func:`_query_block`)."""
-    return jnp.concatenate([
-        jax.lax.dynamic_slice_in_dim(
-            table, at[i], 1, allow_negative_indices=False)
-        for i in range(at.shape[0])])
-
-
-def _query_block(syn0: jax.Array, ids: jax.Array,
-                 block: Optional[jax.Array],
-                 buckets: Optional[jax.Array] = None,
-                 lists: Optional[jax.Array] = None) -> jax.Array:
-    """The [Q, D] query rows, built inside the scan's own program: row
-    ``ids[i]`` of the table; or row ``i`` of ``block`` where ``ids[i]`` is
-    :data:`_VECTOR` (a vector query); or, where it is :data:`_LISTED` (a
-    string a subword model's vocabulary lacks), the mean of the rows of
-    ``buckets`` that row ``i`` of ``lists`` names (ops/subword.list_vectors).
-    ``block`` is None for a batch without vectors and ``lists`` for one
-    without such strings — other traces, without those operands: an all-word
-    batch's program is the one it was before there were lists. The dtype is
-    what stacking the rows gave: the table's for words alone, promoted with
-    the float32 of either block otherwise.
-
-    The rows are read as Q slices, not as one gather op: the TPU keeps a
-    [V, D] table whose D is no multiple of 128 column-major, and its gather
-    first copies the whole table row-major (4.6 GB and a second pass over it
-    at 3M × 300, by the v5e compiler), where a slice reads a row in place.
-    (A table partitioned by rows over a mesh is read the same way, each
-    shard its own rows: :func:`_owner_rows`.) The listed bucket rows ARE
-    gathered, from rows the model keeps at whole lanes of 128 for it
-    (ops/subword.lane_padded), which the gather reads in place."""
-    with jax.named_scope("scan.gather"):
-        rows = _row_slices(syn0, jnp.maximum(ids, 0))
-        if block is not None:
-            rows = jnp.where((ids >= 0)[:, None], rows, block)
-    if lists is None:
-        return rows
-    with jax.named_scope("scan.compose"):
-        from glint_word2vec_tpu.ops.subword import list_vectors
-        return jnp.where((ids == _LISTED)[:, None],
-                         list_vectors(buckets, lists, syn0.shape[1]), rows)
-
-
-@partial(jax.jit, static_argnames=("valid_rows",))
-def _cosine_batch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
-                  valid_rows: int) -> jax.Array:
-    """The [Q, V] masked cosine matrix of :func:`_cosine_topk_batch` without
-    the top-k — the shared front half of the device and CPU top-k routes."""
-    with jax.named_scope("scan.cosine"):
-        qn = jnp.linalg.norm(queries, axis=1, keepdims=True)
-        q = queries / jnp.maximum(qn, 1e-12)
-        dots = q @ syn0.T                                      # [Q, V]
-        cos = jnp.where(norms[None, :] > 0,
-                        dots / jnp.maximum(norms[None, :], 1e-12), 0.0)
-        return jnp.where(jnp.arange(cos.shape[1])[None, :] < valid_rows,
-                         cos, -jnp.inf)
-
-
-# The two-stage selection's group sizes: whole lane tiles (128 columns) of
-# the [Q, V] score block; PR 38's probe timed 256-4,096 at 3M rows (PERF.md §6)
-_TOPK_GROUPS = (128, 256, 512, 1024, 2048, 4096)
-
-
-def _topk_group(num_rows: int, k: int) -> int:
-    """Columns a group of :func:`_two_stage_topk` holds over a ``[Q, num_rows]``
-    score block (a whole table's, or one shard's of a table partitioned by
-    rows), or 0 where the single ``lax.top_k`` ranks the whole block. The
-    size is the grid's nearest to sqrt(num_rows / k), where the group
-    maxima and the k winning groups' members are together fewest. Handed
-    back: rows that k groups would hold whole anyway."""
-    ideal = (num_rows / max(k, 1)) ** 0.5
-    group = min(_TOPK_GROUPS, key=lambda g: abs(math.log(g / max(ideal, 1.0))))
-    return group if k * group < num_rows else 0
-
-
-def _topk_rows(num_rows: int, k: int) -> int:
-    """Scores one query's selection ranks over ``num_rows`` rows (a table's,
-    or one shard's) in the scan's program: the group maxima and the k
-    winning groups' members where the two stages run, every row where the
-    single top-k does (``serve.scan_enqueue``'s ``topk_rows``)."""
-    group = _topk_group(num_rows, k)
-    return -(-num_rows // group) + k * group if group else num_rows
-
-
-def _scan_counts(table: jax.Array, k: int) -> Dict[str, int]:
-    """What ``serve.scan_enqueue`` says of the program a scan of ``table``
-    runs: ``shards``, the partitions of its rows the program runs over (1 on
-    one device); ``topk_rows``, the scores one query's selection ranks on
-    each (:func:`_topk_rows` of a shard's rows; every row of the table where
-    the host ranks them); ``merge_rows``, the candidates one query's merge
-    ranks after the shards' selections (shards · k; 0 where nothing is
-    merged: one device, or the host's top-k over the fetched block)."""
-    shards = _row_shards(table)
-    n = shards.mesh.shape[shards.spec[0]] if shards else 1
-    if _host_topk():
-        return dict(shards=n, topk_rows=table.shape[0], merge_rows=0)
-    per = table.shape[0] // n
-    return dict(shards=n, topk_rows=_topk_rows(per, k),
-                merge_rows=n * min(k, per) if shards else 0)
-
-
-def _grouped_scores(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
-                    valid_rows: int, group: int) -> jax.Array:
-    """:func:`_cosine_batch`'s score block widened to whole groups of
-    ``group`` columns, the added columns -inf as every column past
-    ``valid_rows`` is. On the TPU it is the TABLE's rows that are padded,
-    which the compiler folds into the matmul's read of it: no copy of the
-    table, the scores are the unpadded block's bit for bit (PR 38's probe),
-    and the block is written at its final width, where a pad or a slice of
-    the block itself is a copy of 4·Q·V bytes. Elsewhere it is the padded
-    table that would be the copy, and the block is padded."""
-    extra = -syn0.shape[0] % group
-    if jax.default_backend() != "tpu":
-        return jnp.pad(_cosine_batch(syn0, norms, queries, valid_rows),
-                       ((0, 0), (0, extra)), constant_values=-jnp.inf)
-    return _cosine_batch(jnp.pad(syn0, ((0, extra), (0, 0))),
-                         jnp.pad(norms, (0, extra)), queries, valid_rows)
-
-
-def _two_stage_topk(cos: jax.Array, k: int,
-                    group: int) -> Tuple[jax.Array, jax.Array]:
-    """``lax.top_k(cos, k)``, scores and ids bit for bit, ties included,
-    ranking G + k·group scores a row and not all of them: the maximum of
-    each of the G runs of ``group`` columns, the k runs with the largest
-    maxima, then those runs' members.
-
-    Why it is exact: with t the k-th largest score, fewer than k runs have a
-    maximum over t and all of them are taken; a score equal to t that
-    ``lax.top_k`` returns (it breaks ties toward the lower index) lies in one
-    of those or in one of the lowest-numbered runs whose maximum is t, and
-    the runs are numbered in column order, so the k runs taken (ties toward
-    the lower run) hold all k answers. The members are laid out in ascending
-    column, so the last top-k breaks its ties as the single one does."""
-    rows, width = cos.shape
-    runs = width // group
-    with jax.named_scope("scan.group_max"):
-        # 8 rows by 128 columns is the tile the TPU keeps the block in, so
-        # over whole tiles of rows (what _topk_dispatch hands over) or one
-        # row this view is the block as it lies and the maxima read it once;
-        # [rows, runs, group] is first copied into another tiling (4.9 ms of
-        # a 9.3 ms scan at [32, 3M], PERF.md §6). Any split of the rows
-        # gives the same maxima.
-        sub = math.gcd(rows, 8)
-        top = cos.reshape(rows // sub, sub, runs, group // 128, 128).max(
-            axis=(3, 4)).reshape(rows, runs)
-    with jax.named_scope("scan.topk"):
-        _, won = jax.lax.top_k(top, k)
-        won = jax.lax.sort(won, dimension=1)
-        members = jax.vmap(lambda row, starts: jax.vmap(
-            lambda s: jax.lax.dynamic_slice(row, (s,), (group,)))(starts))(
-                cos, won * group)
-        scores, pos = jax.lax.top_k(members.reshape(rows, k * group), k)
-        run = jnp.take_along_axis(won, pos // group, axis=1)
-        return scores, run * group + pos % group
-
-
-@partial(jax.jit, static_argnames=("k", "valid_rows"))
-def _cosine_topk_batch(syn0: jax.Array, norms: jax.Array, queries: jax.Array,
-                       k: int, valid_rows: int
-                       ) -> Tuple[jax.Array, jax.Array]:
-    """cosine(rows, q) top-k over a [Q, D] query matrix in ONE dispatch:
-    normalize queries (snrm2/sscal analog, mllib:589-596), the [Q, V] cosine
-    matrix as a single MXU matmul (mllib:598's matvec, batched), divide by row
-    norms with zero-norm → 0 (mllib:601-609), batched device top-k instead of
-    the client-side BoundedPriorityQueue scan (mllib:611-619). Rows past
-    valid_rows are padding (a mesh's row count, rounded up), excluded
-    outright. The top-k is taken in two exact stages
-    (:func:`_two_stage_topk`) wherever :func:`_topk_group` names a group
-    size: what it returns is ``lax.top_k``'s over the same scores."""
-    group = _topk_group(syn0.shape[0], k)
-    if not group:
-        cos = _cosine_batch(syn0, norms, queries, valid_rows)
-        with jax.named_scope("scan.topk"):
-            return jax.lax.top_k(cos, k)
-    return _two_stage_topk(
-        _grouped_scores(syn0, norms, queries, valid_rows, group), k, group)
-
-
-def _block_topk(cos: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
-    """``lax.top_k(cos, k)`` of one block of the analogy scan, columns as
-    block-local ids. ``k`` = 1 (the accuracy test) is one variadic reduce,
-    the maximum and the lowest column that holds it in ONE pass over the
-    block, which at thousands of rows is all the block's reading there is;
-    a block narrower than ``k`` is widened with -inf."""
-    if cos.shape[1] < k:
-        cos = jnp.pad(cos, ((0, 0), (0, k - cos.shape[1])),
-                      constant_values=-jnp.inf)
-    if k > 1:
-        with jax.named_scope("scan.topk"):
-            return jax.lax.top_k(cos, k)
-
-    def better(a, b):
-        (sa, ia), (sb, ib) = a, b
-        keep = (sa > sb) | ((sa == sb) & (ia < ib))
-        return jnp.where(keep, sa, sb), jnp.where(keep, ia, ib)
-
-    with jax.named_scope("scan.group_max"):
-        col = jax.lax.broadcasted_iota(jnp.int32, cos.shape, 1)
-        best, at = jax.lax.reduce(
-            (cos, col), (jnp.array(-jnp.inf, cos.dtype), jnp.int32(2 ** 31 - 1)),
-            better, (1,))
-        return best[:, None], at[:, None]
-
-
-@partial(jax.jit, static_argnames=("k", "candidates", "block_rows"))
-def _analogy_topk(syn0: jax.Array, scanned: jax.Array, inv_norms: jax.Array,
-                  words: jax.Array, num_words: jax.Array, pos: jax.Array,
-                  k: int, candidates: int, block_rows: int
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """The analogy scan, ONE program a (question capacity, ``k``,
-    ``candidates``): for each of ``pos.shape[0]`` questions the ``k`` best
-    cosines of q = û_b − û_a + û_c over rows [0, ``candidates``) of ``syn0``,
-    a, b and c excluded, and their row ids: ``lax.top_k``'s over the masked
-    scores, ties toward the lower row. The question rows are read from
-    ``syn0``; the matmul reads ``scanned``
-    (:meth:`Word2VecModel._scan_table`: ``syn0`` itself, or on a TPU its
-    bfloat16 rounding, which is what the MXU multiplies either way).
-
-    ``words`` (``int32[3·capacity]``) holds the part's distinct row ids, the
-    first ``num_words`` live; ``pos`` (``int32[capacity, 3]``) names each
-    question's a, b and c by their place in it (padding questions: 0, 0, 0).
-    The rows are read in place, each DISTINCT word once under a ``while``:
-    the whole lane tile of 128 rows that holds it, and the row picked out of
-    that (a gather from a table whose D is no multiple of 128 first copies
-    all of it, :func:`_row_slices`' reason, and so does a one-row slice
-    under a ``while``); then scaled by ``inv_norms``, the
-    cached 1 / norm (0 for a row of zero norm, whose score is then 0). The
-    table is scored ``block_rows`` rows at a time, ``candidates //
-    block_rows`` whole blocks under one ``fori_loop`` and the rest as a block
-    of its own; rows past ``candidates`` are never read. Per block: the
-    ``[capacity, block_rows]`` cosines at :func:`_cosine_batch`'s precision,
-    a, b and c set to -inf by row id, the block's own top-k
-    (:func:`_block_topk`), and a merge with the running answers (the
-    earlier block's first, so that equal scores keep the lower row). No
-    score block wider than ``block_rows`` is ever resident."""
-    dim = syn0.shape[1]
-    with jax.named_scope("scan.analogy_rows"):
-        def read(i, rows):
-            # the whole lane tile of 128 rows that holds the word's, as the
-            # table lies, and the one row picked out of it: exact
-            first = jnp.minimum(words[i] // 128 * 128, max(syn0.shape[0] - 128, 0))
-            tile = jax.lax.dynamic_slice_in_dim(
-                syn0, first, min(128, syn0.shape[0]), allow_negative_indices=False)
-            mine = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0) == words[i] - first
-            row = jnp.sum(jnp.where(mine, tile, 0), axis=0, keepdims=True)
-            return jax.lax.dynamic_update_slice_in_dim(rows, row, i, 0)
-
-        rows = jax.lax.fori_loop(
-            0, num_words, read, jnp.zeros((words.shape[0], dim), syn0.dtype))
-        unit = rows * inv_norms[words][:, None].astype(rows.dtype)
-        q = unit[pos[:, 1]] - unit[pos[:, 0]] + unit[pos[:, 2]]
-        q = q / jnp.maximum(jnp.linalg.norm(q, axis=1, keepdims=True), 1e-12)
-        ids = words[pos]
-
-    def merged(best, lo: jax.Array, table: jax.Array, inv: jax.Array,
-               cut: bool):
-        with jax.named_scope("scan.cosine"):
-            cos = jax.lax.dot_general(
-                q.astype(table.dtype), table, (((1,), (1,)), ((), ())),
-                preferred_element_type=q.dtype) * inv[None, :].astype(q.dtype)
-            col = lo + jax.lax.broadcasted_iota(jnp.int32, cos.shape, 1)
-            out = ((col == ids[:, 0:1]) | (col == ids[:, 1:2])
-                   | (col == ids[:, 2:3]))
-            if cut:
-                out = out | (col >= candidates)
-            cos = jnp.where(out, -jnp.inf, cos)
-        scores, at = _block_topk(cos, k)
-        with jax.named_scope("scan.block_merge"):
-            scores = jnp.concatenate([best[0], scores], axis=1)
-            at = jnp.concatenate([best[1], lo + at.astype(jnp.int32)], axis=1)
-            scores, won = jax.lax.top_k(scores, k)
-            return scores, jnp.take_along_axis(at, won, axis=1)
-
-    whole = candidates // block_rows
-    best = (jnp.full((pos.shape[0], k), -jnp.inf, q.dtype),
-            jnp.zeros((pos.shape[0], k), jnp.int32))
-    if whole:
-        best = jax.lax.fori_loop(0, whole, lambda i, best: merged(
-            best, i * block_rows,
-            jax.lax.dynamic_slice_in_dim(scanned, i * block_rows, block_rows),
-            jax.lax.dynamic_slice_in_dim(inv_norms, i * block_rows, block_rows),
-            False), best)
-    if candidates > whole * block_rows:
-        lo = whole * block_rows
-        # the last block's rows up to a whole lane tile: a slice of the
-        # table's rows folds into the matmul's read; what lies past
-        # ``candidates`` in it is masked
-        hi = min(-(-candidates // 128) * 128, syn0.shape[0])
-        best = merged(best, jnp.int32(lo), scanned[lo:hi], inv_norms[lo:hi],
-                      hi > candidates)
-    return best[0].astype(jnp.float32), best[1]
-
-
-def _row_shards(table: jax.Array) -> Optional[NamedSharding]:
-    """The sharding of a table whose rows are partitioned over one axis of a
-    mesh (``MeshPlan.embedding`` where the model axis holds more than one
-    device), else None: the table lies on one device, or whole on each."""
-    sh = table.sharding
-    if (isinstance(sh, NamedSharding) and len(sh.spec) > 0
-            and isinstance(sh.spec[0], str) and sh.mesh.shape[sh.spec[0]] > 1
-            and all(axis is None for axis in sh.spec[1:])):
-        return sh
-    return None
-
-
-def _owner_rows(syn0: jax.Array, ids: jax.Array, first: jax.Array,
-                axis: str) -> jax.Array:
-    """The [Q, D] rows ``ids`` name (by GLOBAL row; zeros for a negative
-    id), inside ``shard_map``: this shard, whose block ``syn0`` starts at
-    row ``first``, reads the ids it owns as one-row slices of its block, in
-    place as :func:`_query_block` does on one device (no gather op, so no
-    row-major copy of a 300-wide shard), and contributes zeros for the
-    others; one ``psum`` over ``axis`` hands every shard the whole block.
-    Exact: one addend of each row is not zero."""
-    at = ids - first
-    mine = (at >= 0) & (at < syn0.shape[0])
-    rows = _row_slices(syn0, jnp.clip(at, 0, syn0.shape[0] - 1))
-    return jax.lax.psum(jnp.where(mine[:, None], rows, 0), axis)
-
-
-def _sharded_scan(shards: NamedSharding, syn0: jax.Array, norms: jax.Array,
-                  ids: jax.Array, block: Optional[jax.Array],
-                  valid_rows: int, k: Optional[int]):
-    """The scan over a table partitioned by rows (``shards``: its sharding),
-    as ONE program whose body runs under ``shard_map`` over the axis that
-    partitions them, each shard on its own ``[V/n, D]`` rows and ``[V/n]``
-    norms; the queries are replicated (along a data axis too). The table
-    never moves and nothing V wide leaves a chip:
-
-    - the query rows by :func:`_owner_rows` (the vector block's rows where
-      ``ids[i]`` is :data:`_VECTOR`, as on one device);
-    - the shard's ``[Q, V/n]`` scores as on one device
-      (:func:`_grouped_scores` at the group size :func:`_topk_group` gives
-      for the SHARD's rows), columns whose global row is past
-      ``valid_rows`` at -inf;
-    - ``k`` None (the host top-k route): that block, the result sharded
-      along V. Else the shard's own top-k in two exact stages
-      (:func:`_two_stage_topk`; the single ``lax.top_k`` where its rows are
-      too few, all of them where they are fewer than k), its ids moved to
-      global rows by the shard's first row; one ``all_gather`` each of the
-      ``[Q, k]`` scores and ids; and ``lax.top_k`` over the ``[Q, n·k]``
-      candidates, replicated.
-
-    Why the merge is ``lax.top_k``'s over the whole [Q, V] block, ties
-    included: that one orders by (score, lower row first), each of its k
-    answers is among its own shard's best k under the same order, and the
-    candidates lie shard by shard in ascending row, each shard's of equal
-    score in ascending row too (its own top-k's order): among candidates of
-    equal score a lower position is a lower global row."""
-    axis = shards.spec[0]
-    n = shards.mesh.shape[axis]
-    per = syn0.shape[0] // n
-    group = _topk_group(per, k) if k else 0
-
-    def shard(syn0, norms, ids, block):
-        first = jax.lax.axis_index(axis) * per
-        with jax.named_scope("scan.owner_rows"):
-            queries = _owner_rows(syn0, ids, first, axis)
-            if block is not None:
-                queries = jnp.where((ids >= 0)[:, None], queries, block)
-        cos = (_grouped_scores(syn0, norms, queries, per, group) if group
-               else _cosine_batch(syn0, norms, queries, per))
-        if valid_rows < n * per:
-            # the mesh's padding rows, at the end of the last shards; where
-            # the vocabulary divides there are none and no pass is added
-            with jax.named_scope("scan.cosine"):
-                cos = jnp.where(
-                    first + jnp.arange(cos.shape[1])[None, :] < valid_rows,
-                    cos, -jnp.inf)
-        if k is None:
-            return cos
-        if group:
-            scores, rows = _two_stage_topk(cos, k, group)
-        else:
-            with jax.named_scope("scan.topk"):
-                scores, rows = jax.lax.top_k(cos, min(k, per))
-        with jax.named_scope("scan.merge"):
-            scores = jax.lax.all_gather(scores, axis, axis=1, tiled=True)
-            rows = jax.lax.all_gather(rows + first, axis, axis=1, tiled=True)
-            best, at = jax.lax.top_k(scores, k)
-            return best, jnp.take_along_axis(rows, at, axis=1)
-
-    return jax.shard_map(
-        shard, mesh=shards.mesh,
-        in_specs=(P(axis, None), P(axis), P(), P()),
-        out_specs=P(None, axis) if k is None else (P(), P()),
-        # every shard holds the same gathered candidates and ranks them
-        # alike, but an all_gather's result is typed as varying
-        check_vma=k is None)(syn0, norms, ids, block)
-
-
-@partial(jax.jit, static_argnames=("valid_rows", "shards"))
-def _gather_cosine_batch(syn0: jax.Array, norms: jax.Array, ids: jax.Array,
-                         block: Optional[jax.Array], valid_rows: int,
-                         shards: Optional[NamedSharding],
-                         buckets: Optional[jax.Array] = None,
-                         lists: Optional[jax.Array] = None) -> jax.Array:
-    """:func:`_cosine_batch` over the rows :func:`_query_block` builds; of a
-    table partitioned by rows (``shards``), :func:`_sharded_scan`'s."""
-    if shards:
-        return _sharded_scan(shards, syn0, norms, ids, block, valid_rows, None)
-    return _cosine_batch(
-        syn0, norms, _query_block(syn0, ids, block, buckets, lists), valid_rows)
-
-
-@partial(jax.jit, static_argnames=("k", "valid_rows", "shards"))
-def _gather_topk_batch(syn0: jax.Array, norms: jax.Array, ids: jax.Array,
-                       block: Optional[jax.Array], k: int, valid_rows: int,
-                       shards: Optional[NamedSharding],
-                       buckets: Optional[jax.Array] = None,
-                       lists: Optional[jax.Array] = None
-                       ) -> Tuple[jax.Array, jax.Array]:
-    """Word ids (and lists, and vectors) in, top-k out, ONE program:
-    :func:`_cosine_topk_batch` over the rows :func:`_query_block` reads from
-    the tables the model already holds; over a table partitioned by rows
-    (``shards``: :func:`_row_shards`'s answer), :func:`_sharded_scan`."""
-    if shards:
-        return _sharded_scan(shards, syn0, norms, ids, block, valid_rows, k)
-    return _cosine_topk_batch(
-        syn0, norms, _query_block(syn0, ids, block, buckets, lists), k,
-        valid_rows)
-
-
-# CPU route tiling: queries are sub-chunked so the fetched [q, V] score
-# block stays under ~512 MB of host RAM
-_CPU_TOPK_SCORE_BYTES = 512 << 20
-
-
-def _cpu_topk_row(row: np.ndarray, k: int) -> Tuple[np.ndarray, np.ndarray]:
-    """Top-k of one score row: O(V) selection + a k-element sort, scratch
-    bounded to one float copy of the row (``np.partition``). Tie handling is
-    EXACT to ``lax.top_k``: everything strictly above the k-th value is in,
-    and entries EQUAL to it fill the remaining slots in ascending index order
-    (a plain ``argpartition`` leaves that boundary choice arbitrary — it
-    returned different neighbors than the device route on tied scores)."""
-    V = row.shape[0]
-    if k >= V:
-        cand = np.arange(V)
-    else:
-        kth = np.partition(row, V - k)[V - k]        # the k-th largest value
-        above = np.flatnonzero(row > kth)
-        need = k - above.shape[0]
-        ties = np.flatnonzero(row == kth)[:need]     # lowest tied indices win
-        cand = np.concatenate([above, ties])
-    sc = row[cand]
-    order = np.lexsort((cand, -sc))
-    return sc[order], cand[order]
-
-
-def _host_topk() -> bool:
-    """Whether :func:`_topk_dispatch` ranks the scores on the host."""
-    return (jax.default_backend() == "cpu"
-            and os.environ.get("GLINT_CPU_TOPK") == "argpartition")
-
-
-def _topk_dispatch(syn0: jax.Array, norms: jax.Array, ids: np.ndarray,
-                   block: Optional[np.ndarray], k: int, valid_rows: int,
-                   buckets: Optional[jax.Array] = None,
-                   lists: Optional[np.ndarray] = None):
-    """Route the cosine top-k of one chunk: ``ids`` (and ``block``, where
-    the chunk holds vector queries; and ``lists``, where it holds strings a
-    subword model composes from the ``buckets`` it keeps on the device) are
-    host arrays, transferred by the one call that runs the program. Default
-    everywhere: the top-k in the same program as the gather and the matmul;
-    on a TPU over whole tiles of 8 query rows, so the result may hold padding rows after the chunk's
-    own. The host route — the same
-    gather and cosine on the device, scores fetched in ~512 MB sub-chunks and
-    ranked with chunked ``np.argpartition`` (:func:`_cpu_topk_row`),
-    bit-identical results tie-order included (tested) — exists for CPU
-    backends whose XLA top-k lowers to a per-row SORT (an earlier jaxlib
-    took >30 min for 64 queries at V=10M; under the current one the device
-    route wins 2-3x at every shape tried), so it is opt-in: set
-    ``GLINT_CPU_TOPK=argpartition`` on toolchains that still exhibit the
-    sort lowering."""
-    shards = _row_shards(syn0)
-    if not _host_topk():
-        extra = -ids.shape[0] % 8
-        if jax.default_backend() == "tpu" and ids.shape[0] > 1 and extra:
-            # a TPU keeps the [Q, V] score block in tiles of 8 rows, so whole
-            # tiles cost the scan nothing; they are what the two-stage
-            # selection reads in place, and 9 programs serve the 64 batch
-            # sizes a full batcher sends where 64 did (each ~0.15 s to load
-            # and 1-4 s to compile: the benchmark's set-up). The last query
-            # is repeated; the caller keeps the first ``len(ids)`` rows. A
-            # single query is a matrix-vector product of its own and stays.
-            ids = np.concatenate([ids, np.repeat(ids[-1:], extra)])
-            if block is not None:
-                block = np.concatenate(
-                    [block, np.zeros((extra, block.shape[1]), block.dtype)])
-            if lists is not None:
-                lists = np.concatenate([lists, np.repeat(lists[-1:], extra, 0)])
-        # device arrays: this returns once the program is enqueued, and the
-        # caller's fetch is where the host waits for it
-        return _gather_topk_batch(
-            syn0, norms, ids, block, k, valid_rows, shards,
-            *(() if lists is None else (buckets, lists)))
-    Q, V = ids.shape[0], syn0.shape[0]
-    qsub = max(1, min(Q, _CPU_TOPK_SCORE_BYTES // max(V * 4, 1)))
-    scores = np.empty((Q, k), np.float32)
-    idxs = np.empty((Q, k), np.int64)
-    for lo in range(0, Q, qsub):
-        cos = np.asarray(_gather_cosine_batch(
-            syn0, norms, ids[lo:lo + qsub],
-            None if block is None else block[lo:lo + qsub], valid_rows,
-            shards, buckets,
-            None if lists is None else lists[lo:lo + qsub]))
-        for r in range(cos.shape[0]):
-            scores[lo + r], idxs[lo + r] = _cpu_topk_row(cos[r], k)
-    return scores, idxs

@@ -303,7 +303,7 @@ def hs_step_core(
     first's results order after it: one conditional that held a form's reads
     AND its writes made the compiler copy the table in and out of the
     per-pair branch's scatter loop (the compile for the described v5e,
-    tests/test_step_inplace_tpu.py)."""
+    tests/test_hs_inplace_tpu.py)."""
     syn0, syn1 = params.syn0, params.syn1
     n, d = centers.shape[0], syn0.shape[1]
     v = table.counts.shape[0] - 1

@@ -462,8 +462,8 @@ class EmbeddingService:
         unknown word or op) and hands the ``syn`` queries to
         ``find_synonyms_begin``, which returns with the scan enqueued. What
         is left for the second half follows from what the batch holds: a
-        device result to fetch, or (the ANN arm, the host top-k route, a
-        batch with no ``syn`` query) nothing.
+        device result to fetch, or (the ANN arm, a batch with no ``syn``
+        query) nothing.
 
         From here to the end of the second half is the span
         ``serve.dispatch`` (obs/spans.py; child of the batcher's

@@ -228,11 +228,15 @@ def _scan_batches(syn0):
     }
 
 
-def _row_by_row(model, queries, num, chunk):
+def _row_by_row(model, queries, num, chunk, oracle="matrix_scan"):
     """Today's replies from yesterday's host side: the block read one row
-    at a time, stacked, and scanned by the matrix-query form."""
+    at a time, stacked, and scanned by the matrix-query form
+    (``matrix_scan``), or scored whole by ``_cosine_batch`` and ranked on
+    the host by the tests' reference (``host_rank``: tests/topk_reference.py,
+    ties to the lower row)."""
     import jax.numpy as jnp
-    from glint_word2vec_tpu.models.word2vec import _cosine_topk_batch
+    from glint_word2vec_tpu.ops.scan import _cosine_batch, _cosine_topk_batch
+    from topk_reference import host_topk
     model.norms
     k, out = min(num + 1, model.num_words), []
     for lo in range(0, len(queries), chunk):
@@ -240,27 +244,28 @@ def _row_by_row(model, queries, num, chunk):
         block = jnp.stack([
             model._full0[model.vocab.get(q)] if isinstance(q, str)
             else jnp.asarray(q, jnp.float32) for q in part])
-        scores, idxs = _cosine_topk_batch(
-            model._full0, model._norms, block, k, model.num_words)
+        if oracle == "host_rank":
+            scores, idxs = host_topk(np.asarray(_cosine_batch(
+                model._full0, model._norms, block, model.num_words)), k)
+        else:
+            scores, idxs = _cosine_topk_batch(
+                model._full0, model._norms, block, k, model.num_words)
         out.extend(model._replies(
             [q if isinstance(q, str) else None for q in part],
             np.asarray(scores), np.asarray(idxs), num))
     return out
 
 
-@pytest.mark.parametrize("route", ["device_topk", "argpartition"])
+@pytest.mark.parametrize("oracle", ["matrix_scan", "host_rank"])
 @pytest.mark.parametrize("table", ["float32", "bfloat16", "sharded"])
 @pytest.mark.parametrize("batch", [
     "words_repeated", "vectors", "mixed", "one_word", "one_vector",
     "longer_than_chunk", "num_over_vocabulary"])
-def test_gathered_batch_equals_row_by_row(batch, table, route, monkeypatch):
-    if route == "argpartition":
-        monkeypatch.setenv("GLINT_CPU_TOPK", "argpartition")
+def test_gathered_batch_equals_row_by_row(batch, table, oracle):
     model, syn0 = _scan_model(table)
     queries, num, chunk = _scan_batches(syn0)[batch]
     got = model.find_synonyms_batch(queries, num, chunk=chunk)
-    monkeypatch.delenv("GLINT_CPU_TOPK", raising=False)
-    want = _row_by_row(model, queries, num, chunk)
+    want = _row_by_row(model, queries, num, chunk, oracle)
     assert len(got) == len(queries)
     for q, g, w in zip(queries, got, want):
         assert [x for x, _ in g] == [x for x, _ in w]
@@ -324,14 +329,14 @@ def _topk_case(case: str, rows: int):
 def test_two_stage_topk_is_lax_top_k(case, rows):
     """Scores and ids bit-equal to ``lax.top_k`` of the same score block."""
     import jax
-    from glint_word2vec_tpu.models import word2vec as w2v
+    from glint_word2vec_tpu.ops import scan
     syn0, norms, queries, k, valid_rows, two_stage = _topk_case(case, rows)
-    assert bool(w2v._topk_group(syn0.shape[0], k)) is two_stage
+    assert bool(scan._topk_group(syn0.shape[0], k)) is two_stage
     want_s, want_i = jax.lax.top_k(
-        w2v._cosine_batch(syn0, norms, queries, valid_rows), k)
+        scan._cosine_batch(syn0, norms, queries, valid_rows), k)
     if case.startswith("zero_ties"):
         assert (np.asarray(want_s)[:, -1] == 0.0).all()    # k-th inside the tie
-    got_s, got_i = w2v._cosine_topk_batch(syn0, norms, queries, k, valid_rows)
+    got_s, got_i = scan._cosine_topk_batch(syn0, norms, queries, k, valid_rows)
     np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
     np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
 
@@ -345,7 +350,7 @@ def test_what_only_a_tpu_takes(rows, monkeypatch):
     so that no other test meets these traces."""
     import jax
     import jax.numpy as jnp
-    from glint_word2vec_tpu.models import word2vec as w2v
+    from glint_word2vec_tpu.ops import scan
     rng = np.random.default_rng(rows)
     syn0 = rng.standard_normal((1733, 16)).astype(np.float32)
     vocab = Vocabulary.from_words_and_counts(
@@ -353,14 +358,14 @@ def test_what_only_a_tpu_takes(rows, monkeypatch):
     model = Word2VecModel(vocab, jnp.asarray(syn0))
     queries = [f"w{i}" for i in rng.integers(0, 1733, rows - 1)] + [syn0[9]]
     want = model.find_synonyms_batch(queries, 10)
-    whole = np.asarray(w2v._cosine_batch(
+    whole = np.asarray(scan._cosine_batch(
         model._full0, model._norms, jnp.asarray(syn0[:rows]), 1700))
     seen = []
-    real = w2v._gather_topk_batch
-    monkeypatch.setattr(w2v, "_gather_topk_batch",
+    real = scan._gather_topk_batch
+    monkeypatch.setattr(scan, "_gather_topk_batch",
                         lambda *a: seen.append(a) or real(*a))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    block = np.asarray(w2v._grouped_scores(
+    block = np.asarray(scan._grouped_scores(
         model._full0, model._norms, jnp.asarray(syn0[:rows]), 1700, 128))
     assert block.shape == (rows, 1792)
     np.testing.assert_allclose(block[:, :1733], whole, rtol=0, atol=1e-6)
@@ -388,7 +393,7 @@ def test_two_stage_replies_are_the_single_top_k_s(batch, monkeypatch):
     query keeps every neighbour; both as the single ``lax.top_k`` replies."""
     import jax
     import jax.numpy as jnp
-    from glint_word2vec_tpu.models import word2vec as w2v
+    from glint_word2vec_tpu.ops import scan
     rng = np.random.default_rng(8)
     syn0 = rng.standard_normal((1700, 16)).astype(np.float32)
     syn0[[40, 900, 1699]] = 0.0
@@ -397,14 +402,14 @@ def test_two_stage_replies_are_the_single_top_k_s(batch, monkeypatch):
     model = Word2VecModel(vocab, jnp.asarray(syn0))
     queries = (["w3", "w1699", "w3", "w128"] if batch.startswith("words") else
                [syn0[7] * 2.0, np.zeros(16, np.float32), syn0[1698] + 0.5])
-    assert w2v._topk_group(1700, 11) == 128
+    assert scan._topk_group(1700, 11) == 128
     got = model.find_synonyms_batch(queries, 10)
 
     def single(syn0, norms, ids, block, k, valid_rows, partitioned):
-        return jax.lax.top_k(w2v._gather_cosine_batch(
-            syn0, norms, ids, block, valid_rows, partitioned), k)
+        return jax.lax.top_k(scan._cosine_batch(
+            syn0, norms, scan._query_block(syn0, ids, block), valid_rows), k)
 
-    monkeypatch.setattr(w2v, "_gather_topk_batch", single)
+    monkeypatch.setattr(scan, "_gather_topk_batch", single)
     assert got == model.find_synonyms_batch(queries, 10)
     for q, reply in zip(queries, got):
         assert len(reply) == 10
@@ -417,6 +422,7 @@ def test_unknown_word_dispatches_nothing(monkeypatch):
     from glint_word2vec_tpu.models import word2vec as w2v
     model, _ = _scan_model("float32")
     calls = []
+    # the name the model calls: its own module's
     monkeypatch.setattr(w2v, "_topk_dispatch", lambda *a: calls.append(a))
     # the unknown word sits in the second chunk: the first is not sent either
     with pytest.raises(KeyError, match="zzz not in vocabulary"):
@@ -430,7 +436,7 @@ def test_block_dtype_is_what_stacking_gave():
     bfloat16 control rides on it); a vector query promotes the block."""
     import jax
     import jax.numpy as jnp
-    from glint_word2vec_tpu.models.word2vec import _query_block
+    from glint_word2vec_tpu.ops.scan import _query_block
     table = jnp.ones((8, 4), jnp.bfloat16)
     ids = jnp.asarray([2, -1], jnp.int32)
     words = jax.eval_shape(lambda t, i: _query_block(t, i, None), table, ids)
@@ -449,12 +455,12 @@ def test_row_sharded_table_takes_the_gather(table, partitioned, monkeypatch):
     from the table, and the partitioned one all-gathers neither the table
     nor anything V (or V / n) wide: the candidates, [Q, n * k]."""
     import re
-    from glint_word2vec_tpu.models import word2vec as w2v
+    from glint_word2vec_tpu.ops import scan
     model, _ = _mesh_model((1, 4)) if partitioned else _scan_model(table)
     seen = []
-    real = w2v._gather_topk_batch
+    real = scan._gather_topk_batch
     monkeypatch.setattr(
-        w2v, "_gather_topk_batch",
+        scan, "_gather_topk_batch",
         lambda *a: seen.append(a) or real(*a))
     model.find_synonyms_batch(["w1", "w2"], 3)
     (syn0, norms, ids, block, k, valid_rows, shards), = seen
@@ -535,33 +541,31 @@ def _assert_same_replies(got, want):
                                    rtol=0, atol=1e-6)
 
 
-@pytest.mark.parametrize("route", ["device_topk", "argpartition"])
+@pytest.mark.parametrize("oracle", ["one_device", "host_rank"])
 @pytest.mark.parametrize("mesh", _MESHES, ids=lambda m: f"{m[0]}x{m[1]}")
 @pytest.mark.parametrize("batch", [
     "words", "vectors", "mixed", "ties_across_shards", "zero_norm_word",
     "last_shard_last_row", "one_word"])
-def test_sharded_scan_is_the_one_device_programs(batch, mesh, route, monkeypatch):
+def test_sharded_scan_is_the_one_device_programs(batch, mesh, oracle):
     """``Word2VecModel(plan=make_mesh(d, n))`` answers with the one-device
     program's replies, ties included: the table never gathered, each shard
     ranked by its own two stages (1,501 to 6,004 rows a shard, in runs of
-    128), the mesh's padding rows never returned."""
-    from glint_word2vec_tpu.models import word2vec as w2v
+    128), the mesh's padding rows never returned. ``host_rank``: the same
+    replies from the one-device table's whole score block ranked on the host
+    (``_row_by_row``), which shares no selection with either program."""
+    from glint_word2vec_tpu.ops import scan
     one, syn0 = _mesh_model(None)
     queries, num = _mesh_batches(syn0)[batch]
-    want = one.find_synonyms_batch(queries, num)
-    if route == "argpartition":
-        monkeypatch.setenv("GLINT_CPU_TOPK", "argpartition")
+    want = (one.find_synonyms_batch(queries, num) if oracle == "one_device"
+            else _row_by_row(one, queries, num, 128, "host_rank"))
     model, _ = _mesh_model(mesh)
     assert model._full0.shape[0] == 12008
-    counts = w2v._scan_counts(model._full0, num + 1)
+    counts = scan._scan_counts(model._full0, num + 1)
     rows = 12008 // mesh[1]
     assert counts["shards"] == mesh[1]
-    if route == "argpartition":
-        assert counts["topk_rows"] == 12008 and counts["merge_rows"] == 0
-    else:
-        assert counts["merge_rows"] == mesh[1] * (num + 1)
-        assert counts["topk_rows"] == w2v._topk_rows(rows, num + 1) <= rows
-        assert w2v._topk_group(rows, num + 1) == 128
+    assert counts["merge_rows"] == mesh[1] * (num + 1)
+    assert counts["topk_rows"] == scan._topk_rows(rows, num + 1) <= rows
+    assert scan._topk_group(rows, num + 1) == 128
     got = model.find_synonyms_batch(queries, num)
     _assert_same_replies(got, want)
     if batch == "ties_across_shards":
@@ -635,18 +639,19 @@ def test_multiply_on_a_mesh_is_one_sharded_matvec():
 # -- the batch call's two halves (find_synonyms_begin / find_synonyms_finish) --------
 
 
-@pytest.mark.parametrize("route", ["device_topk", "argpartition"])
+@pytest.mark.parametrize("finished", ["as_begun", "last_begun_first"])
 @pytest.mark.parametrize("table", ["float32", "sharded"])
 @pytest.mark.parametrize("batch", ["words_repeated", "vectors", "mixed",
                                    "one_word", "longer_than_chunk"])
-def test_the_two_halves_are_the_batch_call_bit_for_bit(batch, table, route,
-                                                       monkeypatch):
+def test_the_two_halves_are_the_batch_call_bit_for_bit(batch, table, finished):
     """``find_synonyms_batch(q, k)`` is ``finish(begin(q, k))``: the same
     replies to the last bit with the second half on another thread (the
-    serve batcher's completer) and a second call begun between the two."""
+    serve batcher's completer) and a second call begun between the two.
+    ``last_begun_first``: the second call is finished WHOLE before the first
+    is touched (``begun=`` permits it; the serve completer relies on results
+    being bound to their ``pending``, not to the order of the begins), a call
+    of several chunks enqueueing its later parts while the other's wait."""
     import threading
-    if route == "argpartition":
-        monkeypatch.setenv("GLINT_CPU_TOPK", "argpartition")
     model, syn0 = _scan_model(table)
     queries, num, chunk = _scan_batches(syn0)[batch]
     want = model.find_synonyms_batch(queries, num, chunk=chunk)
@@ -657,10 +662,13 @@ def test_the_two_halves_are_the_batch_call_bit_for_bit(batch, table, route,
     def finish(name, pending):
         out[name] = model.find_synonyms_finish(pending)
 
+    order = (("first", first), ("second", second))
     threads = [threading.Thread(target=finish, args=(n, p))
-               for n, p in (("first", first), ("second", second))]
+               for n, p in (order if finished == "as_begun" else order[::-1])]
     for t in threads:
         t.start()
+        if finished == "last_begun_first":
+            t.join(timeout=60)
     for t in threads:
         t.join(timeout=60)
     assert not any(t.is_alive() for t in threads)

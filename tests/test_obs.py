@@ -1015,7 +1015,7 @@ def _sgns_shared_step_case():
 
 def _cosine_topk_case():
     import jax.numpy as jnp
-    from glint_word2vec_tpu.models.word2vec import _cosine_topk_batch
+    from glint_word2vec_tpu.ops.scan import _cosine_topk_batch
     rng = np.random.default_rng(1)
     syn0 = jnp.asarray(rng.standard_normal((96, 16)), jnp.float32)
 
@@ -1029,7 +1029,7 @@ def _gather_topk_case(mixed=False):
     """The served program: word ids in, top-k out (``scan.gather`` too); the
     mixed form holds a vector query, and so the vector block."""
     import jax.numpy as jnp
-    from glint_word2vec_tpu.models.word2vec import _gather_topk_batch
+    from glint_word2vec_tpu.ops.scan import _gather_topk_batch
     scan, (syn0, norms, queries) = _cosine_topk_case()
     ids = jnp.asarray([3, -1, 95, 3] if mixed else [3, 0, 95, 3], jnp.int32)
 
@@ -1047,7 +1047,7 @@ def _transform_slide_case():
     """A transform slide's one program (``transform.gather``,
     ``transform.segment_mean``), a further pass of it."""
     import jax.numpy as jnp
-    from glint_word2vec_tpu.models.word2vec import _segment_means
+    from glint_word2vec_tpu.ops.transform import _segment_means
     _, (syn0, _, _) = _cosine_topk_case()
     ids = jnp.asarray([3, 0, 95, 3, 7, syn0.shape[0]], jnp.int32)
     seg = jnp.asarray([0, 0, 1, 3, 3, 4], jnp.int32)
@@ -1063,7 +1063,7 @@ def _sentence_slide_case():
     """A ``sentence_vectors`` slide's one program (those two scopes and
     ``transform.list_gather``, ``transform.compose``), a further pass of it."""
     import jax.numpy as jnp
-    from glint_word2vec_tpu.models.word2vec import _sentence_means
+    from glint_word2vec_tpu.ops.transform import _sentence_means
     _, (syn0, norms, _) = _cosine_topk_case()
     ids = jnp.asarray([3, 0, 95, 3, 7, syn0.shape[0]], jnp.int32)
     seg = jnp.asarray([0, 0, 1, 3, 3, 4], jnp.int32)
@@ -1085,7 +1085,7 @@ def _gather_topk_sharded_case():
     """The same program over a table partitioned by rows on four of the
     virtual devices (``scan.owner_rows``, ``scan.merge`` too)."""
     import jax
-    from glint_word2vec_tpu.models.word2vec import _gather_topk_batch
+    from glint_word2vec_tpu.ops.scan import _gather_topk_batch
     from glint_word2vec_tpu.parallel.mesh import make_mesh
     _, (syn0, norms, _) = _cosine_topk_case()
     plan = make_mesh(1, 4)

@@ -323,24 +323,24 @@ def test_export_parallel_byte_identity(tmp_path, binary):
     assert filecmp.cmp(paths[0], paths[1], shallow=False)
 
 
-# -- CPU top-k routing --------------------------------------------------------------
+# -- the scan's top-k against the tests' host ranking (tests/topk_reference.py) ------
 
 
-def test_cpu_topk_matches_lax_topk(monkeypatch):
+def test_cpu_topk_matches_lax_topk():
     from glint_word2vec_tpu.data.vocab import Vocabulary
-    from glint_word2vec_tpu.models import word2vec as w2v
-    if jax.default_backend() != "cpu":
-        pytest.skip("exercises the CPU argpartition route")
-    monkeypatch.setenv("GLINT_CPU_TOPK", "argpartition")
+    from glint_word2vec_tpu.models.word2vec import Word2VecModel
+    from glint_word2vec_tpu.ops import scan
+    from topk_reference import host_topk
     words, counts, syn0, _ = _ckpt_fixtures(rows=800, dim=16)
     vocab = Vocabulary.from_words_and_counts(words, counts)
-    model = w2v.Word2VecModel(vocab, jnp.asarray(syn0))
-    s_ref, i_ref = w2v._cosine_topk_batch(
-        model._full0, model.norms, jnp.asarray(syn0[:5]), 12, 800)
-    s_cpu, i_cpu = w2v._topk_dispatch(
+    model = Word2VecModel(vocab, jnp.asarray(syn0))
+    s_ref, i_ref = host_topk(np.asarray(scan._cosine_batch(
+        model._full0, model.norms, jnp.asarray(syn0[:5]), 800)), 12)
+    s_dev, i_dev = scan._topk_dispatch(
         model._full0, model.norms, np.arange(5, dtype=np.int32), None, 12, 800)
-    assert np.array_equal(np.asarray(i_ref), i_cpu)
-    assert np.allclose(np.asarray(s_ref), s_cpu, atol=1e-6)
+    assert isinstance(s_dev, jax.Array) and isinstance(i_dev, jax.Array)
+    assert np.array_equal(i_ref, np.asarray(i_dev))
+    assert np.allclose(s_ref, np.asarray(s_dev), atol=1e-6)
     # and through the public API
     out = model.find_synonyms_batch(["w0", syn0[3]], 5)
     assert len(out) == 2 and len(out[0]) == 5
@@ -349,9 +349,10 @@ def test_cpu_topk_matches_lax_topk(monkeypatch):
 
 def test_cpu_topk_tie_order_matches_lax_topk():
     # tied scores are real in this domain (duplicate rows, zero-norm rows all
-    # scoring 0.0); lax.top_k breaks ties toward the LOWER index and the host
-    # route must match exactly — a plain argpartition boundary does not
-    from glint_word2vec_tpu.models.word2vec import _cpu_topk_row
+    # scoring 0.0); lax.top_k breaks ties toward the LOWER index and the
+    # tests' host ranking must match exactly — a plain argpartition boundary
+    # does not
+    from topk_reference import _cpu_topk_row
     cases = [
         (np.asarray([1.0, 1.0, 0.5, 1.0], np.float32), 2),
         (np.asarray([0.0] * 10, np.float32), 3),

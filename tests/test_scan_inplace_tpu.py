@@ -1,0 +1,194 @@
+"""The read side's programs read their tables in place on the TPU.
+
+``ops/scan.py`` (the neighbour scan on one chip and under ``shard_map`` on the
+described 2x2 as a 1x4 mesh, the analogy scan) and ``ops/transform.py`` (the
+sentence slides), compiled at their cells' published shapes for a v5e chip that
+is described, not attached (tests/described_v5e.py; nothing runs): no copy, gather
+or conversion of a table, nothing V wide across the mesh, no gathered block
+written. A count of instructions and of bytes, not a time.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from described_v5e import D, SUB_D, SUB_K, SUB_V, V, _no_table_copied
+from described_v5e import one_chip, topo  # noqa: F401  (fixtures)
+
+from glint_word2vec_tpu.ops import scan, transform
+
+
+@pytest.mark.parametrize("lists", [True, False], ids=["with_lists", "words_alone"])
+def test_the_subword_scan_copies_no_table(one_chip, lists, monkeypatch):
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    # _grouped_scores asks for the backend while it is traced: the TPU's branch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    extra = ((spec((SUB_K, 384), jnp.float32), spec((32, 48), jnp.int32))
+             if lists else ())
+    compiled = scan._gather_topk_batch.lower(
+        spec((SUB_V, SUB_D), jnp.float32), spec((SUB_V,), jnp.float32),
+        spec((32,), jnp.int32), None, 11, SUB_V, False, *extra).compile()
+    _no_table_copied(compiled.as_text())
+    # two score blocks of [32, 2,519,552] float32 and no table beside them
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("queries", [32, 64])
+def test_the_sharded_scan_moves_no_table_and_nothing_v_wide(topo, queries, monkeypatch):
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+
+    rows, dim, k = 10_000_000, 300, 11
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    shards = NamedSharding(mesh, PartitionSpec("model", None))
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, PartitionSpec(*axes)))
+
+    # _grouped_scores asks for the backend while it is traced: the TPU's branch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = scan._gather_topk_batch.lower(
+        spec((rows, dim), jnp.float32, "model", None),
+        spec((rows,), jnp.float32, "model"), spec((queries,), jnp.int32),
+        None, k, rows, shards).compile()
+    text = compiled.as_text()
+    assert not re.findall(r"= f32\[2500\d{3},300\]\S* (?:copy|gather|all-gather)\(", text)
+    assert " all-to-all(" not in text and " collective-permute(" not in text
+    reduced = re.findall(r"= (\S+?)\{\S* all-reduce(?:-start)?\(", text)
+    assert reduced == [f"f32[{queries},{dim}]"], reduced
+    gathered = re.findall(r"= \(?(\w+)\[([\d,]+)\]\S* all-gather(?:-start)?\(", text)
+    assert len(gathered) == 2, gathered
+    for _, dims in gathered:
+        assert int(np.prod([int(d) for d in dims.split(",")])) == 4 * k * queries
+    memory = compiled.memory_analysis()
+    # a chip's shard and its norms are the arguments; one score block beside them
+    assert memory.argument_size_in_bytes < 3.1e9
+    assert 4 * queries * 2_500_000 < memory.temp_size_in_bytes < 4 * queries * 2_500_000 * 1.2
+
+
+@pytest.mark.parametrize("carried", [False, True], ids=["one_pass", "a_further_pass"])
+def test_the_transform_slide_copies_no_table_and_writes_no_gathered_block(one_chip, carried):
+    """``transform_sentences``' one program a slide (PR 48) at
+    ``sgns-transform-3m-300``'s size: 327,680 ids gathered from the whole-lane
+    form of syn0 into 10,000 sentences. No copy of the table, and the sorted
+    scatter-add takes the gather as a producer: nothing ``[rows, 384]`` is
+    written (what is made is the ``[10000, 384]`` sums)."""
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, sentences = 327_680, 10_000
+    compiled = transform._segment_means.lower(
+        spec((V, D), jnp.float32), spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+        spec((sentences,), jnp.int32),
+        spec((sentences, D), jnp.float32) if carried else None,
+        segments=sentences, dim=300).compile()
+    text = compiled.as_text()
+    assert not re.findall(r"= f32\[%d,\d+\]\S* copy\(" % V, text)
+    assert " sort(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("carried", [False, True], ids=["one_pass", "a_further_pass"])
+def test_the_sentence_vector_slide_copies_no_table_and_writes_no_block(one_chip, carried):
+    """``sentence_vectors``' one program a slide (PR 52) at
+    ``subword-sentvec-2.5m-300``'s size: 327,680 word rows gathered from the
+    composed table at whole lanes and scaled by their inverse norms, 294,912
+    list rows gathered from the bucket rows into 32,768 tokens, normalised,
+    both summed into 10,000 sentences. No copy of either table, no sort, and
+    every gather is its sorted scatter-add's producer: neither gathered block
+    nor the token block is written (what is made is the ``[10000, 384]``
+    sums)."""
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, listed, tokens, sentences = 327_680, 294_912, 32_768, 10_000
+    lists = (spec((SUB_K, 384), jnp.float32), spec((listed,), jnp.int32),
+             spec((listed,), jnp.int32), spec((tokens,), jnp.int32))
+    before = ((spec((sentences, 384), jnp.float32), spec((sentences,), jnp.int32))
+              if carried else None)
+    compiled = transform._sentence_means.lower(
+        spec((SUB_V, 384), jnp.float32), spec((SUB_V,), jnp.float32),
+        spec((rows,), jnp.int32), spec((rows,), jnp.int32), lists,
+        spec((sentences,), jnp.int32), before, segments=sentences, dim=300).compile()
+    text = compiled.as_text()
+    _no_table_copied(text)
+    assert " sort(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_the_same_gather_from_the_300_wide_table_copies_all_of_it(one_chip):
+    """Why the model keeps a whole-lane form for its row reads: handed syn0 as
+    the scan reads it, the same program first copies the whole table row-major
+    (the parent's ``self.syn0[idx]``: 3.6 GB and ~13 ms a call before one row
+    is read)."""
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = transform._segment_means.lower(
+        spec((V, 300), jnp.float32), spec((327_680,), jnp.int32),
+        spec((327_680,), jnp.int32), spec((10_000,), jnp.int32), None,
+        segments=10_000, dim=300).compile()
+    assert re.findall(r"= f32\[%d,300\]\S* copy\(" % V, compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes > 3 << 30
+
+
+@pytest.mark.parametrize("cap,k", [(512, 1), (2048, 1), (2048, 10)])
+def test_the_analogy_scan_copies_no_table_and_holds_no_block_wider_than_a_tile(one_chip, cap, k):
+    """``Word2VecModel.analogies``' one program a (capacity, k) (PR 55) at
+    ``sgns-analogy-3m-300``'s size: the question rows read a lane tile at a
+    time in place from the float32 table, the bfloat16 form of it that the
+    model keeps (``_scan_table``) scored 65,536 rows a block. No copy or
+    conversion of a [3,000,000, 300] table (a one-row slice under a ``while``
+    made a row-major copy; a float32 table handed to the matmul is converted
+    whole, once a program, 1.8 GB of temporaries), no score block wider than a
+    tile, and at k = 1 not even that: the matmul, the masks and the variadic
+    reduce are ONE output fusion, so the block never leaves the chip's fast
+    memory. For k > 1 one [capacity, 65,536] float32 block is held."""
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, dim, block = 3_000_000, 300, 1 << 16
+    compiled = scan._analogy_topk.lower(
+        spec((rows, dim), jnp.float32), spec((rows, dim), jnp.bfloat16),
+        spec((rows,), jnp.float32), spec((3 * cap,), jnp.int32), spec((), jnp.int32),
+        spec((cap, 3), jnp.int32), k=k, candidates=rows, block_rows=block).compile()
+    text = compiled.as_text()
+    assert not re.findall(
+        r"= \w+\[%d,%d\]\S* (?:copy|transpose|gather|convert)\(" % (rows, dim), text)
+    widths = [int(w) for w in re.findall(r"= \w+\[%d,(\d+)\]" % cap, text)]
+    assert max(widths) <= block, max(widths)
+    if k == 1:
+        fused = re.findall(r"-> \(f32\[%d\], s32\[%d\]\) \{" % (cap, cap), text)
+        assert fused, "the block's maximum is no longer the matmul's own output fusion"
+    memory = compiled.memory_analysis()
+    held = 4 * cap * block if k > 1 else 0
+    assert memory.temp_size_in_bytes < held * 1.05 + (64 << 20), memory.temp_size_in_bytes
+
+
+def test_a_float32_table_at_the_default_precision_is_multiplied_as_bfloat16(one_chip):
+    """Why ``_scan_table`` keeps a bfloat16 form: handed the float32 table, the
+    compiler converts all of it to bfloat16 itself, outside the blocks' loop,
+    once a program, and the matmul's operands are bfloat16 either way."""
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, dim, cap = 3_000_000, 300, 512
+    compiled = scan._analogy_topk.lower(
+        spec((rows, dim), jnp.float32), spec((rows, dim), jnp.float32),
+        spec((rows,), jnp.float32), spec((3 * cap,), jnp.int32), spec((), jnp.int32),
+        spec((cap, 3), jnp.int32), k=1, candidates=rows, block_rows=1 << 16).compile()
+    text = compiled.as_text()
+    assert re.search(r"= bf16\[%d,%d\]\S* convert\(" % (rows, dim), text)
+    assert not re.search(r"convolution\(\S*f32\[", text)
+    assert compiled.memory_analysis().temp_size_in_bytes > 2 * rows * dim

@@ -839,7 +839,7 @@ def test_service_over_a_mesh_answers_as_one_device_does(serve_tracer, mesh):
     ``merge_rows``) and ``serve.row_fetch`` issued one put a batch."""
     from concurrent.futures import ThreadPoolExecutor
 
-    from glint_word2vec_tpu.models.word2vec import _topk_rows
+    from glint_word2vec_tpu.ops.scan import _topk_rows
     from glint_word2vec_tpu.parallel.mesh import make_mesh
     one = make_model(v=3001, d=16)
     sharded = Word2VecModel(one.vocab, np.asarray(one.syn0), plan=make_mesh(*mesh))
@@ -883,7 +883,7 @@ def test_one_program_per_all_word_batch_size(size):
     """A size's first all-word batch compiles the scan-with-gather and
     nothing else (no stack, no row read); its second compiles nothing."""
     import jax.monitoring
-    from glint_word2vec_tpu.models.word2vec import _gather_topk_batch
+    from glint_word2vec_tpu.ops.scan import _gather_topk_batch
     if not _COMPILED:       # jax.monitoring has no public unregister
         _COMPILED.append("listening")
         jax.monitoring.register_event_duration_secs_listener(_on_compile)
@@ -1301,14 +1301,12 @@ def test_every_reply_of_the_service_is_handed_out_by_find_synonyms_batch(monkeyp
     assert begins == ["glint-serve-batcher"]
 
 
-@pytest.mark.parametrize("arm", ["ann", "host_topk"])
+@pytest.mark.parametrize("arm", ["ann"])
 def test_service_arms_with_no_device_result_do_their_work_in_begin(
-        arm, monkeypatch, serve_tracer):
-    """The ANN arm and the host top-k route leave nothing to fetch: chosen
-    from what the batch holds, and the completer's finish hands it back."""
+        arm, serve_tracer):
+    """The ANN arm leaves nothing to fetch: chosen from what the batch
+    holds, and the completer's finish hands it back."""
     model = make_model(v=600, d=16)
-    if arm == "host_topk":
-        monkeypatch.setenv("GLINT_CPU_TOPK", "argpartition")
     want = model.find_synonyms("w3", 5)
     serve_tracer.configure(enabled=True)
     svc = EmbeddingService(model=model, ann=arm == "ann", ann_centroids=16,
@@ -1320,12 +1318,8 @@ def test_service_arms_with_no_device_result_do_their_work_in_begin(
         model.stop()
     assert [w for w, _ in got] == [w for w, _ in want]
     by = {e["name"]: e["thread"] for e in serve_tracer.events()}
-    if arm == "ann":
-        assert "serve.result_fetch" not in by
-        assert by["serve.ann_search"] == by["serve.reply_build"] == "glint-serve-batcher"
-    else:
-        assert by["serve.scan_enqueue"] == "glint-serve-batcher"
-        assert by["serve.result_fetch"] == "glint-serve-batcher-completer"
+    assert "serve.result_fetch" not in by
+    assert by["serve.ann_search"] == by["serve.reply_build"] == "glint-serve-batcher"
 
 
 # -- the benchmark's span reader over two batches in flight ----------------------------

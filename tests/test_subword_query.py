@@ -22,9 +22,9 @@ from reference import subword_query_ref as ref  # noqa: E402
 from glint_word2vec_tpu.config import Word2VecConfig  # noqa: E402
 from glint_word2vec_tpu.data import subword as data_sw  # noqa: E402
 from glint_word2vec_tpu.data.vocab import Vocabulary  # noqa: E402
-from glint_word2vec_tpu.models import word2vec as w2v  # noqa: E402
 from glint_word2vec_tpu.models.word2vec import Word2VecModel  # noqa: E402
 from glint_word2vec_tpu.obs.spans import default_tracer  # noqa: E402
+from glint_word2vec_tpu.ops import scan  # noqa: E402
 from glint_word2vec_tpu.ops import subword as ops_sw  # noqa: E402
 
 V, K, D, NUM = 3000, 500, 24, 5
@@ -180,12 +180,12 @@ def test_eight_mixed_batch_sizes_of_one_tile_share_one_program(world, monkeypatc
     block with it: sizes 9 to 16, each with unseen strings, are one program."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     base = [world.strings[i] for i in range(40, 56)]
-    before = w2v._gather_topk_batch._cache_size()
+    before = scan._gather_topk_batch._cache_size()
     for size in range(9, 17):
         queries = base[:size - 2] + ["zzqx", world.strings[size] + "x"]
         for query, reply in zip(queries, world.model.find_synonyms_batch(queries, NUM)):
             world.holds(query, reply)
-    assert w2v._gather_topk_batch._cache_size() == before + 1
+    assert scan._gather_topk_batch._cache_size() == before + 1
 
 
 def test_the_list_capacity_is_derived_and_a_longer_list_goes_round(world):
@@ -369,12 +369,12 @@ def test_a_chunk_without_lists_lowers_to_the_parents_text(queries, vectors):
     were lists: no operand, no instruction more (sgns-3m-300.query-closed64)."""
     import hashlib
     spec = jax.ShapeDtypeStruct
-    text = w2v._gather_topk_batch.lower(
+    text = scan._gather_topk_batch.lower(
         spec((1733, 16), jnp.float32), spec((1733,), jnp.float32),
         spec((queries,), jnp.int32),
         spec((queries, 16), jnp.float32) if vectors else None, 11, 1733, False).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT_SCAN_TEXT[queries, vectors]
-    mixed = w2v._gather_topk_batch.lower(
+    mixed = scan._gather_topk_batch.lower(
         spec((1733, 16), jnp.float32), spec((1733,), jnp.float32),
         spec((queries,), jnp.int32), None, 11, 1733, False,
         spec((500, 128), jnp.float32), spec((queries, 32), jnp.int32)).as_text()
