@@ -387,13 +387,13 @@ def cbow_step_banded_core(
         elif token_runs is None:
             new_syn0 = syn0.at[tokens].add(d_ctx.astype(dtype))
         else:
-            new_syn0, syn0_rows = scatter_add_by_runs(
+            new_syn0, syn0_rows, _ = scatter_add_by_runs(
                 syn0, tok_i, d_ctx, max_run, cap0, sort=True)
     with jax.named_scope("cbow.scatter_syn1"):
         if token_runs is None:
             new_syn1 = syn1.at[tokens].add(d_out.astype(dtype))
         else:
-            new_syn1, syn1_rows = scatter_add_by_runs(
+            new_syn1, syn1_rows, _ = scatter_add_by_runs(
                 syn1, tok_i, d_out, max_run, cap1, sort=True, keep=live > 0)
         new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
     if stabilizers is not None and stabilizers.post_pass:

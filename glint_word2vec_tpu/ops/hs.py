@@ -363,7 +363,7 @@ def hs_step_core(
             new_syn0 = syn0.at[centers].add(d_in.astype(syn0.dtype))
             syn0_rows = jnp.float32(n)
         else:
-            new_syn0, syn0_rows = scatter_add_by_runs(
+            new_syn0, syn0_rows, _ = scatter_add_by_runs(
                 syn0, centers, d_in, *center_runs)
     pairs = mask.sum()
     if with_metrics:

@@ -28,7 +28,7 @@ unlike the reference's accepted Hogwild races (README.md:17-19).
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -227,7 +227,8 @@ def plan_runs(idx: jax.Array, max_run: int, sort: bool = False,
 def compact_heads(plan: RunPlan, cap: int) -> RunPlan:
     """``plan`` with its heads compacted to a static ``cap`` places (a sort of
     their positions; ``jnp.nonzero`` is itself a 65,536-row scatter); the plan
-    itself where it already holds them."""
+    itself where it already holds them (under a ladder of caps: its last
+    rung's, which every rung before it is a prefix of)."""
     if plan.live is not None:
         return plan
     n = plan.at.shape[0]
@@ -235,13 +236,27 @@ def compact_heads(plan: RunPlan, cap: int) -> RunPlan:
     return plan._replace(live=live, src=jnp.minimum(live, n - 1))
 
 
+def _ladder(cap) -> Tuple[int, ...]:
+    """A cap as the ascending ladder of caps it stands for: one rung for an int."""
+    return (cap,) if isinstance(cap, int) else tuple(cap)
+
+
+def ladder_rung(plan: RunPlan, caps: Tuple[int, ...]) -> jax.Array:
+    """The first rung of the ascending ``caps`` that holds ``plan``'s heads
+    (int32 scalar: the count of rungs they exceed; ``len(caps)`` = none does)."""
+    return sum((plan.heads > cap).astype(jnp.int32) for cap in caps)
+
+
 def scatter_add_by_runs(mat: jax.Array, idx: jax.Array, rows: jax.Array,
-                        max_run: int, cap: int, sort: bool = False,
+                        max_run: int, cap: Union[int, Tuple[int, ...]],
+                        sort: bool = False,
                         keep: Optional[jax.Array] = None,
                         plan: Optional[RunPlan] = None,
-                        ) -> Tuple[jax.Array, jax.Array]:
+                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``mat.at[idx].add(rows)`` that hands the scatter ONE row per run of
-    equal neighbouring ``idx``: ``(new_mat, rows_handed_over)``.
+    equal neighbouring ``idx``: ``(new_mat, rows_handed_over, slots)``, the
+    live rows the scatter was handed and the static rows, padding and all, of
+    the entry the batch took.
 
     XLA's TPU scatter is priced per update row, ~100 ns each into
     f32[3000000,384], whether rows repeat or are dropped out of bounds (PERF.md
@@ -263,6 +278,16 @@ def scatter_add_by_runs(mat: jax.Array, idx: jax.Array, rows: jax.Array,
     either way, in the order the batch holds them (the sort is stable);
     coalesced, the float additions run over the run first, then into the row.
 
+    ``cap`` as an ascending tuple is a ladder of caps, and the padding is
+    priced like the live rows, so the step takes the FIRST rung that holds
+    its batch's heads (:func:`ladder_rung`): one flat ``lax.switch`` whose
+    entry *i* is the coalesced scatter at ``cap[i]`` and whose last entry is
+    the plain scatter. The heads are compacted once, to the last rung, and
+    every rung reads a prefix; a row receives the same run sums in the same
+    order on every rung. ``slots`` is ``cap[i]`` of the entry the batch TOOK,
+    N for the plain one: what the scatter's time follows. A ladder of one
+    rung is the int's program.
+
     ``keep`` (bool [N], with ``sort``): the entries whose ``rows`` are not
     zero by construction (the banded CBOW step's slots that train an example,
     four fifths of a block). The others sort last under a key no word has and
@@ -273,28 +298,43 @@ def scatter_add_by_runs(mat: jax.Array, idx: jax.Array, rows: jax.Array,
     and ``keep``, where the caller made it already (a step whose gather goes
     by the same runs); made here where not."""
     n, v = idx.shape[0], mat.shape[0]
+    caps = _ladder(cap)
     if plan is None:
         plan = plan_runs(idx, max_run, sort, keep)
 
-    def coalesced(mat):
-        by_run = rows if plan.order is None else rows[plan.order]
-        sums = run_sums(by_run, plan.pos, max_run, mat.dtype)
-        at_heads = compact_heads(plan, cap)
-        return mat.at[jnp.where(at_heads.live < n, plan.keys[at_heads.src], v)
-                      ].add(sums[at_heads.src], mode="drop")
+    def coalesced(rung: int):
+        def entry(mat):
+            by_run = rows if plan.order is None else rows[plan.order]
+            sums = run_sums(by_run, plan.pos, max_run, mat.dtype)
+            at_heads = compact_heads(plan, rung)
+            live, src = at_heads.live[:rung], at_heads.src[:rung]
+            return mat.at[jnp.where(live < n, plan.keys[src], v)
+                          ].add(sums[src], mode="drop")
+        return entry
 
     def plain(mat):
         return mat.at[idx].add(rows.astype(mat.dtype))
 
-    fits = plan.heads <= cap
-    return (jax.lax.cond(fits, coalesced, plain, mat),
-            jnp.where(fits, plan.heads, n).astype(jnp.float32))
+    if len(caps) == 1:
+        fits = plan.heads <= caps[0]
+        new_mat = jax.lax.cond(fits, coalesced(caps[0]), plain, mat)
+        slots = jnp.where(fits, caps[0], n)
+    else:
+        # the one sort of the heads' places, ahead of the switch
+        plan = compact_heads(plan, caps[-1])
+        rung = ladder_rung(plan, caps)
+        fits = rung < len(caps)
+        new_mat = jax.lax.switch(
+            rung, [coalesced(c) for c in caps] + [plain], mat)
+        slots = jnp.asarray(caps + (n,))[rung]
+    return (new_mat, jnp.where(fits, plan.heads, n).astype(jnp.float32),
+            slots.astype(jnp.float32))
 
 
 def gather_by_runs(tables, dtype: jnp.dtype) -> Tuple[tuple, jax.Array]:
     """``mat[idx].astype(dtype)`` for every ``(mat, idx, plan, cap)`` of
     ``tables``, made from ONE gathered row per piece of ``plan``'s runs:
-    ``(the [N, D] blocks, whether the batch went by runs)``.
+    ``(the [N, D] blocks, the rows handed to their assembly)``.
 
     Over a model axis a table's rows lie on several chips, and a gather of
     ``mat[idx]`` is assembled by an all-reduce of the whole ``[N, D]`` block,
@@ -308,32 +348,49 @@ def gather_by_runs(tables, dtype: jnp.dtype) -> Tuple[tuple, jax.Array]:
     of N ids is priced like a row scatter on the TPU, PERF.md §6, PR 28). A
     piece of a cut run gathers its row again: the cap counts pieces.
 
-    One conditional holds every table's gather, so the compiler can combine
-    the branch's all-reduces: a batch with more pieces than a table's ``cap``
-    takes the plain gathers, the same ops as without this function. The rows
-    are the same either way, bit for bit (the other chips add zeros). Plans
-    made with ``keep`` are not for this: an entry left out has no piece."""
+    One flat switch holds every table's gather, so the compiler can combine
+    an entry's all-reduces. ``cap`` is an int or an ascending ladder of caps
+    (:func:`scatter_add_by_runs`; a shorter ladder repeats its last rung):
+    entry *i* gathers ``[cap[i], D]`` of every table, the batch takes the
+    first whose rungs hold every table's pieces, and one with more pieces
+    than a table's last rung takes the plain gathers, the same ops as without
+    this function. The rows are the same either way, bit for bit (the other
+    chips add zeros). The second result counts what crossed the mesh: the
+    entry's caps summed (float32 scalar), ``N`` a table on the plain one.
+    Plans made with ``keep`` are not for this: an entry left out has no piece."""
     mats = tuple(mat for mat, _, _, _ in tables)
+    ladders = [_ladder(cap) for _, _, _, cap in tables]
+    rungs = max(len(caps) for caps in ladders)
+    ladders = [caps + caps[-1:] * (rungs - len(caps)) for caps in ladders]
+    # each table's one sort of its heads' places, ahead of the switch
+    plans = [compact_heads(plan, caps[-1])
+             for (_, _, plan, _), caps in zip(tables, ladders)]
 
-    def by_runs(mats):
-        out = []
-        for mat, (_, _, plan, cap) in zip(mats, tables):
-            # the padding reads the last entry's row, which no piece names
-            block = mat[plan.keys[compact_heads(plan, cap).src]].astype(dtype)
-            piece = jnp.cumsum(plan.head.astype(jnp.int32)) - 1
-            if plan.order is not None:
-                _, piece = jax.lax.sort((plan.order, piece), num_keys=1)
-            out.append(block[piece])
-        return tuple(out)
+    def by_runs(at: int):
+        def entry(mats):
+            out = []
+            for mat, plan, caps in zip(mats, plans, ladders):
+                # the padding reads the last entry's row, which no piece names
+                block = mat[plan.keys[plan.src[:caps[at]]]].astype(dtype)
+                piece = jnp.cumsum(plan.head.astype(jnp.int32)) - 1
+                if plan.order is not None:
+                    _, piece = jax.lax.sort((plan.order, piece), num_keys=1)
+                out.append(block[piece])
+            return tuple(out)
+        return entry
 
     def plain(mats):
         return tuple(mat[idx].astype(dtype)
                      for mat, (_, idx, _, _) in zip(mats, tables))
 
-    fits = jnp.bool_(True)
-    for _, _, plan, cap in tables:
-        fits = fits & (plan.heads <= cap)
-    return jax.lax.cond(fits, by_runs, plain, mats), fits
+    rung = jnp.int32(0)
+    for plan, caps in zip(plans, ladders):
+        rung = jnp.maximum(rung, ladder_rung(plan, caps))
+    blocks = jax.lax.switch(
+        rung, [by_runs(at) for at in range(rungs)] + [plain], mats)
+    handed = [sum(caps[at] for caps in ladders) for at in range(rungs)]
+    handed.append(sum(idx.shape[0] for _, idx, _, _ in tables))
+    return blocks, jnp.asarray(handed, jnp.float32)[rung]
 
 
 class EmbeddingPair(NamedTuple):
@@ -386,6 +443,12 @@ class StepMetrics(NamedTuple):
     # and the pool where the batch went by runs, 2B + P where it did not; None
     # where that form is not compiled
     assembly_rows: Optional[jax.Array] = None
+    # static rows each table's scatter was handed, padding and all (the
+    # shared-pool SGNS step: the cap of the rung its batch took,
+    # scatter_add_by_runs; B plain): what the scatter's time follows, where
+    # ``syn0_rows`` / ``syn1_rows`` count the live ones; None = not counted
+    syn0_slots: Optional[jax.Array] = None
+    syn1_slots: Optional[jax.Array] = None
 
 
 def init_embeddings(
@@ -838,14 +901,13 @@ def sgns_step_shared_core(
         if assemble_by_runs:
             # the plans the two scatters go by, compacted here once for both
             (run0, cap0), (run1, cap1) = center_runs, context_runs
-            plan0 = compact_heads(plan_runs(centers, run0), cap0)
-            plan1 = compact_heads(plan_runs(contexts, run1, sort=True), cap1)
-            (e_in, e_pos), by_runs = gather_by_runs(
+            plan0 = compact_heads(plan_runs(centers, run0), _ladder(cap0)[-1])
+            plan1 = compact_heads(plan_runs(contexts, run1, sort=True),
+                                  _ladder(cap1)[-1])
+            (e_in, e_pos), handed = gather_by_runs(
                 ((syn0, centers, plan0, cap0), (syn1, contexts, plan1, cap1)),
                 compute_dtype)
-            B, P = centers.shape[0], negatives.shape[0]
-            assembly_rows = jnp.where(
-                by_runs, cap0 + cap1 + P, 2 * B + P).astype(jnp.float32)
+            assembly_rows = handed + negatives.shape[0]
         else:
             if subword is None:
                 e_in = syn0[centers].astype(compute_dtype)      # [B, D]
@@ -890,7 +952,7 @@ def sgns_step_shared_core(
         d_pos = clip_update_rows(d_pos, stabilizers.update_clip)
 
     dtype = syn0.dtype
-    subword_rows = subword_slots = None
+    subword_rows = subword_slots = syn0_slots = None
     with jax.named_scope("sgns.scatter_syn0"):
         if subword is not None:
             new_syn0 = sw.scatter_center_updates(
@@ -901,14 +963,14 @@ def sgns_step_shared_core(
             subword_slots = sw.scatter_slots(sw_plan, sw_shape)
         elif center_runs is None:
             new_syn0 = syn0.at[centers].add(d_in.astype(dtype))
-            syn0_rows = jnp.float32(centers.shape[0])
+            syn0_rows = syn0_slots = jnp.float32(centers.shape[0])
         else:
-            new_syn0, syn0_rows = scatter_add_by_runs(
+            new_syn0, syn0_rows, syn0_slots = scatter_add_by_runs(
                 syn0, centers, d_in, *center_runs, plan=plan0)
     with jax.named_scope("sgns.scatter_syn1"):
         if context_runs is None:
             new_syn1 = syn1.at[contexts].add(d_pos.astype(dtype))
-            syn1_rows = jnp.float32(contexts.shape[0])
+            syn1_rows = syn1_slots = jnp.float32(contexts.shape[0])
         else:
             # a conditional updates its table in place only where every read
             # of that table is ordered before it. d_pos does not depend on the
@@ -916,7 +978,7 @@ def sgns_step_shared_core(
             # copies syn1 into the branch and back, 14 ms a step at V = 3M
             # (PERF.md §6, PR 30)
             d_pos, _ = jax.lax.optimization_barrier((d_pos, Z))
-            new_syn1, syn1_rows = scatter_add_by_runs(
+            new_syn1, syn1_rows, syn1_slots = scatter_add_by_runs(
                 syn1, contexts, d_pos, *context_runs, sort=True, plan=plan1)
         new_syn1 = new_syn1.at[negatives].add(d_Z.astype(dtype))
     if stabilizers is not None and stabilizers.post_pass:
@@ -945,6 +1007,9 @@ def sgns_step_shared_core(
         subword_rows=subword_rows,
         subword_slots=subword_slots,
         assembly_rows=assembly_rows,
+        syn0_slots=syn0_slots,
+        # the subword step reports its lists' slots (above), not its tables'
+        syn1_slots=None if subword is not None else syn1_slots,
     )
     return EmbeddingPair(new_syn0, new_syn1), metrics
 

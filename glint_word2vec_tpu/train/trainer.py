@@ -22,7 +22,7 @@ from __future__ import annotations
 import logging
 import time
 from dataclasses import dataclass, replace as dc_replace
-from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -76,21 +76,46 @@ def _cbow_examples_per_kept_token(window: int) -> float:
     return max((window - 1) / window, 1e-3)
 
 
-def _center_run_cap(window: int, batch: int) -> int:
-    """Static row cap of the step's coalesced syn0 scatter
-    (ops/sgns.scatter_add_by_runs), 0 = do not build it. The pair feed emits a
-    kept token's pairs consecutively, so a batch holds one center run per
-    token that emits any pair: (window−1)/window of the kept tokens over
+def _center_run_cap(window: int, batch: int) -> Tuple[int, ...]:
+    """Static row caps of the step's coalesced syn0 scatter
+    (ops/sgns.scatter_add_by_runs), an ascending ladder the step takes the
+    first fitting rung of, batch by batch; () = do not build it. The pair feed
+    emits a kept token's pairs consecutively, so a batch holds one center run
+    per token that emits any pair: (window−1)/window of the kept tokens over
     :func:`_pairs_per_kept_token` pairs each, 0.25 runs a pair at window 5
-    (0.262 measured: sentence ends clip windows). The cap is that share with
-    40% of room, in eighths of the batch (24,576 of 65,536 at window 5); where
-    a run holds two pairs or fewer on average (window ≤ 2) it is not built."""
+    (0.262 measured: sentence ends clip windows). The LAST rung is that share
+    with 40% of room, in eighths of the batch (24,576 of 65,536 at window 5):
+    the estimate knows nothing of the corpus, and one of short sentences
+    (queries, titles) makes more runs a pair. The scatter is priced by the
+    rows it is handed, padding too, so a tight rung stands before it
+    (:func:`_run_ladder`: 18,432). Where a run holds two pairs or fewer on
+    average (window ≤ 2) nothing is built."""
     runs_per_pair = (_cbow_examples_per_kept_token(window)
                      / _pairs_per_kept_token(window))
     if runs_per_pair > 0.5 or batch < 8:
-        return 0
+        return ()
     eighth = batch // 8
-    return -(-int(1.4 * runs_per_pair * batch) // eighth) * eighth
+    return _run_ladder(runs_per_pair * batch, batch,
+                       -(-int(1.4 * runs_per_pair * batch) // eighth) * eighth)
+
+
+def _last_rung(caps: Tuple[int, ...]) -> int:
+    """The roomy cap of a ladder alone, 0 = none built: what the rows whose
+    scatter takes one cap are handed (hierarchical softmax, subword)."""
+    return caps[-1] if caps else 0
+
+
+def _run_ladder(heads: float, batch: int, roomy: int) -> Tuple[int, ...]:
+    """``(tight, roomy)``: before a coalesced scatter's ``roomy`` cap a rung
+    for the ``heads`` expected of a batch with 15% of room, to the NEAREST
+    unit (:func:`_nearest_units`, and for its reason: every seed compiles one
+    program): 2,100 feed batches of five seeds hold 16,901-17,405 center
+    heads and 16,425-16,806 (V = 3M) / 16,635-17,141 (10M) context heads
+    under 18,432 of 65,536 (my CPU count, PERF.md §6, PR 58). A batch over it
+    pays the roomy rung's price, as every batch did before. One rung where the
+    tight one would not be under the roomy one, or the batch has no units."""
+    tight = _nearest_units(heads, batch, 1.15)[0] if batch >= 32 else 0
+    return (tight, roomy) if 0 < tight < roomy else (roomy,)
 
 
 # pairs in a piece of a context run (ops/sgns.run_sums makes one shifted add
@@ -135,25 +160,29 @@ def _heads_by_word(p: np.ndarray, draws: float, entries: float,
 
 
 def _context_run_cap(counts: np.ndarray, train_words_count: int,
-                     subsample_ratio: float, window: int, batch: int) -> int:
-    """Static row cap of the step's coalesced syn1 scatter
-    (ops/sgns.scatter_add_by_runs on the batch sorted by context), 0 = do not
-    build it. Unlike center runs, context runs are a property of the corpus:
-    sorted by context a batch holds one run per distinct context word, cut
-    every :data:`_CONTEXT_MAX_RUN` pairs (:func:`_expected_heads`: its
-    contexts are its kept tokens, ``batch`` / :func:`_pairs_per_kept_token`
-    of them, over ``batch`` pairs). At V = 3M / 10M that reads 16,218 /
-    16,472 where feed batches hold 16,440-17,050 (sentence ends clip windows,
-    so a batch holds ~5% more tokens): 20% of room, in sixteenths of the batch
-    (20,480 of 65,536). An estimate over half the batch builds nothing."""
+                     subsample_ratio: float, window: int,
+                     batch: int) -> Tuple[int, ...]:
+    """Static row caps of the step's coalesced syn1 scatter
+    (ops/sgns.scatter_add_by_runs on the batch sorted by context), a ladder as
+    :func:`_center_run_cap`'s; () = do not build it. Unlike center runs,
+    context runs are a property of the corpus: sorted by context a batch
+    holds one run per distinct context word, cut every
+    :data:`_CONTEXT_MAX_RUN` pairs (:func:`_expected_heads`: its contexts are
+    its kept tokens, ``batch`` / :func:`_pairs_per_kept_token` of them, over
+    ``batch`` pairs). At V = 3M / 10M that reads 16,218 / 16,472 where feed
+    batches hold 16,440-17,050 (sentence ends clip windows, so a batch holds
+    ~5% more tokens). The LAST rung has 20% of room, in sixteenths of the
+    batch (20,480 of 65,536), the tight one before it 15% to the nearest unit
+    (:func:`_run_ladder`: 18,432). An estimate over half the batch builds
+    nothing."""
     heads = None if batch < 16 else _expected_heads(
         counts, train_words_count, subsample_ratio,
         batch / _pairs_per_kept_token(window), batch, _CONTEXT_MAX_RUN)
     if heads is None:
-        return 0
+        return ()
     sixteenth = batch // 16
     cap = -(-int(1.2 * heads) // sixteenth) * sixteenth
-    return cap if cap <= batch // 2 else 0
+    return _run_ladder(heads, batch, cap) if cap <= batch // 2 else ()
 
 
 # tokens in a piece of a word's run in a banded CBOW block sorted by word:
@@ -244,15 +273,16 @@ def _word_cap(counts: np.ndarray, train_words_count: int,
     return cap if cap <= 0.8 * run_cap else 0
 
 
-def _nearest_units(live: float, slots: int) -> Tuple[int, int]:
+def _nearest_units(live: float, slots: int,
+                   room: float = 1.2) -> Tuple[int, int]:
     """``live`` expected live slots of a block of ``slots`` (32 or more) with
-    20% of room, to the NEAREST unit, and the unit: the power of two at or
-    under a 32nd of the block's slots, so 15-25% of room. Nearest and not up:
-    the estimate moves a fraction of a percent with the seed's strings, and
-    rounding up gives some seeds a unit more, another program (PERF.md §6,
-    PR 36)."""
+    20% of room (``room``: 1.2), to the NEAREST unit, and the unit: the power
+    of two at or under a 32nd of the block's slots, so 15-25% of room. Nearest
+    and not up: the estimate moves a fraction of a percent with the seed's
+    strings, and rounding up gives some seeds a unit more, another program
+    (PERF.md §6, PR 36)."""
     unit = 1 << ((slots // 32).bit_length() - 1)
-    return int(1.2 * live / unit + 0.5) * unit, unit
+    return int(room * live / unit + 0.5) * unit, unit
 
 
 def _live_slot_cap(live: float, slots: int) -> int:
@@ -386,10 +416,12 @@ class StepChoice(NamedTuple):
     step: Callable
     # (K, B) -> one chunk's negatives; None: the step samples nothing
     neg_shape: Optional[Callable[[int, int], Tuple[int, ...]]]
-    # (max_run, cap) where syn0's update goes to the scatter by center runs
-    center_runs: Optional[Tuple[int, int]]
+    # (max_run, cap) where syn0's update goes to the scatter by center runs:
+    # the cap an ascending ladder on the shared-pool SGNS row, whose step
+    # takes a rung per batch; one int on the subword and path rows
+    center_runs: Optional[Tuple[int, Union[int, Tuple[int, ...]]]]
     # the same for syn1's context update, by runs of the batch sorted by context
-    context_runs: Optional[Tuple[int, int]] = None
+    context_runs: Optional[Tuple[int, Union[int, Tuple[int, ...]]]] = None
     # (max_run, syn0's cap, syn1's cap) where the banded CBOW step's two token
     # scatters go by runs of the block's tokens sorted by word
     token_runs: Optional[Tuple[int, int, int]] = None
@@ -442,7 +474,8 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
                              negatives array (``neg_shape`` None)    fused_logits, bf16_chain, every
                                                                      stabilizer, n != 0, P > 0
 
-    ``context_cap`` is :func:`_context_run_cap` of the trainer's vocabulary,
+    ``context_cap`` is :func:`_context_run_cap` of the trainer's vocabulary
+    (a ladder; the last row takes it whole, the subword row its last rung),
     ``stabilizers`` is the trainer's state (None = all off), ``with_metrics``
     the twin; the rows without a ``with_metrics`` form have one twin.
     ``subword_shape`` is the trainer's :class:`..ops.subword.SubwordShape`
@@ -508,14 +541,16 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
     # syn0's update, one scatter row per center run (the step chooses per
     # batch; ops/sgns.scatter_add_by_runs), where one program sees the batch
     # whole: a batch split over a data axis or fed in per-process segments
-    # cuts runs at every seam. The shared-pool row's and the path row's
-    runs = None
+    # cuts runs at every seam. The shared-pool row's (the ladder of caps) and
+    # the path row's (its last rung: a switch around that step's in-place
+    # scatter is A12's to compile, ops/hs.py)
+    caps = ()
     if plan.num_data == 1 and feed_segments == 1:
-        cap = _center_run_cap(cfg.window, cfg.pairs_per_batch)
-        runs = (2 * cfg.window, cap) if cap else None
+        caps = _center_run_cap(cfg.window, cfg.pairs_per_batch)
 
     if cfg.loss == "hs":
         from glint_word2vec_tpu.ops.hs import hs_step_core
+        runs = (2 * cfg.window, _last_rung(caps)) if caps else None
 
         def step(params, batch, negatives, alpha):
             return hs_step_core(
@@ -565,6 +600,11 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
         context_runs = (_CONTEXT_MAX_RUN, context_cap)
 
     if subword_shape is not None:
+        # one cap a scatter: a switch beside that step's own stands at the
+        # edge of the chip's memory (A11's to compile)
+        context_runs = context_runs and (_CONTEXT_MAX_RUN,
+                                         _last_rung(context_cap))
+
         def step(params, batch, negatives, alpha):
             return sgns_step_shared_core(
                 params, batch["centers"], batch["contexts"], batch["mask"],
@@ -584,6 +624,7 @@ def select_step(cfg: Word2VecConfig, plan: MeshPlan, feed_segments: int,
     # 35% of the bytes at window 5). On one chip nothing is assembled and the
     # expansion costs what the smaller gather saves (PERF.md §6, PR 49): the
     # step there is the one it was
+    runs = (2 * cfg.window, caps) if caps else None
     assemble = bool(plan.num_model > 1 and runs and context_runs)
 
     def step(params, batch, negatives, alpha):
@@ -1152,7 +1193,7 @@ class Trainer:
         else:
             # center runs as the plain step's (one head per run of a center's
             # pairs); where none are built every pair is its own head
-            cap = (_center_run_cap(cfg.window, cfg.pairs_per_batch)
+            cap = (_last_rung(_center_run_cap(cfg.window, cfg.pairs_per_batch))
                    if self.plan.num_data == 1 else 0)
             # and under them one head per distinct word of the batch's
             # centers, where the vocabulary's counts promise fewer
@@ -1448,7 +1489,7 @@ class Trainer:
         construction, and again when a recovery engages ``max_row_norm``)."""
         cfg = self.config
         # select_step's SGNS shared-pool row reads it; CBOW has no such row
-        self._context_cap = 0 if cfg.cbow or cfg.loss == "hs" else _context_run_cap(
+        self._context_cap = () if cfg.cbow or cfg.loss == "hs" else _context_run_cap(
             self.vocab.counts, self.vocab.train_words_count,
             cfg.subsample_ratio, cfg.window, cfg.pairs_per_batch)
         # select_step's banded CBOW row reads it; no other row has a token block
@@ -2884,12 +2925,13 @@ class Trainer:
             with self._tracer.span("device_block") as blocked:
                 (loss_k, fpos_k, pairs_k, rows0_k, rows1_k, rows_sw_k,
                  slots_sw_k, gather_sw_k, nodes_hs_k, assembly_k,
-                 pos) = jax.device_get(
+                 slots0_k, slots1_k, pos) = jax.device_get(
                     (metrics.loss, metrics.mean_f_pos, metrics.pairs,
                      metrics.syn0_rows, metrics.syn1_rows,
                      metrics.subword_rows, metrics.subword_slots,
                      metrics.subword_gather_slots, metrics.hs_nodes,
-                     metrics.assembly_rows, self.params.pos))
+                     metrics.assembly_rows, metrics.syn0_slots,
+                     metrics.syn1_slots, self.params.pos))
                 if pairs_k[real - 1] > 0:
                     # how far the step coalesced each table's update: 1.0
                     # plain, heads over pairs where runs were summed first
@@ -2899,10 +2941,16 @@ class Trainer:
                     # alone beside the token row source)
                     # and the forward assembly over a model axis, where the
                     # step gathers by the same runs: both caps and the pool
-                    # over the pairs (~0.72), 2B + P over them plain (2.03)
+                    # over the pairs (~0.59 on the tight rung, ~0.72 on the
+                    # roomy one), 2B + P over them plain (2.03);
+                    # and the static rows each scatter was handed, padding
+                    # and all (the shared-pool SGNS step: the cap of the rung
+                    # the batch took, ~0.28 tight, 0.375 / 0.3125 roomy)
                     for name, rows_k in (("syn0_rows_per_pair", rows0_k),
                                          ("syn1_rows_per_pair", rows1_k),
-                                         ("assembly_rows_per_pair", assembly_k)):
+                                         ("assembly_rows_per_pair", assembly_k),
+                                         ("syn0_slots_per_pair", slots0_k),
+                                         ("syn1_slots_per_pair", slots1_k)):
                         if rows_k is not None:
                             blocked.set(**{name: float(
                                 rows_k[real - 1] / pairs_k[real - 1])})

@@ -23,6 +23,9 @@ V, D, B, P, K = 3_000_000, 384, 65536, 2048, 2
 # what the trainer derives at this size (tests/test_coalesce_runs.py,
 # tests/test_step_selection.py hold the derivations)
 RUNS = dict(center_runs=(10, 24576), context_runs=(6, 20480))
+# the same as the shared-pool SGNS row is handed them since PR 58: a ladder of
+# caps a scatter, whose first fitting rung the step takes batch by batch
+LADDERS = dict(center_runs=(10, (18432, 24576)), context_runs=(6, (18432, 20480)))
 
 # wiki.en's shape (subword-nn-2.5m-300, subword-sentvec-2.5m-300): words,
 # bucket rows, trained width, groups of the row table

@@ -507,7 +507,15 @@ def test_masked_slots_and_the_lane_padding_stay_zero(slot_cap, tail_cap):
 # 8,192 of 16,384 slots, a fourth entry of the scatter's switch; and the
 # counter `StepMetrics.subword_slots`): those two are PR 47's tree's. The token
 # block's row source shares `spread_sorted` and `scatter_slots` and took none
-# of it: the other eight are as they were.
+# of it: the other eight are as they were. PR 58 changed the shared-pool SGNS
+# step on purpose (each coalesced scatter under a ladder of two caps, one flat
+# switch of three entries, which the trainer's rule derives at the tiny sizes
+# too: (576, 768) and (640, 768) of 2,048 pairs; and the counters
+# `StepMetrics.syn0_slots` / `.syn1_slots`): `sgns-3m-300.train`'s two are PR
+# 58's tree's. The CBOW, subword, subword-CBOW and hierarchical-softmax steps
+# are handed one cap a scatter as before and share the helper
+# (`scatter_add_by_runs` returns the slots beside the rows; unread, they leave
+# no op): the other eight are as they were.
 PARENT_STEP_TEXT = {
     ("cbow-3m-300.train", "train_cbow", "_step_fn"): "b004263a353a0230",
     ("cbow-3m-300.train", "train_cbow", "_step_fn_fast"): "93b9ca55222cf02a",
@@ -515,8 +523,8 @@ PARENT_STEP_TEXT = {
     ("subword-sgns-2.5m-300.train", "train_subword", "_step_fn_fast"): "22b45970317cbaaf",
     ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn"): "3652dd4987fcefa7",
     ("cbow-subword-2m-300.train", "train_cbow_subword", "_step_fn_fast"): "b4492ee4d2d96c50",
-    ("sgns-3m-300.train", "train", "_step_fn"): "f7f4fe22a5785c49",
-    ("sgns-3m-300.train", "train", "_step_fn_fast"): "b994716300289c09",
+    ("sgns-3m-300.train", "train", "_step_fn"): "8ce8095e605b956f",
+    ("sgns-3m-300.train", "train", "_step_fn_fast"): "2f1ac1ca7cdea1fb",
     ("skipgram-hs-3m-300.train", "train_hs", "_step_fn"): "1ca84c1f23489d27",
     ("skipgram-hs-3m-300.train", "train_hs", "_step_fn_fast"): "6841e75acfc947f2",
 }

@@ -47,6 +47,7 @@ from glint_word2vec_tpu.train.trainer import (
     _WORD_MAX_RUN,
     _center_run_cap,
     _context_run_cap,
+    _last_rung,
     _slot_cap,
     _tail_cap,
     _token_run_caps,
@@ -63,12 +64,16 @@ from reference import sgns_ref  # noqa: E402
 
 V, D, B, P, WINDOW = 2000, 24, 1024, 32, 5
 MAX_RUN = 2 * WINDOW
-CAP = _center_run_cap(WINDOW, B)
+# the ladder a trainer derives for syn0's scatter, and its last (roomy) rung:
+# the one cap of the cases that came before the ladder
+LADDER = _center_run_cap(WINDOW, B)
+CAP = LADDER[-1]
 ALPHA, NEG = 0.05, 5
 COUNTS = np.maximum(1e6 / (np.arange(V) + 10.0) ** 1.07, 5.0).astype(np.int64)
 # the context side's pair, as a trainer over this vocabulary derives it
 CTX_RUN = _CONTEXT_MAX_RUN
-CTX_CAP = _context_run_cap(COUNTS, int(COUNTS.sum()), 0.0, WINDOW, B)
+CTX_LADDER = _context_run_cap(COUNTS, int(COUNTS.sum()), 0.0, WINDOW, B)
+CTX_CAP = CTX_LADDER[-1]
 
 
 def _vocab_and_sentences(seed=0, n_tokens=40_000):
@@ -114,7 +119,7 @@ def _helper_against_add_at(idx, dtype, expect_rows=None, by_context=False):
     idx = jnp.asarray(idx, jnp.int32)
     rows = _rows(dtype)
     runs = (CTX_RUN, CTX_CAP, True) if by_context else (MAX_RUN, CAP, False)
-    got, handed = jax.jit(scatter_add_by_runs, static_argnums=(3, 4, 5))(
+    got, handed, _ = jax.jit(scatter_add_by_runs, static_argnums=(3, 4, 5))(
         jnp.zeros((V, D), jnp.float32), idx, rows, *runs)
     want = _add_at(idx, rows)
     # float32 sums of up to B rows: to rounding of the largest entry
@@ -272,11 +277,11 @@ def case_heads_over_cap_bit_equal(dtype):
     assert B // 2 > CAP
     rows = _rows(dtype)
     table = _tables().syn0
-    got, handed = jax.jit(scatter_add_by_runs, static_argnums=(3, 4))(
+    got, handed, slots = jax.jit(scatter_add_by_runs, static_argnums=(3, 4))(
         table, idx, rows, MAX_RUN, CAP)
     want = jax.jit(lambda t: t.at[idx].add(rows.astype(t.dtype)))(table)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    assert int(handed) == B
+    assert int(handed) == B == int(slots)
 
 
 def case_update_clip_and_duplicate_scaling(dtype):
@@ -428,7 +433,7 @@ def _kept_against_add_at(x, dtype, cap):
     idx = jnp.asarray(x, jnp.int32)
     keep = jnp.asarray(np.random.default_rng(13).random(B) < 0.8)
     rows = _rows(dtype) * keep[:, None].astype(dtype)
-    got, handed = jax.jit(scatter_add_by_runs, static_argnums=(3, 4, 5))(
+    got, handed, _ = jax.jit(scatter_add_by_runs, static_argnums=(3, 4, 5))(
         jnp.zeros((V, D), jnp.float32), idx, rows, CTX_RUN, cap, True, keep)
     return idx, keep, rows, got, int(handed)
 
@@ -455,7 +460,7 @@ def case_kept_entries_over_cap_bit_equal(dtype):
 
 def case_nothing_kept_hands_over_nothing(dtype):
     idx = jnp.asarray(feed_batches(1)[0][1], jnp.int32)
-    got, handed = jax.jit(scatter_add_by_runs, static_argnums=(3, 4, 5))(
+    got, handed, _ = jax.jit(scatter_add_by_runs, static_argnums=(3, 4, 5))(
         jnp.ones((V, D), jnp.float32), idx, jnp.zeros((B, D), dtype), CTX_RUN,
         CTX_CAP, True, jnp.zeros(B, bool))
     assert int(handed) == 0 and (np.asarray(got) == 1.0).all()
@@ -477,7 +482,7 @@ def _fit_with_and_without(dtype, cap_fn, arg):
         run_dir = tempfile.mkdtemp(prefix="coalesce_")
         cap = getattr(trainer_mod, cap_fn)
         if not coalesce:
-            setattr(trainer_mod, cap_fn, lambda *a: 0)
+            setattr(trainer_mod, cap_fn, lambda *a: ())
         try:
             t = Trainer(dc_replace(cfg, telemetry_path=os.path.join(run_dir, "run.jsonl")),
                         vocab)
@@ -525,11 +530,11 @@ def _gathered(idx, dtype, sort, went_by_runs):
 
     def both(mat):
         plan = plan_runs(idx, max_run, sort=sort)
-        (got,), by_runs = gather_by_runs(((mat, idx, plan, cap),), dtype)
-        return got, by_runs, mat[idx].astype(dtype)
+        (got,), handed = gather_by_runs(((mat, idx, plan, cap),), dtype)
+        return got, handed, mat[idx].astype(dtype)
 
-    got, by_runs, want = jax.jit(both)(table)
-    assert bool(by_runs) == went_by_runs
+    got, handed, want = jax.jit(both)(table)
+    assert float(handed) == (cap if went_by_runs else B)
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(want, np.float32))
 
@@ -624,6 +629,179 @@ def case_assembly_needs_both_runs(dtype):
                   assemble_by_runs=True, **kw)
 
 
+# ---- a ladder of caps: the step takes the first rung that holds its batch -----------------
+
+# (tight, roomy) and batches whose heads fall under the first rung, between
+# the two, and over both (the plain scatter)
+RUNGS = (200, 400)
+
+
+def _ladder_batch(heads, sort):
+    """B entries in ``heads`` pieces: runs of equal neighbours as they come,
+    or (``sort``) the same words scattered over the batch, where the helper's
+    own sort makes the runs (cut every CTX_RUN)."""
+    max_run = CTX_RUN if sort else MAX_RUN
+    words = np.sort(np.random.default_rng(heads).permutation(V)[:heads])
+    idx = words[np.arange(B) * heads // B]
+    # one run a word, none longer than a piece
+    assert -(-B // heads) <= max_run and len(np.unique(idx)) == heads
+    return np.random.default_rng(1).permutation(idx) if sort else idx
+
+
+def _ladder_against_add_at(dtype, sort, with_keep):
+    for heads, slots in ((180, RUNGS[0]), (200, RUNGS[0]), (201, RUNGS[1]),
+                         (300, RUNGS[1]), (400, RUNGS[1]), (401, B), (600, B)):
+        idx = jnp.asarray(_ladder_batch(heads, sort), jnp.int32)
+        rows = _rows(dtype)
+        keep = None
+        if with_keep:
+            # whole words left out, so the kept entries' pieces are counted
+            keep = jnp.asarray(np.asarray(idx) % 5 != 0)
+            rows = rows * keep[:, None].astype(dtype)
+        max_run = CTX_RUN if sort else MAX_RUN
+        got, handed, took = jax.jit(scatter_add_by_runs, static_argnums=(3, 4, 5))(
+            jnp.zeros((V, D), jnp.float32), idx, rows, max_run, RUNGS, sort, keep)
+        want = _add_at(idx, rows)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-6,
+                                   atol=2e-6 * np.abs(want).max())
+        if with_keep:
+            heads = len(np.unique(np.asarray(idx)[np.asarray(keep)]))
+            slots = RUNGS[0] if heads <= RUNGS[0] else RUNGS[1] if heads <= RUNGS[1] else B
+        # the live rows do not move with the rung; the slots are the rung's
+        assert int(handed) == (heads if slots < B else B) and int(took) == slots
+
+
+def case_ladder_runs_as_they_come_against_add_at(dtype):
+    _ladder_against_add_at(dtype, False, False)
+
+
+def case_ladder_sorted_against_add_at(dtype):
+    _ladder_against_add_at(dtype, True, False)
+
+
+def case_ladder_sorted_kept_entries_against_add_at(dtype):
+    _ladder_against_add_at(dtype, True, True)
+
+
+def case_ladder_rungs_hand_a_row_the_same_sums_bit_equal(dtype):
+    """Whatever the padding behind them: the table under the ladder is the
+    table under the rung's cap alone, and over both rungs the plain scatter's."""
+    table = _tables().syn0
+    rows = _rows(dtype)
+    for heads, cap in ((180, RUNGS[0]), (300, RUNGS[1]), (600, RUNGS[1])):
+        for sort in (False, True):
+            idx = jnp.asarray(_ladder_batch(heads, sort), jnp.int32)
+            max_run = CTX_RUN if sort else MAX_RUN
+            fn = jax.jit(scatter_add_by_runs, static_argnums=(3, 4, 5))
+            got = fn(table, idx, rows, max_run, RUNGS, sort)
+            want = fn(table, idx, rows, max_run, cap, sort)
+            np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+            assert float(got[1]) == float(want[1])
+    # the benchmark's check batches (rows that all differ) take the LAST entry
+    idx = jnp.asarray(np.random.default_rng(3).permutation(V)[:B], jnp.int32)
+    got, handed, took = jax.jit(scatter_add_by_runs, static_argnums=(3, 4))(
+        table, idx, rows, MAX_RUN, RUNGS)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(table.at[idx].add(rows.astype(table.dtype))))
+    assert int(handed) == B == int(took)
+
+
+def case_ladder_of_one_rung_is_the_ints_program(dtype):
+    idx = jnp.asarray(feed_batches(1)[0][0], jnp.int32)
+    args = (_tables().syn0, idx, _rows(dtype), MAX_RUN)
+    for sort in (False, True):
+        texts = {jax.jit(scatter_add_by_runs, static_argnums=(3, 4, 5)).lower(
+            *args, cap, sort).as_text() for cap in (CAP, (CAP,))}
+        assert len(texts) == 1
+    two = jax.jit(scatter_add_by_runs, static_argnums=(3, 4)).lower(*args, RUNGS).as_text()
+    # ONE flat switch of three entries, and one sort of the heads' places
+    assert two.count("stablehlo.case") == 1 and two.count("stablehlo.sort") == 1
+    assert len(texts | {two}) == 2
+
+
+def case_ladder_gather_by_runs_against_indexing(dtype):
+    """One switch for both tables: the batch takes the LARGER of their rungs,
+    a shorter ladder repeats its last, and the rows are ``mat[idx]``'s."""
+    tables = _tables()
+
+    def both(mats, c, x, caps0, caps1):
+        plan0, plan1 = plan_runs(c, MAX_RUN), plan_runs(x, CTX_RUN, sort=True)
+        got, handed = gather_by_runs(
+            ((mats[0], c, plan0, caps0), (mats[1], x, plan1, caps1)), dtype)
+        return got, handed, (mats[0][c].astype(dtype), mats[1][x].astype(dtype))
+
+    for heads0, heads1, caps0, caps1, crossed in (
+            (180, 180, RUNGS, RUNGS, 2 * RUNGS[0]),
+            (180, 300, RUNGS, RUNGS, 2 * RUNGS[1]),     # syn1's rung for both
+            (300, 180, RUNGS, RUNGS, 2 * RUNGS[1]),
+            (180, 600, RUNGS, RUNGS, 2 * B),            # over both: plain
+            (180, 350, RUNGS, (350,), RUNGS[0] + 350),  # one rung beside two
+            (300, 350, RUNGS, (350,), RUNGS[1] + 350),
+            (300, 351, RUNGS, (350,), 2 * B),
+            (180, 180, RUNGS[1], RUNGS[1], 2 * RUNGS[1])):     # ints
+        c = jnp.asarray(_ladder_batch(heads0, False), jnp.int32)
+        x = jnp.asarray(_ladder_batch(heads1, True), jnp.int32)
+        got, handed, want = jax.jit(both, static_argnums=(3, 4))(
+            tables[:2], c, x, caps0, caps1)
+        assert float(handed) == crossed
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                          np.asarray(w, np.float32))
+
+
+def _ladder_step_against_roomy(dtype, model_axis=False, **kw):
+    """The step under the trainer's ladders against the step under their last
+    rungs alone (the parent's): tables, loss and counters bit for bit, and the
+    slots say which rung each table's batch took."""
+    params = _tables()
+    if model_axis:
+        params = jax.device_put(params, make_mesh(1, 4).embedding)
+        kw = dict(kw, assemble_by_runs=True)
+    assert len(LADDER) == 2 and len(CTX_LADDER) == 2
+    slots = []
+    for c, x, mask, _ in _assembly_batches():
+        want, m0 = _step(params, c, x, mask, dtype, (MAX_RUN, CAP),
+                         context_runs=(CTX_RUN, CTX_CAP), **kw)
+        got, m1 = _step(params, c, x, mask, dtype, (MAX_RUN, LADDER),
+                        context_runs=(CTX_RUN, CTX_LADDER), **kw)
+        np.testing.assert_array_equal(np.asarray(got.syn0), np.asarray(want.syn0))
+        np.testing.assert_array_equal(np.asarray(got.syn1), np.asarray(want.syn1))
+        assert float(m1.loss) == float(m0.loss) and float(m1.pairs) == float(m0.pairs)
+        rows = float(m1.syn0_rows), float(m1.syn1_rows)
+        assert rows == (float(m0.syn0_rows), float(m0.syn1_rows))
+        took = float(m1.syn0_slots), float(m1.syn1_slots)
+        for live, slot, ladder in zip(rows, took, (LADDER, CTX_LADDER)):
+            assert slot == next((cap for cap in ladder if live <= cap), B)
+        # under one cap the counter reads that cap, or the batch
+        assert float(m0.syn0_slots) == (CAP if rows[0] <= CAP else B)
+        assert float(m0.syn1_slots) == (CTX_CAP if rows[1] <= CTX_CAP else B)
+        if model_axis:
+            rung = max(ladder.index(slot) if slot < B else 2
+                       for slot, ladder in zip(took, (LADDER, CTX_LADDER)))
+            assert float(m1.assembly_rows) == (
+                (LADDER + (B,))[rung] + (CTX_LADDER + (B,))[rung] + P)
+        slots.append(took)
+    # the feed's batches take the tight rung of both tables; the batches made
+    # to overflow take the roomy one or the plain scatter
+    assert slots[0] == slots[1] == (LADDER[0], CTX_LADDER[0])
+    assert {s[0] for s in slots} >= {LADDER[0], B} and (LADDER[0], B) in slots
+    return slots
+
+
+def case_ladder_step_is_the_roomy_steps_bit_equal(dtype):
+    _ladder_step_against_roomy(dtype)
+
+
+def case_ladder_step_on_a_model_axis_bit_equal(dtype):
+    _ladder_step_against_roomy(dtype, model_axis=True)
+
+
+def case_step_without_runs_hands_over_the_batch(dtype):
+    c, x = feed_batches(1)[0]
+    _, m = _step(_tables(), c, x, np.ones(B), dtype, None)
+    assert float(m.syn0_slots) == B == float(m.syn1_slots)
+
+
 CASES = {name[len("case_"):]: fn for name, fn in sorted(globals().items())
          if name.startswith("case_")}
 
@@ -634,13 +812,25 @@ def test_coalesced_update(case, dtype):
     CASES[case](dtype)
 
 
-def test_cap_is_derived_from_the_window():
-    # window 5: 0.25 runs a pair, 40% of room, in eighths of the batch
-    assert _center_run_cap(5, 65536) == 24576
-    assert _center_run_cap(5, 2048) == 768
-    # a run of two pairs or fewer: not built
-    assert _center_run_cap(1, 65536) == 0 and _center_run_cap(2, 65536) == 0
-    assert 0 < _center_run_cap(10, 65536) < _center_run_cap(3, 65536) < 65536
+# (window, batch) -> (tight, roomy). The roomy rung is what the rule gave as
+# its one cap before the ladder (0.25 runs a pair at window 5 with 40% of
+# room, in eighths of the batch); the tight one the same expectation with 15%
+# to the nearest 32nd (a power of two); () = a run of two pairs or fewer, or a
+# batch of a few pairs: not built; one rung where the batch has no 32nds
+@pytest.mark.parametrize("window, batch, ladder", [
+    (5, 65536, (18432, 24576)),
+    (5, 2048, (576, 768)),
+    (5, B, (288, 384)),
+    (1, 65536, ()),
+    (2, 65536, ()),
+    (3, 65536, (36864, 49152)),
+    (10, 65536, (8192, 16384)),
+    (5, 16, (6,)),
+    (5, 4, ()),
+])
+def test_cap_is_derived_from_the_window(window, batch, ladder):
+    assert _center_run_cap(window, batch) == ladder
+    assert list(ladder) == sorted(set(ladder)) and all(c < batch for c in ladder)
 
 
 def test_word_cap_is_derived_from_the_counts():
@@ -651,7 +841,7 @@ def test_word_cap_is_derived_from_the_counts():
 
     v, b, ratio = 2_519_370, 65536, 6.5e-4
     counts = zipf.zipf_counts(v).astype(np.int64)
-    total, run_cap = int(counts.sum()), _center_run_cap(WINDOW, b)
+    total, run_cap = int(counts.sum()), _last_rung(_center_run_cap(WINDOW, b))
     pieces = _word_pieces(counts, total, ratio, WINDOW, b)
     cap = _word_cap(counts, total, ratio, WINDOW, b, run_cap)
     assert cap == 12288 and cap % (b // 32) == 0 and pieces < cap <= 0.8 * run_cap
@@ -675,7 +865,7 @@ def test_word_cap_is_derived_from_the_counts():
         assert abs(pieces - in_pieces) < 0.1 * in_pieces, (pieces, held)
 
     # more pairs a batch, more words; a smaller share of a larger batch
-    caps = [_word_cap(counts, total, ratio, WINDOW, n, _center_run_cap(WINDOW, n))
+    caps = [_word_cap(counts, total, ratio, WINDOW, n, _last_rung(_center_run_cap(WINDOW, n)))
             for n in (4096, 16384, 65536)]
     assert caps == sorted(caps) and all(c % (n // 32) == 0
                                         for c, n in zip(caps, (4096, 16384, 65536)))
@@ -791,27 +981,59 @@ def test_tail_cap_builds_nothing_where_it_saves_nothing():
     assert _tail_cap(counts, total, 0.0, rows, 16) == 0
 
 
-def test_context_cap_is_derived_from_the_counts():
-    total = int(COUNTS.sum())
-    caps = [_context_run_cap(COUNTS, total, 0.0, WINDOW, b)
-            for b in (256, 1024, 4096, 16384, 65536)]
-    # more pairs a batch, more distinct contexts and more cut pieces; in
-    # sixteenths of the batch, and a smaller share of a larger batch
-    assert caps == sorted(caps) and all(caps)
-    assert all(c % (b // 16) == 0 for c, b in zip(caps, (256, 1024, 4096, 16384, 65536)))
-    assert caps[0] / 256 > caps[-1] / 65536
-    assert CTX_CAP == caps[1] == 320
-    # stronger subsampling flattens the kept tokens: more distinct words
-    assert _context_run_cap(COUNTS, total, 1e-4, WINDOW, B) >= CTX_CAP
+FLAT = np.full(1_000_000, 5, np.int64)
+
+
+# (counts, subsample, window, batch) -> (tight, roomy): the roomy rung is the
+# estimate with 20% of room in sixteenths of the batch, as the one cap was
+@pytest.mark.parametrize("counts, ratio, window, batch, ladder", [
+    (COUNTS, 0.0, WINDOW, 256, (80, 96)),
+    (COUNTS, 0.0, WINDOW, B, (288, 320)),
+    (COUNTS, 0.0, WINDOW, 4096, (1024, 1280)),
+    (COUNTS, 0.0, WINDOW, 16384, (3584, 4096)),
+    (COUNTS, 0.0, WINDOW, 65536, (14336, 16384)),
     # a flat vocabulary: every kept token another word, B / 3.2 of them at
     # window 5, with the room
-    flat = np.full(1_000_000, 5, np.int64)
-    assert _context_run_cap(flat, int(flat.sum()), 0.0, WINDOW, B) == 384
+    (FLAT, 0.0, WINDOW, B, (352, 384)),
     # where the estimate passes half the batch nothing is built: windows with
-    # a pair a token or fewer
-    assert _context_run_cap(flat, int(flat.sum()), 0.0, 2, B) == 0
-    assert _context_run_cap(COUNTS, total, 0.0, 1, B) == 0
-    assert _context_run_cap(COUNTS, total, 0.0, WINDOW, 8) == 0
+    # a pair a token or fewer; nor for a batch of a few pairs
+    (FLAT, 0.0, 2, B, ()),
+    (COUNTS, 0.0, 1, B, ()),
+    (COUNTS, 0.0, WINDOW, 8, ()),
+], ids=lambda v: "counts" if isinstance(v, np.ndarray) else None)
+def test_context_cap_is_derived_from_the_counts(counts, ratio, window, batch, ladder):
+    got = _context_run_cap(counts, int(counts.sum()), ratio, window, batch)
+    assert got == ladder, got
+    if ladder:
+        # ascending, the last in sixteenths, the first in 32nds (a power of two)
+        assert list(got) == sorted(set(got)) and got[-1] % (batch // 16) == 0
+        assert got[0] % (1 << ((batch // 32).bit_length() - 1)) == 0
+
+
+def test_context_cap_grows_with_the_batch_and_the_subsampling():
+    total = int(COUNTS.sum())
+    sizes = (256, 1024, 4096, 16384, 65536)
+    caps = [_context_run_cap(COUNTS, total, 0.0, WINDOW, b) for b in sizes]
+    # more pairs a batch, more distinct contexts and more cut pieces; a
+    # smaller share of a larger batch, on every rung
+    for rung in (0, -1):
+        rungs = [c[rung] for c in caps]
+        assert rungs == sorted(rungs) and rungs[0] / 256 > rungs[-1] / 65536
+    assert CTX_LADDER == caps[1]
+    # stronger subsampling flattens the kept tokens: more distinct words
+    assert _context_run_cap(COUNTS, total, 1e-4, WINDOW, B)[-1] >= CTX_CAP
+
+
+# the two SGNS cells' shapes: what the benchmark's steps are compiled with
+@pytest.mark.parametrize("v, ratio", [(3_000_000, 6.606e-4), (10_000_000, 7.018e-4)])
+def test_ladders_at_the_benchmarks_shapes(v, ratio):
+    from harness import zipf
+
+    counts = zipf.zipf_counts(v).astype(np.int64)
+    ladder = _context_run_cap(counts, int(counts.sum()), ratio, 5, 65536)
+    assert ladder == (18432, 20480) and _center_run_cap(5, 65536) == (18432, 24576)
+    # the subsample a little off (another corpus size): the same program
+    assert _context_run_cap(counts, int(counts.sum()), 8e-4, 5, 65536) == ladder
 
 
 def test_token_caps_are_derived_from_the_counts():

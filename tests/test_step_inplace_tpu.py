@@ -36,14 +36,26 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
-from described_v5e import B, D, K, P, RUNS, V
+from described_v5e import B, D, K, LADDERS, P, RUNS, V
 from described_v5e import one_chip, topo  # noqa: F401  (fixtures)
 
-from glint_word2vec_tpu.ops.sgns import EmbeddingPair, sgns_step_shared_core
+from glint_word2vec_tpu.ops.sgns import EmbeddingPair, _ladder, sgns_step_shared_core
+
+# one cap a scatter (the helper's program where a rule derives one rung), and
+# the ladders the trainer hands this step at the two cells' shapes (PR 58)
+CAPS = pytest.mark.parametrize("runs", [RUNS, LADDERS], ids=["one_cap", "ladder"])
 
 
+def _entries(compiled: str) -> list:
+    """Branch computations of each conditional of a compiled module, in text
+    order (a ``lax.cond`` has two, a ladder of two caps three)."""
+    return [len(names.split(", ")) for names in re.findall(
+        r" conditional\(.*branch_computations=\{([^}]*)\}", compiled)]
+
+
+@CAPS
 @pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
-def test_no_table_is_copied(one_chip, with_metrics):
+def test_no_table_is_copied(one_chip, with_metrics, runs):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -53,22 +65,37 @@ def test_no_table_is_copied(one_chip, with_metrics):
             return sgns_step_shared_core(
                 p, c, x, jnp.ones(B, jnp.float32), n, a, 5, "exact",
                 jnp.bfloat16, logits_dtype=jnp.bfloat16,
-                with_metrics=with_metrics, **RUNS)
+                with_metrics=with_metrics, **runs)
         return jax.lax.scan(body, params, (centers, contexts, negatives, alphas))
 
     table = spec((V, D), jnp.float32)
     compiled = jax.jit(chunk, donate_argnums=(0,)).lower(
         EmbeddingPair(table, table), spec((K, B), jnp.int32),
         spec((K, B), jnp.int32), spec((K, P), jnp.int32),
-        spec((K,), jnp.float32)).compile().as_text()
-    assert " sort(" in compiled and " conditional(" in compiled
-    copies = [line.strip()[:120] for line in compiled.splitlines()
+        spec((K,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert " sort(" in text
+    # ONE flat conditional a table: an entry a rung and the plain scatter
+    assert sorted(_entries(text)) == sorted(
+        len(_ladder(cap)) + 1 for _, cap in runs.values())
+    copies = [line.strip()[:120] for line in text.splitlines()
               if re.search(rf"= f32\[{V},{D}\]\S* copy\(", line)]
     assert not copies, copies
+    # every rung's scatter is handed its own cap's rows, no larger rung's
+    for _, cap in runs.values():
+        for rung in _ladder(cap):
+            assert re.search(rf"f32\[{rung},{D}\]", text), rung
+    # both tables in place and the step's working set: the cell's traced runs
+    # read 10.2 GB at their peak (ledger, PR 57), the tables 9.2 of them
+    memory = compiled.memory_analysis()
+    peak = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    assert peak < 10.4e9, peak
 
 
+@CAPS
 @pytest.mark.parametrize("with_metrics", [True, False], ids=["full", "fast"])
-def test_no_table_shard_is_copied_on_the_model_axis(topo, with_metrics):
+def test_no_table_shard_is_copied_on_the_model_axis(topo, with_metrics, runs):
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -85,7 +112,7 @@ def test_no_table_shard_is_copied_on_the_model_axis(topo, with_metrics):
             new_p, metrics = sgns_step_shared_core(
                 p, c, x, jnp.ones(B, jnp.float32), n, a, 5, "exact",
                 jnp.bfloat16, logits_dtype=jnp.bfloat16,
-                with_metrics=with_metrics, assemble_by_runs=True, **RUNS)
+                with_metrics=with_metrics, assemble_by_runs=True, **runs)
             return jax.lax.with_sharding_constraint(
                 new_p, EmbeddingPair(by_rows, by_rows)), metrics
         return jax.lax.scan(body, params, (centers, contexts, negatives, alphas))
@@ -94,9 +121,14 @@ def test_no_table_shard_is_copied_on_the_model_axis(topo, with_metrics):
     compiled = jax.jit(chunk, donate_argnums=(0,)).lower(
         EmbeddingPair(table, table), spec((K, B), jnp.int32),
         spec((K, B), jnp.int32), spec((K, P), jnp.int32),
-        spec((K,), jnp.float32)).compile().as_text()
-    # the gathers' conditional and one a scatter
-    assert compiled.count(" conditional(") == 3
+        spec((K,), jnp.float32)).compile()
+    memory = compiled.memory_analysis()
+    compiled = compiled.as_text()
+    # the gathers' conditional and one a scatter: an entry a rung and the
+    # plain one in each
+    caps0, caps1 = (_ladder(cap) for _, cap in runs.values())
+    assert sorted(_entries(compiled)) == sorted(
+        [len(caps0) + 1, len(caps0) + 1, len(caps1) + 1])
     moved = [line.strip()[:120] for line in compiled.splitlines()
              if re.search(rf"= \(?f32\[{shard},{D}\]\S* (?:copy|all-gather|all-to-all|"
                           r"collective-permute)(?:-start)?\(", line)]
@@ -108,9 +140,16 @@ def test_no_table_shard_is_copied_on_the_model_axis(topo, with_metrics):
                if re.search(r"= \S.* all-reduce(?:-start)?\(", line)
                and re.search(rf"\[\d+,{D}\]", line.split(" all-reduce")[0])]
     assert all(dtype == "bf16" for op in carried for dtype, _ in op), carried
-    caps = RUNS["center_runs"][1] + RUNS["context_runs"][1]
+    # ONE combined all-reduce a rung of the gathers' switch (syn1's shorter
+    # ladder repeats its last rung)
+    rungs = [a + b for a, b in zip(caps0, caps1 + caps1[-1:] * len(caps0))]
     assert sorted(sum(int(r) for _, r in op) for op in carried) == sorted(
-        [caps, 2 * B, P]), carried
+        rungs + [2 * B, P]), carried
+    # a chip's shards of both tables (7.68 GB) and the step's working set: the
+    # cell's traced runs read 8.8 GB a chip at their peak (ledger, PR 57)
+    peak = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    assert peak < 9.0e9, peak
 
 
 def _sibling_probe(vocab_size: int, threshold: float):

@@ -34,9 +34,13 @@ B, P, N, K = 64, 16, 5, 4
 BASE = dict(vector_size=16, min_count=1, pairs_per_batch=B, negatives=N,
             steps_per_dispatch=K)
 POOL, PER_EXAMPLE, WINDOW_POOLS = (K, P), (K, B, N), (K, 2 * P)
+# the shared-pool SGNS row takes its caps as ladders (tight, roomy); the rows
+# whose scatter takes one cap (hierarchical softmax, subword) the last rung
 RUNS_W5 = (10, _center_run_cap(5, B))
-# what a trainer derived from its vocabulary (_context_run_cap); 0 = nothing
-CONTEXT_CAP = 24
+ROOMY_W5 = (10, RUNS_W5[1][-1])
+assert RUNS_W5[1] == (18, 24)
+# what a trainer derived from its vocabulary (_context_run_cap); () = nothing
+CONTEXT_CAP = (20, 24)
 BY_CONTEXT = (_CONTEXT_MAX_RUN, CONTEXT_CAP)
 # the same for a banded CBOW block's tokens (_token_run_caps: syn0's, syn1's)
 TOKEN_CAPS = (44, 36)
@@ -216,9 +220,9 @@ def test_step_selection_hierarchical_softmax(with_metrics, segments, monkeypatch
     cfg = Word2VecConfig(**{**BASE, "negatives": 0}, loss="hs", window=5)
     assert cfg.negative_pool == 0
     shape = hs.HsShape(2, 8, 16, 128)
-    choice = select_step(cfg, make_mesh(1, 1), segments, 0, None, with_metrics,
+    choice = select_step(cfg, make_mesh(1, 1), segments, (), None, with_metrics,
                          hs_shape=shape)
-    runs = RUNS_W5 if segments == 1 else None
+    runs = ROOMY_W5 if segments == 1 else None      # one cap: the last rung
     assert choice.core is stub and choice.neg_shape is None
     assert choice.center_runs == runs and choice.context_runs is None
     assert choice.step("params", _Batch(path_table="table"), None, "alpha") == "out"
@@ -230,9 +234,9 @@ def test_step_selection_hierarchical_softmax(with_metrics, segments, monkeypatch
 
 def test_context_runs_need_a_cap():
     """A vocabulary whose estimate passes half the batch (_context_run_cap
-    gives 0) builds no context coalescing; syn0's stays as it is."""
+    gives ()) builds no context coalescing; syn0's stays as it is."""
     cfg = Word2VecConfig(**BASE, negative_pool=P, window=5)
-    choice = select_step(cfg, make_mesh(1, 1), 1, 0, None, True)
+    choice = select_step(cfg, make_mesh(1, 1), 1, (), None, True)
     assert choice.context_runs is None and choice.center_runs == RUNS_W5
 
 
@@ -241,7 +245,7 @@ def test_token_runs_need_a_cap():
     gives (0, 0): the default of ``token_caps``) builds no token coalescing."""
     cfg = Word2VecConfig(**BASE, cbow=True, cbow_update="banded", negative_pool=P,
                          window=5)
-    choice = select_step(cfg, make_mesh(1, 1), 1, 0, None, True)
+    choice = select_step(cfg, make_mesh(1, 1), 1, (), None, True)
     assert choice.token_runs is None and choice.center_runs is None
 
 
@@ -255,10 +259,11 @@ def test_x4_step_holds_the_parents_collectives(twin):
     devices): the step with both updates coalesced and its forward gathers by
     the same runs compiles to all-reduces alone (the forward assembly of the
     gathered rows over the model axis) — the sorts, the row gathers and the
-    conditionals bring no other collective. The gathers' conditional holds the
-    assembly once in each branch: by runs, both scatters' caps of rows; by
-    pair, 2B as before PR 49; the pool's P rows beside both. A count of the
-    compiled module's ops and their operands' rows, not a time."""
+    conditionals bring no other collective. The gathers' switch holds the
+    assembly once in each entry: by runs, both scatters' caps of rows on each
+    rung of their ladders (PR 58); by pair, 2B as before PR 49; the pool's P
+    rows beside them. A count of the compiled module's ops and their operands'
+    rows, not a time."""
     from harness import loader
     from kinds import train as train_kind
 
@@ -292,6 +297,8 @@ def test_x4_step_holds_the_parents_collectives(twin):
             result = line.split(" all-reduce")[0].split("=", 1)[1]
             rows_in[computation] += sum(
                 int(r) for r in re.findall(r"\w+\[(\d+),\d+\]", result))
-    (cap0, cap1), pool = (choice.center_runs[1], choice.context_runs[1]), cfg.negative_pool
-    assert sorted(rows_in.values()) == sorted([cap0 + cap1, 2 * b, pool])
-    assert cap0 + cap1 + pool < 0.4 * (2 * b + pool)
+    (caps0, caps1), pool = (choice.center_runs[1], choice.context_runs[1]), cfg.negative_pool
+    assert len(caps0) == len(caps1) == 2
+    rungs = [cap0 + cap1 for cap0, cap1 in zip(caps0, caps1)]
+    assert sorted(rows_in.values()) == sorted(rungs + [2 * b, pool])
+    assert rungs[0] < rungs[1] and rungs[1] + pool < 0.4 * (2 * b + pool)

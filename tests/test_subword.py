@@ -333,7 +333,7 @@ def test_word_slot_cap_is_derived_from_the_counts_and_the_lists(seed):
     from glint_word2vec_tpu.data.pipeline import epoch_batches
     from glint_word2vec_tpu.data.vocab import Vocabulary
     from glint_word2vec_tpu.train.trainer import (
-        _center_run_cap, _word_cap, _word_pieces_by_word, _word_slot_cap)
+        _center_run_cap, _last_rung, _word_cap, _word_pieces_by_word, _word_slot_cap)
 
     v, b, window, ratio = 2_519_370, 65536, 5, 6.54e-4
     counts = zipf.zipf_counts(v).astype(np.int64)
@@ -341,7 +341,7 @@ def test_word_slot_cap_is_derived_from_the_counts_and_the_lists(seed):
     kept = (counts, total, ratio, window, b)
     strings = bench_words.make_words(seed, v)
     table = build_subword_table(strings, 3, 6, 2_000_000)
-    word_cap = _word_cap(*kept, _center_run_cap(window, b))
+    word_cap = _word_cap(*kept, _last_rung(_center_run_cap(window, b)))
     slots = word_cap * table.max_groups * GROUP
     cap = _word_slot_cap(*kept, table.counts, slots)
     assert (word_cap, slots, cap) == (12288, 491_520, 34 * 8192)
@@ -362,7 +362,8 @@ def test_word_slot_cap_is_derived_from_the_counts_and_the_lists(seed):
 
     # a block of another size takes the same share of its slots
     for n in (4096, 16384):
-        small_cap = _word_cap(counts, total, ratio, window, n, _center_run_cap(window, n))
+        small_cap = _word_cap(counts, total, ratio, window, n,
+                              _last_rung(_center_run_cap(window, n)))
         small = _word_slot_cap(counts, total, ratio, window, n, table.counts,
                                small_cap * 40)
         assert 0 < small < 0.8 * small_cap * 40
@@ -449,8 +450,10 @@ def test_masked_pairs_and_the_lane_padding_stay_zero(branch):
 # `sgns-3m-300.train` cell at its `tiny` sizes, taken at the parent commit of
 # PR 31 (4960b0f) and equal on PR 31's tree: the row source adds no op and no
 # argument where the model is not subword. A later PR that changes the SGNS
-# step on purpose takes new digests from its own tree (the failure prints them).
-PARENT_STEP_TEXT = {"_step_fn": "f7f4fe22a5785c49", "_step_fn_fast": "b994716300289c09"}
+# step on purpose takes new digests from its own tree (the failure prints them):
+# PR 58 did (each coalesced scatter under a ladder of two caps and the counters
+# of the slots it was handed; tests/test_cbow_subword.py holds the same two).
+PARENT_STEP_TEXT = {"_step_fn": "8ce8095e605b956f", "_step_fn_fast": "2f1ac1ca7cdea1fb"}
 
 
 @pytest.mark.parametrize("twin", list(PARENT_STEP_TEXT))
