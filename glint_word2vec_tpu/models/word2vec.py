@@ -36,7 +36,8 @@ from glint_word2vec_tpu.lockcheck import make_lock
 from glint_word2vec_tpu.obs.spans import default_tracer, pinned_call
 from glint_word2vec_tpu.ops.scan import (
     _LISTED, _VECTOR, _analogy_topk, _row_shards, _scan_counts, _topk_dispatch)
-from glint_word2vec_tpu.ops.transform import _segment_means, _sentence_means
+from glint_word2vec_tpu.ops.transform import (
+    _segment_means, _sentence_means, _sharded_rows)
 from glint_word2vec_tpu.parallel.mesh import (
     MeshPlan, pad_dim_to_lanes, pad_vocab_for_sharding)
 from glint_word2vec_tpu.train import checkpoint as ckpt
@@ -53,7 +54,9 @@ class Word2VecModel:
     (``_compose``), and, once ``transform_sentences``, ``transform_words`` or
     ``pull`` has read rows, syn0 again with D widened to whole lanes of 128
     (:meth:`_row_table`: 4.61 GB beside syn0's 3.60 at 3M x 300 float32; none
-    where D is a multiple of 128, or on a mesh).
+    where D is a multiple of 128; over a table partitioned by rows the form
+    is partitioned as the table is, 3.84 GB a chip beside 3.0 at 10M x 300
+    over four).
 
     ``resident="rows"`` builds a subword model that holds only what a row
     read reads: the composed table written straight at whole lanes, the
@@ -379,6 +382,16 @@ class Word2VecModel:
         children and the encode's ``transform.encode.walk``
         (docs/observability.md §4).
 
+        Over a table partitioned by rows (a model on a mesh with a model
+        axis) the slide's program runs under ``shard_map`` over the axis that
+        partitions them, as the upstream servers run ``pullAverage``: every
+        shard gathers the ids it owns from its own block of the whole-lane
+        form and sums them by sentence, one psum adds the ``[S, lanes]``
+        partial sums, and the division follows it
+        (:func:`..ops.transform._sharded_segment_sums`). The host halves are
+        the same; ``transform.enqueue`` says ``shards`` and ``owned_max``.
+        Along a data axis every replica does the whole slide.
+
         On a subword model a token the vocabulary lacks is dropped here too
         (upstream's rule); :meth:`sentence_vectors` is the operation that
         composes it from its n-grams."""
@@ -481,6 +494,7 @@ class Word2VecModel:
             span.set(words=live, oov=oov, empty=int((counts == 0).sum()))
         if live:
             table = self._row_table()
+            shards = _row_shards(table)
             segments = batch_size if n == batch_size else _grid_up(n, 8)
             passes = -(-live // _TRANSFORM_MAX_ROWS)
             cap = _grid_up(-(-live // passes), 128)
@@ -488,7 +502,8 @@ class Word2VecModel:
                 inflight = self._slides_inflight
                 self._slides_inflight += 1
             with tracer.span("transform.enqueue", rows=live, rows_cap=cap,
-                             passes=passes, inflight=inflight):
+                             passes=passes, inflight=inflight,
+                             **self._owned(shards, table.shape[0], ids, span)):
                 seg = np.repeat(np.arange(n, dtype=np.int32), counts)
                 counts = np.concatenate(
                     [counts, np.zeros(segments - n, np.int32)])
@@ -496,6 +511,7 @@ class Word2VecModel:
                 for at in range(0, passes * cap, cap):
                     # past the live ids: a row no table has (read as zeros)
                     # in a sentence no slide has (dropped)
+                    # (on a mesh the PADDED row count, which no shard owns)
                     part_ids = np.full(cap, table.shape[0], np.int32)
                     part_seg = np.full(cap, segments, np.int32)
                     part_ids[:live - at] = ids[at:at + cap]
@@ -503,12 +519,29 @@ class Word2VecModel:
                     sums = _segment_means(
                         table, part_ids, part_seg,
                         counts if at + cap >= live else None, sums,
-                        segments, self.vector_size)
+                        segments, self.vector_size, shards)
                 sums.copy_to_host_async()
             pending.result = sums
         if span is not None:
             span.detach()  # the next slide's spans are no children of this one
         return pending
+
+    @staticmethod
+    def _owned(shards, rows: int, ids: np.ndarray, span) -> Dict[str, int]:
+        """What ``transform.enqueue`` says of a slide's program over a table
+        of ``rows`` rows partitioned by rows (``shards``, its sharding;
+        nothing on one device): the ``shards`` it ran over and, where the
+        slide is recorded (``span``), ``owned_max``: the live ids the
+        busiest shard owns. Its gather reads that many rows where an even
+        spread would read ``rows / shards`` (rows in frequency order
+        partitioned by range put ~94% of a Zipf slide's on shard 0)."""
+        if shards is None:
+            return {}
+        n = shards.mesh.shape[shards.spec[0]]
+        if span is None:
+            return {"shards": n}
+        return {"shards": n, "owned_max": int(
+            np.bincount(ids // (rows // n), minlength=n).max())}
 
     def _encode_tokens(self, slide: Sequence[Sequence[str]]) -> "_SlideTokens":
         """One slide's tokens as :func:`..ops.transform._sentence_means` wants
@@ -662,24 +695,35 @@ class Word2VecModel:
 
     def _row_table(self) -> jax.Array:
         """The table ``transform_sentences``, ``transform_words`` and ``pull``
-        gather their rows from. On one device: syn0 with D widened to whole
-        lanes of 128 (ops/subword.lane_padded, as the bucket rows are kept),
-        which the TPU's gather reads in place, where a gather from the
-        [V, 300] table first copies ALL of it row-major (3.6 GB of
-        temporaries and ~13 ms a call at 3M rows, before one row is read:
-        PERF.md §6). Made at the first such read, under the model's lock (a
-        model that only answers ``find_synonyms*`` never holds it: the scan
-        reads the table as it lies), kept until :meth:`stop`. A table on a
-        mesh is gathered as it lies, the ``[:V]`` view (ROADMAP B14 (c))."""
+        gather their rows from: syn0 with D widened to whole lanes of 128
+        (ops/subword.lane_padded, as the bucket rows are kept), which the
+        TPU's gather reads in place, where a gather from the [V, 300] table
+        first copies ALL of it row-major (3.6 GB of temporaries and ~13 ms a
+        call at 3M rows, before one row is read: PERF.md §6). Made at the
+        first such read, under the model's lock (a model that only answers
+        ``find_synonyms*`` never holds it: the scan reads the table as it
+        lies), kept until :meth:`stop`. Over a table partitioned by rows it
+        is made shard by shard under the table's own sharding (the jitted pad
+        hands it out as the table lies: every chip widens the rows it holds, the
+        padding rows of a vocabulary that does not divide with them; at the
+        most a quarter of the 300-wide table and a quarter of the 384-wide
+        form on a chip of four, no ``[V, .]`` array on one chip or the
+        host), and the readers' programs run under ``shard_map`` over it. A
+        table that lies whole on each of several devices is gathered as it
+        lies, the ``[:V]`` view."""
         self._check_alive()
         if self._lanes is not None:     # made, or all a resident="rows" model holds
             return self._lanes
-        if len(self._full0.sharding.device_set) != 1:
+        shards = _row_shards(self._full0)
+        if shards is None and len(self._full0.sharding.device_set) != 1:
             return self.syn0
         from glint_word2vec_tpu.ops.subword import lane_padded
-        return self._once("_lanes", lane_padded, "model.row_table")
+        said = {} if shards is None else {
+            "shards": shards.mesh.shape[shards.spec[0]]}
+        return self._once("_lanes", lane_padded, "model.row_table", **said)
 
-    def _once(self, attr: str, make, span: Optional[str] = None) -> jax.Array:
+    def _once(self, attr: str, make, span: Optional[str] = None,
+              **said) -> jax.Array:
         """The resident form ``attr`` of the model's table: what is there, or
         ``make(table)`` of the table the model holds (syn0; the whole-lane
         form where ``resident="rows"`` kept nothing else, whose rows have the
@@ -687,7 +731,8 @@ class Word2VecModel:
         callers' threads: under the model's lock (not re-entrant: ``make``
         asks for no other form), looked for again there, waited for before
         it is stored, inside the pinned span ``span`` (obs/spans.py; its
-        ``rows`` the table's) where one is named."""
+        ``rows`` the table's, and what else the caller ``said``) where one
+        is named."""
         made = getattr(self, attr)
         if made is not None:
             return made
@@ -696,7 +741,7 @@ class Word2VecModel:
             if made is None:
                 table = self._lanes if self._full0 is None else self._full0
                 with (default_tracer().span(span, pinned=True,
-                                            rows=int(table.shape[0]))
+                                            rows=int(table.shape[0]), **said)
                       if span else contextlib.nullcontext()):
                     made = make(table)
                     made.block_until_ready()
@@ -704,9 +749,17 @@ class Word2VecModel:
         return made
 
     def _read_rows(self, ids: Sequence[int]) -> np.ndarray:
-        """Rows ``ids`` of syn0, fetched: one gather from :meth:`_row_table`."""
-        return np.asarray(self._row_table()[jnp.asarray(ids, jnp.int32)]
-                          [:, : self.vector_size])
+        """Rows ``ids`` of syn0, fetched: one gather from :meth:`_row_table`;
+        over a table partitioned by rows, every shard's gather of the rows it
+        owns and one psum (:func:`..ops.transform._sharded_rows`; a negative
+        id counts from the vocabulary's end there too)."""
+        table, ids = self._row_table(), np.asarray(ids, np.int32)
+        shards = _row_shards(table)
+        if shards is None:
+            return np.asarray(table[jnp.asarray(ids)][:, : self.vector_size])
+        return np.asarray(_sharded_rows(
+            table, np.where(ids < 0, ids + self.vocab.size, ids),
+            self.vector_size, shards))
 
     @property
     def norms(self) -> jax.Array:

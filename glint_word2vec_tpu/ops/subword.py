@@ -507,9 +507,15 @@ def lane_padded(rows: jax.Array) -> jax.Array:
     no multiple of 128 column-major, and a gather from that first copies the
     whole table row-major (3.07 GB at 2,000,000 x 300, by the v5e compiler,
     every call; PERF.md §6, PR 40); one-row slices read in place but only
-    unrolled, one a slot."""
+    unrolled, one a slot. The form lies as ``rows`` lies (one jitted pad,
+    ``rows``' own sharding out): over a table partitioned by rows
+    (ops/scan._row_shards) every chip widens the rows it holds and nothing
+    crosses a chip."""
     extra = pad_dim_to_lanes(rows.shape[1]) - rows.shape[1]
-    return jnp.pad(rows, ((0, 0), (0, extra))) if extra else rows
+    if not extra:
+        return rows
+    return jax.jit(lambda rows: jnp.pad(rows, ((0, 0), (0, extra))),
+                   out_shardings=rows.sharding)(rows)
 
 
 def list_vectors(buckets: jax.Array, lists: jax.Array, dim: int) -> jax.Array:

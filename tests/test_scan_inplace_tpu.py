@@ -2,7 +2,7 @@
 
 ``ops/scan.py`` (the neighbour scan on one chip and under ``shard_map`` on the
 described 2x2 as a 1x4 mesh, the analogy scan) and ``ops/transform.py`` (the
-sentence slides), compiled at their cells' published shapes for a v5e chip that
+sentence slides, on one chip and under ``shard_map`` on the 1x4 mesh), compiled at their cells' published shapes for a v5e chip that
 is described, not attached (tests/described_v5e.py; nothing runs): no copy, gather
 or conversion of a table, nothing V wide across the mesh, no gathered block
 written. A count of instructions and of bytes, not a time.
@@ -122,6 +122,91 @@ def test_the_sentence_vector_slide_copies_no_table_and_writes_no_block(one_chip,
     _no_table_copied(text)
     assert " sort(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def _mesh_1x4(topo):
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, PartitionSpec(*axes)))
+
+    return NamedSharding(mesh, PartitionSpec("model", None)), spec
+
+
+_COLLECTIVE = (r"= (\S+?)\{\S* (all-reduce|all-gather|all-to-all|reduce-scatter|"
+               r"collective-permute)(?:-start)?\(")
+
+
+@pytest.mark.parametrize("carried", [False, True], ids=["one_pass", "a_further_pass"])
+def test_the_sharded_slide_moves_no_table_and_one_block_of_partial_sums(topo, carried):
+    """``transform_sentences``' one program a slide over a table partitioned by
+    rows (PR 59) at ``sgns-transform-10m-300-x4``'s size, on the described 2x2
+    as a 1x4 mesh: 327,680 replicated ids, every chip gathering the ones it
+    owns from its own ``[2,500,000, 384]`` block of the whole-lane form. Exactly
+    one collective, the all-reduce of the ``[10000, 384]`` float32 partial sums;
+    the only thing 2,500,000 (or 10,000,000) rows wide in the whole module is
+    the block, as a parameter: no copy, gather output, slice or all-gather of
+    it; no sort; and the gather is the sorted scatter-add's producer, so nothing
+    ``[rows, 384]`` is written either."""
+    shards, spec = _mesh_1x4(topo)
+    rows, sentences, vocab = 327_680, 10_000, 10_000_000
+    compiled = transform._segment_means.lower(
+        spec((vocab, D), jnp.float32, "model", None), spec((rows,), jnp.int32),
+        spec((rows,), jnp.int32), spec((sentences,), jnp.int32),
+        spec((sentences, D), jnp.float32) if carried else None,
+        segments=sentences, dim=300, shards=shards).compile()
+    text = compiled.as_text()
+    assert re.findall(_COLLECTIVE, text) == [(f"f32[{sentences},{D}]", "all-reduce")]
+    wide = [line.strip()[:80] for line in text.splitlines()
+            if re.search(r"= \(?\w+\[(?:2500000|10000000)[,\]]", line)]
+    # the entry's parameter, and the fusions' that read it in place
+    assert wide and all(" parameter(" in line for line in wide), wide
+    assert " sort(" not in text
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < 3.9e9        # a chip's block, the ids
+    assert memory.temp_size_in_bytes < 64 << 20
+
+
+def test_the_whole_lane_form_is_made_under_the_sharding_with_no_collective(topo):
+    """``_row_table`` on a mesh: ``lane_padded``'s jitted pad, the table's
+    sharding out, compiles, for each chip, to the one-chip program over
+    its quarter: no collective, and nothing wider than a quarter of the
+    300-wide table in and a quarter of the 384-wide form out."""
+    from glint_word2vec_tpu.ops.subword import lane_padded
+
+    _, spec = _mesh_1x4(topo)
+    made = {}
+    real_jit = jax.jit
+
+    def keep(fun, **kw):
+        made["jit"] = real_jit(fun, **kw)
+        return lambda rows: made.setdefault("lowered", made["jit"].lower(rows))
+
+    import unittest.mock
+    with unittest.mock.patch.object(jax, "jit", keep):
+        lane_padded(spec((10_000_000, 300), jnp.float32, "model", None))
+    compiled = made["lowered"].compile()
+    assert not re.findall(_COLLECTIVE, compiled.as_text())
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < 3.1e9 and memory.output_size_in_bytes < 3.9e9
+    assert "f32[10000000," not in compiled.as_text().split("ENTRY")[1]
+
+
+def test_the_sharded_row_read_moves_the_rows_asked_for_and_no_block(topo):
+    """``pull`` / ``transform_words`` over a partitioned table
+    (``_sharded_rows``): one all-reduce of the ``[Q, 384]`` rows, no copy or
+    gather output a block wide."""
+    shards, spec = _mesh_1x4(topo)
+    compiled = transform._sharded_rows.lower(
+        spec((10_000_000, D), jnp.float32, "model", None), spec((10_000,), jnp.int32),
+        dim=300, shards=shards).compile()
+    text = compiled.as_text()
+    assert re.findall(_COLLECTIVE, text) == [(f"f32[10000,{D}]", "all-reduce")]
+    assert not re.findall(r"= f32\[2500000,\d+\]\S* (?:copy|gather|all-gather)\(", text)
 
 
 def test_the_same_gather_from_the_300_wide_table_copies_all_of_it(one_chip):

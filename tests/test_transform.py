@@ -7,7 +7,10 @@ overlapped within a call) against ``benchmark/reference/transform_ref.py`` (its
 own dictionary, a Python loop in float64) on seeded tables: ragged slides with
 out-of-vocabulary tokens, all-OOV and empty sentences, repeated words, one-word
 sentences, a 1,000-token sentence, slides over the row capacity, a short last
-slide, a call of several slides, four threads at once, the compat wrapper. The
+slide, a call of several slides, four threads at once, the compat wrapper; and
+over tables partitioned by rows on meshes of virtual devices (1x4, 1x2, 2x2;
+vocabularies that divide and one that does not), against the one-device program
+and ``benchmark/reference/sharded_transform_ref.py``. The
 stated tolerances: float32 tables within ``F32_TOL`` of the table's half width
 (a float32 sum of up to 1,000 rows against a float64 one), float64 tables
 within ``F64_TOL`` (the one rounding of the result to float32).
@@ -28,6 +31,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 from harness import zipf  # noqa: E402
+from reference import sharded_transform_ref as sharded_ref  # noqa: E402
 from reference import transform_ref as ref  # noqa: E402
 
 from glint_word2vec_tpu.data.vocab import Vocabulary  # noqa: E402
@@ -239,20 +243,204 @@ def test_the_whole_lane_form_is_made_once_and_freed_by_stop():
         m.transform_sentences([["w1"]])
 
 
-def test_a_table_on_a_mesh_keeps_the_gather_of_its_view():
-    """ROADMAP B14 (c) stays open: over a mesh the rows are gathered from the
-    ``[:V]`` view as they were, no whole-lane form is made, and the answers
-    are the one-device program's."""
+def test_a_table_on_a_mesh_builds_the_whole_lane_form_under_its_sharding(tracer):
+    """ROADMAP B14 (c), B19 (a) closed: over a table partitioned by rows the
+    whole-lane form IS made, once, shard by shard under the table's own
+    sharding (its padding rows with it), the slide runs under ``shard_map``
+    over it, and the answers are the one-device program's."""
+    from glint_word2vec_tpu.ops.scan import _row_shards
     from glint_word2vec_tpu.parallel.mesh import make_mesh
     one, sharded = make_model(v=1203), make_model(v=1203, plan=make_mesh(1, 4))
     sents = [[f"w{(i * 13 + j) % 1203}" for j in range(i % 9)] + ["zz"]
              for i in range(70)]
+    sharded.find_synonyms("w1", 3)
+    assert sharded._lanes is None           # a scan never makes it, here either
     got = sharded.transform_sentences(sents, batch_size=32)
-    assert sharded._lanes is None and sharded._full0.shape[0] > 1203
-    assert np.abs(got - one.transform_sentences(sents)).max() <= 1e-6 * HALF_WIDTH
+    lanes = sharded._lanes
+    assert lanes.shape == (sharded._full0.shape[0], 128) and lanes.shape[0] > 1203
+    assert lanes.sharding.is_equivalent_to(sharded._full0.sharding, 2)
+    assert _row_shards(lanes) is not None and len(lanes.sharding.device_set) == 4
+    assert not np.asarray(lanes[:, D:]).any() and not np.asarray(lanes[1203:]).any()
+    assert np.abs(got - one.transform_sentences(sents)).max() <= MESH_TOL
     assert np.array_equal(sharded.pull([5, 1202]), one.pull([5, 1202]))
+    assert sharded._lanes is lanes
+    made = [e["args"] for e in tracer.setup_events() if e["name"] == "model.row_table"]
+    assert made[-2:] == [{"rows": lanes.shape[0], "shards": 4}, {"rows": 1203}]
     one.stop()
     sharded.stop()
+    assert sharded._lanes is None and lanes.is_deleted()
+
+
+# a float32 sum of the same rows in another association (a chip's partial sums,
+# then the four partials): each within F32_TOL of the float64 sum, so the two
+# programs within twice that of each other
+MESH_TOL = 2 * F32_TOL
+MESHES = {"1x4": (1, 4), "1x2": (1, 2), "2x2": (2, 2)}
+
+
+def mesh_sentences(v: int, n: int = 150) -> list:
+    """Ragged sentences over ``v`` words with what a partition could lose: a
+    sentence whose tokens all lie in the last shard's rows, the vocabulary's
+    last word, an all-OOV and an empty sentence, a 300-token sentence, and
+    out-of-vocabulary tokens throughout."""
+    rng = np.random.default_rng(v)
+    out = [[f"w{int(r)}" for r in rng.integers(0, v, int(rng.integers(0, 40)))]
+           + (["zz"] if i % 3 == 0 else []) for i in range(n)]
+    out[7] = [f"w{v - 1 - i}" for i in range(8)]
+    out[8] = [f"w{v - 1}"]
+    out[9] = ["nope", "never"]
+    out[10] = []
+    out[11] = [f"w{int(r)}" for r in rng.integers(0, v, 300)]
+    return out
+
+
+@pytest.fixture(scope="module", params=[1203, 2000], ids=["v1203", "v2000"])
+def on_one_device(request):
+    v = request.param
+    one = make_model(v=v)
+    yield v, one, ref.dictionary(v)
+    one.stop()
+
+
+@pytest.mark.parametrize("mesh", sorted(MESHES))
+def test_the_sharded_slide_answers_as_one_device_and_as_the_reference(
+        on_one_device, mesh, tracer):
+    """1,203 rows do not divide over the mesh (the last shards hold padding
+    rows, the fill id is the PADDED row count); 2,000 do. A call of five slides
+    and a short last one."""
+    from glint_word2vec_tpu.parallel.mesh import make_mesh
+    v, one, index = on_one_device
+    sharded = make_model(v=v, plan=make_mesh(*MESHES[mesh]))
+    sents = mesh_sentences(v)
+    got = sharded.transform_sentences(sents, batch_size=32)
+    said = [e["args"] for e in tracer.events() if e["name"] == "transform.enqueue"]
+    assert got.shape == (150, D) and got.dtype == np.float32
+    assert np.abs(got - one.transform_sentences(sents, batch_size=32)).max() <= MESH_TOL
+    want = sharded_ref.sentence_vectors(sents, index, ROWS_FN, D)
+    assert np.abs(got - want).max() <= F32_TOL
+    assert not got[9].any() and not got[10].any() and got[7].any() and got[8].any()
+    shards = MESHES[mesh][1]
+    per = sharded._full0.shape[0] // shards
+    assert [a["shards"] for a in said] == [shards] * 5
+    for lo, a in zip(range(0, 150, 32), said):
+        ids = np.array([index[w] for s in sents[lo:lo + 32] for w in s if w in index])
+        assert a["rows"] == len(ids) and a["passes"] == 1
+        assert a["owned_max"] == np.bincount(ids // per, minlength=shards).max()
+    sharded.stop()
+
+
+@pytest.mark.parametrize("passes", [2, 3])
+def test_a_sharded_slide_over_the_row_capacity_carries_its_sums(
+        on_one_device, passes, monkeypatch, tracer):
+    from glint_word2vec_tpu.parallel.mesh import make_mesh
+    v, one, index = on_one_device
+    sents = mesh_sentences(v, 64)
+    live = sum(w in index for s in sents for w in s)
+    want = one.transform_sentences(sents)
+    monkeypatch.setattr(w2v, "_TRANSFORM_MAX_ROWS", -(-live // passes))
+    sharded = make_model(v=v, plan=make_mesh(1, 4))
+    tracer.clear()
+    got = sharded.transform_sentences(sents)
+    assert np.abs(got - want).max() <= MESH_TOL
+    assert np.abs(got - sharded_ref.sentence_vectors(sents, index, ROWS_FN, D)
+                  ).max() <= F32_TOL
+    said = [e["args"] for e in tracer.events() if e["name"] == "transform.enqueue"]
+    assert [a["passes"] for a in said] == [passes] and said[0]["shards"] == 4
+    sharded.stop()
+
+
+@pytest.mark.parametrize("v", [1203, 2000])
+def test_row_reads_on_a_mesh_are_the_one_device_rows_bit_for_bit(v):
+    """``pull``, ``transform_words`` and ``transform`` over a partitioned
+    table read the whole-lane form's owner rows: a row is one addend that is
+    not zero, so the psum returns its bits."""
+    from glint_word2vec_tpu.parallel.mesh import make_mesh
+    one, sharded = make_model(v=v), make_model(v=v, plan=make_mesh(1, 4))
+    ids = [0, 5, v // 4 - 1, v // 4, v // 2 + 3, v - 1, 5, -1]
+    assert np.array_equal(sharded.pull(ids), one.pull(ids))
+    assert sharded.pull(ids).shape == (len(ids), D)
+    words = [f"w{i}" for i in range(0, v, 7)]
+    assert np.array_equal(np.stack(list(sharded.transform_words(words, batch_size=100))),
+                          np.stack(list(one.transform_words(words, batch_size=100))))
+    assert np.array_equal(sharded.transform(f"w{v - 1}"), one.transform(f"w{v - 1}"))
+    with pytest.raises(KeyError, match="zz"):
+        list(sharded.transform_words(["w1", "zz"]))
+    assert sharded._lanes is not None
+    one.stop()
+    sharded.stop()
+
+
+def test_a_bfloat16_table_on_a_mesh_is_read_in_its_own_dtype():
+    from glint_word2vec_tpu.parallel.mesh import make_mesh
+    one = make_model(dtype=jnp.bfloat16)
+    sharded = make_model(dtype=jnp.bfloat16, plan=make_mesh(1, 4))
+    assert sharded.pull([3, V - 1]).dtype == one.pull([3, V - 1]).dtype
+    assert np.array_equal(sharded.pull([3, V - 1]), one.pull([3, V - 1]))
+    sents = mesh_sentences(V, 40)
+    assert np.abs(sharded.transform_sentences(sents)
+                  - one.transform_sentences(sents)).max() <= MESH_TOL
+    one.stop()
+    sharded.stop()
+
+
+def test_the_sharded_slide_is_one_program_with_one_collective():
+    """The slide's program over a 1x4 mesh of virtual devices: exactly one
+    collective, an all-reduce of the ``[segments, lanes]`` float32 partial
+    sums, and nothing else crosses a device (the described v5e's compile,
+    with the in-place gather, is tests/test_scan_inplace_tpu.py's)."""
+    import re
+
+    from glint_word2vec_tpu.ops.scan import _row_shards
+    from glint_word2vec_tpu.ops.transform import _segment_means
+    from glint_word2vec_tpu.parallel.mesh import make_mesh
+    sharded = make_model(plan=make_mesh(1, 4))
+    table = sharded._row_table()
+    ids, seg = np.zeros(256, np.int32), np.zeros(256, np.int32)
+    for counts, carried in ((np.ones(64, np.int32), None),
+                            (None, jnp.zeros((64, 128), jnp.float32))):
+        text = _segment_means.lower(table, ids, seg, counts, carried, 64, D,
+                                    _row_shards(table)).compile().as_text()
+        found = re.findall(r"= (\S+?) (all-reduce|all-gather|all-to-all|reduce-scatter|"
+                           r"collective-permute)(?:-start)?\(", text)
+        assert [(s.split("{")[0], op) for s, op in found] == [("f32[64,128]", "all-reduce")]
+    sharded.stop()
+
+
+# the lowered text of the one-device slide programs at [1733, 128] rows, 256
+# ids, 24 sentences, dim 100, as the parent of PR 59 (1b8b1e8) lowers them:
+# sha256, first 16 hex digits. Keys: the program, then for _segment_means
+# (counts handed over, sums carried), for _sentence_means (lists handed over)
+PARENT_SLIDE_TEXT = {
+    ("segment", True, False): "3f52cd2a93f111ea",
+    ("segment", False, False): "4841ed7d9e6465ec",
+    ("segment", True, True): "69ee0d0ae1cba0bd",
+    ("sentence", False): "8136661ce6afaa06",
+    ("sentence", True): "d13a69d88f70720b"}
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_SLIDE_TEXT, key=str), ids=str)
+def test_a_slide_on_one_device_lowers_to_the_parents_text(case):
+    """A table that is not partitioned runs the programs it ran before there
+    was a sharded slide: no operand, no instruction more
+    (sgns-transform-3m-300's and subword-sentvec-2.5m-300's cells)."""
+    import hashlib
+
+    from glint_word2vec_tpu.ops import transform as ops
+    spec = jax.ShapeDtypeStruct
+    table, ids = spec((1733, 128), jnp.float32), spec((256,), jnp.int32)
+    counts = spec((24,), jnp.int32)
+    if case[0] == "segment":
+        _, last, carried = case
+        text = ops._segment_means.lower(
+            table, ids, ids, counts if last else None,
+            spec((24, 128), jnp.float32) if carried else None, 24, 100).as_text()
+    else:
+        lists = (spec((500, 128), jnp.float32), spec((384,), jnp.int32),
+                 spec((384,), jnp.int32), spec((64,), jnp.int32))
+        text = ops._sentence_means.lower(
+            table, spec((1733,), jnp.float32), ids, ids, lists if case[1] else None,
+            counts, None, 24, 100).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT_SLIDE_TEXT[case]
 
 
 def test_spans_of_a_call_of_three_slides(model, tracer):
