@@ -123,9 +123,12 @@ class Word2VecModel:
         self._scan0: Optional[jax.Array] = None
         self._slides_inflight = 0
         self._norms: Optional[jax.Array] = None
-        # sentence_vectors' scale of a word's row, 1 / its norm (0 for a row
-        # of zero norm), and the ids of those rows, on the host
+        # a word's row's scale, 1 / its norm (0 for a row of zero norm): on
+        # the device for the analogy scan, on the host for sentence_vectors'
+        # encode (which takes a slide's scales there), and the ids of the
+        # rows of zero norm, on the host too
         self._inv_norms: Optional[jax.Array] = None
+        self._host_inv: Optional[np.ndarray] = None
         self._zero_rows: Optional[np.ndarray] = None
         self._ann = None
         self._stopped = False
@@ -433,8 +436,10 @@ class Word2VecModel:
           and the list rows' by :func:`_grid_up` and the unseen tokens' the
           power of two over them (coarse, so slides share programs: its
           block is a twentieth of the others). The word rows are gathered in
-          place from the whole-lane table and scaled by the inverse norm of
-          their id; the list rows are gathered in place from the bucket rows,
+          place from the whole-lane table and scaled by their inverse norms,
+          which the encode took on the host and the program is handed slot
+          for slot beside the ids; the list rows are gathered in place from
+          the bucket rows,
           summed by token (sorted segments), divided by |G|, normalised; both
           are summed by sentence and divided by the live count on the device,
           trimmed to ``[S, D]`` there. A slide with more word rows or list
@@ -554,7 +559,10 @@ class Word2VecModel:
         ``zero_norm``: a string with no n-gram at all (``""``), and a word
         whose row has zero norm (:meth:`_inverse_norms` keeps their ids; a
         trained table has none). A composed token whose rows sum to zero is
-        left out by the program."""
+        left out by the program. The ids that stay take their scales here,
+        from the host's copy of the inverse norms (span ``transform.scale``,
+        ``rows`` the live ids): the program is handed them slot for slot
+        beside the ids and gathers nothing but rows."""
         tracer = default_tracer()
         walk = tracer.span("transform.encode.walk")
         n = len(slide)
@@ -586,8 +594,13 @@ class Word2VecModel:
                 of = np.repeat(np.arange(n, dtype=np.int32), counts)
                 counts = np.bincount(of[~dead], minlength=n).astype(np.int32)
                 ids, zero = ids[~dead], zero + int(dead.sum())
-        return _SlideTokens(ids, counts, unseen, list_rows, list_counts, zero,
-                            by_objects)
+        with tracer.span("transform.scale", rows=int(ids.shape[0])):
+            # the vocabulary's own rows, so no bound to check: "clip" is the
+            # take that checks none and leaves the interpreter lock alone
+            # (a[ids] of int32 ids: twice the time with four callers)
+            scales = np.take(self._host_inv, ids, mode="clip")
+        return _SlideTokens(ids, scales, counts, unseen, list_rows, list_counts,
+                            zero, by_objects)
 
     def _sentvec_begin(self, slide: Sequence[Sequence[str]], lo: int,
                        batch_size: int) -> "_PendingSlide":
@@ -605,7 +618,7 @@ class Word2VecModel:
             span.set(words=live, oov=0, unseen=composed, zero_norm=tokens.zero_norm,
                      empty=int(((tokens.counts + tokens.unseen) == 0).sum()))
         if live or composed:
-            table, scale = self._row_table(), self._inverse_norms()
+            table = self._row_table()
             segments = batch_size if n == batch_size else _grid_up(n, 8)
             passes = max(-(-live // _TRANSFORM_MAX_ROWS),
                          -(-listed // _TRANSFORM_MAX_ROWS), 1)
@@ -635,16 +648,15 @@ class Word2VecModel:
                     token_of_row = np.repeat(
                         np.arange(composed, dtype=np.int32), tokens.list_counts)
 
-
-                def part(values, lo, hi, size, fill):
-                    out = np.full(size, fill, np.int32)
+                def part(values, lo, hi, size, fill, dtype=np.int32):
+                    out = np.full(size, fill, dtype)
                     out[:max(hi - lo, 0)] = values[lo:hi]
                     return out
 
                 carried = None
                 for i in range(passes):
                     # past the live ids: a row no table has (read as zeros)
-                    # in a sentence no slide has (dropped)
+                    # in a sentence no slide has (dropped), whatever its scale
                     lo, hi = i * cap, min((i + 1) * cap, live)
                     lists = None
                     if composed:
@@ -659,7 +671,9 @@ class Word2VecModel:
                             part(token_of_row - t0, r0, r1, list_cap, token_cap),
                             part(token_seg, t0, t1, token_cap, segments))
                     carried = _sentence_means(
-                        table, scale, part(tokens.ids, lo, hi, cap, table.shape[0]),
+                        table,
+                        part(tokens.scales, lo, hi, cap, 0, tokens.scales.dtype),
+                        part(tokens.ids, lo, hi, cap, table.shape[0]),
                         part(seg, lo, hi, cap, segments), lists,
                         counts if i == passes - 1 else None, carried,
                         segments, self.vector_size)
@@ -769,18 +783,21 @@ class Word2VecModel:
                           "model.norms")[: self.vocab.size]
 
     def _inverse_norms(self) -> jax.Array:
-        """``sentence_vectors``' scale of every row of :meth:`_row_table`:
-        1 / :attr:`norms`, 0 for a row of zero norm; made once
-        (:meth:`_once`), with the ids of those rows kept on the host
-        (``_zero_rows``: the encode leaves their tokens out of the count)."""
+        """The scale that makes every row of the model's table a unit
+        vector: 1 / :attr:`norms`, 0 for a row of zero norm; made once
+        (:meth:`_once`). On the device for the analogy scan, which reads it
+        inside its program; fetched once for ``sentence_vectors``, whose
+        encode takes a slide's scales from the host's copy (``_host_inv``,
+        the words' alone) and leaves out of the count the tokens of the rows
+        of zero norm (``_zero_rows``, their ids)."""
         if self._inv_norms is None:
             self.norms  # the norms first: _once's lock is not re-entrant
 
         def make(_table) -> jax.Array:
             norms = self._norms
             inv = jnp.where(norms > 0, 1.0 / jnp.where(norms > 0, norms, 1.0), 0.0)
-            self._zero_rows = np.flatnonzero(
-                np.asarray(inv[: self.vocab.size]) == 0).astype(np.int32)
+            self._host_inv = np.asarray(inv[: self.vocab.size])
+            self._zero_rows = np.flatnonzero(self._host_inv == 0).astype(np.int32)
             return inv
 
         return self._once("_inv_norms", make)
@@ -1491,6 +1508,7 @@ class Word2VecModel:
         self._full0 = None  # type: ignore[assignment]
         self._full1 = None
         self._norms = self._inv_norms = None
+        self._host_inv = None
         self._raw0 = self._buckets = self._lanes = self._scan0 = None
         self._ann = None
         self._stopped = True
@@ -1567,6 +1585,7 @@ class _SlideTokens(NamedTuple):
     (``_encode_tokens``): what its program is handed, before the capacities."""
 
     ids: np.ndarray          # int32 [W] rows of the in-vocabulary tokens, as sent
+    scales: np.ndarray       # [W] 1 / the norm of each of those rows, as the norms are kept
     counts: np.ndarray       # int32 [S] how many of them each sentence holds
     unseen: np.ndarray       # int32 [S] composed tokens each sentence holds
     list_rows: np.ndarray    # int32 [L] the composed tokens' bucket rows, flat

@@ -127,8 +127,10 @@ def _sentence_means(table: jax.Array, scale: jax.Array, ids: jax.Array,
                     segments: int, dim: int):
     """One pass of a ``sentence_vectors`` slide, ONE program, a two-level
     ragged reduction. Words, as :func:`_segment_means`: rows ``ids`` of
-    ``table``, each times ``scale[id]`` (1 / its norm: a unit vector),
-    summed into the sentences ``seg`` names. Composed tokens, where ``lists``
+    ``table``, each times its slot of ``scale`` (``[len(ids)]``, 1 / the
+    row's norm taken by the host's encode: a unit vector; the program
+    gathers rows and nothing else), summed into the sentences ``seg``
+    names. Composed tokens, where ``lists``
     is handed over (``buckets``, ``rows``, ``token``, ``token_seg``): rows
     ``rows`` of ``buckets`` (one past them reads zeros) summed into the
     tokens ``token`` names (ascending: a token's rows lie together; one past
@@ -144,8 +146,7 @@ def _sentence_means(table: jax.Array, scale: jax.Array, ids: jax.Array,
     with jax.named_scope("transform.gather"):
         rows = table.at[ids].get(mode="fill", fill_value=0)
         acc = jnp.promote_types(rows.dtype, jnp.float32)
-        unit = rows.astype(acc) * scale.at[ids].get(
-            mode="fill", fill_value=0).astype(acc)[:, None]
+        unit = rows.astype(acc) * scale.astype(acc)[:, None]
     with jax.named_scope("transform.segment_mean"):
         sums = jax.ops.segment_sum(unit, seg, num_segments=segments,
                                    indices_are_sorted=True)

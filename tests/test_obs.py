@@ -1075,7 +1075,9 @@ def _sentence_slide_case():
         return _sentence_means(syn0, scale, ids, seg, (syn0, rows, token, token_seg),
                                counts, (sums, kept), 4, syn0.shape[1])
 
-    return slide, (syn0, 1.0 / norms, ids, seg, rows, token, token_seg,
+    # the ids' own scales, as the host's encode hands them (PR 60)
+    return slide, (syn0, jnp.take(1.0 / norms, ids, mode="clip"), ids, seg,
+                   rows, token, token_seg,
                    jnp.asarray([2, 1, 0, 2], jnp.int32),
                    jnp.ones((4, syn0.shape[1]), jnp.float32),
                    jnp.asarray([0, 1, 0, 0], jnp.int32))

@@ -95,33 +95,68 @@ def test_the_transform_slide_copies_no_table_and_writes_no_gathered_block(one_ch
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
-@pytest.mark.parametrize("carried", [False, True], ids=["one_pass", "a_further_pass"])
-def test_the_sentence_vector_slide_copies_no_table_and_writes_no_block(one_chip, carried):
+def _entry(compiled: str) -> str:
+    """The entry computation of a compiled module: what is scheduled and
+    written, without the fused computations' own instructions."""
+    return compiled[compiled.index("\nENTRY "):]
+
+
+def _lane_of_the_row(table, ids, seg, segments):
+    """ROADMAP A14 (a)'s form of the word side, NOT the program's: a row's
+    inverse norm kept in a spare lane of the row itself (lane 300 of 384), to
+    "ride the row gather for nothing". Kept here as the record of why it was
+    not taken (PR 60): reading one lane of the gathered rows makes the
+    compiler WRITE the gathered block, twice."""
+    rows = table.at[ids].get(mode="fill", fill_value=0)
+    unit = rows * rows[:, 300][:, None]
+    return jax.ops.segment_sum(unit, seg, num_segments=segments,
+                               indices_are_sorted=True)
+
+
+@pytest.mark.parametrize("form", ["one_pass", "a_further_pass", "lane_of_the_row"])
+def test_the_sentence_vector_slide_copies_no_table_and_writes_no_block(one_chip, form):
     """``sentence_vectors``' one program a slide (PR 52) at
     ``subword-sentvec-2.5m-300``'s size: 327,680 word rows gathered from the
-    composed table at whole lanes and scaled by their inverse norms, 294,912
+    composed table at whole lanes and scaled by the inverse norms the host's
+    encode took for them (a dense ``[327680]`` operand, PR 60), 294,912
     list rows gathered from the bucket rows into 32,768 tokens, normalised,
     both summed into 10,000 sentences. No copy of either table, no sort, and
     every gather is its sorted scatter-add's producer: neither gathered block
     nor the token block is written (what is made is the ``[10000, 384]``
-    sums)."""
+    sums), and the program gathers ROWS alone: no one-element gather of a
+    scale an id is left (2.28 ms of the slide's 15.5 before PR 60). The case
+    ``lane_of_the_row`` compiles the form that was refuted instead
+    (:func:`_lane_of_the_row`): over 900 MB of temporaries a slide."""
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     rows, listed, tokens, sentences = 327_680, 294_912, 32_768, 10_000
+    if form == "lane_of_the_row":
+        compiled = jax.jit(_lane_of_the_row, static_argnames="segments").lower(
+            spec((SUB_V, 384), jnp.float32), spec((rows,), jnp.int32),
+            spec((rows,), jnp.int32), segments=sentences).compile()
+        assert re.findall(r"= f32\[%d,384\]" % rows, _entry(compiled.as_text()))
+        assert compiled.memory_analysis().temp_size_in_bytes > 900e6
+        return
     lists = (spec((SUB_K, 384), jnp.float32), spec((listed,), jnp.int32),
              spec((listed,), jnp.int32), spec((tokens,), jnp.int32))
     before = ((spec((sentences, 384), jnp.float32), spec((sentences,), jnp.int32))
-              if carried else None)
+              if form == "a_further_pass" else None)
     compiled = transform._sentence_means.lower(
-        spec((SUB_V, 384), jnp.float32), spec((SUB_V,), jnp.float32),
+        spec((SUB_V, 384), jnp.float32), spec((rows,), jnp.float32),
         spec((rows,), jnp.int32), spec((rows,), jnp.int32), lists,
         spec((sentences,), jnp.int32), before, segments=sentences, dim=300).compile()
     text = compiled.as_text()
     _no_table_copied(text)
     assert " sort(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    # the scale arrives dense: no gather of one float32 an id ...
+    assert not [line.strip()[:100] for line in _entry(text).splitlines()
+                if re.search(r"= f32\[%d\]\S* fusion\(" % rows, line)
+                and "kind=kCustom" in line]
+    # ... and the gathered rows are their scatter-add's producer, never written
+    assert not re.findall(r"= f32\[%d,384\]" % rows, _entry(text))
 
 
 def _mesh_1x4(topo):

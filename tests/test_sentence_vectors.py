@@ -3,7 +3,8 @@ unsupervised branch) held to the plain reference on the CPU.
 
 The program (``models/word2vec.py``: one fixed-shape program a slide, a
 two-level ragged reduction, flat bucket-row lists -> unseen tokens -> unit
-vectors -> sentences, beside the word rows scaled by their inverse norms)
+vectors -> sentences, beside the word rows scaled by their inverse norms,
+which the host's encode takes and hands over dense)
 against ``benchmark/reference/sentvec_ref.py`` (its own dictionary and hasher, a
 Python loop in float64) on seeded tables: a model built each way (every table
 resident, and ``resident="rows"``), float32 and float64, ragged slides with
@@ -20,6 +21,7 @@ rounding of the result to float32).
 import os
 import sys
 import threading
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -199,6 +201,111 @@ def test_a_slide_over_a_capacity_runs_further_passes(model, tracer, monkeypatch,
     assert np.abs(got - expected(sents)).max() <= F32_TOL
     # the same sums in another association: within a rounding of one pass
     assert np.abs(got - one).max() <= 1e-6
+
+
+@partial(jax.jit, static_argnames=("segments", "dim"))
+def _scaled_on_the_device(table, scale, ids, seg, lists, counts, carried, segments, dim):
+    """The slide's program as it was before PR 60, kept here as the plain
+    form the program is held to: ``scale`` is the model's ``[V]`` vector of
+    inverse norms ON THE DEVICE and the program gathers ``scale[ids]`` itself
+    (``rows * inv[ids][:, None]``). ``ops/transform._sentence_means`` is handed
+    those values by the host and multiplies the same two numbers."""
+    rows = table.at[ids].get(mode="fill", fill_value=0)
+    acc = jnp.promote_types(rows.dtype, jnp.float32)
+    unit = rows.astype(acc) * scale.at[ids].get(
+        mode="fill", fill_value=0).astype(acc)[:, None]
+    sums = jax.ops.segment_sum(unit, seg, num_segments=segments,
+                               indices_are_sorted=True)
+    kept = None
+    if lists is not None:
+        buckets, list_rows, token, token_seg = lists
+        listed = buckets.at[list_rows].get(mode="fill", fill_value=0).astype(acc)
+        h = jax.ops.segment_sum(listed, token, num_segments=token_seg.shape[0],
+                                indices_are_sorted=True)[:, :sums.shape[1]]
+        norm = jnp.sqrt((h * h).sum(axis=1))
+        live = norm > 0
+        h = jnp.where(live[:, None], h / jnp.where(live, norm, 1)[:, None], 0)
+        sums = sums + jax.ops.segment_sum(h.astype(acc), token_seg,
+                                          num_segments=segments, indices_are_sorted=True)
+        kept = jax.ops.segment_sum(live.astype(jnp.int32), token_seg,
+                                   num_segments=segments, indices_are_sorted=True)
+    if carried is not None:
+        sums = sums + carried[0]
+        if kept is not None:
+            kept = kept + carried[1]
+    if counts is None:
+        return sums, kept
+    if kept is not None:
+        counts = counts + kept
+    return (sums[:, :dim] / jnp.maximum(counts, 1)[:, None].astype(
+        sums.dtype)).astype(jnp.float32)
+
+
+def _with_a_dead_word(resident, dtype):
+    """A subword model one of whose WORDS has a composed row of zero norm:
+    its own row and every bucket row of its n-grams zeroed."""
+    table = _table(dtype)
+    table[7] = 0.0
+    table[V + np.asarray(subword_module.ngram_buckets(STRINGS[7], MIN_N, MAX_N, K))] = 0.0
+    vocab = Vocabulary.from_words_and_counts(STRINGS, np.ones(V, np.int64))
+    return Word2VecModel(vocab, table[:V], None, config=CONFIG,
+                         subword_buckets=table[V:], resident=resident)
+
+
+def _without_subwords(resident, dtype):
+    table = _table(dtype)[:V]
+    table[7] = 0.0
+    return Word2VecModel(
+        Vocabulary.from_words_and_counts(STRINGS, np.ones(V, np.int64)), table)
+
+
+SCALED = {      # build, resident, dtype, passes, rows of zero norm
+    "float32_rows": (make_model, "rows", np.float32, 1, 0),
+    "float32_all": (make_model, "all", np.float32, 1, 0),
+    "float64_rows": (make_model, "rows", np.float64, 1, 0),
+    "float64_all": (make_model, "all", np.float64, 1, 0),
+    "rows_of_zero_norm": (_with_a_dead_word, "rows", np.float32, 1, 1),
+    "a_second_pass": (make_model, "rows", np.float32, 2, 0),
+    "without_subwords": (_without_subwords, "all", np.float32, 2, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SCALED))
+def test_the_hosts_scales_give_the_rows_the_device_gather_gave(case, monkeypatch):
+    """PR 60: the encode takes a slide's inverse norms from the model's host
+    copy and the program is handed them slot for slot beside the ids. Every
+    row equals, BIT FOR BIT, what the program gave while it gathered
+    ``scale[ids]`` from the ``[V]`` vector on the device
+    (:func:`_scaled_on_the_device`): the same two numbers multiplied."""
+    build, resident, dtype, passes, dead = SCALED[case]
+    with jax.enable_x64(dtype == np.float64):
+        m = build(resident, dtype)
+        sents = (sentences(61, 90) + [[STRINGS[7]], [STRINGS[7], STRINGS[3], "zzq"], [],
+                                      [DEAD, STRINGS[7]]])
+        live = sum(t in INDEX for s in sents for t in s)
+        if passes > 1:
+            most = max(live, _list_rows(sents) if m.composes_unseen else 0)
+            monkeypatch.setattr(w2v, "_TRANSFORM_MAX_ROWS", -(-most // passes))
+        got = m.sentence_vectors(sents)
+        assert m._zero_rows.tolist() == [7] * dead
+        assert m._host_inv.shape == (V,) and m._host_inv.dtype == m._inv_norms.dtype
+        handed = []
+
+        def gathers_its_scales(table, scale, ids, *rest):
+            real = ids < table.shape[0]
+            # the operand: one scale a slot, the live slots' the ids' own
+            assert scale.shape == ids.shape and scale.dtype == m._host_inv.dtype
+            assert np.array_equal(scale[real], np.asarray(m._inv_norms)[ids[real]])
+            handed.append(int(real.sum()))
+            return _scaled_on_the_device(table, m._inv_norms, ids, *rest)
+
+        monkeypatch.setattr(w2v, "_sentence_means", gathers_its_scales)
+        want = m.sentence_vectors(sents)
+        m.stop()
+    assert len(handed) == passes
+    assert sum(handed) == live - dead * sum(t == STRINGS[7] for s in sents for t in s)
+    assert got.dtype == np.float32 and got.any()
+    assert np.array_equal(got, want)
 
 
 def test_four_threads_call_at_once(model, lookup):
@@ -383,12 +490,14 @@ def test_the_default_model_makes_its_row_forms_lazily_and_stop_frees_them():
     m.find_synonyms(STRINGS[1], 3)
     assert m._lanes is None and m._inv_norms is None     # a scan makes neither
     m.sentence_vectors([[STRINGS[1], "zzq"]])
-    lanes, inv = m._lanes, m._inv_norms
+    lanes, inv, host = m._lanes, m._inv_norms, m._host_inv
     assert lanes.shape == (V, 128) and inv.shape == (V,)
+    # the encode's copy of the inverse norms: the same numbers, on the host
+    assert isinstance(host, np.ndarray) and np.array_equal(host, np.asarray(inv))
     m.sentence_vectors([[STRINGS[2]]])
-    assert m._lanes is lanes and m._inv_norms is inv
+    assert m._lanes is lanes and m._inv_norms is inv and m._host_inv is host
     m.stop()
-    assert lanes.is_deleted() and inv.is_deleted()
+    assert lanes.is_deleted() and inv.is_deleted() and m._host_inv is None
 
 
 def test_spans_of_a_call_of_three_slides(model, tracer, lookup):
@@ -416,6 +525,10 @@ def test_spans_of_a_call_of_three_slides(model, tracer, lookup):
         assert hashed["args"]["list_rows"] == _list_rows(part)
         assert hashed["args"]["native"] == int(
             subword_module._load_native() is not None)
+        # one take of the live ids' inverse norms a slide, inside its encode
+        (scaled,) = [e for e in events if e["name"] == "transform.scale"
+                     and e["parent"] == children[0]["id"]]
+        assert scaled["args"] == {"rows": words_}
         enqueue = children[1]["args"]
         assert enqueue["rows"] == words_ and enqueue["passes"] == 1
         assert enqueue["list_rows"] == _list_rows(part)
@@ -427,6 +540,7 @@ def test_spans_of_a_call_of_three_slides(model, tracer, lookup):
             if e["name"] == "transform.enqueue"] == [0, 1, 1]
     walks = [e for e in events if e["name"] == "transform.encode.walk"]
     assert len(walks) == (3 if lookup == "native" else 0)
+    assert sum(e["name"] == "transform.scale" for e in events) == 3
 
 
 def test_compose_says_its_lanes_and_init_its_residency():
